@@ -3,9 +3,10 @@
 Uppercase buffer-API collectives through ``ompi_tpu.compat.MPI`` should
 cost ~the native array API (the stacked-ndarray fast path skips the
 per-rank python list round-trip mpi4py users would never expect from
-uppercase calls).  Run standalone to see the ratio per collective:
+uppercase calls).  Run standalone, from the repo root, to see the ratio
+per collective:
 
-    python examples/facade_collectives_bench.py
+    python -m examples.facade_collectives_bench
 
 Exercised by tests/runtime/test_examples.py as a smoke; the ratio
 assertion lives in tests/mpi/test_mpi4py_compat.py (1-core boxes make

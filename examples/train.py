@@ -5,7 +5,8 @@ prefetch) → 3D-parallel transformer (dp × sp × tp shard_map) → in-jit
 chained train steps → snapshot checkpoint → resume reproducing the
 exact batch stream from the saved step.
 
-Run:  python examples/train.py [--steps 6] [--ckpt-dir /tmp/train_ckpt]
+Run from the repo root (no install needed):
+    python -m examples.train [--steps 6] [--ckpt-dir /tmp/train_ckpt]
 """
 
 import argparse
@@ -23,10 +24,12 @@ def main() -> None:
     import jax
 
     from ompi_tpu.ckpt.store import SnapshotStore
+    from ompi_tpu.core import enable_compile_cache
     from ompi_tpu.models import data as data_mod
     from ompi_tpu.models import transformer as tfm
     from ompi_tpu.parallel.mesh import make_mesh, mesh_shape_for
 
+    enable_compile_cache()
     n = len(jax.devices())
     shape = mesh_shape_for(n, ["dp", "tp"])
     mesh = make_mesh({"dp": shape["dp"], "sp": 1, "tp": shape["tp"]},
@@ -37,7 +40,7 @@ def main() -> None:
         adam_mu_dtype="bfloat16")
     batch = 4 * shape["dp"]
 
-    params = tfm.init_params(cfg)
+    params = tfm.shard_params(cfg, mesh, tfm.init_params(cfg))
     step, init_opt = tfm.make_train_step(cfg, mesh, lr=3e-3)
     opt_state = init_opt(params)
 

@@ -1,8 +1,9 @@
 """Host topology discovery — the hwloc-lite.
 
 ≈ the role opal's vendored hwloc plays for ras/rmaps (opal/mca/hwloc):
-how many packages/cores/threads does this host have, what accelerators
-are attached, and which CPUs may this process use.  Reads Linux /sys
+how many packages/cores/threads does this host have and which CPUs may
+this process use.  It runs in the launcher, so it never touches jax (the
+process that initialises the TPU runtime holds the chips).  Reads Linux /sys
 and falls back to ``os.cpu_count`` elsewhere; no external dependency —
 the consumers (ras slot counts, rmaps binding, diagnostics) need counts
 and ids, not hwloc's full tree.
@@ -25,7 +26,6 @@ class Topology:
     physical_cores: int        # distinct (package, core) pairs
     packages: int              # sockets
     allowed_cpus: int          # this process's cpuset width (affinity)
-    accelerators: int          # non-CPU jax devices visible (0 = none/unknown)
 
     @property
     def smt(self) -> int:
@@ -63,10 +63,8 @@ def _sysfs_topology() -> Optional[tuple[int, int, int]]:
     return logical, len(pairs), len(packages)
 
 
-def discover(probe_accelerators: bool = False) -> Topology:
-    """Inspect this host.  ``probe_accelerators`` touches jax (may
-    initialize a backend — callers on the launch path keep it False and
-    let the app side probe)."""
+def discover() -> Topology:
+    """Inspect this host."""
     sysfs = _sysfs_topology()
     if sysfs is not None:
         logical, cores, pkgs = sysfs
@@ -77,14 +75,5 @@ def discover(probe_accelerators: bool = False) -> Topology:
         allowed = len(os.sched_getaffinity(0))
     except (AttributeError, OSError):
         allowed = logical
-    accel = 0
-    if probe_accelerators:
-        try:
-            import jax
-
-            accel = sum(1 for d in jax.devices() if d.platform != "cpu")
-        except Exception:  # noqa: BLE001 — no backend ⇒ no accelerators
-            accel = 0
     return Topology(logical_cpus=logical, physical_cores=cores,
-                    packages=pkgs, allowed_cpus=allowed,
-                    accelerators=accel)
+                    packages=pkgs, allowed_cpus=allowed)

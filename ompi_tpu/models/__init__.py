@@ -12,6 +12,8 @@ from typing import Any
 _LAZY = {
     "TransformerConfig": ("ompi_tpu.models.transformer",
                           "TransformerConfig"),
+    "FLAGSHIP": ("ompi_tpu.models.transformer", "FLAGSHIP"),
+    "FLAGSHIP_BATCH": ("ompi_tpu.models.transformer", "FLAGSHIP_BATCH"),
     "init_params": ("ompi_tpu.models.transformer", "init_params"),
     "make_train_step": ("ompi_tpu.models.transformer", "make_train_step"),
     "make_train_loop": ("ompi_tpu.models.transformer", "make_train_loop"),

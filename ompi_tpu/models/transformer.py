@@ -20,8 +20,9 @@ from typing import Any, Optional
 
 import numpy as np
 
-__all__ = ["TransformerConfig", "init_params", "param_specs", "make_loss_fn",
-           "make_train_step", "make_train_loop", "make_forward"]
+__all__ = ["TransformerConfig", "FLAGSHIP", "FLAGSHIP_BATCH", "init_params",
+           "param_specs", "shard_params", "make_loss_fn", "make_train_step",
+           "make_train_loop", "make_forward"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,6 +97,18 @@ class TransformerConfig:
         return self.d_model // self.n_heads
 
 
+# The one model the repo runs at a real size: 468M dense, 16 heads of 128.
+# bench.py, chip_smoke.py and the tools/ that time it take the dims from
+# here and vary them with dataclasses.replace.  ce_chunk drops the
+# (B, T, V) f32 logits + log-softmax pair (~4 GiB at batch 16) to
+# O(chunk·V); batch 24 ran out of HBM on one 16 GB chip in the old sweep.
+FLAGSHIP = TransformerConfig(
+    vocab=32_000, d_model=2048, n_heads=16, n_layers=8, d_ff=8192,
+    seq=1024, attention="xla", ce_chunk=256, compute_dtype="bfloat16",
+    remat="dots")
+FLAGSHIP_BATCH = 16
+
+
 def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
     """Global (unsharded) parameter pytree; layers stacked for lax.scan."""
     rng = np.random.default_rng(seed)
@@ -151,6 +164,20 @@ def param_specs(P, cfg: Optional[TransformerConfig] = None, mesh=None):
         specs["w1"] = P(None, None, "tp")
         specs["w2"] = P(None, "tp", None)
     return specs
+
+
+def shard_params(cfg: TransformerConfig, mesh, params: dict) -> dict:
+    """Place each parameter on ``mesh`` with its :func:`param_specs`
+    sharding.  ``init(params)`` of the optimizers then builds state with
+    the same shardings (``zeros_like`` keeps them), so the first step
+    neither reshards nor loses its donation."""
+    import jax
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    specs = param_specs(P, cfg, mesh)
+    return {k: jax.device_put(v, NamedSharding(mesh, specs[k]))
+            for k, v in params.items()}
 
 
 def _rmsnorm(x, scale):
@@ -535,6 +562,29 @@ def _make_step_body(cfg: TransformerConfig, mesh, lr: float):
     return body, _MasterOpt
 
 
+def _init_on_mesh(cfg: TransformerConfig, mesh, init):
+    """``init`` with every leaf of its state committed to ``mesh``.
+
+    The parameters are placed first, so leaves that mirror one inherit its
+    sharding (``zeros_like`` keeps it); what is left, the step counters,
+    is replicated.  A state that enters the first step with an uncommitted
+    or differently placed leaf comes back placed, which makes the second
+    call compile the whole step again.
+    """
+    import jax
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    def on_mesh(params):
+        state = init(shard_params(cfg, mesh, params))
+        replicated = NamedSharding(mesh, P())
+        return jax.tree_util.tree_map(
+            lambda x: x if isinstance(x.sharding, NamedSharding)
+            else jax.device_put(x, replicated), state)
+
+    return on_mesh
+
+
 def make_train_step(cfg: TransformerConfig, mesh, lr: float = 3e-4):
     """jitted (params, opt_state, tokens) → (params, opt_state, loss).
 
@@ -548,7 +598,7 @@ def make_train_step(cfg: TransformerConfig, mesh, lr: float = 3e-4):
     # params/opt_state are donated: the updated trees reuse their HBM
     # in place of a second full copy (≈1.6 GiB at 133M params with Adam)
     step = functools.partial(jax.jit, donate_argnums=(0, 1))(body)
-    return step, opt.init
+    return step, _init_on_mesh(cfg, mesh, opt.init)
 
 
 def make_train_loop(cfg: TransformerConfig, mesh, lr: float = 3e-4,
@@ -557,10 +607,8 @@ def make_train_loop(cfg: TransformerConfig, mesh, lr: float = 3e-4,
     ``steps`` optimizer steps inside ONE compiled program (lax.scan over
     the step), donated carry.
 
-    One dispatch per K steps is how real training loops run — and the only
-    honest way to time the device when the host link has per-call latency
-    (a remote/tunneled runtime stalls between dispatches; chaining keeps
-    the chip busy back-to-back).
+    One dispatch per K steps keeps the chip busy back-to-back: the host's
+    per-call dispatch cost is paid once per K steps.
     """
     import jax
     from jax import lax
@@ -577,4 +625,4 @@ def make_train_loop(cfg: TransformerConfig, mesh, lr: float = 3e-4,
             scan_body, (params, opt_state), None, length=steps)
         return params, opt_state, losses
 
-    return run, opt.init
+    return run, _init_on_mesh(cfg, mesh, opt.init)

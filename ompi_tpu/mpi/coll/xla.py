@@ -31,6 +31,7 @@ from typing import Optional
 
 import numpy as np
 
+from ompi_tpu.core import output
 from ompi_tpu.core.buffer import BufferKind, BufferLocationError, classify
 from ompi_tpu.core.config import VarType, register_var, var_registry
 from ompi_tpu.core.mca import Component
@@ -38,6 +39,8 @@ from ompi_tpu.mpi.coll import coll_framework, rules
 from ompi_tpu.mpi.op import Op
 
 __all__ = ["XlaColl"]
+
+_log = output.get_stream("coll")
 
 
 def _dev_nbytes(buf) -> int:
@@ -71,13 +74,16 @@ def _measured_rules():
     rs = None
     try:
         loaded = rules.load_rules(_MEASURED_PATH)
+    except Exception as e:  # noqa: BLE001 — a bad shipped file must not
+        # break collectives; memoized below, so this is logged once
+        _log.error("shipped rules file %s does not load, fixed decision "
+                   "rules apply: %r", _MEASURED_PATH, e)
+    else:
         import jax
 
         if (len(loaded) > 0
                 and loaded.meta.get("platform") == jax.default_backend()):
             rs = loaded
-    except Exception:  # noqa: BLE001 — a bad shipped file must not break colls
-        rs = None
     _measured_cache[:] = [(mtime, rs)]
     return rs
 

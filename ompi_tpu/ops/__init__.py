@@ -2,9 +2,10 @@
 
 The reference keeps its hot loops in C (the convertor, the coll algorithm
 library); the TPU analog of "hand-tuned native hot path" is a pallas
-kernel feeding the MXU directly from VMEM.  Everything here has a pure-XLA
-fallback — kernels are accelerators, never requirements (same policy as
-ompi_tpu/_native).
+kernel feeding the MXU directly from VMEM.  A kernel compiles for the TPU
+or fails: nothing here chooses interpret mode or another path on its own.
+Callers that want the pure-XLA form ask for it by name
+(``parallel.attention.resolve_impl``).
 """
 
 from ompi_tpu.ops.flash_attention import flash_attention, flash_attention_lse

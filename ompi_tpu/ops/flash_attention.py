@@ -13,9 +13,12 @@ attention weights in pure XLA from the saved (q, k, v, out, logsumexp)
 residuals — the standard flash-attention recompute strategy (no O(T²)
 activation storage).
 
-Fallback policy: non-TPU backends run the kernel in pallas interpret mode
-(tests on the virtual CPU mesh); shapes that don't tile (T % block != 0)
-fall back to the jnp reference implementation.
+The kernels are always compiled for the TPU: there is no interpret
+selection here.  Off-TPU the call fails to lower unless the caller traces
+it under ``pltpu.force_tpu_interpret_mode()`` (tests/conftest.py does, for
+the virtual CPU mesh).  Shapes that don't tile (T % block != 0) are
+rejected; ``parallel.attention.resolve_impl`` is where callers choose
+between this kernel and the jnp reference.
 """
 
 from __future__ import annotations
@@ -43,6 +46,25 @@ register_var("ops", "flash_bwd_kernel", VarType.BOOL, False,
              "materialized pure-XLA backward")
 
 _NEG = -1e30
+
+# K and V (in the dk/dv kernel Q and dO) enter a grid cell as ONE
+# whole-sequence VMEM block each.  Measured on a v5e (libtpu 0.0.34): 32768
+# rows of 128 bf16 compile, forward and both backwards; 65536 rows are
+# refused ("Scoped allocation with size 32.xM and limit 16.00M").  Past
+# this many bytes per operand the call raises here instead of deep in the
+# compiler; re-blocking K/V is ROADMAP S3.
+_WHOLE_SEQ_VMEM_BYTES = 8 << 20
+
+
+def _check_whole_seq_fits(t: int, d: int, dtype, what: str) -> None:
+    nbytes = t * d * jnp.dtype(dtype).itemsize
+    if nbytes > _WHOLE_SEQ_VMEM_BYTES:
+        raise ValueError(
+            f"flash_attention: {what} of {t} rows x {d} x {jnp.dtype(dtype)} "
+            f"is {nbytes >> 20} MiB, and the kernel holds it in VMEM as one "
+            f"block (limit {_WHOLE_SEQ_VMEM_BYTES >> 20} MiB, where the TPU "
+            f"compiler refuses it).  Shard the sequence (ring_attention "
+            f"over sp) so that each device's share fits.")
 
 
 def flash_tiles(t_q: int, t_k: int, block_q: int = 128,
@@ -81,8 +103,9 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     def body(j, carry):
         m, l, acc = carry
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]     # (bk, D)
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
+        ks = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k_blk = k_ref[0, ks, :]                              # (bk, D)
+        v_blk = v_ref[0, ks, :]
         s = jax.lax.dot_general(                             # (bq, bk)
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -116,8 +139,7 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _flash_fwd_raw(q3, k3, v3, q_offset, k_offset, scale: float,
-                   causal: bool, block_q: int, block_k: int,
-                   interpret: bool):
+                   causal: bool, block_q: int, block_k: int):
     """(BH, Tq, D) × (BH, Tk, D) → ((BH, Tq, D), (BH, Tq) lse f32)."""
     from jax.experimental import pallas as pl
 
@@ -148,7 +170,6 @@ def _flash_fwd_raw(q3, k3, v3, q_offset, k_offset, scale: float,
             jax.ShapeDtypeStruct((bh, t_q, d), q3.dtype),
             jax.ShapeDtypeStruct((bh, nq, 8, block_q), jnp.float32),
         ],
-        interpret=interpret,
     )(qoff, koff, q3, k3, v3)
     return o3, lse3[:, :, 0, :].reshape(bh, t_q)
 
@@ -188,8 +209,9 @@ def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, g_ref, lse_ref,
             + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0))
 
     def body(j, acc):
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
+        ks = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k_blk = k_ref[0, ks, :]
+        v_blk = v_ref[0, ks, :]
         s = lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
         if causal:
@@ -226,8 +248,9 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, g_ref,
 
     def body(i, carry):
         dk, dv = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :]         # (bq, D)
-        g = g_ref[0, pl.ds(i * block_q, block_q), :]
+        qs = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        q = q_ref[0, qs, :]                                  # (bq, D)
+        g = g_ref[0, qs, :]
         lse = lse_ref[0, i, 0]                               # (bq,)
         dm = dm_ref[0, i, 0]
         s = lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
@@ -258,8 +281,7 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, g_ref,
 
 
 def _flash_bwd_raw(q3, k3, v3, g3, lse3, dm3, qoff, koff, scale: float,
-                   causal: bool, block_q: int, block_k: int,
-                   interpret: bool):
+                   causal: bool, block_q: int, block_k: int):
     """(BH,·,D) inputs → (dq3, dk3, dv3)."""
     from jax.experimental import pallas as pl
 
@@ -294,7 +316,6 @@ def _flash_bwd_raw(q3, k3, v3, g3, lse3, dm3, qoff, koff, scale: float,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t_q, d), q3.dtype),
-        interpret=interpret,
     )(qoff, koff, q3, k3, v3, g3, lse_c, dm_c)
     dk3, dv3 = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -318,7 +339,6 @@ def _flash_bwd_raw(q3, k3, v3, g3, lse3, dm3, qoff, koff, scale: float,
             jax.ShapeDtypeStruct((bh, t_k, d), k3.dtype),
             jax.ShapeDtypeStruct((bh, t_k, d), v3.dtype),
         ],
-        interpret=interpret,
     )(qoff, koff, q3, k3, v3, g3, lse_c, dm_c)
     return dq3, dk3, dv3
 
@@ -344,10 +364,6 @@ def _from3(x3, b, h):
     return x3.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _flash(q, k, v, qoff, koff, scale, causal, blocks):
     return _flash_core(q, k, v, qoff, koff, scale, causal, blocks)
@@ -357,8 +373,7 @@ def _flash_core(q, k, v, qoff, koff, scale, causal, blocks):
     b, t_q, h, d = q.shape
     block_q, block_k = blocks
     o3, lse3 = _flash_fwd_raw(_to3(q), _to3(k), _to3(v), qoff, koff,
-                              scale, causal, block_q, block_k,
-                              _use_interpret())
+                              scale, causal, block_q, block_k)
     return _from3(o3, b, h), lse3.reshape(b, h, t_q)
 
 
@@ -379,6 +394,7 @@ def _flash_bwd(scale, causal, blocks, res, cts):
     zoff = np.zeros((1,), dtype=jax.dtypes.float0)  # int args: no tangent
     b, t_q, h, d = q.shape
     if _bwd_kernel_wanted():
+        _check_whole_seq_fits(t_q, d, q.dtype, "Q/dO in the dk/dv kernel")
         block_q, block_k = blocks
         f32 = jnp.float32
         g3, o3, q3 = _to3(g), _to3(out), _to3(q)
@@ -389,7 +405,7 @@ def _flash_bwd(scale, causal, blocks, res, cts):
             dm = delta - g_lse.reshape(b * h, t_q).astype(f32)
         dq3, dk3, dv3 = _flash_bwd_raw(
             q3, _to3(k), _to3(v), g3, lse.reshape(b * h, t_q), dm,
-            qoff, koff, scale, causal, block_q, block_k, _use_interpret())
+            qoff, koff, scale, causal, block_q, block_k)
         return (_from3(dq3, b, h), _from3(dk3, b, h), _from3(dv3, b, h),
                 zoff, zoff)
     f32 = jnp.float32
@@ -430,6 +446,7 @@ def _check_blocks(q, k, block_q, block_k):
         raise ValueError(
             f"flash_attention: T ({t_q},{t_k}) must tile by blocks "
             f"({block_q},{block_k})")
+    _check_whole_seq_fits(t_k, k.shape[-1], k.dtype, "K/V")
     return min(block_q, t_q), min(block_k, t_k)
 
 

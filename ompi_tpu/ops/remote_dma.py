@@ -20,15 +20,21 @@ be called inside ``shard_map`` over the mesh axis (the same contract as
 every DeviceCommunicator method); ``DeviceCommunicator.put/get`` wrap
 them for driver mode.
 
-CPU testing: pass ``interpret=pltpu.InterpretParams()`` (the TPU
-interpret mode models cross-device DMA + semaphores on the host); the
-real path lowers to ICI RDMA on TPU.
+The kernels are always compiled for the TPU.  Off-TPU they fail to
+lower unless traced under ``pltpu.force_tpu_interpret_mode()`` (the TPU
+interpret mode models cross-device DMA + semaphores on the host;
+tests/conftest.py enters it for the virtual CPU mesh).
+
+Every cross-device kernel opens with a handshake on the barrier
+semaphore: a remote write may only start once its target has entered the
+same kernel (its semaphores exist and the window's producer has
+finished).  That is what ``collective_id`` is for; the kernels never
+overlap, so they share one id.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
 
 __all__ = ["window_put", "window_get", "fetch_bcast"]
 
@@ -40,16 +46,27 @@ def _pl():
     return pl, pltpu
 
 
-def _interp(interpret):
-    """Default: interpret on non-TPU backends (CPU tests), native on TPU."""
-    if interpret is not None:
-        return interpret
-    import jax
+_COLLECTIVE_ID = 0
 
-    if jax.default_backend() == "tpu":
-        return False
+
+def _params(cross_device: bool):
     _, pltpu = _pl()
-    return pltpu.InterpretParams()
+    return pltpu.CompilerParams(
+        has_side_effects=True,
+        collective_id=_COLLECTIVE_ID if cross_device else None)
+
+
+def _meet(my, a: int, b: int) -> None:
+    """Devices ``a`` and ``b`` each wait until the other is in the kernel."""
+    pl, pltpu = _pl()
+    barrier = pltpu.get_barrier_semaphore()
+    for me, peer in ((a, b), (b, a)):
+        @pl.when(my == me)
+        def _(peer=peer):
+            pltpu.semaphore_signal(
+                barrier, 1, device_id=peer,
+                device_id_type=pltpu.DeviceIdType.LOGICAL)
+            pltpu.semaphore_wait(barrier, 1)
 
 
 def _put_kernel(src_ref, win_ref, out_ref, send_sem, recv_sem, *,
@@ -59,7 +76,6 @@ def _put_kernel(src_ref, win_ref, out_ref, send_sem, recv_sem, *,
     out_ref is input/output-aliased to win_ref, so "unchanged" costs
     nothing; only the landing shard is written remotely.
     """
-    import jax
     from jax import lax
 
     pl, pltpu = _pl()
@@ -71,6 +87,7 @@ def _put_kernel(src_ref, win_ref, out_ref, send_sem, recv_sem, *,
             copy.start()
             copy.wait()
         return
+    _meet(my, src, dst)
     rdma = pltpu.make_async_remote_copy(
         src_ref=src_ref, dst_ref=out_ref, send_sem=send_sem,
         recv_sem=recv_sem, device_id=dst,
@@ -86,8 +103,7 @@ def _put_kernel(src_ref, win_ref, out_ref, send_sem, recv_sem, *,
         rdma.wait_recv()
 
 
-def window_put(win, value, src: int, dst: int, axis: str,
-               interpret: Optional[Any] = None):
+def window_put(win, value, src: int, dst: int, axis: str):
     """One-sided put (inside shard_map): device ``src`` writes ``value``
     into device ``dst``'s window shard; returns the new window.  Bytes
     cross ICI once, src→dst — no collective dataflow.
@@ -112,8 +128,7 @@ def window_put(win, value, src: int, dst: int, axis: str,
         out_shape=jax.ShapeDtypeStruct(win.shape, win.dtype),
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
         input_output_aliases={1: 0},      # win -> out
-        interpret=_interp(interpret),
-        compiler_params=pltpu.CompilerParams(has_side_effects=True),
+        compiler_params=_params(src != dst),
     )(value, win)
 
 
@@ -131,6 +146,7 @@ def _get_kernel(win_ref, local_ref, out_ref, send_sem, recv_sem, *,
             copy.start()
             copy.wait()
         return
+    _meet(my, src, dst)
     rdma = pltpu.make_async_remote_copy(
         src_ref=win_ref, dst_ref=out_ref, send_sem=send_sem,
         recv_sem=recv_sem, device_id=dst,
@@ -146,8 +162,7 @@ def _get_kernel(win_ref, local_ref, out_ref, send_sem, recv_sem, *,
         rdma.wait_recv()
 
 
-def window_get(win, src: int, dst: int, axis: str,
-               interpret: Optional[Any] = None):
+def window_get(win, src: int, dst: int, axis: str):
     """One-sided get (inside shard_map): device ``dst`` fetches device
     ``src``'s window shard; returns the fetched buffer (on every other
     device: its own window shard, via a local copy).
@@ -170,8 +185,7 @@ def window_get(win, src: int, dst: int, axis: str,
         out_shape=jax.ShapeDtypeStruct(win.shape, win.dtype),
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
         input_output_aliases={1: 0},      # local buf -> out
-        interpret=_interp(interpret),
-        compiler_params=pltpu.CompilerParams(has_side_effects=True),
+        compiler_params=_params(src != dst),
     )(win, win)
 
 
@@ -184,12 +198,14 @@ def _bcast_kernel(src_ref, out_ref, send_sem, recv_sem, *,
 
     pl, pltpu = _pl()
     my = lax.axis_index(axis)
+    barrier = pltpu.get_barrier_semaphore()
 
     @pl.when(my == root)
     def _serve():
         copy = pltpu.make_async_copy(src_ref, out_ref, send_sem)
         copy.start()
         copy.wait()
+        pltpu.semaphore_wait(barrier, n - 1)   # every receiver is in
         for peer in range(n):
             if peer == root:
                 continue
@@ -202,19 +218,23 @@ def _bcast_kernel(src_ref, out_ref, send_sem, recv_sem, *,
 
     @pl.when(my != root)
     def _recv():
+        pltpu.semaphore_signal(
+            barrier, 1, device_id=root,
+            device_id_type=pltpu.DeviceIdType.LOGICAL)
         pltpu.make_async_remote_copy(
             src_ref=src_ref, dst_ref=out_ref, send_sem=send_sem,
             recv_sem=recv_sem, device_id=root,
             device_id_type=pltpu.DeviceIdType.LOGICAL).wait_recv()
 
 
-def fetch_bcast(x, root: int, n: int, axis: str,
-                interpret: Optional[Any] = None):
+def fetch_bcast(x, root: int, n: int, axis: str):
     """Root's buffer delivered to all n devices by explicit one-sided
     puts (demonstrates put composition; the production bcast stays on
     the coll/xla decision layer)."""
     import jax
 
+    if n == 1:
+        return x
     pl, pltpu = _pl()
     return pl.pallas_call(
         functools.partial(_bcast_kernel, root=root, n=n, axis=axis),
@@ -222,6 +242,5 @@ def fetch_bcast(x, root: int, n: int, axis: str,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-        interpret=_interp(interpret),
-        compiler_params=pltpu.CompilerParams(has_side_effects=True),
+        compiler_params=_params(True),
     )(x)

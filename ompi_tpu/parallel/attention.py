@@ -23,54 +23,54 @@ from __future__ import annotations
 from typing import Optional
 
 __all__ = ["local_attention", "local_attention_lse", "ring_attention",
-           "ulysses_attention", "gathered_attention"]
+           "ulysses_attention", "gathered_attention", "resolve_impl"]
 
 _NEG = -1e30
 
 
-def _flash_blocks(t_q: int, t_k: int) -> tuple[int, int]:
-    """Resolve the ops_flash_block_q/k tuning vars against this shape:
-    non-positive values and non-tiling combinations fall back (each side
-    independently) to the kernel's 128 default.  flash_tiles stays the
-    single source of the tiling rule."""
+def _flash_blocks() -> tuple[int, int]:
+    """The ops_flash_block_q/k tuning vars; non-positive values raise."""
+    import ompi_tpu.ops.flash_attention  # noqa: F401 — registers the vars
     from ompi_tpu.core.config import var_registry
-    from ompi_tpu.ops.flash_attention import flash_tiles
 
-    bq = int(var_registry.get("ops_flash_block_q") or 128)
-    bk = int(var_registry.get("ops_flash_block_k") or 128)
-    if bq <= 0:
-        bq = 128
-    if bk <= 0:
-        bk = 128
-    if not flash_tiles(t_q, t_k, bq, bk):
-        if flash_tiles(t_q, t_k, bq, 128):
-            bk = 128
-        elif flash_tiles(t_q, t_k, 128, bk):
-            bq = 128
-        else:
-            bq = bk = 128
+    bq = int(var_registry.get("ops_flash_block_q"))
+    bk = int(var_registry.get("ops_flash_block_k"))
+    if bq <= 0 or bk <= 0:
+        raise ValueError(
+            f"ops_flash_block_q/k must be positive, got ({bq}, {bk})")
     return bq, bk
 
 
-def _flash_wanted(impl: str, t_q: int, t_k: int,
-                  bq: int = 128, bk: int = 128) -> bool:
-    """Route to the pallas kernel?  "auto" = yes on TPU when the shape
-    tiles AT THE RESOLVED BLOCK SIZES (CPU test meshes keep the cheap
-    jnp path — interpret-mode pallas is orders of magnitude slower and
-    tests cross-check both paths explicitly); "flash" = required, raise
-    if untileable."""
+def resolve_impl(impl: str, t_q: int, t_k: int) -> str:
+    """Which local attention runs for this shape: ``"flash"`` or ``"jnp"``.
+
+    "jnp" and "flash" are taken at their word ("flash" raises when the
+    configured blocks do not tile the shape).  "auto" picks the pallas
+    kernel on a TPU backend when the shape tiles, and the jnp path on any
+    other backend (the kernel only compiles for the TPU) or when no block
+    setting could tile the shape.  Configured blocks that break a shape
+    the 128 default would tile are an error, not a reason to switch
+    paths.  Callers that must know which path ran (chip_smoke.py) ask
+    here instead of re-deriving the rule.
+    """
     import jax
 
     from ompi_tpu.ops.flash_attention import flash_tiles
 
     if impl == "jnp":
-        return False
-    tiles = flash_tiles(t_q, t_k, bq, bk)
-    if impl == "flash":
-        if not tiles:
-            raise ValueError("flash impl needs block-tiling shapes")
-        return True
-    return tiles and jax.default_backend() == "tpu"
+        return "jnp"
+    if impl not in ("auto", "flash"):
+        raise ValueError(f"unknown attention impl {impl!r}")
+    if impl == "auto" and jax.default_backend() != "tpu":
+        return "jnp"
+    bq, bk = _flash_blocks()
+    if flash_tiles(t_q, t_k, bq, bk):
+        return "flash"
+    if impl == "flash" or flash_tiles(t_q, t_k):
+        raise ValueError(
+            f"flash attention: sequence lengths ({t_q}, {t_k}) do not "
+            f"tile by ops_flash_block_q/k = ({bq}, {bk})")
+    return "jnp"
 
 
 def local_attention(q, k, v, causal: bool = True,
@@ -83,8 +83,7 @@ def local_attention(q, k, v, causal: bool = True,
     Shapes: q (B, Tq, H, D), k/v (B, Tk, H, D) → (B, Tq, H, D).
 
     ``impl``: "flash" = the pallas blockwise kernel (ompi_tpu.ops),
-    "jnp" = materialized scores, "auto" = flash on TPU when the shape
-    tiles, jnp otherwise.
+    "jnp" = materialized scores, "auto" = see :func:`resolve_impl`.
     """
     o, _ = local_attention_lse(q, k, v, causal=causal, q_offset=q_offset,
                                k_offset=k_offset, scale=scale, impl=impl)
@@ -100,10 +99,10 @@ def local_attention_lse(q, k, v, causal: bool = True,
     import jax.numpy as jnp
 
     scale = scale if scale is not None else q.shape[-1] ** -0.5
-    bq, bk = _flash_blocks(q.shape[1], k.shape[1])
-    if _flash_wanted(impl, q.shape[1], k.shape[1], bq, bk):
+    if resolve_impl(impl, q.shape[1], k.shape[1]) == "flash":
         from ompi_tpu.ops.flash_attention import flash_attention_lse
 
+        bq, bk = _flash_blocks()
         return flash_attention_lse(q, k, v, causal=causal,
                                    q_offset=q_offset, k_offset=k_offset,
                                    scale=scale, block_q=bq, block_k=bk)

@@ -1080,7 +1080,6 @@ class DvmHnp(MultiHostLauncher):
         for i, n in enumerate(vm.nodes):
             row = {"vpid": i + 1, "host": n.name, "slots": n.slots,
                    "slots_inuse": n.slots_inuse,
-                   "chips": (len(n.chips) if n.chips else 0),
                    "pid": (self._daemon_popen[i].pid
                            if i < len(self._daemon_popen) else None)}
             if i + 1 in hb_ages:

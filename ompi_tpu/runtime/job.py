@@ -48,10 +48,7 @@ class Node:
 
     name: str
     slots: int = 1
-    # TPU metadata: chip coordinates for device-per-rank mapping, or None.
-    chips: Optional[list[Any]] = None
     slots_inuse: int = 0
-    topology: Optional[dict] = None  # fake hwloc-ish topology from simulator
 
     @property
     def slots_available(self) -> int:
@@ -65,7 +62,6 @@ class Proc:
     rank: int
     node: Optional[Node] = None
     slot: Optional[int] = None
-    chip: Optional[Any] = None
     app_idx: int = 0  # which AppContext this rank runs
     state: ProcState = ProcState.INIT
     pid: Optional[int] = None

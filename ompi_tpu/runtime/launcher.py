@@ -96,8 +96,6 @@ class LocalLauncher:
         env[pmix.ENV_SIZE] = str(job.np)
         env[pmix.ENV_JOBID] = str(job.jobid)
         env[pmix.ENV_LOCAL_RANK] = str(proc.local_rank)
-        if proc.chip is not None:
-            env[pmix.ENV_CHIP] = str(proc.chip)
         if proc.lives:
             env["OMPI_TPU_RESTART"] = str(proc.lives)
         return env

@@ -77,7 +77,7 @@ class _StdinWriter:
 
 class _LocalJob:
     """One tenant's local state on this daemon: the launch spec, the
-    rows this daemon owns (rank → (local_rank, chip)), and the live
+    rows this daemon owns (rank → local_rank), and the live
     Popen/stdin handles.  A multi-tenant DVM runs several jobs at once,
     so everything that used to be daemon-global lives here, keyed by
     jobid.  The full spec is stored on EVERY daemon (the launch xcast
@@ -87,7 +87,7 @@ class _LocalJob:
     def __init__(self, jobid: int, spec: dict) -> None:
         self.jobid = jobid
         self.spec = spec
-        self.rows: dict[int, tuple[int, Optional[str]]] = {}
+        self.rows: dict[int, int] = {}
         self.popen: dict[int, subprocess.Popen] = {}
         self.stdin_writers: dict[int, _StdinWriter] = {}
 
@@ -333,7 +333,7 @@ class Orted:
     # -- odls: local launch ------------------------------------------------
 
     def _on_launch(self, origin: int, payload) -> None:
-        # payload: {"by_daemon": [(vpid, [(rank, local_rank, chip)...])...],
+        # payload: {"by_daemon": [(vpid, [(rank, local_rank)...])...],
         #           "argv", "env", "cwd", "stdin_rank"} — the whole map is
         # xcast once; each daemon picks its own rows (≈ the launch msg
         # grpcomm floods down the tree)
@@ -341,7 +341,7 @@ class Orted:
                          daemon=True).start()
 
     def _spawn_rank(self, lj: _LocalJob, rank: int, local_rank: int,
-                    chip, restarts: int = 0) -> None:
+                    restarts: int = 0) -> None:
         """Fork/exec one rank (first launch or TAG_RESPAWN revival)."""
         from ompi_tpu.core import pkg_root as _pkg_root
         from ompi_tpu.runtime.rtc import bind_child
@@ -356,8 +356,6 @@ class Orted:
                 root + (os.pathsep + pypath if pypath else ""))
         env[pmix.ENV_RANK] = str(rank)
         env[pmix.ENV_LOCAL_RANK] = str(local_rank)
-        if chip is not None:
-            env[pmix.ENV_CHIP] = str(chip)
         if self.fake_host:
             env["OMPI_TPU_FAKE_HOST"] = self.fake_host
         if restarts:
@@ -406,7 +404,7 @@ class Orted:
                 lj = self._jobs[jobid] = _LocalJob(jobid, spec)
             else:
                 lj.spec = spec
-            lj.rows = {r: (lr, ch) for r, lr, ch in mine}
+            lj.rows = dict(mine)
         # deterministic chaos, barrier-keyed: a plan entry
         # ``daemon=<vpid>:kill@reg=N`` arms a self-SIGKILL that fires
         # only once N ranks have registered with the job's PMIx server
@@ -415,8 +413,8 @@ class Orted:
         from ompi_tpu.testing import faultinject
 
         faultinject.arm_daemon_launch(self.vpid, spec.get("env") or {})
-        for rank, local_rank, chip in mine:
-            self._spawn_rank(lj, rank, local_rank, chip)
+        for rank, local_rank in mine:
+            self._spawn_rank(lj, rank, local_rank)
         # replay stdin that raced ahead of the launch xcast.  The replay
         # must happen under the lock that gates _launched: otherwise a
         # chunk arriving on the RML thread right after the flag flips
@@ -518,20 +516,17 @@ class Orted:
                 lj.rows.pop(rank, None)
                 lj.popen.pop(rank, None)
                 return
-            row = lj.rows.get(rank)
-            if row is None:
-                # adoption: keep the rank's original local_rank/chip —
-                # on a sim pool local_rank only feeds ENV/bind hints,
-                # and a real placement would remap chips at rejoin
-                row = (int(payload.get("local_rank") or 0),
-                       payload.get("chip"))
-                lj.rows[rank] = row
-        local_rank, chip = row
+            local_rank = lj.rows.get(rank)
+            if local_rank is None:
+                # adoption: keep the rank's original local_rank — on a
+                # sim pool it only feeds ENV/bind hints
+                local_rank = int(payload.get("local_rank") or 0)
+                lj.rows[rank] = local_rank
         _log.verbose(1, "orted %d: respawning rank %d (restart %d)",
                      self.vpid, rank, lives)
         # spawn off the RML reader thread (fork/exec + iof setup)
         threading.Thread(
-            target=self._spawn_rank, args=(lj, rank, local_rank, chip),
+            target=self._spawn_rank, args=(lj, rank, local_rank),
             kwargs={"restarts": lives}, daemon=True).start()
 
     def _on_stats(self, origin: int, payload) -> None:

@@ -370,9 +370,7 @@ class MultiHostLauncher:
         env.update(self._jax_coord_env(job))
         by_daemon = []
         for node in job.nodes:
-            rows = [(p.rank, p.local_rank,
-                     None if p.chip is None else str(p.chip))
-                    for p in job.procs_on(node)]
+            rows = [(p.rank, p.local_rank) for p in job.procs_on(node)]
             by_daemon.append((self._node_vpid(node), rows))
         stdin_rank = (self.stdin_target if self.stdin_target in ("all",)
                       else None if self.stdin_target == "none"
@@ -507,8 +505,7 @@ class MultiHostLauncher:
                 "jobid": job.jobid, "rank": proc.rank, "lives": proc.lives,
                 "target": (self._node_vpid(proc.node)
                            if proc.node is not None else 0),
-                "local_rank": proc.local_rank,
-                "chip": None if proc.chip is None else str(proc.chip)})
+                "local_rank": proc.local_rank})
         except Exception as e:  # noqa: BLE001 — tree may be tearing down
             _log.error("respawn xcast for rank %d failed: %r", proc.rank, e)
             return False

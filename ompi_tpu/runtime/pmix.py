@@ -52,7 +52,6 @@ ENV_RANK = "OMPI_TPU_RANK"
 ENV_SIZE = "OMPI_TPU_SIZE"
 ENV_JOBID = "OMPI_TPU_JOBID"
 ENV_LOCAL_RANK = "OMPI_TPU_LOCAL_RANK"
-ENV_CHIP = "OMPI_TPU_CHIP"
 
 
 class PMIxError(RuntimeError):

@@ -7,9 +7,10 @@
 - ``simulator`` — fabricates an arbitrary cluster from config vars, cloning
   orte/mca/ras/simulator/ras_sim_module.c:67-91 (ras_sim num_nodes /
   slots_per_node); lets mapping/binding logic be tested with no real machines.
-- ``tpu``      — discovers the local TPU slice via jax.devices() and exposes
-  one slot per chip, so ranks map 1:1 onto chips (the reference's
-  ras components ask SLURM/PBS; here the "scheduler" is the slice topology).
+- ``tpu``      — ``tpurun --tpu``: this host gets ONE slot.  A TPU chip belongs
+  to one process at a time, so one rank drives all the chips of its host
+  and the launcher itself never initialises a JAX backend (a launcher that
+  did would hold the chips its ranks need).
 - ``hostfile`` — parses a hostfile (``name slots=N`` lines), the reference's
   --hostfile path.
 """
@@ -64,8 +65,6 @@ class SimulatorRAS(Component):
                      "simulator: number of fake nodes")
         register_var("ras", "sim_slots_per_node", VarType.INT, 4,
                      "simulator: slots per fake node")
-        register_var("ras", "sim_chips_per_node", VarType.INT, 0,
-                     "simulator: fake TPU chips per node (0 = none)")
 
     def query(self, **ctx):
         return self.PRIORITY if ctx.get("allow_simulator", True) else None
@@ -73,42 +72,31 @@ class SimulatorRAS(Component):
     def allocate(self, job: Job, **ctx) -> list[Node]:
         n = var_registry.get("ras_sim_num_nodes")
         slots = var_registry.get("ras_sim_slots_per_node")
-        chips = var_registry.get("ras_sim_chips_per_node")
-        nodes = []
-        for i in range(n):
-            node = Node(name=f"sim{i:03d}", slots=slots)
-            if chips:
-                node.chips = [f"sim{i:03d}/chip{c}" for c in range(chips)]
-                node.topology = {"chips": chips, "cores": slots}
-            nodes.append(node)
-        return nodes
+        return [Node(name=f"sim{i:03d}", slots=slots) for i in range(n)]
 
 
 @ras_framework.component
 class TpuRAS(Component):
-    """One slot per local TPU chip: ranks map 1:1 onto chips."""
+    """``--tpu``: one slot on this host; its rank owns every local chip.
+
+    Nothing here imports jax: the process that first touches the TPU
+    runtime holds the chips, and that must be the rank, not the launcher.
+    """
 
     NAME = "tpu"
     PRIORITY = 50
 
     def query(self, **ctx):
-        if not ctx.get("want_tpu", False):
-            return None
-        try:
-            import jax
-
-            if any(d.platform == "tpu" for d in jax.devices()):
-                return self.PRIORITY
-        except Exception:
-            pass
-        return None
+        return self.PRIORITY if ctx.get("want_tpu", False) else None
 
     def allocate(self, job: Job, **ctx) -> list[Node]:
-        import jax
-
-        chips = [d for d in jax.devices() if d.platform == "tpu"]
-        node = Node(name=os.uname().nodename, slots=len(chips), chips=chips)
-        return [node]
+        if job.np > 1:
+            raise ValueError(
+                f"--tpu places one rank on this host (asked for {job.np}): "
+                f"a TPU chip belongs to one process at a time, so a second "
+                f"rank could not open the chips the first one holds.  Use "
+                f"-np 1 and let that rank drive all local chips.")
+        return [Node(name=os.uname().nodename, slots=1)]
 
 
 @ras_framework.component

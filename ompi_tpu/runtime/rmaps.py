@@ -1,7 +1,7 @@
-"""RMAPS — mapping ranks onto nodes/slots/chips.
+"""RMAPS — mapping ranks onto nodes/slots.
 
 ≈ orte/mca/rmaps (rmaps_base_map_job.c): given an allocation, place each rank
-on a node+slot, assign local ranks, and bind to chips where available.
+on a node+slot and assign local ranks.
 
 Components:
 - ``round_robin`` — by-slot (fill a node) or by-node (spread) placement, the
@@ -9,9 +9,9 @@ Components:
 - ``ppr``         — procs-per-resource: exactly N procs per node.
 - ``seq``         — rank i on node[i % len], one per step (reference's seq).
 
-Chip binding: if a node carries ``chips`` metadata, local rank r binds to
-chip r (device-per-rank — the TPU replacement for cpu binding in
-orte/mca/rmaps + rtc/hwloc).
+There is no chip binding: a TPU chip belongs to one process at a time and
+one rank drives all the chips of its host (ras ``tpu`` gives a host one
+slot), so a rank's devices are whatever ``jax.devices()`` shows it.
 """
 
 from __future__ import annotations
@@ -26,7 +26,7 @@ rmaps_framework = Framework("rmaps", "process mapping")
 
 
 def _finalize(job: Job) -> Job:
-    """Assign local ranks, app indices, and chip bindings after placement."""
+    """Assign local ranks and app indices after placement."""
     # app boundaries: ranks [0, np0) run app 0, [np0, np0+np1) app 1, ...
     bounds = []
     acc = 0
@@ -39,8 +39,6 @@ def _finalize(job: Job) -> Job:
         idx = per_node_count.get(proc.node.name, 0)
         proc.local_rank = idx
         per_node_count[proc.node.name] = idx + 1
-        if proc.node.chips:
-            proc.chip = proc.node.chips[idx % len(proc.node.chips)]
         for bound, app_i in bounds:
             if proc.rank < bound:
                 proc.app_idx = app_i

@@ -73,7 +73,7 @@ TAG_STDIN = "stdin"             # xcast: (target_rank, chunk | None=EOF)
 TAG_PROC_EXIT = "proc_exit"     # up: (jobid, rank, rc, errmsg)
 TAG_DAEMON_READY = "ready"      # up: daemon wired + children connected
 TAG_RESPAWN = "respawn"         # xcast: {jobid, rank, lives, target,
-#                                 local_rank, chip} — the daemon whose
+#                                 local_rank} — the daemon whose
 #                                 vpid == target adopts the row and
 #                                 revives the rank (migration: every
 #                                 daemon holds the job spec, so the

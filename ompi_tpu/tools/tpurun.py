@@ -29,7 +29,8 @@ def build_parser() -> argparse.ArgumentParser:
                    metavar=("PARAM", "VALUE"),
                    help="set a config variable (repeatable)")
     p.add_argument("--tpu", action="store_true",
-                   help="map ranks 1:1 onto local TPU chips")
+                   help="ranks drive TPU chips: one rank per host, which owns "
+                        "all of that host's chips")
     p.add_argument("--hostfile", default=None, help="hostfile path")
     p.add_argument("--map-by", default=None, choices=["byslot", "bynode"],
                    help="round-robin mapping policy")
@@ -258,7 +259,7 @@ def main(argv: list[str] | None = None) -> int:
         # --mca pairs were exported into os.environ above, so they ride
         # along.
         _skip = {_pmix.ENV_URI, _pmix.ENV_RANK, _pmix.ENV_SIZE,
-                 _pmix.ENV_JOBID, _pmix.ENV_LOCAL_RANK, _pmix.ENV_CHIP,
+                 _pmix.ENV_JOBID, _pmix.ENV_LOCAL_RANK,
                  "OMPI_TPU_RESTART", "OMPI_TPU_FAKE_HOST",
                  "PATH", "HOME", "TMPDIR", "TMP", "TEMP", "PWD",
                  "OLDPWD", "SHLVL", "HOSTNAME", "LD_LIBRARY_PATH",

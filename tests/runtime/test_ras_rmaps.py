@@ -19,11 +19,10 @@ def _reset_vars():
     var_registry.set("rmaps_rr_policy", "byslot")
 
 
-def sim(num_nodes, slots, chips=0):
+def sim(num_nodes, slots):
     var_registry.set("ras_", "simulator")
     var_registry.set("ras_sim_num_nodes", num_nodes)
     var_registry.set("ras_sim_slots_per_node", slots)
-    var_registry.set("ras_sim_chips_per_node", chips)
 
 
 def test_localhost_allocation():
@@ -60,13 +59,6 @@ def test_oversubscription_wraps():
     job = rmaps.map_job(ras.allocate(mkjob(6)))
     assert len(job.procs) == 6
     assert [p.rank for p in job.procs] == list(range(6))
-
-
-def test_chip_binding():
-    sim(2, 4, chips=4)
-    job = rmaps.map_job(ras.allocate(mkjob(8)))
-    assert job.procs[0].chip == "sim000/chip0"
-    assert job.procs[5].chip == "sim001/chip1"
 
 
 def test_ppr_mapping():

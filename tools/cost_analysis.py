@@ -7,7 +7,7 @@ step (MFU_SWEEP.jsonl) sits well above both bounds, the gap is
 scheduling/fusion, not physics; if the bytes bound dominates, the model
 is HBM-bound and the remat/fusion knobs are the lever.
 
-Usage:  python tools/cost_analysis.py [--cpu] [--small]
+Usage:  python tools/cost_analysis.py          (from the repo root, on a TPU)
 Appends a JSON line to MFU_SWEEP.jsonl (label "cost-analysis").
 """
 
@@ -18,45 +18,24 @@ import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-from bench import _enable_compile_cache  # noqa: E402
-
-_enable_compile_cache()
 OUT = os.path.join(REPO, "MFU_SWEEP.jsonl")
-
-HBM_BW = {"v5 lite": 819e9, "v5e": 819e9, "v5p": 2765e9,
-          "v4": 1228e9, "v6": 1638e9, "trillium": 1638e9}
 
 
 def main() -> None:
     t0 = time.time()
-    small = "--small" in sys.argv
-    if "--cpu" in sys.argv:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-        jax.config.update("jax_num_cpu_devices", 1)
-    import jax
     import numpy as np
 
-    from bench import _peak_flops
+    from bench import device_peaks, require_tpu
+    from ompi_tpu.core import enable_compile_cache
     from ompi_tpu.models import transformer as tfm
     from ompi_tpu.parallel.mesh import make_mesh
 
-    kind = jax.devices()[0].device_kind
-    mesh = make_mesh({"dp": 1, "sp": 1, "tp": 1},
-                     devices=jax.devices()[:1])
-    if small:
-        cfg = tfm.TransformerConfig(
-            vocab=1024, d_model=256, n_heads=4, n_layers=2, d_ff=1024,
-            seq=256, attention="xla", ce_chunk=64,
-            compute_dtype="bfloat16")
-        batch = 2
-    else:
-        cfg = tfm.TransformerConfig(
-            vocab=32_000, d_model=2048, n_heads=16, n_layers=8,
-            d_ff=8192, seq=1024, attention="xla", ce_chunk=256,
-            compute_dtype="bfloat16")
-        batch = 16
+    devices = require_tpu()
+    enable_compile_cache()
+    kind = devices[0].device_kind
+    peaks = device_peaks(kind)
+    mesh = make_mesh({"dp": 1, "sp": 1, "tp": 1}, devices=devices[:1])
+    cfg, batch = tfm.FLAGSHIP, tfm.FLAGSHIP_BATCH
     params = tfm.init_params(cfg)
     step, init_opt = tfm.make_train_step(cfg, mesh, lr=1e-3)
     opt_state = init_opt(params)
@@ -71,14 +50,13 @@ def main() -> None:
         ca = ca[0]
     flops = float(ca.get("flops", 0.0))
     bytes_acc = float(ca.get("bytes accessed", 0.0))
-    peak = _peak_flops(kind) or 0.0
-    bw = next((v for k, v in HBM_BW.items() if k in kind.lower()), 0.0)
+    peak, bw = peaks["bf16_flops"], peaks["hbm_bytes_per_s"]
     rec = {
         "label": "cost-analysis",
         "backend": kind, "batch": batch, "seq": cfg.seq,
         "xla_flops": flops, "xla_bytes_accessed": bytes_acc,
-        "flops_bound_ms": round(flops / peak * 1e3, 2) if peak else None,
-        "bytes_bound_ms": round(bytes_acc / bw * 1e3, 2) if bw else None,
+        "flops_bound_ms": round(flops / peak * 1e3, 2),
+        "bytes_bound_ms": round(bytes_acc / bw * 1e3, 2),
         "arith_intensity": round(flops / bytes_acc, 1) if bytes_acc
         else None,
         "wall_s": round(time.time() - t0, 1),

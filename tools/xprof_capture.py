@@ -20,6 +20,7 @@ the trace as summary.json).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import glob
 import json
 import os
@@ -131,21 +132,16 @@ def capture(out_dir: str, steps: int, small: bool) -> dict:
 
     kind = getattr(jax.devices()[0], "device_kind", "cpu")
     mesh = make_mesh({"dp": 1, "sp": 1, "tp": 1}, devices=jax.devices()[:1])
-    # the bench.py flagship config (or its CPU-smoke shrink)
-    base = dict(vocab=32_000, d_model=2048, n_heads=16, n_layers=8,
-                d_ff=8192, seq=1024, attention="xla", ce_chunk=256)
-    batch = 16
-    if small:
-        base.update(vocab=512, d_model=128, n_heads=8, n_layers=2,
-                    d_ff=256, seq=64, ce_chunk=0)
+    cfg, batch = tfm.FLAGSHIP, tfm.FLAGSHIP_BATCH
+    if small:    # the trace-reduction test's size, not a measurement
+        cfg = dataclasses.replace(cfg, vocab=512, d_model=128, n_heads=8,
+                                  n_layers=2, d_ff=256, seq=64, ce_chunk=0)
         batch = 2
-    cfg = tfm.TransformerConfig(**base, compute_dtype="bfloat16",
-                                remat="dots")
     params = tfm.init_params(cfg)
     step, init_opt = tfm.make_train_step(cfg, mesh, lr=1e-3)
     opt_state = init_opt(params)
     tokens = np.random.default_rng(0).integers(
-        0, base["vocab"], size=(batch, base["seq"])).astype(np.int32)
+        0, cfg.vocab, size=(batch, cfg.seq)).astype(np.int32)
 
     # warm outside the trace so compile time doesn't pollute it
     params, opt_state, loss = step(params, opt_state, tokens)
@@ -186,8 +182,9 @@ def main(argv=None) -> int:
                     help="force an N-device virtual CPU platform")
     args = ap.parse_args(argv)
 
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          os.path.join(REPO, ".jax_cache"))
+    from ompi_tpu.core import enable_compile_cache
+
+    enable_compile_cache()
     if args.cpu:
         import jax
 
