@@ -1,0 +1,89 @@
+"""From a configuration file to the program's own objects.
+
+The file's ``entry`` names the program's callables by dotted path and maps
+the published size keys onto the arguments of its configuration class, so a
+model of another family that keeps these call signatures is a new file and
+not new code.
+"""
+
+from __future__ import annotations
+
+import importlib
+
+
+def import_dotted(path: str):
+    module, _, attr = path.rpartition(".")
+    return getattr(importlib.import_module(module), attr)
+
+
+def tiny(config: dict) -> dict:
+    """The configuration at its ``tiny`` sizes: for tests on the CPU and for
+    learning the tree of an optimizer state, never for a measurement."""
+    return {**config, **config["tiny"]}
+
+
+def program_config(config: dict):
+    entry = config["entry"]
+    sizes = {arg: config[key] for arg, key in entry["sizes"].items()}
+    return import_dotted(entry["config"])(**sizes, **entry["options"])
+
+
+def reference(config: dict):
+    """The configuration's plain reference, ``reference/<name>.py``."""
+    return importlib.import_module("benchmarks.reference."
+                                   + config["reference"])
+
+
+def mesh(config: dict, devices):
+    from ompi_tpu.parallel.mesh import make_mesh
+
+    return make_mesh(dict(config["mesh"]), devices=list(devices))
+
+
+def param_shardings(config: dict, cfg, on_mesh) -> dict:
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    specs = import_dotted(config["entry"]["param_specs"])(P, cfg, on_mesh)
+    return {name: NamedSharding(on_mesh, spec) for name, spec in specs.items()}
+
+
+def param_table(config: dict) -> dict:
+    """Leaf name -> (shape, standard deviation or None), as the
+    configuration's reference lays the parameters out."""
+    ref = reference(config)
+    return ref.param_init(ref.Shape.from_config(config))
+
+
+def abstract_params(config: dict, shardings: dict) -> dict:
+    """The parameter tree as shapes, for compiling without arrays."""
+    import jax
+
+    return {name: jax.ShapeDtypeStruct(dims, config["param_dtype"],
+                                       sharding=shardings[name])
+            for name, (dims, _std) in param_table(config).items()}
+
+
+def init_params(config: dict, shardings: dict, seed: int) -> dict:
+    """Seeded random parameters made on the devices, already sharded, in one
+    jitted call and in the type they are stored in: no host array and no
+    transfer.  Leaf ``i`` in name order draws from ``fold_in(key(seed), i)``;
+    with JAX's partitionable threefry the values do not depend on the mesh."""
+    import jax
+    import jax.numpy as jnp
+
+    table = param_table(config)
+    dtype = jnp.dtype(config["param_dtype"])
+
+    def make(key):
+        out = {}
+        for i, (name, (dims, std)) in enumerate(sorted(table.items())):
+            if std is None:
+                out[name] = jnp.ones(dims, dtype)
+            else:
+                draw = jax.random.normal(jax.random.fold_in(key, i), dims,
+                                         jnp.float32)
+                out[name] = (draw * std).astype(dtype)
+        return out
+
+    return jax.jit(make, out_shardings=shardings)(jax.random.key(seed))
