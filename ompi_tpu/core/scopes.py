@@ -1,0 +1,62 @@
+"""The names inside the device programs.
+
+Every boundary the performance records talk about is a ``jax.named_scope``
+from the one vocabulary below, so that a profile of any program built on
+this package can be read by layer instead of by ``fusion.461``
+(``benchmarks/lib/scopes.py`` is the reader; ``PERF.md`` section 3 says
+which metric reads which name).  A scope is HLO metadata (``op_name``): it
+changes no computation and costs nothing when the program runs.  JAX's own
+name stack already tells forward (``jvp``), backward (``transpose``) and
+recomputation (``rematted_computation``) apart, so none of them is a scope.
+
+One caution: JAX's persistent compilation cache leaves metadata out of its
+key.  A program that differs from a cached one only in where its scopes sit
+is served the cached executable with the old names; use a fresh
+``JAX_COMPILATION_CACHE_DIR`` after moving a scope.
+"""
+
+from __future__ import annotations
+
+__all__ = ["SCOPES", "COLL", "scope", "coll"]
+
+# plain lower-case words; a dot says which scope a name belongs under
+SCOPES = (
+    "embed",            # token lookup
+    "layers",           # the lax.scan over layers, its slicing and stacking
+    "attn_proj",        # ln1, q/k/v projections, rope, the wo projection
+    "attention",        # scores, mask, softmax, context
+    "attention.ring",   # ... K/V blocks around the sp ring
+    "attention.ulysses",    # ... resharded seq -> heads by all_to_all
+    "attention.flash",  # ... the pallas kernels
+    "ffn",              # ln2, the MLP, the residual
+    "moe.route", "moe.dispatch", "moe.experts", "moe.combine",
+    "loss",             # the unembed matmul and the cross entropy
+    "optimizer",        # the optimizer's update and its application
+    "prefill",          # backbone over the prompt, cache padding, first logits
+    "decode.step",      # one cached token for the whole batch
+    "kv_cache",         # ... writing the new K/V into the cache
+    "unembed",          # ... the vocabulary matmul
+    "sample",           # ... picking the next token
+)
+
+# ``coll.<method>.<axes>``: one collective call site, named by the
+# DeviceCommunicator method (or its lax equivalent's method) and the mesh
+# axes it runs over, joined by "-"
+COLL = "coll"
+
+
+def scope(name: str):
+    """``with scope("attention"): ...`` around traced code."""
+    import jax
+
+    if name not in SCOPES:
+        raise ValueError(f"{name!r} is not in the scope vocabulary {SCOPES}")
+    return jax.named_scope(name)
+
+
+def coll(method: str, axes):
+    """``with coll("allreduce", "tp"): lax.psum(x, "tp")``."""
+    import jax
+
+    names = (axes,) if isinstance(axes, str) else tuple(axes)
+    return jax.named_scope(f"{COLL}.{method}.{'-'.join(names)}")

@@ -88,6 +88,12 @@ def prefetch(it: Iterator[np.ndarray], mesh=None, spec=None,
     ``P("dp", None)``), keeping up to ``depth`` batches in flight so
     the H2D transfer of step k+1 overlaps step k's compute.  Yields
     device arrays in order.
+
+    The iterator counts what it hands out: ``stats()`` returns
+    ``{"batches": n, "starved": n}``, ``starved`` being the ``next`` calls
+    that found the queue empty (the consumer then waits for the worker: the
+    host sets the pace).  In a profile each batch the worker makes is an
+    ``ompi_tpu:data.produce`` span.
     """
     import jax
 
@@ -117,10 +123,16 @@ def prefetch(it: Iterator[np.ndarray], mesh=None, spec=None,
 
     def worker() -> None:
         try:
-            for host_batch in it:
-                dev = (jax.device_put(host_batch, sharding)
-                       if sharding is not None
-                       else jax.device_put(host_batch))
+            source = iter(it)
+            while True:
+                with jax.profiler.TraceAnnotation("ompi_tpu:data.produce"):
+                    try:
+                        host_batch = next(source)
+                    except StopIteration:
+                        break
+                    dev = (jax.device_put(host_batch, sharding)
+                           if sharding is not None
+                           else jax.device_put(host_batch))
                 if not _put(dev):
                     return
             _put(_stop)
@@ -138,12 +150,16 @@ def prefetch(it: Iterator[np.ndarray], mesh=None, spec=None,
         even when called before the first ``next`` or via GC — a
         generator's finally never runs if it was never started."""
 
+        def __init__(self):
+            self.batches = self.starved = 0     # consumer thread only
+
         def __iter__(self):
             return self
 
         def __next__(self):
             if closed.is_set():
                 raise StopIteration
+            self.starved += q.empty()
             item = q.get()
             if item is _stop:
                 self.close()
@@ -151,7 +167,11 @@ def prefetch(it: Iterator[np.ndarray], mesh=None, spec=None,
             if isinstance(item, BaseException):
                 self.close()
                 raise item
+            self.batches += 1
             return item
+
+        def stats(self) -> dict:
+            return {"batches": self.batches, "starved": self.starved}
 
         def close(self, _empty=queue.Empty) -> None:
             # release the worker and drop any buffered device batches.

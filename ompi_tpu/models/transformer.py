@@ -209,14 +209,16 @@ def _moe_ffn_tail(cfg, h, lp, comm):
     """Post-attention half of the MoE layer: ln2 → ep-sharded switch →
     residual (shared by the training layer and the cached decode step —
     one source of truth, like _dense_ffn_tail).  Returns (h, aux)."""
+    from ompi_tpu.core.scopes import scope
     from ompi_tpu.parallel.moe import switch_moe
 
-    x = _rmsnorm(h, lp["ln2"])
-    mo, aux = switch_moe(
-        comm, x, {"wg": lp["wg"], "w1": lp["w1"], "w2": lp["w2"]},
-        axis="ep", capacity_factor=cfg.moe_capacity_factor,
-        with_aux=True)
-    return h + mo, aux
+    with scope("ffn"):
+        x = _rmsnorm(h, lp["ln2"])
+        mo, aux = switch_moe(
+            comm, x, {"wg": lp["wg"], "w1": lp["w1"], "w2": lp["w2"]},
+            axis="ep", capacity_factor=cfg.moe_capacity_factor,
+            with_aux=True)
+        return h + mo, aux
 
 
 def _dense_ffn_tail(h, lp, comm, cdt):
@@ -225,11 +227,13 @@ def _dense_ffn_tail(h, lp, comm, cdt):
     models/decode.py — one source of truth for this math)."""
     import jax
 
+    from ompi_tpu.core.scopes import scope
     from ompi_tpu.parallel.layers import column_parallel, row_parallel
 
-    x = _rmsnorm(h, lp["ln2"])
-    y = jax.nn.gelu(column_parallel(x, lp["w1"].astype(cdt)))
-    return h + row_parallel(y, lp["w2"].astype(cdt), comm, axis="tp")
+    with scope("ffn"):
+        x = _rmsnorm(h, lp["ln2"])
+        y = jax.nn.gelu(column_parallel(x, lp["w1"].astype(cdt)))
+        return h + row_parallel(y, lp["w2"].astype(cdt), comm, axis="tp")
 
 
 def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
@@ -247,6 +251,7 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     import jax.numpy as jnp
     from jax import lax
 
+    from ompi_tpu.core.scopes import scope
     from ompi_tpu.parallel import attention as attn_mod
     from ompi_tpu.parallel.layers import column_parallel, row_parallel
 
@@ -259,31 +264,35 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     sp_idx = lax.axis_index("sp")
     positions = sp_idx * T + jnp.arange(T)
 
-    h = params["emb"][tokens].astype(cdt)  # (b, t, D)
+    with scope("embed"):
+        h = params["emb"][tokens].astype(cdt)  # (b, t, D)
 
     def layer(h, lp):
-        x = _rmsnorm(h, lp["ln1"])
-        q = column_parallel(x, lp["wq"].astype(cdt))
-        k = column_parallel(x, lp["wk"].astype(cdt))
-        v = column_parallel(x, lp["wv"].astype(cdt))
-        B, t = x.shape[0], x.shape[1]
-        q = _rope(q.reshape(B, t, h_local, hd), positions)
-        k = _rope(k.reshape(B, t, h_local, hd), positions)
-        v = v.reshape(B, t, h_local, hd)
-        if cfg.attention == "ring":
-            o = attn_mod.ring_attention(comm, q, k, v, axis="sp")
-        elif cfg.attention == "ulysses":
-            o = attn_mod.ulysses_attention(comm, q, k, v, axis="sp")
-        elif cfg.attention == "flash":
-            o = attn_mod.ulysses_attention(comm, q, k, v, axis="sp",
-                                           impl="flash")
-        elif cfg.attention == "xla":
-            o = attn_mod.ulysses_attention(comm, q, k, v, axis="sp",
-                                           impl="jnp")
-        else:
-            o = attn_mod.gathered_attention(comm, q, k, v, axis="sp")
-        o = o.reshape(B, t, h_local * hd)
-        h = h + row_parallel(o, lp["wo"].astype(cdt), comm, axis="tp")
+        with scope("attn_proj"):
+            x = _rmsnorm(h, lp["ln1"])
+            q = column_parallel(x, lp["wq"].astype(cdt))
+            k = column_parallel(x, lp["wk"].astype(cdt))
+            v = column_parallel(x, lp["wv"].astype(cdt))
+            B, t = x.shape[0], x.shape[1]
+            q = _rope(q.reshape(B, t, h_local, hd), positions)
+            k = _rope(k.reshape(B, t, h_local, hd), positions)
+            v = v.reshape(B, t, h_local, hd)
+        with scope("attention"):
+            if cfg.attention == "ring":
+                o = attn_mod.ring_attention(comm, q, k, v, axis="sp")
+            elif cfg.attention == "ulysses":
+                o = attn_mod.ulysses_attention(comm, q, k, v, axis="sp")
+            elif cfg.attention == "flash":
+                o = attn_mod.ulysses_attention(comm, q, k, v, axis="sp",
+                                               impl="flash")
+            elif cfg.attention == "xla":
+                o = attn_mod.ulysses_attention(comm, q, k, v, axis="sp",
+                                               impl="jnp")
+            else:
+                o = attn_mod.gathered_attention(comm, q, k, v, axis="sp")
+        with scope("attn_proj"):
+            o = o.reshape(B, t, h_local * hd)
+            h = h + row_parallel(o, lp["wo"].astype(cdt), comm, axis="tp")
         if cfg.moe_experts:
             # MoE family: expert-parallel switch FFN over the "ep" axis
             # (tp ranks replicate the expert compute — activations are
@@ -307,7 +316,8 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
             layer, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
     else:
         layer_fn = layer
-    h, ys = lax.scan(layer_fn, h, layer_params)
+    with scope("layers"):
+        h, ys = lax.scan(layer_fn, h, layer_params)
     h = _rmsnorm(h, params["lnf"])
     if collect_kv:
         aux, ks, vs = ys
@@ -321,15 +331,19 @@ def _local_forward(cfg: TransformerConfig, comm, params, tokens):
     tokens: (B/dp, S/sp) int32.  Returns (logits (B/dp, S/sp, V) float32,
     aux) — aux is the summed MoE load-balancing loss (0.0 for dense).
     """
+    h, aux = _local_backbone(cfg, comm, params, tokens)
+    return _unembed(cfg, h, params["emb"]), aux
+
+
+def _unembed(cfg: TransformerConfig, h, emb):
+    """(B, T, D) -> (B, T, V) float32 logits: on the MXU in compute dtype
+    with f32 accumulation — a f32×f32 matmul here would run at a fraction
+    of the bf16 rate."""
     import jax.numpy as jnp
 
-    h, aux = _local_backbone(cfg, comm, params, tokens)
-    cdt = jnp.dtype(cfg.compute_dtype)
-    # unembed on the MXU in compute dtype, f32 accumulation — a f32×f32
-    # matmul here would run at a fraction of the bf16 rate
-    logits = jnp.einsum("btd,vd->btv", h, params["emb"].astype(cdt),
-                        preferred_element_type=jnp.float32)
-    return logits, aux
+    return jnp.einsum("btd,vd->btv", h,
+                      emb.astype(jnp.dtype(cfg.compute_dtype)),
+                      preferred_element_type=jnp.float32)
 
 
 def _chunked_nll_sum(cfg: TransformerConfig, h, emb, labels, weight):
@@ -373,6 +387,8 @@ def _local_loss(cfg: TransformerConfig, comm, params, tokens):
     import jax.numpy as jnp
     from jax import lax
 
+    from ompi_tpu.core.scopes import coll, scope
+
     sp = int(comm.mesh.shape["sp"])
     T = tokens.shape[1]
     sp_idx = lax.axis_index("sp")
@@ -384,37 +400,43 @@ def _local_loss(cfg: TransformerConfig, comm, params, tokens):
     else:
         # neighbor's first token: device r receives from r+1 (shift -1)
         perm = [((i + 1) % sp, i) for i in range(sp)]
-        from_right = lax.ppermute(first_col, "sp", perm)
+        with coll("permute", "sp"):
+            from_right = lax.ppermute(first_col, "sp", perm)
     labels = jnp.concatenate([tokens[:, 1:], from_right], axis=1)
     # the final global position has no next token
     positions = sp_idx * T + jnp.arange(T)
     weight = (positions < cfg.seq - 1).astype(jnp.float32)[None, :]
 
-    if cfg.ce_chunk and T % cfg.ce_chunk == 0:
-        h, aux = _local_backbone(cfg, comm, params, tokens)
-        B = tokens.shape[0]
-        local_sum = _chunked_nll_sum(
-            cfg, h, params["emb"], labels,
-            jnp.broadcast_to(weight, (B, T)))
-    else:
-        logits, aux = _local_forward(cfg, comm, params, tokens)
-        logprobs = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(
-            logprobs, labels[..., None], axis=-1)[..., 0]
-        local_sum = (nll * weight).sum()
+    h, aux = _local_backbone(cfg, comm, params, tokens)
+    with scope("loss"):
+        if cfg.ce_chunk and T % cfg.ce_chunk == 0:
+            B = tokens.shape[0]
+            local_sum = _chunked_nll_sum(
+                cfg, h, params["emb"], labels,
+                jnp.broadcast_to(weight, (B, T)))
+        else:
+            logprobs = jax.nn.log_softmax(_unembed(cfg, h, params["emb"]),
+                                          axis=-1)
+            nll = -jnp.take_along_axis(
+                logprobs, labels[..., None], axis=-1)[..., 0]
+            local_sum = (nll * weight).sum()
     local_cnt = weight.sum() * tokens.shape[0]
     dp = int(comm.mesh.shape["dp"])
     if dp * sp == 1:  # degenerate data/seq axes: psum is identity
         total, count = local_sum, local_cnt
     else:
-        total = lax.psum(local_sum, ("dp", "sp"))
-        count = lax.psum(local_cnt, ("dp", "sp"))
+        with coll("allreduce", ("dp", "sp")):
+            total = lax.psum(local_sum, ("dp", "sp"))
+            count = lax.psum(local_cnt, ("dp", "sp"))
     loss = total / count
     if cfg.moe_experts:
         # average the per-device balance loss over the whole mesh (tp/ep
         # ranks see replicated tokens, so the mean is layout-invariant)
-        aux_mean = (aux if comm.size == 1
-                    else lax.psum(aux, comm.axes)) / comm.size
+        if comm.size == 1:
+            aux_mean = aux
+        else:
+            with coll("allreduce", comm.axes):
+                aux_mean = lax.psum(aux, comm.axes) / comm.size
         loss = loss + cfg.moe_aux_weight * aux_mean
     return loss
 
@@ -466,6 +488,8 @@ def _make_step_body(cfg: TransformerConfig, mesh, lr: float):
 
     import jax.numpy as jnp
     from jax import lax
+
+    from ompi_tpu.core.scopes import scope
 
     loss_fn = make_loss_fn(cfg, mesh)
     opt = optax.adamw(lr, b1=0.9, b2=0.95, weight_decay=0.01,
@@ -531,8 +555,9 @@ def _make_step_body(cfg: TransformerConfig, mesh, lr: float):
     if store is None:
         def body(params, opt_state, tokens):
             loss, grads = loss_and_grads(params, tokens)
-            updates, opt_state = opt.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with scope("optimizer"):
+                updates, opt_state = opt.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, loss
 
         return body, opt
@@ -547,13 +572,14 @@ def _make_step_body(cfg: TransformerConfig, mesh, lr: float):
 
     def body(params, opt_state, tokens):
         loss, grads = loss_and_grads(params, tokens)
-        g32 = jax.tree_util.tree_map(
-            lambda g: g.astype(jnp.float32), grads)
-        updates, inner = opt.update(g32, opt_state["opt"],
-                                    opt_state["master"])
-        master = optax.apply_updates(opt_state["master"], updates)
-        params = jax.tree_util.tree_map(
-            lambda m: m.astype(store), master)
+        with scope("optimizer"):
+            g32 = jax.tree_util.tree_map(
+                lambda g: g.astype(jnp.float32), grads)
+            updates, inner = opt.update(g32, opt_state["opt"],
+                                        opt_state["master"])
+            master = optax.apply_updates(opt_state["master"], updates)
+            params = jax.tree_util.tree_map(
+                lambda m: m.astype(store), master)
         return params, {"opt": inner, "master": master}, loss
 
     class _MasterOpt:
@@ -595,10 +621,15 @@ def make_train_step(cfg: TransformerConfig, mesh, lr: float = 3e-4):
     import jax
 
     body, opt = _make_step_body(cfg, mesh, lr)
+
     # params/opt_state are donated: the updated trees reuse their HBM
-    # in place of a second full copy (≈1.6 GiB at 133M params with Adam)
-    step = functools.partial(jax.jit, donate_argnums=(0, 1))(body)
-    return step, _init_on_mesh(cfg, mesh, opt.init)
+    # in place of a second full copy (≈1.6 GiB at 133M params with Adam).
+    # The function's name is the program's name in a profile.
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def train_step(params, opt_state, tokens):
+        return body(params, opt_state, tokens)
+
+    return train_step, _init_on_mesh(cfg, mesh, opt.init)
 
 
 def make_train_loop(cfg: TransformerConfig, mesh, lr: float = 3e-4,
@@ -616,7 +647,7 @@ def make_train_loop(cfg: TransformerConfig, mesh, lr: float = 3e-4,
     body, opt = _make_step_body(cfg, mesh, lr)
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def run(params, opt_state, tokens):
+    def train_loop(params, opt_state, tokens):
         def scan_body(carry, _):
             p, s, loss = body(*carry, tokens)
             return (p, s), loss
@@ -625,4 +656,4 @@ def make_train_loop(cfg: TransformerConfig, mesh, lr: float = 3e-4,
             scan_body, (params, opt_state), None, length=steps)
         return params, opt_state, losses
 
-    return run, _init_on_mesh(cfg, mesh, opt.init)
+    return train_loop, _init_on_mesh(cfg, mesh, opt.init)

@@ -718,6 +718,44 @@ class DeviceCommunicator:
                 f"size={self.size})")
 
 
+# Every traced method runs under ``coll.<method>.<axes>`` (core/scopes.py),
+# so that a profile names each collective by the call that asked for it.
+# ``axes`` is the communicator's, or the one mesh axis the call names.
+_TRACED = ("allreduce", "reduce", "bcast", "reduce_scatter", "allgather",
+           "alltoall", "alltoall_stacked", "gather", "scatter", "scan",
+           "exscan", "allreduce_rs_ag", "allreduce_qint8",
+           "allreduce_segmented", "allgather_ring", "bcast_ring",
+           "allgatherv", "gatherv", "scatterv", "alltoallv", "barrier",
+           "shift", "permute", "sendrecv", "put", "get")
+# ... whose ``axis`` argument is a mesh axis (default: the last one)
+_TAKE_MESH_AXIS = ("alltoall_stacked", "shift", "permute", "sendrecv")
+
+
+def _scoped(method: str, fn: Callable) -> Callable:
+    import inspect
+
+    from ompi_tpu.core.scopes import coll
+
+    bind = (inspect.signature(fn).bind if method in _TAKE_MESH_AXIS
+            else None)
+
+    @functools.wraps(fn)
+    def traced(self, *args, **kw):
+        axes = self.axes
+        if bind:
+            named = bind(self, *args, **kw).arguments.get("axis")
+            axes = (named or self.axes[-1],)
+        with coll(method, axes):
+            return fn(self, *args, **kw)
+
+    return traced
+
+
+for _method in _TRACED:
+    setattr(DeviceCommunicator, _method,
+            _scoped(_method, getattr(DeviceCommunicator, _method)))
+
+
 def _my_block(comm: DeviceCommunicator, full, axis: int):
     """Slice this rank's equal block along `axis` (traced)."""
     from jax import lax

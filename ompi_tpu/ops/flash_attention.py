@@ -170,6 +170,7 @@ def _flash_fwd_raw(q3, k3, v3, q_offset, k_offset, scale: float,
             jax.ShapeDtypeStruct((bh, t_q, d), q3.dtype),
             jax.ShapeDtypeStruct((bh, nq, 8, block_q), jnp.float32),
         ],
+        name="flash_fwd",
     )(qoff, koff, q3, k3, v3)
     return o3, lse3[:, :, 0, :].reshape(bh, t_q)
 
@@ -316,6 +317,7 @@ def _flash_bwd_raw(q3, k3, v3, g3, lse3, dm3, qoff, koff, scale: float,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t_q, d), q3.dtype),
+        name="flash_bwd_dq",
     )(qoff, koff, q3, k3, v3, g3, lse_c, dm_c)
     dk3, dv3 = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -339,6 +341,7 @@ def _flash_bwd_raw(q3, k3, v3, g3, lse3, dm3, qoff, koff, scale: float,
             jax.ShapeDtypeStruct((bh, t_k, d), k3.dtype),
             jax.ShapeDtypeStruct((bh, t_k, d), v3.dtype),
         ],
+        name="flash_bwd_dkv",
     )(qoff, koff, q3, k3, v3, g3, lse_c, dm_c)
     return dq3, dk3, dv3
 

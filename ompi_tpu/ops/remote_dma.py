@@ -129,6 +129,7 @@ def window_put(win, value, src: int, dst: int, axis: str):
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
         input_output_aliases={1: 0},      # win -> out
         compiler_params=_params(src != dst),
+        name="remote_put",
     )(value, win)
 
 
@@ -186,6 +187,7 @@ def window_get(win, src: int, dst: int, axis: str):
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
         input_output_aliases={1: 0},      # local buf -> out
         compiler_params=_params(src != dst),
+        name="remote_get",
     )(win, win)
 
 
@@ -243,4 +245,5 @@ def fetch_bcast(x, root: int, n: int, axis: str):
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
         compiler_params=_params(True),
+        name="remote_bcast",
     )(x)

@@ -100,12 +100,14 @@ def local_attention_lse(q, k, v, causal: bool = True,
 
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     if resolve_impl(impl, q.shape[1], k.shape[1]) == "flash":
+        from ompi_tpu.core.scopes import scope
         from ompi_tpu.ops.flash_attention import flash_attention_lse
 
         bq, bk = _flash_blocks()
-        return flash_attention_lse(q, k, v, causal=causal,
-                                   q_offset=q_offset, k_offset=k_offset,
-                                   scale=scale, block_q=bq, block_k=bk)
+        with scope("attention.flash"):
+            return flash_attention_lse(q, k, v, causal=causal,
+                                       q_offset=q_offset, k_offset=k_offset,
+                                       scale=scale, block_q=bq, block_k=bk)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
@@ -142,6 +144,8 @@ def ring_attention(comm, q, k, v, axis: Optional[str] = None,
     import jax.numpy as jnp
     from jax import lax
 
+    from ompi_tpu.core.scopes import coll, scope
+
     ax = axis or comm.axes[-1]
     sp = int(comm.mesh.shape[ax])
     if sp == 1:  # degenerate ring: skip the loop machinery entirely
@@ -165,14 +169,16 @@ def ring_attention(comm, q, k, v, axis: Optional[str] = None,
         out = (out * c_old.transpose(0, 2, 1)[..., None]
                + o_i.astype(jnp.float32)
                * c_new.transpose(0, 2, 1)[..., None])
-        k_nxt = lax.ppermute(k_cur, ax, perm)
-        v_nxt = lax.ppermute(v_cur, ax, perm)
+        with coll("permute", ax):
+            k_nxt = lax.ppermute(k_cur, ax, perm)
+            v_nxt = lax.ppermute(v_cur, ax, perm)
         return (out, lse_new, k_nxt, v_nxt)
 
-    out0 = jnp.zeros((B, T, H, D), jnp.float32)
-    lse0 = jnp.full((B, H, T), _NEG, jnp.float32)
-    out, _, _, _ = lax.fori_loop(0, sp, step, (out0, lse0, k, v))
-    return out.astype(q.dtype)
+    with scope("attention.ring"):
+        out0 = jnp.zeros((B, T, H, D), jnp.float32)
+        lse0 = jnp.full((B, H, T), _NEG, jnp.float32)
+        out, _, _, _ = lax.fori_loop(0, sp, step, (out0, lse0, k, v))
+        return out.astype(q.dtype)
 
 
 def ulysses_attention(comm, q, k, v, axis: Optional[str] = None,
@@ -183,6 +189,8 @@ def ulysses_attention(comm, q, k, v, axis: Optional[str] = None,
     attention runs the pallas flash kernel with ``impl='flash'`` (static
     offsets by construction — the canonical place to use it)."""
     from jax import lax
+
+    from ompi_tpu.core.scopes import coll, scope
 
     ax = axis or comm.axes[-1]
     sp = int(comm.mesh.shape[ax])
@@ -195,12 +203,17 @@ def ulysses_attention(comm, q, k, v, axis: Optional[str] = None,
         # skip the resharding entirely
         return local_attention(q, k, v, causal=causal, scale=scale,
                                impl=impl)
-    # (B, T/sp, H, D) → (B, T, H/sp, D)
-    q2, k2, v2 = (lax.all_to_all(t, ax, split_axis=2, concat_axis=1,
-                                 tiled=True) for t in (q, k, v))
-    o = local_attention(q2, k2, v2, causal=causal, scale=scale, impl=impl)
-    # (B, T, H/sp, D) → (B, T/sp, H, D)
-    return lax.all_to_all(o, ax, split_axis=1, concat_axis=2, tiled=True)
+    with scope("attention.ulysses"):
+        # (B, T/sp, H, D) → (B, T, H/sp, D)
+        with coll("alltoall", ax):
+            q2, k2, v2 = [lax.all_to_all(t, ax, split_axis=2, concat_axis=1,
+                                         tiled=True) for t in (q, k, v)]
+        o = local_attention(q2, k2, v2, causal=causal, scale=scale,
+                            impl=impl)
+        # (B, T, H/sp, D) → (B, T/sp, H, D)
+        with coll("alltoall", ax):
+            return lax.all_to_all(o, ax, split_axis=1, concat_axis=2,
+                                  tiled=True)
 
 
 def gathered_attention(comm, q, k, v, axis: Optional[str] = None,
@@ -213,9 +226,12 @@ def gathered_attention(comm, q, k, v, axis: Optional[str] = None,
     ax = axis or comm.axes[-1]
     if int(comm.mesh.shape[ax]) == 1:
         return local_attention(q, k, v, causal=causal, scale=scale)
+    from ompi_tpu.core.scopes import coll
+
     my = lax.axis_index(ax)
     T = q.shape[1]
-    k_all = lax.all_gather(k, ax, axis=1, tiled=True)
-    v_all = lax.all_gather(v, ax, axis=1, tiled=True)
+    with coll("allgather", ax):
+        k_all = lax.all_gather(k, ax, axis=1, tiled=True)
+        v_all = lax.all_gather(v, ax, axis=1, tiled=True)
     return local_attention(q, k_all, v_all, causal=causal,
                            q_offset=my * T, k_offset=0, scale=scale)

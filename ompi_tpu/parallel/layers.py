@@ -27,8 +27,11 @@ def row_parallel(x_shard, w_shard, comm, axis: Optional[str] = None):
     import jax.numpy as jnp
     from jax import lax
 
+    from ompi_tpu.core.scopes import coll
+
     partial = jnp.einsum("...f,fd->...d", x_shard, w_shard)
     ax = axis or comm.axes[-1]
     if int(comm.mesh.shape[ax]) == 1:
         return partial  # degenerate tp: psum is identity, skip the channel op
-    return lax.psum(partial, ax)
+    with coll("allreduce", ax):
+        return lax.psum(partial, ax)

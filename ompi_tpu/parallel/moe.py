@@ -67,7 +67,8 @@ def switch_moe(comm, x, params, axis: str = "ep",
     """
     import jax
     import jax.numpy as jnp
-    from jax import lax
+
+    from ompi_tpu.core.scopes import scope
 
     B, T, D = x.shape
     if axis in comm.mesh.axis_names and axis not in comm.axes:
@@ -84,62 +85,65 @@ def switch_moe(comm, x, params, axis: str = "ep",
     C = capacity
 
     xf = x.reshape(n_tok, D)
-    logits = jnp.einsum("td,de->te", xf, params["wg"].astype(x.dtype),
-                        preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    expert = jnp.argmax(probs, axis=-1)                  # (n_tok,)
-    gate = jnp.take_along_axis(probs, expert[:, None], axis=1)[:, 0]
+    with scope("moe.route"):
+        logits = jnp.einsum("td,de->te", xf, params["wg"].astype(x.dtype),
+                            preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        expert = jnp.argmax(probs, axis=-1)                  # (n_tok,)
+        gate = jnp.take_along_axis(probs, expert[:, None], axis=1)[:, 0]
 
-    # position of each token within its expert's queue (0-based); tokens
-    # at position >= C are dropped
-    onehot = jax.nn.one_hot(expert, E, dtype=jnp.int32)  # (n_tok, E)
-    pos = jnp.cumsum(onehot, axis=0) * onehot            # 1-based in-slot
-    pos = pos.sum(axis=-1) - 1                           # (n_tok,)
-    keep = pos < C
+        # position of each token within its expert's queue (0-based);
+        # tokens at position >= C are dropped
+        onehot = jax.nn.one_hot(expert, E, dtype=jnp.int32)  # (n_tok, E)
+        pos = jnp.cumsum(onehot, axis=0) * onehot        # 1-based in-slot
+        pos = pos.sum(axis=-1) - 1                           # (n_tok,)
+        keep = pos < C
 
-    # dispatch tensor (n_tok, E, C): MXU-friendly one-hot outer product
-    dis = (onehot.astype(x.dtype)[:, :, None]
-           * jax.nn.one_hot(jnp.where(keep, pos, C), C + 1,
-                            dtype=x.dtype)[:, None, :-1]
-           )                                             # (n_tok, E, C)
-    send = jnp.einsum("tec,td->ecd", dis, xf)            # (E, C, D)
+    with scope("moe.dispatch"):
+        # dispatch tensor (n_tok, E, C): MXU-friendly one-hot outer product
+        dis = (onehot.astype(x.dtype)[:, :, None]
+               * jax.nn.one_hot(jnp.where(keep, pos, C), C + 1,
+                                dtype=x.dtype)[:, None, :-1]
+               )                                             # (n_tok, E, C)
+        send = jnp.einsum("tec,td->ecd", dis, xf)            # (E, C, D)
 
-    if ep > 1:
-        # (E, C, D) → every device ends with (E_local·ep, C, D): the
-        # blocks of ITS experts from every source device
-        send = comm.alltoall_stacked(send.reshape(ep, e_local, C, D),
-                                     axis=axis)
-        # (ep, e_local, C, D): source-device-major blocks of my experts
-        recv = send.reshape(ep, e_local, C, D)
-    else:
-        recv = send.reshape(1, e_local, C, D)
+        if ep > 1:
+            # (E, C, D) → every device ends with (E_local·ep, C, D): the
+            # blocks of ITS experts from every source device
+            send = comm.alltoall_stacked(send.reshape(ep, e_local, C, D),
+                                         axis=axis)
+            # (ep, e_local, C, D): source-device-major blocks of my experts
+            recv = send.reshape(ep, e_local, C, D)
+        else:
+            recv = send.reshape(1, e_local, C, D)
 
-    # expert FFN on my local experts (batched over source devices)
-    w1 = params["w1"].astype(x.dtype)                    # (e_local, D, F)
-    w2 = params["w2"].astype(x.dtype)                    # (e_local, F, D)
-    h = jnp.einsum("secd,edf->secf", recv, w1,
-                   preferred_element_type=jnp.float32).astype(x.dtype)
-    h = jax.nn.gelu(h)
-    out = jnp.einsum("secf,efd->secd", h, w2,
-                     preferred_element_type=jnp.float32).astype(x.dtype)
+    with scope("moe.experts"):
+        # expert FFN on my local experts (batched over source devices)
+        w1 = params["w1"].astype(x.dtype)                # (e_local, D, F)
+        w2 = params["w2"].astype(x.dtype)                # (e_local, F, D)
+        h = jnp.einsum("secd,edf->secf", recv, w1,
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+        h = jax.nn.gelu(h)
+        out = jnp.einsum("secf,efd->secd", h, w2,
+                         preferred_element_type=jnp.float32).astype(x.dtype)
 
-    if ep > 1:
-        # inverse exchange: give every source device back its tokens
-        out = comm.alltoall_stacked(out, axis=axis)
+    with scope("moe.combine"):
+        if ep > 1:
+            # inverse exchange: give every source device back its tokens
+            out = comm.alltoall_stacked(out, axis=axis)
         out = out.reshape(E, C, D)
-    else:
-        out = out.reshape(E, C, D)
 
-    # combine back to token positions, scaled by the gate prob; dropped
-    # tokens contribute zero (their residual path carries them)
-    y = jnp.einsum("tec,ecd->td", dis, out)
-    y = y * gate[:, None].astype(x.dtype)
-    y = y.reshape(B, T, D)
+        # combine back to token positions, scaled by the gate prob; dropped
+        # tokens contribute zero (their residual path carries them)
+        y = jnp.einsum("tec,ecd->td", dis, out)
+        y = y * gate[:, None].astype(x.dtype)
+        y = y.reshape(B, T, D)
     if not with_aux:
         return y
-    # switch load-balancing loss (Fedus et al.): differentiable through
-    # the mean gate prob; the routed fraction is the (stop-grad) signal
-    frac = jnp.mean(onehot.astype(jnp.float32), axis=0)      # (E,)
-    mean_p = jnp.mean(probs, axis=0)                         # (E,)
-    aux = E * jnp.sum(frac * mean_p)
+    with scope("moe.route"):
+        # switch load-balancing loss (Fedus et al.): differentiable through
+        # the mean gate prob; the routed fraction is the (stop-grad) signal
+        frac = jnp.mean(onehot.astype(jnp.float32), axis=0)      # (E,)
+        mean_p = jnp.mean(probs, axis=0)                         # (E,)
+        aux = E * jnp.sum(frac * mean_p)
     return y, aux

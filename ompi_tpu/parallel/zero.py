@@ -61,6 +61,8 @@ def zero1_wrap(opt, mesh, axis: str = "dp", param_dtype: Any = None,
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
+    from ompi_tpu.core.scopes import scope
+
     if axis not in mesh.shape:
         raise ValueError(
             f"zero1 axis {axis!r} is not a mesh axis "
@@ -84,6 +86,7 @@ def zero1_wrap(opt, mesh, axis: str = "dp", param_dtype: Any = None,
             if getattr(leaf, "ndim", 0) == 2 else leaf, inner)
         return {"opt": inner, "master": master}
 
+    @scope("optimizer")
     def update(grads, opt_state, params):
         del params  # the master copy is authoritative
         constrain = jax.lax.with_sharding_constraint

@@ -139,3 +139,34 @@ def test_prefetch_close_before_first_next_releases_worker():
 
     with pytest.raises(StopIteration):
         next(stream)
+
+
+def test_prefetch_counts_batches_and_starved_takes():
+    """``stats()``: a take that finds the queue empty is starved (the
+    consumer outran the worker); one that finds a batch waiting is not."""
+    import threading
+    import time
+
+    gate = threading.Event()
+
+    def slow_then_fast():
+        yield np.zeros((2, 4), np.int32)
+        gate.wait(timeout=10.0)         # the consumer has to wait here
+        for i in range(3):
+            yield np.full((2, 4), i, np.int32)
+
+    stream = data_mod.prefetch(slow_then_fast(), depth=4)
+    assert stream.stats() == {"batches": 0, "starved": 0}
+    deadline = time.time() + 5.0
+    while stream.stats()["batches"] == 0 and time.time() < deadline:
+        next(stream)
+    threading.Timer(0.2, gate.set).start()
+    next(stream)                        # the worker is held: queue empty
+    starved = stream.stats()["starved"]
+    assert starved >= 1 and stream.stats()["batches"] == 2
+    time.sleep(0.5)                     # the worker fills the queue
+    next(stream)
+    assert stream.stats() == {"batches": 3, "starved": starved}
+    next(stream)
+    stream.close()
+    assert stream.stats()["batches"] == 4
