@@ -22,7 +22,7 @@ __all__ = ["SCOPES", "COLL", "scope", "coll"]
 # plain lower-case words; a dot says which scope a name belongs under
 SCOPES = (
     "embed",            # token lookup
-    "layers",           # the lax.scan over layers, its slicing and stacking
+    "layers",           # the loop over layers, its slicing and stacking
     "attn_proj",        # ln1, q/k/v projections, rope, the wo projection
     "attention",        # scores, mask, softmax, context
     "attention.ring",   # ... K/V blocks around the sp ring

@@ -4,9 +4,22 @@ of the train step, built from the same layer math.
 TPU-first shape: ONE compiled program per (prompt_len, max_new) pair —
 prefill runs the training backbone once (``collect_kv`` returns every
 layer's post-rope K/V in a single pass), then a ``lax.scan`` generates
-tokens against a static-shape cache updated with
-``lax.dynamic_update_slice`` (no growing arrays, no recompilation per
-token).  Sharding: batch over dp, heads over tp (the cache is
+tokens against a static-shape cache (no growing arrays, no
+recompilation per token).
+
+The life of the cache: it is allocated once, stacked over layers at its
+final length ``(L, B, Tp+max_new, Hl, hd)``, and from then on it is loop
+carry — of the token scan and, inside it, of a ``lax.fori_loop`` over
+the layer index.  A layer writes its new K/V in place at the one
+position ``(l, 0, pos, 0, 0)`` of the whole stack and attention reads
+layer ``l`` through a slice that the compiler fuses into the scores and
+context products, so a step writes ``B·Hl·hd`` values a layer and reads
+the cache once.  The cache is never the ``xs`` or ``ys`` of a scan:
+those are separate buffers, and a step would then copy every layer's
+cache out of the stack and back (``tests/parallel/test_decode.py`` holds
+the compiled program to this).
+
+Sharding: batch over dp, heads over tp (the cache is
 head-sharded exactly like the weights); greedy argmax over the full
 vocab.  Sequence parallelism is a training-time layout — decode
 requires sp == 1.  MoE configs route each generated token through the
@@ -25,11 +38,12 @@ from ompi_tpu.models.transformer import (TransformerConfig,
 __all__ = ["make_decoder"]
 
 
-def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, pos):
-    """One layer for ONE new token position, updating this layer's cache.
+def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, layer, pos):
+    """Layer ``layer`` for ONE new token position, against the whole cache.
 
-    h: (B, 1, D); kc/vc: (B, Tmax, Hl, hd).  Returns (h, kc, vc) with
-    the new token's k/v written at index ``pos``.
+    h: (B, 1, D); kc/vc: the stacked cache (L, B, Tmax, Hl, hd); lp:
+    this layer's parameters.  Returns (h, kc, vc) with the new token's
+    k/v written in place at ``(layer, :, pos)``.
     """
     import jax
     import jax.numpy as jnp
@@ -40,7 +54,7 @@ def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, pos):
 
     cdt = h.dtype
     B = h.shape[0]
-    Tmax, hl, hd = kc.shape[1], kc.shape[2], kc.shape[3]
+    Tmax, hl, hd = kc.shape[2:]
 
     with scope("attn_proj"):
         x = _rmsnorm(h, lp["ln1"])
@@ -50,18 +64,20 @@ def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, pos):
         q = _rope(q, pos[None])
         k = _rope(k, pos[None])
     with scope("kv_cache"):
-        kc = lax.dynamic_update_slice(kc, k.astype(kc.dtype),
-                                      (0, pos, 0, 0))
-        vc = lax.dynamic_update_slice(vc, v.astype(vc.dtype),
-                                      (0, pos, 0, 0))
+        kc = lax.dynamic_update_slice(kc, k.astype(kc.dtype)[None],
+                                      (layer, 0, pos, 0, 0))
+        vc = lax.dynamic_update_slice(vc, v.astype(vc.dtype)[None],
+                                      (layer, 0, pos, 0, 0))
     with scope("attention"):
         # scores against every cached position, masked beyond `pos`
+        k_all = lax.dynamic_index_in_dim(kc, layer, keepdims=False)
+        v_all = lax.dynamic_index_in_dim(vc, layer, keepdims=False)
         s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                       kc.astype(jnp.float32)) * (hd ** -0.5)
+                       k_all.astype(jnp.float32)) * (hd ** -0.5)
         live = jnp.arange(Tmax)[None, None, None, :] <= pos
         s = jnp.where(live, s, -1e30)
         w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", w, vc.astype(jnp.float32))
+        o = jnp.einsum("bhqk,bkhd->bqhd", w, v_all.astype(jnp.float32))
     with scope("attn_proj"):
         o = o.astype(cdt).reshape(B, 1, hl * hd)
         h = h + row_parallel(o, lp["wo"].astype(cdt), comm, axis="tp")
@@ -157,15 +173,16 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
             with scope("embed"):
                 h = params["emb"][tok].astype(cdt)[:, None, :]  # (B, 1, D)
 
-            def per_layer(h, inp):
-                lp, kc_l, vc_l = inp
-                h, kc_l, vc_l = _step_layer(cfg, comm, lp, h,
-                                            kc_l, vc_l, pos)
-                return h, (kc_l, vc_l)
+            # the whole stacked cache is this loop's carry too; as a
+            # scan's xs and ys it would be sliced out and copied back
+            def per_layer(layer, state):
+                lp = {k: lax.dynamic_index_in_dim(w, layer, keepdims=False)
+                      for k, w in layer_params.items()}
+                return _step_layer(cfg, comm, lp, *state, layer, pos)
 
             with scope("layers"):
-                h, (kc, vc) = lax.scan(per_layer, h,
-                                       (layer_params, kc, vc))
+                h, kc, vc = lax.fori_loop(0, cfg.n_layers, per_layer,
+                                          (h, kc, vc))
             with scope("unembed"):
                 h = _rmsnorm(h, params["lnf"])
                 logits = jnp.einsum("bd,vd->bv", h[:, 0, :], emb,
@@ -177,8 +194,8 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
         # emit the PRODUCED token and scan max_new-1 steps: tok0 is
         # already known from prefill, so the last single-token pass is
         # not computed just to be thrown away
-        # (the scope is around the scan, not inside ``gen``, so that the
-        # copies XLA makes of the loop's carry are the step's as well)
+        # (the scope is around the scan, not inside ``gen``, so that a
+        # copy XLA makes of the loop's carry would be the step's as well)
         with scope("decode.step"):
             (_, _, _, _), toks = lax.scan(
                 gen, (kc, vc, tok0, jnp.int32(Tp)), None,
