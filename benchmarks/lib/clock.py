@@ -32,7 +32,7 @@ from __future__ import annotations
 
 from typing import Iterable, NamedTuple
 
-from benchmarks.lib import scopes, xplane
+from benchmarks.lib import xplane
 from benchmarks.lib.spans import TRACE_PREFIX
 from benchmarks.lib.xplane import Event
 
@@ -76,7 +76,7 @@ def estimate(events: Iterable[Event]) -> Lead | None:
     """``None`` where the trace has no job with a program run to bound the
     lead with, or where the two bounds cross (the spans are not around the
     device work they were taken for)."""
-    _ops, host, runs = scopes.split(events)
+    _ops, host, runs = xplane.split(events)
     jobs = _jobs(host)
     lower, upper = float("-inf"), float("inf")
     for plane_runs in runs.values():
@@ -105,8 +105,8 @@ def aligned_gaps(events: Iterable[Event], lead: Lead,
     program's own annotations included, to ``outside`` where none is, and
     to ``below_band`` where it is shorter than the band."""
     events = list(events)
-    per_device, host, _runs = scopes.split(events)
-    window = scopes.window_of(per_device, host)
+    per_device, host, _runs = xplane.split(events)
+    window = xplane.window_of(per_device, host)
     named = host + program_annotations(events)
     gap_ns: dict[str, float] = {}
     for ops in per_device.values():

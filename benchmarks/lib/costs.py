@@ -18,34 +18,39 @@ def tree_bytes(tree: dict) -> int:
     return sum(int(leaf.size) * leaf.dtype.itemsize for leaf in tree.values())
 
 
-def train_flops_per_token(n_params: int, n_layers: int, d_model: int,
+def train_flops_per_token(active_params: int, n_layers: int, d_model: int,
                           seq: int) -> int:
-    """Forward and backward of a dense decoder, PaLM's accounting: 6 per
-    parameter (a tied embedding counts once, as the output projection) and
-    12·L·D·S for attention's two T×T products, the causal half included.
-    Recomputation under ``jax.checkpoint`` is excluded."""
-    return 6 * n_params + 12 * n_layers * d_model * seq
+    """Forward and backward of a decoder, PaLM's accounting: 6 per parameter
+    a token multiplies (``counts(shape)["active_params"]`` of the
+    configuration's reference: in a dense model every parameter, a tied
+    embedding once, as the output projection; in a routed one the experts a
+    token is sent to) and 12·L·D·S for attention's two T×T products, the
+    causal half included.  Recomputation under ``jax.checkpoint`` is
+    excluded."""
+    return 6 * active_params + 12 * n_layers * d_model * seq
 
 
-def prefill_flops(n_params: int, vocab: int, n_layers: int, d_model: int,
-                  batch: int, prompt_len: int) -> int:
+def prefill_flops(active_params: int, projection_params: int, n_layers: int,
+                  d_model: int, batch: int, prompt_len: int) -> int:
     """Forward over ``batch`` prompts that ends in one token each: every
-    position passes the blocks (2 per block parameter, 4·L·D·T for
+    position passes the blocks (2 per active block parameter, 4·L·D·T for
     attention), and only the last position of each prompt is projected
-    onto the vocabulary."""
-    body = n_params - vocab * d_model
+    onto the vocabulary (``projection_params``: ``vocab x d_model``)."""
+    body = active_params - projection_params
     per_token = 2 * body + 4 * n_layers * d_model * prompt_len
-    return batch * prompt_len * per_token + batch * 2 * vocab * d_model
+    return batch * prompt_len * per_token + batch * 2 * projection_params
 
 
-def kv_bytes(n_layers: int, batch: int, positions: float, d_model: int,
+def kv_bytes(n_layers: int, batch: int, positions: float, kv_elements: int,
              itemsize: int) -> float:
-    """Keys and values of ``positions`` cached positions, all heads."""
-    return 2 * n_layers * batch * positions * d_model * itemsize
+    """Keys and values of ``positions`` cached positions; ``kv_elements`` is
+    what one position holds of both in one layer (``2 x d_model`` with as
+    many K/V heads as query heads)."""
+    return n_layers * batch * positions * kv_elements * itemsize
 
 
 def decode_step_bytes(param_bytes: int, n_layers: int, batch: int,
-                      prompt_len: int, max_new: int, d_model: int,
+                      prompt_len: int, max_new: int, kv_elements: int,
                       kv_itemsize: int) -> float:
     """Bytes one cached decode step must read: every parameter once at its
     stored type, and the live keys and values once at theirs.  The cache is
@@ -53,4 +58,5 @@ def decode_step_bytes(param_bytes: int, n_layers: int, batch: int,
     after the first token it holds ``prompt_len + max_new / 2`` positions on
     average."""
     live = prompt_len + max_new / 2
-    return param_bytes + kv_bytes(n_layers, batch, live, d_model, kv_itemsize)
+    return param_bytes + kv_bytes(n_layers, batch, live, kv_elements,
+                                  kv_itemsize)

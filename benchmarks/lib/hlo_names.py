@@ -35,15 +35,13 @@ from __future__ import annotations
 
 import bisect
 import collections
-import gzip
-import json
-from typing import Iterable, Iterator, NamedTuple
+from typing import Iterable, Iterator
 
 from benchmarks.lib import xplane
+from benchmarks.lib.xplane import Event
 
 METADATA_PLANE = "/host:metadata"
 HLO_PROTO_STAT = "Hlo Proto"
-MODULES_LINE = "XLA Modules"
 
 
 def _varint(buf: bytes, i: int) -> tuple[int, int]:
@@ -184,19 +182,6 @@ def program_op_names(pb_path: str) -> dict[str, dict[str, str]]:
             for name, proto in _embedded_programs(pb)}
 
 
-class ScopedEvent(NamedTuple):
-    """``xplane.Event`` and, for a device operation, its HLO ``op_name``.
-    ``xplane``'s reductions read events by field name and take these as they
-    take their own.  This and ``load_events`` below go once ``xplane.Event``
-    itself has the sixth field (``PERF.md``, open questions)."""
-    plane: str
-    line: str
-    name: str
-    start_ns: float
-    duration_ns: float
-    scope: str = ""
-
-
 def run_at(plane_runs: list[tuple[float, float, str]],
            t: float) -> tuple[float, float, str] | None:
     """The (start, end, name) of ``plane_runs``, sorted, that holds ``t``."""
@@ -204,15 +189,15 @@ def run_at(plane_runs: list[tuple[float, float, str]],
     return plane_runs[i - 1] if i and t < plane_runs[i - 1][1] else None
 
 
-def with_op_names(events: Iterable,
-                  programs: dict[str, dict[str, str]]) -> list[ScopedEvent]:
+def with_op_names(events: Iterable[Event],
+                  programs: dict[str, dict[str, str]]) -> list[Event]:
     """``events`` with ``scope`` filled on the device's operations: the
     ``op_name`` of the event's instruction in the program whose run, an
     event of the same plane's ``XLA Modules`` line, encloses it in time."""
-    events = [ScopedEvent(*e) for e in events]
+    events = list(events)
     runs: dict[str, list[tuple[float, float, str]]] = {}
     for e in events:
-        if e.line == MODULES_LINE and e.name in programs:
+        if e.line == xplane.MODULES_LINE and e.name in programs:
             runs.setdefault(e.plane, []).append(
                 (e.start_ns, e.start_ns + e.duration_ns, e.name))
     for plane_runs in runs.values():
@@ -226,16 +211,3 @@ def with_op_names(events: Iterable,
                     xplane.instruction(e.name), ""))
         out.append(e)
     return out
-
-
-def read_events(pb_path: str) -> list[ScopedEvent]:
-    """``xplane.read_events`` with the op_names the profile embeds."""
-    return with_op_names(xplane.read_events(pb_path),
-                         program_op_names(pb_path))
-
-
-def load_events(path: str) -> list[ScopedEvent]:
-    """What ``xplane.save_events`` wrote, of scoped events (six columns) or
-    of plain ones (five)."""
-    with gzip.open(path, "rt", encoding="utf-8") as f:
-        return [ScopedEvent(*row) for row in json.load(f)]

@@ -9,6 +9,9 @@ not new code.
 from __future__ import annotations
 
 import importlib
+import os
+
+from benchmarks.lib import cells
 
 
 def import_dotted(path: str):
@@ -28,10 +31,17 @@ def program_config(config: dict):
     return import_dotted(entry["config"])(**sizes, **entry["options"])
 
 
-def reference(config: dict):
-    """The configuration's plain reference, ``reference/<name>.py``."""
-    return importlib.import_module("benchmarks.reference."
-                                   + config["reference"])
+def reference(config: dict, bench_dir: str = cells.BENCH_DIR):
+    """The configuration's plain reference, ``reference/<name>.py`` under
+    the benchmark directory the cell was resolved in: found by file, as
+    runners and readers are, so a model of another family brings its own.
+
+    A reference module has ``Shape.from_config(config)`` (with ``vocab``,
+    ``d_model`` and ``n_layers``), ``param_init(shape)``, ``logits``,
+    ``loss``, ``token_deficits`` and ``counts(shape)``, which says what
+    ``lib/costs.py`` may count of this family's parameters."""
+    return cells.load_module(os.path.join(bench_dir, "reference",
+                                          config["reference"] + ".py"))
 
 
 def mesh(config: dict, devices):
@@ -48,23 +58,22 @@ def param_shardings(config: dict, cfg, on_mesh) -> dict:
     return {name: NamedSharding(on_mesh, spec) for name, spec in specs.items()}
 
 
-def param_table(config: dict) -> dict:
-    """Leaf name -> (shape, standard deviation or None), as the
-    configuration's reference lays the parameters out."""
-    ref = reference(config)
+def param_table(ref, config: dict) -> dict:
+    """Leaf name -> (shape, standard deviation or None), as the reference
+    ``ref`` lays out the parameters of ``config``."""
     return ref.param_init(ref.Shape.from_config(config))
 
 
-def abstract_params(config: dict, shardings: dict) -> dict:
+def abstract_params(ref, config: dict, shardings: dict) -> dict:
     """The parameter tree as shapes, for compiling without arrays."""
     import jax
 
     return {name: jax.ShapeDtypeStruct(dims, config["param_dtype"],
                                        sharding=shardings[name])
-            for name, (dims, _std) in param_table(config).items()}
+            for name, (dims, _std) in param_table(ref, config).items()}
 
 
-def init_params(config: dict, shardings: dict, seed: int) -> dict:
+def init_params(ref, config: dict, shardings: dict, seed: int) -> dict:
     """Seeded random parameters made on the devices, already sharded, in one
     jitted call and in the type they are stored in: no host array and no
     transfer.  Leaf ``i`` in name order draws from ``fold_in(key(seed), i)``;
@@ -72,7 +81,7 @@ def init_params(config: dict, shardings: dict, seed: int) -> dict:
     import jax
     import jax.numpy as jnp
 
-    table = param_table(config)
+    table = param_table(ref, config)
     dtype = jnp.dtype(config["param_dtype"])
 
     def make(key):

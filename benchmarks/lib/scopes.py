@@ -14,8 +14,9 @@ in which a scope is an element of its own, or sits inside the transform
 that was entered just before it (``jvp(loss)``, ``transpose(jvp(loss))``).
 ``classify`` reads one path; ``reduce_scopes`` adds up a trace's device time
 by what ``classify`` says.  Time is the union of intervals on each device,
-clipped to the window ``xplane.reduce_events`` uses, and the mean over the
-devices: the arithmetic of ``collective_s``.
+clipped to the window ``xplane.reduce_events`` uses (``xplane.split`` and
+``xplane.window_of`` are shared), and the mean over the devices: the
+arithmetic of ``collective_s``.
 
 The keys of the table (seconds, but for ``executions``):
 
@@ -65,10 +66,8 @@ import sys
 from typing import Iterable, NamedTuple
 
 from benchmarks.lib import hlo_names, xplane
-from benchmarks.lib.hlo_names import MODULES_LINE
-from benchmarks.lib.hlo_names import ScopedEvent as Event
 from benchmarks.lib.spans import TRACE_PREFIX
-from benchmarks.lib.xplane import Interval
+from benchmarks.lib.xplane import Event, Interval
 
 # ompi_tpu.core.scopes.SCOPES, letter for letter
 VOCABULARY = (
@@ -144,35 +143,6 @@ def coll_site(where: Where) -> str:
     return "other"
 
 
-def split(events: Iterable[Event]):
-    """(device plane -> its operations in time order, the benchmark's host
-    spans, device plane -> its program runs): the selection and the window
-    rule of ``xplane.reduce_events``."""
-    per_device: dict[str, list[Event]] = {}
-    runs: dict[str, list[Event]] = {}
-    host: list[Event] = []
-    for e in events:
-        if xplane.DEVICE_PLANE.match(e.plane):
-            if e.line == xplane.OPS_LINE:
-                if (xplane.opcode(e.name) not in xplane.ENVELOPES
-                        and e.duration_ns > 0):
-                    per_device.setdefault(e.plane, []).append(e)
-            elif e.line == MODULES_LINE:
-                runs.setdefault(e.plane, []).append(e)
-        elif e.plane == xplane.HOST_PLANE and e.name.startswith(TRACE_PREFIX):
-            host.append(e)
-    for ops in list(per_device.values()) + list(runs.values()):
-        ops.sort(key=lambda e: e.start_ns)
-    return per_device, host, runs
-
-
-def window_of(per_device: dict[str, list[Event]],
-              host: list[Event]) -> Interval:
-    bounds = host or [e for ops in per_device.values() for e in ops]
-    return (min(e.start_ns for e in bounds),
-            max(e.start_ns + e.duration_ns for e in bounds))
-
-
 def _mostly_in(run: Event, intervals: list[Interval]) -> bool:
     """More than half of the run lies in the (disjoint) intervals: the
     clocks differ by a millisecond or two (clock.py), so a run may begin
@@ -186,11 +156,11 @@ def reduce_scopes(events: Iterable[Event], window: Interval | None = None,
                   span: str | None = None) -> dict[str, float] | None:
     """``None`` where no operation ran on a device plane.  With ``span``,
     of the program runs mostly under a host span of that name alone."""
-    per_device, host, runs = split(events)
+    per_device, host, runs = xplane.split(events)
     if not per_device:
         return None
     if window is None:
-        window = window_of(per_device, host)
+        window = xplane.window_of(per_device, host)
     if span is not None:
         under = [(h.start_ns, h.start_ns + h.duration_ns) for h in host
                  if h.name == TRACE_PREFIX + span]
@@ -237,14 +207,24 @@ def seconds(table: dict[str, float] | None,
     return sum(found) if found else None
 
 
+def warn_missing(name: str, keys: Iterable[str], where: str = "") -> None:
+    """Says on stderr that the metric ``name`` found no time under ``keys``,
+    and what to suspect."""
+    print(f"{name}: no device time under {', '.join(keys)}{where}. First "
+          f"suspect: a stale executable from the compilation cache, whose "
+          f"key leaves scope names out (try an empty "
+          f"JAX_COMPILATION_CACHE_DIR); then a scope that moved.",
+          file=sys.stderr)
+
+
 def main(argv: list[str]) -> int:
     if len(argv) != 1:
         print("usage: python3 -m benchmarks.lib.scopes "
               "<trace.xplane.pb | events.json.gz>", file=sys.stderr)
         return 2
     path = argv[0]
-    events = (hlo_names.load_events(path) if path.endswith(".json.gz")
-              else hlo_names.read_events(path))
+    events = (xplane.load_events(path) if path.endswith(".json.gz")
+              else xplane.read_events(path))
     summary = xplane.reduce_events(events)
     table = reduce_scopes(events)
     if table is None:

@@ -3,8 +3,10 @@
 Two stages, so that each can be checked apart:
 
 1. ``read_events``: ``.xplane.pb`` -> ``Event`` tuples (plane, line, name,
-   start, duration; nanoseconds on the profiler's clock).  ``save_events`` /
-   ``load_events`` keep such a list as gzip JSON.
+   start, duration; nanoseconds on the profiler's clock; and, of a device
+   operation, the HLO ``op_name`` that ``hlo_names`` finds for it in the
+   programs the profile embeds).  ``save_events`` / ``load_events`` keep
+   such a list as gzip JSON.
 2. ``reduce_events``: events -> ``TraceSummary``.
 
 What a TPU trace looks like, as read by hand from traces recorded on a v5e
@@ -42,6 +44,7 @@ from benchmarks.lib.spans import TRACE_PREFIX
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"    # one event per run of a program
 HOST_PLANE = "/host:CPU"
 ENVELOPES = ("while", "conditional", "call")
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
@@ -56,6 +59,7 @@ class Event(NamedTuple):
     name: str
     start_ns: float
     duration_ns: float
+    scope: str = ""     # a device operation's op_name, where one was found
 
 
 # --------------------------------------------------------------------------
@@ -67,11 +71,15 @@ def read_events(pb_path: str) -> list[Event]:
     renamed; ``device_and_span_events`` picks what stage 2 reads."""
     import jax.profiler
 
+    from benchmarks.lib import hlo_names    # imports this module
+
     data = jax.profiler.ProfileData.from_file(pb_path)
-    return [Event(plane.name, line.name, ev.name or "", float(ev.start_ns),
-                  float(ev.duration_ns or 0.0))
-            for plane in data.planes for line in plane.lines
-            for ev in line.events]
+    events = [Event(plane.name, line.name, ev.name or "", float(ev.start_ns),
+                    float(ev.duration_ns or 0.0))
+              for plane in data.planes for line in plane.lines
+              for ev in line.events]
+    return hlo_names.with_op_names(events,
+                                   hlo_names.program_op_names(pb_path))
 
 
 def device_and_span_events(events: Iterable[Event]) -> list[Event]:
@@ -88,6 +96,8 @@ def save_events(events: Iterable[Event], path: str) -> None:
 
 
 def load_events(path: str) -> list[Event]:
+    """What ``save_events`` wrote: six columns, or the five of a recording
+    from before events had a scope."""
     with gzip.open(path, "rt", encoding="utf-8") as f:
         return [Event(*row) for row in json.load(f)]
 
@@ -246,33 +256,51 @@ class TraceSummary:
         return 1.0 - self.busy_s / self.window_s
 
 
+def split(events: Iterable[Event]):
+    """(device plane -> its operations in time order, the benchmark's host
+    spans, device plane -> its program runs): what every reduction reads.
+    Envelopes and events without a duration are left out."""
+    per_device: dict[str, list[Event]] = {}
+    runs: dict[str, list[Event]] = {}
+    host: list[Event] = []
+    for e in events:
+        if DEVICE_PLANE.match(e.plane):
+            if e.line == OPS_LINE:
+                if opcode(e.name) not in ENVELOPES and e.duration_ns > 0:
+                    per_device.setdefault(e.plane, []).append(e)
+            elif e.line == MODULES_LINE:
+                runs.setdefault(e.plane, []).append(e)
+        elif e.plane == HOST_PLANE and e.name.startswith(TRACE_PREFIX):
+            host.append(e)
+    for ops in list(per_device.values()) + list(runs.values()):
+        ops.sort(key=lambda e: e.start_ns)
+    return per_device, host, runs
+
+
+def window_of(per_device: dict[str, list[Event]],
+              host: list[Event]) -> Interval:
+    """The extent of the benchmark's host spans, which enclose whole samples
+    of the job; without any, the extent of the device's operations."""
+    bounds = host or [e for ops in per_device.values() for e in ops]
+    return (min(e.start_ns for e in bounds),
+            max(e.start_ns + e.duration_ns for e in bounds))
+
+
 def reduce_events(events: Iterable[Event], top: int = 10) -> TraceSummary | None:
     """``None`` where no operation ran on a device plane.
 
-    The window is the extent of the benchmark's host spans, which enclose
-    whole samples of the job; without any it is the extent of the device
-    operations.  An idle gap goes to the innermost host span that is open at
-    its middle, and to ``outside`` where none is.
+    The window is ``window_of``.  An idle gap goes to the innermost host
+    span that is open at its middle, and to ``outside`` where none is.
     """
-    per_device: dict[str, list[Event]] = {}
-    host: list[Event] = []
-    for e in events:
-        if DEVICE_PLANE.match(e.plane) and e.line == OPS_LINE:
-            if opcode(e.name) not in ENVELOPES and e.duration_ns > 0:
-                per_device.setdefault(e.plane, []).append(e)
-        elif e.plane == HOST_PLANE and e.name.startswith(TRACE_PREFIX):
-            host.append(e)
+    per_device, host, _runs = split(events)
     if not per_device:
         return None
-    bounds = host or [e for ops in per_device.values() for e in ops]
-    window = (min(e.start_ns for e in bounds),
-              max(e.start_ns + e.duration_ns for e in bounds))
+    window = window_of(per_device, host)
 
     busy = coll = exposed = 0.0
     op_ns: dict[str, float] = {}
     gap_ns: dict[str, float] = {}
     for ops in per_device.values():
-        ops.sort(key=lambda e: e.start_ns)
         others = union(clip(((e.start_ns, e.start_ns + e.duration_ns)
                              for e in ops
                              if collective_kind(e.name) is None), window))

@@ -67,6 +67,20 @@ def param_shapes(shape: Shape) -> dict[str, tuple[int, ...]]:
     return {name: dims for name, (dims, _std) in param_init(shape).items()}
 
 
+def counts(shape: Shape) -> dict[str, int]:
+    """What ``lib/costs.py`` counts of this family.  ``active_params``: the
+    parameters one token multiplies, which are the blocks' and the output
+    projection (a lookup table that is not also the projection would count
+    nothing); here every parameter, the tied embedding once, as the
+    projection.  ``projection_params``: those of the output projection
+    alone.  ``kv_elements``: the elements of one position's keys and values
+    in one layer."""
+    every = sum(math.prod(dims) for dims in param_shapes(shape).values())
+    return {"active_params": every,
+            "projection_params": shape.vocab * shape.d_model,
+            "kv_elements": 2 * shape.d_model}
+
+
 def _rmsnorm(x, scale):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
                              + 1e-6) * scale

@@ -13,7 +13,12 @@ change to this repo moves and which would bury one that adds a second to the
 rest.  It is reported beside it, under ``setup``.  Then samples of the
 cell's job are taken for ``--seconds`` seconds.  With ``--trace 1`` a few more samples
 run under ``jax.profiler`` and the line carries the cell's per-layer metrics
-instead of its end-to-end ones.
+instead of its end-to-end ones; ``breakdown`` then has the longest device
+operations and idle gaps, the scope table of ``lib/scopes.py``
+(``device_scopes``: device seconds by pass, ``jax.named_scope`` and collective
+site) and the device clock's lead with the gaps named after it
+(``lib/clock.py``).  ``--dump DIR`` keeps the traced events, scopes and
+all; ``python3 -m benchmarks.lib.scopes <file>`` reads them again.
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` (and ``breakdown`` when traced); the
@@ -125,11 +130,20 @@ def measure(cell: cells.Cell, devices, meter, spans: Spans, seed: int,
 
     durations = spans.durations(start, end)
     fullest = max((peak_bytes(d) or 0 for d in devices)) or None
-    events = xplane.device_and_span_events(traced)
-    summary = xplane.reduce_events(events) if trace else None
+    events, summary, table, by_clock = [], None, None, {}
+    if trace:
+        # the reduction's modules are a traced run's: set-up, which counts
+        # from process start, imports none of them
+        from benchmarks.lib import clock, scopes
+
+        events = (xplane.device_and_span_events(traced)
+                  + clock.program_annotations(traced))
+        summary = xplane.reduce_events(events)
+        table = scopes.reduce_scopes(events)
+        by_clock = clock.breakdown(events)
     run = RunData(durations=durations, facts=facts, peaks=peaks,
                   trace=summary, compiles_in_window=compiles,
-                  peak_bytes=fullest)
+                  peak_bytes=fullest, scopes=table, events=events)
 
     if trace:
         rows = [(row, reader.read(run)) for row, reader in cell.per_layer]
@@ -151,7 +165,8 @@ def measure(cell: cells.Cell, devices, meter, spans: Spans, seed: int,
         result["device"].update(busy_s=summary.busy_s,
                                 window_s=summary.window_s)
         result["breakdown"] = {"device_ops": summary.device_ops,
-                               "idle_gaps": summary.idle_gaps}
+                               "idle_gaps": summary.idle_gaps,
+                               "device_scopes": table, **by_clock}
     result.update(
         checks=outcome["checks"], setup=setup,
         spans={name: {"n": len(v), "median_s": statistics.median(v)}

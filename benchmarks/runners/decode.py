@@ -17,11 +17,16 @@ plus continuation lies within ``DEFICIT_TOL`` of that position's maximum.
 
 from __future__ import annotations
 
+import os
 import statistics
 
 import numpy as np
 
 from benchmarks.lib import costs, program
+
+# the benchmark directory this runner was found in: its configurations'
+# references are found there too
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # In standard deviations of a position's reference logits.  The decoder
 # multiplies in bfloat16 and keeps its cache in bfloat16; where it picks
@@ -44,7 +49,7 @@ class Job:
         self.devices = list(devices)
         self.mesh = program.mesh(config, self.devices)
         self.cfg = program.program_config(config)
-        self.reference = program.reference(config)
+        self.reference = program.reference(config, BENCH_DIR)
         self.shape = self.reference.Shape.from_config(config)
         self.shardings = program.param_shardings(config, self.cfg, self.mesh)
         make_decoder = program.import_dotted(config["entry"]["decoder"])
@@ -64,7 +69,8 @@ class Job:
         the real sizes for devices that are described and not attached."""
         import jax
 
-        params = program.abstract_params(self.config, self.shardings)
+        params = program.abstract_params(self.reference, self.config,
+                                         self.shardings)
         prompt = jax.ShapeDtypeStruct((self.batch, self.prompt_len), np.int32,
                                       sharding=self._prompt_sharding())
         return {"decode_first": (jax.jit(self.first), (params, prompt)),
@@ -78,7 +84,8 @@ class Job:
         self.spans = spans
         with spans.span("setup.params"):
             self.params = jax.block_until_ready(
-                program.init_params(self.config, self.shardings, seed))
+                program.init_params(self.reference, self.config,
+                                    self.shardings, seed))
             self.n_params = costs.tree_count(self.params)
             prompts = np.random.default_rng(seed).integers(
                 0, self.shape.vocab, size=(self.batch, self.prompt_len))
@@ -128,17 +135,18 @@ class Job:
         import jax.numpy as jnp
 
         shape = self.shape
+        counts = self.reference.counts(shape)
         return {
             "chips": len(self.devices),
             "n_params": self.n_params,
             "batch": self.batch, "max_new": self.max_new,
             "prompt_len": self.prompt_len,
             "prefill_flops": costs.prefill_flops(
-                self.n_params, shape.vocab, shape.n_layers, shape.d_model,
-                self.batch, self.prompt_len),
+                counts["active_params"], counts["projection_params"],
+                shape.n_layers, shape.d_model, self.batch, self.prompt_len),
             "decode_step_bytes": costs.decode_step_bytes(
                 costs.tree_bytes(self.params), shape.n_layers, self.batch,
-                self.prompt_len, self.max_new, shape.d_model,
+                self.prompt_len, self.max_new, counts["kv_elements"],
                 jnp.dtype(self.config["kv_cache_dtype"]).itemsize),
         }
 

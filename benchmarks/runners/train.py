@@ -13,11 +13,16 @@ loss is finite; the last is below the first.
 from __future__ import annotations
 
 import math
+import os
 import statistics
 
 import numpy as np
 
 from benchmarks.lib import costs, program
+
+# the benchmark directory this runner was found in: its configurations'
+# references are found there too
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # The program multiplies in bfloat16 (8 bits of mantissa, float32
 # accumulation) and the reference in float32 at "highest".  At the initial
@@ -53,7 +58,7 @@ class Job:
         self.devices = list(devices)
         self.mesh = program.mesh(config, self.devices)
         self.cfg = program.program_config(config)
-        self.reference = program.reference(config)
+        self.reference = program.reference(config, BENCH_DIR)
         self.shape = self.reference.Shape.from_config(config)
         self.shardings = program.param_shardings(config, self.cfg, self.mesh)
         make_step = program.import_dotted(config["entry"]["train_step"])
@@ -72,7 +77,8 @@ class Job:
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
-        params = program.abstract_params(self.config, self.shardings)
+        params = program.abstract_params(self.reference, self.config,
+                                         self.shardings)
         tokens = jax.ShapeDtypeStruct(
             (self.batch, self.seq), np.int32,
             sharding=NamedSharding(self.mesh, P("dp", None)))
@@ -101,7 +107,8 @@ class Job:
                             lr=self.traffic["lr"])
         small_params = {
             name: np.zeros(dims, self.config["param_dtype"])
-            for name, (dims, _std) in program.param_table(small).items()}
+            for name, (dims, _std) in program.param_table(self.reference,
+                                                          small).items()}
 
         def size_up(path, leaf):
             names = [p.key for p in path
@@ -130,7 +137,8 @@ class Job:
         self.spans = spans
         with spans.span("setup.params"):
             params = jax.block_until_ready(
-                program.init_params(self.config, self.shardings, seed))
+                program.init_params(self.reference, self.config,
+                                    self.shardings, seed))
             self.n_params = costs.tree_count(params)
             self.stream = data.train_stream(
                 data.ArraySource(corpus(seed, self.shape.vocab), seed=seed),
@@ -150,6 +158,7 @@ class Job:
                                                 next(self.stream))
             jax.block_until_ready(loss)
         self.params, self.opt_state = params, opt_state
+        self.stream_warm = self.stream.stats()
 
     def _reference_loss(self, params: dict, tokens) -> float:
         import jax
@@ -180,13 +189,18 @@ class Job:
     # ---- the results -----------------------------------------------------
 
     def facts(self) -> dict:
+        counts = self.reference.counts(self.shape)
+        stream = self.stream.stats()
         return {
             "chips": len(self.devices),
             "n_params": self.n_params,
             "tokens_per_sample": self.batch * self.seq,
             "flops_per_token": costs.train_flops_per_token(
-                self.n_params, self.shape.n_layers, self.shape.d_model,
-                self.seq),
+                counts["active_params"], self.shape.n_layers,
+                self.shape.d_model, self.seq),
+            # the input stream's own counters since warm-up
+            "stream": {key: value - self.stream_warm[key]
+                       for key, value in stream.items()},
         }
 
     def end_to_end(self, durations: dict[str, list[float]]) -> dict:

@@ -1,5 +1,6 @@
 """The harness is driven by data: every name in BENCHMARK.json resolves to
-files, a fourth cell is added by adding files, and run.py has no CPU mode.
+files, a fourth cell and a model of another family are added by adding
+files, and run.py has no CPU mode.
 
 Everything here runs on the CPU at the configurations' ``tiny`` sizes: it
 checks resolution, control flow and the shape of the result line, never a
@@ -19,8 +20,9 @@ import jax
 import pytest
 
 from benchmarks import run as bench_run
-from benchmarks.lib import cells, program
+from benchmarks.lib import cells, program, scopes, xplane
 from benchmarks.lib.compile_meter import CompileMeter
+from benchmarks.lib.rundata import RunData
 from benchmarks.lib.spans import Spans
 
 ROOT = os.path.dirname(cells.BENCH_DIR)
@@ -60,10 +62,33 @@ def tiny(cell: cells.Cell) -> cells.Cell:
     return cell
 
 
-def measure(cell, meter, trace):
+def measure(cell, meter, trace, dump=None):
     return bench_run.measure(cell, jax.devices()[:cell.chips], meter, Spans(),
                              seed=3, seconds=0.3, trace=trace,
-                             peaks=MADE_UP_PEAKS, t0=time.perf_counter())
+                             peaks=MADE_UP_PEAKS, t0=time.perf_counter(),
+                             dump=dump)
+
+
+def digest(top) -> dict[str, str]:
+    """relative path -> sha256 of every file under ``top``."""
+    out = {}
+    for folder, _dirs, files in os.walk(top):
+        for f in files:
+            if f.endswith(".pyc"):
+                continue
+            path = os.path.join(folder, f)
+            with open(path, "rb") as fh:
+                out[os.path.relpath(path, top)] = hashlib.sha256(
+                    fh.read()).hexdigest()
+    return out
+
+
+def copied_benchmark(tmp_path) -> tuple[str, dict]:
+    """The benchmark's directory copied under ``tmp_path``, and its digest."""
+    bench_dir = str(tmp_path / "benchmarks")
+    shutil.copytree(cells.BENCH_DIR, bench_dir,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    return bench_dir, digest(bench_dir)
 
 
 @pytest.mark.parametrize("workload", WORKLOADS)
@@ -122,13 +147,23 @@ def test_names_and_shape_of_the_benchmark_file():
 def test_harness_names_no_cell_configuration_runner_or_metric():
     """Whatever belongs to one cell sits in its own file: run.py and lib/
     find it by the name BENCHMARK.json gives and know none themselves
-    (``setup_s`` is the contract's own name)."""
+    (``setup_s`` is the contract's own name).  A runner, a shared reader or
+    a reference has a common word for a name, so it is looked for as code
+    would spell it: quoted, or as a module of its package."""
     names = {x["name"] for key in ("configs", "workloads", "end_to_end",
                                    "per_layer") for x in BENCH[key]}
     names |= {w["traffic"] for w in BENCH["workloads"]}
     names.discard("setup_s")
-    runners = {cells.resolve(w).traffic["runner"] for w in WORKLOADS}
-    quoted = {q + r + q for r in runners for q in "'\""}
+    resolved = [cells.resolve(w) for w in WORKLOADS]
+    files = {"runners": {c.traffic["runner"] for c in resolved},
+             "reference": {c.config["reference"] for c in resolved},
+             "readers": {reader.spec["reader"] for c in resolved
+                         for _row, reader in c.per_layer
+                         if hasattr(reader, "spec")}}
+    assert all(files.values()), files
+    quoted = {spelt for package, stems in files.items() for stem in stems
+              for spelt in (f"'{stem}'", f'"{stem}"', f"{package}.{stem}",
+                            f"{package}/{stem}", f"import {stem}")}
     sources = [os.path.join(cells.BENCH_DIR, "run.py")]
     lib = os.path.join(cells.BENCH_DIR, "lib")
     sources += [os.path.join(lib, f) for f in os.listdir(lib)
@@ -150,6 +185,23 @@ def test_run_py_has_no_cpu_mode():
     assert proc.returncode != 0
     assert "no CPU mode" in proc.stderr
     assert "metrics" not in proc.stdout and "{" not in proc.stdout
+
+
+def test_set_up_imports_none_of_the_traced_runs_reduction():
+    """``setup_s`` counts from process start, and an untraced run reads no
+    trace: resolving every cell, its runner, reference and readers, leaves
+    the modules of the scope reduction unimported."""
+    code = ("import sys; import benchmarks.run; "
+            "from benchmarks.lib import cells, program; "
+            f"found = [cells.resolve(w) for w in {WORKLOADS!r}]; "
+            "[program.reference(c.config) for c in found]; "
+            "late = {'scopes', 'clock', 'hlo_names'}; "
+            "print(sorted(m for m in sys.modules "
+            "if m.rpartition('.')[2] in late and m.startswith('benchmarks')))")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.strip() == "[]"
 
 
 @pytest.mark.parametrize("workload", WORKLOADS)
@@ -181,22 +233,7 @@ def test_a_fourth_cell_is_added_without_editing_a_file(tmp_path, meter):
     """A copied configuration with another depth, a copied traffic file, one
     new metric file and one more row each: the new cell resolves and runs,
     and every file the benchmark had is byte for byte what it was."""
-    def digest(top):
-        out = {}
-        for folder, _dirs, files in os.walk(top):
-            for f in files:
-                if f.endswith(".pyc"):
-                    continue
-                path = os.path.join(folder, f)
-                with open(path, "rb") as fh:
-                    out[os.path.relpath(path, top)] = hashlib.sha256(
-                        fh.read()).hexdigest()
-        return out
-
-    bench_dir = str(tmp_path / "benchmarks")
-    shutil.copytree(cells.BENCH_DIR, bench_dir,
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    before = digest(bench_dir)
+    bench_dir, before = copied_benchmark(tmp_path)
 
     base = next(w for w in BENCH["workloads"] if w["chips"] == 1)
     config = cells.resolve(base["name"]).config
@@ -238,3 +275,98 @@ def test_a_fourth_cell_is_added_without_editing_a_file(tmp_path, meter):
     assert set(after) - set(before) == {"configs/added-config.json",
                                         "traffic/added-mix.json",
                                         "metrics/added_metric.py"}
+
+
+OTHER_COUNTS = {"active_params": 1000 + 35, "projection_params": 35,
+                "kv_elements": 6}
+
+
+def test_a_model_of_another_family_is_added_without_editing_a_file(
+        tmp_path, meter):
+    """Another reference (the equations of the first configuration's under
+    another name, with counts of its own: a routed model with an untied
+    head and grouped K/V heads would have such), a configuration that names
+    it, a cell on the decode mix, and a share that a shared reader reads
+    from keys of its own, as data alone: the cell resolves and runs tiny and
+    traced, its facts follow the new reference's counts, the share reads a
+    recorded trace, and every file the benchmark had is byte for byte what
+    it was."""
+    bench_dir, before = copied_benchmark(tmp_path)
+
+    base = next(w for w in BENCH["workloads"] if w["chips"] == 1
+                and "prompt_len" in cells.resolve(w["name"]).traffic)
+    config = cells.resolve(base["name"]).config
+    with open(os.path.join(bench_dir, "reference",
+                           config["reference"] + ".py")) as f:
+        equations = f.read()
+    with open(os.path.join(bench_dir, "reference", "other.py"), "w") as f:
+        f.write(equations + f"\n\ndef counts(shape):\n"
+                            f"    return {OTHER_COUNTS!r}\n")
+    config = {**config, "name": "other-family", "reference": "other"}
+    with open(os.path.join(bench_dir, "configs", "other-family.json"),
+              "w") as f:
+        json.dump(config, f)
+    keys = ["scope/unembed@decode.step", "scope/ffn@decode.step"]
+    with open(os.path.join(bench_dir, "metrics", "other_weights_share.json"),
+              "w") as f:
+        json.dump({"reader": "scope_share", "keys": keys}, f)
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append({**BENCH["configs"][0], "name": "other-family",
+                             "file": "benchmarks/configs/other-family.json"})
+    bench["workloads"].append({"name": "other-cell", "config": "other-family",
+                               "traffic": base["traffic"], "chips": 1,
+                               "why": "dry addition"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if base["name"] in m.get("workloads", []):
+            m["workloads"].append("other-cell")
+    moved = next(m["name"] for m in BENCH["end_to_end"]
+                 if base["name"] in m.get("workloads", []))
+    bench["per_layer"].append({"name": "other_weights_share", "unit": "%",
+                               "better": "lower", "source": "device_trace",
+                               "layer": "decoder", "moves": moved,
+                               "workloads": ["other-cell"]})
+    with open(tmp_path / "BENCHMARK.json", "w") as f:
+        json.dump(bench, f)
+
+    cell = tiny(cells.resolve("other-cell", bench_dir))
+    assert cell.config["reference"] == "other"
+    # the runner is the copy's and finds the reference beside it: the
+    # benchmark this test was started from has no such file
+    assert cell.runner.__file__.startswith(bench_dir)
+    assert not os.path.exists(os.path.join(cells.BENCH_DIR, "reference",
+                                           "other.py"))
+    dump = str(tmp_path / "dump")
+    traced = measure(cell, meter, trace=True, dump=dump)
+    assert traced["correct"] is True, traced["checks"]
+    assert "other_weights_share" not in traced["metrics"]  # no device plane here
+    assert traced["metrics"]["decode_step_ms"]["value"] > 0
+    with open(os.path.join(dump, "other-cell.seed3.trace1.json")) as f:
+        facts = json.load(f)["facts"]
+    t, small = cell.traffic, cell.config
+    L, D = small["num_hidden_layers"], small["hidden_size"]
+    assert facts["prefill_flops"] == (
+        t["batch"] * t["prompt_len"] * (2 * 1000 + 4 * L * D * t["prompt_len"])
+        + t["batch"] * 2 * 35)
+    param_bytes = facts["n_params"] * 4
+    assert facts["decode_step_bytes"] == param_bytes + (
+        L * t["batch"] * (t["prompt_len"] + t["max_new"] / 2) * 6 * 2)
+
+    # the share, on the events the chip recorded of the base cell
+    reader = dict((r["name"], rd) for r, rd in cell.per_layer)
+    events = xplane.load_events(os.path.join(
+        os.path.dirname(xplane.__file__), "testdata", "scoped",
+        base["name"] + ".events.json.gz"))
+    table = scopes.reduce_scopes(events)
+    run = RunData(durations={}, facts={}, peaks=None,
+                  trace=xplane.reduce_events(events), compiles_in_window=0,
+                  peak_bytes=None, scopes=table, events=events)
+    share = reader["other_weights_share"].read(run)
+    assert share == pytest.approx(
+        100 * (table[keys[0]] + table[keys[1]]) / run.trace.window_s)
+    assert 0 < share < reader["decode_attention_share"].read(run) < 100
+
+    after = digest(bench_dir)
+    assert {k: after[k] for k in before} == before
+    assert set(after) - set(before) == {"reference/other.py",
+                                        "configs/other-family.json",
+                                        "metrics/other_weights_share.json"}

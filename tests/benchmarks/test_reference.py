@@ -1,111 +1,212 @@
-"""The yardstick against the program at a tiny size, and its arithmetic
-against hand counts.  CPU only: agreement and counts, no device metric."""
+"""Every configuration's yardstick against the program at the configuration's
+tiny sizes, and the yardstick's arithmetic against hand counts.  No
+reference is named here: each configuration of ``BENCHMARK.json`` is held to
+the one its file names (``program.reference``), so a model of another family
+gets these tests by being added.  CPU only: agreement and counts, no device
+metric."""
 
-import dataclasses
+import copy
+import math
 
 import jax
 import numpy as np
 import pytest
 
 from benchmarks.lib import cells, costs, program
-from benchmarks.reference import dense
-from ompi_tpu.models import transformer as tfm
-from ompi_tpu.models.decode import make_decoder
-from ompi_tpu.parallel.mesh import make_mesh
 
+BENCH = cells.load_benchmark()
 CONFIGS = {c["name"]: cells.load_json(f"{cells.BENCH_DIR}/../{c['file']}")
-           for c in cells.load_benchmark()["configs"]}
+           for c in BENCH["configs"]}
+
+_built: dict[str, tuple] = {}
 
 
-@pytest.fixture(scope="module")
-def tiny():
-    """(configuration dict at tiny sizes, the program's config in float32,
-    a one-device mesh, parameters from the benchmark's initializer)."""
-    config = program.tiny(CONFIGS["pythia-1.4b-widths"])
-    cfg = dataclasses.replace(program.program_config(config),
-                              compute_dtype="float32")
-    mesh = make_mesh({"dp": 1, "sp": 1, "tp": 1}, devices=jax.devices()[:1])
-    params = program.init_params(
-        config, program.param_shardings(config, cfg, mesh), seed=5)
-    return config, cfg, mesh, params
+def tiny(name: str):
+    """(reference module, configuration at tiny sizes, the program's config
+    in float32, the configuration's mesh of CPU devices, parameters from the
+    benchmark's initializer), made once a configuration."""
+    if name not in _built:
+        config = copy.deepcopy(program.tiny(CONFIGS[name]))
+        # float32 on both sides: what is left is the order of summation
+        config["entry"]["options"]["compute_dtype"] = "float32"
+        ref = program.reference(config)
+        cfg = program.program_config(config)
+        mesh = program.mesh(config, jax.devices()[:config["chips"]])
+        params = program.init_params(
+            ref, config, program.param_shardings(config, cfg, mesh), seed=5)
+        _built[name] = ref, config, cfg, mesh, params
+    return _built[name]
 
 
 @pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_param_shapes_equal_the_programs(name):
     """Catches drift between the program's tree and the yardstick's."""
     config = program.tiny(CONFIGS[name])
-    ours = dense.param_shapes(dense.Shape.from_config(config))
+    ref = program.reference(config)
+    ours = {k: dims for k, (dims, _std) in
+            program.param_table(ref, config).items()}
+    init = program.import_dotted(config["entry"]["init_params"])
     theirs = {k: v.shape for k, v in
-              tfm.init_params(program.program_config(config)).items()}
+              init(program.program_config(config)).items()}
     assert ours == theirs
 
 
-def test_initializer_is_seeded_and_scaled(tiny):
-    config, cfg, mesh, params = tiny
+@pytest.mark.parametrize("name", sorted(CONFIGS))
+def test_initializer_is_seeded_and_scaled(name):
+    ref, config, cfg, mesh, params = tiny(name)
     shardings = program.param_shardings(config, cfg, mesh)
-    again = program.init_params(config, shardings, seed=5)
-    other = program.init_params(config, shardings, seed=6)
+    again = program.init_params(ref, config, shardings, seed=5)
+    other = program.init_params(ref, config, shardings, seed=6)
     assert all(np.array_equal(params[k], again[k]) for k in params)
-    assert not np.array_equal(params["wq"], other["wq"])
-    table = dense.param_init(dense.Shape.from_config(config))
-    for name, (dims, std) in table.items():
-        assert params[name].shape == dims
-        assert params[name].dtype == np.float32
+    drawn = [k for k, (_dims, std) in
+             program.param_table(ref, config).items() if std is not None]
+    assert drawn and not any(np.array_equal(params[k], other[k])
+                             for k in drawn)
+    for leaf, (dims, std) in program.param_table(ref, config).items():
+        assert params[leaf].shape == dims
+        assert params[leaf].dtype == np.dtype(config["param_dtype"])
         if std is None:
-            assert (np.asarray(params[name]) == 1).all()
+            assert (np.asarray(params[leaf]) == 1).all()
         else:
-            assert np.asarray(params[name]).std() == pytest.approx(std,
+            assert np.asarray(params[leaf]).std() == pytest.approx(std,
                                                                    rel=0.1)
 
 
-def test_reference_loss_equals_make_loss_fn_in_float32(tiny):
-    config, cfg, mesh, params = tiny
+@pytest.mark.parametrize("name", sorted(CONFIGS))
+def test_reference_loss_equals_the_programs_in_float32(name):
+    ref, config, cfg, mesh, params = tiny(name)
+    shape = ref.Shape.from_config(config)
+    seq = config["max_position_embeddings"]
     tokens = np.random.default_rng(0).integers(
-        0, cfg.vocab, size=(4, cfg.seq)).astype(np.int32)
-    theirs = float(jax.jit(tfm.make_loss_fn(cfg, mesh))(params, tokens))
-    shape = dense.Shape.from_config(config)
+        0, shape.vocab, size=(4, seq)).astype(np.int32)
+    make_loss_fn = program.import_dotted(config["entry"]["loss_fn"])
+    theirs = float(jax.jit(make_loss_fn(cfg, mesh))(params, tokens))
     for block in (1, 4):
-        ours = dense.loss(shape, params, tokens, block=block)
-        # both float32: what is left is the order of summation
+        ours = ref.loss(shape, params, tokens, block=block)
         assert ours == pytest.approx(theirs, rel=1e-5)
 
 
-def test_decode_check_accepts_the_decoder_and_rejects_a_swapped_token(tiny):
-    config, cfg, mesh, params = tiny
-    shape = dense.Shape.from_config(config)
+@pytest.mark.parametrize("name", sorted(CONFIGS))
+def test_decode_check_accepts_the_decoder_and_rejects_a_swapped_token(name):
+    ref, config, cfg, mesh, params = tiny(name)
+    shape = ref.Shape.from_config(config)
     prompt_len, max_new = 12, 8
     prompts = np.random.default_rng(1).integers(
-        0, cfg.vocab, size=(2, prompt_len)).astype(np.int32)
+        0, shape.vocab, size=(2, prompt_len)).astype(np.int32)
+    make_decoder = program.import_dotted(config["entry"]["decoder"])
     answer = np.asarray(make_decoder(cfg, mesh, max_new=max_new)(params,
                                                                  prompts))
-    deficits = np.asarray(dense.token_deficits(shape, params, answer,
-                                               prompt_len))
+    deficits = np.asarray(ref.token_deficits(shape, params, answer,
+                                             prompt_len))
     assert deficits.shape == (2, max_new)
     assert deficits.max() < 1e-3    # float32 on both sides: the same argmax
 
-    logits = np.asarray(dense.logits(shape, params, answer))
+    logits = np.asarray(ref.logits(shape, params, answer))
     at = prompt_len + 3                             # scored at position at-1
     swapped = answer.copy()
     swapped[0, at] = logits[0, at - 1].argmin()
-    bad = np.asarray(dense.token_deficits(shape, params, swapped, prompt_len))
+    bad = np.asarray(ref.token_deficits(shape, params, swapped, prompt_len))
     assert bad[0, 3] > 1.0
 
 
-def test_train_flops_per_token_is_the_hand_count():
-    shape = dense.Shape.from_config(CONFIGS["pythia-1.4b-widths"])
-    assert shape.n_layers == 6
-    n_params = sum(int(np.prod(d)) for d in dense.param_shapes(shape).values())
-    assert n_params == 405_039_104
-    assert (costs.train_flops_per_token(n_params, 6, 2048, 2048)
-            == 6 * 405_039_104 + 12 * 6 * 2048 * 2048)
+@pytest.mark.parametrize("name", sorted(CONFIGS))
+def test_counts_are_of_the_references_own_tree(name):
+    """``counts(shape)`` at the real sizes: no more active parameters than
+    the tree holds, a projection among them, and K and V of one position."""
+    config = CONFIGS[name]
+    ref = program.reference(config)
+    shape = ref.Shape.from_config(config)
+    stored = sum(math.prod(dims) for dims, _std in
+                 program.param_table(ref, config).values())
+    counts = ref.counts(shape)
+    assert set(counts) == {"active_params", "projection_params",
+                           "kv_elements"}
+    assert all(isinstance(v, int) and v > 0 for v in counts.values())
+    assert counts["projection_params"] == shape.vocab * shape.d_model
+    assert counts["projection_params"] < counts["active_params"] <= stored
 
 
-def test_prefill_flops_is_the_hand_count():
+# ---- the arithmetic, against counts made by hand ---------------------------
+
+# What ``facts()`` returned for the two Pythia configurations before a
+# reference had ``counts`` (the parent of the PR that added them): every
+# parameter active, the tied embedding the projection, K and V as wide as
+# the model.  ``train_mfu``, ``prefill_mfu`` and ``decode_hbm_share`` are
+# these integers over a time.
+HAND_COUNTS = {
+    "pythia-1.4b-widths": dict(
+        n_params=405_039_104,           # 6 layers of 50.3M and 103M embedding
+        flops_per_token=6 * 405_039_104 + 12 * 6 * 2048 * 2048,
+        prefill_flops=48 * 1024 * (2 * (405_039_104 - 50304 * 2048)
+                                   + 4 * 6 * 2048 * 1024)
+        + 48 * 2 * 50304 * 2048,
+        decode_step_bytes=4 * 405_039_104
+        + 2 * 6 * 48 * (1024 + 64) * 2048 * 2),
+    "pythia-6.9b-widths": dict(
+        n_params=1_011_912_704,         # 4 layers of 201.3M and 206.6M embedding
+        flops_per_token=6 * 1_011_912_704 + 12 * 4 * 4096 * 2048,
+        prefill_flops=48 * 1024 * (2 * (1_011_912_704 - 50432 * 4096)
+                                   + 4 * 4 * 4096 * 1024)
+        + 48 * 2 * 50432 * 4096,
+        decode_step_bytes=4 * 1_011_912_704
+        + 2 * 4 * 48 * (1024 + 64) * 4096 * 2),
+}
+# the parent's ``facts()``, printed (CPU box, shapes only)
+PARENT_FACTS = {
+    "pythia-1.4b-widths": dict(
+        n_params=405039104, flops_per_token=2732224512,
+        prefill_flops=32173222526976, decode_step_bytes=4187070464.0),
+    "pythia-6.9b-widths": dict(
+        n_params=1011912704, flops_per_token=6474129408,
+        prefill_flops=82486826631168, decode_step_bytes=7470202880.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(HAND_COUNTS))
+def test_facts_of_the_pythia_configurations_are_the_hand_counts(name):
+    """Through the runners' own ``facts()`` at the real sizes (shapes only:
+    nothing is placed or run), at the sizes of both traffic files."""
+    config, want = CONFIGS[name], HAND_COUNTS[name]
+    assert want == PARENT_FACTS[name]
+    ref = program.reference(config)
+    table = program.param_table(ref, config)
+    params = {leaf: jax.ShapeDtypeStruct(dims, config["param_dtype"])
+              for leaf, (dims, _std) in table.items()}
+    assert costs.tree_count(params) == want["n_params"]
+    devices = jax.devices()[:config["chips"]]
+
+    class Counted:
+        """The stream of a train job, as ``facts()`` reads it."""
+        def stats(self):
+            return {"batches": 7, "starved": 2}
+
+    for mix in ("train-2k", "decode-1k-128"):
+        traffic = cells.load_json(f"{cells.BENCH_DIR}/traffic/{mix}.json")
+        runner = cells.load_module(
+            f"{cells.BENCH_DIR}/runners/{traffic['runner']}.py")
+        job = runner.build(config, traffic, devices)
+        job.n_params, job.params = want["n_params"], params
+        job.stream, job.stream_warm = Counted(), {"batches": 3, "starved": 2}
+        facts = job.facts()
+        for key in facts.keys() & want.keys():
+            assert facts[key] == want[key], key
+            assert facts[key] == int(facts[key])
+        if "stream" in facts:
+            assert facts["stream"] == {"batches": 4, "starved": 0}
+        assert facts.keys() & {"flops_per_token", "prefill_flops"}
+
+
+def test_costs_count_only_what_the_counts_say():
+    """A routed model with an untied head: 1000 active block parameters, a
+    projection of 35 and an embedding that counts nothing; K and V narrower
+    than the model."""
+    active, proj, L, D, B, T, S = 1000 + 35, 35, 2, 5, 3, 11, 13
+    assert costs.train_flops_per_token(active, L, D, S) == (
+        6 * 1035 + 12 * L * D * S)
     # 2 per block parameter and 4·L·D·T per position, and one projection
     # onto the vocabulary per prompt
-    n, V, L, D, B, T = 1000 + 7 * 5, 7, 2, 5, 3, 11
-    assert costs.prefill_flops(n, V, L, D, B, T) == (
-        B * T * (2 * 1000 + 4 * L * D * T) + B * 2 * V * D)
+    assert costs.prefill_flops(active, proj, L, D, B, T) == (
+        B * T * (2 * 1000 + 4 * L * D * T) + B * 2 * 35)
 
 
 def test_decode_bytes_are_parameters_plus_live_kv():
@@ -114,5 +215,7 @@ def test_decode_bytes_are_parameters_plus_live_kv():
     assert costs.tree_bytes(params) == 127
     L, B, Tp, N, D = 6, 48, 1024, 128, 2048
     live = 2 * L * B * (Tp + N / 2) * D * 2      # k and v, bfloat16
-    assert costs.kv_bytes(L, B, Tp + N / 2, D, 2) == live
-    assert costs.decode_step_bytes(127, L, B, Tp, N, D, 2) == 127 + live
+    assert costs.kv_bytes(L, B, Tp + N / 2, 2 * D, 2) == live
+    assert costs.decode_step_bytes(127, L, B, Tp, N, 2 * D, 2) == 127 + live
+    # grouped K/V heads: a quarter of the elements, a quarter of the bytes
+    assert costs.kv_bytes(L, B, Tp + N / 2, 2 * D // 4, 2) == live / 4

@@ -6,15 +6,16 @@ earlier reduction gave for the first recording are pinned first."""
 
 import glob
 import gzip
-import io
 import os
+import types
 
 import pytest
 
-from benchmarks import trace_cell
-from benchmarks.lib import clock, hlo_names, scopes, xplane
-from benchmarks.lib.hlo_names import ScopedEvent as Event
+from benchmarks import run as bench_run
+from benchmarks.lib import cells, clock, hlo_names, scopes, xplane
+from benchmarks.lib.rundata import RunData
 from benchmarks.lib.spans import TRACE_PREFIX
+from benchmarks.lib.xplane import Event
 
 TESTDATA = os.path.join(os.path.dirname(xplane.__file__), "testdata")
 SCOPED = sorted(glob.glob(TESTDATA + "/scoped/*.json.gz"))
@@ -25,6 +26,32 @@ SCOPED = sorted(glob.glob(TESTDATA + "/scoped/*.json.gz"))
 # ``optimizer``.  It holds ``hlo_names``' field numbers to a real file.
 V5E_PROFILE = os.path.join(TESTDATA, "scoped",
                            "probe-train-step.v5e.xplane.pb.gz")
+BENCH = cells.load_benchmark()
+
+
+def readers(workload: str) -> dict:
+    """Per-layer metric -> its reader, of the metrics ``workload`` reports
+    through a shared reader: data files, found as ``run.py`` finds them."""
+    return {row["name"]: reader
+            for row, reader in cells.resolve(workload).per_layer
+            if hasattr(reader, "spec")}
+
+
+BY_CELL = {w["name"]: readers(w["name"]) for w in BENCH["workloads"]}
+TRAIN = next(r for r in BY_CELL.values() if "coll_tp_share" in r)
+DECODE = next(r for r in BY_CELL.values() if "prefill_device_ms" in r)
+
+
+def traced_run(events, table="whole", window_s=None) -> RunData:
+    """What ``run.measure`` hands the readers of a traced run's events."""
+    if table == "whole":
+        table = scopes.reduce_scopes(events)
+    summary = xplane.reduce_events(events)
+    if window_s is not None:
+        summary = types.SimpleNamespace(window_s=window_s)
+    return RunData(durations={}, facts={}, peaks=None, trace=summary,
+                   compiles_in_window=0, peak_bytes=None, scopes=table,
+                   events=list(events))
 
 
 def test_the_first_recording_still_reads_what_it_read():
@@ -33,10 +60,8 @@ def test_the_first_recording_still_reads_what_it_read():
     another number from the same events."""
     path = os.path.join(TESTDATA,
                         "pythia-6.9b-widths.train-2k-dp2tp2.events.json.gz")
-    events = hlo_names.load_events(path)
+    events = xplane.load_events(path)
     assert all(e.scope == "" for e in events)       # five columns, defaulted
-    assert [tuple(e[:5]) for e in events] == [
-        tuple(e) for e in xplane.load_events(path)]
     s = xplane.reduce_events(events)
     assert (s.devices, s.window_s, s.busy_s, s.collective_s,
             s.exposed_collective_s) == (4, 1.305604004, 1.29811002575,
@@ -139,7 +164,7 @@ def test_op_names_are_read_from_the_profiles_own_programs(tmp_path):
     # what xplane saves of them, six columns, loads again
     saved = str(tmp_path / "scoped.json.gz")
     xplane.save_events(named, saved)
-    assert hlo_names.load_events(saved) == named
+    assert xplane.load_events(saved) == named
 
 
 # ---- the same, on a profile the v5e wrote ----------------------------------
@@ -166,7 +191,7 @@ def test_op_names_are_read_from_a_profile_the_v5e_wrote(v5e_profile):
         ("recompute", ("layers", "attention")), ("bwd", ("layers", "ffn")),
         ("fwd", ("loss",)), (None, ("optimizer",))}
 
-    events = hlo_names.read_events(v5e_profile)
+    events = xplane.read_events(v5e_profile)
     device_ops = [e for e in events if e.line == xplane.OPS_LINE
                   and xplane.DEVICE_PLANE.match(e.plane)]
     assert len(device_ops) == 140
@@ -334,10 +359,10 @@ def test_seconds_tell_no_time_from_no_such_scope():
     assert scopes.seconds(None, ["unscoped"]) is None
 
 
-def test_shares_of_the_hand_counted_table():
+def test_shares_of_the_hand_counted_table(capsys):
     table = scopes.reduce_scopes(SYNTHETIC)
-    out = io.StringIO()
-    got = trace_cell.shares(table, 200 * NS, "train", file=out)
+    run = traced_run(SYNTHETIC, table, window_s=200 * NS)
+    got = {name: reader.read(run) for name, reader in TRAIN.items()}
     assert got == {
         "train_fwd_share": pytest.approx(25.0),
         "train_bwd_share": pytest.approx(100 * (37 + 30) / 200),
@@ -349,33 +374,31 @@ def test_shares_of_the_hand_counted_table():
         "train_unscoped_share": pytest.approx(5.0),
         "coll_tp_share": pytest.approx(5.0),
         "coll_grad_sync_share": pytest.approx(15.0)}
-    assert out.getvalue() == ""
+    assert capsys.readouterr().err == ""
     # a train program that lost a scope says which, and what to suspect
     for key in ("scope/optimizer", "self/optimizer"):
         del table[key]
-    trace_cell.shares(table, 200 * NS, "train", file=out)
-    said = out.getvalue()
+    lost = {name: reader.read(run) for name, reader in TRAIN.items()}
+    assert lost == {**got, "train_optimizer_share": None}
+    said = capsys.readouterr().err
     assert said.count("\n") == 1 and said.startswith("train_optimizer_share: ")
     assert "scope/optimizer" in said and "stale executable" in said
     assert "JAX_COMPILATION_CACHE_DIR" in said
-    # a program without any scope (the parent's) names every share it
-    # lacks, but the collectives' where the trace has no collective
-    out = io.StringIO()
-    bare = {"unscoped": 1e-7, "executions": 1.0}
-    assert trace_cell.shares(bare, 200 * NS, "decode", file=out) == {
+    # a program without any scope (the parent's) names every share it lacks
+    bare = traced_run(SYNTHETIC, {"unscoped": 1e-7, "executions": 1.0},
+                      window_s=200 * NS)
+    assert {name: reader.read(bare) for name, reader in DECODE.items()
+            if reader.spec["reader"] == "scope_share"} == {
+        "decode_cache_move_share": None, "decode_attention_share": None,
         "decode_unscoped_share": pytest.approx(50)}
-    assert [line.split(":")[0] for line in out.getvalue().splitlines()] == [
+    assert [line.split(":")[0] for line in
+            capsys.readouterr().err.splitlines()] == [
         "decode_cache_move_share", "decode_attention_share"]
-    out = io.StringIO()
-    trace_cell.shares(bare, 200 * NS, "train", file=out)
-    assert "train_loss_share" in out.getvalue()
-    assert "coll_" not in out.getvalue()
-    trace_cell.shares({**bare, "coll/other": 1e-8}, 200 * NS, "train",
-                      file=out)
-    assert "coll_tp_share: " in out.getvalue()
-    assert "coll_grad_sync_share: " in out.getvalue()
-    # a kind of job the shares do not know has none
-    assert trace_cell.shares(table, 200 * NS, "serve", file=out) == {}
+    # a run without a device trace (the CPU) has no share and says nothing
+    nothing = traced_run(SYNTHETIC, None)
+    assert {reader.read(nothing) for reader in
+            list(TRAIN.values()) + list(DECODE.values())} == {None}
+    assert capsys.readouterr().err == ""
 
 
 def decode_sample(prefill_first, prefill_full, lead=0.0):
@@ -405,7 +428,7 @@ def decode_sample(prefill_first, prefill_full, lead=0.0):
 
 
 @pytest.mark.parametrize("lead", [0.0, -40.0, 40.0])
-def test_prefill_is_read_from_the_program_that_ttft_times(lead):
+def test_prefill_is_read_from_the_program_that_ttft_times(lead, capsys):
     events = decode_sample(400, 600, lead)
     whole = scopes.reduce_scopes(events)
     assert whole["scope/prefill"] == pytest.approx(1000 * NS)
@@ -418,18 +441,18 @@ def test_prefill_is_read_from_the_program_that_ttft_times(lead):
     full = scopes.reduce_scopes(events, span="full")
     assert full["scope/prefill"] == pytest.approx(600 * NS)
     assert full["scope/attention@decode.step"] == pytest.approx(60 * NS)
-    out = io.StringIO()
-    assert trace_cell.prefill_ms(events, "decode", file=out) == {
-        "prefill_device_ms": pytest.approx(400e-6)}
-    assert trace_cell.prefill_ms(events, "train", file=out) == {}
-    assert out.getvalue() == ""
+    prefill_ms = DECODE["prefill_device_ms"]
+    assert prefill_ms.spec["span"] == "first"
+    assert prefill_ms.read(traced_run(events)) == pytest.approx(400e-6)
+    assert capsys.readouterr().err == ""
     # no run under such a span, or no prefill in it: said, and left out
     nowhere = scopes.reduce_scopes(events, span="nowhere")
     assert nowhere == {"executions": 0.0, "unscoped": 0.0}
     bare = [e._replace(scope="") for e in events]
-    assert trace_cell.prefill_ms(bare, "decode", file=out) == {}
-    assert out.getvalue().startswith("prefill_device_ms: ")
-    assert "'first'" in out.getvalue() and "stale" in out.getvalue()
+    assert prefill_ms.read(traced_run(bare)) is None
+    said = capsys.readouterr().err
+    assert said.startswith("prefill_device_ms: ")
+    assert "'first'" in said and "stale" in said
 
 
 # ---- the clock -------------------------------------------------------------
@@ -517,7 +540,7 @@ def test_the_streams_own_spans_reach_the_profile():
 
     job = Job()
     try:
-        events = trace_cell.traced_events(job, 8)
+        events = bench_run.trace_samples(job, 8)
     finally:
         job.stream.close()
     ours = clock.program_annotations(events)
@@ -530,9 +553,9 @@ def test_the_streams_own_spans_reach_the_profile():
 
 def busy_by_the_selection_of_scopes(events) -> tuple[float, float]:
     """(window, busy) seconds as ``reduce_events`` defines them, computed
-    from what ``scopes.split`` selects and ``scopes.window_of`` bounds."""
-    per_device, hosts, _runs = scopes.split(events)
-    window = scopes.window_of(per_device, hosts)
+    from what ``xplane.split`` selects and ``xplane.window_of`` bounds."""
+    per_device, hosts, _runs = xplane.split(events)
+    window = xplane.window_of(per_device, hosts)
     busy = sum(xplane.length(xplane.union(xplane.clip(
         [(e.start_ns, e.start_ns + e.duration_ns) for e in device_ops]
         + xplane.collective_intervals(device_ops), window)))
@@ -544,11 +567,10 @@ def busy_by_the_selection_of_scopes(events) -> tuple[float, float]:
     glob.glob(TESTDATA + "/**/*.json.gz", recursive=True)),
     ids=lambda p: "synthetic" if p is None else os.path.relpath(p, TESTDATA))
 def test_the_scope_tables_window_is_reduce_events_window(path):
-    """``lib/scopes.py`` repeats the selection and the window rule of
-    ``xplane.reduce_events``, which a PR that edits no benchmark file cannot
-    factor out.  Held together here, so that the sites' shares of the
-    collectives keep adding up to ``coll_time_share``."""
-    events = SYNTHETIC if path is None else hlo_names.load_events(path)
+    """``lib/scopes.py`` and ``xplane.reduce_events`` select and bound by
+    the same two functions.  Held together here all the same, so that the
+    sites' shares of the collectives keep adding up to ``coll_time_share``."""
+    events = SYNTHETIC if path is None else xplane.load_events(path)
     s = xplane.reduce_events(events)
     window_s, busy_s = busy_by_the_selection_of_scopes(events)
     assert window_s == pytest.approx(s.window_s, rel=1e-12)
@@ -567,7 +589,10 @@ def test_scoped_recordings_are_there_and_small():
 
 @pytest.mark.parametrize("path", SCOPED, ids=os.path.basename)
 def test_scope_reduction_on_events_recorded_on_the_chip(path):
-    events = hlo_names.load_events(path)
+    events = xplane.load_events(path)
+    # a recording is named after its cell: the cell's own metrics read it
+    mine = BY_CELL[os.path.basename(path).removesuffix(".events.json.gz")]
+    data = traced_run(events)
     named = [e for e in events if e.scope]
     assert named and all(e.line == xplane.OPS_LINE for e in named)
     s = xplane.reduce_events(events)
@@ -589,10 +614,11 @@ def test_scope_reduction_on_events_recorded_on_the_chip(path):
                                        "phase/recompute", "scope/optimizer",
                                        "unscoped"))
         assert parts == pytest.approx(s.busy_s, rel=0.02)
-        assert trace_cell.shares(table, s.window_s, "train") == {
-            name: pytest.approx(100 * trace_cell.scopes.seconds(table, keys)
+        assert len(mine) == 10
+        assert {name: reader.read(data) for name, reader in mine.items()} == {
+            name: pytest.approx(100 * scopes.seconds(table, reader.spec["keys"])
                                 / s.window_s)
-            for name, keys in trace_cell.SHARES["train"].items()}
+            for name, reader in mine.items()}
     else:                                   # prefill and cached steps
         for key in ("scope/prefill", "scope/decode.step",
                     "scope/kv_cache@decode.step", "self/layers@decode.step",
@@ -606,7 +632,7 @@ def test_scope_reduction_on_events_recorded_on_the_chip(path):
         run = min((e for e in events if e.line == "XLA Modules"),
                   key=lambda e: e.start_ns)     # the sample's first job
         took, = [e.duration_ns for e in events if e.name == "bench:first"]
-        ms = trace_cell.prefill_ms(events, "decode")["prefill_device_ms"]
+        ms = mine["prefill_device_ms"].read(data)
         assert ms == pytest.approx(1e3 * first["scope/prefill"])
         assert 0.95 * run.duration_ns < ms * 1e6 < run.duration_ns < took
         assert table["scope/prefill"] > 2 * first["scope/prefill"]

@@ -7,14 +7,12 @@ it should be in, and ``classify`` has to say of it what the metric assumes.
 Nothing here is a time.
 """
 
-import json
 import re
 
 import jax
 import numpy as np
 import pytest
 
-from benchmarks import trace_cell
 from benchmarks.lib import cells, program, scopes, xplane
 from benchmarks.lib.scopes import Where, classify
 from ompi_tpu.core import scopes as program_scopes
@@ -78,22 +76,20 @@ TRAIN = [w for w in WORKLOADS if kind_of(w) == "train"]
 DECODE = [w for w in WORKLOADS if kind_of(w) == "decode"]
 
 
-def shares_of(workload: str) -> dict[str, tuple]:
-    """The shares ``trace_cell.py`` should print for this cell, each with
-    its keys.  On one chip the compiler leaves no collective to find."""
-    kind = kind_of(workload)
-    found = {name: keys for name, keys in trace_cell.SHARES[kind].items()
-             if WORKLOADS[workload]["chips"] > 1
-             or not keys[0].startswith("coll/")}
-    if kind in trace_cell.PREFILL_MS:
-        name, _span, keys = trace_cell.PREFILL_MS[kind]
-        found[name] = keys
+def keyed_metrics(workloads) -> dict[str, list[str]]:
+    """Per-layer metric -> the scope-table keys its data file hands to a
+    shared reader, of the metrics a cell among ``workloads`` reports."""
+    found = {}
+    for workload in workloads:
+        for row, reader in cells.resolve(workload).per_layer:
+            if "keys" in getattr(reader, "spec", {}):
+                found[row["name"]] = reader.spec["keys"]
     return found
 
 
 def share_cases():
     for workload in WORKLOADS:
-        for name, keys in shares_of(workload).items():
+        for name, keys in keyed_metrics([workload]).items():
             for key in keys:
                 yield pytest.param(workload, name, key,
                                    id=f"{workload}-{name}-{key}")
@@ -110,40 +106,16 @@ def test_the_two_vocabularies_are_one():
 def test_every_key_a_share_reads_is_in_the_cells_programs(workload, name, key):
     table = {k: 1.0 for k in cell_table(workload)}
     assert scopes.seconds(table, [key]), sorted(table)
-    # and no share of another kind of job finds anything here, but the one
-    # any program has and the collectives' (the CPU's compiler keeps the
-    # one-device all-reduces that the chip's removes)
-    found = {other for kind, rows in trace_cell.SHARES.items()
-             if kind != kind_of(workload)
-             for other, keys in rows.items()
-             if keys != ("unscoped",) and not keys[0].startswith("coll/")
+    # and no metric that only another kind of job reports finds anything
+    # here, but the one any program has and the collectives' (the CPU's
+    # compiler keeps the one-device all-reduces that the chip's removes)
+    mine = set(TRAIN if workload in TRAIN else DECODE)
+    ours, theirs = keyed_metrics(mine), keyed_metrics(set(WORKLOADS) - mine)
+    found = {other for other, keys in theirs.items()
+             if other not in ours
+             and keys != ["unscoped"] and not keys[0].startswith("coll/")
              and scopes.seconds(table, keys)}
     assert not found, found
-
-
-@pytest.mark.parametrize("workload", list(WORKLOADS))
-def test_trace_cell_runs_tiny_on_the_cpu(workload):
-    """Control flow only: the CPU's trace has no device plane, so the tables
-    are empty; the job runs, is correct, and the stream's counters are read
-    where the job has a stream."""
-    cell = cells.resolve(workload)
-    cell.config = program.tiny(cell.config)
-    cell.traffic = {k: TINY_TRAFFIC.get(k, v) for k, v in cell.traffic.items()}
-    line = trace_cell.trace_cell(cell, jax.devices()[:cell.chips], seed=3)
-    json.dumps(line)
-    assert line["correct"] is True and line["samples"] == 2
-    assert line["device_scopes"] is None and line["shares"] is None
-    assert "clock" not in line
-    if workload in TRAIN:
-        assert line["stream"]["batches"] == 2
-        assert 0.0 <= line["data_starved_share"] <= 100.0
-    else:
-        assert line["stream"] is None and "data_starved_share" not in line
-
-
-def test_trace_cell_has_no_cpu_mode():
-    assert trace_cell.main(["--workload", next(iter(WORKLOADS)),
-                            "--seed", "0"]) == 2
 
 
 @pytest.mark.parametrize("scope", ["embed", "layers", "attn_proj",
