@@ -23,17 +23,23 @@ Sharding: batch over dp, heads over tp (the cache is
 head-sharded exactly like the weights); greedy argmax over the full
 vocab.  Sequence parallelism is a training-time layout — decode
 requires sp == 1.  MoE configs route each generated token through the
-same ep-sharded switch as training; note the switch capacity is
-computed per single-token step (B tokens), so under a binding capacity
-the drop pattern can differ from a full-sequence forward — cached and
-full paths agree exactly whenever capacity doesn't bind.
+same layer as training and prefill (``_moe_ffn_tail``).  The top-1 switch
+(``moe_top_k == 0``) computes its capacity per single-token step (B
+tokens), so under a binding capacity the drop pattern can differ from a
+full-sequence forward — cached and full paths agree exactly whenever
+capacity doesn't bind.  The dropless path (``moe_top_k >= 1``) has no
+capacity: a token's experts and their weights depend on that token alone,
+so the cached step (``B·k`` rows over all experts, a handful a tile) and
+the full forward agree at any batch, up to the order of summation.
 """
 
 from __future__ import annotations
 
 from ompi_tpu.models.transformer import (TransformerConfig,
-                                         _dense_ffn_tail, _rmsnorm,
-                                         _rope, param_specs)
+                                         _dense_ffn_tail, _head,
+                                         _moe_ffn_tail, _qk_norm, _rmsnorm,
+                                         _rope, layer_leaves, param_specs)
+from ompi_tpu.parallel.moe import EXPERT_LEAVES
 
 __all__ = ["make_decoder"]
 
@@ -42,8 +48,10 @@ def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, layer, pos):
     """Layer ``layer`` for ONE new token position, against the whole cache.
 
     h: (B, 1, D); kc/vc: the stacked cache (L, B, Tmax, Hl, hd); lp:
-    this layer's parameters.  Returns (h, kc, vc) with the new token's
-    k/v written in place at ``(layer, :, pos)``.
+    this layer's parameters, but for the dropless experts' leaves
+    (``moe.EXPERT_LEAVES``), which are the whole stacks over layers that
+    ``routed_moe`` indexes by ``layer``.  Returns (h, kc, vc) with the new
+    token's k/v written in place at ``(layer, :, pos)``.
     """
     import jax
     import jax.numpy as jnp
@@ -57,10 +65,15 @@ def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, layer, pos):
     Tmax, hl, hd = kc.shape[2:]
 
     with scope("attn_proj"):
-        x = _rmsnorm(h, lp["ln1"])
-        q = column_parallel(x, lp["wq"].astype(cdt)).reshape(B, 1, hl, hd)
-        k = column_parallel(x, lp["wk"].astype(cdt)).reshape(B, 1, hl, hd)
-        v = column_parallel(x, lp["wv"].astype(cdt)).reshape(B, 1, hl, hd)
+        x = _rmsnorm(h, lp["ln1"], cfg.norm_eps)
+
+        def project(w, norm=None):
+            y = column_parallel(x, lp[w].astype(cdt))
+            if cfg.qk_norm and norm:
+                y = _qk_norm(cfg, y, lp[norm], comm)
+            return y.reshape(B, 1, hl, hd)
+
+        q, k, v = project("wq", "qn"), project("wk", "kn"), project("wv")
         q = _rope(q, pos[None])
         k = _rope(k, pos[None])
     with scope("kv_cache"):
@@ -82,11 +95,11 @@ def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, layer, pos):
         o = o.astype(cdt).reshape(B, 1, hl * hd)
         h = h + row_parallel(o, lp["wo"].astype(cdt), comm, axis="tp")
     if cfg.moe_experts:
-        from ompi_tpu.models.transformer import _moe_ffn_tail
-
-        h, _aux = _moe_ffn_tail(cfg, h, lp, comm)  # aux: training-only
+        # aux is training-only; the dropless experts come as whole stacks
+        h, _aux = _moe_ffn_tail(cfg, h, lp, comm,
+                                layer=layer if cfg.moe_top_k else None)
         return h, kc, vc
-    return _dense_ffn_tail(h, lp, comm, cdt), kc, vc
+    return _dense_ffn_tail(h, lp, comm, cdt, cfg.norm_eps), kc, vc
 
 
 def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
@@ -95,9 +108,9 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
 
     Greedy decode by default: prefill through the training backbone
     (one pass, K/V collected per layer), then ``max_new`` single-token
-    steps over the static cache.  Requires sp == 1; dense and
-    switch-MoE configs both supported (MoE routes each token through
-    the same ep-sharded switch as training).
+    steps over the static cache.  Requires sp == 1; dense, switch-MoE
+    and dropless top-k MoE configs are supported (MoE routes each token
+    through the same layer as training).
 
     ``temperature > 0`` switches to sampling (optionally truncated to
     the ``top_k`` highest logits); the returned callable then takes a
@@ -127,9 +140,6 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
                  if a in mesh.axis_names)
     comm = DeviceCommunicator(mesh, axes)
     cdt = jnp.dtype(cfg.compute_dtype)
-    keys = ["wq", "wk", "wv", "wo", "w1", "w2", "ln1", "ln2"]
-    if cfg.moe_experts:
-        keys.append("wg")
     if temperature < 0:
         raise ValueError(f"temperature must be >= 0, got {temperature}")
     if top_k and not temperature:
@@ -154,7 +164,7 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
 
     def local(params, prompt, seed):
         B, Tp = prompt.shape
-        emb = params["emb"].astype(cdt)
+        head = _head(cfg, params).astype(cdt)
         # ---- prefill: one training-backbone pass, K/V collected ----
         with scope("prefill"):
             h, (_aux, ks, vs) = tfm._local_backbone(
@@ -162,11 +172,13 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
             pad = [(0, 0), (0, 0), (0, max_new), (0, 0), (0, 0)]
             kc = jnp.pad(ks, pad)       # (L, B, Tp+max_new, Hl, hd)
             vc = jnp.pad(vs, pad)
-            logits = jnp.einsum("bd,vd->bv", h[:, -1, :], emb,
+            logits = jnp.einsum("bd,vd->bv", h[:, -1, :], head,
                                 preferred_element_type=jnp.float32)
             tok0 = pick(logits, jnp.int32(Tp - 1), seed)          # (B,)
 
-        layer_params = {k: params[k] for k in keys}
+        layer_params = {k: params[k] for k in layer_leaves(cfg)}
+        # the dropless experts' kernel reads its layer out of the stack
+        whole = EXPERT_LEAVES if cfg.moe_top_k else ()
 
         def gen(carry, _):
             kc, vc, tok, pos = carry
@@ -176,7 +188,8 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
             # the whole stacked cache is this loop's carry too; as a
             # scan's xs and ys it would be sliced out and copied back
             def per_layer(layer, state):
-                lp = {k: lax.dynamic_index_in_dim(w, layer, keepdims=False)
+                lp = {k: w if k in whole
+                      else lax.dynamic_index_in_dim(w, layer, keepdims=False)
                       for k, w in layer_params.items()}
                 return _step_layer(cfg, comm, lp, *state, layer, pos)
 
@@ -184,8 +197,8 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
                 h, kc, vc = lax.fori_loop(0, cfg.n_layers, per_layer,
                                           (h, kc, vc))
             with scope("unembed"):
-                h = _rmsnorm(h, params["lnf"])
-                logits = jnp.einsum("bd,vd->bv", h[:, 0, :], emb,
+                h = _rmsnorm(h, params["lnf"], cfg.norm_eps)
+                logits = jnp.einsum("bd,vd->bv", h[:, 0, :], head,
                                     preferred_element_type=jnp.float32)
             with scope("sample"):
                 nxt = pick(logits, pos, seed)

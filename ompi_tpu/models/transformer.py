@@ -45,6 +45,22 @@ class TransformerConfig:
     moe_experts: int = 0
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
+    # Experts a token: 0 keeps the top-1 switch above (capacity drops, the
+    # aux loss, experts over "ep").  k >= 1 routes every token to its k
+    # most probable experts and drops none (parallel/moe.routed_moe:
+    # sort by expert, grouped matmul, weighted sum back); every expert is
+    # then on every device (ep == 1) and no aux term joins the loss.
+    moe_top_k: int = 0
+    # Gated experts: down(silu(gate(x)) * up(x)) with a third leaf "w3"
+    # (up) beside "w1" (gate) and "w2" (down), instead of w2(gelu(w1 x)).
+    moe_gated: bool = False
+    # RMSNorm of q and k over their whole projected width, before the
+    # split into heads and the rotary embedding (leaves "qn", "kn").
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    # False: the output projection is a leaf of its own, "head" (V, D),
+    # and "emb" is a lookup table only.
+    tie_head: bool = True
     # chunked cross-entropy: >0 computes the loss over sequence chunks of
     # this length without materializing the full (B, T, V) logits/log-
     # softmax pair — at vocab 32k that pair is the single largest HBM
@@ -126,12 +142,19 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
         "ln2": np.ones((L, D), np.float32),
         "lnf": np.ones((D,), np.float32),
     }
+    if cfg.qk_norm:
+        params["qn"] = np.ones((L, D), np.float32)
+        params["kn"] = np.ones((L, D), np.float32)
+    if not cfg.tie_head:
+        params["head"] = w(V, D, scale=0.02)
     if cfg.moe_experts:
         E = cfg.moe_experts
         params["wg"] = w(L, D, E, scale=0.02)
         params["w1"] = w(L, E, D, F)
         params["w2"] = w(L, E, F, D,
                          scale=(F ** -0.5) / max(1, 2 * L) ** 0.5)
+        if cfg.moe_gated:
+            params["w3"] = w(L, E, D, F)
     else:
         params["w1"] = w(L, D, F)
         params["w2"] = w(L, F, D, scale=(F ** -0.5) / max(1, 2 * L) ** 0.5)
@@ -155,11 +178,17 @@ def param_specs(P, cfg: Optional[TransformerConfig] = None, mesh=None):
         "wq": P(None, None, "tp"), "wk": P(None, None, "tp"),
         "wv": P(None, None, "tp"), "wo": P(None, "tp", None),
     }
+    if cfg is not None and cfg.qk_norm:
+        specs["qn"] = specs["kn"] = P(None, "tp")
+    if cfg is not None and not cfg.tie_head:
+        specs["head"] = P()
     if cfg is not None and cfg.moe_experts:
         has_ep = mesh is not None and "ep" in mesh.axis_names
         specs["wg"] = P()
         specs["w1"] = P(None, "ep", None, None) if has_ep else P()
         specs["w2"] = P(None, "ep", None, None) if has_ep else P()
+        if cfg.moe_gated:
+            specs["w3"] = specs["w1"]
     else:
         specs["w1"] = P(None, None, "tp")
         specs["w2"] = P(None, "tp", None)
@@ -180,13 +209,50 @@ def shard_params(cfg: TransformerConfig, mesh, params: dict) -> dict:
             for k, v in params.items()}
 
 
-def _rmsnorm(x, scale):
+def _rmsnorm(x, scale, eps: float = 1e-6):
     import jax.numpy as jnp
     from jax import lax
 
     xf = x.astype(jnp.float32)
-    norm = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + 1e-6)
+    norm = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (norm * scale).astype(x.dtype)
+
+
+def _qk_norm(cfg, x, scale, comm):
+    """RMSNorm of a projected q or k over its whole width ``d_model``, of
+    which this device holds the ``tp`` shard of its heads: the squares are
+    summed over ``tp`` before the root."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ompi_tpu.core.scopes import coll
+
+    if int(comm.mesh.shape["tp"]) == 1:
+        return _rmsnorm(x, scale, cfg.norm_eps)
+    xf = x.astype(jnp.float32)
+    with coll("allreduce", "tp"):
+        total = lax.psum(jnp.sum(xf * xf, axis=-1, keepdims=True), "tp")
+    norm = xf * lax.rsqrt(total / cfg.d_model + cfg.norm_eps)
+    return (norm * scale).astype(x.dtype)
+
+
+def layer_leaves(cfg: TransformerConfig) -> tuple:
+    """Names of the leaves stacked over layers: what the layer loops of the
+    backbone and of the cached decode step slice a layer's parameters
+    from."""
+    leaves = ["wq", "wk", "wv", "wo", "w1", "w2", "ln1", "ln2"]
+    if cfg.moe_experts:
+        leaves.append("wg")
+        if cfg.moe_gated:
+            leaves.append("w3")
+    if cfg.qk_norm:
+        leaves += ["qn", "kn"]
+    return tuple(leaves)
+
+
+def _head(cfg: TransformerConfig, params):
+    """The (V, D) output projection: the embedding itself when tied."""
+    return params["emb"] if cfg.tie_head else params["head"]
 
 
 def _rope(x, positions):
@@ -205,15 +271,35 @@ def _rope(x, positions):
     return rot.astype(x.dtype)
 
 
-def _moe_ffn_tail(cfg, h, lp, comm):
-    """Post-attention half of the MoE layer: ln2 → ep-sharded switch →
-    residual (shared by the training layer and the cached decode step —
-    one source of truth, like _dense_ffn_tail).  Returns (h, aux)."""
+def _moe_ffn_tail(cfg, h, lp, comm, layer=None):
+    """Post-attention half of the MoE layer: ln2 → ep-sharded switch, or
+    dropless top-k routed experts → residual (shared by the training
+    layer, the prefill and the cached decode step — one source of truth,
+    like _dense_ffn_tail).  Returns (h, aux).  With ``layer``, the
+    dropless path's expert leaves (``moe.EXPERT_LEAVES``) are the whole
+    stacks over layers and ``layer`` this layer's index in them
+    (``routed_moe`` says why)."""
+    import jax.numpy as jnp
+
     from ompi_tpu.core.scopes import scope
-    from ompi_tpu.parallel.moe import switch_moe
+    from ompi_tpu.parallel.moe import EXPERT_LEAVES, routed_moe, switch_moe
 
     with scope("ffn"):
-        x = _rmsnorm(h, lp["ln2"])
+        x = _rmsnorm(h, lp["ln2"], cfg.norm_eps)
+        if cfg.moe_top_k:
+            if int(dict(comm.mesh.shape).get("ep", 1)) > 1:
+                raise ValueError(
+                    f"moe_top_k={cfg.moe_top_k} routes without drops, so "
+                    f"the exchange over ep={comm.mesh.shape['ep']} would be "
+                    f"ragged: not built; keep every expert on the device "
+                    f"(ep == 1), or use the top-1 switch (moe_top_k=0)")
+            weights = {k: lp[k] for k in ("wg", *EXPERT_LEAVES) if k in lp}
+            # the pallas kernel where the mesh is of TPUs (attached, or
+            # described for a compile); XLA's ragged_dot on any other
+            mo = routed_moe(x, weights, cfg.moe_top_k, gated=cfg.moe_gated,
+                            layer=layer, kernel=comm.mesh.devices.flat[
+                                0].platform == "tpu")
+            return h + mo, jnp.zeros((), jnp.float32)
         mo, aux = switch_moe(
             comm, x, {"wg": lp["wg"], "w1": lp["w1"], "w2": lp["w2"]},
             axis="ep", capacity_factor=cfg.moe_capacity_factor,
@@ -221,7 +307,7 @@ def _moe_ffn_tail(cfg, h, lp, comm):
         return h + mo, aux
 
 
-def _dense_ffn_tail(h, lp, comm, cdt):
+def _dense_ffn_tail(h, lp, comm, cdt, eps: float = 1e-6):
     """Post-attention half of the dense layer: ln2 → gelu MLP →
     residual (shared by the training layer and the cached decode step,
     models/decode.py — one source of truth for this math)."""
@@ -231,7 +317,7 @@ def _dense_ffn_tail(h, lp, comm, cdt):
     from ompi_tpu.parallel.layers import column_parallel, row_parallel
 
     with scope("ffn"):
-        x = _rmsnorm(h, lp["ln2"])
+        x = _rmsnorm(h, lp["ln2"], eps)
         y = jax.nn.gelu(column_parallel(x, lp["w1"].astype(cdt)))
         return h + row_parallel(y, lp["w2"].astype(cdt), comm, axis="tp")
 
@@ -269,10 +355,13 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
 
     def layer(h, lp):
         with scope("attn_proj"):
-            x = _rmsnorm(h, lp["ln1"])
+            x = _rmsnorm(h, lp["ln1"], cfg.norm_eps)
             q = column_parallel(x, lp["wq"].astype(cdt))
             k = column_parallel(x, lp["wk"].astype(cdt))
             v = column_parallel(x, lp["wv"].astype(cdt))
+            if cfg.qk_norm:
+                q = _qk_norm(cfg, q, lp["qn"], comm)
+                k = _qk_norm(cfg, k, lp["kn"], comm)
             B, t = x.shape[0], x.shape[1]
             q = _rope(q.reshape(B, t, h_local, hd), positions)
             k = _rope(k.reshape(B, t, h_local, hd), positions)
@@ -299,16 +388,13 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
             # identical across tp after the row_parallel psum)
             h, aux = _moe_ffn_tail(cfg, h, lp, comm)
         else:
-            h = _dense_ffn_tail(h, lp, comm, cdt)
+            h = _dense_ffn_tail(h, lp, comm, cdt, cfg.norm_eps)
             aux = jnp.zeros((), jnp.float32)
         if collect_kv:
             return h, (aux, k, v)
         return h, aux
 
-    keys = ["wq", "wk", "wv", "wo", "w1", "w2", "ln1", "ln2"]
-    if cfg.moe_experts:
-        keys.append("wg")
-    layer_params = {k: params[k] for k in keys}
+    layer_params = {k: params[k] for k in layer_leaves(cfg)}
     if cfg.remat in (True, "full"):
         layer_fn = jax.checkpoint(layer)
     elif cfg.remat == "dots":
@@ -318,7 +404,7 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
         layer_fn = layer
     with scope("layers"):
         h, ys = lax.scan(layer_fn, h, layer_params)
-    h = _rmsnorm(h, params["lnf"])
+    h = _rmsnorm(h, params["lnf"], cfg.norm_eps)
     if collect_kv:
         aux, ks, vs = ys
         return h, (aux.sum(), ks, vs)
@@ -332,7 +418,7 @@ def _local_forward(cfg: TransformerConfig, comm, params, tokens):
     aux) — aux is the summed MoE load-balancing loss (0.0 for dense).
     """
     h, aux = _local_backbone(cfg, comm, params, tokens)
-    return _unembed(cfg, h, params["emb"]), aux
+    return _unembed(cfg, h, _head(cfg, params)), aux
 
 
 def _unembed(cfg: TransformerConfig, h, emb):
@@ -412,11 +498,11 @@ def _local_loss(cfg: TransformerConfig, comm, params, tokens):
         if cfg.ce_chunk and T % cfg.ce_chunk == 0:
             B = tokens.shape[0]
             local_sum = _chunked_nll_sum(
-                cfg, h, params["emb"], labels,
+                cfg, h, _head(cfg, params), labels,
                 jnp.broadcast_to(weight, (B, T)))
         else:
-            logprobs = jax.nn.log_softmax(_unembed(cfg, h, params["emb"]),
-                                          axis=-1)
+            logprobs = jax.nn.log_softmax(
+                _unembed(cfg, h, _head(cfg, params)), axis=-1)
             nll = -jnp.take_along_axis(
                 logprobs, labels[..., None], axis=-1)[..., 0]
             local_sum = (nll * weight).sum()
@@ -429,7 +515,7 @@ def _local_loss(cfg: TransformerConfig, comm, params, tokens):
             total = lax.psum(local_sum, ("dp", "sp"))
             count = lax.psum(local_cnt, ("dp", "sp"))
     loss = total / count
-    if cfg.moe_experts:
+    if cfg.moe_experts and not cfg.moe_top_k:
         # average the per-device balance loss over the whole mesh (tp/ep
         # ranks see replicated tokens, so the mean is layout-invariant)
         if comm.size == 1:

@@ -9,5 +9,6 @@ Callers that want the pure-XLA form ask for it by name
 """
 
 from ompi_tpu.ops.flash_attention import flash_attention, flash_attention_lse
+from ompi_tpu.ops.grouped_matmul import grouped_matmul
 
-__all__ = ["flash_attention", "flash_attention_lse"]
+__all__ = ["flash_attention", "flash_attention_lse", "grouped_matmul"]

@@ -1,0 +1,173 @@
+"""Grouped matmul: row tiles that each multiply one matrix of a stack,
+chosen at run time — the matmul under dropless mixture-of-experts routing
+(``parallel/moe.routed_moe``).
+
+``rows`` is ``(n_tiles · tm, K)`` and ``w`` is ``(G, K, N)``; tile ``t`` (the
+rows ``t·tm .. (t+1)·tm``) multiplies ``w[tile_group[t]]``.  The caller lays
+the rows out so that a tile never straddles two groups (a group's rows
+start at a tile boundary and its last tile is filled up with rows nobody
+reads back), which is what keeps the kernel this small: no masks, no tile
+visited twice, the group of a tile one scalar read from SMEM by the index
+map of the weights' block.  ``tile_group`` is non-decreasing, so when a
+whole matrix is one block (small ``tm``: the memory-bound case, a few rows
+an expert) consecutive tiles of one group fetch it once.  Tiles from
+``tiles_used`` on hold no row of any group; they are written as zeros and
+multiply nothing.
+
+XLA's own ``lax.ragged_dot`` lowers on the TPU to kernels of the same kind,
+but under the one ``op_name`` ``ragged-dot-none``: the scopes around it are
+lost, and with them the time of the experts in every profile
+(``core/scopes.py``).  This kernel carries its scopes and its own name,
+``grouped_matmul``.
+
+Autodiff: ``jax.custom_vjp``.  Since every group is a run of whole tiles,
+the function is ``lax.ragged_dot(rows, w, rows-a-group)``
+(``grouped_matmul_xla``), and the backward is that function's own (pure
+XLA), from the saved ``rows`` and ``w``.
+
+Like the other kernels here it always compiles for the TPU: off the TPU a
+caller traces it under ``pltpu.force_tpu_interpret_mode()`` (the tests of
+this file's do), or chooses ``grouped_matmul_xla`` itself, as the model
+does on a mesh that is not of TPUs (``models/transformer._moe_ffn_tail``).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+__all__ = ["grouped_matmul", "grouped_matmul_xla", "tile_rows"]
+
+# A block of the weights may take this many bytes of VMEM (it is double
+# buffered).  Up to it, and under small row tiles, a whole (K, N) matrix is
+# one block.
+_RHS_BLOCK_BYTES = 4 << 20
+
+
+def tile_rows(rows_a_group: float) -> int:
+    """Rows a tile for groups of about ``rows_a_group`` rows: the largest
+    power of two not above it, from 16 (one bfloat16 sublane tile) to 512
+    (above the v5e's ridge of 240 operations a byte of weights)."""
+    tm = 16
+    while tm * 2 <= min(rows_a_group, 512):
+        tm *= 2
+    return tm
+
+
+def _block(dim: int, cap: int) -> int:
+    """``dim`` itself, or its largest power-of-two divisor up to ``cap``."""
+    if dim <= cap:
+        return dim
+    b = cap
+    while b >= 128 and dim % b:
+        b //= 2
+    if b < 128:
+        raise ValueError(f"grouped_matmul: {dim} has no power-of-two tile "
+                         f"between 128 and {cap}")
+    return b
+
+
+def _kernel(tile_group, tiles_used, lhs, rhs, out, acc):
+    from jax.experimental import pallas as pl
+
+    del tile_group          # read by the index map of ``rhs``
+    i, k = pl.program_id(0), pl.program_id(2)
+    last = k == pl.num_programs(2) - 1
+
+    @pl.when(i < tiles_used[0])
+    def _():
+        @pl.when(k == 0)
+        def _():
+            acc[...] = jnp.zeros_like(acc)
+
+        acc[...] += jnp.dot(lhs[...], rhs[...],
+                            preferred_element_type=jnp.float32)
+
+        @pl.when(last)
+        def _():
+            out[...] = acc[...].astype(out.dtype)
+
+    @pl.when(jnp.logical_and(i >= tiles_used[0], last))
+    def _():
+        out[...] = jnp.zeros_like(out)
+
+
+def _forward(rows, w, tile_group, tiles_used):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_tiles = tile_group.shape[0]
+    m, K = rows.shape
+    G, K2, N = w.shape
+    if K != K2 or m % n_tiles:
+        raise ValueError(f"grouped_matmul: rows {rows.shape}, w {w.shape}, "
+                         f"{n_tiles} tiles")
+    tm = m // n_tiles
+    itemsize = jnp.dtype(w.dtype).itemsize
+    if tm <= 64 and K * N * itemsize <= _RHS_BLOCK_BYTES:
+        tk, tn = K, N       # a few rows a group: every matrix read once
+    else:
+        tk, tn = _block(K, 1024), _block(N, 512)
+    return pl.pallas_call(
+        _kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_tiles, N // tn, K // tk),
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda i, j, k, tg, used: (i, k)),
+                pl.BlockSpec((None, tk, tn),
+                             lambda i, j, k, tg, used: (tg[i], k, j)),
+            ],
+            out_specs=pl.BlockSpec((tm, tn),
+                                   lambda i, j, k, tg, used: (i, j)),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, N), rows.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="grouped_matmul",
+    )(tile_group, tiles_used, rows, w)
+
+
+@jax.custom_vjp
+def grouped_matmul(rows, w, tile_group, tiles_used):
+    """rows (n_tiles·tm, K) × w (G, K, N) → (n_tiles·tm, N) in rows' dtype,
+    float32 accumulation: tile ``t`` times ``w[tile_group[t]]``.
+
+    ``tile_group``: (n_tiles,) int32, non-decreasing, every entry a valid
+    group.  ``tiles_used``: (1,) int32; tiles from there on come back zero.
+    """
+    return _forward(rows, w, tile_group, tiles_used)
+
+
+def _fwd(rows, w, tile_group, tiles_used):
+    return (_forward(rows, w, tile_group, tiles_used),
+            (rows, w, tile_group, tiles_used))
+
+
+def grouped_matmul_xla(rows, w, tile_group, tiles_used):
+    """The same function without the kernel: every group is a run of whole
+    tiles, so it is ``lax.ragged_dot`` with those runs as its groups (rows
+    past the last run come back zero there too).  The kernel's backward,
+    and what ``parallel/moe.routed_moe`` runs where the kernel does not
+    compile."""
+    from jax import lax
+
+    n_tiles = tile_group.shape[0]
+    tm = rows.shape[0] // n_tiles
+    used = jnp.arange(n_tiles) < tiles_used[0]
+    tiles_a_group = jnp.sum(
+        (tile_group[:, None] == jnp.arange(w.shape[0])[None, :])
+        & used[:, None], axis=0, dtype=jnp.int32)
+    return lax.ragged_dot(rows, w, tiles_a_group * tm)
+
+
+def _bwd(saved, g):
+    rows, w, tile_group, tiles_used = saved
+    _, vjp = jax.vjp(
+        lambda r, m: grouped_matmul_xla(r, m, tile_group, tiles_used),
+        rows, w)
+    return (*vjp(g), None, None)
+
+
+grouped_matmul.defvjp(_fwd, _bwd)

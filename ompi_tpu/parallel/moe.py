@@ -1,4 +1,6 @@
-"""Expert parallelism: a switch-style MoE layer over an ``ep`` mesh axis.
+"""Mixture-of-experts layers: a switch-style MoE over an ``ep`` mesh axis
+(:func:`switch_moe`, below) and dropless top-k routing with every expert
+on the device (:func:`routed_moe`, at the end).
 
 The fourth parallelism dimension (after dp/sp/tp): experts shard over
 ``ep`` and tokens travel to their expert's device through
@@ -29,7 +31,11 @@ from __future__ import annotations
 
 from typing import Optional
 
-__all__ = ["switch_moe", "moe_params"]
+__all__ = ["switch_moe", "routed_moe", "moe_params", "EXPERT_LEAVES"]
+
+# the experts' matrices in a parameter tree: gate (or the one up
+# projection), down, and with gated experts up
+EXPERT_LEAVES = ("w1", "w2", "w3")
 
 
 def moe_params(rng, d_model: int, d_ff: int, n_experts: int,
@@ -147,3 +153,103 @@ def switch_moe(comm, x, params, axis: str = "ep",
         mean_p = jnp.mean(probs, axis=0)                         # (E,)
         aux = E * jnp.sum(frac * mean_p)
     return y, aux
+
+
+def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
+               kernel: bool = False):
+    """Dropless top-k MoE layer, every expert on this device: x (B, T, D)
+    local tokens → (B, T, D).
+
+    ``params``: ``wg`` (D, E) the router; ``w1`` (E, D, F) and ``w2``
+    (E, F, D) the experts, ``w2(gelu(w1 x))``; with ``gated`` also ``w3``
+    (E, D, F), and an expert is ``w2(silu(w1 x) * w3 x)``.  With ``layer``
+    (a traced index) the three are the whole stacks over layers, (L, E, ·,
+    ·), and the kernel reads layer ``layer``'s matrices out of them: a
+    layer loop that sliced them first would copy every expert of the layer
+    out of the stack each time (a pallas call takes whole arrays), 0.8 GB
+    a layer of a cached step at OLMoE's widths.
+
+    Routing (all shapes static, no capacity, no token dropped): the
+    router's logits, softmax and top-k in float32; each token keeps its
+    ``top_k`` most probable experts with their probabilities as they are
+    (not renormalised).  The ``tokens × top_k`` assignments are sorted by
+    expert (stable) and laid out in row tiles of ``tm`` rows, every
+    expert's run starting at a tile boundary, so a tile's rows all go to
+    one expert and the experts run as ``ops.grouped_matmul`` over the
+    tiles (with ``kernel`` the pallas kernel, which compiles for the TPU
+    alone; without, the same tiles through ``lax.ragged_dot``, which is
+    what the kernel computes; an expert's rows, and with them the tiles in use, are known
+    only at run time; ``tokens·top_k/tm + E`` tiles always suffice).  The
+    slots that fill an expert's last tile up hold other real rows and are
+    read back by nobody.  Each token then sums its ``top_k`` result rows
+    weighted by their probabilities, in float32.
+
+    ``tm`` follows the rows an expert gets on average
+    (``ops.grouped_matmul.tile_rows``): 512 for a prefill of thousands of
+    rows an expert, 16 for a cached step's handful, where the layer is the
+    stream of every expert's weights.  One function, two tilings.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ompi_tpu.core.scopes import scope
+    from ompi_tpu.ops.grouped_matmul import (grouped_matmul,
+                                             grouped_matmul_xla, tile_rows)
+
+    B, T, D = x.shape
+    n, k = B * T, top_k
+    E = params["wg"].shape[-1]
+    cdt = x.dtype
+    xf = x.reshape(n, D)
+    tm = tile_rows(n * k / E)
+    n_tiles = -(-n * k // tm) + E       # sum of ceil(rows_e / tm) is below
+    with scope("moe.route"):
+        logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
+                            params["wg"].astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        gate, expert = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    with scope("moe.dispatch"):
+        # assignments sorted by expert; ``order``: sorted row -> assignment
+        sorted_group, order = lax.sort(
+            (expert.reshape(n * k), jnp.arange(n * k, dtype=jnp.int32)),
+            num_keys=1, is_stable=True)
+        is_group = sorted_group[:, None] == jnp.arange(E)[None, :]
+        rows_of = jnp.sum(is_group, axis=0, dtype=jnp.int32)    # (E,)
+        tiles_of = -(-rows_of // tm)
+        tile_end = jnp.cumsum(tiles_of)             # expert e ends before
+        # a run of sorted rows moves up by this much into the tiled layout
+        shift = (tile_end - tiles_of) * tm - (jnp.cumsum(rows_of) - rows_of)
+        tile_group = jnp.minimum(
+            jnp.searchsorted(tile_end, jnp.arange(n_tiles), side="right"),
+            E - 1).astype(jnp.int32)
+        # slot -> the sorted row it holds (where a slot only fills a tile
+        # up, a real row of the next run, or the last)
+        held = jnp.clip(jnp.arange(n_tiles * tm).reshape(n_tiles, tm)
+                        - shift[tile_group][:, None], 0, n * k - 1)
+        rows = xf[order[held.reshape(-1)] // k]     # (n_tiles·tm, D)
+        # assignment -> its slot (a sort by ``order`` is its inverse)
+        _, slot = lax.sort(
+            (order, jnp.arange(n * k)
+             + jnp.sum(jnp.where(is_group, shift[None, :], 0), axis=1)),
+            num_keys=1)
+    with scope("moe.experts"):
+        used = tile_end[-1:]
+        stacks = {name: params[name].astype(cdt)
+                  for name in EXPERT_LEAVES if name in params}
+        if layer is not None:       # (L, E, ·, ·): layer l's are l·E + e
+            stacks = {name: w.reshape(-1, *w.shape[2:])
+                      for name, w in stacks.items()}
+            tile_group = tile_group + layer * E
+        matmul = grouped_matmul if kernel else grouped_matmul_xla
+        hid = matmul(rows, stacks["w1"], tile_group, used)
+        if gated:
+            hid = jax.nn.silu(hid) * matmul(rows, stacks["w3"], tile_group,
+                                            used)
+        else:
+            hid = jax.nn.gelu(hid)
+        out = matmul(hid, stacks["w2"], tile_group, used)
+    with scope("moe.combine"):
+        out = out[slot].reshape(n, k, D).astype(jnp.float32)
+        y = jnp.sum(out * gate[:, :, None], axis=1).astype(cdt)
+    return y.reshape(B, T, D)

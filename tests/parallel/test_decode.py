@@ -17,6 +17,7 @@ import pytest
 from ompi_tpu.models import transformer as tfm
 from ompi_tpu.models.decode import make_decoder
 from ompi_tpu.parallel.mesh import make_mesh
+from ompi_tpu.parallel.moe import EXPERT_LEAVES
 
 CFG = tfm.TransformerConfig(
     vocab=97, d_model=64, n_heads=4, n_layers=2, d_ff=128, seq=64,
@@ -125,6 +126,137 @@ def test_moe_cached_decode_matches_full_forward(mesh_shape, max_new,
     cfg = dataclasses.replace(CFG, moe_experts=4, moe_capacity_factor=4.0,
                               n_layers=n_layers)
     _assert_cached_equals_full(cfg, mesh_shape, max_new, prompt_len=6, seed=1)
+
+
+# ---- an OLMoE-shaped block: dropless routed experts, q/k-norm, untied head --
+
+OLMOE = tfm.TransformerConfig(
+    vocab=97, d_model=64, n_heads=4, n_layers=2, d_ff=32, seq=64,
+    attention="xla", compute_dtype="float32", remat=False,
+    moe_experts=8, moe_top_k=2, moe_gated=True, qk_norm=True,
+    norm_eps=1e-5, tie_head=False)
+
+
+def _cached_logits(cfg, mesh, params, tokens, prompt_len):
+    """Logits of positions ``prompt_len - 1 ..`` of ``tokens`` through the
+    decoder's own pieces: the backbone as prefill, then ``_step_layer`` a
+    token at a time against the cache, fed the given tokens."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from ompi_tpu.models import decode
+    from ompi_tpu.mpi.device_comm import DeviceCommunicator
+
+    comm = DeviceCommunicator(mesh, tuple(mesh.axis_names))
+    cdt = jnp.dtype(cfg.compute_dtype)
+    steps = tokens.shape[1] - prompt_len
+
+    def local(params, tokens):
+        head = tfm._head(cfg, params).astype(cdt)
+        h, (_aux, ks, vs) = tfm._local_backbone(
+            cfg, comm, params, tokens[:, :prompt_len], collect_kv=True)
+        pad = [(0, 0), (0, 0), (0, steps), (0, 0), (0, 0)]
+        kc, vc = jnp.pad(ks, pad), jnp.pad(vs, pad)
+        out = [h[:, -1, :] @ head.T]
+        for pos in range(prompt_len, prompt_len + steps):
+            h = params["emb"][tokens[:, pos]].astype(cdt)[:, None, :]
+            for l in range(cfg.n_layers):
+                lp = {k: params[k] if k in EXPERT_LEAVES
+                      else params[k][l] for k in tfm.layer_leaves(cfg)}
+                h, kc, vc = decode._step_layer(cfg, comm, lp, h, kc, vc, l,
+                                               jnp.int32(pos))
+            out.append(tfm._rmsnorm(h, params["lnf"], cfg.norm_eps)[:, 0, :]
+                       @ head.T)
+        return jnp.stack(out, axis=1)
+
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(tfm.param_specs(P, cfg, mesh),
+                                    P("dp", None)),
+        out_specs=P("dp", None, None), check_vma=False))(params, tokens)
+
+
+@pytest.mark.parametrize("mesh_shape", [
+    pytest.param({"dp": 1, "sp": 1, "tp": 1}, id="one"),
+    pytest.param({"dp": 2, "sp": 1, "tp": 2}, id="dp2tp2")])
+def test_olmoe_shaped_cached_logits_equal_the_full_forwards(mesh_shape):
+    """Logits, not tokens: the routed tail at one token a sequence (8 rows
+    over 8 experts), the q/k-norm (summed over tp where the heads are
+    split) and the untied head in the cached step, against the full
+    forward, both in float32.  What is left is the order of summation."""
+    import jax
+
+    mesh = make_mesh(mesh_shape,
+                     devices=jax.devices()[:math.prod(mesh_shape.values())])
+    params = tfm.init_params(OLMOE, seed=3)
+    # norm scales away from one, so that a norm left out would show
+    rng = np.random.default_rng(4)
+    for leaf in ("qn", "kn", "ln1", "ln2", "lnf"):
+        params[leaf] = rng.uniform(0.5, 1.5, size=params[leaf].shape).astype(
+            np.float32)
+    tokens = rng.integers(0, OLMOE.vocab, size=(4, 12)).astype(np.int32)
+    full = np.asarray(jax.jit(tfm.make_forward(OLMOE, mesh))(params, tokens))
+    got = np.asarray(_cached_logits(OLMOE, mesh, params, tokens, prompt_len=9))
+    assert got.shape == (4, 4, OLMOE.vocab)
+    np.testing.assert_allclose(got, full[:, 8:], rtol=0, atol=2e-5 * full.std())
+
+
+def test_olmoe_shaped_cached_decode_matches_full_forward():
+    _assert_cached_equals_full(OLMOE, {"dp": 2, "sp": 1, "tp": 1}, 3,
+                               prompt_len=8, seed=2)
+
+
+# At the tiny sizes of benchmarks/configs/olmoe-1b-7b.json, float32 on both
+# sides, the program's logits sit within 1e-5 of their deviation of the
+# plain reference's (measured 1.9e-6: the order of summation).  Each
+# variant below moves them by half a deviation or more (0.63 to 2.6), so
+# 1e-4 tells them apart by orders either way.
+OLMOE_PARITY = 1e-4
+
+
+@pytest.mark.parametrize("variant,holds", [
+    pytest.param({}, True, id="as-published"),
+    pytest.param({"moe_top_k": -1}, False, id="one-expert-fewer"),
+    pytest.param({"qk_norm": False}, False, id="no-qk-norm"),
+    pytest.param({"norm_eps": 1e-6}, False, id="eps-1e-6"),
+])
+def test_olmoe_program_logits_against_the_plain_reference(variant, holds):
+    """The test with teeth: the program as the configuration file builds it
+    agrees with ``benchmarks/reference/olmoe.py`` on logits, and a program
+    with one expert a token fewer (7 of 8 at the published size), without
+    the q/k-norm or with the dense block's eps does not."""
+    import copy
+
+    import jax
+
+    from benchmarks.lib import cells, program
+
+    config = copy.deepcopy(program.tiny(cells.resolve(
+        "olmoe-1b-7b.decode-1k-128").config))
+    config["entry"]["options"]["compute_dtype"] = "float32"
+    ref = program.reference(config)
+    cfg = program.program_config(config)
+    mesh = program.mesh(config, jax.devices()[:1])
+    params = program.init_params(
+        ref, config, program.param_shardings(config, cfg, mesh), seed=11)
+    rng = np.random.default_rng(12)
+    params = {k: (jax.numpy.asarray(rng.uniform(0.5, 1.5, size=v.shape),
+                                    v.dtype)
+                  if k in ("qn", "kn", "ln1", "ln2", "lnf") else v)
+              for k, v in params.items()}
+    tokens = rng.integers(0, cfg.vocab, size=(4, cfg.seq)).astype(np.int32)
+    want = np.asarray(ref.logits(ref.Shape.from_config(config), params,
+                                 tokens))
+
+    if "moe_top_k" in variant:
+        variant = {"moe_top_k": cfg.moe_top_k - 1}
+    cfg = dataclasses.replace(cfg, **variant)
+    if not cfg.qk_norm:
+        params = {k: v for k, v in params.items() if k not in ("qn", "kn")}
+    got = np.asarray(jax.jit(tfm.make_forward(cfg, mesh))(params, tokens))
+    error = np.abs(got - want).max() / want.std()
+    assert (error < OLMOE_PARITY) == holds, error
 
 
 def test_decode_odd_prompt_length():

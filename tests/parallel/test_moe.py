@@ -127,3 +127,137 @@ def test_switch_moe_aux_loss():
     assert float(aux_skew) > float(aux)
     g = jax.grad(lambda wg: run(wg)[1])(params["wg"])
     assert np.isfinite(np.asarray(g)).all() and np.abs(np.asarray(g)).sum() > 0
+
+
+# ---- dropless top-k routing (routed_moe) -----------------------------------
+
+def _gelu_tanh(x):
+    return 0.5 * x * (1 + np.tanh(np.sqrt(2 / np.pi) * (x + 0.044715 * x ** 3)))
+
+
+def _routed_params(rng, D, F, E, gated, skewed):
+    p = {"wg": rng.normal(0, D ** -0.5, size=(D, E)),
+         "w1": rng.normal(0, D ** -0.5, size=(E, D, F)),
+         "w2": rng.normal(0, F ** -0.5, size=(E, F, D))}
+    if gated:
+        p["w3"] = rng.normal(0, D ** -0.5, size=(E, D, F))
+    if skewed:
+        p["wg"][0, 0] = 4.0     # with x[..., 0] = 3: +12 on expert 0's logit
+    return {k: v.astype(np.float32) for k, v in p.items()}
+
+
+def _routed_oracle(x, p, k, gated):
+    """numpy, float64, a loop over experts: every token's k most probable
+    experts, each weighted by its probability as it is; returns the layer's
+    output and the rows each expert got."""
+    D = x.shape[-1]
+    xf = x.reshape(-1, D).astype(np.float64)
+    p = {name: w.astype(np.float64) for name, w in p.items()}
+    logits = xf @ p["wg"]
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    best = np.argsort(-probs, axis=-1, kind="stable")[:, :k]
+    y, rows = np.zeros_like(xf), []
+    for e in range(p["w1"].shape[0]):
+        mine = np.where((best == e).any(axis=-1))[0]
+        rows.append(len(mine))
+        h = xf[mine] @ p["w1"][e]
+        if gated:
+            h = h / (1 + np.exp(-h)) * (xf[mine] @ p["w3"][e])
+        else:
+            h = _gelu_tanh(h)
+        y[mine] += probs[mine, e][:, None] * (h @ p["w2"][e])
+    return y.reshape(x.shape), rows
+
+
+def _routed_jnp(x, p, k, gated):
+    """The oracle again in jax.numpy (every expert on every token, under a
+    mask), for its gradient."""
+    xf = x.reshape(-1, x.shape[-1])
+    probs = jax.nn.softmax(xf @ p["wg"], axis=-1)
+    kth = jnp.sort(probs, axis=-1)[:, -k, None]
+    weight = jnp.where(probs >= kth, probs, 0.0)
+    h = jnp.einsum("td,edf->etf", xf, p["w1"])
+    if gated:
+        h = jax.nn.silu(h) * jnp.einsum("td,edf->etf", xf, p["w3"])
+    else:
+        h = jax.nn.gelu(h)
+    y = jnp.einsum("etf,efd,te->td", h, p["w2"], weight)
+    return y.reshape(x.shape)
+
+
+ROUTED = [pytest.param(8, 2, True, id="8x2-gated"),
+          pytest.param(8, 2, False, id="8x2-gelu"),
+          pytest.param(4, 1, False, id="4x1-gelu"),
+          pytest.param(16, 4, True, id="16x4-gated"),
+          pytest.param(64, 8, True, id="64x8-gated")]
+
+
+def _routed_case(E, gated, skewed, seed):
+    rng = np.random.default_rng(seed)
+    B, T, D, F = 2, 24, 32, 16
+    x = rng.normal(size=(B, T, D)).astype(np.float32)
+    if skewed:
+        x[..., 0] = 3.0
+    return x, _routed_params(rng, D, F, E, gated, skewed)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+@pytest.mark.parametrize("skewed", [False, True], ids=["spread", "skewed"])
+@pytest.mark.parametrize("E,k,gated", ROUTED)
+def test_routed_moe_equals_a_loop_over_experts(E, k, gated, skewed, kernel):
+    """No capacity: with a router that sends every token to expert 0 first,
+    that expert gets all 48 rows (three and more tiles of 16) while others
+    get none, and every token still has all k of its experts' results and
+    nothing of the rows that fill a tile up.  Through the pallas kernel (in
+    interpret mode here) and through XLA's ragged_dot, which the model
+    runs off the TPU."""
+    from ompi_tpu.parallel.moe import routed_moe
+
+    x, p = _routed_case(E, gated, skewed, seed=E + k)
+    want, rows = _routed_oracle(x, p, k, gated)
+    assert sum(rows) == x.shape[0] * x.shape[1] * k     # nothing dropped
+    if skewed:
+        assert rows[0] == x.shape[0] * x.shape[1] and min(rows) < 16
+    got = jax.jit(lambda a, q: routed_moe(a, q, k, gated=gated,
+                                          kernel=kernel))(x, p)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+@pytest.mark.parametrize("E,k,gated", ROUTED[:4])
+def test_routed_moe_gradient(E, k, gated, kernel):
+    """Through the kernel's custom_vjp, the gathers and the float32 router,
+    against the gradient of the masked dense form."""
+    from ompi_tpu.parallel.moe import routed_moe
+
+    x, p = _routed_case(E, gated, skewed=True, seed=7)
+    target = np.random.default_rng(1).normal(size=x.shape).astype(np.float32)
+
+    def loss(layer, **how):
+        return lambda a, q: ((layer(a, q, k, gated, **how) - target) ** 2
+                             ).sum()
+
+    got = jax.jit(jax.grad(loss(routed_moe, kernel=kernel),
+                           argnums=(0, 1)))(x, p)
+    want = jax.grad(loss(_routed_jnp), argnums=(0, 1))(x, p)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.abs(np.asarray(w)).max() > 0
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3,
+                                   atol=2e-4 * np.abs(np.asarray(w)).max())
+
+
+def test_routed_moe_refuses_ep():
+    """More than one expert a token over ep > 1 would be a ragged exchange."""
+    from ompi_tpu.models import transformer as tfm
+    from ompi_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh({"dp": 1, "sp": 1, "tp": 1, "ep": 2},
+                     devices=jax.devices()[:2])
+    cfg = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=2, n_layers=1,
+                                d_ff=16, seq=16, attention="xla",
+                                moe_experts=4, moe_top_k=2, remat=False)
+    with pytest.raises(ValueError, match="ragged"):
+        jax.jit(tfm.make_loss_fn(cfg, mesh)).lower(
+            tfm.init_params(cfg), np.zeros((2, 16), np.int32))
