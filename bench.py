@@ -801,44 +801,38 @@ def matrix_decode_throughput(devices) -> dict:
 
 
 def matrix_flash_bwd_kernel(devices) -> dict:
-    """Pallas flash-attention BACKWARD kernels (opt-in path): compile +
-    run fwd+bwd with ops_flash_bwd_kernel=1."""
+    """Pallas flash-attention kernels, forward and both backwards: compile
+    + run the gradient of a causal call."""
     import jax
     import jax.numpy as jnp
 
-    from ompi_tpu.core.config import var_registry
     from ompi_tpu.ops.flash_attention import flash_attention
 
-    old = var_registry.get("ops_flash_bwd_kernel")
-    var_registry.set("ops_flash_bwd_kernel", 1)
-    try:
-        b, t, h, d = 2, 512, 4, 128
-        rng = np.random.default_rng(0)
-        q, k, v = (jnp.asarray(rng.normal(size=(b, t, h, d)),
-                               jnp.bfloat16) for _ in range(3))
+    b, t, h, d = 2, 512, 4, 128
+    rng = np.random.default_rng(0)
+    q, k, v = (jnp.asarray(rng.normal(size=(b, t, h, d)),
+                           jnp.bfloat16) for _ in range(3))
 
-        def loss(q, k, v):
-            return flash_attention(q, k, v, causal=True).astype(
-                jnp.float32).sum()
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
 
-        fn = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))  # bind ONCE:
-        # a fresh jit wrapper per call would re-trace and the timed run
-        # would measure compilation, not the kernels
-        grads = fn(q, k, v)
-        jax.block_until_ready(grads)
-        t0 = time.perf_counter()
-        grads = fn(q, k, v)
-        jax.block_until_ready(grads)
-        dt = time.perf_counter() - t0
-        finite = all(bool(np.isfinite(np.asarray(
-            g, dtype=np.float32)).all()) for g in grads)
-        return {
-            "metric": f"flash bwd pallas kernels (seq {t})",
-            "value": round(dt * 1e3, 2), "unit": "ms", "vs_baseline": 1.0,
-            "grads_finite": finite,
-        }
-    finally:
-        var_registry.set("ops_flash_bwd_kernel", old)
+    fn = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))  # bind ONCE:
+    # a fresh jit wrapper per call would re-trace and the timed run
+    # would measure compilation, not the kernels
+    grads = fn(q, k, v)
+    jax.block_until_ready(grads)
+    t0 = time.perf_counter()
+    grads = fn(q, k, v)
+    jax.block_until_ready(grads)
+    dt = time.perf_counter() - t0
+    finite = all(bool(np.isfinite(np.asarray(
+        g, dtype=np.float32)).all()) for g in grads)
+    return {
+        "metric": f"flash bwd pallas kernels (seq {t})",
+        "value": round(dt * 1e3, 2), "unit": "ms", "vs_baseline": 1.0,
+        "grads_finite": finite,
+    }
 
 
 def matrix_tuned_crossovers(devices) -> dict:

@@ -8,10 +8,13 @@ examples/train.py builds it), at the full width of the flagship model
 (models/transformer.FLAGSHIP), through the entry points a user calls:
 
 - trainer: ``data.train_stream`` → ``make_train_step`` / ``make_train_loop``,
-  loss finite and falling; again with ``attention="flash"``, pallas backward
-  off and on, the loss curve equal to the XLA-attention one;
-- kernels: flash forward and both backwards against
-  ``local_attention(impl="jnp")``; ``DeviceCommunicator.put`` / ``get``;
+  loss finite and falling (1024 positions: the jnp path, by the rule); again
+  at 2048 positions and the same tokens a step, where the rule takes the
+  kernels: the step holds the three flash kernels and the rotary one, and
+  starts from the same loss;
+- kernels: flash forward and both backward kernels against
+  ``local_attention(impl="jnp")`` and its autodiff;
+  ``DeviceCommunicator.put`` / ``get``;
 - decoder: ``make_decoder`` answers two prompt batches, greedy output equal
   on a repeat, first token equal to the argmax of ``make_forward``;
 - MPI surface: ``ompi_tpu.init()``, ``bind_device(device_world())``,
@@ -31,10 +34,10 @@ The last line of stdout is ``{"ok": true, "device": {...}}``.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import json
+import re
 import sys
 import time
 
@@ -128,23 +131,17 @@ def _corpus(vocab: int) -> np.ndarray:
     return np.tile(pattern, 512).astype(np.int32)
 
 
-@contextlib.contextmanager
-def _flash_bwd_kernel(on: bool):
-    import ompi_tpu.ops.flash_attention  # noqa: F401 — registers the var
-    from ompi_tpu.core.config import var_registry
-
-    old = var_registry.get("ops_flash_bwd_kernel")
-    var_registry.set("ops_flash_bwd_kernel", on)
-    try:
-        yield
-    finally:
-        var_registry.set("ops_flash_bwd_kernel", old)
-
-
 def _kernels(jitted, *args) -> int:
     """How many compiled pallas kernels the program holds.  Interpret mode
     lowers a kernel to plain HLO and callbacks, never to tpu_custom_call."""
     return jitted.lower(*args).as_text().count("tpu_custom_call")
+
+
+def _kernel_names(jitted, *args) -> list[str]:
+    """The names of the compiled pallas kernels the program holds, one
+    entry a call."""
+    return re.findall(r'kernel_name = "(\w+)"',
+                      jitted.lower(*args).as_text())
 
 
 def _rel_err(got, ref) -> float:
@@ -210,38 +207,41 @@ def _on_every_device(tree, devices, what: str) -> None:
 # ---------------------------------------------------------------------------
 
 def phase_train(size: Size, devices, attention: str = "xla",
-                bwd_kernel: bool = False) -> dict:
-    """A few optimizer steps on fresh batches from ``train_stream``; with
-    XLA attention also a ``make_train_loop`` chain and, on several
-    devices, the loss against one device's and the state's placement."""
+                seq: int = 0) -> dict:
+    """A few optimizer steps on fresh batches from ``train_stream``; under
+    the flagship's own ``attention`` word also a ``make_train_loop`` chain
+    and, on several devices, the loss against one device's and the
+    state's placement.  ``seq`` trains the same weights at another length,
+    the same tokens a step (0 = the size's own)."""
     import jax
 
     from ompi_tpu.models import data
     from ompi_tpu.models import transformer as tfm
 
-    cfg = dataclasses.replace(size.cfg, attention=attention)
+    cfg = dataclasses.replace(size.cfg, attention=attention,
+                              seq=seq or size.cfg.seq)
+    batch_size = size.batch * size.cfg.seq // cfg.seq
     mesh = model_mesh(devices)
     params = tfm.shard_params(cfg, mesh, _host_params(size.cfg))
     step, init_opt = tfm.make_train_step(cfg, mesh, lr=1e-3)
     opt_state = init_opt(params)
     stream = data.train_stream(data.ArraySource(_corpus(cfg.vocab)), mesh,
-                               size.batch, cfg.seq)
+                               batch_size, cfg.seq)
     out: dict = {"losses": [], "step_s": []}
     try:
-        with _flash_bwd_kernel(bwd_kernel):
+        batch = next(stream)
+        out["kernels"] = _kernel_names(step, params, opt_state, batch)
+        programs = []       # compiled so far, after each step
+        for _ in range(size.steps):
+            (params, opt_state, loss), dt = _timed(
+                step, params, opt_state, batch)
+            out["losses"].append(float(loss))
+            out["step_s"].append(dt)
+            programs.append(compile_meter().programs)
             batch = next(stream)
-            out["kernels"] = _kernels(step, params, opt_state, batch)
-            programs = []       # compiled so far, after each step
-            for _ in range(size.steps):
-                (params, opt_state, loss), dt = _timed(
-                    step, params, opt_state, batch)
-                out["losses"].append(float(loss))
-                out["step_s"].append(dt)
-                programs.append(compile_meter().programs)
-                batch = next(stream)
-            _check(programs[-1] == programs[0],
-                   f"train[{attention}]: a step after the first compiled a "
-                   f"program: its inputs changed placement or shape")
+        _check(programs[-1] == programs[0],
+               f"train[{attention}]: a step after the first compiled a "
+               f"program: its inputs changed placement or shape")
         if attention == "xla":
             loop, _ = tfm.make_train_loop(cfg, mesh, lr=1e-3, steps=2)
             params, opt_state, chained = loop(params, opt_state, batch)
@@ -270,9 +270,9 @@ def phase_train(size: Size, devices, attention: str = "xla",
 
 
 def phase_flash(size: Size, devices) -> dict:
-    """Flash forward and both backwards (XLA recompute, pallas kernels)
-    against the jnp path, at the model's attention shape, batch rows
-    spread over the devices."""
+    """Flash forward and both backward kernels against the jnp path and its
+    autodiff, at the model's attention shape, batch rows spread over the
+    devices."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
@@ -295,17 +295,13 @@ def phase_flash(size: Size, devices) -> dict:
             mesh, P("world"))
 
     ref = out_and_grads("jnp")(q, k, v, g)
-    out: dict = {}
-    for name, on in (("xla_bwd", False), ("pallas_bwd", True)):
-        with _flash_bwd_kernel(on):
-            fn = out_and_grads("flash")
-            kernels = _kernels(fn, q, k, v, g)
-            got, run_s = _warm_timed(fn, q, k, v, g)
-        errs = [_rel_err(a, b) for a, b in zip(got, ref)]
-        _check(max(errs) < BF16_TOL,
-               f"flash[{name}] out/dq/dk/dv error {errs} at {shape}")
-        out[name] = {"kernels": kernels, "errs": errs, "run_s": run_s}
-    return out
+    fn = out_and_grads("flash")
+    kernels = _kernels(fn, q, k, v, g)
+    got, run_s = _warm_timed(fn, q, k, v, g)
+    errs = [_rel_err(a, b) for a, b in zip(got, ref)]
+    _check(max(errs) < BF16_TOL,
+           f"flash out/dq/dk/dv error {errs} at {shape}")
+    return {"kernels": kernels, "errs": errs, "run_s": run_s}
 
 
 def phase_decode(size: Size, devices) -> dict:
@@ -424,9 +420,11 @@ def phase_dma(size: Size, devices) -> dict:
     return {"kernels": kernels, "run_s": run_s, "src": src, "dst": dst}
 
 
-def phase_ring(size: Size, devices) -> dict:
+def phase_ring(size: Size, devices, impl: str = "auto") -> dict:
     """One ring-attention step over sp = every device, forward and
-    backward, against attention over the gathered K/V on the jnp path."""
+    backward, against attention over the gathered K/V on the jnp path.
+    ``impl`` as ``ring_attention`` takes it: on the chip the run asks for
+    the kernels, whose k_offset is then a traced hop index."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -456,7 +454,8 @@ def phase_ring(size: Size, devices) -> dict:
                                     impl="jnp")
 
     ring = _out_and_grads(
-        lambda q, k, v: attn.ring_attention(comm, q, k, v, axis="sp"),
+        lambda q, k, v: attn.ring_attention(comm, q, k, v, axis="sp",
+                                            impl=impl),
         mesh, seq)
     kernels = _kernels(ring, q, k, v, g)
     got, run_s = _warm_timed(ring, q, k, v, g)
@@ -464,7 +463,10 @@ def phase_ring(size: Size, devices) -> dict:
     errs = [_rel_err(a, b) for a, b in zip(got, ref)]
     _check(max(errs) < BF16_TOL,
            f"ring sp={n} out/dq/dk/dv error {errs} at {shape}")
-    return {"kernels": kernels, "impl": attn.resolve_impl("auto", t, t),
+    hop = (shape[0], t, *shape[2:])     # what one device holds
+    return {"kernels": kernels,
+            "impl": attn.local_impl(impl, hop, hop, q.dtype,
+                                    devices[0].platform),
             "errs": errs, "run_s": run_s, "sp": n}
 
 
@@ -503,26 +505,34 @@ def main() -> int:
               f"{json.dumps(out)}", flush=True)
         return out
 
-    xla = run("train xla", phase_train)
-    compiled = {}
-    for name, on in (("train flash", False), ("train flash+pallas-bwd", True)):
-        t = run(name, phase_train, attention="flash", bwd_kernel=on)
-        compiled[name] = t["kernels"]
-        same = xla["losses"][:FULL.steps]
-        _check(np.allclose(t["losses"], same, rtol=LOSS_RTOL),
-               f"{name}: losses {t['losses']} vs XLA attention {same}")
+    train = run("train", phase_train)
+    # the flagship's sequence is under the rule's 2048 keys: the jnp path
+    _check(not train["kernels"], f"train holds {train['kernels']}")
+    # twice the positions, the same tokens a step: the rule takes the
+    # kernels, reached as a user reaches them, by shape.  The trainer, the
+    # three kernels, the rotary kernel and the checkpoint policy that keeps
+    # the forward kernel's results, together: the step holds all four and
+    # starts from the loss the jnp path starts from (the same weights; at
+    # random weights the loss does not know the length).  phase_flash holds
+    # the kernels to the jnp path value for value.
+    kern = run("train 2k", phase_train, attention="ulysses",
+               seq=2 * FULL.cfg.seq)
+    want = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "rope"}
+    _check(want <= set(kern["kernels"]),
+           f"train 2k: the step holds {kern['kernels']}, not all of {want}")
+    _check(np.isclose(kern["losses"][0], train["losses"][0], rtol=LOSS_RTOL),
+           f"train 2k: first loss {kern['losses'][0]} vs "
+           f"{train['losses'][0]} at {FULL.cfg.seq} positions")
     flash = run("flash kernels", phase_flash)
-    compiled["flash fwd"] = flash["xla_bwd"]["kernels"]
-    compiled["flash bwd"] = (flash["pallas_bwd"]["kernels"]
-                             - flash["xla_bwd"]["kernels"])
+    compiled = {"train 2k": len(kern["kernels"]),
+                "flash fwd, dq, dkv": flash["kernels"]}
     run("decode", phase_decode)
     run("mpi collectives", phase_mpi)
     compiled["put/get"] = run("put/get", phase_dma)["kernels"]
     if len(devices) > 1:
-        ring = run("ring attention", phase_ring)
-        _check(ring["impl"] == "flash", f"ring ran the {ring['impl']} path")
+        ring = run("ring attention", phase_ring, impl="flash")
         compiled["ring attention"] = ring["kernels"]
-        in_use = xla["bytes_in_use"]
+        in_use = train["bytes_in_use"]
         _check(max(in_use) < 4 * min(in_use),
                f"trained state is not spread evenly: bytes_in_use {in_use}")
         print(f"peak_bytes_in_use "

@@ -34,10 +34,13 @@ class TransformerConfig:
     d_ff: int = 2048
     seq: int = 512
     attention: str = "ring"  # ring | ulysses | flash | xla | gathered
-    # ("flash" = ulysses resharding + the pallas flash kernel for the
-    # local attention — offsets are static there, so the kernel applies;
-    # "xla" = the same ulysses resharding but the jnp/XLA local attention,
-    # the pallas-vs-XLA ablation pair for "flash")
+    # The word selects a layout over "sp", never an implementation: "flash",
+    # "xla" and "ulysses" all mean the ulysses layout (they are kept because
+    # configuration files and tests spell them), and the local attention of
+    # every layout is parallel/attention.local_impl("auto")'s choice from
+    # shape and the mesh's platform (the pallas flash kernels, forward and
+    # backward, on a mesh of TPUs from 2048 keys a device; the jnp path
+    # elsewhere).
     # MoE model family: >0 replaces every layer's dense FFN with a
     # switch-MoE of this many experts, sharded over the mesh's "ep" axis
     # (experts % ep == 0); the load-balancing aux loss joins the training
@@ -255,8 +258,17 @@ def _head(cfg: TransformerConfig, params):
     return params["emb"] if cfg.tie_head else params["head"]
 
 
-def _rope(x, positions):
-    """Rotary embeddings with *global* positions (sp-offset aware)."""
+# TransformerConfig.attention's words, as layouts of parallel/attention
+_ATTENTION_LAYOUT = {"ring": "ring", "ulysses": "ulysses", "flash": "ulysses",
+                     "xla": "ulysses", "gathered": "gathered"}
+
+
+def _rope(x, positions, impl: str = "jnp"):
+    """Rotary embeddings with *global* positions (sp-offset aware).
+    ``impl`` is the local attention's that reads the result
+    (``parallel/attention.layout_impl``): for "flash" the pallas form where
+    it tiles (``ops/rope.py``: the same sums on row-major (B, T, H·D),
+    which is what the flash kernels read)."""
     import jax.numpy as jnp
 
     B, T, H, D = x.shape
@@ -264,6 +276,11 @@ def _rope(x, positions):
     freqs = 1.0 / (10_000 ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = positions[:, None].astype(jnp.float32) * freqs[None, :]  # (T, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if impl == "flash":
+        from ompi_tpu.ops import rope as rope_kernel
+
+        if rope_kernel.rope_tiles(T, H, D, x.dtype):
+            return rope_kernel.rope(x, cos, sin)
     x1, x2 = x[..., :half], x[..., half:]
     rot = jnp.concatenate([x1 * cos[None, :, None] - x2 * sin[None, :, None],
                            x1 * sin[None, :, None] + x2 * cos[None, :, None]],
@@ -349,6 +366,12 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     T = tokens.shape[1]
     sp_idx = lax.axis_index("sp")
     positions = sp_idx * T + jnp.arange(T)
+    # one choice of local attention for the layer, made where the layouts
+    # make theirs; the rotary embedding writes what that attention reads
+    layout = _ATTENTION_LAYOUT.get(cfg.attention, "gathered")
+    attend = getattr(attn_mod, layout + "_attention")
+    shape = (tokens.shape[0], T, h_local, hd)
+    impl = attn_mod.layout_impl(comm, layout, shape, shape, cdt, "sp")
 
     with scope("embed"):
         h = params["emb"][tokens].astype(cdt)  # (b, t, D)
@@ -363,22 +386,11 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
                 q = _qk_norm(cfg, q, lp["qn"], comm)
                 k = _qk_norm(cfg, k, lp["kn"], comm)
             B, t = x.shape[0], x.shape[1]
-            q = _rope(q.reshape(B, t, h_local, hd), positions)
-            k = _rope(k.reshape(B, t, h_local, hd), positions)
+            q = _rope(q.reshape(B, t, h_local, hd), positions, impl)
+            k = _rope(k.reshape(B, t, h_local, hd), positions, impl)
             v = v.reshape(B, t, h_local, hd)
         with scope("attention"):
-            if cfg.attention == "ring":
-                o = attn_mod.ring_attention(comm, q, k, v, axis="sp")
-            elif cfg.attention == "ulysses":
-                o = attn_mod.ulysses_attention(comm, q, k, v, axis="sp")
-            elif cfg.attention == "flash":
-                o = attn_mod.ulysses_attention(comm, q, k, v, axis="sp",
-                                               impl="flash")
-            elif cfg.attention == "xla":
-                o = attn_mod.ulysses_attention(comm, q, k, v, axis="sp",
-                                               impl="jnp")
-            else:
-                o = attn_mod.gathered_attention(comm, q, k, v, axis="sp")
+            o = attend(comm, q, k, v, axis="sp", impl=impl)
         with scope("attn_proj"):
             o = o.reshape(B, t, h_local * hd)
             h = h + row_parallel(o, lp["wo"].astype(cdt), comm, axis="tp")
@@ -398,8 +410,14 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     if cfg.remat in (True, "full"):
         layer_fn = jax.checkpoint(layer)
     elif cfg.remat == "dots":
-        layer_fn = jax.checkpoint(
-            layer, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        # a pallas_call's result is no saveable dot: the flash kernel's out
+        # and lse are kept by name, or its forward would run twice
+        from ompi_tpu.ops.flash_attention import RESIDUAL_NAMES
+
+        policies = jax.checkpoint_policies
+        layer_fn = jax.checkpoint(layer, policy=policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable,
+            policies.save_only_these_names(*RESIDUAL_NAMES)))
     else:
         layer_fn = layer
     with scope("layers"):

@@ -5,7 +5,7 @@ library); the TPU analog of "hand-tuned native hot path" is a pallas
 kernel feeding the MXU directly from VMEM.  A kernel compiles for the TPU
 or fails: nothing here chooses interpret mode or another path on its own.
 Callers that want the pure-XLA form ask for it by name
-(``parallel.attention.resolve_impl``).
+(``parallel.attention.local_impl``).
 """
 
 from ompi_tpu.ops.flash_attention import flash_attention, flash_attention_lse

@@ -23,54 +23,84 @@ from __future__ import annotations
 from typing import Optional
 
 __all__ = ["local_attention", "local_attention_lse", "ring_attention",
-           "ulysses_attention", "gathered_attention", "resolve_impl"]
+           "ulysses_attention", "gathered_attention", "local_impl",
+           "layout_impl"]
 
 _NEG = -1e30
 
 
-def _flash_blocks() -> tuple[int, int]:
-    """The ops_flash_block_q/k tuning vars; non-positive values raise."""
-    import ompi_tpu.ops.flash_attention  # noqa: F401 — registers the vars
-    from ompi_tpu.core.config import var_registry
+# Keys one device attends over from which "auto" takes the kernels.  Not the
+# length from which the kernels are faster: on a v5e they beat the jnp path
+# from 256 positions standalone, and a train step of cell 1's model at the
+# same tokens takes 358.6 against 452.8 ms at 16 x 1024 and 352.6 against
+# 405.4 at 32 x 512 (at 8 x 2048: 369.3 against 611.5; PERF.md section 6,
+# PR 28, chip call 26).  It is the length under which the one forward-only
+# program the records hold, the decoders' 1024-token prefill, gains 1 ms a
+# layer, while a process's first pallas kernel costs it 0.9 s of start-up,
+# 0.75 s of that the import of jax.experimental.pallas (ledger, PR 27: cell
+# 2's ttft_ms -6 ms, its setup_s +1.16 s against a bound of 0.466 s).  A
+# length cannot tell a trainer from a prefill, so a trainer under 2048
+# positions stays on the jnp path too: PERF.md section 7 has what that costs.
+_FLASH_FROM_KEYS = 2048
 
-    bq = int(var_registry.get("ops_flash_block_q"))
-    bk = int(var_registry.get("ops_flash_block_k"))
-    if bq <= 0 or bk <= 0:
-        raise ValueError(
-            f"ops_flash_block_q/k must be positive, got ({bq}, {bk})")
-    return bq, bk
+# Head widths the kernels have run at on the chip: a head of 128 lanes is a
+# column block of (B, T, H*D); narrower ones are transposed through HBM.
+_FLASH_MAX_HEAD_DIM = 128
 
 
-def resolve_impl(impl: str, t_q: int, t_k: int) -> str:
-    """Which local attention runs for this shape: ``"flash"`` or ``"jnp"``.
+def local_impl(impl: str, q_shape, k_shape, dtype,
+               platform: Optional[str] = None) -> str:
+    """Which local attention runs for q (B, Tq, H, D) over k (B, Tk, H, D)
+    of ``dtype`` on one device: ``"flash"`` or ``"jnp"``.
 
-    "jnp" and "flash" are taken at their word ("flash" raises when the
-    configured blocks do not tile the shape).  "auto" picks the pallas
-    kernel on a TPU backend when the shape tiles, and the jnp path on any
-    other backend (the kernel only compiles for the TPU) or when no block
-    setting could tile the shape.  Configured blocks that break a shape
-    the 128 default would tile are an error, not a reason to switch
-    paths.  Callers that must know which path ran (chip_smoke.py) ask
-    here instead of re-deriving the rule.
+    "jnp" and "flash" are taken at their word (the kernels raise for lengths
+    no block tiles).  "auto" is the rule, from shape and platform alone:
+    the pallas kernels, forward and backward, where the devices are TPUs,
+    the keys are ``_FLASH_FROM_KEYS`` or more, the lengths tile, the head is
+    no wider than ``_FLASH_MAX_HEAD_DIM`` and a sequence's K and V (in the
+    backward Q and dO) fit the kernels' whole-sequence VMEM block; the jnp
+    path otherwise.  ``platform`` is that of the devices the program is
+    built for (a mesh's, attached or only described: the layouts pass their
+    communicator's); None asks the default backend.  Callers that must know
+    which path ran (chip_smoke.py) ask here instead of re-deriving the rule.
     """
     import jax
 
-    from ompi_tpu.ops.flash_attention import flash_tiles
+    from ompi_tpu.ops.flash_attention import flash_tiles, whole_seq_fits
 
     if impl == "jnp":
         return "jnp"
     if impl not in ("auto", "flash"):
         raise ValueError(f"unknown attention impl {impl!r}")
-    if impl == "auto" and jax.default_backend() != "tpu":
-        return "jnp"
-    bq, bk = _flash_blocks()
-    if flash_tiles(t_q, t_k, bq, bk):
+    if impl == "flash":
         return "flash"
-    if impl == "flash" or flash_tiles(t_q, t_k):
-        raise ValueError(
-            f"flash attention: sequence lengths ({t_q}, {t_k}) do not "
-            f"tile by ops_flash_block_q/k = ({bq}, {bk})")
-    return "jnp"
+    (_, t_q, _, d), t_k = q_shape, k_shape[1]
+    takes = ((platform or jax.default_backend()) == "tpu"
+             and t_k >= _FLASH_FROM_KEYS and flash_tiles(t_q, t_k)
+             and d <= _FLASH_MAX_HEAD_DIM
+             and whole_seq_fits(max(t_q, t_k), d, dtype))
+    return "flash" if takes else "jnp"
+
+
+def layout_impl(comm, layout: str, q_shape, k_shape, dtype,
+                axis: Optional[str] = None, impl: str = "auto") -> str:
+    """:func:`local_impl` for what one device attends over when
+    ``<layout>_attention`` is given q and k of these shapes a device, on the
+    platform of the devices ``comm``'s mesh is made of: a ring hop's own
+    pieces, all positions of its share of the heads after the ulysses
+    re-shard, its own queries against all keys when gathered.  The three
+    layouts ask here, and so does a caller that prepares their operands
+    (the model's rotary embedding), so the rule exists once."""
+    sp = int(comm.mesh.shape[axis or comm.axes[-1]])
+    (b, t_q, h, d), t_k = q_shape, k_shape[1]
+    if layout == "ulysses":
+        q_shape, k_shape = (b, t_q * sp, h // sp, d), (b, t_k * sp, h // sp, d)
+    elif layout == "gathered":
+        k_shape = (b, t_k * sp, h, d)
+    elif layout != "ring":
+        raise ValueError(f"unknown attention layout {layout!r}")
+    return local_impl(impl, q_shape, k_shape, dtype,
+                      comm.mesh.devices.flat[0].platform)
 
 
 def local_attention(q, k, v, causal: bool = True,
@@ -83,7 +113,7 @@ def local_attention(q, k, v, causal: bool = True,
     Shapes: q (B, Tq, H, D), k/v (B, Tk, H, D) → (B, Tq, H, D).
 
     ``impl``: "flash" = the pallas blockwise kernel (ompi_tpu.ops),
-    "jnp" = materialized scores, "auto" = see :func:`resolve_impl`.
+    "jnp" = materialized scores, "auto" = see :func:`local_impl`.
     """
     o, _ = local_attention_lse(q, k, v, causal=causal, q_offset=q_offset,
                                k_offset=k_offset, scale=scale, impl=impl)
@@ -99,15 +129,14 @@ def local_attention_lse(q, k, v, causal: bool = True,
     import jax.numpy as jnp
 
     scale = scale if scale is not None else q.shape[-1] ** -0.5
-    if resolve_impl(impl, q.shape[1], k.shape[1]) == "flash":
+    if local_impl(impl, q.shape, k.shape, q.dtype) == "flash":
         from ompi_tpu.core.scopes import scope
         from ompi_tpu.ops.flash_attention import flash_attention_lse
 
-        bq, bk = _flash_blocks()
         with scope("attention.flash"):
             return flash_attention_lse(q, k, v, causal=causal,
                                        q_offset=q_offset, k_offset=k_offset,
-                                       scale=scale, block_q=bq, block_k=bk)
+                                       scale=scale)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
@@ -148,11 +177,12 @@ def ring_attention(comm, q, k, v, axis: Optional[str] = None,
 
     ax = axis or comm.axes[-1]
     sp = int(comm.mesh.shape[ax])
+    B, T, H, D = q.shape
+    impl = layout_impl(comm, "ring", q.shape, k.shape, q.dtype, ax, impl)
     if sp == 1:  # degenerate ring: skip the loop machinery entirely
         return local_attention(q, k, v, causal=causal, scale=scale,
                                impl=impl)
     my = lax.axis_index(ax)
-    B, T, H, D = q.shape
     scale = scale if scale is not None else D ** -0.5
     perm = [(i, (i + 1) % sp) for i in range(sp)]
 
@@ -186,8 +216,8 @@ def ulysses_attention(comm, q, k, v, axis: Optional[str] = None,
                       impl: str = "auto"):
     """All-to-all sequence parallelism: re-shard seq→heads, attend fully
     locally, re-shard back.  Exact; one alltoall each way.  The local
-    attention runs the pallas flash kernel with ``impl='flash'`` (static
-    offsets by construction — the canonical place to use it)."""
+    attention is :func:`local_attention`'s, ``impl`` resolved for the
+    platform of ``comm``'s mesh."""
     from jax import lax
 
     from ompi_tpu.core.scopes import coll, scope
@@ -197,6 +227,7 @@ def ulysses_attention(comm, q, k, v, axis: Optional[str] = None,
     H = q.shape[2]
     if H % sp:
         raise ValueError(f"ulysses needs heads ({H}) divisible by sp ({sp})")
+    impl = layout_impl(comm, "ulysses", q.shape, k.shape, q.dtype, ax, impl)
     if sp == 1:
         # degenerate axis: a single-participant all_to_all still lowers
         # to a channel op (copy + scheduling barrier, 4 per layer) —
@@ -217,21 +248,27 @@ def ulysses_attention(comm, q, k, v, axis: Optional[str] = None,
 
 
 def gathered_attention(comm, q, k, v, axis: Optional[str] = None,
-                       causal: bool = True, scale: Optional[float] = None):
+                       causal: bool = True, scale: Optional[float] = None,
+                       impl: str = "auto"):
     """Reference implementation: allgather K/V and attend (O(T) memory per
     device — the thing ring attention exists to avoid). Used for testing."""
     import jax.numpy as jnp
     from jax import lax
 
     ax = axis or comm.axes[-1]
-    if int(comm.mesh.shape[ax]) == 1:
-        return local_attention(q, k, v, causal=causal, scale=scale)
+    sp = int(comm.mesh.shape[ax])
+    T = q.shape[1]
+    impl = layout_impl(comm, "gathered", q.shape, k.shape, q.dtype, ax,
+                       impl)
+    if sp == 1:
+        return local_attention(q, k, v, causal=causal, scale=scale,
+                               impl=impl)
     from ompi_tpu.core.scopes import coll
 
     my = lax.axis_index(ax)
-    T = q.shape[1]
     with coll("allgather", ax):
         k_all = lax.all_gather(k, ax, axis=1, tiled=True)
         v_all = lax.all_gather(v, ax, axis=1, tiled=True)
     return local_attention(q, k_all, v_all, causal=causal,
-                           q_offset=my * T, k_offset=0, scale=scale)
+                           q_offset=my * T, k_offset=0, scale=scale,
+                           impl=impl)

@@ -38,7 +38,6 @@ _REGISTERING_MODULES = [
     "ompi_tpu.core.memchecker",
     "ompi_tpu.parallel.multihost",
     "ompi_tpu.shmem.api",
-    "ompi_tpu.ops.flash_attention",   # ops_flash_* kernel tuning vars
 ]
 
 

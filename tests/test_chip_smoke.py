@@ -47,7 +47,7 @@ def xla_on_four():
 def test_train_phase_on_one_device():
     out = chip_smoke.phase_train(TINY, jax.devices()[:1])
     assert len(out["losses"]) == TINY.steps + 2      # steps, then the loop
-    assert out["kernels"] == 0 and "loss_all_vs_one" not in out
+    assert out["kernels"] == [] and "loss_all_vs_one" not in out
 
 
 def test_train_phase_on_four_devices(xla_on_four):
@@ -56,19 +56,29 @@ def test_train_phase_on_four_devices(xla_on_four):
     assert xla_on_four["bytes_in_use"] == [None] * 4   # CPU: no stats
 
 
-@pytest.mark.parametrize("bwd_kernel", [False, True])
-def test_train_phase_flash_matches_xla(xla_on_four, bwd_kernel):
-    flash = chip_smoke.phase_train(TINY, jax.devices()[:4],
-                                   attention="flash", bwd_kernel=bwd_kernel)
-    np.testing.assert_allclose(flash["losses"],
-                               xla_on_four["losses"][:TINY.steps],
-                               rtol=chip_smoke.LOSS_RTOL)
+@pytest.mark.parametrize("word", ["flash", "ulysses"])
+def test_train_phase_words_select_no_implementation(xla_on_four, word):
+    """"flash" and "xla" mean what "ulysses" means: a layout over sp.  The
+    local attention is chosen from shape and the mesh's platform, so on the
+    CPU mesh every word runs the one program, kernel-free."""
+    other = chip_smoke.phase_train(TINY, jax.devices()[:4], attention=word)
+    assert other["kernels"] == []
+    assert other["losses"] == xla_on_four["losses"][:TINY.steps]
+
+
+@pytest.mark.parametrize("seq", [32, 128])
+def test_train_phase_takes_another_length_at_the_same_tokens(seq):
+    """On the chip the smoke trains once more at twice the flagship's
+    positions, where the rule takes the kernels; here, on the CPU mesh, any
+    length stays kernel-free."""
+    out = chip_smoke.phase_train(TINY, jax.devices()[:1],
+                                 attention="ulysses", seq=seq)
+    assert out["kernels"] == [] and len(out["losses"]) == TINY.steps
 
 
 def test_flash_phase(devices):
     out = chip_smoke.phase_flash(TINY, devices)
-    for name in ("xla_bwd", "pallas_bwd"):
-        assert max(out[name]["errs"]) < chip_smoke.BF16_TOL
+    assert max(out["errs"]) < chip_smoke.BF16_TOL
 
 
 def test_decode_phase(devices):
@@ -88,6 +98,7 @@ def test_dma_phase(devices):
 def test_ring_phase():
     out = chip_smoke.phase_ring(TINY, jax.devices()[:4])
     assert out["sp"] == 4 and out["impl"] == "jnp"   # auto, off the TPU
+    assert out["kernels"] == 0
 
 
 def _run_on_cpu(script: str):
@@ -180,21 +191,3 @@ def test_flash_refuses_a_sequence_the_compiler_would():
     jax.eval_shape(flash_attention, ok, ok, ok)
     with pytest.raises(ValueError, match="holds it in VMEM as one block"):
         jax.eval_shape(flash_attention, big, big, big)
-
-
-def test_bad_flash_block_raises():
-    from ompi_tpu.core.config import var_registry
-    from ompi_tpu.parallel.attention import resolve_impl
-
-    assert resolve_impl("jnp", 384, 384) == "jnp"
-    assert resolve_impl("flash", 384, 384) == "flash"
-    assert resolve_impl("auto", 200, 200) == "jnp"    # off the TPU
-    var_registry.set("ops_flash_block_q", 256)
-    try:
-        with pytest.raises(ValueError, match="do not tile"):
-            resolve_impl("flash", 384, 384)           # 384 % 256 != 0
-        var_registry.set("ops_flash_block_q", 0)
-        with pytest.raises(ValueError, match="must be positive"):
-            resolve_impl("flash", 384, 384)
-    finally:
-        var_registry.set("ops_flash_block_q", 128)

@@ -39,12 +39,6 @@ mesh = make_mesh({{"dp": 1, "sp": 1, "tp": 1}}, devices=devices[:1])
 batch = cfg.pop("batch")
 chain = cfg.pop("chain", 8)
 outer = cfg.pop("outer", 2)
-mca = cfg.pop("_mca", None)
-if mca:
-    import ompi_tpu.ops.flash_attention  # registers the ops_* vars
-    from ompi_tpu.core.config import var_registry
-    for k, v in mca.items():
-        var_registry.set(k, v)
 model = dataclasses.replace(FLAGSHIP, **cfg)
 rng = np.random.default_rng(0)
 tokens = rng.integers(0, model.vocab, size=(batch, model.seq)).astype(np.int32)
@@ -144,11 +138,6 @@ GRID = [
      1800),
     ("b16-full-dots", {"batch": 16, "ce_chunk": 0, "remat": "dots",
                        "attention": "flash"}, 1500),
-    # pallas BACKWARD kernels too (opt-in flag; fwd-only kernel's bwd
-    # otherwise recomputes O(T²) scores through XLA)
-    ("b16-chunk128-dots-pbwd", {"batch": 16, "ce_chunk": 128,
-                                "remat": "dots", "attention": "flash",
-                                "_mca": {"ops_flash_bwd_kernel": 1}}, 1800),
     # long chain amortizes the per-dispatch cost (the matmul_peak row
     # measures it) — the steady-state number
     ("b16-chunk128-dots-chain32", {"batch": 16, "ce_chunk": 128,
@@ -210,17 +199,6 @@ GRID = [
                                 "ce_chunk": 256, "remat": "dots",
                                 "attention": "xla",
                                 "chain": 16, "outer": 1}, 1800),
-    # flash kernel block-size tuning at seq 1024 (the kernel lost to
-    # XLA attention at the 128x128 default; bigger k-streaming blocks
-    # raise arithmetic intensity per grid cell)
-    ("b16-flash-bq256", {"batch": 16, "ce_chunk": 256, "remat": "dots",
-                         "attention": "flash", "chain": 16, "outer": 1,
-                         "_mca": {"ops_flash_block_q": 256,
-                                  "ops_flash_block_k": 256}}, 1800),
-    ("b16-flash-bk512", {"batch": 16, "ce_chunk": 256, "remat": "dots",
-                         "attention": "flash", "chain": 16, "outer": 1,
-                         "_mca": {"ops_flash_block_q": 128,
-                                  "ops_flash_block_k": 512}}, 1800),
     # longer sequence at constant tokens/step: attention FLOPs per token
     # double (12·L·D·S) while weight-read overhead stays flat, so MFU
     # usually rises IF the attention backward fits; flash may retake the
