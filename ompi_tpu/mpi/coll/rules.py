@@ -33,12 +33,8 @@ SHM_ALLREDUCE_ALGORITHMS = ("root_fold", "segment_parallel")
 
 
 class RuleSet:
-    def __init__(self, rules: list[tuple[str, int, int, str]],
-                 meta: Optional[dict] = None) -> None:
+    def __init__(self, rules: list[tuple[str, int, int, str]]) -> None:
         # rules: (collective, comm_size_min, msg_bytes_min, algorithm)
-        # meta: provenance from "#!" lines (platform=…, n_devices=…) —
-        # lets a consumer refuse rules measured on a different backend
-        self.meta: dict[str, str] = meta or {}
         self._by_coll: dict[str, list[tuple[int, int, str]]] = {}
         for coll, cmin, mmin, alg in rules:
             self._by_coll.setdefault(coll, []).append((cmin, mmin, alg))
@@ -60,14 +56,7 @@ class RuleSet:
 
 def parse(text: str, source: str = "<string>") -> RuleSet:
     rules = []
-    meta: dict[str, str] = {}
     for lineno, line in enumerate(text.splitlines(), 1):
-        if line.startswith("#!"):  # provenance: "#! key=value"
-            body = line[2:].strip()
-            if "=" in body:
-                k, v = body.split("=", 1)
-                meta[k.strip()] = v.strip()
-            continue
         line = line.split("#", 1)[0].strip()
         if not line:
             continue
@@ -86,7 +75,7 @@ def parse(text: str, source: str = "<string>") -> RuleSet:
             from ompi_tpu.mpi.constants import MPIException
 
             raise MPIException(f"{source}:{lineno}: {e}") from e
-    return RuleSet(rules, meta)
+    return RuleSet(rules)
 
 
 _cache: dict[str, tuple[float, RuleSet]] = {}

@@ -306,161 +306,6 @@ class DeviceCommunicator:
             run = op.device(run, stacked[r])
         return jnp.stack(outs)[self.rank()]
 
-    # -- alternative algorithm implementations (the decision layer's menu) -
-
-    def allreduce_rs_ag(self, x, op: Op = SUM, axis: Optional[int] = None):
-        """Bandwidth-optimal 2-phase allreduce: reduce_scatter then
-        all_gather (≈ the reference's ring allreduce,
-        coll_base_allreduce.c:339 — same 2·(n-1)/n bytes on the wire,
-        expressed as the two XLA collectives so ICI runs both phases).
-        Scatters along ``axis`` (default: first n-divisible dim; falls back
-        to plain psum when no dim divides — shapes are static, so the
-        choice compiles away)."""
-        from jax import lax
-
-        if op is not SUM:
-            return self.allreduce(x, op)
-        n = self.size
-        if axis is None:
-            axis = next((i for i, d in enumerate(x.shape) if d % n == 0),
-                        None)
-            if axis is None:
-                return self.allreduce(x, op)
-        scattered = lax.psum_scatter(x, self._ax, scatter_dimension=axis,
-                                     tiled=True)
-        return lax.all_gather(scattered, self._ax, axis=axis, tiled=True)
-
-    def allreduce_qint8(self, x, op: Op = SUM, block: int = 256):
-        """Quantized 2-phase allreduce (≈ EQuARX, arxiv 2506.17615):
-        int8 payloads with per-block f32 scales cut wire bytes ~4×.
-
-        Phase 1 is the reduce-scatter expressed as an all_to_all of
-        QUANTIZED chunks — each device dequantizes the n pieces of its
-        chunk locally and sums in f32 (int8 representations under
-        different scales cannot be summed on the wire).  Phase 2
-        re-quantizes the reduced chunk and all_gathers it.  LOSSY
-        (~0.2-0.5% rms for gradient-like data): never auto-selected —
-        opt-in via ``--mca coll xla_allreduce_algorithm qint8``.
-        """
-        import jax.numpy as jnp
-        from jax import lax
-
-        if op is not SUM:
-            return self.allreduce(x, op)
-        n = self.size
-        flat = x.reshape(-1)
-        unit = n * block
-        padded = -(-flat.shape[0] // unit) * unit
-        if padded != flat.shape[0]:
-            flat = jnp.pad(flat, (0, padded - flat.shape[0]))
-        chunk = padded // n                       # my phase-1 ownership
-
-        def quant(v):                             # (..., block) blocks
-            b = v.reshape(*v.shape[:-1], v.shape[-1] // block, block)
-            b32 = b.astype(jnp.float32)
-            scale = jnp.max(jnp.abs(b32), axis=-1, keepdims=True) / 127.0
-            scale = jnp.where(scale == 0, 1.0, scale)
-            q = jnp.clip(jnp.round(b32 / scale), -127, 127).astype(jnp.int8)
-            return q, scale
-
-        def dequant(q, scale):
-            return (q.astype(jnp.float32) * scale).reshape(
-                *q.shape[:-2], q.shape[-2] * block)
-
-        # phase 1: quantized chunks to their owners, local dequant-sum
-        q, s = quant(flat.reshape(n, chunk))
-        q = lax.all_to_all(q, self._ax, split_axis=0, concat_axis=0,
-                           tiled=False)
-        s = lax.all_to_all(s, self._ax, split_axis=0, concat_axis=0,
-                           tiled=False)
-        reduced = dequant(q, s).sum(axis=0)       # (chunk,) f32
-        # phase 2: re-quantize the reduced chunk, gather everywhere
-        q2, s2 = quant(reduced)
-        q2 = lax.all_gather(q2, self._ax, axis=0, tiled=False)
-        s2 = lax.all_gather(s2, self._ax, axis=0, tiled=False)
-        out = dequant(q2, s2).reshape(-1)[: x.size]
-        return out.reshape(x.shape).astype(x.dtype)
-
-    def allreduce_segmented(self, x, op: Op = SUM,
-                            segment_elems: int = 1 << 20):
-        """Segmented 2-phase allreduce (≈ the reference's segmented ring,
-        coll_base_allreduce.c:615): the buffer is processed in fixed
-        segments via lax.scan, bounding the per-step collective working
-        set — the form very large buffers want when a monolithic psum
-        would stage the whole array through collective scratch."""
-        import jax.numpy as jnp
-
-        from jax import lax
-
-        if op is not SUM:
-            return self.allreduce(x, op)
-        n = self.size
-        flat = x.reshape(-1)
-        seg = max(n, min(segment_elems, flat.shape[0]))
-        seg -= seg % n                       # scatter needs n-divisible
-        if seg <= 0 or flat.shape[0] <= seg:
-            return self.allreduce_rs_ag(x, op)
-        nseg, rem = divmod(flat.shape[0], seg)
-        head, tail = flat[: nseg * seg], flat[nseg * seg:]
-
-        def step(_, chunk):
-            scattered = lax.psum_scatter(chunk, self._ax,
-                                         scatter_dimension=0, tiled=True)
-            return None, lax.all_gather(scattered, self._ax, axis=0,
-                                        tiled=True)
-
-        _, out = lax.scan(step, None, head.reshape(nseg, seg))
-        parts = [out.reshape(-1)]
-        if rem:
-            parts.append(lax.psum(tail, self._ax))
-        return jnp.concatenate(parts).reshape(x.shape)
-
-    def allgather_ring(self, x, axis: int = 0):
-        """Explicit ring allgather over ppermute hops (≈
-        coll_base_allgather.c:364).  n-1 neighbor hops; each hop moves 1/n
-        of the result — the shape DCN-spanning axes prefer (one peer at a
-        time) over the all-to-one fan-in XLA may pick for all_gather."""
-        import jax.numpy as jnp
-
-        from jax import lax
-
-        n = self.size
-        ax = self._ax
-        if isinstance(ax, tuple):  # ring over the flattened multi-axis
-            return self.allgather(x, axis=axis)  # fall back to native
-        perm = [(i, (i + 1) % n) for i in range(n)]
-        blocks = [x]
-        cur = x
-        for _ in range(n - 1):
-            cur = lax.ppermute(cur, ax, perm)
-            blocks.append(cur)
-        # blocks[j] is the block of rank (my - j) mod n, so rank p's block
-        # sits at index (my - p) mod n — the permutation is self-inverse
-        my = self.rank()
-        stacked = jnp.stack(blocks)                    # (n, ...)
-        ordered = stacked[(my - jnp.arange(n)) % n]    # rank-ordered blocks
-        return jnp.concatenate([ordered[i] for i in range(n)], axis=axis)
-
-    def bcast_ring(self, x, root: int = 0):
-        """Pipeline/chain broadcast via n-1 ppermute hops (≈
-        coll_base_bcast.c:257 chain) — each hop touches one neighbor link
-        instead of the masked-psum tree."""
-        import jax.numpy as jnp
-
-        from jax import lax
-
-        n = self.size
-        ax = self._ax
-        if isinstance(ax, tuple):
-            return self.bcast(x, root)
-        perm = [(i, (i + 1) % n) for i in range(n)]
-        cur = jnp.where(self.rank() == root, x, jnp.zeros_like(x))
-        acc = cur
-        for _ in range(n - 1):
-            cur = lax.ppermute(cur, ax, perm)
-            acc = acc + cur
-        return acc.astype(x.dtype)
-
     # -- v-collectives (ragged → pad + static counts) ----------------------
     #
     # SPMD/XLA needs one static-shape program on every device, so ragged
@@ -723,10 +568,8 @@ class DeviceCommunicator:
 # ``axes`` is the communicator's, or the one mesh axis the call names.
 _TRACED = ("allreduce", "reduce", "bcast", "reduce_scatter", "allgather",
            "alltoall", "alltoall_stacked", "gather", "scatter", "scan",
-           "exscan", "allreduce_rs_ag", "allreduce_qint8",
-           "allreduce_segmented", "allgather_ring", "bcast_ring",
-           "allgatherv", "gatherv", "scatterv", "alltoallv", "barrier",
-           "shift", "permute", "sendrecv", "put", "get")
+           "exscan", "allgatherv", "gatherv", "scatterv", "alltoallv",
+           "barrier", "shift", "permute", "sendrecv", "put", "get")
 # ... whose ``axis`` argument is a mesh axis (default: the last one)
 _TAKE_MESH_AXIS = ("alltoall_stacked", "shift", "permute", "sendrecv")
 

@@ -262,7 +262,7 @@ def count(name: str, delta: int = 1) -> None:
 
 def counters_snapshot() -> dict[str, int]:
     """Point-in-time copy of every always-on counter plus the convertor
-    call stats — the provenance block bench.py embeds per record."""
+    call stats — the provenance block a bench record embeds."""
     snap = dict(counters)
     from ompi_tpu.mpi import datatype as _dt
 
@@ -312,8 +312,8 @@ _HIST_SPECS = (
      "(labels: slot, provider, szb = log2 payload-size bucket)"),
     ("coll_host_algo_ns", "nanoseconds",
      "coll/host algorithm-body latency, labeled by collective and the "
-     "algorithm the decision layer picked (the per-rung distribution "
-     "the coll_xla_algorithm ladder wants)"),
+     "algorithm the decision layer picked (one distribution per rung "
+     "of the coll_host_*_algorithm ladder)"),
     ("coll_nbc_ns", "nanoseconds",
      "nonblocking-collective schedule latency: NbcRequest post to "
      "completion (labels: kind)"),
@@ -1303,6 +1303,12 @@ FULL_EVERY = 8
 VEC_DELTA = "d"
 VEC_ABS = "a"
 
+#: the uplink's own meters: every send moves them, so alone they are no
+#: reason for the next one (an idle rank would push for ever to say that
+#: it pushed); they ride the next datagram that carries anything else
+_SELF_METERS = frozenset({"metrics_push_datagrams_total",
+                          "metrics_push_bytes_total"})
+
 
 class _MetricsPusher:
     """Background uplink thread: one small UDP datagram per period."""
@@ -1372,7 +1378,7 @@ class _MetricsPusher:
                 vals[key] = [VEC_DELTA,
                              *(a - b for a, b in zip(vec, last))]
         self._n += 1
-        if not vals and not full:
+        if not full and vals.keys() <= _SELF_METERS:
             return
         pkt = dss.pack(("m1", self.jobid, self.rank, self._n, vals))
         self._sock.sendto(pkt, self._addr)

@@ -275,24 +275,32 @@ class MetricsCollector:
 
     def _push_up(self) -> None:
         while not self._stop.wait(self.period):
-            payload = self.drain()
-            if not payload:
-                continue
-            try:
-                # one extra pack per period buys the actual per-hop
-                # byte rate the fan-in sizing needs (payloads are a few
-                # KiB; the RML frame adds a constant it doesn't count)
-                nbytes = len(dss.pack(payload))
-                self._send_fn(payload)
-                with self._lock:
-                    self.pushes_up += 1
-                    self.up_bytes += nbytes
-            except Exception:  # noqa: BLE001 — keep the merged delta:
-                # an orphaned-window send failure must not lose it
-                with self._lock:
-                    merged = self._pending
-                    self._pending = payload
-                    merge_hop(self._pending, merged)
+            self.push_now()
+
+    def push_now(self) -> None:
+        """Send what is pending one hop up now.  The period's tick calls
+        it, and so does the owner before it reports a rank's exit: the
+        report travels at once, and a rank's last snapshot waiting here
+        for the next tick would reach the aggregate after its job was
+        already called complete."""
+        payload = self.drain()
+        if not payload:
+            return
+        try:
+            # one extra pack per push buys the actual per-hop byte rate
+            # the fan-in sizing needs (payloads are a few KiB; the RML
+            # frame adds a constant it doesn't count)
+            nbytes = len(dss.pack(payload))
+            self._send_fn(payload)
+            with self._lock:
+                self.pushes_up += 1
+                self.up_bytes += nbytes
+        except Exception:  # noqa: BLE001 — keep the merged delta:
+            # an orphaned-window send failure must not lose it
+            with self._lock:
+                merged = self._pending
+                self._pending = payload
+                merge_hop(self._pending, merged)
 
     def drain(self) -> HopPayload:
         """Take the pending merged delta (callers push it one hop up),

@@ -457,6 +457,9 @@ class Orted:
         rc = p.wait()
         # let IOF readers drain the tail before the exit report races them
         time.sleep(0.05)
+        if self._metrics is not None:
+            # the rank's last snapshot goes up ahead of its exit report
+            self._metrics.push_now()
         try:
             self.node.send_up(rml.TAG_PROC_EXIT, (jobid, rank, rc, ""))
         except ConnectionError:

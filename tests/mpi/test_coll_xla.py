@@ -173,3 +173,51 @@ def test_dup_propagates_device_binding():
         assert dup.device is comm.device
     finally:
         pml.close()
+
+
+def _primitives(jaxpr) -> set:
+    """Names of every primitive in a jaxpr, nested jaxprs included."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+_NATIVE = {"allreduce": "psum", "allgather": "all_gather", "bcast": "psum"}
+
+
+@pytest.mark.parametrize("how", ["traced-1KiB", "traced-64MiB", "committed-1KiB"])
+@pytest.mark.parametrize("coll", sorted(_NATIVE))
+def test_one_lowering_per_device_collective(coll, how):
+    """Whatever the size and the buffer kind, a device collective through
+    the MPI surface is XLA's own lowering: no hand-written ring, no
+    reduce-scatter + all-gather, at 64 MiB a shard as at 1 KiB."""
+    comm, pml = _solo_comm()
+    dc = comm.device
+    n = dc.size
+    per_shard = (64 << 20 if how == "traced-64MiB" else 1 << 10) // 4
+    call = {"allreduce": comm.allreduce, "allgather": comm.allgather,
+            "bcast": lambda b: comm.bcast(b, 1)}[coll]
+    try:
+        if how == "committed-1KiB":
+            x = jax.numpy.arange(n * per_shard, dtype=jax.numpy.float32)
+            out = np.asarray(call(x))
+            (program,) = dc._method_cache.values()
+            jaxpr = jax.make_jaxpr(program)(x)
+            shards = np.asarray(x).reshape(n, per_shard)
+            want = {"allreduce": np.tile(shards.sum(0), n),
+                    "allgather": np.tile(shards.reshape(-1), n),
+                    "bcast": np.tile(shards[1], n)}[coll]
+            np.testing.assert_allclose(out, want)
+        else:
+            fn = jax.shard_map(call, mesh=dc.mesh, in_specs=P("world"),
+                               out_specs=P("world"), check_vma=False)
+            jaxpr = jax.make_jaxpr(fn)(jax.ShapeDtypeStruct(
+                (n * per_shard,), jax.numpy.float32))
+    finally:
+        pml.close()
+    names = _primitives(jaxpr.jaxpr)
+    assert _NATIVE[coll] in names, names
+    assert not names & {"ppermute", "reduce_scatter", "all_to_all"}, names

@@ -173,30 +173,3 @@ def test_inside_user_jit_composes(mesh8):
     out = comm.run(step, x)
     want = np.tile((np.sin(x) * 2).sum(axis=0) / 8, (8, 1))
     np.testing.assert_allclose(np.asarray(out), want, rtol=1e-6)
-
-
-def test_allreduce_qint8_accuracy(mesh8):
-    """EQuARX-style quantized allreduce: int8 wire format, per-block
-    scales — result within quantization error of the exact psum, shape/
-    dtype preserved, including a non-(n*block)-divisible size."""
-    comm = device_world(mesh8)
-    rng = np.random.default_rng(3)
-    for n in (8 * 256, 1000):        # aligned and ragged
-        x = rng.normal(0, 1, size=(8, n)).astype(np.float32)
-        out = comm.run(lambda c, s: c.allreduce_qint8(s), x)
-        want = np.tile(x.sum(axis=0), (8, 1))
-        got = np.asarray(out)
-        assert got.shape == want.shape and got.dtype == want.dtype
-        err = np.abs(got - want).max()
-        scale_bound = np.abs(x).max() * 8 / 127 * 4  # per-block worst case
-        assert err <= scale_bound, (err, scale_bound)
-        rel = np.linalg.norm(got - want) / np.linalg.norm(want)
-        assert rel < 0.02, rel
-
-
-def test_allreduce_qint8_non_sum_falls_back(mesh8):
-    comm = device_world(mesh8)
-    x = _global()
-    out = comm.run(lambda c, s: c.allreduce_qint8(s, op_mod.MAX), x)
-    want = np.tile(x.max(axis=0), (8, 1))
-    np.testing.assert_allclose(np.asarray(out), want)

@@ -1,11 +1,7 @@
-"""Device-path v-collectives, exscan, alternative algorithms, and the
-coll/xla decision layer on the virtual 8-device CPU mesh.
+"""Device-path v-collectives and exscan on the virtual 8-device CPU mesh.
 
 The ragged convention (pad to max(counts), static counts vector) is checked
-against per-rank numpy references; the alternative algorithm forms
-(allreduce_rs_ag, allgather_ring, bcast_ring) must be bit-compatible with
-the XLA-native lowerings they substitute for; the decision layer must honor
-forced config vars and the dynamic rules file on the DEVICE path.
+against per-rank numpy references.
 """
 
 import numpy as np
@@ -55,32 +51,6 @@ def test_exscan_noncommutative(mesh8):
     for r in range(1, 8):
         np.testing.assert_allclose(out[r], want)
         want = want @ mats[r]
-
-
-# -- alternative algorithm forms -------------------------------------------
-
-def test_allreduce_rs_ag_matches_psum(mesh8):
-    comm = device_world(mesh8)
-    x = _global(128)
-    a = np.asarray(comm.run(lambda c, s: c.allreduce(s), x))
-    b = np.asarray(comm.run(lambda c, s: c.allreduce_rs_ag(s), x))
-    np.testing.assert_allclose(a, b, rtol=1e-6)
-
-
-def test_allgather_ring_matches_all_gather(mesh8):
-    comm = device_world(mesh8)
-    x = _global(64)
-    a = np.asarray(comm.run(lambda c, s: c.allgather(s), x))
-    b = np.asarray(comm.run(lambda c, s: c.allgather_ring(s), x))
-    np.testing.assert_allclose(a, b)
-
-
-def test_bcast_ring_matches_bcast(mesh8):
-    comm = device_world(mesh8)
-    x = _global(64)
-    a = np.asarray(comm.run(lambda c, s: c.bcast(s, 3), x))
-    b = np.asarray(comm.run(lambda c, s: c.bcast_ring(s, 3), x))
-    np.testing.assert_allclose(a, b)
 
 
 # -- v-collectives (ragged, pad + static counts) ----------------------------
@@ -168,77 +138,3 @@ def test_alltoallv_ragged(mesh8):
                                        x[s, d, :m[s, d]],
                                        err_msg=f"src {s} dst {d}")
             np.testing.assert_allclose(out[d, s, m[s, d]:], 0.0)
-
-
-# -- decision layer ---------------------------------------------------------
-
-def test_xla_decision_fixed_and_forced():
-    from ompi_tpu.core.config import var_registry
-    from ompi_tpu.mpi.coll.xla import XlaColl
-
-    comp = XlaColl()
-    comp.register_params()
-
-    class FakeDC:
-        size = 8
-        axes = ("world",)
-
-    dc = FakeDC()
-    # fixed: small → psum, huge → rs_ag
-    assert comp._decide("allreduce", None, dc, 1024) == "psum"
-    assert comp._decide("allreduce", None, dc, 1 << 30) == "rs_ag"
-    assert comp._decide("allgather", None, dc, 1024) == "all_gather"
-    # dcn axis flips the preference
-    var_registry.set("coll_xla_dcn_axes", "world")
-    try:
-        assert comp._decide("allreduce", None, dc, 1024) == "rs_ag"
-        assert comp._decide("allgather", None, dc, 1024) == "ring"
-        assert comp._decide("bcast", None, dc, 0) == "ring"
-    finally:
-        var_registry.set("coll_xla_dcn_axes", "")
-    # forced var wins over everything
-    var_registry.set("coll_xla_allreduce_algorithm", "rs_ag")
-    try:
-        assert comp._decide("allreduce", None, dc, 8) == "rs_ag"
-    finally:
-        var_registry.set("coll_xla_allreduce_algorithm", "")
-
-
-def test_xla_decision_rules_file(tmp_path):
-    from ompi_tpu.core.config import var_registry
-    from ompi_tpu.mpi.coll.xla import XlaColl
-
-    comp = XlaColl()
-    comp.register_params()
-    rules = tmp_path / "device.rules"
-    rules.write_text("allreduce 0 4096 rs_ag\n")
-    var_registry.set("coll_xla_dynamic_rules", str(rules))
-
-    class FakeDC:
-        size = 8
-        axes = ("world",)
-
-    try:
-        assert comp._decide("allreduce", None, FakeDC(), 100) == "psum"
-        assert comp._decide("allreduce", None, FakeDC(), 8192) == "rs_ag"
-    finally:
-        var_registry.set("coll_xla_dynamic_rules", "")
-
-
-def test_allreduce_segmented_matches_psum(mesh8):
-    comm = device_world(mesh8)
-    # 3000 elems/shard, segment 1024 → several segments + ragged tail
-    x = np.arange(8 * 3000, dtype=np.float32).reshape(8, 3000)
-    a = np.asarray(comm.run(lambda c, s: c.allreduce(s), x))
-    b = np.asarray(comm.run(
-        lambda c, s: c.allreduce_segmented(s, segment_elems=1024), x))
-    np.testing.assert_allclose(a, b, rtol=1e-6)
-
-
-def test_allreduce_segmented_small_falls_back(mesh8):
-    comm = device_world(mesh8)
-    x = _global(64)
-    a = np.asarray(comm.run(lambda c, s: c.allreduce(s), x))
-    b = np.asarray(comm.run(
-        lambda c, s: c.allreduce_segmented(s, segment_elems=1 << 20), x))
-    np.testing.assert_allclose(a, b, rtol=1e-6)

@@ -490,6 +490,23 @@ def test_pusher_delta_compresses_and_full_heals():
         col.close()
 
 
+def test_collector_push_now_sends_pending_without_waiting_a_period():
+    """What an orted does before it reports a rank's exit: the pending
+    snapshot goes one hop up at once, so the exit report cannot overtake
+    it; with nothing pending nothing is sent."""
+    sent = []
+    col = MetricsCollector(period=30.0, send_fn=sent.append)
+    try:
+        col.push_now()
+        assert sent == []
+        col.on_child_payload({7: {0: [1.0, {"x": 1}]}})
+        col.push_now()
+        assert sent == [{7: {0: [1.0, {"x": 1}]}}]
+        assert col.drain() == {} and col.stats()["pushes_up"] == 1
+    finally:
+        col.close()
+
+
 def test_start_metrics_push_disabled_without_uri_or_period():
     old = var_registry.get("trace_metrics_push_period")
     try:

@@ -1,5 +1,5 @@
 """chip_smoke.py's phases at a tiny size on the virtual CPU mesh, and the
-no-fallback behaviour of the entry points that need the chip.
+no-fallback behaviour of the entry point that needs the chip.
 
 The phases are the same functions ``python chip_smoke.py`` runs at the
 flagship width on the TPU; here the suite's conftest has pallas in
@@ -19,8 +19,8 @@ import pytest
 REPO = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO))
 
-import bench  # noqa: E402
 import chip_smoke  # noqa: E402
+from benchmarks.lib.peaks import device_peaks  # noqa: E402
 
 jax = pytest.importorskip("jax")
 
@@ -101,24 +101,20 @@ def test_ring_phase():
     assert out["kernels"] == 0
 
 
-def _run_on_cpu(script: str):
+def test_no_tpu_exits_nonzero_without_a_result():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    return subprocess.run([sys.executable, script], cwd=REPO, env=env,
-                          capture_output=True, text=True, timeout=120)
-
-
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
-def test_no_tpu_exits_nonzero_without_a_result(script):
-    proc = _run_on_cpu(script)
+    proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=REPO,
+                          env=env, capture_output=True, text=True,
+                          timeout=120)
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""           # no record, no JSON line
     assert "no CPU mode" in proc.stderr
 
 
 def test_peak_table_rejects_unknown_device_kind():
-    assert bench.device_peaks("TPU v5 lite")["bf16_flops"] == 197e12
+    assert device_peaks("TPU v5 lite")["bf16_flops"] == 197e12
     with pytest.raises(ValueError, match="no published peaks"):
-        bench.device_peaks("cpu")
+        device_peaks("cpu")
 
 
 def test_compile_cache_follows_env_else_checkout(monkeypatch):

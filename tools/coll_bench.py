@@ -6,8 +6,7 @@ real matching, real shm-BTL rings for the host path — the same rig the
 58 µs/hop scheduler-floor number was measured on), run twice per
 config: once with the coll/shm arena enabled and once forced to
 coll/host (``coll_shm_enable 0``).  The per-op number is wall time of
-a synchronized loop divided by iterations, best of ``--reps`` runs —
-the two-point/best-of discipline bench.py uses, collective form.
+a synchronized loop divided by iterations, best of ``--reps`` runs.
 
 Rows append to ``COLL_BENCH.jsonl`` next to the repo root (the
 PACK_BENCH.jsonl convention — append-only, one JSON object per line)

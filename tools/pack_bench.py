@@ -15,10 +15,10 @@ compile + copy), then slope-timed steady-state ``pack_into`` (the
 memoryview variant the transports use — no bytes materialization),
 ``pack`` (bytes-returning) and ``unpack``.  Slope timing: the same
 call at two rep counts, cost = (t_hi - t_lo) / (reps_hi - reps_lo), so
-per-call constants cancel (the bench.py two-point method, host-side).
+per-call constants cancel (the two-point method, host-side).
 
 Rows append to ``PACK_BENCH.jsonl`` next to the repo root
-(MFU_SWEEP.jsonl style — append-only, one JSON object per line) so the
+(append-only, one JSON object per line) so the
 92 ms → target headline stays reproducible and future regressions are
 visible.  Run: ``python tools/pack_bench.py [--runs 1,1000,...]``.
 """
