@@ -68,8 +68,8 @@ class TransformerConfig:
     # this length without materializing the full (B, T, V) logits/log-
     # softmax pair — at vocab 32k that pair is the single largest HBM
     # tensor in the train step (f32, ~4 GiB at batch 16 / seq 1024).
-    # Each chunk's logits are recomputed in the backward (jax.checkpoint),
-    # so peak memory drops from O(T·V) to O(chunk·V).  0 = full path.
+    # Each chunk's gradient is made in the pass that makes its logits
+    # (a custom_vjp: nothing recomputed), so the peak is O(chunk·V).  0 = full.
     ce_chunk: int = 0
     compute_dtype: Any = "bfloat16"
     # jax.checkpoint policy per layer — HBM ↔ FLOPs trade:
@@ -452,8 +452,12 @@ def _unembed(cfg: TransformerConfig, h, emb):
 
 def _chunked_nll_sum(cfg: TransformerConfig, h, emb, labels, weight):
     """Σ weight·nll over the local shard WITHOUT materializing the full
-    (B, T, V) logits: lax.scan over sequence chunks, each chunk's logits
-    recomputed in the backward (jax.checkpoint around the chunk body).
+    (B, T, V) logits: lax.scan over sequence chunks, one vocabulary matmul
+    a chunk.  A jax.custom_vjp: where it is differentiated, the same scan
+    forms each chunk's (softmax − onehot)·weight from the logits it has
+    just made and multiplies it into the gradients of h and emb (three
+    vocabulary matmuls a chunk), so no chunk's logits are kept or made
+    twice; the backward pass only scales the two by its cotangent.
 
     h: (B, T, D) compute dtype; emb: (V, D) f32; labels: (B, T) int32;
     weight: (B, T) f32.  Returns a f32 scalar.
@@ -465,23 +469,61 @@ def _chunked_nll_sum(cfg: TransformerConfig, h, emb, labels, weight):
     B, T, D = h.shape
     c = cfg.ce_chunk
     n = T // c
-    emb_c = emb.astype(h.dtype)
 
-    def body(acc, inp):
-        h_c, lab_c, w_c = inp  # (B, c, D), (B, c), (B, c)
+    def scan_chunks(body, init, *xs):  # each x: (B, T, ...) -> (n, B, c, ...)
+        return lax.scan(body, init, tuple(
+            jnp.moveaxis(x.reshape(B, n, c, *x.shape[2:]), 1, 0) for x in xs))
+
+    def chunk_nll(emb_c, h_c, lab_c, w_c):
         logits = jnp.einsum("btd,vd->btv", h_c, emb_c,
                             preferred_element_type=jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)
         lab_logit = jnp.take_along_axis(
             logits, lab_c[..., None], axis=-1)[..., 0]
-        return acc + ((lse - lab_logit) * w_c).sum(), None
+        return logits, lse, ((lse - lab_logit) * w_c).sum()
 
-    hs = jnp.moveaxis(h.reshape(B, n, c, D), 1, 0)
-    labs = jnp.moveaxis(labels.reshape(B, n, c), 1, 0)
-    ws = jnp.moveaxis(weight.reshape(B, n, c), 1, 0)
-    total, _ = lax.scan(jax.checkpoint(body), jnp.zeros((), jnp.float32),
-                        (hs, labs, ws))
-    return total
+    @jax.custom_vjp
+    def nll_sum(h, emb, labels, weight):
+        emb_c = emb.astype(h.dtype)
+
+        def body(acc, inp):
+            return acc + chunk_nll(emb_c, *inp)[2], None
+
+        total, _ = scan_chunks(body, jnp.zeros((), jnp.float32),
+                               h, labels, weight)
+        return total
+
+    def fwd(h, emb, labels, weight):
+        emb_c = emb.astype(h.dtype)
+
+        def body(carry, inp):
+            acc, d_emb = carry
+            h_c, lab_c, w_c = inp  # (B, c, D), (B, c), (B, c)
+            logits, lse, nll = chunk_nll(emb_c, h_c, lab_c, w_c)
+            # d nll / d logits in f32, rounded where it meets the MXU
+            d_logits = ((jnp.exp(logits - lse[..., None])
+                         - jax.nn.one_hot(lab_c, logits.shape[-1],
+                                          dtype=jnp.float32))
+                        * w_c[..., None]).astype(h.dtype)
+            d_h = jnp.einsum("btv,vd->btd", d_logits, emb_c,
+                             preferred_element_type=jnp.float32)
+            d_emb = d_emb + jnp.einsum("btv,btd->vd", d_logits, h_c,
+                                       preferred_element_type=jnp.float32)
+            return (acc + nll, d_emb), d_h.astype(h.dtype)
+
+        (total, d_emb), d_hs = scan_chunks(
+            body, (jnp.zeros((), jnp.float32),
+                   jnp.zeros(emb.shape, jnp.float32)), h, labels, weight)
+        d_h = jnp.moveaxis(d_hs, 0, 1).reshape(B, T, D)
+        return total, (d_h, d_emb.astype(emb.dtype))
+
+    def bwd(res, g):
+        d_h, d_emb = res
+        return ((g * d_h).astype(d_h.dtype),
+                (g * d_emb).astype(d_emb.dtype), None, None)
+
+    nll_sum.defvjp(fwd, bwd)
+    return nll_sum(h, emb, labels, weight)
 
 
 def _local_loss(cfg: TransformerConfig, comm, params, tokens):

@@ -1,0 +1,172 @@
+"""The chunked cross entropy (``TransformerConfig.ce_chunk``): a
+``jax.custom_vjp`` that forms each chunk's gradient in the pass that makes
+its logits.  Held to the full-logits path, to the checkpointed scan it
+replaced (a local copy), and to its own shape: one vocabulary matmul a
+chunk for the value, three for value and gradient, none recomputed."""
+
+import dataclasses
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ompi_tpu.models import transformer as tfm
+from ompi_tpu.parallel.mesh import make_mesh
+
+CFG = tfm.TransformerConfig(
+    vocab=128, d_model=64, n_heads=4, n_layers=2, d_ff=128, seq=32,
+    attention="ring", compute_dtype="float32")
+MESHES = {
+    "dp2sp2tp2": {"dp": 2, "sp": 2, "tp": 2},
+    "dp4sp1tp2": {"dp": 4, "sp": 1, "tp": 2},
+}
+
+
+def _tokens(cfg, batch=4, seed=1):
+    rng = np.random.default_rng(seed)
+    return rng.integers(0, cfg.vocab, size=(batch, cfg.seq)).astype(np.int32)
+
+
+def _value_and_grad(cfg, mesh, scale=1.0):
+    loss = tfm.make_loss_fn(cfg, mesh)
+    return jax.jit(jax.value_and_grad(lambda p, t: scale * loss(p, t)))
+
+
+@pytest.mark.parametrize("mesh_name", sorted(MESHES))
+def test_matches_full_logits_float32(mesh_name):
+    """(a) ce_chunk is numerically invisible in float32: the same loss and
+    the same gradient of every leaf as the full-logits path."""
+    mesh = make_mesh(MESHES[mesh_name])
+    params, toks = tfm.init_params(CFG), _tokens(CFG)
+    cfg_c = dataclasses.replace(CFG, ce_chunk=8)  # 2 or 4 chunks a device
+    l_full, g_full = _value_and_grad(CFG, mesh)(params, toks)
+    l_chunk, g_chunk = _value_and_grad(cfg_c, mesh)(params, toks)
+    np.testing.assert_allclose(float(l_full), float(l_chunk), rtol=1e-6)
+    for k in g_full:
+        np.testing.assert_allclose(
+            np.asarray(g_full[k]), np.asarray(g_chunk[k]),
+            rtol=2e-5, atol=1e-6, err_msg=k)
+
+
+def _checkpointed_nll_sum(chunk, h, emb, labels, weight):
+    """The scan this repo ran up to PR 30: the chunk's forward under
+    ``jax.checkpoint``, run again in the backward pass."""
+    B, T, D = h.shape
+    n = T // chunk
+    emb_c = emb.astype(h.dtype)
+
+    def body(acc, inp):
+        h_c, lab_c, w_c = inp
+        logits = jnp.einsum("btd,vd->btv", h_c, emb_c,
+                            preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        lab_logit = jnp.take_along_axis(
+            logits, lab_c[..., None], axis=-1)[..., 0]
+        return acc + ((lse - lab_logit) * w_c).sum(), None
+
+    hs = jnp.moveaxis(h.reshape(B, n, chunk, D), 1, 0)
+    labs = jnp.moveaxis(labels.reshape(B, n, chunk), 1, 0)
+    ws = jnp.moveaxis(weight.reshape(B, n, chunk), 1, 0)
+    total, _ = lax.scan(jax.checkpoint(body), jnp.zeros((), jnp.float32),
+                        (hs, labs, ws))
+    return total
+
+
+def _loss_inputs(dtype, B=4, T=32, D=64, V=128, seed=0):
+    rng = np.random.default_rng(seed)
+    h = jnp.asarray(rng.standard_normal((B, T, D)), dtype)
+    emb = jnp.asarray(0.2 * rng.standard_normal((V, D)), jnp.float32)
+    labels = jnp.asarray(rng.integers(0, V, size=(B, T)), jnp.int32)
+    weight = jnp.asarray(rng.random((B, T)) < 0.8, jnp.float32)
+    return h, emb, labels, weight
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
+def test_matches_checkpointed_scan(dtype, tol):
+    """(b) against the scan it replaced: the same operands meet the matmuls
+    at the same precision, so bfloat16 agrees to bfloat16's own rounding
+    (the hidden states' gradient is rounded once more, after the scaling)."""
+    cfg = dataclasses.replace(CFG, ce_chunk=8, compute_dtype=dtype)
+    h, emb, labels, weight = _loss_inputs(dtype)
+    new = jax.jit(jax.value_and_grad(
+        lambda h, e: 0.01 * tfm._chunked_nll_sum(cfg, h, e, labels, weight),
+        argnums=(0, 1)))
+    old = jax.jit(jax.value_and_grad(
+        lambda h, e: 0.01 * _checkpointed_nll_sum(8, h, e, labels, weight),
+        argnums=(0, 1)))
+    (l_new, (dh_new, de_new)), (l_old, (dh_old, de_old)) = new(h, emb), old(h, emb)
+    np.testing.assert_allclose(float(l_new), float(l_old), rtol=1e-6)
+    assert dh_new.dtype == h.dtype and de_new.dtype == emb.dtype
+    for name, a, b in (("h", dh_new, dh_old), ("emb", de_new, de_old)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= tol * np.abs(b).max(), name
+
+
+def test_cotangent_scales_every_gradient():
+    """(c) the backward rule scales by the scalar cotangent: grad of
+    3·loss is 3·grad of loss, leaf by leaf."""
+    mesh = make_mesh(MESHES["dp2sp2tp2"])
+    cfg = dataclasses.replace(CFG, ce_chunk=8)
+    params, toks = tfm.init_params(cfg), _tokens(cfg)
+    l1, g1 = _value_and_grad(cfg, mesh)(params, toks)
+    l3, g3 = _value_and_grad(cfg, mesh, scale=3.0)(params, toks)
+    np.testing.assert_allclose(float(l3), 3 * float(l1), rtol=1e-6)
+    for k in g1:
+        assert np.abs(np.asarray(g1[k])).max() > 0, k
+        np.testing.assert_allclose(
+            np.asarray(g3[k]), 3 * np.asarray(g1[k]),
+            rtol=1e-5, atol=1e-6, err_msg=k)
+
+
+def test_weight_zero_positions_get_no_gradient():
+    """(d) a position of weight 0 is not in the sum: its hidden state's
+    gradient is exactly zero, and the others' is not."""
+    cfg = dataclasses.replace(CFG, ce_chunk=8)
+    h, emb, labels, weight = _loss_inputs("float32")
+    assert 0 < float(weight.sum()) < weight.size
+    d_h = jax.jit(jax.grad(
+        lambda h: tfm._chunked_nll_sum(cfg, h, emb, labels, weight)))(h)
+    norms = np.abs(np.asarray(d_h)).max(axis=-1)
+    dropped = np.asarray(weight) == 0
+    assert (norms[dropped] == 0).all()
+    assert (norms[~dropped] > 0).all()
+
+
+def _equations(jaxpr, under=()):
+    """Every equation of a jaxpr and of the jaxprs its equations hold, with
+    the names of the primitives it sits under."""
+    for eqn in jaxpr.eqns:
+        yield eqn, under
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub, under + (eqn.primitive.name,))
+
+
+def _vocabulary_dots(fn, *args, vocab):
+    found = []
+    for eqn, under in _equations(jax.make_jaxpr(fn)(*args).jaxpr):
+        if eqn.primitive.name == "dot_general" and any(
+                vocab in v.aval.shape for v in (*eqn.invars, *eqn.outvars)):
+            found.append(under)
+    return found
+
+
+@pytest.mark.parametrize("what,per_chunk", [("value", 1), ("value_and_grad", 3)])
+def test_vocabulary_matmuls_a_chunk(what, per_chunk):
+    """(e) the value alone holds one vocabulary matmul in its scan's body,
+    value and gradient hold three (logits, the hidden states' gradient, the
+    head's), and none of them sits under a checkpoint: nothing is made
+    twice.  (The checkpointed scan held four.)"""
+    mesh = make_mesh({"dp": 1, "sp": 1, "tp": 1}, devices=jax.devices()[:1])
+    # a vocabulary no other width of the model equals
+    cfg = dataclasses.replace(CFG, ce_chunk=8, attention="xla", vocab=160)
+    params, toks = tfm.init_params(cfg), _tokens(cfg)
+    loss = tfm.make_loss_fn(cfg, mesh)
+    fn = loss if what == "value" else jax.value_and_grad(loss)
+    dots = _vocabulary_dots(fn, params, toks, vocab=cfg.vocab)
+    assert len(dots) == per_chunk, dots
+    for under in dots:
+        assert "scan" in under, under
+        assert not {"checkpoint", "remat", "remat2"} & set(under), under
