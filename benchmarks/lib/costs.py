@@ -18,45 +18,54 @@ def tree_bytes(tree: dict) -> int:
     return sum(int(leaf.size) * leaf.dtype.itemsize for leaf in tree.values())
 
 
-def train_flops_per_token(active_params: int, n_layers: int, d_model: int,
-                          seq: int) -> int:
+def train_flops_per_token(active_params: int, attention_layers: int,
+                          attention_width: int, seq: int) -> int:
     """Forward and backward of a decoder, PaLM's accounting: 6 per parameter
     a token multiplies (``counts(shape)["active_params"]`` of the
     configuration's reference: in a dense model every parameter, a tied
     embedding once, as the output projection; in a routed one the experts a
     token is sent to) and 12·L·D·S for attention's two T×T products, the
-    causal half included.  Recomputation under ``jax.checkpoint`` is
+    causal half included: L the layers that attend and D the summed width
+    of their query heads (``attention_layers``, ``attention_width`` of the
+    same ``counts``).  Recomputation under ``jax.checkpoint`` is
     excluded."""
-    return 6 * active_params + 12 * n_layers * d_model * seq
+    return 6 * active_params + 12 * attention_layers * attention_width * seq
 
 
-def prefill_flops(active_params: int, projection_params: int, n_layers: int,
-                  d_model: int, batch: int, prompt_len: int) -> int:
+def prefill_flops(active_params: int, projection_params: int,
+                  attention_layers: int, attention_width: int, batch: int,
+                  prompt_len: int) -> int:
     """Forward over ``batch`` prompts that ends in one token each: every
     position passes the blocks (2 per active block parameter, 4·L·D·T for
-    attention), and only the last position of each prompt is projected
-    onto the vocabulary (``projection_params``: ``vocab x d_model``)."""
+    attention in the L layers that attend, D wide), and only the last
+    position of each prompt is projected onto the vocabulary
+    (``projection_params``: ``vocab x d_model``)."""
     body = active_params - projection_params
-    per_token = 2 * body + 4 * n_layers * d_model * prompt_len
+    per_token = (2 * body
+                 + 4 * attention_layers * attention_width * prompt_len)
     return batch * prompt_len * per_token + batch * 2 * projection_params
 
 
-def kv_bytes(n_layers: int, batch: int, positions: float, kv_elements: int,
-             itemsize: int) -> float:
-    """Keys and values of ``positions`` cached positions; ``kv_elements`` is
-    what one position holds of both in one layer (``2 x d_model`` with as
-    many K/V heads as query heads)."""
-    return n_layers * batch * positions * kv_elements * itemsize
+def kv_bytes(attention_layers: int, batch: int, positions: float,
+             kv_elements: int, itemsize: int) -> float:
+    """Keys and values of ``positions`` cached positions in the layers that
+    attend; ``kv_elements`` is what one position holds of both in one such
+    layer (``2 x d_model`` with as many K/V heads as query heads)."""
+    return attention_layers * batch * positions * kv_elements * itemsize
 
 
-def decode_step_bytes(param_bytes: int, n_layers: int, batch: int,
+def decode_step_bytes(param_bytes: int, attention_layers: int, batch: int,
                       prompt_len: int, max_new: int, kv_elements: int,
-                      kv_itemsize: int) -> float:
+                      kv_itemsize: int, state_elements: int = 0) -> float:
     """Bytes one cached decode step must read: every parameter once at its
-    stored type, and the live keys and values once at theirs.  The cache is
-    live up to the position being written, so over the ``max_new - 1`` steps
-    after the first token it holds ``prompt_len + max_new / 2`` positions on
-    average."""
+    stored type, the live keys and values once at theirs, and what a
+    sequence holds of fixed-size state (``state_elements``, over all layers:
+    a convolution's last positions, a linear attention's matrix) once at
+    the cache's type.  The cache is live up to the position being written,
+    so over the ``max_new - 1`` steps after the first token it holds
+    ``prompt_len + max_new / 2`` positions on average."""
     live = prompt_len + max_new / 2
-    return param_bytes + kv_bytes(n_layers, batch, live, kv_elements,
-                                  kv_itemsize)
+    return (param_bytes
+            + kv_bytes(attention_layers, batch, live, kv_elements,
+                       kv_itemsize)
+            + batch * state_elements * kv_itemsize)

@@ -39,9 +39,30 @@ def reference(config: dict, bench_dir: str = cells.BENCH_DIR):
     A reference module has ``Shape.from_config(config)`` (with ``vocab``,
     ``d_model`` and ``n_layers``), ``param_init(shape)``, ``logits``,
     ``loss``, ``token_deficits`` and ``counts(shape)``, which says what
-    ``lib/costs.py`` may count of this family's parameters."""
+    ``lib/costs.py`` may count of this family (``counts`` below has the
+    keys)."""
     return cells.load_module(os.path.join(bench_dir, "reference",
                                           config["reference"] + ".py"))
+
+
+def counts(ref, shape) -> dict:
+    """``ref.counts(shape)`` with the keys a reference may leave out at
+    their defaults; what the runners' ``facts()`` count from and carry.
+
+    Every reference gives ``active_params`` (what one token multiplies; a
+    lookup table that is not the projection counts nothing),
+    ``projection_params`` (the output projection alone) and ``kv_elements``
+    (one position's keys and values in one attending layer).  One whose
+    layers differ may also give ``attention_layers`` (how many layers
+    attend; default every layer), ``attention_width`` (the summed width of
+    the query heads; default ``d_model``), ``state_elements`` (what one
+    sequence holds, over all layers, of fixed-size state that a cached step
+    reads, counted at ``kv_cache_dtype``; default 0) and ``routed``
+    (``{"layers", "experts", "top_k", "d_model", "d_expert"}``: the routed
+    layers' own shape, for the readers of their kernels; no default)."""
+    return {"attention_layers": shape.n_layers,
+            "attention_width": shape.d_model, "state_elements": 0,
+            **ref.counts(shape)}
 
 
 def mesh(config: dict, devices):

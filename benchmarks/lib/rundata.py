@@ -24,6 +24,10 @@ class RunData:
     scopes: dict[str, float] | None = None
     # the traced events that table was made of, for ``scopes_under``
     events: list[Event] = dataclasses.field(default_factory=list, repr=False)
+    # the cell that was run: its configuration's file and its traffic
+    # mix's, as the runner was given them
+    config: dict = dataclasses.field(default_factory=dict, repr=False)
+    traffic: dict = dataclasses.field(default_factory=dict, repr=False)
 
     def median(self, span: str) -> float | None:
         values = self.durations.get(span)

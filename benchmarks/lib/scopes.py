@@ -1,9 +1,12 @@
 """From the ``op_name`` of a device operation to the layer it belongs to.
 
 The program wraps every boundary the records talk about in a
-``jax.named_scope`` from one vocabulary (``ompi_tpu/core/scopes.py``; the
-copy below is held to it by ``tests/benchmarks/test_scopes.py``), and JAX's
-own name stack tells the passes apart.  An ``op_name`` is a path,
+``jax.named_scope`` from one vocabulary, ``ompi_tpu/core/scopes.py``, and
+JAX's own name stack tells the passes apart.  This module keeps no copy of
+that vocabulary: ``classify`` looks a name up in the program's own tuple
+when it is called, so a scope added there is a scope here, a key of the
+table and a line of ``breakdown.device_scopes``, with no edit under
+``benchmarks/``.  An ``op_name`` is a path,
 
     jit(train_step)/jvp()/shard_map/layers/while/body/closed_call/attention/dot_general
     jit(train_step)/transpose(jvp())/shard_map/layers/while/body/closed_call/
@@ -69,14 +72,9 @@ from benchmarks.lib import hlo_names, xplane
 from benchmarks.lib.spans import TRACE_PREFIX
 from benchmarks.lib.xplane import Event, Interval
 
-# ompi_tpu.core.scopes.SCOPES, letter for letter
-VOCABULARY = (
-    "embed", "layers", "attn_proj", "attention", "attention.ring",
-    "attention.ulysses", "attention.flash", "ffn", "moe.route",
-    "moe.dispatch", "moe.experts", "moe.combine", "loss", "optimizer",
-    "prefill", "decode.step", "kv_cache", "unembed", "sample",
-)
-COLL = "coll."
+# the one vocabulary, ``SCOPES`` and ``COLL``: the program's own, read
+# when a name is classified (``classify`` remembers what it has read)
+from ompi_tpu.core import scopes as vocabulary
 
 _TRANSFORM = re.compile(r"^([A-Za-z_][\w.]*)\((.*)\)$")
 # a function's name, not a scope, is what these wrap
@@ -99,6 +97,7 @@ def classify(op_name: str) -> Where:
     chain: list[str] = []
     coll = None
     recompute = False
+    coll_prefix = vocabulary.COLL + "."
     for element in op_name.split("/"):
         call = False
         while (m := _TRANSFORM.match(element)):
@@ -109,10 +108,10 @@ def classify(op_name: str) -> Where:
             continue
         if element == "rematted_computation":
             recompute = True
-        elif element in VOCABULARY:
+        elif element in vocabulary.SCOPES:
             chain.append(element)
-        elif element.startswith(COLL) and coll is None:
-            coll = element[len(COLL):]
+        elif element.startswith(coll_prefix) and coll is None:
+            coll = element[len(coll_prefix):]
     phase = ("recompute" if recompute else "bwd" if "transpose" in transforms
              else "fwd" if "jvp" in transforms else None)
     return Where(phase, tuple(chain), coll)

@@ -19,19 +19,16 @@ not keep its result.  So the share cannot pass 100%.  At the cells' head
 width the operations bind (cell 1: 137 GFLOP against 268 MB a layer
 forward).
 
-The sizes come from the configuration of the cell that was run, found as
-``grouped_matmul_roofline`` finds its own: among the cells that report this
-metric, the one whose reference lays out as many parameters as the run held,
-on as many chips, with as many tokens a step.
+The sizes are those of the cell that was run: its configuration's file and
+its traffic mix's, which the run carries (``run.config``, ``run.traffic``),
+and the layers that attend as its reference counts them
+(``facts["counts"]["attention_layers"]``).
 """
 
-import math
-import os
 import re
 
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 NAMED = re.compile(r"^%?(" + "|".join(KERNELS) + r")(\.\d+)? ")
-BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def costs(batch: int, heads: int, seq: int, head_dim: int,
@@ -55,26 +52,6 @@ def least_seconds(batch: int, heads: int, seq: int, head_dim: int,
                      nbytes / peaks["hbm_bytes_per_s"])
                  for operations, nbytes in costs(batch, heads, seq, head_dim,
                                                  itemsize))
-
-
-def cell_of(run):
-    """The cell this run was of (see the module's docstring), or None."""
-    from benchmarks.lib import cells, program
-
-    name = os.path.splitext(os.path.basename(__file__))[0]
-    row = next((m for m in cells.load_benchmark(BENCH_DIR)["per_layer"]
-                if m["name"] == name), None)
-    for workload in (row or {}).get("workloads", []):
-        cell = cells.resolve(workload, BENCH_DIR)
-        table = program.param_table(program.reference(cell.config, BENCH_DIR),
-                                    cell.config)
-        held = sum(math.prod(dims) for dims, _std in table.values())
-        tokens = cell.traffic.get("batch", 0) * cell.traffic.get("seq", 0)
-        if (held == run.facts.get("n_params")
-                and cell.chips == run.facts.get("chips")
-                and tokens == run.facts.get("tokens_per_sample")):
-            return cell
-    return None
 
 
 def device_shape(config: dict, batch: int, seq: int) -> tuple:
@@ -108,12 +85,9 @@ def read(run):
     if not any(seconds.values()):
         return None
     counts = {kernel: len(times) for kernel, times in seconds.items()}
-    cell = cell_of(run)
-    if cell is None:
-        raise ValueError(f"flash_attention_roofline: kernel events {counts} "
-                         f"in a run that is of none of this metric's cells "
-                         f"({run.facts})")
-    calls = cell.config["num_hidden_layers"] * cell.chips  # a step, a kernel
+    chips = run.facts["chips"]
+    # a kernel's calls a step: once a layer that attends, on every chip
+    calls = run.facts["counts"]["attention_layers"] * chips
     forwards, dqs, dkvs = (counts[kernel] for kernel in KERNELS)
     steps, left = divmod(dqs, calls)
     # each backward kernel once a layer and step; the forward once, or
@@ -121,9 +95,10 @@ def read(run):
     if left or not steps or dkvs != dqs or forwards not in (dqs, 2 * dqs):
         raise ValueError(f"flash_attention_roofline: kernel events {counts} "
                          f"are not whole steps of {calls} calls a kernel "
-                         f"in {cell.name}")
+                         f"in a run of {run.config.get('name')} on {chips} "
+                         f"chip(s)")
     forward, backward = least_seconds(
-        *device_shape(cell.config, cell.traffic["batch"],
-                      cell.traffic["seq"]), run.peaks)
+        *device_shape(run.config, run.traffic["batch"], run.traffic["seq"]),
+        run.peaks)
     least = steps * calls * (forward + backward)
     return 100.0 * least / sum(sum(times) for times in seconds.values())

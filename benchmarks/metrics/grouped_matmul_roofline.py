@@ -12,19 +12,17 @@ cached step's by bytes (a handful of rows an expert, every expert's matrix
 read once), so one share over both says how near the kernel is to whichever
 bound applies where it runs.
 
-The sizes come from the configuration of the cell that was run.  A reader
-is handed the run and not the cell, so the cell is found again: among the
-cells that report this metric, the one whose reference lays out as many
-parameters as the run held, under its batch and lengths.
+The sizes are the run's own: the routed layers' shape as the
+configuration's reference counts it (``facts["counts"]["routed"]``, so an
+expert's width may sit under any key of a configuration's file and some
+layers may have no experts) and the type the cell's configuration computes
+in.  A run whose reference names no routed layers reads as nothing.
 """
 
-import math
-import os
 import re
 
 KERNEL = "grouped_matmul"
 NAMED = re.compile(r"^%?" + KERNEL + r"(\.\d+)? ")
-BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def costs(rows: int, k_dim: int, n_dim: int, groups: int,
@@ -47,51 +45,33 @@ def least_seconds(rows: int, k_dim: int, n_dim: int, groups: int,
                nbytes / peaks["hbm_bytes_per_s"])
 
 
-def layer_seconds(config: dict, tokens: int, peaks: dict) -> float:
+def layer_seconds(routed: dict, itemsize: int, tokens: int,
+                  peaks: dict) -> float:
     """The three matmuls of one routed layer over ``tokens`` tokens: gate
     and up (hidden to expert width), down (expert width to hidden)."""
-    import jax.numpy as jnp
-
-    rows = tokens * config["num_experts_per_tok"]
-    wide, narrow = config["hidden_size"], config["intermediate_size"]
-    experts = config["num_experts"]
-    itemsize = jnp.dtype(config["entry"]["options"]["compute_dtype"]).itemsize
+    rows = tokens * routed["top_k"]
+    wide, narrow = routed["d_model"], routed["d_expert"]
+    experts = routed["experts"]
     return (2 * least_seconds(rows, wide, narrow, experts, itemsize, peaks)
             + least_seconds(rows, narrow, wide, experts, itemsize, peaks))
 
 
-def config_of(run) -> dict | None:
-    """The configuration of the cell this run was of (see the module's
-    docstring), or None where no cell that reports the metric fits."""
-    from benchmarks.lib import cells, program
-
-    name = os.path.splitext(os.path.basename(__file__))[0]
-    row = next((m for m in cells.load_benchmark(BENCH_DIR)["per_layer"]
-                if m["name"] == name), None)
-    for workload in (row or {}).get("workloads", []):
-        cell = cells.resolve(workload, BENCH_DIR)
-        table = program.param_table(program.reference(cell.config, BENCH_DIR),
-                                    cell.config)
-        held = sum(math.prod(dims) for dims, _std in table.values())
-        same = all(cell.traffic.get(key) == run.facts.get(key)
-                   for key in ("batch", "prompt_len", "max_new"))
-        if held == run.facts.get("n_params") and same:
-            return cell.config
-    return None
-
-
 def read(run):
+    import jax.numpy as jnp
+
     if run.trace is None or run.peaks is None:
         return None
     kernel_s = [e.duration_ns / 1e9 for e in run.events
                 if NAMED.match(e.name)]
-    config = config_of(run) if kernel_s else None
-    if config is None or "num_experts_per_tok" not in config:
+    routed = run.facts["counts"].get("routed")
+    if not kernel_s or routed is None:
         return None
-    batch, layers = run.facts["batch"], config["num_hidden_layers"]
-    prefill = layer_seconds(config, batch * run.facts["prompt_len"],
-                            run.peaks)
-    step = layer_seconds(config, batch, run.peaks)
+    itemsize = jnp.dtype(
+        run.config["entry"]["options"]["compute_dtype"]).itemsize
+    batch, layers = run.facts["batch"], routed["layers"]
+    prefill = layer_seconds(routed, itemsize,
+                            batch * run.facts["prompt_len"], run.peaks)
+    step = layer_seconds(routed, itemsize, batch, run.peaks)
     # a sample is two jobs: each prefills, one also takes max_new - 1 steps
     calls = 3 * layers * (2 + run.facts["max_new"] - 1)
     samples, left = divmod(len(kernel_s), calls)

@@ -108,19 +108,22 @@ def param_init(shape: Shape) -> dict[str, tuple[tuple[int, ...], float | None]]:
     }
 
 
-def counts(shape: Shape) -> dict[str, int]:
+def counts(shape: Shape) -> dict:
     """What ``lib/costs.py`` counts of this family.  ``active_params``: the
     parameters one token multiplies: in every layer the four attention
     projections, the router, and ``top_k`` of the experts' three matrices;
     and the output head.  The embedding is a lookup table and the norms'
     scales multiply no matrix: they count nothing.  ``kv_elements``: one
     position's keys and values in one layer (as many K/V heads as query
-    heads)."""
+    heads).  ``routed``: every layer is routed, over ``n_experts`` gated
+    experts of width ``d_ff``, ``top_k`` a token."""
     L, D, F, V = shape.n_layers, shape.d_model, shape.d_ff, shape.vocab
     block = 4 * D * D + D * shape.n_experts + shape.top_k * 3 * D * F
     return {"active_params": L * block + V * D,
             "projection_params": V * D,
-            "kv_elements": 2 * D}
+            "kv_elements": 2 * D,
+            "routed": {"layers": L, "experts": shape.n_experts,
+                       "top_k": shape.top_k, "d_model": D, "d_expert": F}}
 
 
 def _rmsnorm(x, scale, eps):

@@ -143,7 +143,8 @@ def measure(cell: cells.Cell, devices, meter, spans: Spans, seed: int,
         by_clock = clock.breakdown(events)
     run = RunData(durations=durations, facts=facts, peaks=peaks,
                   trace=summary, compiles_in_window=compiles,
-                  peak_bytes=fullest, scopes=table, events=events)
+                  peak_bytes=fullest, scopes=table, events=events,
+                  config=cell.config, traffic=cell.traffic)
 
     if trace:
         rows = [(row, reader.read(run)) for row, reader in cell.per_layer]

@@ -134,20 +134,23 @@ class Job:
     def facts(self) -> dict:
         import jax.numpy as jnp
 
-        shape = self.shape
-        counts = self.reference.counts(shape)
+        counts = program.counts(self.reference, self.shape)
         return {
             "chips": len(self.devices),
             "n_params": self.n_params,
             "batch": self.batch, "max_new": self.max_new,
             "prompt_len": self.prompt_len,
+            "counts": counts,
             "prefill_flops": costs.prefill_flops(
                 counts["active_params"], counts["projection_params"],
-                shape.n_layers, shape.d_model, self.batch, self.prompt_len),
+                counts["attention_layers"], counts["attention_width"],
+                self.batch, self.prompt_len),
             "decode_step_bytes": costs.decode_step_bytes(
-                costs.tree_bytes(self.params), shape.n_layers, self.batch,
-                self.prompt_len, self.max_new, counts["kv_elements"],
-                jnp.dtype(self.config["kv_cache_dtype"]).itemsize),
+                costs.tree_bytes(self.params), counts["attention_layers"],
+                self.batch, self.prompt_len, self.max_new,
+                counts["kv_elements"],
+                jnp.dtype(self.config["kv_cache_dtype"]).itemsize,
+                counts["state_elements"]),
         }
 
     def end_to_end(self, durations: dict[str, list[float]]) -> dict:

@@ -189,15 +189,16 @@ class Job:
     # ---- the results -----------------------------------------------------
 
     def facts(self) -> dict:
-        counts = self.reference.counts(self.shape)
+        counts = program.counts(self.reference, self.shape)
         stream = self.stream.stats()
         return {
             "chips": len(self.devices),
             "n_params": self.n_params,
             "tokens_per_sample": self.batch * self.seq,
+            "counts": counts,
             "flops_per_token": costs.train_flops_per_token(
-                counts["active_params"], self.shape.n_layers,
-                self.shape.d_model, self.seq),
+                counts["active_params"], counts["attention_layers"],
+                counts["attention_width"], self.seq),
             # the input stream's own counters since warm-up
             "stream": {key: value - self.stream_warm[key]
                        for key, value in stream.items()},

@@ -3,8 +3,6 @@ count of the pairs a causal mask leaves, and its reading of made-up events
 that carry the kernels' names as the chip's trace spells them.  CPU only:
 arithmetic, no device metric."""
 
-import math
-
 import numpy as np
 import pytest
 
@@ -72,13 +70,10 @@ def test_a_device_of_the_mesh_has_its_share_of_batch_and_heads(metric):
     assert metric.device_shape(four, 8, 2048) == (4, 16, 2048, 128, 2)
 
 
-def _run(workload: str, counts: dict, seconds_each: float,
-         n_params: int | None = None) -> RunData:
+def _run(workload: str, counts: dict, seconds_each: float) -> RunData:
+    """What ``run.measure`` hands the reader of a traced run of the cell:
+    the cell's two files, and the facts its reference counts."""
     cell = cells.resolve(workload)
-    if n_params is None:
-        table = program.param_table(program.reference(cell.config),
-                                    cell.config)
-        n_params = sum(math.prod(dims) for dims, _std in table.values())
     events = []
     for kernel, n in counts.items():
         name = (f"%{kernel}.16 = (bf16[8,2048,2048]{{2,1,0:T(8,128)(2,1)}}, "
@@ -89,11 +84,13 @@ def _run(workload: str, counts: dict, seconds_each: float,
     events.append(Event("/device:TPU:0", "XLA Ops",
                         "%fusion.1 = bf16[8] fusion(%flash_fwd.16)",
                         0.0, 5e9))      # names a kernel, is not one
-    facts = {"chips": cell.chips, "n_params": n_params,
-             "tokens_per_sample": cell.traffic["batch"] * cell.traffic["seq"]}
+    ref = program.reference(cell.config)
+    facts = {"chips": cell.chips,
+             "counts": program.counts(ref,
+                                      ref.Shape.from_config(cell.config))}
     return RunData(durations={}, facts=facts, peaks=PEAKS, trace=object(),
                    compiles_in_window=0, peak_bytes=None, scopes={},
-                   events=events)
+                   events=events, config=cell.config, traffic=cell.traffic)
 
 
 @pytest.mark.parametrize("workload,batch", [(ONE_CHIP, 8), (FOUR_CHIPS, 4)])
@@ -144,19 +141,31 @@ def test_no_kernel_event_reads_as_nothing(metric):
     assert metric.read(untraced) is None
 
 
-@pytest.mark.parametrize("change,n_params,says", [
-    ({"flash_fwd": -1, "flash_bwd_dq": -1, "flash_bwd_dkv": -1}, None,
-     "whole steps"),                                    # a cut sample
-    ({"flash_bwd_dkv": None}, None, "whole steps"),     # a kernel missing
-    ({"flash_fwd": +1}, None, "whole steps"),           # one forward more
-    ({}, 1, "none of this metric's cells"),             # another model's run
-], ids=["cut", "dkv_missing", "forward_extra", "other_model"])
-def test_kernels_that_engaged_in_part_raise(metric, change, n_params, says):
+@pytest.mark.parametrize("change", [
+    {"flash_fwd": -1, "flash_bwd_dq": -1, "flash_bwd_dkv": -1},  # a cut sample
+    {"flash_bwd_dkv": None},                            # a kernel missing
+    {"flash_fwd": +1},                                  # one forward more
+], ids=["cut", "dkv_missing", "forward_extra"])
+def test_kernels_that_engaged_in_part_raise(metric, change):
     """Events that carry the kernels' names and do not add up to the cell's
     steps must not read like the parent's absent kernels."""
     calls = cells.resolve(ONE_CHIP).config["num_hidden_layers"]
     counts = dict.fromkeys(metric.KERNELS, 3 * calls)
     for kernel, by in change.items():
         counts[kernel] = 0 if by is None else counts[kernel] + by
-    with pytest.raises(ValueError, match=says):
-        metric.read(_run(ONE_CHIP, counts, 2e-3, n_params=n_params))
+    with pytest.raises(ValueError, match="whole steps"):
+        metric.read(_run(ONE_CHIP, counts, 2e-3))
+
+
+def test_kernels_run_in_the_layers_that_attend(metric):
+    """A model whose reference counts fewer layers that attend than it has
+    layers calls the kernels that many times a step."""
+    calls = cells.resolve(ONE_CHIP).config["num_hidden_layers"]
+    every = dict.fromkeys(metric.KERNELS, 3 * calls)
+    whole = metric.read(_run(ONE_CHIP, every, 2e-3))
+    fewer = _run(ONE_CHIP, dict.fromkeys(metric.KERNELS, 3 * 2), 2e-3)
+    fewer.facts["counts"]["attention_layers"] = 2
+    assert metric.read(fewer) == pytest.approx(whole)
+    with pytest.raises(ValueError, match="whole steps"):
+        metric.read(_run(ONE_CHIP, dict.fromkeys(metric.KERNELS, 3 * 2 + 1),
+                         2e-3))

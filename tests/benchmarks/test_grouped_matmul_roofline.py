@@ -3,8 +3,6 @@ counts made by hand, and its reading of made-up events that carry the
 kernel's name as the chip's trace spells it.  CPU only: arithmetic, no
 device metric."""
 
-import math
-
 import pytest
 
 from benchmarks.lib import cells, program
@@ -43,7 +41,9 @@ def test_the_prefill_is_bound_by_operations_and_the_step_by_bytes(metric):
     assert 0.3e-3 < step < 0.4e-3 and 8e-3 < prefill < 9e-3
 
 
-def _run(n_events: int, seconds_each: float, n_params: int) -> RunData:
+def _run(n_events: int, seconds_each: float) -> RunData:
+    """What ``run.measure`` hands the reader of a traced run of the cell:
+    the cell's two files, and the facts its reference counts."""
     cell = cells.resolve(CELL)
     name = ("%grouped_matmul.57 = bf16[1408,1024]{1,0:T(8,128)(2,1)S(1)} "
             "custom-call(s32[88]{0} %broadcast_minimum_fusion.5)")
@@ -54,25 +54,31 @@ def _run(n_events: int, seconds_each: float, n_params: int) -> RunData:
                         0.0, 5e9))      # names the kernel, is not it
     facts = {key: cell.traffic[key] for key in ("batch", "prompt_len",
                                                 "max_new")}
-    return RunData(durations={}, facts={**facts, "n_params": n_params},
-                   peaks=PEAKS, trace=object(), compiles_in_window=0,
-                   peak_bytes=None, scopes={}, events=events)
+    ref = program.reference(cell.config)
+    facts["counts"] = program.counts(ref, ref.Shape.from_config(cell.config))
+    return RunData(durations={}, facts=facts, peaks=PEAKS, trace=object(),
+                   compiles_in_window=0, peak_bytes=None, scopes={},
+                   events=events, config=cell.config, traffic=cell.traffic)
 
 
 def test_reading_is_least_time_over_the_kernels_time(metric):
     config = cells.resolve(CELL).config
-    table = program.param_table(program.reference(config), config)
-    n_params = sum(math.prod(dims) for dims, _std in table.values())
-    layers, new = config["num_hidden_layers"], 128
+    routed = {"layers": config["num_hidden_layers"], "experts": 64,
+              "top_k": 8, "d_model": 2048, "d_expert": 1024}
+    assert _run(0, 2e-3).facts["counts"]["routed"] == routed
+    layers, new = routed["layers"], 128
     calls = 3 * layers * (2 + new - 1)
-    prefill = metric.layer_seconds(config, 48 * 1024, PEAKS)
-    step = metric.layer_seconds(config, 48, PEAKS)
+    prefill = metric.layer_seconds(routed, 2, 48 * 1024, PEAKS)
+    step = metric.layer_seconds(routed, 2, 48, PEAKS)
     least = layers * (2 * prefill + (new - 1) * step)
     assert 1.0 < least < 1.5    # seconds of a pair's kernels at their bounds
     # two traced samples, every call 2 ms
-    got = metric.read(_run(2 * calls, 2e-3, n_params))
+    got = metric.read(_run(2 * calls, 2e-3))
     assert got == pytest.approx(100 * 2 * least / (2 * calls * 2e-3))
-    # another model's run, a cut sample, no kernel event: left out
-    assert metric.read(_run(2 * calls, 2e-3, n_params + 1)) is None
-    assert metric.read(_run(calls - 1, 2e-3, n_params)) is None
-    assert metric.read(_run(0, 2e-3, n_params)) is None
+    # a cut sample, no kernel event: left out
+    assert metric.read(_run(calls - 1, 2e-3)) is None
+    assert metric.read(_run(0, 2e-3)) is None
+    # a run whose reference names no routed layers
+    dense = _run(2 * calls, 2e-3)
+    del dense.facts["counts"]["routed"]
+    assert metric.read(dense) is None

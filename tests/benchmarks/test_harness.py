@@ -277,6 +277,49 @@ def test_a_fourth_cell_is_added_without_editing_a_file(tmp_path, meter):
                                         "metrics/added_metric.py"}
 
 
+def add_family(tmp_path, bench_dir: str, stem: str, counts: dict, share: str,
+               keys: list[str], also: tuple[str, ...] = ()):
+    """To the copied benchmark, by files and rows alone: a reference
+    ``<stem>.py`` (the equations of the first decode configuration's, with
+    ``counts`` of its own), a configuration ``<stem>-family`` that names it,
+    the cell ``<stem>-cell`` on that configuration's decode mix, which
+    reports what the base cell reports and the metrics ``also`` names, and
+    the share ``share`` of the scope table's ``keys``, as data.  Returns the
+    base cell's row and the new cell at tiny sizes."""
+    base = next(w for w in BENCH["workloads"] if w["chips"] == 1
+                and "prompt_len" in cells.resolve(w["name"]).traffic)
+    config = cells.resolve(base["name"]).config
+    with open(os.path.join(bench_dir, "reference",
+                           config["reference"] + ".py")) as f:
+        equations = f.read()
+    with open(os.path.join(bench_dir, "reference", stem + ".py"), "w") as f:
+        f.write(equations + f"\n\ndef counts(shape):\n"
+                            f"    return {counts!r}\n")
+    family, cell = stem + "-family", stem + "-cell"
+    config = {**config, "name": family, "reference": stem}
+    with open(os.path.join(bench_dir, "configs", family + ".json"), "w") as f:
+        json.dump(config, f)
+    with open(os.path.join(bench_dir, "metrics", share + ".json"), "w") as f:
+        json.dump({"reader": "scope_share", "keys": keys}, f)
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append({**BENCH["configs"][0], "name": family,
+                             "file": f"benchmarks/configs/{family}.json"})
+    bench["workloads"].append({"name": cell, "config": family,
+                               "traffic": base["traffic"], "chips": 1,
+                               "why": "dry addition"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if base["name"] in m.get("workloads", []) or m["name"] in also:
+            m["workloads"].append(cell)
+    moved = next(m["name"] for m in BENCH["end_to_end"]
+                 if base["name"] in m.get("workloads", []))
+    bench["per_layer"].append({"name": share, "unit": "%", "better": "lower",
+                               "source": "device_trace", "layer": "decoder",
+                               "moves": moved, "workloads": [cell]})
+    with open(tmp_path / "BENCHMARK.json", "w") as f:
+        json.dump(bench, f)
+    return base, tiny(cells.resolve(cell, bench_dir))
+
+
 OTHER_COUNTS = {"active_params": 1000 + 35, "projection_params": 35,
                 "kv_elements": 6}
 
@@ -292,43 +335,9 @@ def test_a_model_of_another_family_is_added_without_editing_a_file(
     recorded trace, and every file the benchmark had is byte for byte what
     it was."""
     bench_dir, before = copied_benchmark(tmp_path)
-
-    base = next(w for w in BENCH["workloads"] if w["chips"] == 1
-                and "prompt_len" in cells.resolve(w["name"]).traffic)
-    config = cells.resolve(base["name"]).config
-    with open(os.path.join(bench_dir, "reference",
-                           config["reference"] + ".py")) as f:
-        equations = f.read()
-    with open(os.path.join(bench_dir, "reference", "other.py"), "w") as f:
-        f.write(equations + f"\n\ndef counts(shape):\n"
-                            f"    return {OTHER_COUNTS!r}\n")
-    config = {**config, "name": "other-family", "reference": "other"}
-    with open(os.path.join(bench_dir, "configs", "other-family.json"),
-              "w") as f:
-        json.dump(config, f)
     keys = ["scope/unembed@decode.step", "scope/ffn@decode.step"]
-    with open(os.path.join(bench_dir, "metrics", "other_weights_share.json"),
-              "w") as f:
-        json.dump({"reader": "scope_share", "keys": keys}, f)
-    bench = json.loads(json.dumps(BENCH))
-    bench["configs"].append({**BENCH["configs"][0], "name": "other-family",
-                             "file": "benchmarks/configs/other-family.json"})
-    bench["workloads"].append({"name": "other-cell", "config": "other-family",
-                               "traffic": base["traffic"], "chips": 1,
-                               "why": "dry addition"})
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if base["name"] in m.get("workloads", []):
-            m["workloads"].append("other-cell")
-    moved = next(m["name"] for m in BENCH["end_to_end"]
-                 if base["name"] in m.get("workloads", []))
-    bench["per_layer"].append({"name": "other_weights_share", "unit": "%",
-                               "better": "lower", "source": "device_trace",
-                               "layer": "decoder", "moves": moved,
-                               "workloads": ["other-cell"]})
-    with open(tmp_path / "BENCHMARK.json", "w") as f:
-        json.dump(bench, f)
-
-    cell = tiny(cells.resolve("other-cell", bench_dir))
+    base, cell = add_family(tmp_path, bench_dir, "other", OTHER_COUNTS,
+                            "other_weights_share", keys)
     assert cell.config["reference"] == "other"
     # the runner is the copy's and finds the reference beside it: the
     # benchmark this test was started from has no such file
@@ -370,3 +379,114 @@ def test_a_model_of_another_family_is_added_without_editing_a_file(
     assert set(after) - set(before) == {"reference/other.py",
                                         "configs/other-family.json",
                                         "metrics/other_weights_share.json"}
+
+
+# 1 of the layers attends, 6 query heads of 8 over a K/V of 3 + 3 elements a
+# position; a sequence holds 21 elements of state beside the cache; the one
+# routed layer's experts are 24 wide, which no key of the configuration says
+HYBRID_COUNTS = {"active_params": 1000 + 35, "projection_params": 35,
+                 "kv_elements": 6, "attention_layers": 1,
+                 "attention_width": 48, "state_elements": 21,
+                 "routed": {"layers": 1, "experts": 4, "top_k": 2,
+                            "d_model": 64, "d_expert": 24}}
+NEW_SCOPE = "conv.state"
+
+
+def test_a_model_whose_layers_differ_is_added_without_editing_a_file(
+        tmp_path, meter, monkeypatch):
+    """A reference whose ``counts`` say that fewer layers attend than there
+    are, over a narrower K/V, beside a fixed-size state, with routed layers
+    of a shape of their own (the first decode configuration's equations
+    under another name: the harness reads the counts and never the
+    equations), a configuration that names it, a cell on the decode mix,
+    and a share, data alone, of a scope that the program's vocabulary gains
+    here.  The cell resolves and runs tiny and traced; its facts are the
+    hand counts from the new keys; the new name is a scope to the reader
+    and the share reads it; ``grouped_matmul_roofline`` reads the routed
+    layers' own shape; and every file the benchmark had is byte for byte
+    what it was."""
+    from ompi_tpu.core import scopes as program_scopes
+
+    bench_dir, before = copied_benchmark(tmp_path)
+    routed_kernel = "grouped_matmul_roofline"
+    _base, cell = add_family(
+        tmp_path, bench_dir, "hybrid", HYBRID_COUNTS, "hybrid_state_share",
+        [f"scope/{NEW_SCOPE}@decode.step"], also=(routed_kernel,))
+    assert cell.runner.__file__.startswith(bench_dir)
+    dump = str(tmp_path / "dump")
+    traced = measure(cell, meter, trace=True, dump=dump)
+    assert traced["correct"] is True, traced["checks"]
+    assert traced["metrics"]["decode_step_ms"]["value"] > 0
+    # no device plane on the CPU: nothing to read, and nothing raised
+    assert not {"hybrid_state_share", routed_kernel} & set(traced["metrics"])
+    with open(os.path.join(dump, "hybrid-cell.seed3.trace1.json")) as f:
+        facts = json.load(f)["facts"]
+    assert facts["counts"] == HYBRID_COUNTS
+    t = cell.traffic
+    B, T = t["batch"], t["prompt_len"]
+    assert cell.config["num_hidden_layers"] > 1     # not every layer attends
+    assert facts["prefill_flops"] == (
+        B * T * (2 * 1000 + 4 * 1 * 48 * T) + B * 2 * 35)
+    assert facts["decode_step_bytes"] == (
+        facts["n_params"] * 4 + 1 * B * (T + t["max_new"] / 2) * 6 * 2
+        + B * 21 * 2)
+
+    # the program's vocabulary gains a name: the reader's table gains its
+    # keys, and the share, which is data, reads them
+    reader = dict((r["name"], rd) for r, rd in cell.per_layer)
+    under = "jit(decode)/shard_map/decode.step/layers/while/body/"
+    events = [xplane.Event("/device:TPU:0", xplane.OPS_LINE, name, lo,
+                           hi - lo, under + scope)
+              for name, lo, hi, scope in [
+                  ("fusion.1", 0, 30, NEW_SCOPE + "/mul"),
+                  ("fusion.2", 30, 100, "attention/exp")]]
+
+    def run_of(events):
+        return RunData(durations={}, facts=facts, peaks=MADE_UP_PEAKS,
+                       trace=xplane.reduce_events(events),
+                       compiles_in_window=0, peak_bytes=None,
+                       scopes=scopes.reduce_scopes(events), events=events,
+                       config=cell.config, traffic=cell.traffic)
+
+    scopes.classify.cache_clear()
+    assert scopes.classify(events[0].scope).scope == "layers"
+    assert reader["hybrid_state_share"].read(run_of(events)) is None
+    monkeypatch.setattr(program_scopes, "SCOPES",
+                        program_scopes.SCOPES + (NEW_SCOPE,))
+    scopes.classify.cache_clear()
+    try:
+        assert scopes.classify(events[0].scope).chain == (
+            "decode.step", "layers", NEW_SCOPE)
+        run = run_of(events)
+        assert run.scopes["self/" + NEW_SCOPE] == pytest.approx(30e-9)
+        assert reader["hybrid_state_share"].read(run) == pytest.approx(30.0)
+        assert reader["decode_attention_share"].read(run) == pytest.approx(
+            70.0)
+    finally:
+        scopes.classify.cache_clear()
+
+    # the routed layers' kernel, read by the shape the reference counts:
+    # one routed layer, so three calls a pass; every call 1 ms
+    routed = HYBRID_COUNTS["routed"]
+    calls = 3 * routed["layers"] * (2 + t["max_new"] - 1)
+    kernel = ("%grouped_matmul.3 = bf16[64,24]{1,0:T(8,128)(2,1)} "
+              "custom-call(s32[8]{0} %fusion.5)")
+    kernels = [xplane.Event("/device:TPU:0", xplane.OPS_LINE, kernel,
+                            2e6 * i, 1e6) for i in range(calls)]
+    metric = reader[routed_kernel]
+
+    def layer(tokens):      # gate and up, then down, of bfloat16
+        rows = tokens * routed["top_k"]
+        return (2 * metric.least_seconds(rows, 64, 24, 4, 2, MADE_UP_PEAKS)
+                + metric.least_seconds(rows, 24, 64, 4, 2, MADE_UP_PEAKS))
+
+    least = routed["layers"] * (2 * layer(t["batch"] * t["prompt_len"])
+                                + (t["max_new"] - 1) * layer(t["batch"]))
+    assert metric.read(run_of(kernels)) == pytest.approx(
+        100 * least / (calls * 1e-3))
+
+    after = digest(bench_dir)
+    assert {k: after[k] for k in before} == before
+    assert set(after) - set(before) == {"reference/hybrid.py",
+                                        "configs/hybrid-family.json",
+                                        "metrics/hybrid_state_share.json"}

@@ -109,21 +109,62 @@ def test_decode_check_accepts_the_decoder_and_rejects_a_swapped_token(name):
     assert bad[0, 3] > 1.0
 
 
+MUST_COUNT = {"active_params", "projection_params", "kv_elements"}
+MAY_COUNT = {"attention_layers", "attention_width", "state_elements",
+             "routed"}
+ROUTED = {"layers", "experts", "top_k", "d_model", "d_expert"}
+
+
 @pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_counts_are_of_the_references_own_tree(name):
-    """``counts(shape)`` at the real sizes: no more active parameters than
-    the tree holds, a projection among them, and K and V of one position."""
+    """``counts(shape)`` at the real sizes: the three keys every reference
+    gives, of the four it may give none that the tree cannot bear out (no
+    more active parameters than the tree holds, a projection among them, K
+    and V of one position; no more layers that attend or route than there
+    are layers, no more experts' parameters than are stored), and no key
+    beyond them, which no cost function would read."""
     config = CONFIGS[name]
     ref = program.reference(config)
     shape = ref.Shape.from_config(config)
     stored = sum(math.prod(dims) for dims, _std in
                  program.param_table(ref, config).values())
     counts = ref.counts(shape)
-    assert set(counts) == {"active_params", "projection_params",
-                           "kv_elements"}
-    assert all(isinstance(v, int) and v > 0 for v in counts.values())
+    assert MUST_COUNT <= set(counts) <= MUST_COUNT | MAY_COUNT
+    assert all(isinstance(counts[k], int) and counts[k] > 0
+               for k in MUST_COUNT)
     assert counts["projection_params"] == shape.vocab * shape.d_model
     assert counts["projection_params"] < counts["active_params"] <= stored
+    whole = program.counts(ref, shape)
+    assert set(whole) - {"routed"} == (MUST_COUNT | MAY_COUNT) - {"routed"}
+    assert {k: whole[k] for k in counts} == counts
+    assert 0 < whole["attention_layers"] <= shape.n_layers
+    assert whole["attention_width"] > 0 and whole["state_elements"] >= 0
+    if "routed" in counts:
+        routed = counts["routed"]
+        assert set(routed) == ROUTED
+        assert all(isinstance(v, int) and v > 0 for v in routed.values())
+        assert routed["layers"] <= shape.n_layers
+        assert routed["top_k"] <= routed["experts"]
+        one_expert = 3 * routed["d_model"] * routed["d_expert"]
+        assert routed["layers"] * routed["experts"] * one_expert <= stored
+        assert (routed["layers"] * routed["top_k"] * one_expert
+                < counts["active_params"])
+
+
+def test_a_reference_that_says_nothing_more_counts_every_layer():
+    """The defaults of the keys a reference may leave out."""
+    class Ref:
+        @staticmethod
+        def counts(shape):
+            return {"active_params": 9, "projection_params": 2,
+                    "kv_elements": 4, "attention_layers": 3}
+
+    class Shape:
+        n_layers, d_model = 7, 5
+
+    assert program.counts(Ref, Shape) == {
+        "active_params": 9, "projection_params": 2, "kv_elements": 4,
+        "attention_layers": 3, "attention_width": 5, "state_elements": 0}
 
 
 # ---- the arithmetic, against counts made by hand ---------------------------
@@ -132,7 +173,10 @@ def test_counts_are_of_the_references_own_tree(name):
 # reference had ``counts`` (the parent of the PR that added them): every
 # parameter active, the tied embedding the projection, K and V as wide as
 # the model.  ``train_mfu``, ``prefill_mfu`` and ``decode_hbm_share`` are
-# these integers over a time.
+# these integers over a time.  OLMoE's are what its reference's ``counts``
+# gave before a reference could say which layers attend (the parent of the
+# PR that let it): 7 layers, all of them attending and routed, the
+# parameters stored in bfloat16.
 HAND_COUNTS = {
     "pythia-1.4b-widths": dict(
         n_params=405_039_104,           # 6 layers of 50.3M and 103M embedding
@@ -150,6 +194,17 @@ HAND_COUNTS = {
         + 48 * 2 * 50432 * 4096,
         decode_step_bytes=4 * 1_011_912_704
         + 2 * 4 * 48 * (1024 + 64) * 4096 * 2),
+    "olmoe-1b-7b": dict(
+        # 7 layers of 4 projections, two norms' scales, a router and 64
+        # experts of three matrices; embedding, head and the last norm
+        n_params=7 * (4 * 2048 * 2048 + 2 * 2048 + 2 * 2048 + 2048 * 64
+                      + 64 * 3 * 2048 * 1024) + 2 * 50304 * 2048 + 2048,
+        # a token multiplies 8 of the 64 experts, and the head once
+        prefill_flops=48 * 1024 * (
+            2 * 7 * (4 * 2048 * 2048 + 2048 * 64 + 8 * 3 * 2048 * 1024)
+            + 4 * 7 * 2048 * 1024) + 48 * 2 * 50304 * 2048,
+        decode_step_bytes=2 * 3_143_034_880
+        + 2 * 7 * 48 * (1024 + 64) * 2048 * 2),
 }
 # the parent's ``facts()``, printed (CPU box, shapes only)
 PARENT_FACTS = {
@@ -159,13 +214,18 @@ PARENT_FACTS = {
     "pythia-6.9b-widths": dict(
         n_params=1011912704, flops_per_token=6474129408,
         prefill_flops=82486826631168, decode_step_bytes=7470202880.0),
+    "olmoe-1b-7b": dict(
+        n_params=3143034880, prefill_flops=49165790871552,
+        decode_step_bytes=9280802816.0),
 }
 
 
 @pytest.mark.parametrize("name", sorted(HAND_COUNTS))
 def test_facts_of_the_pythia_configurations_are_the_hand_counts(name):
     """Through the runners' own ``facts()`` at the real sizes (shapes only:
-    nothing is placed or run), at the sizes of both traffic files."""
+    nothing is placed or run), at the sizes of both traffic files; a
+    configuration pinned without ``flops_per_token`` is one that no cell
+    trains, and is held at the decode mix alone."""
     config, want = CONFIGS[name], HAND_COUNTS[name]
     assert want == PARENT_FACTS[name]
     ref = program.reference(config)
@@ -180,7 +240,8 @@ def test_facts_of_the_pythia_configurations_are_the_hand_counts(name):
         def stats(self):
             return {"batches": 7, "starved": 2}
 
-    for mix in ("train-2k", "decode-1k-128"):
+    mixes = ("train-2k", "decode-1k-128")
+    for mix in mixes if "flops_per_token" in want else mixes[1:]:
         traffic = cells.load_json(f"{cells.BENCH_DIR}/traffic/{mix}.json")
         runner = cells.load_module(
             f"{cells.BENCH_DIR}/runners/{traffic['runner']}.py")
@@ -191,6 +252,7 @@ def test_facts_of_the_pythia_configurations_are_the_hand_counts(name):
         for key in facts.keys() & want.keys():
             assert facts[key] == want[key], key
             assert facts[key] == int(facts[key])
+        assert facts["counts"] == program.counts(ref, job.shape)
         if "stream" in facts:
             assert facts["stream"] == {"batches": 4, "starved": 0}
         assert facts.keys() & {"flops_per_token", "prefill_flops"}
@@ -219,3 +281,22 @@ def test_decode_bytes_are_parameters_plus_live_kv():
     assert costs.decode_step_bytes(127, L, B, Tp, N, 2 * D, 2) == 127 + live
     # grouped K/V heads: a quarter of the elements, a quarter of the bytes
     assert costs.kv_bytes(L, B, Tp + N / 2, 2 * D // 4, 2) == live / 4
+
+
+def test_layers_that_do_not_attend_hold_a_state_and_no_cache():
+    """A model of 9 layers of which 2 attend, 32 query heads of 64 over 8
+    K/V heads, and 7 layers that keep the last 3 positions of 2048 channels
+    a sequence: the attention terms count the 2 layers at the query heads'
+    width, the cache the 2 layers at the K/V heads', and a cached step
+    reads every sequence's state once."""
+    attend, D, S, B, Tp, N = 2, 32 * 64, 2048, 48, 1024, 128
+    kv, state = 2 * 8 * 64, 7 * 3 * 2048
+    assert costs.train_flops_per_token(1000, attend, D, S) == (
+        6 * 1000 + 12 * 2 * 2048 * 2048)
+    assert costs.prefill_flops(1000 + 35, 35, attend, D, B, Tp) == (
+        B * Tp * (2 * 1000 + 4 * 2 * 2048 * Tp) + B * 2 * 35)
+    cache = 2 * B * (Tp + N / 2) * kv * 2
+    assert costs.decode_step_bytes(127, attend, B, Tp, N, kv, 2) == (
+        127 + cache)
+    assert costs.decode_step_bytes(127, attend, B, Tp, N, kv, 2, state) == (
+        127 + cache + B * 7 * 3 * 2048 * 2)

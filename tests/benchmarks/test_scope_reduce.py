@@ -601,7 +601,7 @@ def test_scope_reduction_on_events_recorded_on_the_chip(path):
     assert table["unscoped"] < 0.05 * s.busy_s
     assert all(0.0 <= v <= s.window_s for k, v in table.items()
                if k != "executions")
-    for name in scopes.VOCABULARY:
+    for name in scopes.vocabulary.SCOPES:
         if "scope/" + name in table:
             assert table["self/" + name] <= table["scope/" + name] * (1 + 1e-9)
     if "phase/bwd" in table:                # a train step

@@ -95,9 +95,32 @@ def share_cases():
                                    id=f"{workload}-{name}-{key}")
 
 
-def test_the_two_vocabularies_are_one():
-    assert scopes.VOCABULARY == program_scopes.SCOPES
-    assert scopes.COLL == program_scopes.COLL + "."
+def test_the_reader_holds_no_vocabulary_of_its_own(monkeypatch):
+    """A name appended to the program's tuple is a scope to the reader, a
+    key of its table, with no edit under ``benchmarks/``; before that it is
+    an element like any other."""
+    op_name = "jit(decode)/shard_map/decode.step/layers/conv.state/mul"
+    assert not any(isinstance(v, tuple) and "attention" in v
+                   for v in vars(scopes).values())
+    classify.cache_clear()
+    assert classify(op_name).chain == ("decode.step", "layers")
+    monkeypatch.setattr(program_scopes, "SCOPES",
+                        program_scopes.SCOPES + ("conv.state",))
+    classify.cache_clear()
+    try:
+        where = classify(op_name)
+        assert where.chain == ("decode.step", "layers", "conv.state")
+        assert scopes.keys_of(where)[-2:] == ("self/conv.state",
+                                              "self/conv.state@decode.step")
+        with program_scopes.scope("conv.state"):
+            pass
+        site = f"jit(f)/{program_scopes.COLL}.allreduce.tp/psum"
+        assert classify(site).coll == "allreduce.tp"
+    finally:
+        classify.cache_clear()
+
+
+def test_a_name_outside_the_vocabulary_is_refused():
     with pytest.raises(ValueError):
         program_scopes.scope("attnetion")
 
