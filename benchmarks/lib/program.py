@@ -37,10 +37,19 @@ def reference(config: dict, bench_dir: str = cells.BENCH_DIR):
     runners and readers are, so a model of another family brings its own.
 
     A reference module has ``Shape.from_config(config)`` (with ``vocab``,
-    ``d_model`` and ``n_layers``), ``param_init(shape)``, ``logits``,
-    ``loss``, ``token_deficits`` and ``counts(shape)``, which says what
-    ``lib/costs.py`` may count of this family (``counts`` below has the
-    keys)."""
+    ``d_model`` and ``n_layers``), ``param_init(shape, serving=False)``
+    (``param_table`` below), ``logits``, ``loss``, ``token_deficits`` and
+    ``counts(shape)``, which says what ``lib/costs.py`` may count of this
+    family (``counts`` below has the keys).
+
+    Keys a configuration file may carry beside its published ones and those
+    every file has (``entry``, ``mesh``, ``reference``, ``tiny``, ...):
+    ``check``, which a configuration that a cell decodes must give: the
+    limits its decoder is held to, ``{"limit": x, "why": "..."}`` each; and
+    ``entry.decoder_logits``, the keyword of ``entry.decoder`` that makes it
+    hand back the logits of the first n sequences, whose limits ``check``
+    then holds (the runner of a decode mix says in its docstring what a
+    decoder may return, which limits it reads and what is asked)."""
     return cells.load_module(os.path.join(bench_dir, "reference",
                                           config["reference"] + ".py"))
 
@@ -79,10 +88,13 @@ def param_shardings(config: dict, cfg, on_mesh) -> dict:
     return {name: NamedSharding(on_mesh, spec) for name, spec in specs.items()}
 
 
-def param_table(ref, config: dict) -> dict:
+def param_table(ref, config: dict, serving: bool = False) -> dict:
     """Leaf name -> (shape, standard deviation or None), as the reference
-    ``ref`` lays out the parameters of ``config``."""
-    return ref.param_init(ref.Shape.from_config(config))
+    ``ref`` lays out the parameters of ``config``.  ``serving`` asks for the
+    deviations the reference declares for greedy decoding (a decode runner
+    does; a reference whose draw serves both gives one table): the shapes
+    are the same either way."""
+    return ref.param_init(ref.Shape.from_config(config), serving=serving)
 
 
 def abstract_params(ref, config: dict, shardings: dict) -> dict:
@@ -94,15 +106,17 @@ def abstract_params(ref, config: dict, shardings: dict) -> dict:
             for name, (dims, _std) in param_table(ref, config).items()}
 
 
-def init_params(ref, config: dict, shardings: dict, seed: int) -> dict:
+def init_params(ref, config: dict, shardings: dict, seed: int,
+                serving: bool = False) -> dict:
     """Seeded random parameters made on the devices, already sharded, in one
     jitted call and in the type they are stored in: no host array and no
     transfer.  Leaf ``i`` in name order draws from ``fold_in(key(seed), i)``;
-    with JAX's partitionable threefry the values do not depend on the mesh."""
+    with JAX's partitionable threefry the values do not depend on the mesh.
+    ``serving``: at the deviations ``param_table`` gives for serving."""
     import jax
     import jax.numpy as jnp
 
-    table = param_table(ref, config)
+    table = param_table(ref, config, serving)
     dtype = jnp.dtype(config["param_dtype"])
 
     def make(key):

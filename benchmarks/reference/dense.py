@@ -42,21 +42,52 @@ class Shape:
                    d_ff=config["intermediate_size"])
 
 
-def param_init(shape: Shape) -> dict[str, tuple[tuple[int, ...], float | None]]:
+# The draw for serving (``param_init(shape, serving=True)``: a decode runner
+# asks for it, the train runner does not, so the train cells' parameters and
+# losses are what they were), as gains on fan-in ** -0.5; PERF.md section 2
+# has the chip's readings.  At the trainer's draw greedy decoding of random
+# prompts repeats one token (``repeat_share`` 0.99 to 1.0 in 10 seeds on the
+# chip), for two reasons.  The head is the embedding, so a token's own
+# embedding votes for it by sqrt(d_model) * |emb| / |stream| deviations: 2
+# where the blocks' outputs are divided by sqrt(2 * layers).  And tanh-GELU
+# of a unit-deviation input has a mean of 0.28, so every FFN adds one fixed
+# vector at every position, and attention whose scores have deviation 1 is a
+# mean over a thousand positions: it passes what all positions share at gain
+# one and what differs at a twentieth, until the stream is that vector.
+# Hence: ``w1`` at a tenth (the FFN's fixed vector is then 0.6% of its
+# output's power, not 18%) and ``w2`` at 27, so the stream is 170 times the
+# embedding and a token's vote for itself 0.27 deviations; ``wq`` at 1.5,
+# so scores have deviation 1.5 and a query weighs 150 positions of 1024 (at
+# 1.0 the constant still takes over in some seeds, ``repeat_share`` up to
+# 0.38; at 3 the sound program's worst deficit reads 0.05 to 0.11 and at 4
+# 0.14 to 0.23, where 1.5 reads up to 0.056, because a sharper softmax
+# multiplies the noise of its scores); ``wo`` at 0.7.  Of the last layer's
+# stream an FFN layer is then 16% and an attending layer 0.5% (CPU box,
+# float32, seed 3): a zeroed attending layer moves every logit by 0.07
+# deviations and the sound program by 0.006.
+SERVING = {"wq": 1.5, "w1": 0.1, "wo": 0.7, "w2": 27.0}
+
+
+def param_init(shape: Shape, serving: bool = False
+               ) -> dict[str, tuple[tuple[int, ...], float | None]]:
     """Leaf name -> (shape, standard deviation of its normal initializer);
     ``None`` marks a norm scale, which starts at one.  Layers are stacked on
     the leading axis.  Scaled as the program's own initializer scales them,
-    so that a loss at the initial parameters is near ln(vocab)."""
+    so that a loss at the initial parameters is near ln(vocab); ``serving``
+    gives the draw for greedy decoding, above."""
     L, D, F, V = shape.n_layers, shape.d_model, shape.d_ff, shape.vocab
     depth = math.sqrt(max(1, 2 * L))
+    gain = {"wq": 1.0, "w1": 1.0, "wo": 1 / depth, "w2": 1 / depth}
+    if serving:
+        gain = SERVING
     return {
         "emb": ((V, D), 0.02),
-        "wq": ((L, D, D), D ** -0.5),
+        "wq": ((L, D, D), D ** -0.5 * gain["wq"]),
         "wk": ((L, D, D), D ** -0.5),
         "wv": ((L, D, D), D ** -0.5),
-        "wo": ((L, D, D), D ** -0.5 / depth),
-        "w1": ((L, D, F), D ** -0.5),
-        "w2": ((L, F, D), F ** -0.5 / depth),
+        "wo": ((L, D, D), D ** -0.5 * gain["wo"]),
+        "w1": ((L, D, F), D ** -0.5 * gain["w1"]),
+        "w2": ((L, F, D), F ** -0.5 * gain["w2"]),
         "ln1": ((L, D), None),
         "ln2": ((L, D), None),
         "lnf": ((D,), None),

@@ -22,7 +22,9 @@ all; ``python3 -m benchmarks.lib.scopes <file>`` reads them again.
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` (and ``breakdown`` when traced); the
-further keys ``checks``, ``setup`` and ``spans`` are for people.  There is
+further keys ``setup``, ``spans`` and, last, ``checks`` are for people:
+``checks`` has every number ``correct`` compared beside its limit, and is
+also the last lines of stderr.  There is
 no CPU mode: without a TPU, with fewer chips than the cell asks for, or on a
 device without published peaks, it exits non-zero and prints no result.
 """
@@ -168,10 +170,13 @@ def measure(cell: cells.Cell, devices, meter, spans: Spans, seed: int,
         result["breakdown"] = {"device_ops": summary.device_ops,
                                "idle_gaps": summary.idle_gaps,
                                "device_scopes": table, **by_clock}
+    # ``checks`` comes last: every number ``correct`` compared, beside its
+    # limit, where the end of the line is what a record keeps
     result.update(
-        checks=outcome["checks"], setup=setup,
+        setup=setup,
         spans={name: {"n": len(v), "median_s": statistics.median(v)}
-               for name, v in sorted(durations.items())})
+               for name, v in sorted(durations.items())},
+        checks=outcome["checks"])
     if dump:
         os.makedirs(dump, exist_ok=True)
         stem = os.path.join(dump, f"{cell.name}.seed{seed}.trace{int(trace)}")
@@ -221,6 +226,9 @@ def main(argv=None) -> int:
     result = measure(cell, devices[:cell.chips], CompileMeter(), spans,
                      args.seed, args.seconds, bool(args.trace), peaks, T0,
                      args.dump)
+    for name, value in result["checks"].items():
+        print(f"check {name} = {value}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

@@ -7,6 +7,7 @@ checks resolution, control flow and the shape of the result line, never a
 device metric.
 """
 
+import functools
 import hashlib
 import json
 import os
@@ -490,3 +491,176 @@ def test_a_model_whose_layers_differ_is_added_without_editing_a_file(
     assert set(after) - set(before) == {"reference/hybrid.py",
                                         "configs/hybrid-family.json",
                                         "metrics/hybrid_state_share.json"}
+
+
+# ---- a decoder that hands its logits back ----------------------------------
+
+# what the dry addition below gives as its ``check``: in float32 at tiny
+# sizes the program's own forward sits within 1e-5 of the reference's
+LOGIT_CHECK = {
+    "logit_err_median": {"limit": 0.01, "why": "float32 on both sides"},
+    "logit_err_position": {"limit": 0.05, "why": "float32 on both sides"},
+    "positions_over": {"limit": 0.1, "why": "room for two positions of 40"},
+}
+
+
+def logits_decoder(cfg, mesh, max_new, keep_logits=0, fault=""):
+    """``entry.decoder`` of the dry addition: the program's decoder, wrapped
+    so that with ``keep_logits=n`` it also returns float32 ``(n, max_new,
+    vocab)`` logits, made with the program's own forward over what it
+    generated, and with ``fault`` wrong in the way the name says."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ompi_tpu.models import transformer as tfm
+    from ompi_tpu.models.decode import make_decoder
+
+    decode = make_decoder(cfg, mesh, max_new=max_new)
+    if not keep_logits:
+        return decode
+    forward = jax.jit(tfm.make_forward(cfg, mesh))
+
+    def run(params, prompts):
+        tokens = decode(params, prompts)
+        start = prompts.shape[1]
+        z = np.array(forward(params, tokens[:keep_logits])[:, start - 1:-1],
+                     np.float32)
+        rng = np.random.default_rng(0)
+        if fault == "shifted":      # every position, a tenth of a deviation
+            z += 0.1 * z.std(-1, keepdims=True) * rng.normal(size=z.shape)
+        if fault == "spiky":        # one position in twenty, three deviations
+            for n, t in zip(*np.nonzero(np.arange(z[..., 0].size).reshape(
+                    z.shape[:2]) % 20 == 7)):
+                z[n, t] += 3 * z[n, t].std() * rng.normal(size=z.shape[-1])
+        if fault in ("shifted", "spiky"):   # the picked token still leads
+            picked = np.asarray(tokens[:keep_logits, start:])[..., None]
+            np.put_along_axis(z, picked, z.max(-1, keepdims=True) + 1e-3, -1)
+        if fault == "wrong_token":
+            tokens = tokens.at[0, start + 2].set(
+                (tokens[0, start + 2] + 1) % cfg.vocab)
+        return tokens, jnp.asarray(z)
+
+    return run
+
+
+# ``entry.decoder`` is a dotted path, so each fault has a name of its own
+logits_decoder_shifted = functools.partial(logits_decoder, fault="shifted")
+logits_decoder_spiky = functools.partial(logits_decoder, fault="spiky")
+logits_decoder_wrong_token = functools.partial(logits_decoder,
+                                               fault="wrong_token")
+
+
+def add_logits_cell(tmp_path, bench_dir: str, decoder: str, check) -> cells.Cell:
+    """To the copied benchmark, by files and rows alone: the first decode
+    configuration at tiny sizes and float32 with ``entry.decoder`` the test's
+    factory ``decoder``, ``entry.decoder_logits`` its keyword and ``check``
+    (left out where None), and a cell on a mix of 40 checked positions."""
+    base = next(w for w in BENCH["workloads"] if w["chips"] == 1
+                and "prompt_len" in cells.resolve(w["name"]).traffic)
+    resolved = cells.resolve(base["name"])
+    config = json.loads(json.dumps(program.tiny(resolved.config)))
+    config["name"] = "logits-family"
+    config["entry"]["decoder"] = f"{__name__}.{decoder}"
+    config["entry"]["decoder_logits"] = "keep_logits"
+    config["entry"]["options"]["compute_dtype"] = "float32"
+    config.pop("check")     # the base's own, for tokens
+    if check is not None:
+        config["check"] = check
+    with open(os.path.join(bench_dir, "configs", "logits-family.json"),
+              "w") as f:
+        json.dump(config, f)
+    traffic = {**resolved.traffic, "batch": 4, "prompt_len": 12,
+               "max_new": 20, "reference_sequences": 2}
+    with open(os.path.join(bench_dir, "traffic", "logits-mix.json"),
+              "w") as f:
+        json.dump(traffic, f)
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append({**BENCH["configs"][0], "name": "logits-family",
+                             "file": "benchmarks/configs/logits-family.json"})
+    bench["workloads"].append({"name": "logits-cell",
+                               "config": "logits-family",
+                               "traffic": "logits-mix", "chips": 1,
+                               "why": "dry addition"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if base["name"] in m.get("workloads", []):
+            m["workloads"].append("logits-cell")
+    with open(tmp_path / "BENCHMARK.json", "w") as f:
+        json.dump(bench, f)
+    return cells.resolve("logits-cell", bench_dir)
+
+
+def over(limit: float) -> dict:
+    return {**LOGIT_CHECK, "positions_over": {"limit": limit, "why": "test"}}
+
+
+@pytest.mark.parametrize("decoder,check,correct", [
+    pytest.param("logits_decoder", LOGIT_CHECK, True, id="sound"),
+    pytest.param("logits_decoder_shifted", LOGIT_CHECK, False,
+                 id="shifted-at-every-position"),
+    pytest.param("logits_decoder_spiky", LOGIT_CHECK, True,
+                 id="one-position-in-twenty-allowed"),
+    pytest.param("logits_decoder_spiky", over(0.04), False,
+                 id="one-position-in-twenty-refused"),
+    pytest.param("logits_decoder_wrong_token", LOGIT_CHECK, False,
+                 id="token-not-its-logits-argmax"),
+])
+def test_a_decoder_that_hands_its_logits_back_is_compared_on_logits(
+        tmp_path, meter, decoder, check, correct):
+    """A configuration whose ``entry`` names ``decoder_logits``, with limits
+    of its own under ``check``, added by files and rows alone: it resolves,
+    runs tiny on the CPU, and ``correct`` asks the logits: the median of the
+    per-position error, the share of positions over the position limit, and
+    that every checked token is the argmax of the logits handed back.  Every
+    file the benchmark had is byte for byte what it was."""
+    bench_dir, before = copied_benchmark(tmp_path)
+    cell = add_logits_cell(tmp_path, bench_dir, decoder, check)
+    assert cell.runner.__file__.startswith(bench_dir)
+    line = measure(cell, meter, trace=False)
+    c = line["checks"]
+    assert line["correct"] is correct, c
+    assert c["tokens_checked"] == 40 and line["attempted"] > 0
+    assert set(line["metrics"]) == {r["name"] for r in cell.end_to_end}
+    assert c["logit_err_median_limit"] == 0.01
+    assert c["logit_err_position_limit"] == 0.05
+    if decoder == "logits_decoder":
+        assert c["logit_err_max"] < 1e-4 and c["positions_over"] == 0
+        assert c["tokens_are_argmax"] and c["deficit_max"] < 1e-3
+    elif decoder == "logits_decoder_shifted":
+        assert c["logit_err_median"] > 0.05 and c["positions_over"] == 1
+        assert c["tokens_are_argmax"]
+    elif decoder == "logits_decoder_spiky":
+        assert c["logit_err_median"] < 1e-3 and c["tokens_are_argmax"]
+        assert c["positions_over"] == 2 / 40 and c["logit_err_max"] > 1
+    else:
+        assert not c["tokens_are_argmax"] and c["logit_err_median"] < 1e-3
+    after = digest(bench_dir)
+    assert {k: after[k] for k in before} == before
+    assert set(after) - set(before) == {"configs/logits-family.json",
+                                        "traffic/logits-mix.json"}
+
+
+@pytest.mark.parametrize("check", [
+    None, {}, {k: v for k, v in LOGIT_CHECK.items() if k != "positions_over"},
+    {**LOGIT_CHECK, "logit_err_median": {"limit": 0.01}},
+    {**LOGIT_CHECK, "logit_err_median": {"limit": "0.01", "why": "a string"}},
+], ids=["no-check", "empty", "one-missing", "no-reason", "limit-not-a-number"])
+def test_a_decoder_that_hands_logits_back_without_its_limits_is_refused(
+        tmp_path, check):
+    bench_dir, _before = copied_benchmark(tmp_path)
+    cell = add_logits_cell(tmp_path, bench_dir, "logits_decoder", check)
+    with pytest.raises(ValueError, match="check"):
+        cell.runner.build(cell.config, cell.traffic, jax.devices()[:1])
+
+
+@pytest.mark.parametrize("workload", WORKLOADS)
+def test_a_configuration_without_decoder_logits_is_built_as_before(workload):
+    """None of the benchmark's own configurations names ``decoder_logits``:
+    their decoders are built with ``max_new`` alone, return tokens, and are
+    held to the two limits for tokens of their file's ``check``."""
+    cell = cells.resolve(workload)
+    assert "decoder_logits" not in cell.config["entry"]
+    if "prompt_len" in cell.traffic:    # held to its own limits, on tokens
+        job = cell.runner.build(program.tiny(cell.config), cell.traffic,
+                                jax.devices()[:cell.chips])
+        assert job.kept == {}
+        assert set(job.held_to()) == {"deficit_max", "mismatch_share"}
