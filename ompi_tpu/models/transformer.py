@@ -117,10 +117,10 @@ class TransformerConfig:
 
 
 # The one model the repo runs at a real size: 468M dense, 16 heads of 128.
-# bench.py, chip_smoke.py and the tools/ that time it take the dims from
-# here and vary them with dataclasses.replace.  ce_chunk drops the
-# (B, T, V) f32 logits + log-softmax pair (~4 GiB at batch 16) to
-# O(chunk·V); batch 24 ran out of HBM on one 16 GB chip in the old sweep.
+# chip_smoke.py takes the dims from here and varies them with
+# dataclasses.replace.  ce_chunk drops the (B, T, V) f32 logits +
+# log-softmax pair (~4 GiB at batch 16) to O(chunk·V); batch 24 ran out of
+# HBM on one 16 GB chip in the old sweep.
 FLAGSHIP = TransformerConfig(
     vocab=32_000, d_model=2048, n_heads=16, n_layers=8, d_ff=8192,
     seq=1024, attention="xla", ce_chunk=256, compute_dtype="bfloat16",
@@ -174,8 +174,12 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
 def param_specs(P, cfg: Optional[TransformerConfig] = None, mesh=None):
     """PartitionSpecs: attention weights tp-sharded Megatron-style, dense
     FFN tp-sharded, MoE experts ep-sharded (replicated when the mesh has
-    no "ep" axis), everything else replicated (grad-synced over dp/sp by
-    the AD transpose)."""
+    no "ep" axis), everything else replicated (grad-synced over dp/sp/tp
+    by the AD transpose).  The embedding (and an untied head) is stored
+    whole on every device: the loss splits its work over ``tp`` by
+    positions (:func:`_local_loss`), not by vocabulary rows, so each
+    rank's gradient of the table is a partial sum over its positions that
+    the transpose's psum over dp, sp and tp completes."""
     specs = {
         "emb": P(), "lnf": P(), "ln1": P(), "ln2": P(),
         "wq": P(None, None, "tp"), "wk": P(None, None, "tp"),
@@ -528,7 +532,20 @@ def _chunked_nll_sum(cfg: TransformerConfig, h, emb, labels, weight):
 
 def _local_loss(cfg: TransformerConfig, comm, params, tokens):
     """Next-token cross entropy; labels cross sp-shard boundaries via a ring
-    shift (the first token of my right neighbor labels my last position)."""
+    shift (the first token of my right neighbor labels my last position).
+
+    The hidden states are the same on every rank of a ``tp`` group (the last
+    ``row_parallel`` psum left them so) and the head is stored whole on each,
+    so where ``tp`` divides the local length, rank ``r`` of ``tp`` takes the
+    cross entropy of local positions ``[r·T/tp, (r+1)·T/tp)`` alone and the
+    sums are added over ``tp`` as over ``dp`` and ``sp``: each position's
+    logits are made once a group, not ``tp`` times.  Differentiated, the
+    slice pads the hidden states' gradient with zeros outside the rank's
+    positions and the head's gradient is a partial sum on each rank; the
+    sums over ``tp`` that the backward pass already holds (a
+    ``row_parallel`` psum's transpose, shard_map's psum for a replicated
+    leaf) add the parts.  A length ``tp`` does not divide keeps every
+    position on every rank."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -536,7 +553,8 @@ def _local_loss(cfg: TransformerConfig, comm, params, tokens):
     from ompi_tpu.core.scopes import coll, scope
 
     sp = int(comm.mesh.shape["sp"])
-    T = tokens.shape[1]
+    tp = int(comm.mesh.shape["tp"])
+    B, T = tokens.shape
     sp_idx = lax.axis_index("sp")
 
     # labels: tokens shifted left by one *global* position
@@ -554,9 +572,16 @@ def _local_loss(cfg: TransformerConfig, comm, params, tokens):
     weight = (positions < cfg.seq - 1).astype(jnp.float32)[None, :]
 
     h, aux = _local_backbone(cfg, comm, params, tokens)
+    sum_axes = ("dp", "sp")
     with scope("loss"):
+        if tp > 1 and T % tp == 0:
+            T = T // tp
+            start = lax.axis_index("tp") * T
+            h, labels, weight = (
+                lax.dynamic_slice_in_dim(x, start, T, axis=1)
+                for x in (h, labels, weight))
+            sum_axes += ("tp",)
         if cfg.ce_chunk and T % cfg.ce_chunk == 0:
-            B = tokens.shape[0]
             local_sum = _chunked_nll_sum(
                 cfg, h, _head(cfg, params), labels,
                 jnp.broadcast_to(weight, (B, T)))
@@ -566,14 +591,13 @@ def _local_loss(cfg: TransformerConfig, comm, params, tokens):
             nll = -jnp.take_along_axis(
                 logprobs, labels[..., None], axis=-1)[..., 0]
             local_sum = (nll * weight).sum()
-    local_cnt = weight.sum() * tokens.shape[0]
-    dp = int(comm.mesh.shape["dp"])
-    if dp * sp == 1:  # degenerate data/seq axes: psum is identity
-        total, count = local_sum, local_cnt
+    local_cnt = weight.sum() * B
+    if all(int(comm.mesh.shape[a]) == 1 for a in sum_axes):
+        total, count = local_sum, local_cnt  # psum is identity
     else:
-        with coll("allreduce", ("dp", "sp")):
-            total = lax.psum(local_sum, ("dp", "sp"))
-            count = lax.psum(local_cnt, ("dp", "sp"))
+        with coll("allreduce", sum_axes):
+            total = lax.psum(local_sum, sum_axes)
+            count = lax.psum(local_cnt, sum_axes)
     loss = total / count
     if cfg.moe_experts and not cfg.moe_top_k:
         # average the per-device balance loss over the whole mesh (tp/ep
@@ -760,9 +784,12 @@ def _init_on_mesh(cfg: TransformerConfig, mesh, init):
 def make_train_step(cfg: TransformerConfig, mesh, lr: float = 3e-4):
     """jitted (params, opt_state, tokens) → (params, opt_state, loss).
 
-    AdamW via optax; gradients arrive already synchronized (psum over dp/sp
-    is the AD transpose of the replicated in_specs; tp shards update their
-    local slice only — exactly ZeRO-0 + Megatron semantics).
+    AdamW via optax; gradients arrive already synchronized (psum over
+    dp/sp/tp is the AD transpose of the replicated in_specs; tp shards
+    update their local slice only — exactly ZeRO-0 + Megatron semantics).
+    Rank r of ``tp`` computes the loss of local positions
+    ``[r·T/tp, (r+1)·T/tp)`` (:func:`_local_loss`), so before that psum a
+    replicated leaf's gradient is each rank's partial sum, not a copy.
     """
     import jax
 

@@ -2,9 +2,13 @@
 ``jax.custom_vjp`` that forms each chunk's gradient in the pass that makes
 its logits.  Held to the full-logits path, to the checkpointed scan it
 replaced (a local copy), and to its own shape: one vocabulary matmul a
-chunk for the value, three for value and gradient, none recomputed."""
+chunk for the value, three for value and gradient, none recomputed.  And
+the split of the positions over ``tp`` (``_local_loss``): each rank of a
+``tp`` group scans its ``1/tp`` of the local positions, and loss and
+gradients are those of the mesh without ``tp``."""
 
 import dataclasses
+import math
 
 import numpy as np
 import pytest
@@ -23,6 +27,8 @@ MESHES = {
     "dp2sp2tp2": {"dp": 2, "sp": 2, "tp": 2},
     "dp4sp1tp2": {"dp": 4, "sp": 1, "tp": 2},
 }
+TP4 = {"dp": 2, "sp": 1, "tp": 4}
+TP_MESHES = {**MESHES, "dp2sp1tp4": TP4}
 
 
 def _tokens(cfg, batch=4, seed=1):
@@ -33,6 +39,22 @@ def _tokens(cfg, batch=4, seed=1):
 def _value_and_grad(cfg, mesh, scale=1.0):
     loss = tfm.make_loss_fn(cfg, mesh)
     return jax.jit(jax.value_and_grad(lambda p, t: scale * loss(p, t)))
+
+
+def _mesh(axes):
+    return make_mesh(axes, devices=jax.devices()[:math.prod(axes.values())])
+
+
+def _assert_same_loss_and_gradients(got, want, tol=1e-5):
+    """Loss to ``tol``, every leaf's gradient to ``tol`` of its largest
+    element."""
+    (l_got, g_got), (l_want, g_want) = got, want
+    np.testing.assert_allclose(float(l_got), float(l_want), rtol=tol)
+    assert set(g_got) == set(g_want)
+    for k in g_want:
+        a, b = np.asarray(g_got[k]), np.asarray(g_want[k])
+        assert np.abs(b).max() > 0, k
+        assert np.abs(a - b).max() <= tol * np.abs(b).max(), k
 
 
 @pytest.mark.parametrize("mesh_name", sorted(MESHES))
@@ -105,10 +127,12 @@ def test_matches_checkpointed_scan(dtype, tol):
         assert np.abs(a - b).max() <= tol * np.abs(b).max(), name
 
 
-def test_cotangent_scales_every_gradient():
+@pytest.mark.parametrize("mesh_name", sorted(MESHES))
+def test_cotangent_scales_every_gradient(mesh_name):
     """(c) the backward rule scales by the scalar cotangent: grad of
-    3·loss is 3·grad of loss, leaf by leaf."""
-    mesh = make_mesh(MESHES["dp2sp2tp2"])
+    3·loss is 3·grad of loss, leaf by leaf (on a ``tp`` mesh the scale
+    reaches every rank's part of the positions)."""
+    mesh = make_mesh(MESHES[mesh_name])
     cfg = dataclasses.replace(CFG, ce_chunk=8)
     params, toks = tfm.init_params(cfg), _tokens(cfg)
     l1, g1 = _value_and_grad(cfg, mesh)(params, toks)
@@ -137,36 +161,81 @@ def test_weight_zero_positions_get_no_gradient():
 
 def _equations(jaxpr, under=()):
     """Every equation of a jaxpr and of the jaxprs its equations hold, with
-    the names of the primitives it sits under."""
+    the equations it sits under."""
     for eqn in jaxpr.eqns:
         yield eqn, under
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _equations(sub, under + (eqn.primitive.name,))
+            yield from _equations(sub, under + (eqn,))
 
 
 def _vocabulary_dots(fn, *args, vocab):
+    """Of each matmul with the vocabulary among its dimensions: the names
+    of the primitives it sits under, and the length of the innermost scan
+    among them."""
     found = []
     for eqn, under in _equations(jax.make_jaxpr(fn)(*args).jaxpr):
         if eqn.primitive.name == "dot_general" and any(
                 vocab in v.aval.shape for v in (*eqn.invars, *eqn.outvars)):
-            found.append(under)
+            scans = [e.params["length"] for e in under
+                     if e.primitive.name == "scan"]
+            found.append((tuple(e.primitive.name for e in under),
+                          scans[-1] if scans else None))
     return found
 
 
+# a vocabulary no other width of the model equals
+_DOTS_CFG = dataclasses.replace(CFG, ce_chunk=8, attention="xla", vocab=160)
+
+
+@pytest.mark.parametrize("tp", [1, 2])
 @pytest.mark.parametrize("what,per_chunk", [("value", 1), ("value_and_grad", 3)])
-def test_vocabulary_matmuls_a_chunk(what, per_chunk):
+def test_vocabulary_matmuls_a_chunk(what, per_chunk, tp):
     """(e) the value alone holds one vocabulary matmul in its scan's body,
     value and gradient hold three (logits, the hidden states' gradient, the
     head's), and none of them sits under a checkpoint: nothing is made
-    twice.  (The checkpointed scan held four.)"""
-    mesh = make_mesh({"dp": 1, "sp": 1, "tp": 1}, devices=jax.devices()[:1])
-    # a vocabulary no other width of the model equals
-    cfg = dataclasses.replace(CFG, ce_chunk=8, attention="xla", vocab=160)
+    twice.  (The checkpointed scan held four.)  The scan runs over this
+    rank's positions: ``T / ce_chunk`` chunks without ``tp``,
+    ``T / (tp · ce_chunk)`` with it."""
+    mesh = _mesh({"dp": 1, "sp": 1, "tp": tp})
+    cfg = _DOTS_CFG
     params, toks = tfm.init_params(cfg), _tokens(cfg)
     loss = tfm.make_loss_fn(cfg, mesh)
     fn = loss if what == "value" else jax.value_and_grad(loss)
     dots = _vocabulary_dots(fn, params, toks, vocab=cfg.vocab)
     assert len(dots) == per_chunk, dots
-    for under in dots:
+    for under, length in dots:
         assert "scan" in under, under
         assert not {"checkpoint", "remat", "remat2"} & set(under), under
+        assert length == cfg.seq // (tp * cfg.ce_chunk), (length, under)
+
+
+@pytest.mark.parametrize("mesh_name", sorted(TP_MESHES))
+def test_positions_split_over_tp_match_no_tp(mesh_name):
+    """(f) each ``tp`` rank takes the cross entropy of its own ``1/tp`` of
+    the local positions (two chunks a rank on ``dp2sp2tp2`` and
+    ``dp2sp1tp4``, four on ``dp4sp1tp2``): the loss and the gradient of
+    every leaf, the replicated ``emb``, ``lnf``, ``ln1`` and the sharded
+    ``wq``, ``wo``, ``w1``, ``w2`` alike, are those of the same mesh
+    without ``tp``, where every position is one rank's."""
+    axes = TP_MESHES[mesh_name]
+    cfg = dataclasses.replace(CFG, ce_chunk=4)
+    params, toks = tfm.init_params(cfg), _tokens(cfg)
+    split = _value_and_grad(cfg, _mesh(axes))(params, toks)
+    whole = _value_and_grad(cfg, _mesh({**axes, "tp": 1}))(params, toks)
+    assert {"emb", "lnf", "ln1", "wq", "wo", "w1", "w2"} <= set(split[1])
+    _assert_same_loss_and_gradients(split, whole)
+
+
+def test_length_tp_does_not_divide_stays_whole():
+    """(g) 30 local positions over ``tp`` = 4: no rank takes a part, the
+    scan runs over all six chunks on every rank, and loss and gradients
+    are still those of the mesh without ``tp``."""
+    cfg = dataclasses.replace(_DOTS_CFG, seq=30, ce_chunk=5)
+    params, toks = tfm.init_params(cfg), _tokens(cfg)
+    loss = tfm.make_loss_fn(cfg, _mesh(TP4))
+    dots = _vocabulary_dots(jax.value_and_grad(loss), params, toks,
+                            vocab=cfg.vocab)
+    assert [length for _under, length in dots] == [6, 6, 6], dots
+    split = _value_and_grad(cfg, _mesh(TP4))(params, toks)
+    whole = _value_and_grad(cfg, _mesh({**TP4, "tp": 1}))(params, toks)
+    _assert_same_loss_and_gradients(split, whole)
