@@ -3,7 +3,8 @@
 thing wrong, read by the runner's own comparison.
 
     python3 benchmarks/controls.py --workload <cell> --seeds 1,2,3 \
-        --faults sound,all_lower_precision [--tiny] [--out FILE.jsonl]
+        --faults sound,all_lower_precision [--tiny] [--out FILE.jsonl] \
+        [--bench-dir DIR]
 
 A ``correct`` says as much as the faults it refuses.  For each seed and each
 fault this draws the cell's seeded parameters, plants the fault (in the
@@ -16,7 +17,9 @@ under ``checks`` had the program had that fault, and ``Job.verdict``'s
 ``--out``).  ``run.py`` reads nothing of this file; no measured window, no
 metric.  ``--tiny`` runs the configuration's ``tiny`` sizes in float32 on
 whatever device JAX has (the CPU tests); without it a TPU is asked for, as
-``run.py`` asks.
+``run.py`` asks.  ``--bench-dir`` names the benchmark directory the cell is
+resolved in (``cells.resolve``'s; ``BENCHMARK.json`` beside it), so that a
+cell of a copied benchmark is read before it is added.
 
 Faults (one whose leaf the configuration lacks raises):
 
@@ -33,7 +36,11 @@ Faults (one whose leaf the configuration lacks raises):
 ``top_k_less_one``           the decoder built with one expert a token fewer
 ``router_in_bfloat16``       the router's probabilities rounded to bfloat16
                              before the top-k (its only ``lax.top_k`` in a
-                             greedy decoder), while the decoder is traced
+                             greedy decoder), while the decoder is traced;
+                             by ``lax.reduce_precision`` too: as a cast to
+                             bfloat16 and back, which it was until PR 37,
+                             it read the sound program's numbers bit for
+                             bit on the chip (PERF.md section 6)
 """
 
 from __future__ import annotations
@@ -112,15 +119,15 @@ def plant(job, fault: str, params: dict) -> dict:
 @contextlib.contextmanager
 def _router_rounds_to_bfloat16():
     """While a decoder is traced: ``lax.top_k`` sees its operand rounded to
-    bfloat16.  A greedy decoder picks by argmax, so the router's is the only
-    top-k in its programs."""
-    import jax.numpy as jnp
+    bfloat16 (8 exponent bits, 7 of mantissa).  A greedy decoder picks by
+    argmax, so the router's is the only top-k in its programs."""
     from jax import lax
 
     top_k = lax.top_k
 
     def rounded(operand, k, *args, **kwargs):
-        return top_k(operand.astype(jnp.bfloat16).astype(operand.dtype), k,
+        return top_k(lax.reduce_precision(operand, exponent_bits=8,
+                                          mantissa_bits=7), k,
                      *args, **kwargs)
 
     lax.top_k = rounded
@@ -186,12 +193,13 @@ def tiny(cell: cells.Cell, **traffic) -> tuple[dict, dict]:
 
 
 def run(workload: str, seeds: list[int], faults: list[str], small: bool,
-        out: str | None = None):
-    """Every reading of ``faults`` x ``seeds`` in the cell ``workload``, as
-    dicts; printed, and appended to ``out``, as they come."""
+        out: str | None = None, bench_dir: str = cells.BENCH_DIR):
+    """Every reading of ``faults`` x ``seeds`` in the cell ``workload`` of
+    the benchmark under ``bench_dir``, as dicts; printed, and appended to
+    ``out``, as they come."""
     import jax
 
-    cell = cells.resolve(workload)
+    cell = cells.resolve(workload, bench_dir)
     if "prompt_len" not in cell.traffic:
         raise ValueError(f"{workload} decodes nothing: the controls are of "
                          f"decode cells")
@@ -225,6 +233,8 @@ def main(argv=None) -> int:
     ap.add_argument("--tiny", action="store_true",
                     help="the configuration's tiny sizes, float32, any device")
     ap.add_argument("--out", metavar="FILE", help="append the lines here too")
+    ap.add_argument("--bench-dir", metavar="DIR", default=cells.BENCH_DIR,
+                    help="the benchmark directory the cell is resolved in")
     args = ap.parse_args(argv)
 
     import jax
@@ -240,7 +250,7 @@ def main(argv=None) -> int:
     if args.out:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     run(args.workload, [int(s) for s in args.seeds.split(",")],
-        args.faults.split(","), args.tiny, args.out)
+        args.faults.split(","), args.tiny, args.out, args.bench_dir)
     return 0
 
 

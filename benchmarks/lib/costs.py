@@ -13,9 +13,16 @@ def tree_count(tree: dict) -> int:
     return sum(int(leaf.size) for leaf in tree.values())
 
 
-def tree_bytes(tree: dict) -> int:
-    """Bytes of a tree of arrays at the type they are stored in."""
-    return sum(int(leaf.size) * leaf.dtype.itemsize for leaf in tree.values())
+def step_param_bytes(n_params: int, lookup_params: int, stored_itemsize: int,
+                     compute_itemsize: int) -> int:
+    """Bytes of the parameters that one cached decode step must read: every
+    stored parameter but a table the step only looks rows up in
+    (``lookup_params`` of the reference's ``counts``: a few rows a step, which
+    count nothing; a table that is also the projection is read whole and is
+    not one), at the narrower of the type it is stored in and the type the
+    step computes in: a step that multiplies in bfloat16 reads the bfloat16
+    copy of a float32 leaf, half its bytes."""
+    return (n_params - lookup_params) * min(stored_itemsize, compute_itemsize)
 
 
 def train_flops_per_token(active_params: int, attention_layers: int,
@@ -57,13 +64,19 @@ def kv_bytes(attention_layers: int, batch: int, positions: float,
 def decode_step_bytes(param_bytes: int, attention_layers: int, batch: int,
                       prompt_len: int, max_new: int, kv_elements: int,
                       kv_itemsize: int, state_elements: int = 0) -> float:
-    """Bytes one cached decode step must read: every parameter once at its
-    stored type, the live keys and values once at theirs, and what a
-    sequence holds of fixed-size state (``state_elements``, over all layers:
-    a convolution's last positions, a linear attention's matrix) once at
-    the cache's type.  The cache is live up to the position being written,
-    so over the ``max_new - 1`` steps after the first token it holds
-    ``prompt_len + max_new / 2`` positions on average."""
+    """Bytes one cached decode step must read: the parameters it reads, once,
+    at the type it reads them in (``param_bytes``: ``step_param_bytes``), the
+    live keys and values once at theirs, and what a sequence holds of
+    fixed-size state (``state_elements``, over all layers: a convolution's
+    last positions, a linear attention's matrix) once at the cache's type.
+    The cache is live up to the position being written, so over the
+    ``max_new - 1`` steps after the first token it holds ``prompt_len +
+    max_new / 2`` positions on average: what the algorithm needs, not what a
+    program that reads a padded cache touches.  A routed model's parameters
+    are counted whole, every expert's matrices once a step: that is what a
+    step reads where its choices (batch x experts a token) are several times
+    the experts, and more than it reads in a cell under that, whose share
+    then passes 100%."""
     live = prompt_len + max_new / 2
     return (param_bytes
             + kv_bytes(attention_layers, batch, live, kv_elements,

@@ -66,12 +66,15 @@ def counts(ref, shape) -> dict:
     attend; default every layer), ``attention_width`` (the summed width of
     the query heads; default ``d_model``), ``state_elements`` (what one
     sequence holds, over all layers, of fixed-size state that a cached step
-    reads, counted at ``kv_cache_dtype``; default 0) and ``routed``
-    (``{"layers", "experts", "top_k", "d_model", "d_expert"}``: the routed
-    layers' own shape, for the readers of their kernels; no default)."""
+    reads, counted at ``kv_cache_dtype``; default 0), ``lookup_params`` (the
+    elements of a table that a step only looks rows up in, an embedding that
+    is not also the projection: a cached step's bytes leave it out; default
+    0) and ``routed`` (``{"layers", "experts", "top_k", "d_model",
+    "d_expert"}``: the routed layers' own shape, for the readers of their
+    kernels; no default)."""
     return {"attention_layers": shape.n_layers,
             "attention_width": shape.d_model, "state_elements": 0,
-            **ref.counts(shape)}
+            "lookup_params": 0, **ref.counts(shape)}
 
 
 def mesh(config: dict, devices):

@@ -1,8 +1,8 @@
 """Share of the published HBM bandwidth a decode step reaches, counting only
-the bytes it must read (``lib/costs.decode_step_bytes``: parameters and live
-keys and values, once, at their stored types).  The cached step is bound by
-memory, not by operations: this is that bound's share, for the step as a
-whole and not for one kernel."""
+the bytes it must read (``lib/costs.decode_step_bytes``: the parameters a
+step reads, at the type it reads them in, and the live keys and values,
+once).  The cached step is bound by memory, not by operations: this is that
+bound's share, for the step as a whole and not for one kernel."""
 
 
 def read(run):

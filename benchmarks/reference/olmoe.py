@@ -64,8 +64,9 @@ import jax.numpy as jnp
 # layer cannot be more: where a token's 8th and 9th expert change places on
 # bfloat16 noise (one token in thirty a layer) its logits move as far as
 # that layer's share lets them, and the worst token of a sound run has to
-# stay inside DEFICIT_TOL; at these shares a zeroed attending layer moves every logit by
-# 0.05 deviations, all experts off by 0.1, and the sound program by 0.005.
+# stay inside the configuration's ``check.deficit_max``; at these shares a
+# zeroed attending layer moves every logit by 0.05 deviations, all experts
+# off by 0.1, and the sound program by 0.005.
 ROUTER_SPREAD = 0.9
 EXPERT_OUT = 0.15
 EMB = 0.1
@@ -130,14 +131,17 @@ def counts(shape: Shape) -> dict:
     parameters one token multiplies: in every layer the four attention
     projections, the router, and ``top_k`` of the experts' three matrices;
     and the output head.  The embedding is a lookup table and the norms'
-    scales multiply no matrix: they count nothing.  ``kv_elements``: one
-    position's keys and values in one layer (as many K/V heads as query
-    heads).  ``routed``: every layer is routed, over ``n_experts`` gated
-    experts of width ``d_ff``, ``top_k`` a token."""
+    scales multiply no matrix: they count nothing; ``lookup_params`` says
+    how large that table is, for the bytes of a cached step, which looks up
+    a row a sequence.  ``kv_elements``: one position's keys and values in
+    one layer (as many K/V heads as query heads).  ``routed``: every layer
+    is routed, over ``n_experts`` gated experts of width ``d_ff``, ``top_k``
+    a token."""
     L, D, F, V = shape.n_layers, shape.d_model, shape.d_ff, shape.vocab
     block = 4 * D * D + D * shape.n_experts + shape.top_k * 3 * D * F
     return {"active_params": L * block + V * D,
             "projection_params": V * D,
+            "lookup_params": V * D,
             "kv_elements": 2 * D,
             "routed": {"layers": L, "experts": shape.n_experts,
                        "top_k": shape.top_k, "d_model": D, "d_expert": F}}

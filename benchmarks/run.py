@@ -6,18 +6,24 @@
 from the root of a checkout.  Set-up (imports, parameters on the device from
 ``--seed``, compile or cache read, warm-up, the correctness check against
 the plain reference) runs from process start to the first timed sample and
-is reported as ``setup_s``, less the start-up of the TPU runtime inside
-``jax.devices()``: that took 7 to 12 s of a one-chip run's 17 to 21 s and
-drifted by 2 s between sets of runs of the same code (PERF.md), which no
-change to this repo moves and which would bury one that adds a second to the
-rest.  It is reported beside it, under ``setup``.  Then samples of the
-cell's job are taken for ``--seconds`` seconds.  With ``--trace 1`` a few more samples
-run under ``jax.profiler`` and the line carries the cell's per-layer metrics
-instead of its end-to-end ones; ``breakdown`` then has the longest device
-operations and idle gaps, the scope table of ``lib/scopes.py``
+is reported as ``setup_s``, less two things that are not the program's.  The
+start-up of the TPU runtime inside ``jax.devices()``: that took 7 to 12 s of
+a one-chip run's 17 to 21 s and drifted by 2 s between sets of runs of the
+same code (PERF.md), which no change to this repo moves and which would bury
+one that adds a second to the rest.  And the benchmark's own check against
+the plain reference (the runners' spans ``setup.reference``): 6.2 s of the
+four-chip cell's 15.0, the checker's cost and not the program's, which
+refused a change for how its leaves reach the checker.  Both are reported
+beside it, under ``setup`` (``backend_s``, ``reference_s``).  Then samples
+of the cell's job are taken for ``--seconds`` seconds.  With ``--trace 1`` a
+few more samples run under ``jax.profiler`` and the line carries the cell's
+per-layer metrics instead of its end-to-end ones; ``breakdown`` then has the
+longest device operations and idle gaps, the scope table of ``lib/scopes.py``
 (``device_scopes``: device seconds by pass, ``jax.named_scope`` and collective
-site) and the device clock's lead with the gaps named after it
-(``lib/clock.py``).  ``--dump DIR`` keeps the traced events, scopes and
+site), the device clock's lead with the gaps named after it
+(``lib/clock.py``), and ``tracing``: how much of the device's program runs
+its operations cover, and how often the window was traced again because the
+profiler had lost events.  ``--dump DIR`` keeps the traced events, scopes and
 all; ``python3 -m benchmarks.lib.scopes <file>`` reads them again.
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
@@ -53,6 +59,7 @@ from benchmarks.lib.spans import Spans  # noqa: E402
 
 
 BACKEND_SPAN = "setup.backend"     # around jax.devices(), left out of setup_s
+REFERENCE_SPAN = "setup.reference"  # the runners' check, left out as well
 
 
 def trace_samples(job, n: int) -> list[xplane.Event]:
@@ -75,6 +82,24 @@ def trace_samples(job, n: int) -> list[xplane.Event]:
         return xplane.read_events(files[0])
     finally:
         shutil.rmtree(out, ignore_errors=True)
+
+
+def whole_trace(job, n: int, attempts: int = 3) -> tuple[list, dict]:
+    """``trace_samples`` again, up to ``attempts`` times in all, while the
+    device's operations leave a hole in its program runs
+    (``xplane.program_coverage``): the profiler lost events, and every share
+    of such a trace would read wrong.  The events of the last trace taken,
+    and what was seen: ``{"covered": [a share a trace], "retraced": n}``."""
+    covered = []
+    for left in reversed(range(attempts)):
+        traced = trace_samples(job, n)
+        covered.append(xplane.program_coverage(traced))
+        if covered[-1] is None or covered[-1] >= xplane.WHOLE:
+            break
+        print(f"the trace covers {covered[-1]:.3f} of the device's program "
+              f"runs: events were lost"
+              + ("; tracing again" if left else ""), file=sys.stderr)
+    return traced, {"covered": covered, "retraced": len(covered) - 1}
 
 
 def peak_bytes(device) -> int | None:
@@ -109,7 +134,9 @@ def measure(cell: cells.Cell, devices, meter, spans: Spans, seed: int,
         setup["phases"] = {name: sum(v) for name, v in
                            spans.durations(t0, start).items()}
         setup["backend_s"] = setup["phases"].get(BACKEND_SPAN, 0.0)
-        setup["seconds"] = start - t0 - setup["backend_s"]
+        setup["reference_s"] = setup["phases"].get(REFERENCE_SPAN, 0.0)
+        setup["seconds"] = (start - t0 - setup["backend_s"]
+                            - setup["reference_s"])
         while time.perf_counter() - start < seconds:
             before = meter.programs
             attempted += 1
@@ -123,8 +150,8 @@ def measure(cell: cells.Cell, devices, meter, spans: Spans, seed: int,
                 failed += 1
         end = time.perf_counter()
         compiles = meter.programs - setup["programs"]
-        traced = (trace_samples(job, cell.traffic["trace_samples"])
-                  if trace else [])
+        traced, tracing = (whole_trace(job, cell.traffic["trace_samples"])
+                           if trace else ([], None))
         outcome = job.finish()
         facts = job.facts()
     finally:
@@ -169,7 +196,8 @@ def measure(cell: cells.Cell, devices, meter, spans: Spans, seed: int,
                                 window_s=summary.window_s)
         result["breakdown"] = {"device_ops": summary.device_ops,
                                "idle_gaps": summary.idle_gaps,
-                               "device_scopes": table, **by_clock}
+                               "device_scopes": table, **by_clock,
+                               "tracing": tracing}
     # ``checks`` comes last: every number ``correct`` compared, beside its
     # limit, where the end of the line is what a record keeps
     result.update(

@@ -6,8 +6,10 @@ in, ``max_new`` greedy tokens each out, read back to the host.  One sample
 is a pair of jobs on the same prompts: ``first`` with ``max_new=1`` (prefill
 and the first token) and ``full`` with the traffic file's ``max_new``.  The
 difference of their medians is the time of ``max_new - 1`` cached steps with
-prefill and dispatch cancelled (the method of ``bench.matrix_decode_
-throughput``, at this cell's sizes and with medians).
+prefill and dispatch cancelled.  ``facts()`` counts a cached step's bytes as
+the step reads them (``lib/costs.py``): the parameters at the narrower of
+``param_dtype`` and ``entry.options.compute_dtype``, less what the
+reference's ``counts`` call ``lookup_params``, and the live keys and values.
 
 What a decoder may return.  ``entry.decoder(cfg, mesh, max_new=N)`` gives a
 callable ``(params, prompts (B, Tp) int32) -> tokens (B, Tp + N) int32``.
@@ -308,6 +310,8 @@ class Job:
         import jax.numpy as jnp
 
         counts = program.counts(self.reference, self.shape)
+        stored = self.config["param_dtype"]
+        compute = self.config["entry"]["options"].get("compute_dtype", stored)
         return {
             "chips": len(self.devices),
             "n_params": self.n_params,
@@ -319,7 +323,10 @@ class Job:
                 counts["attention_layers"], counts["attention_width"],
                 self.batch, self.prompt_len),
             "decode_step_bytes": costs.decode_step_bytes(
-                costs.tree_bytes(self.params), counts["attention_layers"],
+                costs.step_param_bytes(
+                    self.n_params, counts["lookup_params"],
+                    jnp.dtype(stored).itemsize, jnp.dtype(compute).itemsize),
+                counts["attention_layers"],
                 self.batch, self.prompt_len, self.max_new,
                 counts["kv_elements"],
                 jnp.dtype(self.config["kv_cache_dtype"]).itemsize,

@@ -7,8 +7,6 @@ checks resolution, control flow and the shape of the result line, never a
 device metric.
 """
 
-import functools
-import hashlib
 import json
 import os
 import re
@@ -25,6 +23,9 @@ from benchmarks.lib import cells, program, scopes, xplane
 from benchmarks.lib.compile_meter import CompileMeter
 from benchmarks.lib.rundata import RunData
 from benchmarks.lib.spans import Spans
+from tests.benchmarks import decode_cells
+from tests.benchmarks.decode_cells import (LOGIT_CHECK, copied_benchmark,
+                                           digest)
 
 ROOT = os.path.dirname(cells.BENCH_DIR)
 BENCH = cells.load_benchmark()
@@ -68,28 +69,6 @@ def measure(cell, meter, trace, dump=None):
                              seed=3, seconds=0.3, trace=trace,
                              peaks=MADE_UP_PEAKS, t0=time.perf_counter(),
                              dump=dump)
-
-
-def digest(top) -> dict[str, str]:
-    """relative path -> sha256 of every file under ``top``."""
-    out = {}
-    for folder, _dirs, files in os.walk(top):
-        for f in files:
-            if f.endswith(".pyc"):
-                continue
-            path = os.path.join(folder, f)
-            with open(path, "rb") as fh:
-                out[os.path.relpath(path, top)] = hashlib.sha256(
-                    fh.read()).hexdigest()
-    return out
-
-
-def copied_benchmark(tmp_path) -> tuple[str, dict]:
-    """The benchmark's directory copied under ``tmp_path``, and its digest."""
-    bench_dir = str(tmp_path / "benchmarks")
-    shutil.copytree(cells.BENCH_DIR, bench_dir,
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    return bench_dir, digest(bench_dir)
 
 
 @pytest.mark.parametrize("workload", WORKLOADS)
@@ -357,7 +336,12 @@ def test_a_model_of_another_family_is_added_without_editing_a_file(
     assert facts["prefill_flops"] == (
         t["batch"] * t["prompt_len"] * (2 * 1000 + 4 * L * D * t["prompt_len"])
         + t["batch"] * 2 * 35)
-    param_bytes = facts["n_params"] * 4
+    # stored in float32 at tiny sizes and read as the step computes, in
+    # bfloat16; this reference names no lookup table
+    assert cell.config["param_dtype"] == "float32"
+    assert cell.config["entry"]["options"]["compute_dtype"] == "bfloat16"
+    param_bytes = facts["n_params"] * 2
+    assert decode_cells.routed(cell, bench_dir) is None
     assert facts["decode_step_bytes"] == param_bytes + (
         L * t["batch"] * (t["prompt_len"] + t["max_new"] / 2) * 6 * 2)
 
@@ -422,15 +406,19 @@ def test_a_model_whose_layers_differ_is_added_without_editing_a_file(
     assert not {"hybrid_state_share", routed_kernel} & set(traced["metrics"])
     with open(os.path.join(dump, "hybrid-cell.seed3.trace1.json")) as f:
         facts = json.load(f)["facts"]
-    assert facts["counts"] == HYBRID_COUNTS
+    assert facts["counts"] == {**HYBRID_COUNTS, "lookup_params": 0}
     t = cell.traffic
     B, T = t["batch"], t["prompt_len"]
     assert cell.config["num_hidden_layers"] > 1     # not every layer attends
     assert facts["prefill_flops"] == (
         B * T * (2 * 1000 + 4 * 1 * 48 * T) + B * 2 * 35)
     assert facts["decode_step_bytes"] == (
-        facts["n_params"] * 4 + 1 * B * (T + t["max_new"] / 2) * 6 * 2
+        facts["n_params"] * 2 + 1 * B * (T + t["max_new"] / 2) * 6 * 2
         + B * 21 * 2)
+    # sorted with the routed cells by what its reference counts: its routed
+    # layers sit under keys of its own, and no key of its file says so
+    assert decode_cells.routed(cell, bench_dir) == HYBRID_COUNTS["routed"]
+    assert not [key for key in cell.config if "expert" in key]
 
     # the program's vocabulary gains a name: the reader's table gains its
     # keys, and the share, which is data, reads them
@@ -495,98 +483,18 @@ def test_a_model_whose_layers_differ_is_added_without_editing_a_file(
 
 # ---- a decoder that hands its logits back ----------------------------------
 
-# what the dry addition below gives as its ``check``: in float32 at tiny
-# sizes the program's own forward sits within 1e-5 of the reference's
-LOGIT_CHECK = {
-    "logit_err_median": {"limit": 0.01, "why": "float32 on both sides"},
-    "logit_err_position": {"limit": 0.05, "why": "float32 on both sides"},
-    "positions_over": {"limit": 0.1, "why": "room for two positions of 40"},
-}
-
-
-def logits_decoder(cfg, mesh, max_new, keep_logits=0, fault=""):
-    """``entry.decoder`` of the dry addition: the program's decoder, wrapped
-    so that with ``keep_logits=n`` it also returns float32 ``(n, max_new,
-    vocab)`` logits, made with the program's own forward over what it
-    generated, and with ``fault`` wrong in the way the name says."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from ompi_tpu.models import transformer as tfm
-    from ompi_tpu.models.decode import make_decoder
-
-    decode = make_decoder(cfg, mesh, max_new=max_new)
-    if not keep_logits:
-        return decode
-    forward = jax.jit(tfm.make_forward(cfg, mesh))
-
-    def run(params, prompts):
-        tokens = decode(params, prompts)
-        start = prompts.shape[1]
-        z = np.array(forward(params, tokens[:keep_logits])[:, start - 1:-1],
-                     np.float32)
-        rng = np.random.default_rng(0)
-        if fault == "shifted":      # every position, a tenth of a deviation
-            z += 0.1 * z.std(-1, keepdims=True) * rng.normal(size=z.shape)
-        if fault == "spiky":        # one position in twenty, three deviations
-            for n, t in zip(*np.nonzero(np.arange(z[..., 0].size).reshape(
-                    z.shape[:2]) % 20 == 7)):
-                z[n, t] += 3 * z[n, t].std() * rng.normal(size=z.shape[-1])
-        if fault in ("shifted", "spiky"):   # the picked token still leads
-            picked = np.asarray(tokens[:keep_logits, start:])[..., None]
-            np.put_along_axis(z, picked, z.max(-1, keepdims=True) + 1e-3, -1)
-        if fault == "wrong_token":
-            tokens = tokens.at[0, start + 2].set(
-                (tokens[0, start + 2] + 1) % cfg.vocab)
-        return tokens, jnp.asarray(z)
-
-    return run
-
-
-# ``entry.decoder`` is a dotted path, so each fault has a name of its own
-logits_decoder_shifted = functools.partial(logits_decoder, fault="shifted")
-logits_decoder_spiky = functools.partial(logits_decoder, fault="spiky")
-logits_decoder_wrong_token = functools.partial(logits_decoder,
-                                               fault="wrong_token")
-
-
 def add_logits_cell(tmp_path, bench_dir: str, decoder: str, check) -> cells.Cell:
-    """To the copied benchmark, by files and rows alone: the first decode
-    configuration at tiny sizes and float32 with ``entry.decoder`` the test's
-    factory ``decoder``, ``entry.decoder_logits`` its keyword and ``check``
-    (left out where None), and a cell on a mix of 40 checked positions."""
-    base = next(w for w in BENCH["workloads"] if w["chips"] == 1
+    """To the copied benchmark, by files and rows alone
+    (``decode_cells.add_logits_cell``): the first decode configuration at tiny
+    sizes and float32 behind the factory ``decoder``, held to ``check`` (left
+    out where None), and a cell on a mix of 40 checked positions."""
+    base = next(w["name"] for w in BENCH["workloads"] if w["chips"] == 1
                 and "prompt_len" in cells.resolve(w["name"]).traffic)
-    resolved = cells.resolve(base["name"])
-    config = json.loads(json.dumps(program.tiny(resolved.config)))
-    config["name"] = "logits-family"
-    config["entry"]["decoder"] = f"{__name__}.{decoder}"
-    config["entry"]["decoder_logits"] = "keep_logits"
-    config["entry"]["options"]["compute_dtype"] = "float32"
-    config.pop("check")     # the base's own, for tokens
-    if check is not None:
-        config["check"] = check
-    with open(os.path.join(bench_dir, "configs", "logits-family.json"),
-              "w") as f:
-        json.dump(config, f)
-    traffic = {**resolved.traffic, "batch": 4, "prompt_len": 12,
-               "max_new": 20, "reference_sequences": 2}
-    with open(os.path.join(bench_dir, "traffic", "logits-mix.json"),
-              "w") as f:
-        json.dump(traffic, f)
-    bench = json.loads(json.dumps(BENCH))
-    bench["configs"].append({**BENCH["configs"][0], "name": "logits-family",
-                             "file": "benchmarks/configs/logits-family.json"})
-    bench["workloads"].append({"name": "logits-cell",
-                               "config": "logits-family",
-                               "traffic": "logits-mix", "chips": 1,
-                               "why": "dry addition"})
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if base["name"] in m.get("workloads", []):
-            m["workloads"].append("logits-cell")
-    with open(tmp_path / "BENCHMARK.json", "w") as f:
-        json.dump(bench, f)
-    return cells.resolve("logits-cell", bench_dir)
+    cell = decode_cells.add_logits_cell(
+        tmp_path, bench_dir, base, decoder, check, tiny=True,
+        traffic={"batch": 4, "prompt_len": 12, "max_new": 20,
+                 "reference_sequences": 2})
+    return cells.resolve(cell, bench_dir)
 
 
 def over(limit: float) -> dict:
@@ -652,15 +560,42 @@ def test_a_decoder_that_hands_logits_back_without_its_limits_is_refused(
         cell.runner.build(cell.config, cell.traffic, jax.devices()[:1])
 
 
-@pytest.mark.parametrize("workload", WORKLOADS)
-def test_a_configuration_without_decoder_logits_is_built_as_before(workload):
-    """None of the benchmark's own configurations names ``decoder_logits``:
-    their decoders are built with ``max_new`` alone, return tokens, and are
-    held to the two limits for tokens of their file's ``check``."""
-    cell = cells.resolve(workload)
-    assert "decoder_logits" not in cell.config["entry"]
-    if "prompt_len" in cell.traffic:    # held to its own limits, on tokens
-        job = cell.runner.build(program.tiny(cell.config), cell.traffic,
-                                jax.devices()[:cell.chips])
+def built_as_its_kind(cell: cells.Cell) -> None:
+    """A decode cell's job, built at tiny sizes, asks its decoder for what
+    the configuration says it hands back and is held to that kind's limits
+    alone; a train cell builds no decoder, whatever its configuration (which
+    a decode cell may share) says of one."""
+    if "prompt_len" not in cell.traffic:
+        assert not hasattr(cell.runner.build(
+            program.tiny(cell.config), cell.traffic,
+            jax.devices()[:cell.chips]), "kept")
+        return
+    job = cell.runner.build(program.tiny(cell.config), cell.traffic,
+                            jax.devices()[:cell.chips])
+    if decode_cells.hands_back(cell) == "tokens":
+        # built with ``max_new`` alone, returns tokens
         assert job.kept == {}
-        assert set(job.held_to()) == {"deficit_max", "mismatch_share"}
+    else:
+        assert job.kept == {cell.config["entry"]["decoder_logits"]:
+                            cell.traffic["reference_sequences"]}
+    assert set(job.held_to()) == set(decode_cells.limit_keys(cell))
+
+
+@pytest.mark.parametrize("workload", WORKLOADS)
+def test_a_configuration_is_built_as_what_its_decoder_hands_back(workload):
+    built_as_its_kind(cells.resolve(workload))
+
+
+def test_a_configuration_that_names_decoder_logits_is_built_with_it(tmp_path):
+    """The same assertions on a cell whose configuration names the keyword,
+    gives the three limits for logits and no limit for tokens."""
+    bench_dir, before = copied_benchmark(tmp_path)
+    base = next(w for w in WORKLOADS
+                if "prompt_len" in cells.resolve(w).traffic)
+    cell = cells.resolve(
+        decode_cells.add_logits_cell(tmp_path, bench_dir, base), bench_dir)
+    assert decode_cells.hands_back(cell) == "logits"
+    assert not set(cell.config["check"]) & set(decode_cells.TOKEN_KEYS)
+    built_as_its_kind(cell)
+    after = digest(bench_dir)
+    assert {k: after[k] for k in before} == before

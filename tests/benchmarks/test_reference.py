@@ -111,18 +111,19 @@ def test_decode_check_accepts_the_decoder_and_rejects_a_swapped_token(name):
 
 MUST_COUNT = {"active_params", "projection_params", "kv_elements"}
 MAY_COUNT = {"attention_layers", "attention_width", "state_elements",
-             "routed"}
+             "lookup_params", "routed"}
 ROUTED = {"layers", "experts", "top_k", "d_model", "d_expert"}
 
 
 @pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_counts_are_of_the_references_own_tree(name):
     """``counts(shape)`` at the real sizes: the three keys every reference
-    gives, of the four it may give none that the tree cannot bear out (no
+    gives, of the five it may give none that the tree cannot bear out (no
     more active parameters than the tree holds, a projection among them, K
     and V of one position; no more layers that attend or route than there
-    are layers, no more experts' parameters than are stored), and no key
-    beyond them, which no cost function would read."""
+    are layers, no more experts' parameters than are stored; no lookup
+    table larger than what the tree holds beside the projection), and no
+    key beyond them, which no cost function would read."""
     config = CONFIGS[name]
     ref = program.reference(config)
     shape = ref.Shape.from_config(config)
@@ -139,6 +140,11 @@ def test_counts_are_of_the_references_own_tree(name):
     assert {k: whole[k] for k in counts} == counts
     assert 0 < whole["attention_layers"] <= shape.n_layers
     assert whole["attention_width"] > 0 and whole["state_elements"] >= 0
+    assert isinstance(whole["lookup_params"], int)
+    assert 0 <= whole["lookup_params"] <= stored - counts["projection_params"]
+    # a table that a token multiplies is no lookup table: the active
+    # parameters and the table together are no more than the tree
+    assert counts["active_params"] + whole["lookup_params"] <= stored
     if "routed" in counts:
         routed = counts["routed"]
         assert set(routed) == ROUTED
@@ -164,7 +170,8 @@ def test_a_reference_that_says_nothing_more_counts_every_layer():
 
     assert program.counts(Ref, Shape) == {
         "active_params": 9, "projection_params": 2, "kv_elements": 4,
-        "attention_layers": 3, "attention_width": 5, "state_elements": 0}
+        "attention_layers": 3, "attention_width": 5, "state_elements": 0,
+        "lookup_params": 0}
 
 
 # ---- the arithmetic, against counts made by hand ---------------------------
@@ -176,7 +183,12 @@ def test_a_reference_that_says_nothing_more_counts_every_layer():
 # these integers over a time.  OLMoE's are what its reference's ``counts``
 # gave before a reference could say which layers attend (the parent of the
 # PR that let it): 7 layers, all of them attending and routed, the
-# parameters stored in bfloat16.
+# parameters stored in bfloat16.  ``decode_step_bytes`` alone is not the
+# parent's: since PR 37 a cached step's parameters are counted as the step
+# reads them.  The Pythia configurations store float32 and compute in
+# bfloat16, so a step reads half of the stored bytes, the tied table once,
+# as the projection; OLMoE's untied embedding is a lookup table and leaves
+# the count (48 rows of it a step).
 HAND_COUNTS = {
     "pythia-1.4b-widths": dict(
         n_params=405_039_104,           # 6 layers of 50.3M and 103M embedding
@@ -184,7 +196,7 @@ HAND_COUNTS = {
         prefill_flops=48 * 1024 * (2 * (405_039_104 - 50304 * 2048)
                                    + 4 * 6 * 2048 * 1024)
         + 48 * 2 * 50304 * 2048,
-        decode_step_bytes=4 * 405_039_104
+        decode_step_bytes=2 * 405_039_104
         + 2 * 6 * 48 * (1024 + 64) * 2048 * 2),
     "pythia-6.9b-widths": dict(
         n_params=1_011_912_704,         # 4 layers of 201.3M and 206.6M embedding
@@ -192,7 +204,7 @@ HAND_COUNTS = {
         prefill_flops=48 * 1024 * (2 * (1_011_912_704 - 50432 * 4096)
                                    + 4 * 4 * 4096 * 1024)
         + 48 * 2 * 50432 * 4096,
-        decode_step_bytes=4 * 1_011_912_704
+        decode_step_bytes=2 * 1_011_912_704
         + 2 * 4 * 48 * (1024 + 64) * 4096 * 2),
     "olmoe-1b-7b": dict(
         # 7 layers of 4 projections, two norms' scales, a router and 64
@@ -203,20 +215,25 @@ HAND_COUNTS = {
         prefill_flops=48 * 1024 * (
             2 * 7 * (4 * 2048 * 2048 + 2048 * 64 + 8 * 3 * 2048 * 1024)
             + 4 * 7 * 2048 * 1024) + 48 * 2 * 50304 * 2048,
-        decode_step_bytes=2 * 3_143_034_880
+        decode_step_bytes=2 * (3_143_034_880 - 50304 * 2048)
         + 2 * 7 * 48 * (1024 + 64) * 2048 * 2),
 }
-# the parent's ``facts()``, printed (CPU box, shapes only)
+# the parent's ``facts()``, printed (CPU box, shapes only), and under
+# ``decode_step_bytes`` the parent's less what a step does not read: the
+# float32 half of the Pythia parameters (1,620,156,416 and 4,047,650,816
+# bytes) and OLMoE's embedding in bfloat16 (206,045,184 bytes)
 PARENT_FACTS = {
     "pythia-1.4b-widths": dict(
         n_params=405039104, flops_per_token=2732224512,
-        prefill_flops=32173222526976, decode_step_bytes=4187070464.0),
+        prefill_flops=32173222526976,
+        decode_step_bytes=4187070464.0 - 1620156416 // 2),
     "pythia-6.9b-widths": dict(
         n_params=1011912704, flops_per_token=6474129408,
-        prefill_flops=82486826631168, decode_step_bytes=7470202880.0),
+        prefill_flops=82486826631168,
+        decode_step_bytes=7470202880.0 - 4047650816 // 2),
     "olmoe-1b-7b": dict(
         n_params=3143034880, prefill_flops=49165790871552,
-        decode_step_bytes=9280802816.0),
+        decode_step_bytes=9280802816.0 - 206045184),
 }
 
 
@@ -258,6 +275,41 @@ def test_facts_of_the_pythia_configurations_are_the_hand_counts(name):
         assert facts.keys() & {"flops_per_token", "prefill_flops"}
 
 
+@pytest.mark.parametrize("name", sorted(CONFIGS))
+def test_a_lookup_table_the_reference_names_leaves_a_cached_steps_bytes(name):
+    """Through the decode runner's ``facts()``, shapes only: the same
+    configuration under a reference that counts 1000 more elements of a
+    table that a step only looks rows up in reads that many elements fewer a
+    step, at the narrower of the stored and the computing type, and nothing
+    else of ``facts()`` moves."""
+    config = CONFIGS[name]
+    traffic = cells.load_json(f"{cells.BENCH_DIR}/traffic/decode-1k-128.json")
+    runner = cells.load_module(
+        f"{cells.BENCH_DIR}/runners/{traffic['runner']}.py")
+    job = runner.build(config, traffic, jax.devices()[:config["chips"]])
+    job.n_params = sum(math.prod(dims) for dims, _std in
+                       program.param_table(job.reference, config).values())
+    before, ref = job.facts(), job.reference
+
+    class WithTable:
+        @staticmethod
+        def counts(shape):
+            counted = ref.counts(shape)
+            return {**counted, "lookup_params":
+                    counted.get("lookup_params", 0) + 1000}
+
+    job.reference = WithTable
+    after = job.facts()
+    narrower = min(jax.numpy.dtype(dtype).itemsize for dtype in (
+        config["param_dtype"], config["entry"]["options"]["compute_dtype"]))
+    assert (before["decode_step_bytes"] - after["decode_step_bytes"]
+            == 1000 * narrower)
+    assert (after["counts"]["lookup_params"]
+            == before["counts"]["lookup_params"] + 1000)
+    for key in before.keys() - {"decode_step_bytes", "counts"}:
+        assert after[key] == before[key], key
+
+
 def test_costs_count_only_what_the_counts_say():
     """A routed model with an untied head: 1000 active block parameters, a
     projection of 35 and an embedding that counts nothing; K and V narrower
@@ -271,10 +323,16 @@ def test_costs_count_only_what_the_counts_say():
         B * T * (2 * 1000 + 4 * L * D * T) + B * 2 * 35)
 
 
-def test_decode_bytes_are_parameters_plus_live_kv():
+def test_decode_bytes_are_parameters_as_read_plus_live_kv():
     params = {"a": np.zeros((10, 3), np.float32), "b": np.zeros(7, np.int8)}
     assert costs.tree_count(params) == 37
-    assert costs.tree_bytes(params) == 127
+    # float32 stored and bfloat16 read: half the bytes; bfloat16 stored and
+    # float32 computed: the step reads what is stored; a table of 7 that a
+    # step only looks rows up in leaves the count
+    assert costs.step_param_bytes(37, 0, 4, 4) == 148
+    assert costs.step_param_bytes(37, 0, 4, 2) == 74
+    assert costs.step_param_bytes(37, 0, 2, 4) == 74
+    assert costs.step_param_bytes(37, 7, 4, 2) == 60
     L, B, Tp, N, D = 6, 48, 1024, 128, 2048
     live = 2 * L * B * (Tp + N / 2) * D * 2      # k and v, bfloat16
     assert costs.kv_bytes(L, B, Tp + N / 2, 2 * D, 2) == live

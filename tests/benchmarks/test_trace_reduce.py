@@ -168,3 +168,62 @@ def test_first_stage_reads_what_jax_profiler_writes():
     kept = xplane.device_and_span_events(events)
     assert kept == ours or sorted(kept) == sorted(ours)
     assert xplane.reduce_events(kept) is None
+
+
+# ---- a trace that lost events -----------------------------------------------
+
+def program_runs(device, rows):
+    return [Event(f"/device:TPU:{device}", xplane.MODULES_LINE, name, lo,
+                  hi - lo) for name, lo, hi in rows]
+
+
+def test_program_coverage_is_the_least_covered_devices_share():
+    """Device 0 runs a program over [0, 100] and its operations cover 70 of
+    it (the asynchronous collective counts while it is in flight); device 1
+    runs one over [0, 80], covered whole; a device without a program run,
+    or a trace without one, has nothing to cover."""
+    assert xplane.program_coverage(SYNTHETIC) == pytest.approx(0.70)
+    both = SYNTHETIC + program_runs(1, [("jit_step", 0, 80)])
+    assert xplane.program_coverage(both) == pytest.approx(0.70)
+    assert xplane.program_coverage(
+        ops(1, [("fusion.1", 0, 50), ("fusion.2", 50, 80)])
+        + program_runs(1, [("jit_step", 0, 80)])) == 1.0
+    assert xplane.program_coverage(ops(1, [("fusion.1", 0, 50)])) is None
+    assert xplane.program_coverage([]) is None
+
+
+@pytest.mark.parametrize("file", [
+    "pythia-6.9b-widths.train-2k-dp2tp2.events.json.gz",
+    "scoped/pythia-6.9b-widths.train-2k-dp2tp2.events.json.gz"])
+def test_a_trace_recorded_on_the_chip_is_whole(file):
+    events = xplane.load_events(os.path.join(TESTDATA, file))
+    assert 0.999 < xplane.program_coverage(events) <= 1.0
+    assert xplane.WHOLE < 0.999
+
+
+@pytest.mark.parametrize("covered,taken", [
+    ([1.0], 1), ([0.6, 0.995], 2), ([0.5, 0.6, 0.7], 3), ([None], 1)])
+def test_a_trace_that_lost_events_is_taken_again(monkeypatch, capsys,
+                                                 covered, taken):
+    """``whole_trace`` traces again while the operations leave a hole in the
+    device's program runs, three times at most, keeps the last trace, and
+    says what it saw."""
+    traces = []
+
+    def trace_samples(job, n):
+        share = covered[len(traces)]
+        traces.append(
+            [] if share is None else
+            ops(0, [("fusion.1", 0, round(1000 * share))])
+            + program_runs(0, [("jit_step", 0, 1000)]))
+        return traces[-1]
+
+    monkeypatch.setattr(bench_run, "trace_samples", trace_samples)
+    traced, tracing = bench_run.whole_trace(object(), 1)
+    assert len(traces) == taken and traced is traces[-1]
+    assert tracing == {"covered": covered[:taken], "retraced": taken - 1}
+    said = capsys.readouterr().err
+    assert said.count("events were lost") == sum(
+        share is not None and share < xplane.WHOLE
+        for share in covered[:taken])
+    assert said.count("tracing again") == taken - 1
