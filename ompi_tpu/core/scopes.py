@@ -1,13 +1,18 @@
 """The names inside the device programs.
 
 Every boundary the performance records talk about is a ``jax.named_scope``
-from the one vocabulary below, so that a profile of any program built on
-this package can be read by layer instead of by ``fusion.461``
-(``benchmarks/lib/scopes.py`` is the reader; ``PERF.md`` section 3 says
-which metric reads which name).  A scope is HLO metadata (``op_name``): it
-changes no computation and costs nothing when the program runs.  JAX's own
-name stack already tells forward (``jvp``), backward (``transpose``) and
-recomputation (``rematted_computation``) apart, so none of them is a scope.
+from the one vocabulary below.  ``SCOPES`` is the only copy of it: the
+reader of a profile, ``benchmarks/lib/scopes.py``, keeps no list of its own
+and looks a name up in this tuple when it classifies an operation, so a name
+added here is a scope there, a key of its table and a line of a traced run's
+``breakdown.device_scopes``, with no edit under ``benchmarks/``; a metric
+that reads it is a data file that names its keys (``PERF.md`` section 3 says
+which metric reads which name).  A profile of any program built on this
+package is thus read by layer instead of by ``fusion.461``.  A scope is HLO
+metadata (``op_name``): it changes no computation and costs nothing when
+the program runs.  JAX's own name stack already tells forward (``jvp``),
+backward (``transpose``) and recomputation (``rematted_computation``)
+apart, so none of them is a scope.
 
 One caution: JAX's persistent compilation cache leaves metadata out of its
 key.  A program that differs from a cached one only in where its scopes sit
@@ -30,6 +35,11 @@ SCOPES = (
     "attention.flash",  # ... the pallas kernels
     "ffn",              # ln2, the MLP, the residual
     "moe.route", "moe.dispatch", "moe.experts", "moe.combine",
+    "ssm_proj",         # the mixer's scalings, in/out projections, gated norm
+    "ssm.conv",         # ... its causal convolution (from a state, when cached)
+    "ssm.scan",         # ... the chunked scan over a whole sequence
+    "ssm.update",       # ... one cached step's recurrence: from reading the
+                        # layer's state out of the carry to writing it back
     "loss",             # the unembed matmul and the cross entropy
     "optimizer",        # the optimizer's update and its application
     "prefill",          # backbone over the prompt, cache padding, first logits

@@ -2,22 +2,42 @@
 of the train step, built from the same layer math.
 
 TPU-first shape: ONE compiled program per (prompt_len, max_new) pair —
-prefill runs the training backbone once (``collect_kv`` returns every
-layer's post-rope K/V in a single pass), then a ``lax.scan`` generates
+prefill runs the training backbone (``collect_kv`` returns every
+layer's post-rope K/V, and with a hybrid block its mixer's states after
+the last position, in a single pass), then a ``lax.scan`` generates
 tokens against a static-shape cache (no growing arrays, no
 recompilation per token).
 
 The life of the cache: it is allocated once, stacked over layers at its
-final length ``(L, B, Tp+max_new, Hl, hd)``, and from then on it is loop
-carry — of the token scan and, inside it, of a ``lax.fori_loop`` over
-the layer index.  A layer writes its new K/V in place at the one
+final length ``(L, B, Tp+max_new, Hkv/tp, hd)``, and from then on it is
+loop carry — of the token scan and, inside it, of a ``lax.fori_loop``
+over the layer index.  A layer writes its new K/V in place at the one
 position ``(l, 0, pos, 0, 0)`` of the whole stack and attention reads
 layer ``l`` through a slice that the compiler fuses into the scores and
-context products, so a step writes ``B·Hl·hd`` values a layer and reads
+context products, so a step writes ``B·Hkv·hd`` values a layer and reads
 the cache once.  The cache is never the ``xs`` or ``ys`` of a scan:
 those are separate buffers, and a step would then copy every layer's
 cache out of the stack and back (``tests/parallel/test_decode.py`` holds
-the compiled program to this).
+the compiled program to this).  K/V heads may be fewer than query heads
+(``TransformerConfig.n_kv_heads``): the cache holds the K/V heads and a
+step reads each once for the query heads it serves.
+
+A hybrid block (``models/ssm.py``: a state-space mixer beside attention
+in every layer) carries two more stacks the same way, of a size that
+does not grow with the sequence: the convolution's last inputs ``(L, B,
+d_conv - 1, conv_dim)`` and the heads' states ``(L, B, H, P, N)`` in the
+block's ``state_dtype``.  A step reads layer ``l``'s states out of the
+stacks and writes them back in place at ``(l,)``; the update is float32
+and is rounded once on the way back (the same test file holds the chip's
+compiled program to one write a step and no copy of the stack).
+
+The prefill hands the carry over.  By default it is one pass over every
+prompt whose K/V are padded to the cache's length.  With
+``TransformerConfig.prefill_tokens`` (and for a hybrid block, always) the
+carry is allocated first at its final size and the prompts are
+prefilled a group of whole sequences at a time, each group writing its
+K/V and final states into it, so the pass's temporaries are a group's
+and not the batch's.
 
 Sharding: batch over dp, heads over tp (the cache is
 head-sharded exactly like the weights); greedy argmax over the full
@@ -44,14 +64,18 @@ from ompi_tpu.parallel.moe import EXPERT_LEAVES
 __all__ = ["make_decoder"]
 
 
-def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, layer, pos):
+def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, layer, pos,
+                states=()):
     """Layer ``layer`` for ONE new token position, against the whole cache.
 
-    h: (B, 1, D); kc/vc: the stacked cache (L, B, Tmax, Hl, hd); lp:
+    h: (B, 1, D); kc/vc: the stacked cache (L, B, Tmax, Hkv/tp, hd); lp:
     this layer's parameters, but for the dropless experts' leaves
     (``moe.EXPERT_LEAVES``), which are the whole stacks over layers that
-    ``routed_moe`` indexes by ``layer``.  Returns (h, kc, vc) with the new
-    token's k/v written in place at ``(layer, :, pos)``.
+    ``routed_moe`` indexes by ``layer``.  ``states``: with a hybrid block
+    the mixer's two stacks, the convolution's last inputs ``(L, B,
+    d_conv - 1, conv_dim)`` and the heads' states ``(L, B, H, P, N)``.
+    Returns (h, kc, vc, *states) with the new token's k/v written in place
+    at ``(layer, :, pos)`` and the layer's states at ``(layer,)``.
     """
     import jax
     import jax.numpy as jnp
@@ -62,20 +86,26 @@ def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, layer, pos):
 
     cdt = h.dtype
     B = h.shape[0]
-    Tmax, hl, hd = kc.shape[2:]
+    Tmax, hkv, hd = kc.shape[2:]
+    hl = hkv * (cfg.n_heads // cfg.kv_heads)
+    hy = cfg.hybrid
 
     with scope("attn_proj"):
         x = _rmsnorm(h, lp["ln1"], cfg.norm_eps)
+        xa = x if hy is None else x * hy.attention_in_multiplier
 
-        def project(w, norm=None):
-            y = column_parallel(x, lp[w].astype(cdt))
+        def project(w, heads, norm=None):
+            y = column_parallel(xa, lp[w].astype(cdt))
             if cfg.qk_norm and norm:
                 y = _qk_norm(cfg, y, lp[norm], comm)
-            return y.reshape(B, 1, hl, hd)
+            return y.reshape(B, 1, heads, hd)
 
-        q, k, v = project("wq", "qn"), project("wk", "kn"), project("wv")
-        q = _rope(q, pos[None])
-        k = _rope(k, pos[None])
+        q, k, v = (project("wq", hl, "qn"), project("wk", hkv, "kn"),
+                   project("wv", hkv))
+        if hy is not None:
+            k = k * hy.key_multiplier
+        q = _rope(q, pos[None], theta=cfg.rope_theta)
+        k = _rope(k, pos[None], theta=cfg.rope_theta)
     with scope("kv_cache"):
         kc = lax.dynamic_update_slice(kc, k.astype(kc.dtype)[None],
                                       (layer, 0, pos, 0, 0))
@@ -85,32 +115,78 @@ def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, layer, pos):
         # scores against every cached position, masked beyond `pos`
         k_all = lax.dynamic_index_in_dim(kc, layer, keepdims=False)
         v_all = lax.dynamic_index_in_dim(vc, layer, keepdims=False)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                       k_all.astype(jnp.float32)) * (hd ** -0.5)
-        live = jnp.arange(Tmax)[None, None, None, :] <= pos
-        s = jnp.where(live, s, -1e30)
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", w, v_all.astype(jnp.float32))
+        if hkv == hl:
+            s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                           k_all.astype(jnp.float32)) * (hd ** -0.5)
+            live = jnp.arange(Tmax)[None, None, None, :] <= pos
+            s = jnp.where(live, s, -1e30)
+            w = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bhqk,bkhd->bqhd", w, v_all.astype(jnp.float32))
+        else:       # K/V head g serves the query heads (g, r): read it once
+            qg = q.astype(jnp.float32).reshape(B, 1, hkv, hl // hkv, hd)
+            s = jnp.einsum("bqgrd,bkgd->bgrqk", qg,
+                           k_all.astype(jnp.float32)) * (hd ** -0.5)
+            s = jnp.where(jnp.arange(Tmax) <= pos, s, -1e30)
+            w = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bgrqk,bkgd->bqgrd", w,
+                           v_all.astype(jnp.float32))
     with scope("attn_proj"):
         o = o.astype(cdt).reshape(B, 1, hl * hd)
-        h = h + row_parallel(o, lp["wo"].astype(cdt), comm, axis="tp")
+        a = row_parallel(o, lp["wo"].astype(cdt), comm, axis="tp")
+        if hy is None:
+            h = h + a
+    if hy is not None:
+        from ompi_tpu.models import ssm
+
+        s, *states = ssm.mixer(cfg, lp, x, carry=(*states, layer))
+        with scope("attn_proj"):
+            h = h + a * hy.attention_out_multiplier + s
     if cfg.moe_experts:
         # aux is training-only; the dropless experts come as whole stacks
         h, _aux = _moe_ffn_tail(cfg, h, lp, comm,
                                 layer=layer if cfg.moe_top_k else None)
-        return h, kc, vc
-    return _dense_ffn_tail(h, lp, comm, cdt, cfg.norm_eps), kc, vc
+        return h, kc, vc, *states
+    return (_dense_ffn_tail(h, lp, comm, cdt, cfg.norm_eps,
+                            gated=hy and hy.mlp_multipliers), kc, vc, *states)
+
+
+def _prefill_group(batch: int, prompt_len: int, prefill_tokens: int) -> int:
+    """Sequences one pass of the prefill holds: the most that divide the
+    batch and stay within ``prefill_tokens`` tokens (0: the whole batch)."""
+    if not prefill_tokens:
+        return batch
+    return max(g for g in range(1, batch + 1)
+               if batch % g == 0 and (g == 1 or g * prompt_len
+                                      <= prefill_tokens))
 
 
 def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
-                 temperature: float = 0.0, top_k: int = 0):
+                 temperature: float = 0.0, top_k: int = 0,
+                 keep_logits: int = 0):
     """jitted (params, prompt (B, Tp) int32[, seed]) → (B, Tp+max_new).
 
     Greedy decode by default: prefill through the training backbone
-    (one pass, K/V collected per layer), then ``max_new`` single-token
-    steps over the static cache.  Requires sp == 1; dense, switch-MoE
-    and dropless top-k MoE configs are supported (MoE routes each token
-    through the same layer as training).
+    (K/V collected per layer), then ``max_new`` single-token
+    steps over the static cache.  Requires sp == 1; dense, switch-MoE,
+    dropless top-k MoE and hybrid (``models/ssm.py``) configs are
+    supported (MoE routes each token through the same layer as training).
+
+    The carry of the token scan and of the loop over layers inside it:
+    K and V ``(L, B, Tp+max_new, Hkv/tp, hd)`` in the compute dtype and,
+    with a hybrid block, the mixer's two states beside them, stacked over
+    layers alike: the convolution's last inputs ``(L, B, d_conv - 1,
+    conv_dim)`` and the heads' states ``(L, B, H, P, N)`` in the block's
+    ``state_dtype``.  The prefill hands over each layer's K/V and its
+    states after the last prompt position.  It runs in one pass and pads
+    its K/V to the cache's length; with ``cfg.prefill_tokens``, or a hybrid
+    block, it runs a group of whole sequences at a time, each group
+    writing its K/V and states into the carry allocated once at its final
+    size (one group where ``prefill_tokens`` is 0).
+
+    ``keep_logits=n``: returns ``(tokens, logits)``, ``logits`` float32
+    ``(n, max_new, vocab)``: what each generated token of the first ``n``
+    sequences was picked from, the prefill's logits for the first and the
+    cached step's after.  Needs dp == 1.
 
     ``temperature > 0`` switches to sampling (optionally truncated to
     the ``top_k`` highest logits); the returned callable then takes a
@@ -136,6 +212,15 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
     if int(mesh.shape["sp"]) != 1:
         raise ValueError("decode requires sp == 1 (sequence parallelism "
                          "is a training-time layout)")
+    if keep_logits and int(mesh.shape["dp"]) != 1:
+        raise ValueError(f"keep_logits={keep_logits} hands back the first "
+                         f"sequences' logits whole and needs dp == 1; the "
+                         f"mesh has dp={mesh.shape['dp']}")
+    hy = cfg.hybrid
+    if hy is not None:
+        from ompi_tpu.models import ssm
+
+        ssm.check_mesh(cfg, mesh)
     axes = tuple(a for a in ("dp", "sp", "tp", "ep")
                  if a in mesh.axis_names)
     comm = DeviceCommunicator(mesh, axes)
@@ -162,17 +247,51 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
         return jax.random.categorical(key, scaled,
                                       axis=-1).astype(jnp.int32)
 
+    def prefill_in_groups(params, prompt):
+        """The carry, filled a group of sequences at a time: (last hidden
+        states (B, D), kc, vc, *states)."""
+        B, Tp = prompt.shape
+        group = _prefill_group(B, Tp, cfg.prefill_tokens)
+        kv = (cfg.n_layers, B, Tp + max_new,
+              cfg.kv_heads // int(mesh.shape["tp"]), cfg.head_dim)
+        stacks = [jnp.zeros(kv, cdt), jnp.zeros(kv, cdt)]
+        if hy is not None:
+            stacks += [jnp.zeros((cfg.n_layers, *shape), dtype)
+                       for shape, dtype in zip(ssm.state_shapes(cfg, B),
+                                               (cdt, hy.state_dtype))]
+
+        def one(g, carry):
+            last, *stacks = carry
+            rows = lax.dynamic_slice_in_dim(prompt, g * group, group)
+            h, (_aux, *cached) = tfm._local_backbone(
+                cfg, comm, params, rows, collect_kv=True)
+            stacks = [lax.dynamic_update_slice(
+                stack, new.astype(stack.dtype),
+                (0, g * group) + (0,) * (stack.ndim - 2))
+                for stack, new in zip(stacks, cached)]
+            return (lax.dynamic_update_slice(last, h[:, -1, :],
+                                             (g * group, 0)), *stacks)
+
+        return lax.fori_loop(
+            0, B // group, one, (jnp.zeros((B, cfg.d_model), cdt), *stacks))
+
     def local(params, prompt, seed):
         B, Tp = prompt.shape
+        if keep_logits > B:
+            raise ValueError(f"keep_logits={keep_logits} of {B} sequences")
         head = _head(cfg, params).astype(cdt)
-        # ---- prefill: one training-backbone pass, K/V collected ----
+        # ---- prefill: the training backbone, K/V collected ----
         with scope("prefill"):
-            h, (_aux, ks, vs) = tfm._local_backbone(
-                cfg, comm, params, prompt, collect_kv=True)
-            pad = [(0, 0), (0, 0), (0, max_new), (0, 0), (0, 0)]
-            kc = jnp.pad(ks, pad)       # (L, B, Tp+max_new, Hl, hd)
-            vc = jnp.pad(vs, pad)
-            logits = jnp.einsum("bd,vd->bv", h[:, -1, :], head,
+            if hy is None and not cfg.prefill_tokens:
+                h, (_aux, ks, vs) = tfm._local_backbone(
+                    cfg, comm, params, prompt, collect_kv=True)
+                pad = [(0, 0), (0, 0), (0, max_new), (0, 0), (0, 0)]
+                kc = jnp.pad(ks, pad)       # (L, B, Tp+max_new, Hl, hd)
+                vc = jnp.pad(vs, pad)
+                last, states = h[:, -1, :], ()
+            else:
+                last, kc, vc, *states = prefill_in_groups(params, prompt)
+            logits = jnp.einsum("bd,vd->bv", last, head,
                                 preferred_element_type=jnp.float32)
             tok0 = pick(logits, jnp.int32(Tp - 1), seed)          # (B,)
 
@@ -181,9 +300,11 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
         whole = EXPERT_LEAVES if cfg.moe_top_k else ()
 
         def gen(carry, _):
-            kc, vc, tok, pos = carry
+            kc, vc, *states, tok, pos = carry
             with scope("embed"):
                 h = params["emb"][tok].astype(cdt)[:, None, :]  # (B, 1, D)
+                if hy is not None:
+                    h = h * hy.embedding_multiplier
 
             # the whole stacked cache is this loop's carry too; as a
             # scan's xs and ys it would be sliced out and copied back
@@ -191,18 +312,22 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
                 lp = {k: w if k in whole
                       else lax.dynamic_index_in_dim(w, layer, keepdims=False)
                       for k, w in layer_params.items()}
-                return _step_layer(cfg, comm, lp, *state, layer, pos)
+                return _step_layer(cfg, comm, lp, *state[:3], layer, pos,
+                                   state[3:])
 
             with scope("layers"):
-                h, kc, vc = lax.fori_loop(0, cfg.n_layers, per_layer,
-                                          (h, kc, vc))
+                h, kc, vc, *states = lax.fori_loop(
+                    0, cfg.n_layers, per_layer, (h, kc, vc, *states))
             with scope("unembed"):
                 h = _rmsnorm(h, params["lnf"], cfg.norm_eps)
+                if hy is not None:
+                    h = h * hy.lm_head_multiplier
                 logits = jnp.einsum("bd,vd->bv", h[:, 0, :], head,
                                     preferred_element_type=jnp.float32)
             with scope("sample"):
                 nxt = pick(logits, pos, seed)
-            return (kc, vc, nxt, pos + 1), nxt
+            out = (nxt, logits[:keep_logits]) if keep_logits else nxt
+            return (kc, vc, *states, nxt, pos + 1), out
 
         # emit the PRODUCED token and scan max_new-1 steps: tok0 is
         # already known from prefill, so the last single-token pass is
@@ -210,17 +335,23 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
         # (the scope is around the scan, not inside ``gen``, so that a
         # copy XLA makes of the loop's carry would be the step's as well)
         with scope("decode.step"):
-            (_, _, _, _), toks = lax.scan(
-                gen, (kc, vc, tok0, jnp.int32(Tp)), None,
+            _, toks = lax.scan(
+                gen, (kc, vc, *states, tok0, jnp.int32(Tp)), None,
                 length=max_new - 1)
+        if keep_logits:
+            toks, kept = toks
+            kept = jnp.concatenate([logits[None, :keep_logits], kept],
+                                   axis=0).swapaxes(0, 1)
         gen_toks = jnp.concatenate(
             [tok0[None], toks], axis=0)       # (max_new, B)
-        return jnp.concatenate([prompt, gen_toks.swapaxes(0, 1)], axis=1)
+        tokens = jnp.concatenate([prompt, gen_toks.swapaxes(0, 1)], axis=1)
+        return (tokens, kept) if keep_logits else tokens
 
     mapped = jax.shard_map(
         local, mesh=mesh,
         in_specs=(param_specs(P, cfg, mesh), P("dp", None), P()),
-        out_specs=P("dp", None), check_vma=False)
+        out_specs=(P("dp", None), P()) if keep_logits else P("dp", None),
+        check_vma=False)
     # the function's name is the program's name in a profile
     @jax.jit
     def decode(params, prompt, seed):

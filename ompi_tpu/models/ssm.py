@@ -1,0 +1,307 @@
+"""The hybrid block: a Mamba-2 mixer beside attention in every layer.
+
+``TransformerConfig.hybrid`` holds a :class:`HybridBlock`; the block of
+``models/transformer.py`` then computes, from one normed input ``u``,
+
+    x <- x + attention(u) * attention_out_multiplier + mixer(u)
+    x <- x + down(silu(gate(f) * m0) * up(f)) * m1,   f = RMSNorm(x; ln2)
+
+with grouped K/V heads, the embedding, the keys, the attention's input and
+the logits each scaled by a published constant (``falcon_h1``'s block).
+This module has what is the mixer's own: its sizes and the multipliers, its
+leaves, and :func:`mixer`, the one function both paths call: the whole
+sequence (trainer, prefill: a causal convolution over the sequence and the
+chunked scan, from a zero state) and one position against a carried state
+(``models/decode.py``: the convolution from its last inputs, the recurrence
+once).  Everything here is ``jax.numpy`` and ``lax``: no kernel.
+
+Nothing imports this module but a configuration that has the block, so the
+other programs' set-up does not pay for it.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+
+import numpy as np
+
+__all__ = ["HybridBlock", "hybrid_config", "mixer", "chunked_scan",
+           "init_leaves", "leaf_names", "state_shapes", "check_mesh"]
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridBlock:
+    """Sizes of the mixer and the block's constant multipliers, under the
+    published configuration's names where it has one."""
+    d_ssm: int                  # the mixer's inner width: heads x head width
+    d_state: int                # N: a head's state is (head width, N)
+    n_groups: int               # groups that share one B and one C
+    n_heads: int
+    d_conv: int                 # taps of the causal depthwise convolution
+    chunk: int                  # positions a chunk of the whole-sequence scan
+    embedding_multiplier: float
+    attention_in_multiplier: float
+    attention_out_multiplier: float
+    key_multiplier: float
+    lm_head_multiplier: float
+    ssm_in_multiplier: float
+    ssm_multipliers: tuple      # on z, x, B, C, dt of the input projection
+    ssm_out_multiplier: float
+    mlp_multipliers: tuple      # on the gate's pre-activation, on the output
+    # what the carried state is stored in between cached steps; the update
+    # itself is float32 and is rounded once, on the way back
+    state_dtype: str = "bfloat16"
+
+    @property
+    def head_dim(self) -> int:
+        return self.d_ssm // self.n_heads
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: x, B and C."""
+        return self.d_ssm + 2 * self.n_groups * self.d_state
+
+    @property
+    def in_dim(self) -> int:
+        """Columns of the input projection: z, x, B, C, dt in this order."""
+        return self.d_ssm + self.conv_dim + self.n_heads
+
+
+_BLOCK_FIELDS = {f.name for f in dataclasses.fields(HybridBlock)}
+
+
+def hybrid_config(**sizes):
+    """``entry.config`` of a configuration file with this block: a
+    ``TransformerConfig`` from flat keys, those of :class:`HybridBlock`
+    (``n_heads`` of the mixer as ``ssm_heads``) gathered under ``hybrid``."""
+    from ompi_tpu.models.transformer import TransformerConfig
+
+    ssm_heads = sizes.pop("ssm_heads")      # n_heads stays attention's
+    block = {k: sizes.pop(k) for k in list(sizes)
+             if k in _BLOCK_FIELDS and k != "n_heads"}
+    for pair in ("ssm_multipliers", "mlp_multipliers"):
+        block[pair] = tuple(float(m) for m in block[pair])
+    # a float: 1e11 is past int32, which a python int would be traced as
+    sizes["rope_theta"] = float(sizes.get("rope_theta", 10_000))
+    return TransformerConfig(
+        hybrid=HybridBlock(n_heads=ssm_heads, **block), **sizes)
+
+
+def check_mesh(cfg, mesh) -> None:
+    """The block's 2 groups and 4 K/V heads bound any split over ``tp``, and
+    over ``sp`` the scan needs an exclusive scan of per-rank states: neither
+    is built, and no cell asks."""
+    for axis in ("sp", "tp"):
+        if int(dict(mesh.shape).get(axis, 1)) > 1:
+            raise ValueError(
+                f"the hybrid block (a state-space mixer beside attention) "
+                f"runs with {axis} == 1 only, and the mesh has {axis}="
+                f"{mesh.shape[axis]}: its mixer is not split over {axis}")
+
+
+def leaf_names() -> tuple:
+    """The block's leaves beside those of the dense block, stacked over
+    layers: ``w3`` is the MLP's up projection (``w1`` its gate, ``w2`` its
+    down projection)."""
+    return ("w3", "ssm_in", "ssm_out", "conv_w", "conv_b", "a_log",
+            "dt_bias", "ssm_d", "ssm_norm")
+
+
+def init_leaves(cfg, rng) -> dict:
+    """The block's own leaves as the model initialises them, float32: dt
+    log-uniform in [1e-3, 1e-1] through the inverse softplus, A uniform in
+    [1, 16], D ones."""
+    hy, L, D, F = cfg.hybrid, cfg.n_layers, cfg.d_model, cfg.d_ff
+    depth = math.sqrt(max(1, 2 * L))
+
+    def w(*shape, scale):
+        return rng.normal(0, scale, size=shape).astype(np.float32)
+
+    dt = np.exp(rng.uniform(math.log(1e-3), math.log(1e-1),
+                            size=(L, hy.n_heads)))
+    bound = hy.d_conv ** -0.5
+    return {
+        "w3": w(L, D, F, scale=D ** -0.5),
+        "ssm_in": w(L, D, hy.in_dim, scale=D ** -0.5),
+        "ssm_out": w(L, hy.d_ssm, D, scale=hy.d_ssm ** -0.5 / depth),
+        "conv_w": rng.uniform(-bound, bound, size=(L, hy.d_conv, hy.conv_dim)
+                              ).astype(np.float32),
+        "conv_b": np.zeros((L, hy.conv_dim), np.float32),
+        "a_log": np.log(rng.uniform(1, 16, size=(L, hy.n_heads))
+                        ).astype(np.float32),
+        "dt_bias": (dt + np.log(-np.expm1(-dt))).astype(np.float32),
+        "ssm_d": np.ones((L, hy.n_heads), np.float32),
+        "ssm_norm": np.ones((L, hy.d_ssm), np.float32),
+    }
+
+
+def state_shapes(cfg, batch: int) -> tuple:
+    """One layer's carried state for ``batch`` sequences: the convolution's
+    last inputs ``(B, d_conv - 1, conv_dim)`` and the heads' states
+    ``(B, heads, head width, N)``."""
+    hy = cfg.hybrid
+    return ((batch, hy.d_conv - 1, hy.conv_dim),
+            (batch, hy.n_heads, hy.head_dim, hy.d_state))
+
+
+def _column_multipliers(hy: HybridBlock) -> np.ndarray:
+    """The constant vector on the input projection's columns."""
+    z, x, b, c, dt = hy.ssm_multipliers
+    gn = hy.n_groups * hy.d_state
+    return np.concatenate([np.full(hy.d_ssm, z), np.full(hy.d_ssm, x),
+                           np.full(gn, b), np.full(gn, c),
+                           np.full(hy.n_heads, dt)]).astype(np.float32)
+
+
+def chunked_scan(x, dt, a, b, c, chunk: int):
+    """``h_t = exp(dt_t a) h_{t-1} + dt_t x_t (x) B_t``, ``y_t = h_t C_t``
+    over whole sequences from a zero state, a chunk of ``chunk`` positions at
+    a time: inside a chunk as products over its positions, across chunks as
+    the recurrence on the chunks' final states.
+
+    x: (B, T, H, P); dt: (B, T, H) float32, positive; a: (H,) float32,
+    negative; b, c: (B, T, G, N), head ``h`` reading group ``h // (H / G)``.
+    The decays are float32; the products run in x's type and add up in
+    float32.  A length that is no multiple of the chunk is
+    padded with positions of dt = 0, which leave the state as it is.
+    Returns y (B, T, H, P) float32 and the final state (B, H, P, N) float32.
+    """
+    import jax.numpy as jnp
+    from jax import lax
+
+    f32, cdt = jnp.float32, x.dtype
+    B, T, H, P = x.shape
+    G, N = b.shape[2:]
+    R, Q = H // G, chunk
+    nc = -(-T // Q)
+    pad = [(0, 0), (0, nc * Q - T)]
+    xs = jnp.pad(x.astype(f32) * dt[..., None], pad + [(0, 0), (0, 0)])
+    xs = xs.reshape(B, nc, Q, G, R, P).astype(cdt)      # dt-weighted input
+    cs = jnp.cumsum(jnp.pad(dt * a, pad + [(0, 0)]).reshape(B, nc, Q, G, R),
+                    axis=2)                             # log-decay, inclusive
+    bc = jnp.pad(b, pad + [(0, 0), (0, 0)]).reshape(B, nc, Q, G, N)
+    cc = jnp.pad(c, pad + [(0, 0), (0, 0)]).reshape(B, nc, Q, G, N)
+
+    # inside a chunk: position q reads k <= q through exp(cs_q - cs_k)
+    cb = jnp.einsum("bxqgn,bxkgn->bxgqk", cc.astype(cdt), bc.astype(cdt),
+                    preferred_element_type=f32)
+    seg = (jnp.moveaxis(cs, 2, -1)[..., :, None]
+           - jnp.moveaxis(cs, 2, -1)[..., None, :])     # (B, nc, G, R, q, k)
+    causal = jnp.tril(jnp.ones((Q, Q), bool))
+    weight = cb[:, :, :, None] * jnp.exp(jnp.where(causal, seg, -jnp.inf))
+    y = jnp.einsum("bxgrqk,bxkgrp->bxqgrp", weight.astype(cdt), xs,
+                   preferred_element_type=f32)
+    # a chunk's own contribution to the state at its end
+    to_end = jnp.exp(cs[:, :, -1:] - cs)                # (B, nc, Q, G, R)
+    own = jnp.einsum("bxkgrp,bxkgn->bxgrpn",
+                     (xs.astype(f32) * to_end[..., None]).astype(cdt),
+                     bc.astype(cdt), preferred_element_type=f32)
+    if nc == 1:                 # one chunk: nothing comes in from before
+        return (y.reshape(B, Q, H, P)[:, :T],
+                own.reshape(B, H, P, N))
+
+    def across(h, chunk_of):
+        own_c, decay_c = chunk_of
+        return h * decay_c[..., None, None] + own_c, h  # emits the state before
+
+    last, before = lax.scan(
+        across, jnp.zeros((B, G, R, P, N), f32),
+        (jnp.moveaxis(own, 1, 0), jnp.moveaxis(jnp.exp(cs[:, :, -1]), 1, 0)))
+    carried = jnp.einsum("bxqgn,bxgrpn->bxqgrp", cc.astype(cdt),
+                         jnp.moveaxis(before, 0, 1).astype(cdt),
+                         preferred_element_type=f32)
+    y = y + carried * jnp.exp(cs)[..., None]
+    return (y.reshape(B, nc * Q, H, P)[:, :T], last.reshape(B, H, P, N))
+
+
+def _conv_before(conv_c, layer):
+    """A cached step's last ``d_conv - 1`` inputs of layer ``layer``.  (A
+    function of its own, like ``_state_before``, so that the benchmark's
+    controls can plant a state that is not read while a decoder is traced.)"""
+    from jax import lax
+
+    return lax.dynamic_index_in_dim(conv_c, layer, keepdims=False)
+
+
+def _state_before(ssm_c, layer):
+    """A cached step's state as the update starts from it, float32."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    return lax.dynamic_index_in_dim(ssm_c, layer,
+                                    keepdims=False).astype(jnp.float32)
+
+
+def mixer(cfg, lp, u, carry=None):
+    """The mixer branch of one layer on the block's normed input ``u``
+    (B, T, D), without its residual add.
+
+    ``carry`` None: whole sequences from a zero state; returns
+    ``(s, conv_state, ssm_state)``, the layer's states after the last
+    position, ``(B, d_conv - 1, conv_dim)`` in u's type and ``(B, H, P, N)``
+    float32.  ``carry = (conv_c, ssm_c, layer)``: T == 1 against the states
+    of layer ``layer`` of the stacks ``(L, ...)``, read and written in place;
+    returns ``(s, conv_c, ssm_c)``.  The two differ only in where the
+    convolution's window comes from and in scan against one update."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ompi_tpu.core.scopes import scope
+
+    hy, f32, cdt = cfg.hybrid, jnp.float32, u.dtype
+    B, T, _ = u.shape
+    H, P, G, N = hy.n_heads, hy.head_dim, hy.n_groups, hy.d_state
+    with scope("ssm_proj"):
+        p = jnp.einsum("btd,df->btf", u * hy.ssm_in_multiplier,
+                       lp["ssm_in"].astype(cdt))
+        p = p * jnp.asarray(_column_multipliers(hy), cdt)
+        z, xbc, dt = jnp.split(p, [hy.d_ssm, hy.d_ssm + hy.conv_dim], -1)
+    with scope("ssm.conv"):
+        taps = hy.d_conv
+        if carry is None:
+            window = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
+            conv_out = window[:, T:]
+        else:
+            conv_c, ssm_c, layer = carry
+            window = jnp.concatenate(
+                [_conv_before(conv_c, layer).astype(cdt), xbc], axis=1)
+            conv_out = lax.dynamic_update_slice(
+                conv_c, window[:, 1:].astype(conv_c.dtype)[None],
+                (layer, 0, 0, 0))
+        w = lp["conv_w"].astype(f32)                    # (taps, C)
+        acc = lp["conv_b"].astype(f32) + sum(
+            window[:, k:k + T].astype(f32) * w[k] for k in range(taps))
+        xbc = jax.nn.silu(acc).astype(cdt)
+    x, b, c = jnp.split(xbc, [hy.d_ssm, hy.d_ssm + G * N], -1)
+    x = x.reshape(B, T, H, P)
+    b, c = b.reshape(B, T, G, N), c.reshape(B, T, G, N)
+    dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+    a = -jnp.exp(lp["a_log"].astype(f32))
+    if carry is None:
+        with scope("ssm.scan"):
+            y, ssm_out = chunked_scan(x, dt, a, b, c, hy.chunk)
+    else:
+        with scope("ssm.update"):
+            h = _state_before(ssm_c, layer).reshape(B, G, H // G, P, N)
+            dth = dt.reshape(B, G, H // G)              # T == 1
+            xh = x.astype(f32).reshape(B, G, H // G, P) * dth[..., None]
+            h = (h * jnp.exp(dth * a.reshape(G, H // G))[..., None, None]
+                 + xh[..., None] * b.astype(f32).reshape(B, G, 1, 1, N))
+            y = jnp.einsum("bgrpn,bgn->bgrp", h,
+                           c.astype(f32).reshape(B, G, N)).reshape(B, 1, H, P)
+            ssm_out = lax.dynamic_update_slice(
+                ssm_c, h.reshape(B, H, P, N).astype(ssm_c.dtype)[None],
+                (layer, 0, 0, 0, 0))
+    with scope("ssm_proj"):
+        y = y + lp["ssm_d"].astype(f32)[:, None] * x.astype(f32)
+        y = y.reshape(B, T, hy.d_ssm) * jax.nn.silu(z.astype(f32))
+        # gate, then an RMSNorm over each group with one scale of d_ssm
+        yg = y.reshape(B, T, G, hy.d_ssm // G)
+        yg = yg * lax.rsqrt(jnp.mean(yg * yg, axis=-1, keepdims=True)
+                            + cfg.norm_eps)
+        y = (yg.reshape(B, T, hy.d_ssm)
+             * lp["ssm_norm"].astype(f32)).astype(cdt)
+        s = jnp.einsum("btf,fd->btd", y, lp["ssm_out"].astype(cdt))
+        return s * hy.ssm_out_multiplier, conv_out, ssm_out

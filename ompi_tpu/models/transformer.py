@@ -110,10 +110,29 @@ class TransformerConfig:
     # inserts the one all-gather per leaf that re-replicates updated
     # params (see parallel/zero.py).  None = replicated optimizer state.
     zero1_axis: Any = None
+    # K/V heads, each serving n_heads / n_kv_heads query heads in order
+    # (0: as many as query heads), and a head's width where it is not
+    # d_model / n_heads (0).  The cache holds the K/V heads.
+    n_kv_heads: int = 0
+    head_width: int = 0
+    rope_theta: Any = 10_000
+    # models/ssm.HybridBlock: every layer runs a state-space mixer beside
+    # attention on one normed input, its dense MLP is gated (a third leaf
+    # "w3": down(silu(gate(x)) * up(x))), and the block's constant
+    # multipliers apply.  None: the block above.
+    hybrid: Any = None
+    # The most tokens one pass of a decoder's prefill holds: the prompts are
+    # then prefilled a group of whole sequences at a time, each group writing
+    # into the cache that was allocated once.  0: every prompt in one pass.
+    prefill_tokens: int = 0
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_width or self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
 
 
 # The one model the repo runs at a real size: 468M dense, 16 heads of 128.
@@ -137,10 +156,11 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
         scale = scale if scale is not None else (shape[-2] ** -0.5)
         return rng.normal(0, scale, size=shape).astype(np.float32)
 
+    Dq, Dkv = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
     params = {
         "emb": w(V, D, scale=0.02),
-        "wq": w(L, D, D), "wk": w(L, D, D), "wv": w(L, D, D),
-        "wo": w(L, D, D, scale=(D ** -0.5) / max(1, 2 * L) ** 0.5),
+        "wq": w(L, D, Dq), "wk": w(L, D, Dkv), "wv": w(L, D, Dkv),
+        "wo": w(L, Dq, D, scale=(Dq ** -0.5) / max(1, 2 * L) ** 0.5),
         "ln1": np.ones((L, D), np.float32),
         "ln2": np.ones((L, D), np.float32),
         "lnf": np.ones((D,), np.float32),
@@ -161,6 +181,10 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
     else:
         params["w1"] = w(L, D, F)
         params["w2"] = w(L, F, D, scale=(F ** -0.5) / max(1, 2 * L) ** 0.5)
+    if cfg.hybrid is not None:
+        from ompi_tpu.models import ssm
+
+        params.update(ssm.init_leaves(cfg, rng))
     if cfg.param_dtype not in (None, "float32"):
         # live params are stored in param_dtype; the optimizer's f32
         # master copy is created from them at init (one-time rounding)
@@ -199,6 +223,10 @@ def param_specs(P, cfg: Optional[TransformerConfig] = None, mesh=None):
     else:
         specs["w1"] = P(None, None, "tp")
         specs["w2"] = P(None, "tp", None)
+    if cfg is not None and cfg.hybrid is not None:
+        from ompi_tpu.models import ssm
+
+        specs.update({leaf: P() for leaf in ssm.leaf_names()})
     return specs
 
 
@@ -254,6 +282,10 @@ def layer_leaves(cfg: TransformerConfig) -> tuple:
             leaves.append("w3")
     if cfg.qk_norm:
         leaves += ["qn", "kn"]
+    if cfg.hybrid is not None:
+        from ompi_tpu.models import ssm
+
+        leaves += ssm.leaf_names()
     return tuple(leaves)
 
 
@@ -267,7 +299,7 @@ _ATTENTION_LAYOUT = {"ring": "ring", "ulysses": "ulysses", "flash": "ulysses",
                      "xla": "ulysses", "gathered": "gathered"}
 
 
-def _rope(x, positions, impl: str = "jnp"):
+def _rope(x, positions, impl: str = "jnp", theta=10_000):
     """Rotary embeddings with *global* positions (sp-offset aware).
     ``impl`` is the local attention's that reads the result
     (``parallel/attention.layout_impl``): for "flash" the pallas form where
@@ -277,7 +309,7 @@ def _rope(x, positions, impl: str = "jnp"):
 
     B, T, H, D = x.shape
     half = D // 2
-    freqs = 1.0 / (10_000 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = positions[:, None].astype(jnp.float32) * freqs[None, :]  # (T, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     if impl == "flash":
@@ -328,10 +360,12 @@ def _moe_ffn_tail(cfg, h, lp, comm, layer=None):
         return h + mo, aux
 
 
-def _dense_ffn_tail(h, lp, comm, cdt, eps: float = 1e-6):
+def _dense_ffn_tail(h, lp, comm, cdt, eps: float = 1e-6, gated=None):
     """Post-attention half of the dense layer: ln2 → gelu MLP →
     residual (shared by the training layer and the cached decode step,
-    models/decode.py — one source of truth for this math)."""
+    models/decode.py — one source of truth for this math).  ``gated``, a
+    pair of multipliers (m0, m1): down(silu(gate(x)·m0) * up(x))·m1 with
+    the up projection in a third leaf "w3"."""
     import jax
 
     from ompi_tpu.core.scopes import scope
@@ -339,6 +373,12 @@ def _dense_ffn_tail(h, lp, comm, cdt, eps: float = 1e-6):
 
     with scope("ffn"):
         x = _rmsnorm(h, lp["ln2"], eps)
+        if gated is not None:
+            y = (jax.nn.silu(column_parallel(x, lp["w1"].astype(cdt))
+                             * gated[0])
+                 * column_parallel(x, lp["w3"].astype(cdt)))
+            return h + row_parallel(y, lp["w2"].astype(cdt), comm,
+                                    axis="tp") * gated[1]
         y = jax.nn.gelu(column_parallel(x, lp["w1"].astype(cdt)))
         return h + row_parallel(y, lp["w2"].astype(cdt), comm, axis="tp")
 
@@ -351,8 +391,11 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     tokens: (B/dp, S/sp) int32.  Returns (h (B/dp, S/sp, D) compute-dtype,
     aux) — aux is the summed MoE load-balancing loss (0.0 for dense).
     With ``collect_kv`` returns (h, (aux, k, v)) where k/v are the
-    post-rope per-layer attention inputs stacked (L, B, T, H/tp, hd) —
-    the KV-cache prefill (models/decode.py).
+    post-rope per-layer attention inputs stacked (L, B, T, Hkv/tp, hd) —
+    the KV-cache prefill (models/decode.py); with a hybrid block
+    (h, (aux, k, v, conv, ssm)): every layer's mixer states after the last
+    position too, stacked alike, the second in the block's ``state_dtype``.
+    With that block h comes scaled by its ``lm_head_multiplier``.
     """
     import jax
     import jax.numpy as jnp
@@ -365,7 +408,12 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     cdt = jnp.dtype(cfg.compute_dtype)
     tp = int(comm.mesh.shape["tp"])
     sp = int(comm.mesh.shape["sp"])
-    h_local = cfg.n_heads // tp
+    hy = cfg.hybrid
+    if hy is not None:
+        from ompi_tpu.models import ssm
+
+        ssm.check_mesh(cfg, comm.mesh)
+    h_local, kv_local = cfg.n_heads // tp, cfg.kv_heads // tp
     hd = cfg.head_dim
     T = tokens.shape[1]
     sp_idx = lax.axis_index("sp")
@@ -379,33 +427,55 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
 
     with scope("embed"):
         h = params["emb"][tokens].astype(cdt)  # (b, t, D)
+        if hy is not None:
+            h = h * hy.embedding_multiplier
 
     def layer(h, lp):
         with scope("attn_proj"):
             x = _rmsnorm(h, lp["ln1"], cfg.norm_eps)
-            q = column_parallel(x, lp["wq"].astype(cdt))
-            k = column_parallel(x, lp["wk"].astype(cdt))
-            v = column_parallel(x, lp["wv"].astype(cdt))
+            xa = x if hy is None else x * hy.attention_in_multiplier
+            q = column_parallel(xa, lp["wq"].astype(cdt))
+            k = column_parallel(xa, lp["wk"].astype(cdt))
+            v = column_parallel(xa, lp["wv"].astype(cdt))
+            if hy is not None:
+                k = k * hy.key_multiplier
             if cfg.qk_norm:
                 q = _qk_norm(cfg, q, lp["qn"], comm)
                 k = _qk_norm(cfg, k, lp["kn"], comm)
             B, t = x.shape[0], x.shape[1]
-            q = _rope(q.reshape(B, t, h_local, hd), positions, impl)
-            k = _rope(k.reshape(B, t, h_local, hd), positions, impl)
-            v = v.reshape(B, t, h_local, hd)
+            q = _rope(q.reshape(B, t, h_local, hd), positions, impl,
+                      cfg.rope_theta)
+            k = _rope(k.reshape(B, t, kv_local, hd), positions, impl,
+                      cfg.rope_theta)
+            v = v.reshape(B, t, kv_local, hd)
+            k_all, v_all = k, v
+            if kv_local != h_local:     # each K/V head before its queries
+                k_all, v_all = (jnp.repeat(y, h_local // kv_local, axis=2)
+                                for y in (k, v))
         with scope("attention"):
-            o = attend(comm, q, k, v, axis="sp", impl=impl)
+            o = attend(comm, q, k_all, v_all, axis="sp", impl=impl)
         with scope("attn_proj"):
             o = o.reshape(B, t, h_local * hd)
-            h = h + row_parallel(o, lp["wo"].astype(cdt), comm, axis="tp")
+            a = row_parallel(o, lp["wo"].astype(cdt), comm, axis="tp")
+            if hy is None:
+                h = h + a
+        if hy is not None:
+            # both branches read the one normed input and share a residual
+            s, *states = ssm.mixer(cfg, lp, x)
+            with scope("attn_proj"):
+                h = h + a * hy.attention_out_multiplier + s
         if cfg.moe_experts:
             # MoE family: expert-parallel switch FFN over the "ep" axis
             # (tp ranks replicate the expert compute — activations are
             # identical across tp after the row_parallel psum)
             h, aux = _moe_ffn_tail(cfg, h, lp, comm)
         else:
-            h = _dense_ffn_tail(h, lp, comm, cdt, cfg.norm_eps)
+            h = _dense_ffn_tail(h, lp, comm, cdt, cfg.norm_eps,
+                                gated=hy and hy.mlp_multipliers)
             aux = jnp.zeros((), jnp.float32)
+        if collect_kv and hy is not None:
+            return h, (aux, k, v, states[0],
+                       states[1].astype(hy.state_dtype))
         if collect_kv:
             return h, (aux, k, v)
         return h, aux
@@ -427,9 +497,11 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     with scope("layers"):
         h, ys = lax.scan(layer_fn, h, layer_params)
     h = _rmsnorm(h, params["lnf"], cfg.norm_eps)
+    if hy is not None:
+        h = h * hy.lm_head_multiplier
     if collect_kv:
-        aux, ks, vs = ys
-        return h, (aux.sum(), ks, vs)
+        aux, *cached = ys
+        return h, (aux.sum(), *cached)
     return h, ys.sum()
 
 

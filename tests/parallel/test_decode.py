@@ -411,6 +411,29 @@ def test_compiled_step_for_the_chip_writes_the_cache_and_moves_nothing(
                        position * (job.prompt_len + job.max_new), position)
 
 
+def test_compiled_hybrid_step_for_the_chip_keeps_its_state_in_place(
+        chips, no_compile_cache):
+    """The state-space cell at its real sizes, for the described v5e: the
+    mixer's stacked state (6 layers x 192 sequences x 32 x 128 x 256) is loop
+    carry like K/V.  Of everything the generation loop runs, only the one
+    write of a layer's states may produce an array of the stack's size, and
+    no copy of that size may exist: the update reads the carried stack
+    through a fused slice and writes it back in place by layer index (as the
+    ``xs`` and ``ys`` of a scan it would be rebuilt and copied a step).  The
+    CPU's compiler does copy it, twice a layer, so this is asked here only."""
+    from benchmarks.lib import cells
+    from ompi_tpu.models import ssm
+
+    cell = cells.resolve("falcon-h1-34b.decode-128-64-b192")
+    job = cell.runner.build(cell.config, cell.traffic, chips[:cell.chips])
+    fn, args = job.programs()["decode_full"]
+    layer = math.prod(ssm.state_shapes(job.cfg, job.batch)[1])
+    writes, moves = cache_sized_operations(
+        fn.lower(*args).compile().as_text(), job.cfg.n_layers * layer, layer)
+    assert not moves, "\n".join(moves)
+    assert len(writes) == 1, writes
+
+
 def test_compiled_step_on_the_cpu_keeps_the_stacked_cache_in_place():
     """The CPU backend's program at a tiny shape, for where the chip's
     compiler is not installed.  Its dot wants another layout, so it does
