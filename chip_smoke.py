@@ -29,7 +29,10 @@ exits non-zero before compiling anything.  The phases are functions of a
 ``Size`` so that tests/test_chip_smoke.py can drive them tiny on the virtual
 CPU mesh, where the suite's conftest puts pallas in interpret mode.
 
-The last line of stdout is ``{"ok": true, "device": {...}}``.
+The line before the last is ``startup {...}``: where the host's time went,
+by span and by program (``ompi_tpu/core/scopes.startup()``, which also gives
+every phase's compile seconds and cache hits).  The last line of stdout is
+``{"ok": true, "device": {...}}``.
 """
 
 from __future__ import annotations
@@ -71,40 +74,34 @@ FULL = Size(cfg=FLAGSHIP, batch=FLAGSHIP_BATCH, steps=4, prompt=512,
 # helpers
 # ---------------------------------------------------------------------------
 
-class CompileMeter:
-    """JAX's own compile clock: how many programs went to the backend, the
-    seconds they spent there (compiling, or reading the persistent cache
-    instead), and the persistent cache's hits and misses.  Tracing and
-    lowering are python work that no cache saves; they are not counted."""
+def compile_totals() -> dict:
+    """JAX's own compile clock, from the program's record
+    (``ompi_tpu/core/scopes.py``): ``programs`` sent to the backend since the
+    first factory was called, ``backend_s`` there (compiling, or reading the
+    persistent cache instead), and that cache's hits and misses."""
+    from ompi_tpu.core import scopes
 
-    def __init__(self) -> None:
-        import jax
-
-        self.seconds = 0.0
-        self.programs = 0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, secs: float, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += secs
-            self.programs += 1
-
-    def _event(self, event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def snapshot(self) -> tuple[float, int, int]:
-        return self.seconds, self.hits, self.misses
+    return scopes.startup()["totals"]
 
 
-@functools.lru_cache(maxsize=1)
-def compile_meter() -> CompileMeter:
-    return CompileMeter()
+def startup_summary(slowest: int = 5) -> dict:
+    """The program's own account of the host side of this process
+    (``scopes.startup()``), with every program that is not the package's own
+    summed but for the ``slowest`` few."""
+    from ompi_tpu.core import scopes
+
+    out = scopes.startup()
+    others = out.pop("others")
+
+    def seconds(row):
+        return row["trace_s"] + row["lower_s"] + row["backend_s"]
+
+    by_cost = sorted(others, key=lambda name: -seconds(others[name]))
+    out["others"] = {"programs": len(others),
+                     "seconds": sum(map(seconds, others.values())),
+                     "slowest": {name: seconds(others[name])
+                                 for name in by_cost[:slowest]}}
+    return out
 
 
 def model_mesh(devices):
@@ -237,7 +234,7 @@ def phase_train(size: Size, devices, attention: str = "xla",
                 step, params, opt_state, batch)
             out["losses"].append(float(loss))
             out["step_s"].append(dt)
-            programs.append(compile_meter().programs)
+            programs.append(compile_totals()["programs"])
             batch = next(stream)
         _check(programs[-1] == programs[0],
                f"train[{attention}]: a step after the first compiled a "
@@ -490,19 +487,19 @@ def main() -> int:
               f"no CPU mode", file=sys.stderr)
         return 1
     cache = enable_compile_cache()
-    meter = compile_meter()
     print(f"device {device}; jax {jax.__version__}, jaxlib "
           f"{md.version('jaxlib')}, libtpu {md.version('libtpu')}, python "
           f"{sys.version.split()[0]}; compile cache {cache}", flush=True)
 
     def run(name, phase, *args, **kw):
-        c0, h0, m0 = meter.snapshot()
+        before = compile_totals()
         t0 = time.perf_counter()
         out = phase(FULL, devices, *args, **kw)
-        c1, h1, m1 = meter.snapshot()
+        since = {k: v - before[k] for k, v in compile_totals().items()}
         print(f"[{name}] wall {time.perf_counter() - t0:.1f}s, compile "
-              f"{c1 - c0:.1f}s (cache hits {h1 - h0}, misses {m1 - m0}) "
-              f"{json.dumps(out)}", flush=True)
+              f"{since['backend_s']:.1f}s (cache hits {since['cache_hits']}, "
+              f"misses {since['cache_misses']}) {json.dumps(out)}",
+              flush=True)
         return out
 
     train = run("train", phase_train)
@@ -543,6 +540,7 @@ def main() -> int:
     _check(all(n > 0 for n in compiled.values()),
            f"a pallas phase lowered no tpu_custom_call: {compiled}")
     print(f"compiled pallas kernels per program: {compiled}", flush=True)
+    print(f"startup {json.dumps(startup_summary())}", flush=True)
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 

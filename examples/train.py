@@ -15,6 +15,11 @@ import tempfile
 import numpy as np
 
 
+def _rounded(row: dict) -> dict:
+    return {k: round(v, 3) if isinstance(v, float) else v
+            for k, v in row.items()}
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=6)
@@ -58,6 +63,16 @@ def main() -> None:
                                     "step": np.int64(i + 1)})
             store.commit(0, nranks=1)
             print(f"checkpoint at step {i + 1} -> {store.snapshot_dir(0)}")
+
+    # where the host's time went before the first step, from the program's
+    # own record (core/scopes.py), and what the stream waited for
+    from ompi_tpu.core import scopes
+
+    started = scopes.startup()
+    row = started["programs"]["train_step"]
+    print(f"startup: spans {_rounded(started['spans'])}; train_step "
+          f"{_rounded(row)}; retraces {started['retraces']}; stream "
+          f"{_rounded(stream.stats())}")
 
     # resume: the (seed, step) contract reproduces the stream exactly
     resumed = data_mod.train_stream(src, mesh, batch, cfg.seq,

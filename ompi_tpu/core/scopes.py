@@ -1,28 +1,57 @@
-"""The names inside the device programs.
+"""The names of the device path: inside the programs, and on the host
+around them.
 
-Every boundary the performance records talk about is a ``jax.named_scope``
-from the one vocabulary below.  ``SCOPES`` is the only copy of it: the
-reader of a profile, ``benchmarks/lib/scopes.py``, keeps no list of its own
-and looks a name up in this tuple when it classifies an operation, so a name
-added here is a scope there, a key of its table and a line of a traced run's
-``breakdown.device_scopes``, with no edit under ``benchmarks/``; a metric
-that reads it is a data file that names its keys (``PERF.md`` section 3 says
-which metric reads which name).  A profile of any program built on this
-package is thus read by layer instead of by ``fusion.461``.  A scope is HLO
-metadata (``op_name``): it changes no computation and costs nothing when
-the program runs.  JAX's own name stack already tells forward (``jvp``),
-backward (``transpose``) and recomputation (``rematted_computation``)
-apart, so none of them is a scope.
+**The device half.**  Every boundary the performance records talk about is a
+``jax.named_scope`` from the one vocabulary ``SCOPES``.  The tuple is the
+only copy of it: the reader of a profile, ``benchmarks/lib/scopes.py``,
+keeps no list of its own and reads this tuple when it classifies an
+operation, so a name added here is a scope there, a key of its table and a
+line of a traced run's ``breakdown.device_scopes``, with no edit under
+``benchmarks/``; a metric that reads it is a data file that names its keys
+(``PERF.md`` section 3 says which metric reads which name).  A profile of
+any program built on this package is thus read by layer instead of by
+``fusion.461``.  A scope is HLO metadata (``op_name``): it changes no
+computation and costs nothing when the program runs.  JAX's own name stack
+already tells forward (``jvp``), backward (``transpose``) and recomputation
+(``rematted_computation``) apart, so none of them is a scope.
 
 One caution: JAX's persistent compilation cache leaves metadata out of its
 key.  A program that differs from a cached one only in where its scopes sit
 is served the cached executable with the old names; use a fresh
 ``JAX_COMPILATION_CACHE_DIR`` after moving a scope.
+
+**The host half.**  What the host does before a program's first step
+(importing, building, tracing, lowering, compiling or reading the
+compilation cache) and to feed it after is a span from the second tuple,
+``HOST_SPANS``, opened by ``host()`` where the work happens: the factories
+(``make_train_step``, ``make_train_loop``, ``make_decoder``,
+``train_stream``), the package's import of pallas and of jax, the input
+stream's worker.  A span is a ``jax.profiler.TraceAnnotation`` named
+``ompi_tpu:<name>``, so a profile shows it on the device trace's clock
+(``benchmarks/lib/clock.py`` names idle gaps after it), and one record in
+memory on ``time.perf_counter``, so that a process can say where its
+set-up went with no profiler: ``startup()``.  The three ``compile.*`` names
+are not opened here: they are JAX's own timing of every program's trace,
+lowering and backend compile (``jax.monitoring``), recorded under the
+program's name by listeners that the first ``host()`` registers, once, for
+the life of the process.  Always on, like the stream's ``stats()``: no
+file, no exporter, no option.  The flight recorder of ``mpi/trace.py``
+belongs to the host MPI plane and carries none of this
+(``OBSERVABILITY.md``, "The device path").
 """
 
 from __future__ import annotations
 
-__all__ = ["SCOPES", "COLL", "scope", "coll"]
+import collections
+import itertools
+import sys
+import threading
+import time
+from typing import NamedTuple, Optional
+
+__all__ = ["SCOPES", "COLL", "scope", "coll",
+           "HOST_SPANS", "Span", "host", "program", "records", "startup",
+           "reset"]
 
 # plain lower-case words; a dot says which scope a name belongs under
 SCOPES = (
@@ -70,3 +99,340 @@ def coll(method: str, axes):
 
     names = (axes,) if isinstance(axes, str) else tuple(axes)
     return jax.named_scope(f"{COLL}.{method}.{'-'.join(names)}")
+
+
+# ---------------------------------------------------------------------------
+# the host half
+# ---------------------------------------------------------------------------
+
+# ``<what>.<which>``; ``PERF.md`` section 3 says which metric reads which
+HOST_SPANS = (
+    "import.jax",       # the process's first ``import jax``, made by host()
+    "import.pallas",    # jax.experimental.pallas[.tpu] (``ops/_pallas.py``)
+    "import.optax",     # the train step's optimizer library
+    "build.train_step", "build.train_loop", "build.decoder", "build.stream",
+                        # the bodies of the four factories
+    "compile.trace",    # JAX's own clock, by program: python to a jaxpr
+    "compile.lower",    # ... the jaxpr to an MLIR module
+    "compile.backend",  # ... the backend: a compile, or a cache read
+    "data.produce",     # the input stream's worker: one host batch made
+                        # and put on the devices
+)
+PREFIX = "ompi_tpu:"    # of the annotation's name in a profile
+# The record keeps a process's first LIMIT spans and counts the rest: what
+# it is read for is the start, and a long job's stream makes a span a batch.
+LIMIT = 16384
+
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+}
+# a stage's key in a program's row of startup()
+_STAGE_KEYS = {"compile.trace": "trace_s", "compile.lower": "lower_s",
+               "compile.backend": "backend_s"}
+_CACHE = {"/jax/compilation_cache/cache_hits": "hit",
+          "/jax/compilation_cache/cache_misses": "miss"}
+_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+class Span(NamedTuple):
+    """One record.  ``start`` and ``end`` are on ``time.perf_counter``;
+    ``parent`` is the ``id`` of the innermost span that was open on the same
+    thread when this one began, or None."""
+    name: str                   # of HOST_SPANS
+    program: Optional[str]      # the program built, traced, lowered, compiled
+    start: float
+    end: float
+    parent: Optional[int]
+    id: int
+    cache: Optional[str] = None     # compile.backend: "hit" | "miss"
+
+
+class _Open:
+    """A span that has begun on a thread and not ended: what a later span
+    of that thread names as its parent."""
+    __slots__ = ("id", "name", "program", "cache")
+
+    def __init__(self, name: str, program: Optional[str]) -> None:
+        self.id, self.name, self.program = next(_ids), name, program
+        self.cache = None       # compile.backend: what the cache answered
+
+
+class Program:
+    """One program a factory builds.  The factory calls ``traced()`` as the
+    first statement of the jitted function: python that runs when JAX traces
+    the function and never when the program runs."""
+    __slots__ = ("name", "traces")
+
+    def __init__(self, name: str) -> None:
+        self.name, self.traces = name, 0
+
+    def traced(self) -> None:
+        self.traces += 1
+
+
+def _new_totals() -> dict:
+    return {"programs": 0, "backend_s": 0.0, "cache_hits": 0,
+            "cache_misses": 0, "cache_retrieval_s": 0.0}
+
+
+_lock = threading.Lock()        # the record, the totals, the registration
+_records: list = []
+_programs: list = []
+_totals = _new_totals()
+_dropped = 0
+_ids = itertools.count()
+_open = threading.local()       # .stack: the spans open on this thread
+_listening = False
+_offset = 0.0                   # perf_counter less time.time, at registration
+
+
+def _stack() -> list:
+    try:
+        return _open.stack
+    except AttributeError:
+        _open.stack = []
+        return _open.stack
+
+
+def _append(span: Span) -> None:
+    global _dropped
+    with _lock:
+        if len(_records) < LIMIT:
+            _records.append(span)
+        else:
+            _dropped += 1
+
+
+def _close(stack: list, entry: _Open) -> Optional[int]:
+    """Take ``entry`` (and anything left open above it) off ``stack``; the
+    id of its parent."""
+    for i in range(len(stack) - 1, -1, -1):
+        if stack[i] is entry:
+            del stack[i:]
+            break
+    return stack[-1].id if stack else None
+
+
+def _jax():
+    """``jax``, with the listeners registered; imported under an
+    ``import.jax`` record where this is the process's first import of it."""
+    first = "jax" not in sys.modules
+    start = time.perf_counter()
+    import jax
+
+    if first:
+        stack = _stack()
+        _append(Span("import.jax", None, start, time.perf_counter(),
+                     stack[-1].id if stack else None, next(_ids)))
+    if not _listening:
+        _listen(jax)
+    return jax
+
+
+def _listen(jax) -> None:
+    global _listening, _offset
+    with _lock:
+        if _listening:
+            return
+        _listening = True
+        # JAX stamps its stages on time.time(); the record is on perf_counter
+        _offset = time.perf_counter() - time.time()
+    monitoring = jax.monitoring
+    monitoring.register_scalar_listener(_on_begin)
+    monitoring.register_event_time_span_listener(_on_span)
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def _program_of(fun_name: str) -> str:
+    """``jit(train_step)`` (a lowering, a compile) and ``train_step`` (a
+    trace) are one program."""
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return fun_name[4:-1]
+    return fun_name
+
+
+def _on_begin(event: str, _value, fun_name: str = "", **_kw) -> None:
+    """A stage begins (JAX records its start as a scalar): it is open on
+    this thread until ``_on_span`` closes it, so what happens inside it, an
+    inner trace or a lazy import, is its child."""
+    name = _STAGES.get(event)
+    if name is not None:
+        _stack().append(_Open(name, _program_of(fun_name)))
+
+
+def _on_span(event: str, start: float, end: float, fun_name: str = "",
+             **_kw) -> None:
+    name = _STAGES.get(event)
+    if name is None:
+        return
+    prog, stack = _program_of(fun_name), _stack()
+    entry = next((e for e in reversed(stack)
+                  if e.name == name and e.program == prog), None)
+    if entry is None:       # it began before the listeners were registered
+        entry = _Open(name, prog)
+    parent = _close(stack, entry)
+    _append(Span(name, prog, start + _offset, end + _offset, parent,
+                 entry.id, entry.cache))
+    if name == "compile.backend":
+        with _lock:
+            _totals["programs"] += 1
+            _totals["backend_s"] += end - start
+
+
+def _on_event(event: str, **_kw) -> None:
+    found = _CACHE.get(event)
+    if found is None:
+        return
+    with _lock:
+        _totals["cache_hits" if found == "hit" else "cache_misses"] += 1
+    stack = _stack()    # the cache is asked inside the backend's stage
+    if stack and stack[-1].name == "compile.backend":
+        stack[-1].cache = found
+
+
+def _on_duration(event: str, seconds: float, **_kw) -> None:
+    if event == _RETRIEVAL:
+        with _lock:
+            _totals["cache_retrieval_s"] += seconds
+
+
+class host:
+    """``with host("build.decoder", program="decode"): ...`` around host
+    work: an ``ompi_tpu:<name>`` annotation in a profile, and one record."""
+    __slots__ = ("name", "program", "entry", "annotation", "start")
+
+    def __init__(self, name: str, program: Optional[str] = None) -> None:
+        if name not in HOST_SPANS:
+            raise ValueError(f"{name!r} is not in the host span vocabulary "
+                             f"{HOST_SPANS}")
+        self.name, self.program = name, program
+
+    def __enter__(self) -> "host":
+        jax = _jax()
+        self.entry = _Open(self.name, self.program)
+        self.annotation = jax.profiler.TraceAnnotation(PREFIX + self.name)
+        _stack().append(self.entry)
+        self.annotation.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end = time.perf_counter()
+        self.annotation.__exit__(*exc)
+        _append(Span(self.name, self.program, self.start, end,
+                     _close(_stack(), self.entry), self.entry.id))
+
+
+def program(name: str) -> Program:
+    """Called by the factory that builds the program ``name`` (the name of
+    its jitted function): the record then knows ``name`` for one of the
+    package's own programs, and the handle counts that object's traces."""
+    handle = Program(name)
+    with _lock:
+        _programs.append(handle)
+    return handle
+
+
+def records() -> list:
+    """The spans recorded so far, in the order they ended."""
+    with _lock:
+        return list(_records)
+
+
+def reset() -> None:
+    """Forget the record, the registered programs and the totals (for
+    tests; the listeners stay)."""
+    global _dropped, _totals
+    with _lock:
+        del _records[:]
+        del _programs[:]
+        _totals = _new_totals()
+        _dropped = 0
+
+
+def _row() -> dict:
+    return {"trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0, "cache": None,
+            "traces": 0, "compiles": 0}
+
+
+def startup(spans: Optional[list] = None) -> dict:
+    """Where the host's time went, from the record (or from ``spans``).
+
+    Seconds are **self time by containment**: a span's duration less its
+    children's, because spans nest (``matmul`` is traced inside
+    ``train_step``'s trace, a lazy ``import.pallas`` falls inside the trace
+    of ``decode``) and a plain sum would count those seconds twice.
+
+    - ``spans``: seconds by span name.
+    - ``programs``: the package's own programs (those a factory registered),
+      a row each: ``trace_s``, ``lower_s``, ``backend_s``; ``cache``, "miss"
+      where a compile of it missed the persistent cache, else "hit" where
+      one was read from it, else None; ``traces``, how often the python
+      bodies of its objects ran (``Program.traced``); ``compiles``, how
+      often it went to the backend.  A stage inside another stage counts
+      for the outermost program of the package's own around it, else for
+      the outermost: the helpers traced inside ``train_step`` are
+      ``train_step``'s seconds.
+    - ``others``: the same rows for every other program of the process;
+      their ``traces`` are JAX's trace events, and JAX also records one, of
+      microseconds, for a call that misses the jitted function's fast path
+      and finds its jaxpr cached.
+    - ``retraces``: traces of an own program beyond the first of each
+      distinct program object.
+    - ``totals``: the process's counts since the listeners were registered:
+      ``programs`` sent to the backend, ``backend_s`` there, the persistent
+      cache's ``cache_hits``, ``cache_misses`` and ``cache_retrieval_s``.
+    - ``records``, ``dropped``: spans kept, and spans beyond ``LIMIT``.
+    """
+    with _lock:
+        traced = collections.Counter()
+        for p in _programs:
+            traced[p.name] += p.traces
+        retraces = sum(max(0, p.traces - 1) for p in _programs)
+        totals, dropped = dict(_totals), _dropped
+    own = set(traced)
+    if spans is None:
+        spans = records()
+    by_id = {s.id: s for s in spans}
+    inside = collections.defaultdict(float)     # id -> its children's seconds
+    for s in spans:
+        if s.parent in by_id:
+            inside[s.parent] += s.end - s.start
+
+    def owner(span: Span) -> str:
+        found, outermost_own = span.program, None
+        while span is not None:
+            if span.name in _STAGE_KEYS:
+                found = span.program
+                if found in own:
+                    outermost_own = found
+            span = by_id.get(span.parent)
+        return outermost_own or found
+
+    by_name = collections.defaultdict(float)
+    rows = {"programs": collections.defaultdict(_row),
+            "others": collections.defaultdict(_row)}
+    for s in spans:
+        self_s = max(0.0, s.end - s.start - inside[s.id])
+        by_name[s.name] += self_s
+        key = _STAGE_KEYS.get(s.name)
+        if key is None:
+            continue
+        name = owner(s)
+        row = rows["programs" if name in own else "others"][name]
+        row[key] += self_s
+        if s.name == "compile.trace":
+            row["traces"] += s.program == name  # an own row's: see below
+        elif s.name == "compile.backend":
+            row["compiles"] += 1
+            if s.cache == "miss" or row["cache"] is None:
+                row["cache"] = s.cache
+    for name, row in rows["programs"].items():
+        row["traces"] = traced[name]    # counted where the python body ran
+    return {"spans": dict(by_name), "programs": dict(rows["programs"]),
+            "others": dict(rows["others"]), "retraces": retraces,
+            "totals": totals, "records": len(spans), "dropped": dropped}
+

@@ -24,6 +24,7 @@ from __future__ import annotations
 import os
 import queue
 import threading
+import time
 from typing import Iterator, Optional
 
 import numpy as np
@@ -90,12 +91,16 @@ def prefetch(it: Iterator[np.ndarray], mesh=None, spec=None,
     device arrays in order.
 
     The iterator counts what it hands out: ``stats()`` returns
-    ``{"batches": n, "starved": n}``, ``starved`` being the ``next`` calls
-    that found the queue empty (the consumer then waits for the worker: the
-    host sets the pace).  In a profile each batch the worker makes is an
-    ``ompi_tpu:data.produce`` span.
+    ``{"batches": n, "starved": n, "wait_s": s}``, ``starved`` being the
+    ``next`` calls that found the queue empty (the consumer then waits for
+    the worker: the host sets the pace) and ``wait_s`` the seconds all of
+    them spent taking a batch from the queue.  Each batch the worker makes
+    is a ``data.produce`` span of ``core/scopes.host``: in a profile,
+    ``ompi_tpu:data.produce``.
     """
     import jax
+
+    from ompi_tpu.core.scopes import host
 
     if mesh is not None:
         from jax.sharding import NamedSharding
@@ -125,7 +130,7 @@ def prefetch(it: Iterator[np.ndarray], mesh=None, spec=None,
         try:
             source = iter(it)
             while True:
-                with jax.profiler.TraceAnnotation("ompi_tpu:data.produce"):
+                with host("data.produce"):
                     try:
                         host_batch = next(source)
                     except StopIteration:
@@ -152,6 +157,7 @@ def prefetch(it: Iterator[np.ndarray], mesh=None, spec=None,
 
         def __init__(self):
             self.batches = self.starved = 0     # consumer thread only
+            self.wait_s = 0.0
 
         def __iter__(self):
             return self
@@ -160,7 +166,9 @@ def prefetch(it: Iterator[np.ndarray], mesh=None, spec=None,
             if closed.is_set():
                 raise StopIteration
             self.starved += q.empty()
+            start = time.perf_counter()
             item = q.get()
+            self.wait_s += time.perf_counter() - start
             if item is _stop:
                 self.close()
                 raise StopIteration
@@ -171,7 +179,8 @@ def prefetch(it: Iterator[np.ndarray], mesh=None, spec=None,
             return item
 
         def stats(self) -> dict:
-            return {"batches": self.batches, "starved": self.starved}
+            return {"batches": self.batches, "starved": self.starved,
+                    "wait_s": self.wait_s}
 
         def close(self, _empty=queue.Empty) -> None:
             # release the worker and drop any buffered device batches.
@@ -203,8 +212,11 @@ def train_stream(source: TokenSource, mesh, batch: int, seq: int,
                  spec: Optional[object] = None) -> Iterator:
     """The one-call composition: deterministic batches → dp-sharded
     device prefetch (resume by passing the checkpointed step)."""
-    from jax.sharding import PartitionSpec as P
+    from ompi_tpu.core.scopes import host
 
-    return prefetch(batches(source, batch, seq, start_step), mesh,
-                    spec if spec is not None else P("dp", None),
-                    depth=depth)
+    with host("build.stream"):
+        from jax.sharding import PartitionSpec as P
+
+        return prefetch(batches(source, batch, seq, start_step), mesh,
+                        spec if spec is not None else P("dp", None),
+                        depth=depth)

@@ -195,11 +195,22 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
     independent noise — into the key; tp ranks share the key and hence
     agree on every sampled token (their logits are identical).
     """
+    from ompi_tpu.core import scopes
+
+    with scopes.host("build.decoder", program="decode"):
+        return _build_decoder(cfg, mesh, max_new, temperature, top_k,
+                              keep_logits)
+
+
+def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
+                   temperature: float, top_k: int, keep_logits: int):
+    """The body of :func:`make_decoder`."""
     import jax
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
+    from ompi_tpu.core import scopes
     from ompi_tpu.core.scopes import scope
     from ompi_tpu.models import transformer as tfm
     from ompi_tpu.mpi.device_comm import DeviceCommunicator
@@ -352,9 +363,13 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
         in_specs=(param_specs(P, cfg, mesh), P("dp", None), P()),
         out_specs=(P("dp", None), P()) if keep_logits else P("dp", None),
         check_vma=False)
-    # the function's name is the program's name in a profile
+    record = scopes.program("decode")
+
+    # the function's name is the program's name in a profile and in the
+    # host's record (``scopes.startup()``)
     @jax.jit
     def decode(params, prompt, seed):
+        record.traced()
         return mapped(params, prompt, seed)
 
     if temperature:

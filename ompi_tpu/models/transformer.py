@@ -975,11 +975,12 @@ def _make_step_body(cfg: TransformerConfig, mesh, lr: float):
     (params, opt_state, loss) — the single definition both the one-step
     and the scanned-loop entry points compile."""
     import jax
-    import optax
-
     import jax.numpy as jnp
 
-    from ompi_tpu.core.scopes import scope
+    from ompi_tpu.core.scopes import host, scope
+
+    with host("import.optax"):      # most of a second, once a process
+        import optax
 
     opt = optax.adamw(lr, b1=0.9, b2=0.95, weight_decay=0.01,
                       mu_dtype=cfg.adam_mu_dtype)
@@ -1106,19 +1107,25 @@ def make_train_step(cfg: TransformerConfig, mesh, lr: float = 3e-4):
     ``_OVERLAP_OPTIONS``: its all-reduces are asynchronous and run under
     independent matmuls of the same computation.
     """
-    import jax
+    from ompi_tpu.core import scopes
 
-    body, opt = _make_step_body(cfg, mesh, lr)
+    with scopes.host("build.train_step", program="train_step"):
+        import jax
 
-    # params/opt_state are donated: the updated trees reuse their HBM
-    # in place of a second full copy (≈1.6 GiB at 133M params with Adam).
-    # The function's name is the program's name in a profile.
-    @functools.partial(jax.jit, donate_argnums=(0, 1),
-                       compiler_options=_compiler_options(mesh))
-    def train_step(params, opt_state, tokens):
-        return body(params, opt_state, tokens)
+        body, opt = _make_step_body(cfg, mesh, lr)
+        record = scopes.program("train_step")
 
-    return train_step, _init_on_mesh(cfg, mesh, opt.init)
+        # params/opt_state are donated: the updated trees reuse their HBM
+        # in place of a second full copy (≈1.6 GiB at 133M params with Adam).
+        # The function's name is the program's name in a profile and in
+        # the host's record (``scopes.startup()``).
+        @functools.partial(jax.jit, donate_argnums=(0, 1),
+                           compiler_options=_compiler_options(mesh))
+        def train_step(params, opt_state, tokens):
+            record.traced()
+            return body(params, opt_state, tokens)
+
+        return train_step, _init_on_mesh(cfg, mesh, opt.init)
 
 
 def make_train_loop(cfg: TransformerConfig, mesh, lr: float = 3e-4,
@@ -1130,20 +1137,26 @@ def make_train_loop(cfg: TransformerConfig, mesh, lr: float = 3e-4,
     One dispatch per K steps keeps the chip busy back-to-back: the host's
     per-call dispatch cost is paid once per K steps.
     """
-    import jax
-    from jax import lax
+    from ompi_tpu.core import scopes
 
-    body, opt = _make_step_body(cfg, mesh, lr)
+    with scopes.host("build.train_loop", program="train_loop"):
+        import jax
+        from jax import lax
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1),
-                       compiler_options=_compiler_options(mesh))
-    def train_loop(params, opt_state, tokens):
-        def scan_body(carry, _):
-            p, s, loss = body(*carry, tokens)
-            return (p, s), loss
+        body, opt = _make_step_body(cfg, mesh, lr)
+        record = scopes.program("train_loop")
 
-        (params, opt_state), losses = lax.scan(
-            scan_body, (params, opt_state), None, length=steps)
-        return params, opt_state, losses
+        @functools.partial(jax.jit, donate_argnums=(0, 1),
+                           compiler_options=_compiler_options(mesh))
+        def train_loop(params, opt_state, tokens):
+            record.traced()
 
-    return train_loop, _init_on_mesh(cfg, mesh, opt.init)
+            def scan_body(carry, _):
+                p, s, loss = body(*carry, tokens)
+                return (p, s), loss
+
+            (params, opt_state), losses = lax.scan(
+                scan_body, (params, opt_state), None, length=steps)
+            return params, opt_state, losses
+
+        return train_loop, _init_on_mesh(cfg, mesh, opt.init)

@@ -165,7 +165,7 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     the f32 product) with float32 accumulation via preferred_element_type.
     """
     from jax import lax
-    from jax.experimental import pallas as pl
+    from ompi_tpu.ops._pallas import pl
 
     iq = pl.program_id(2)
     q = q_ref[0]                                             # (bq, D)
@@ -221,7 +221,7 @@ def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, g_ref, lse_ref,
     """One (batch, head, q-block) cell: dq = scale · Σ_j ds_j · k_j with
     ds = p · (dO·vᵀ − dm), p rebuilt from the saved lse."""
     from jax import lax
-    from jax.experimental import pallas as pl
+    from ompi_tpu.ops._pallas import pl
 
     iq = pl.program_id(2)
     q = q_ref[0]                                             # (bq, D)
@@ -269,7 +269,7 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, g_ref,
     queries across) so that lse and dm broadcast along lanes and no matmul
     transposes its left operand: dv = Σ_i pᵀ·dO, dk = scale · Σ_i dsᵀ·q."""
     from jax import lax
-    from jax.experimental import pallas as pl
+    from ompi_tpu.ops._pallas import pl
 
     jk = pl.program_id(2)
     k_blk = k_ref[0]                                         # (bk, D)
@@ -319,8 +319,8 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, g_ref,
 def _specs(d: int):
     """BlockSpecs over (B', T, H'·D) operands and (B', H', 1, T) rows for a
     grid of (batch, head, block): one block of a sequence, a whole one."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    from ompi_tpu.ops._pallas import pl
+    from ompi_tpu.ops._pallas import pltpu
 
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
@@ -340,7 +340,7 @@ def _specs(d: int):
 
 
 def _params():
-    from jax.experimental.pallas import tpu as pltpu
+    from ompi_tpu.ops._pallas import pltpu
 
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel"),
@@ -354,7 +354,7 @@ def _flash_fwd_raw(q3, k3, v3, qoff, koff, heads: int, scale: float,
                    causal: bool, blocks: tuple[int, int]):
     """(B', Tq, H'·D) × (B', Tk, H'·D) → (out like q3, lse (B', H', 1, Tq)
     float32)."""
-    from jax.experimental import pallas as pl
+    from ompi_tpu.ops._pallas import pl
 
     b, t_q, hd = q3.shape
     t_k, d = k3.shape[1], hd // heads
@@ -376,7 +376,7 @@ def _flash_fwd_raw(q3, k3, v3, qoff, koff, heads: int, scale: float,
 def _flash_bwd_raw(q3, k3, v3, g3, lse4, dm4, qoff, koff, heads: int,
                    scale: float, causal: bool, blocks: tuple[int, int]):
     """(B', ·, H'·D) operands, (B', H', 1, Tq) rows → (dq3, dk3, dv3)."""
-    from jax.experimental import pallas as pl
+    from ompi_tpu.ops._pallas import pl
 
     b, t_q, hd = q3.shape
     t_k, d = k3.shape[1], hd // heads

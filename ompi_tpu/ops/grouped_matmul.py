@@ -68,7 +68,7 @@ def _block(dim: int, cap: int) -> int:
 
 
 def _kernel(tile_group, tiles_used, lhs, rhs, out, acc):
-    from jax.experimental import pallas as pl
+    from ompi_tpu.ops._pallas import pl
 
     del tile_group          # read by the index map of ``rhs``
     i, k = pl.program_id(0), pl.program_id(2)
@@ -93,8 +93,8 @@ def _kernel(tile_group, tiles_used, lhs, rhs, out, acc):
 
 
 def _forward(rows, w, tile_group, tiles_used):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    from ompi_tpu.ops._pallas import pl
+    from ompi_tpu.ops._pallas import pltpu
 
     n_tiles = tile_group.shape[0]
     m, K = rows.shape

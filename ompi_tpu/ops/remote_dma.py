@@ -40,8 +40,8 @@ __all__ = ["window_put", "window_get", "fetch_bcast"]
 
 
 def _pl():
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    from ompi_tpu.ops._pallas import pl
+    from ompi_tpu.ops._pallas import pltpu
 
     return pl, pltpu
 

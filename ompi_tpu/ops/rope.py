@@ -53,7 +53,7 @@ def rope_tiles(t: int, heads: int, head_dim: int, dtype) -> bool:
 
 
 def _kernel(x_ref, cos_ref, sin_ref, o_ref, *, head_dim: int):
-    from jax.experimental.pallas import tpu as pltpu
+    from ompi_tpu.ops._pallas import pltpu
 
     cos, sin = cos_ref[...], sin_ref[...]                   # (rows, D) f32
     for h in range(x_ref.shape[-1] // head_dim):
@@ -67,8 +67,8 @@ def _kernel(x_ref, cos_ref, sin_ref, o_ref, *, head_dim: int):
 # train step at one shape, traced once a process and lowered once a program
 @functools.partial(jax.jit, static_argnums=3)
 def _call(x, cos, sin, head_dim: int):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    from ompi_tpu.ops._pallas import pl
+    from ompi_tpu.ops._pallas import pltpu
 
     b, t, width = x.shape
     rows = _rows(t, width, x.dtype.itemsize)

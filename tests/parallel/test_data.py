@@ -156,7 +156,7 @@ def test_prefetch_counts_batches_and_starved_takes():
             yield np.full((2, 4), i, np.int32)
 
     stream = data_mod.prefetch(slow_then_fast(), depth=4)
-    assert stream.stats() == {"batches": 0, "starved": 0}
+    assert stream.stats() == {"batches": 0, "starved": 0, "wait_s": 0.0}
     deadline = time.time() + 5.0
     while stream.stats()["batches"] == 0 and time.time() < deadline:
         next(stream)
@@ -166,7 +166,64 @@ def test_prefetch_counts_batches_and_starved_takes():
     assert starved >= 1 and stream.stats()["batches"] == 2
     time.sleep(0.5)                     # the worker fills the queue
     next(stream)
-    assert stream.stats() == {"batches": 3, "starved": starved}
+    counts = {k: v for k, v in stream.stats().items() if k != "wait_s"}
+    assert counts == {"batches": 3, "starved": starved}
     next(stream)
     stream.close()
     assert stream.stats()["batches"] == 4
+
+
+def test_prefetch_counts_the_seconds_it_waited_for_a_batch():
+    """``stats()["wait_s"]``: the seconds ``next`` spent taking from the
+    queue.  It grows by the worker's delay when the source is slower than
+    the consumer and stays near 0 when batches are waiting."""
+    import time
+
+    def slow(n, delay):
+        for i in range(n):
+            time.sleep(delay)
+            yield np.full((2, 4), i, np.int32)
+
+    stream = data_mod.prefetch(slow(4, 0.1), depth=2)
+    for _ in range(4):
+        next(stream)
+    starved = stream.stats()
+    stream.close()
+    assert starved["batches"] == 4 and starved["starved"] >= 3
+    assert 0.25 < starved["wait_s"] < 5.0
+
+    stream = data_mod.prefetch(slow(6, 0.0), depth=6)
+    deadline = time.time() + 10.0
+    time.sleep(0.3)                     # the worker fills the queue
+    for _ in range(6):
+        next(stream)
+        assert time.time() < deadline
+    fed = stream.stats()
+    stream.close()
+    assert fed["batches"] == 6 and fed["wait_s"] < 0.05
+
+
+def test_stream_and_its_worker_are_host_spans():
+    """``train_stream`` is a ``build.stream`` span and each batch its worker
+    makes a ``data.produce`` span of ``core/scopes.host``."""
+    import time
+
+    from ompi_tpu.core import scopes
+
+    scopes.reset()
+    mesh = make_mesh({"dp": 4, "sp": 1, "tp": 2})
+    src = data_mod.ArraySource(np.arange(1000, dtype=np.int32), seed=1)
+    stream = data_mod.train_stream(src, mesh, batch=8, seq=4)
+    for _ in range(3):
+        next(stream)
+    stream.close()
+    deadline = time.time() + 5.0
+    names = [s.name for s in scopes.records()]
+    while names.count("data.produce") < 3 and time.time() < deadline:
+        time.sleep(0.01)
+        names = [s.name for s in scopes.records()]
+    assert names.count("build.stream") == 1
+    assert names.count("data.produce") >= 3
+    produced = [s for s in scopes.records() if s.name == "data.produce"]
+    assert all(s.parent is None for s in produced)  # the worker's thread
+    scopes.reset()
