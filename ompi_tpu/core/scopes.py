@@ -80,7 +80,8 @@ SCOPES = (
 
 # ``coll.<method>.<axes>``: one collective call site, named by the
 # DeviceCommunicator method (or its lax equivalent's method) and the mesh
-# axes it runs over, joined by "-"
+# axes it runs over, joined by "-"; ``allreduce_rows`` is the all-reduce
+# that completes a table split by rows (``models/transformer._ROWS_SITE``)
 COLL = "coll"
 
 

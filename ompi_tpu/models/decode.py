@@ -40,10 +40,15 @@ K/V and final states into it, so the pass's temporaries are a group's
 and not the batch's.
 
 Sharding: batch over dp, heads over tp (the cache is
-head-sharded exactly like the weights); greedy argmax over the full
-vocab.  Sequence parallelism is a training-time layout — decode
-requires sp == 1.  MoE configs route each generated token through the
-same layer as training and prefill (``_moe_ffn_tail``).  The top-1 switch
+head-sharded exactly like the weights), the table's rows over tp where
+``param_specs`` splits them: a rank looks up the tokens whose rows it
+holds and a psum over tp completes them (``transformer._lookup``), and it
+makes the logits of its own rows, which are gathered over tp
+(``transformer._whole_vocab``), so greedy argmax, sampling and
+``keep_logits`` see the full vocab.  Sequence parallelism is a
+training-time layout — decode requires sp == 1.  MoE configs route each
+generated token through the same layer as training and prefill
+(``_moe_ffn_tail``).  The top-1 switch
 (``moe_top_k == 0``) computes its capacity per single-token step (B
 tokens), so under a binding capacity the drop pattern can differ from a
 full-sequence forward — cached and full paths agree exactly whenever
@@ -188,6 +193,14 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
     sequences was picked from, the prefill's logits for the first and the
     cached step's after.  Needs dp == 1.
 
+    The embedding and the head are read as :func:`transformer.param_specs`
+    places them: whole on every device, or, where ``tp`` is above 1 and
+    divides the vocabulary, rank r's rows ``[r·V/tp, (r+1)·V/tp)``.  Of
+    split rows the prefill's and every step's lookup is completed by one
+    psum over ``tp`` and their logits, made against the rank's rows, are
+    gathered over ``tp`` before a token is picked, so every rank picks
+    from the whole vocabulary and ``keep_logits`` hands it back whole.
+
     ``temperature > 0`` switches to sampling (optionally truncated to
     the ``top_k`` highest logits); the returned callable then takes a
     third argument ``seed`` (int32 scalar).  Each step folds the
@@ -302,8 +315,8 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
                 last, states = h[:, -1, :], ()
             else:
                 last, kc, vc, *states = prefill_in_groups(params, prompt)
-            logits = jnp.einsum("bd,vd->bv", last, head,
-                                preferred_element_type=jnp.float32)
+            logits = tfm._whole_vocab(cfg, jnp.einsum(
+                "bd,vd->bv", last, head, preferred_element_type=jnp.float32))
             tok0 = pick(logits, jnp.int32(Tp - 1), seed)          # (B,)
 
         layer_params = {k: params[k] for k in layer_leaves(cfg)}
@@ -313,7 +326,7 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
         def gen(carry, _):
             kc, vc, *states, tok, pos = carry
             with scope("embed"):
-                h = params["emb"][tok].astype(cdt)[:, None, :]  # (B, 1, D)
+                h = tfm._lookup(cfg, params["emb"], tok)[:, None, :]
                 if hy is not None:
                     h = h * hy.embedding_multiplier
 
@@ -333,8 +346,9 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
                 h = _rmsnorm(h, params["lnf"], cfg.norm_eps)
                 if hy is not None:
                     h = h * hy.lm_head_multiplier
-                logits = jnp.einsum("bd,vd->bv", h[:, 0, :], head,
-                                    preferred_element_type=jnp.float32)
+                logits = tfm._whole_vocab(cfg, jnp.einsum(
+                    "bd,vd->bv", h[:, 0, :], head,
+                    preferred_element_type=jnp.float32))
             with scope("sample"):
                 nxt = pick(logits, pos, seed)
             out = (nxt, logits[:keep_logits]) if keep_logits else nxt

@@ -4,11 +4,12 @@ communication goes through this framework's device collectives.
 This is the "7B-param data-parallel gradient harness" config of BASELINE.json
 generalized: data parallelism over ``dp``, sequence/context parallelism over
 ``sp`` (ring attention — K/V ppermute ring, exact online-softmax), Megatron
-column/row tensor parallelism over ``tp`` (one psum per block), gradient
+column/row tensor parallelism over ``tp`` (one psum per block; the
+embedding table by vocabulary rows, :func:`param_specs`), gradient
 synchronization over the axes a leaf is replicated on (dp, sp, and tp for
-the norms and the table) by the train step's own sums: a layer's in that
-layer's backward, inside the loop over layers, where the TPU's compiler
-can run them under the rest of the layer's backward
+the norms and a table ``tp`` does not divide) by the train step's own sums:
+a layer's in that layer's backward, inside the loop over layers, where the
+TPU's compiler can run them under the rest of the layer's backward
 (:func:`_make_loss_and_grads`, ``_OVERLAP_OPTIONS``).
 
 Everything is expressed with shard_map + explicit collectives (no GSPMD
@@ -200,6 +201,14 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
     return params
 
 
+def _rows_over_tp(cfg, mesh) -> bool:
+    """Whether :func:`param_specs` splits the table's rows over ``tp``."""
+    if cfg is None or mesh is None:
+        return False
+    tp = int(dict(mesh.shape).get("tp", 1))
+    return tp > 1 and cfg.vocab % tp == 0
+
+
 def param_specs(P, cfg: Optional[TransformerConfig] = None, mesh=None):
     """PartitionSpecs: attention weights tp-sharded Megatron-style, dense
     FFN tp-sharded, MoE experts ep-sharded (replicated when the mesh has
@@ -209,20 +218,28 @@ def param_specs(P, cfg: Optional[TransformerConfig] = None, mesh=None):
     backward and the others once after the loop over layers
     (:func:`_make_loss_and_grads`); ``jax.grad`` of :func:`make_loss_fn`
     gets the same sums from shard_map's transpose, after the whole
-    backward.  The embedding (and an untied head) is stored whole on every
-    device: the loss splits its work over ``tp`` by positions
-    (:func:`_local_loss`), not by vocabulary rows, so each rank's gradient
-    of the table is a partial sum over its positions that the sum over dp,
-    sp and tp completes."""
+    backward.  The embedding (and an untied head) is stored by vocabulary
+    rows over ``tp`` where the mesh's ``tp`` is above 1 and divides the
+    vocabulary: rank r holds rows ``[r·V/tp, (r+1)·V/tp)``, its float32
+    copy, its optimizer state and its gradient, which is whole for those
+    rows and is summed over dp and sp alone.  Who reads the table goes
+    through :func:`_lookup` (each rank's own rows, completed by one psum
+    over ``tp``) and :func:`_unembed` (logits against the rank's rows, which
+    the loss completes by its maximum and sums and :func:`_whole_vocab`
+    gathers for a caller that wants them whole); each tells a split table
+    by its shape.  Without a mesh, or with a vocabulary ``tp`` does not
+    divide, the table is whole on every device and the loss splits its
+    work over ``tp`` by positions (:func:`_local_loss`)."""
+    table = P("tp", None) if _rows_over_tp(cfg, mesh) else P()
     specs = {
-        "emb": P(), "lnf": P(), "ln1": P(), "ln2": P(),
+        "emb": table, "lnf": P(), "ln1": P(), "ln2": P(),
         "wq": P(None, None, "tp"), "wk": P(None, None, "tp"),
         "wv": P(None, None, "tp"), "wo": P(None, "tp", None),
     }
     if cfg is not None and cfg.qk_norm:
         specs["qn"] = specs["kn"] = P(None, "tp")
     if cfg is not None and not cfg.tie_head:
-        specs["head"] = P()
+        specs["head"] = table
     if cfg is not None and cfg.moe_experts:
         has_ep = mesh is not None and "ep" in mesh.axis_names
         specs["wg"] = P()
@@ -375,6 +392,91 @@ def _head(cfg: TransformerConfig, params):
     return params["emb"] if cfg.tie_head else params["head"]
 
 
+# The all-reduces over tp that complete what a table split by rows gives in
+# parts (the lookup's rows, the softmax's maximum and sums) are sites of
+# their own, ``coll.allreduce_rows.tp``, apart from the block's
+# ``coll.allreduce.tp``: a profile then says what the split costs.
+_ROWS_SITE = "allreduce_rows"
+
+
+def _own_rows(cfg: TransformerConfig, ids, rows: int):
+    """Vocabulary ids as indices into this device's ``rows`` rows of a table:
+    the ids themselves where it holds the whole table, ``id - r·rows`` on
+    rank r of ``tp`` where it holds rows ``[r·rows, (r+1)·rows)``
+    (:func:`param_specs`), which lies outside ``[0, rows)`` for an id whose
+    row another rank holds."""
+    from jax import lax
+
+    return ids if rows == cfg.vocab else ids - lax.axis_index("tp") * rows
+
+
+def _lookup(cfg: TransformerConfig, table, tokens):
+    """``table[tokens]`` in the compute dtype.  Of a table split by rows
+    over ``tp`` each rank looks up the tokens whose rows it holds, zero
+    elsewhere, and one psum over ``tp`` completes the rows (one addend is
+    not zero, so the sum is exact in any type).  Differentiated, the psum's
+    transpose is the same psum of the rows' cotangent, and the lookup's
+    scatter-add writes the rank's own rows."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ompi_tpu.core.scopes import coll
+
+    cdt = jnp.dtype(cfg.compute_dtype)
+    rows = table.shape[0]
+    if rows == cfg.vocab:
+        return table[tokens].astype(cdt)
+    local = _own_rows(cfg, tokens, rows)
+    mine = (local >= 0) & (local < rows)
+    h = jnp.where(mine[..., None], table[jnp.where(mine, local, 0)],
+                  0).astype(cdt)
+    with coll(_ROWS_SITE, "tp"):
+        return lax.psum(h, "tp")
+
+
+def _whole_vocab(cfg: TransformerConfig, logits):
+    """Logits against this device's rows of the head as logits over the
+    whole vocabulary: gathered over ``tp`` where the rows are split."""
+    from jax import lax
+
+    from ompi_tpu.core.scopes import coll
+
+    if logits.shape[-1] == cfg.vocab:
+        return logits
+    with coll("allgather", "tp"):
+        return lax.all_gather(logits, "tp", axis=logits.ndim - 1, tiled=True)
+
+
+def _lse_and_label_logit(cfg: TransformerConfig, logits, labels):
+    """Of float32 ``logits`` (..., rows) against this device's rows of the
+    head: each position's logsumexp over the whole vocabulary and its
+    label's logit.  Where the rows are split over ``tp`` the maximum and the
+    sum of exponentials are completed over ``tp`` and the label's logit
+    comes from the rank that holds the label's row.  The maximum goes
+    through ``stop_gradient``: its gradient cancels, and a pmax has no
+    transpose."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ompi_tpu.core.scopes import coll
+
+    rows = logits.shape[-1]
+    if rows == cfg.vocab:
+        return (jax.nn.logsumexp(logits, axis=-1), jnp.take_along_axis(
+            logits, labels[..., None], axis=-1)[..., 0])
+    local = _own_rows(cfg, labels, rows)
+    mine = (local >= 0) & (local < rows)
+    with coll(_ROWS_SITE, "tp"):
+        top = lax.pmax(lax.stop_gradient(logits.max(axis=-1)), "tp")
+    part = jnp.exp(logits - top[..., None]).sum(axis=-1)
+    label = jnp.where(mine, jnp.take_along_axis(
+        logits, jnp.where(mine, local, 0)[..., None], axis=-1)[..., 0], 0.0)
+    with coll(_ROWS_SITE, "tp"):
+        part, label = lax.psum((part, label), "tp")
+    return top + jnp.log(part), label
+
+
 # TransformerConfig.attention's words, as layouts of parallel/attention
 _ATTENTION_LAYOUT = {"ring": "ring", "ulysses": "ulysses", "flash": "ulysses",
                      "xla": "ulysses", "gathered": "gathered"}
@@ -522,7 +624,7 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     impl = attn_mod.layout_impl(comm, layout, shape, shape, cdt, "sp")
 
     with scope("embed"):
-        h = params["emb"][tokens].astype(cdt)  # (b, t, D)
+        h = _lookup(cfg, params["emb"], tokens)  # (b, t, D)
         if hy is not None:
             h = h * hy.embedding_multiplier
 
@@ -625,13 +727,14 @@ def _local_forward(cfg: TransformerConfig, comm, params, tokens):
     aux) — aux is the summed MoE load-balancing loss (0.0 for dense).
     """
     h, aux = _local_backbone(cfg, comm, params, tokens)
-    return _unembed(cfg, h, _head(cfg, params)), aux
+    return _whole_vocab(cfg, _unembed(cfg, h, _head(cfg, params))), aux
 
 
 def _unembed(cfg: TransformerConfig, h, emb):
-    """(B, T, D) -> (B, T, V) float32 logits: on the MXU in compute dtype
-    with f32 accumulation — a f32×f32 matmul here would run at a fraction
-    of the bf16 rate."""
+    """(B, T, D) -> (B, T, rows) float32 logits against the rows of the head
+    this device holds (all V, or V/tp: :func:`param_specs`): on the MXU in
+    compute dtype with f32 accumulation — a f32×f32 matmul here would run
+    at a fraction of the bf16 rate."""
     import jax.numpy as jnp
 
     return jnp.einsum("btd,vd->btv", h,
@@ -648,16 +751,33 @@ def _chunked_nll_sum(cfg: TransformerConfig, h, emb, labels, weight):
     vocabulary matmuls a chunk), so no chunk's logits are kept or made
     twice; the backward pass only scales the two by its cotangent.
 
-    h: (B, T, D) compute dtype; emb: (V, D) f32; labels: (B, T) int32;
-    weight: (B, T) f32.  Returns a f32 scalar.
+    ``emb`` is the head as this device holds it: whole, or rank r's rows
+    ``[r·V/tp, (r+1)·V/tp)`` of a table split over ``tp``
+    (:func:`param_specs`), told by its shape.  Of split rows a chunk's
+    logits are (B, c, V/tp) on each rank, and inside the chunk its maximum
+    and sum of exponentials are completed over ``tp`` and the label's
+    logit comes from the rank that holds the label's row
+    (:func:`_lse_and_label_logit`: a pmax and a psum of (B, c) a chunk), so
+    every rank returns the sum over all its positions and the same three
+    matmuls a chunk run over V/tp rows.  The gradient of ``emb`` is then
+    whole for the rank's rows; that of ``h`` is the rank's rows' part,
+    which the backward pass's next psum over ``tp`` adds to the others';
+    and the cotangent, a part on each rank of what every rank's sum is
+    owed, is summed over ``tp`` first, as the psums' transposes would.
+
+    h: (B, T, D) compute dtype; emb: (V, D) or (V/tp, D) f32; labels:
+    (B, T) int32; weight: (B, T) f32.  Returns a f32 scalar.
     """
     import jax
     import jax.numpy as jnp
     from jax import lax
 
+    from ompi_tpu.core.scopes import coll
+
     B, T, D = h.shape
     c = cfg.ce_chunk
     n = T // c
+    rows = emb.shape[0]
 
     def scan_chunks(body, init, *xs):  # each x: (B, T, ...) -> (n, B, c, ...)
         return lax.scan(body, init, tuple(
@@ -666,9 +786,7 @@ def _chunked_nll_sum(cfg: TransformerConfig, h, emb, labels, weight):
     def chunk_nll(emb_c, h_c, lab_c, w_c):
         logits = jnp.einsum("btd,vd->btv", h_c, emb_c,
                             preferred_element_type=jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        lab_logit = jnp.take_along_axis(
-            logits, lab_c[..., None], axis=-1)[..., 0]
+        lse, lab_logit = _lse_and_label_logit(cfg, logits, lab_c)
         return logits, lse, ((lse - lab_logit) * w_c).sum()
 
     @jax.custom_vjp
@@ -691,7 +809,7 @@ def _chunked_nll_sum(cfg: TransformerConfig, h, emb, labels, weight):
             logits, lse, nll = chunk_nll(emb_c, h_c, lab_c, w_c)
             # d nll / d logits in f32, rounded where it meets the MXU
             d_logits = ((jnp.exp(logits - lse[..., None])
-                         - jax.nn.one_hot(lab_c, logits.shape[-1],
+                         - jax.nn.one_hot(_own_rows(cfg, lab_c, rows), rows,
                                           dtype=jnp.float32))
                         * w_c[..., None]).astype(h.dtype)
             d_h = jnp.einsum("btv,vd->btd", d_logits, emb_c,
@@ -708,6 +826,9 @@ def _chunked_nll_sum(cfg: TransformerConfig, h, emb, labels, weight):
 
     def bwd(res, g):
         d_h, d_emb = res
+        if rows != cfg.vocab:
+            with coll(_ROWS_SITE, "tp"):
+                g = lax.psum(g, "tp")
         return ((g * d_h).astype(d_h.dtype),
                 (g * d_emb).astype(d_emb.dtype), None, None)
 
@@ -721,17 +842,26 @@ def _local_loss(cfg: TransformerConfig, comm, params, tokens,
     shift (the first token of my right neighbor labels my last position).
 
     The hidden states are the same on every rank of a ``tp`` group (the last
-    ``row_parallel`` psum left them so) and the head is stored whole on each,
-    so where ``tp`` divides the local length, rank ``r`` of ``tp`` takes the
-    cross entropy of local positions ``[r·T/tp, (r+1)·T/tp)`` alone and the
-    sums are added over ``tp`` as over ``dp`` and ``sp``: each position's
-    logits are made once a group, not ``tp`` times.  Differentiated, the
-    slice pads the hidden states' gradient with zeros outside the rank's
-    positions and the head's gradient is a partial sum on each rank; the
-    sums over ``tp`` that the backward pass already holds (a
-    ``row_parallel`` psum's transpose, the sum of a replicated leaf's
-    gradient) add the parts.  A length ``tp`` does not divide keeps every
-    position on every rank.
+    ``row_parallel`` psum left them so), so the group shares the work and
+    makes each position's logits once, not ``tp`` times.  **By vocabulary
+    rows**, where :func:`param_specs` splits the head's rows over ``tp``:
+    rank ``r`` makes the logits of all local positions against its own
+    ``V/tp`` rows, the softmax's maximum and sum are completed over ``tp``
+    and the label's logit comes from the rank that holds its row
+    (:func:`_lse_and_label_logit`), so every rank of the group has the whole
+    sum of its positions and the sums are added over ``dp`` and ``sp``
+    alone.  Differentiated, the head's gradient is whole for the rank's
+    rows and the hidden states' is the part that the rank's rows give.
+    **By positions**, where the head is whole on every rank (a vocabulary
+    ``tp`` does not divide) and ``tp`` divides the local length: rank ``r``
+    takes the cross entropy of local positions ``[r·T/tp, (r+1)·T/tp)``
+    alone and the sums are added over ``tp`` as over ``dp`` and ``sp``;
+    the slice pads the hidden states' gradient with zeros outside the
+    rank's positions and the head's gradient is a partial sum on each
+    rank.  Either way the sums over ``tp`` that the backward pass already
+    holds (a ``row_parallel`` psum's transpose, the lookup's, the sum of a
+    replicated leaf's gradient) add the parts.  Where neither divides,
+    every rank keeps every position and the whole head.
 
     With ``grad_axes`` (:func:`grad_sum_axes`; the train step, which takes
     the gradient inside its shard_map) returns ``(objective, loss)``.  The
@@ -744,7 +874,6 @@ def _local_loss(cfg: TransformerConfig, comm, params, tokens,
     and the other leaves' once, when the embedding's backward is done: the
     whole loss's gradient of every leaf, equal on the devices that hold a
     copy."""
-    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -776,7 +905,9 @@ def _local_loss(cfg: TransformerConfig, comm, params, tokens,
     h, aux = _local_backbone(cfg, comm, params, tokens, grad_axes=grad_axes)
     sum_axes = ("dp", "sp")
     with scope("loss"):
-        if tp > 1 and T % tp == 0:
+        head = _head(cfg, params)
+        by_rows = head.shape[0] != cfg.vocab    # split over tp: not positions
+        if not by_rows and tp > 1 and T % tp == 0:
             T = T // tp
             start = lax.axis_index("tp") * T
             h, labels, weight = (
@@ -785,14 +916,11 @@ def _local_loss(cfg: TransformerConfig, comm, params, tokens,
             sum_axes += ("tp",)
         if cfg.ce_chunk and T % cfg.ce_chunk == 0:
             local_sum = _chunked_nll_sum(
-                cfg, h, _head(cfg, params), labels,
-                jnp.broadcast_to(weight, (B, T)))
+                cfg, h, head, labels, jnp.broadcast_to(weight, (B, T)))
         else:
-            logprobs = jax.nn.log_softmax(
-                _unembed(cfg, h, _head(cfg, params)), axis=-1)
-            nll = -jnp.take_along_axis(
-                logprobs, labels[..., None], axis=-1)[..., 0]
-            local_sum = (nll * weight).sum()
+            lse, label = _lse_and_label_logit(
+                cfg, _unembed(cfg, h, head), labels)
+            local_sum = ((lse - label) * weight).sum()
     local_cnt = weight.sum() * B
     if all(int(comm.mesh.shape[a]) == 1 for a in sum_axes):
         total, count = local_sum, local_cnt  # psum is identity
@@ -1100,9 +1228,11 @@ def make_train_step(cfg: TransformerConfig, mesh, lr: float = 3e-4):
     layers; the table's, ``lnf``'s and an untied head's once the
     embedding's backward is done; with ``grad_accum`` > 1 every leaf's once
     after the last microbatch.  Every leaf is all-reduced once a step.
-    Rank r of ``tp`` computes the loss of local positions
-    ``[r·T/tp, (r+1)·T/tp)`` (:func:`_local_loss`), so before its sum a
-    replicated leaf's gradient is each rank's partial sum, not a copy.
+    Rank r of ``tp`` holds rows ``[r·V/tp, (r+1)·V/tp)`` of the table
+    (:func:`param_specs`), computes the loss of every local position
+    against them (:func:`_local_loss`) and updates them alone; their
+    gradient is summed over dp and sp.  Before its sum a replicated leaf's
+    gradient is each rank's partial sum, not a copy.
     On a mesh of TPUs with an axis to sum over, the step is compiled with
     ``_OVERLAP_OPTIONS``: its all-reduces are asynchronous and run under
     independent matmuls of the same computation.

@@ -3,9 +3,12 @@
 its logits.  Held to the full-logits path, to the checkpointed scan it
 replaced (a local copy), and to its own shape: one vocabulary matmul a
 chunk for the value, three for value and gradient, none recomputed.  And
-the split of the positions over ``tp`` (``_local_loss``): each rank of a
-``tp`` group scans its ``1/tp`` of the local positions, and loss and
-gradients are those of the mesh without ``tp``."""
+the split of its work over ``tp`` (``_local_loss``): by vocabulary rows
+where ``param_specs`` stores the table by rows over ``tp`` (each rank scans
+every local position against its ``V/tp`` rows and the softmax is completed
+over ``tp`` inside the chunk), by positions where ``tp`` does not divide the
+vocabulary and the table stays whole; loss and gradients are those of the
+mesh without ``tp`` either way."""
 
 import dataclasses
 import math
@@ -183,59 +186,156 @@ def _vocabulary_dots(fn, *args, vocab):
     return found
 
 
-# a vocabulary no other width of the model equals
+# a vocabulary no other width of the model equals, nor its half or quarter;
+# and one that tp = 4 does not divide
 _DOTS_CFG = dataclasses.replace(CFG, ce_chunk=8, attention="xla", vocab=160)
+_WHOLE_CFG = dataclasses.replace(_DOTS_CFG, vocab=162)
 
 
-@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("tp", [1, 2, 4])
 @pytest.mark.parametrize("what,per_chunk", [("value", 1), ("value_and_grad", 3)])
 def test_vocabulary_matmuls_a_chunk(what, per_chunk, tp):
     """(e) the value alone holds one vocabulary matmul in its scan's body,
     value and gradient hold three (logits, the hidden states' gradient, the
     head's), and none of them sits under a checkpoint: nothing is made
-    twice.  (The checkpointed scan held four.)  The scan runs over this
-    rank's positions: ``T / ce_chunk`` chunks without ``tp``,
-    ``T / (tp · ce_chunk)`` with it."""
+    twice.  (The checkpointed scan held four.)  With the table's rows over
+    ``tp`` every rank scans all ``T / ce_chunk`` chunks and its matmuls run
+    over ``V / tp`` rows; no matmul over the whole vocabulary is left."""
     mesh = _mesh({"dp": 1, "sp": 1, "tp": tp})
     cfg = _DOTS_CFG
     params, toks = tfm.init_params(cfg), _tokens(cfg)
     loss = tfm.make_loss_fn(cfg, mesh)
     fn = loss if what == "value" else jax.value_and_grad(loss)
-    dots = _vocabulary_dots(fn, params, toks, vocab=cfg.vocab)
+    dots = _vocabulary_dots(fn, params, toks, vocab=cfg.vocab // tp)
     assert len(dots) == per_chunk, dots
     for under, length in dots:
         assert "scan" in under, under
         assert not {"checkpoint", "remat", "remat2"} & set(under), under
-        assert length == cfg.seq // (tp * cfg.ce_chunk), (length, under)
+        assert length == cfg.seq // cfg.ce_chunk, (length, under)
+    if tp > 1:
+        assert not _vocabulary_dots(fn, params, toks, vocab=cfg.vocab)
 
 
 @pytest.mark.parametrize("mesh_name", sorted(TP_MESHES))
-def test_positions_split_over_tp_match_no_tp(mesh_name):
-    """(f) each ``tp`` rank takes the cross entropy of its own ``1/tp`` of
-    the local positions (two chunks a rank on ``dp2sp2tp2`` and
-    ``dp2sp1tp4``, four on ``dp4sp1tp2``): the loss and the gradient of
-    every leaf, the replicated ``emb``, ``lnf``, ``ln1`` and the sharded
-    ``wq``, ``wo``, ``w1``, ``w2`` alike, are those of the same mesh
-    without ``tp``, where every position is one rank's."""
+def test_rows_split_over_tp_match_no_tp(mesh_name):
+    """(f) each ``tp`` rank holds ``1/tp`` of the table's rows and takes the
+    cross entropy of every local position against them (eight chunks a
+    rank on ``dp4sp1tp2`` and ``dp2sp1tp4``, four on ``dp2sp2tp2``): the
+    loss and the gradient of every leaf, the table's rows put back
+    together, the replicated ``lnf``, ``ln1`` and the sharded ``wq``,
+    ``wo``, ``w1``, ``w2`` alike, are those of the same mesh without
+    ``tp``, where the table is whole."""
     axes = TP_MESHES[mesh_name]
     cfg = dataclasses.replace(CFG, ce_chunk=4)
     params, toks = tfm.init_params(cfg), _tokens(cfg)
     split = _value_and_grad(cfg, _mesh(axes))(params, toks)
     whole = _value_and_grad(cfg, _mesh({**axes, "tp": 1}))(params, toks)
     assert {"emb", "lnf", "ln1", "wq", "wo", "w1", "w2"} <= set(split[1])
+    assert split[1]["emb"].shape == (cfg.vocab, cfg.d_model)
     _assert_same_loss_and_gradients(split, whole)
 
 
-def test_length_tp_does_not_divide_stays_whole():
-    """(g) 30 local positions over ``tp`` = 4: no rank takes a part, the
-    scan runs over all six chunks on every rank, and loss and gradients
-    are still those of the mesh without ``tp``."""
-    cfg = dataclasses.replace(_DOTS_CFG, seq=30, ce_chunk=5)
+def test_vocabulary_tp_does_not_divide_keeps_the_split_by_positions():
+    """(g) 162 rows over ``tp`` = 4: the table stays whole on every rank and
+    each rank takes its quarter of the positions, one chunk of three
+    matmuls over all 162 rows; loss and gradients are those of the mesh
+    without ``tp``."""
+    cfg = _WHOLE_CFG
+    mesh = _mesh(TP4)
+    params, toks = tfm.init_params(cfg), _tokens(cfg)
+    dots = _vocabulary_dots(jax.value_and_grad(tfm.make_loss_fn(cfg, mesh)),
+                            params, toks, vocab=cfg.vocab)
+    assert [length for _under, length in dots] == [1, 1, 1], dots
+    split = _value_and_grad(cfg, mesh)(params, toks)
+    whole = _value_and_grad(cfg, _mesh({**TP4, "tp": 1}))(params, toks)
+    _assert_same_loss_and_gradients(split, whole)
+
+
+@pytest.mark.parametrize("cfg,rows", [
+    pytest.param(_DOTS_CFG, 40, id="rows-split"),
+    pytest.param(_WHOLE_CFG, 162, id="nothing-divides")])
+def test_length_tp_does_not_divide(cfg, rows):
+    """(h) 30 local positions over ``tp`` = 4: no rank takes a part of the
+    positions and the scan runs over all six chunks on every rank, against
+    its quarter of the rows where ``tp`` divides the vocabulary and against
+    the whole table where it divides neither; loss and gradients are still
+    those of the mesh without ``tp``."""
+    cfg = dataclasses.replace(cfg, seq=30, ce_chunk=5)
     params, toks = tfm.init_params(cfg), _tokens(cfg)
     loss = tfm.make_loss_fn(cfg, _mesh(TP4))
     dots = _vocabulary_dots(jax.value_and_grad(loss), params, toks,
-                            vocab=cfg.vocab)
+                            vocab=rows)
     assert [length for _under, length in dots] == [6, 6, 6], dots
     split = _value_and_grad(cfg, _mesh(TP4))(params, toks)
     whole = _value_and_grad(cfg, _mesh({**TP4, "tp": 1}))(params, toks)
     _assert_same_loss_and_gradients(split, whole)
+
+
+def _split_nll(cfg, h, emb, labels, weight):
+    """``_chunked_nll_sum`` on two ranks of ``tp``, each with its half of
+    ``emb``'s rows: (sum, the hidden states' gradient with the two ranks'
+    parts added, the head's gradient with its rows put back together).
+    Each rank differentiates its half of the sum, which every rank has
+    whole: the two halves are the objective."""
+    from jax.sharding import PartitionSpec as P
+
+    def local(h, emb):
+        total, (d_h, d_emb) = jax.value_and_grad(
+            lambda h, e: 0.5 * tfm._chunked_nll_sum(cfg, h, e, labels,
+                                                    weight),
+            argnums=(0, 1))(h, emb)
+        return 2 * total, lax.psum(d_h, "tp"), d_emb
+
+    return jax.jit(jax.shard_map(
+        local, mesh=_mesh({"dp": 1, "sp": 1, "tp": 2}),
+        in_specs=(P(), P("tp", None)), out_specs=(P(), P(), P("tp", None)),
+        check_vma=False))(h, emb)
+
+
+@pytest.mark.parametrize("case", ["label-on-the-other-rank",
+                                  "label-on-this-rank", "last-weight-zero"])
+def test_rows_split_chunk_against_the_whole_table(case):
+    """(i) the chunk's own pieces on two ranks of ``tp``.  Every label's
+    row on rank 1 (rank 0 gives the label's logit nothing and its one-hot
+    is empty), every label's row on rank 0, and a last position of weight
+    zero, whose hidden state gets exactly no gradient from either rank:
+    sum and both gradients are the whole table's."""
+    cfg = dataclasses.replace(CFG, ce_chunk=8)
+    h, emb, labels, weight = _loss_inputs("float32")
+    half = emb.shape[0] // 2
+    if case == "label-on-the-other-rank":
+        labels = half + labels % half
+    elif case == "label-on-this-rank":
+        labels = labels % half
+    else:
+        weight = jnp.ones_like(weight).at[:, -1].set(0.0)
+    want = jax.jit(jax.value_and_grad(
+        lambda h, e: tfm._chunked_nll_sum(cfg, h, e, labels, weight),
+        argnums=(0, 1)))(h, emb)
+    total, d_h, d_emb = _split_nll(cfg, h, emb, labels, weight)
+    _assert_same_loss_and_gradients(
+        (total, {"h": d_h, "emb": d_emb}),
+        (want[0], {"h": want[1][0], "emb": want[1][1]}))
+    if case == "last-weight-zero":
+        norms = np.abs(np.asarray(d_h)).max(axis=-1)
+        assert (norms[:, -1] == 0).all() and (norms[:, :-1] > 0).all()
+
+
+@pytest.mark.parametrize("vocab,spec,axes", [
+    pytest.param(128, ("tp", None), ("dp",), id="rows-over-tp"),
+    pytest.param(127, (), ("dp", "tp"), id="tp-does-not-divide")])
+def test_the_table_is_summed_over_the_axes_it_is_not_split_over(vocab, spec,
+                                                                axes):
+    """(j) on the four-chip cell's mesh, dp2 x tp2: the table by rows over
+    ``tp`` and its gradient summed over the dp pair alone, an untied head
+    alike; a vocabulary ``tp`` does not divide whole, summed over all four."""
+    from jax.sharding import PartitionSpec as P
+
+    cfg = dataclasses.replace(CFG, vocab=vocab, tie_head=False)
+    mesh = _mesh({"dp": 2, "sp": 1, "tp": 2})
+    specs, sums = tfm.param_specs(P, cfg, mesh), tfm.grad_sum_axes(cfg, mesh)
+    for leaf in ("emb", "head"):
+        assert specs[leaf] == P(*spec), leaf
+        assert sums[leaf] == axes, leaf
+    assert tfm.param_specs(P, cfg)["emb"] == P()      # no mesh: whole
+    assert sums["lnf"] == ("dp", "tp") and sums["w1"] == ("dp",)

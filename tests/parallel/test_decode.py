@@ -38,13 +38,22 @@ def _greedy_reference(fwd, params, prompt, max_new):
     return cur
 
 
-def _assert_cached_equals_full(cfg, mesh_shape, max_new, prompt_len, seed):
+def _mesh_of(mesh_shape):
     import jax
 
-    mesh = make_mesh(mesh_shape,
+    return make_mesh(mesh_shape,
                      devices=jax.devices()[:math.prod(mesh_shape.values())])
+
+
+def _assert_cached_equals_full(cfg, mesh_shape, max_new, prompt_len, seed,
+                               forward_on=None):
+    """``forward_on``: the mesh of the full forward where it is not the
+    decoder's."""
+    import jax
+
+    mesh = _mesh_of(mesh_shape)
     params = tfm.init_params(cfg)
-    fwd = jax.jit(tfm.make_forward(cfg, mesh))
+    fwd = jax.jit(tfm.make_forward(cfg, _mesh_of(forward_on or mesh_shape)))
     prompt = np.random.default_rng(seed).integers(
         0, cfg.vocab, size=(4, prompt_len)).astype(np.int32)
     got = np.asarray(make_decoder(cfg, mesh, max_new=max_new)(params, prompt))
@@ -66,6 +75,51 @@ def _assert_cached_equals_full(cfg, mesh_shape, max_new, prompt_len, seed):
 def test_cached_decode_matches_full_forward(mesh_shape, max_new, n_layers):
     _assert_cached_equals_full(dataclasses.replace(CFG, n_layers=n_layers),
                                mesh_shape, max_new, prompt_len=8, seed=0)
+
+
+# ---- the table by vocabulary rows over tp (``param_specs``) -----------------
+
+ONE = {"dp": 1, "sp": 1, "tp": 1}
+ROWS = dataclasses.replace(CFG, vocab=96)       # tp = 2 and 4 divide it
+
+
+@pytest.mark.parametrize("mesh_shape", [
+    pytest.param({"dp": 1, "sp": 1, "tp": 2}, id="tp2"),
+    pytest.param({"dp": 2, "sp": 1, "tp": 2}, id="dp2tp2"),
+    pytest.param({"dp": 1, "sp": 1, "tp": 4}, id="tp4")])
+def test_cached_decode_with_the_tables_rows_over_tp(mesh_shape):
+    """Each rank of ``tp`` holds ``1/tp`` of the tied table's rows: the
+    prefill's and the cached step's lookup are completed by a psum and
+    their logits gathered over ``tp`` before the argmax, and the tokens are
+    those of full forward passes on one device, which holds the table
+    whole."""
+    from jax.sharding import PartitionSpec as P
+
+    assert tfm.param_specs(P, ROWS, _mesh_of(mesh_shape))["emb"] == P(
+        "tp", None)
+    _assert_cached_equals_full(ROWS, mesh_shape, 5, prompt_len=8, seed=0,
+                               forward_on=ONE)
+
+
+def test_kept_logits_are_the_whole_vocabularys_with_the_rows_over_tp():
+    """``keep_logits`` on ``tp`` = 2 with the table split: the tokens are
+    what they are without it, and the logits are (n, max_new, vocab), the
+    one-device full forward's."""
+    import jax
+
+    mesh = _mesh_of({"dp": 1, "sp": 1, "tp": 2})
+    params = tfm.init_params(ROWS)
+    prompts = np.random.default_rng(6).integers(
+        0, ROWS.vocab, size=(4, 8)).astype(np.int32)
+    plain = np.asarray(make_decoder(ROWS, mesh, max_new=5)(params, prompts))
+    tokens, logits = make_decoder(ROWS, mesh, max_new=5, keep_logits=2)(
+        tfm.shard_params(ROWS, mesh, params), prompts)
+    np.testing.assert_array_equal(np.asarray(tokens), plain)
+    want = np.asarray(jax.jit(tfm.make_forward(ROWS, _mesh_of(ONE)))(
+        params, plain[:2]))[:, 7:-1]
+    assert logits.shape == (2, 5, ROWS.vocab)
+    np.testing.assert_allclose(np.asarray(logits), want, rtol=0,
+                               atol=2e-5 * want.std())
 
 
 def test_decoder_called_again_returns_the_same_tokens():
@@ -159,16 +213,16 @@ def _cached_logits(cfg, mesh, params, tokens, prompt_len):
             cfg, comm, params, tokens[:, :prompt_len], collect_kv=True)
         pad = [(0, 0), (0, 0), (0, steps), (0, 0), (0, 0)]
         kc, vc = jnp.pad(ks, pad), jnp.pad(vs, pad)
-        out = [h[:, -1, :] @ head.T]
+        out = [tfm._whole_vocab(cfg, h[:, -1, :] @ head.T)]
         for pos in range(prompt_len, prompt_len + steps):
-            h = params["emb"][tokens[:, pos]].astype(cdt)[:, None, :]
+            h = tfm._lookup(cfg, params["emb"], tokens[:, pos])[:, None, :]
             for l in range(cfg.n_layers):
                 lp = {k: params[k] if k in EXPERT_LEAVES
                       else params[k][l] for k in tfm.layer_leaves(cfg)}
                 h, kc, vc = decode._step_layer(cfg, comm, lp, h, kc, vc, l,
                                                jnp.int32(pos))
-            out.append(tfm._rmsnorm(h, params["lnf"], cfg.norm_eps)[:, 0, :]
-                       @ head.T)
+            out.append(tfm._whole_vocab(cfg, tfm._rmsnorm(
+                h, params["lnf"], cfg.norm_eps)[:, 0, :] @ head.T))
         return jnp.stack(out, axis=1)
 
     return jax.jit(jax.shard_map(
@@ -177,28 +231,37 @@ def _cached_logits(cfg, mesh, params, tokens, prompt_len):
         out_specs=P("dp", None, None), check_vma=False))(params, tokens)
 
 
-@pytest.mark.parametrize("mesh_shape", [
-    pytest.param({"dp": 1, "sp": 1, "tp": 1}, id="one"),
-    pytest.param({"dp": 2, "sp": 1, "tp": 2}, id="dp2tp2")])
-def test_olmoe_shaped_cached_logits_equal_the_full_forwards(mesh_shape):
+@pytest.mark.parametrize("mesh_shape,vocab", [
+    pytest.param({"dp": 1, "sp": 1, "tp": 1}, 97, id="one"),
+    pytest.param({"dp": 2, "sp": 1, "tp": 2}, 97, id="dp2tp2"),
+    pytest.param({"dp": 2, "sp": 1, "tp": 2}, 96, id="dp2tp2-rows-over-tp")])
+def test_olmoe_shaped_cached_logits_equal_the_full_forwards(mesh_shape,
+                                                            vocab):
     """Logits, not tokens: the routed tail at one token a sequence (8 rows
     over 8 experts), the q/k-norm (summed over tp where the heads are
     split) and the untied head in the cached step, against the full
-    forward, both in float32.  What is left is the order of summation."""
+    forward on one device, both in float32.  What is left is the order of
+    summation.  At 96 rows ``tp`` = 2 splits the lookup table and the
+    untied head, each a leaf of its own, by rows; 97 it leaves whole."""
     import jax
+    from jax.sharding import PartitionSpec as P
 
-    mesh = make_mesh(mesh_shape,
-                     devices=jax.devices()[:math.prod(mesh_shape.values())])
-    params = tfm.init_params(OLMOE, seed=3)
+    cfg = dataclasses.replace(OLMOE, vocab=vocab)
+    mesh = _mesh_of(mesh_shape)
+    split = P("tp", None) if vocab == 96 else P()
+    specs = tfm.param_specs(P, cfg, mesh)
+    assert specs["emb"] == specs["head"] == split
+    params = tfm.init_params(cfg, seed=3)
     # norm scales away from one, so that a norm left out would show
     rng = np.random.default_rng(4)
     for leaf in ("qn", "kn", "ln1", "ln2", "lnf"):
         params[leaf] = rng.uniform(0.5, 1.5, size=params[leaf].shape).astype(
             np.float32)
-    tokens = rng.integers(0, OLMOE.vocab, size=(4, 12)).astype(np.int32)
-    full = np.asarray(jax.jit(tfm.make_forward(OLMOE, mesh))(params, tokens))
-    got = np.asarray(_cached_logits(OLMOE, mesh, params, tokens, prompt_len=9))
-    assert got.shape == (4, 4, OLMOE.vocab)
+    tokens = rng.integers(0, cfg.vocab, size=(4, 12)).astype(np.int32)
+    full = np.asarray(jax.jit(tfm.make_forward(cfg, _mesh_of(ONE)))(
+        params, tokens))
+    got = np.asarray(_cached_logits(cfg, mesh, params, tokens, prompt_len=9))
+    assert got.shape == (4, 4, cfg.vocab)
     np.testing.assert_allclose(got, full[:, 8:], rtol=0, atol=2e-5 * full.std())
 
 

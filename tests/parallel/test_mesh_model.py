@@ -339,17 +339,28 @@ def _all_reduces(text, trips):
     return found
 
 
-def test_dp2tp2_step_sums_each_leaf_once_over_the_same_devices():
+@pytest.mark.parametrize("vocab,rows,over_dp,over_every", [
+    pytest.param(128, 64, [False] + [True] * 6, [False, True, True],
+                 id="rows-over-tp"),
+    pytest.param(127, 127, [True] * 6, [False, False, True, True],
+                 id="whole-table")])
+def test_dp2tp2_step_sums_each_leaf_once_over_the_same_devices(
+        vocab, rows, over_dp, over_every):
     """The lowered dp2 x tp2 step holds one all-reduce a leaf, a layer's
     inside the backward loop, over the replica groups and with the total
-    operand bytes of the sums shard_map's transpose made."""
-    L = SUM_CFG.n_layers
+    operand bytes of the sums shard_map's transpose made.  The table, by
+    rows over ``tp``, is summed over the dp pair after the loop; whole (a
+    vocabulary ``tp`` does not divide), over all four devices."""
+    import dataclasses
+
+    cfg = dataclasses.replace(SUM_CFG, vocab=vocab)
+    L = cfg.n_layers
     mesh = _mesh_of(SUM_MESHES["dp2tp2"])
-    params, toks = tfm.init_params(SUM_CFG), _tokens(SUM_CFG, batch=8)
+    params, toks = tfm.init_params(cfg), _tokens(cfg, batch=8)
     before = _all_reduces(jax.jit(jax.value_and_grad(tfm.make_loss_fn(
-        SUM_CFG, mesh))).lower(params, toks).as_text(), L)
+        cfg, mesh))).lower(params, toks).as_text(), L)
     now = _all_reduces(jax.jit(tfm._make_loss_and_grads(
-        SUM_CFG, mesh)).lower(params, toks).as_text(), L)
+        cfg, mesh)).lower(params, toks).as_text(), L)
 
     def totals(rows):
         out = {}
@@ -362,10 +373,33 @@ def test_dp2tp2_step_sums_each_leaf_once_over_the_same_devices():
     assert set(totals(now)) == {dp, every, "[[0, 1], [2, 3]]"}
     # the six matrices of a layer over dp and its two norms over dp and tp,
     # in the loop; the table and lnf after it
-    assert sorted(in_loop for g, _, in_loop in now if g == dp) == [True] * 6
-    assert sorted(in_loop for g, _, in_loop in now if g == every) == [
-        False, False, True, True]
+    assert sorted(in_loop for g, _, in_loop in now if g == dp) == over_dp
+    assert sorted(in_loop for g, _, in_loop in now if g == every) == over_every
     assert not any(in_loop for g, _, in_loop in before if g in (dp, every))
+    # the table's sum: the ``rows`` a rank holds, after the loop
+    assert [size for g, size, in_loop in now if not in_loop
+            and g == (every if rows == vocab else dp)
+            and size > 4 * cfg.d_model] == [4 * rows * cfg.d_model]
+
+
+@pytest.mark.parametrize("mesh_name", ["tp2", "dp2tp2", "dp2sp2tp2"])
+def test_forward_with_the_tables_rows_over_tp_equals_one_device(mesh_name):
+    """``make_forward`` on a mesh whose ``tp`` splits the table's rows (each
+    rank looks up its own rows and makes the logits of its own, gathered
+    over ``tp``) hands back the whole vocabulary's logits, those of one
+    device."""
+    from jax.sharding import PartitionSpec as P
+
+    axes = {**SUM_MESHES, "dp2sp2tp2": {"dp": 2, "sp": 2, "tp": 2}}[mesh_name]
+    mesh = _mesh_of(axes)
+    assert tfm.param_specs(P, SUM_CFG, mesh)["emb"] == P("tp", None)
+    params, toks = tfm.init_params(SUM_CFG), _tokens(SUM_CFG, batch=4)
+    want = np.asarray(jax.jit(tfm.make_forward(
+        SUM_CFG, _mesh_of({"dp": 1, "sp": 1, "tp": 1})))(params, toks))
+    got = np.asarray(jax.jit(tfm.make_forward(SUM_CFG, mesh))(
+        tfm.shard_params(SUM_CFG, mesh, params), toks))
+    assert got.shape == (4, SUM_CFG.seq, SUM_CFG.vocab)
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-5 * want.std())
 
 
 def test_forward_only_programs_know_nothing_of_the_gradient_sums(monkeypatch):

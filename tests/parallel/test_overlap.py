@@ -13,6 +13,7 @@ mesh of CPU devices is handed none either, or its backend would refuse the
 compile ("No such compile option").
 """
 
+import contextlib
 import os
 import re
 
@@ -41,8 +42,8 @@ def chips():
         pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
 
 
-@pytest.fixture(autouse=True)
-def _compile_for_the_chip():
+@contextlib.contextmanager
+def _for_the_chip():
     """A program compiled for a described chip is written to the persistent
     cache but cannot be read back without one; and the suite's session
     fixture puts pallas kernels into TPU interpret mode, for the CPU, under
@@ -54,10 +55,28 @@ def _compile_for_the_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    with pltpu.force_tpu_interpret_mode(None):
+    try:
+        with pltpu.force_tpu_interpret_mode(None):
+            yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def _compile_for_the_chip():
+    with _for_the_chip():
         yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def four_chip_step(chips):
+    """name -> lines of every computation of the four-chip cell's real
+    ``train_step`` as the v5e's compiler scheduled it: one compile for the
+    tests that read it."""
+    with _for_the_chip():
+        fn, args = _job(FOUR_CHIPS, chips).programs()["train_step"]
+        return _computations(fn.lower(*args).compile().as_text())
 
 
 def _job(workload, devices):
@@ -79,18 +98,25 @@ def _computations(text):
     return out
 
 
-def test_four_chip_step_runs_all_reduces_under_matmuls(chips):
-    fn, args = _job(FOUR_CHIPS, chips).programs()["train_step"]
-    computations = _computations(fn.lower(*args).compile().as_text())
-    # the backward loop's body: where a layer's gradient sums over dp end
+def _backward_body(computations):
+    """The backward loop's body: where a layer's gradient sums over dp end."""
     bodies = [lines for lines in computations.values()
               if any(re.match(r"\s*%async-collective-done[\w.]* = .*transpose"
                               r"\(jvp\(layers\)\)/while/body/.*coll\.allreduce"
                               r"\.dp/", line) for line in lines)]
     assert len(bodies) == 1, [len(b) for b in bodies]
-    body = bodies[0]
-    starts = [line for line in body
-              if re.match(r"\s*%async-collective-start(\.\d+)? = ", line)]
+    return bodies[0]
+
+
+def _async_starts(lines):
+    return [line for line in lines
+            if re.match(r"\s*%async-collective-start(\.\d+)? = ", line)]
+
+
+def test_four_chip_step_runs_all_reduces_under_matmuls(four_chip_step):
+    computations = four_chip_step
+    body = _backward_body(computations)
+    starts = _async_starts(body)
     assert len(starts) >= 2, len(starts)
     # between a start and its done: fusions that continue the all-reduce
     # beside a matmul
@@ -103,6 +129,21 @@ def test_four_chip_step_runs_all_reduces_under_matmuls(chips):
                    if (m := re.search(r" ([a-z][\w-]*)\(", line.split(
                        " = ", 1)[-1]))}
         assert {"all-reduce", "convolution"} <= opcodes, (name, opcodes)
+
+
+def test_four_chip_step_sums_half_of_the_table_over_the_dp_pair(
+        four_chip_step):
+    """The table is stored by rows over ``tp``: the scheduled step holds no
+    all-reduce of the whole table's gradient, f32[50432, 4096], and one of a
+    rank's rows, f32[25216, 4096], between the two chips of a dp pair; and
+    a layer's backward still holds its seven asynchronous pairs."""
+    reduces = [line for lines in four_chip_step.values() for line in lines
+               if re.search(r" all-reduce(-start)?\(", line)]
+    assert not [line for line in reduces if "f32[50432,4096]" in line]
+    table = [line for line in reduces if "f32[25216,4096]" in line]
+    assert len(table) == 1, table
+    assert "replica_groups={{0,2},{1,3}}" in table[0], table[0]
+    assert len(_async_starts(_backward_body(four_chip_step))) == 7
 
 
 def _step_before(cfg, mesh, lr):
