@@ -62,6 +62,8 @@ SCOPES = (
     "attention.ring",   # ... K/V blocks around the sp ring
     "attention.ulysses",    # ... resharded seq -> heads by all_to_all
     "attention.flash",  # ... the pallas kernels
+    "attention.gather",     # a cached step's selected K/V rows read out of
+                            # the carry (models/sparse_index.py)
     "ffn",              # ln2, the MLP, the residual
     "moe.route", "moe.dispatch", "moe.experts", "moe.combine",
     "ssm_proj",         # the mixer's scalings, in/out projections, gated norm
@@ -69,11 +71,14 @@ SCOPES = (
     "ssm.scan",         # ... the chunked scan over a whole sequence
     "ssm.update",       # ... one cached step's recurrence: from reading the
                         # layer's state out of the carry to writing it back
+    "index_proj",       # the index's three projections, key norm, rotary
+    "index.score",      # ... its scores of a query against the index keys
+    "index.select",     # ... the topk positions: a threshold, or a top-k
     "loss",             # the unembed matmul and the cross entropy
     "optimizer",        # the optimizer's update and its application
     "prefill",          # backbone over the prompt, cache padding, first logits
     "decode.step",      # one cached token for the whole batch
-    "kv_cache",         # ... writing the new K/V into the cache
+    "kv_cache",         # ... writing the new K/V (and index key) into the cache
     "unembed",          # ... the vocabulary matmul
     "sample",           # ... picking the next token
 )

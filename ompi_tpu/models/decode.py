@@ -31,6 +31,19 @@ stacks and writes them back in place at ``(l,)``; the update is float32
 and is rounded once on the way back (the same test file holds the chip's
 compiled program to one write a step and no copy of the stack).
 
+An index (``models/sparse_index.py``: learned sparse attention) carries a
+stack more that does grow with the sequence, the index's keys ``(L, B,
+width, Tp+max_new)``, positions last, written at ``pos`` beside K and V by
+the prefill and by every step.  A step scores the new position's index
+queries against the layer's index keys, takes the ``topk`` positions of the
+largest scores, and reads K and V of those positions out of the carry: the
+layer's whole K and V are the operand of one gather and of nothing else.
+One gather, because with an index a position's K and V are one row of one
+stack, ``(L, B, Tp+max_new, 2 Hkv/tp, hd)``, the K heads and then the V
+heads, and there is no second stack (``vc`` is ``None``): a gather on this
+chip costs its 15 ns a row whether the row is 1 KB or 2, so two stacks
+would take twice as long to read the same bytes (PERF.md section 5).
+
 The prefill hands the carry over.  By default it is one pass over every
 prompt whose K/V are padded to the cache's length.  With
 ``TransformerConfig.prefill_tokens`` (and for a hybrid block, always) the
@@ -69,53 +82,19 @@ from ompi_tpu.parallel.moe import EXPERT_LEAVES
 __all__ = ["make_decoder"]
 
 
-def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, layer, pos,
-                states=()):
-    """Layer ``layer`` for ONE new token position, against the whole cache.
-
-    h: (B, 1, D); kc/vc: the stacked cache (L, B, Tmax, Hkv/tp, hd); lp:
-    this layer's parameters, but for the dropless experts' leaves
-    (``moe.EXPERT_LEAVES``), which are the whole stacks over layers that
-    ``routed_moe`` indexes by ``layer``.  ``states``: with a hybrid block
-    the mixer's two stacks, the convolution's last inputs ``(L, B,
-    d_conv - 1, conv_dim)`` and the heads' states ``(L, B, H, P, N)``.
-    Returns (h, kc, vc, *states) with the new token's k/v written in place
-    at ``(layer, :, pos)`` and the layer's states at ``(layer,)``.
-    """
+def _attend_whole_cache(q, kc, vc, layer, pos):
+    """q (B, 1, H, hd) against every position up to ``pos`` of layer
+    ``layer`` of the cache (L, B, Tmax, Hkv, hd), a K/V head read once for
+    the query heads it serves: the context, float32, (B, 1, H, hd) or
+    grouped (B, 1, Hkv, H / Hkv, hd)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     from ompi_tpu.core.scopes import scope
-    from ompi_tpu.parallel.layers import column_parallel, row_parallel
 
-    cdt = h.dtype
-    B = h.shape[0]
-    Tmax, hkv, hd = kc.shape[2:]
-    hl = hkv * (cfg.n_heads // cfg.kv_heads)
-    hy = cfg.hybrid
-
-    with scope("attn_proj"):
-        x = _rmsnorm(h, lp["ln1"], cfg.norm_eps)
-        xa = x if hy is None else x * hy.attention_in_multiplier
-
-        def project(w, heads, norm=None):
-            y = column_parallel(xa, lp[w].astype(cdt))
-            if cfg.qk_norm and norm:
-                y = _qk_norm(cfg, y, lp[norm], comm)
-            return y.reshape(B, 1, heads, hd)
-
-        q, k, v = (project("wq", hl, "qn"), project("wk", hkv, "kn"),
-                   project("wv", hkv))
-        if hy is not None:
-            k = k * hy.key_multiplier
-        q = _rope(q, pos[None], theta=cfg.rope_theta)
-        k = _rope(k, pos[None], theta=cfg.rope_theta)
-    with scope("kv_cache"):
-        kc = lax.dynamic_update_slice(kc, k.astype(kc.dtype)[None],
-                                      (layer, 0, pos, 0, 0))
-        vc = lax.dynamic_update_slice(vc, v.astype(vc.dtype)[None],
-                                      (layer, 0, pos, 0, 0))
+    B, hl, hd = q.shape[0], q.shape[2], q.shape[3]
+    Tmax, hkv = kc.shape[2], kc.shape[3]
     with scope("attention"):
         # scores against every cached position, masked beyond `pos`
         k_all = lax.dynamic_index_in_dim(kc, layer, keepdims=False)
@@ -135,6 +114,98 @@ def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, layer, pos,
             w = jax.nn.softmax(s, axis=-1)
             o = jnp.einsum("bgrqk,bkgd->bqgrd", w,
                            v_all.astype(jnp.float32))
+    return o
+
+
+def _attend_selection(cfg, lp, x, q, k, v, kvc, ic, layer, pos):
+    """The indexed block's cached attention of ONE new position: from the
+    block's normed input x (B, 1, D) and its rotated q (B, 1, H, hd) and k,
+    v (B, 1, Hkv, hd), against kvc (L, B, Tmax, 2 Hkv, hd), a position's K
+    heads and then its V heads in one row, and the index keys ic (L, B,
+    width, Tmax).  Writes the row and the index key at ``pos``, then reads
+    the ``topk`` selected rows alone (every row where the cache is no longer
+    than ``topk``).  Returns (context float32, kvc, ic)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ompi_tpu.core.scopes import scope
+    from ompi_tpu.models import sparse_index
+
+    Tmax, hkv = kvc.shape[2], kvc.shape[3] // 2
+    qi, ki, wi = sparse_index.project(cfg, lp, x, pos[None])
+    with scope("kv_cache"):
+        kvc = lax.dynamic_update_slice(
+            kvc, jnp.concatenate([k, v], axis=2).astype(kvc.dtype)[None],
+            (layer, 0, pos, 0, 0))
+        ic = sparse_index.positions_minor(lax.dynamic_update_slice(
+            ic, ki.swapaxes(1, 2).astype(ic.dtype)[None], (layer, 0, 0, pos)))
+    if cfg.index.topk < Tmax:
+        o = sparse_index.attend_cached(cfg, q, kvc, ic, qi, wi, layer, pos)
+    else:
+        o = _attend_whole_cache(q, kvc[..., :hkv, :], kvc[..., hkv:, :],
+                                layer, pos)
+    return o, kvc, ic
+
+
+def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, layer, pos,
+                states=()):
+    """Layer ``layer`` for ONE new token position, against the whole cache.
+
+    h: (B, 1, D); kc/vc: the stacked cache (L, B, Tmax, Hkv/tp, hd), or with
+    an index kc alone, a position's K and V heads in one row (L, B, Tmax,
+    2 Hkv/tp, hd), and vc ``None``; lp:
+    this layer's parameters, but for the dropless experts' leaves
+    (``moe.EXPERT_LEAVES``), which are the whole stacks over layers that
+    ``routed_moe`` indexes by ``layer``.  ``states``: with a hybrid block
+    the mixer's two stacks, the convolution's last inputs ``(L, B,
+    d_conv - 1, conv_dim)`` and the heads' states ``(L, B, H, P, N)``; with
+    an index its keys' stack ``(L, B, width, Tmax)``.
+    Returns (h, kc, vc, *states) with the new token's k/v written in place
+    at ``(layer, :, pos)``, its index key at ``(layer, :, :, pos)`` and the
+    layer's states at ``(layer,)``.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ompi_tpu.core.scopes import scope
+    from ompi_tpu.parallel.layers import column_parallel, row_parallel
+
+    cdt = h.dtype
+    B = h.shape[0]
+    hkv, hd = kc.shape[3:]
+    hy, ix = cfg.hybrid, cfg.index
+    if ix is not None:
+        hkv //= 2       # a row of kc holds the K heads and then the V heads
+    hl = hkv * (cfg.n_heads // cfg.kv_heads)
+
+    with scope("attn_proj"):
+        x = _rmsnorm(h, lp["ln1"], cfg.norm_eps)
+        xa = x if hy is None else x * hy.attention_in_multiplier
+
+        def project(w, heads, norm=None):
+            y = column_parallel(xa, lp[w].astype(cdt))
+            if cfg.qk_norm and norm:
+                y = _qk_norm(cfg, y, lp[norm], comm)
+            return y.reshape(B, 1, heads, hd)
+
+        q, k, v = (project("wq", hl, "qn"), project("wk", hkv, "kn"),
+                   project("wv", hkv))
+        if hy is not None:
+            k = k * hy.key_multiplier
+        q = _rope(q, pos[None], theta=cfg.rope_theta)
+        k = _rope(k, pos[None], theta=cfg.rope_theta)
+    if ix is None:
+        with scope("kv_cache"):
+            kc = lax.dynamic_update_slice(kc, k.astype(kc.dtype)[None],
+                                          (layer, 0, pos, 0, 0))
+            vc = lax.dynamic_update_slice(vc, v.astype(vc.dtype)[None],
+                                          (layer, 0, pos, 0, 0))
+        o = _attend_whole_cache(q, kc, vc, layer, pos)
+    else:
+        o, kc, ic = _attend_selection(cfg, lp, x, q, k, v, kc, states[0],
+                                      layer, pos)
+        states = (ic,)
     with scope("attn_proj"):
         o = o.astype(cdt).reshape(B, 1, hl * hd)
         a = row_parallel(o, lp["wo"].astype(cdt), comm, axis="tp")
@@ -173,17 +244,20 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
     Greedy decode by default: prefill through the training backbone
     (K/V collected per layer), then ``max_new`` single-token
     steps over the static cache.  Requires sp == 1; dense, switch-MoE,
-    dropless top-k MoE and hybrid (``models/ssm.py``) configs are
-    supported (MoE routes each token through the same layer as training).
+    dropless top-k MoE, hybrid (``models/ssm.py``) and indexed
+    (``models/sparse_index.py``) configs are supported (MoE routes each
+    token through the same layer as training).
 
     The carry of the token scan and of the loop over layers inside it:
-    K and V ``(L, B, Tp+max_new, Hkv/tp, hd)`` in the compute dtype and,
+    K and V ``(L, B, Tp+max_new, Hkv/tp, hd)`` in the compute dtype (with an
+    index one stack of both, a position's K and V heads in one row) and,
     with a hybrid block, the mixer's two states beside them, stacked over
     layers alike: the convolution's last inputs ``(L, B, d_conv - 1,
     conv_dim)`` and the heads' states ``(L, B, H, P, N)`` in the block's
-    ``state_dtype``.  The prefill hands over each layer's K/V and its
-    states after the last prompt position.  It runs in one pass and pads
-    its K/V to the cache's length; with ``cfg.prefill_tokens``, or a hybrid
+    ``state_dtype``; with an index, its keys ``(L, B, width, Tp+max_new)``
+    in the compute dtype.  The prefill hands over each layer's K/V, its
+    index keys, and its states after the last prompt position.  It runs in
+    one pass and pads its K/V to the cache's length; with ``cfg.prefill_tokens``, or a hybrid
     block, it runs a group of whole sequences at a time, each group
     writing its K/V and states into the carry allocated once at its final
     size (one group where ``prefill_tokens`` is 0).
@@ -245,6 +319,10 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
         from ompi_tpu.models import ssm
 
         ssm.check_mesh(cfg, mesh)
+    if cfg.index is not None:
+        from ompi_tpu.models import sparse_index
+
+        sparse_index.check_mesh(cfg, mesh)
     axes = tuple(a for a in ("dp", "sp", "tp", "ep")
                  if a in mesh.axis_names)
     comm = DeviceCommunicator(mesh, axes)
@@ -273,22 +351,29 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
 
     def prefill_in_groups(params, prompt):
         """The carry, filled a group of sequences at a time: (last hidden
-        states (B, D), kc, vc, *states)."""
+        states (B, D), kc, vc, *states); with an index kc holds K and V and
+        vc is ``None``."""
         B, Tp = prompt.shape
         group = _prefill_group(B, Tp, cfg.prefill_tokens)
         kv = (cfg.n_layers, B, Tp + max_new,
               cfg.kv_heads // int(mesh.shape["tp"]), cfg.head_dim)
-        stacks = [jnp.zeros(kv, cdt), jnp.zeros(kv, cdt)]
+        ix = cfg.index
+        stacks = ([jnp.zeros(kv, cdt), jnp.zeros(kv, cdt)] if ix is None else
+                  [jnp.zeros((*kv[:3], 2 * kv[3], kv[4]), cdt)])
         if hy is not None:
             stacks += [jnp.zeros((cfg.n_layers, *shape), dtype)
                        for shape, dtype in zip(ssm.state_shapes(cfg, B),
                                                (cdt, hy.state_dtype))]
+        if ix is not None:
+            stacks.append(jnp.zeros((*kv[:2], ix.head_dim, kv[2]), cdt))
 
         def one(g, carry):
             last, *stacks = carry
             rows = lax.dynamic_slice_in_dim(prompt, g * group, group)
             h, (_aux, *cached) = tfm._local_backbone(
-                cfg, comm, params, rows, collect_kv=True)
+                cfg, comm, params, rows, collect_kv=True, forward_only=True)
+            if ix is not None:
+                cached[:2] = [jnp.concatenate(cached[:2], axis=3)]
             stacks = [lax.dynamic_update_slice(
                 stack, new.astype(stack.dtype),
                 (0, g * group) + (0,) * (stack.ndim - 2))
@@ -296,8 +381,9 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
             return (lax.dynamic_update_slice(last, h[:, -1, :],
                                              (g * group, 0)), *stacks)
 
-        return lax.fori_loop(
+        last, kc, *stacks = lax.fori_loop(
             0, B // group, one, (jnp.zeros((B, cfg.d_model), cdt), *stacks))
+        return (last, kc, *stacks) if ix is None else (last, kc, None, *stacks)
 
     def local(params, prompt, seed):
         B, Tp = prompt.shape
@@ -307,12 +393,19 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
         # ---- prefill: the training backbone, K/V collected ----
         with scope("prefill"):
             if hy is None and not cfg.prefill_tokens:
-                h, (_aux, ks, vs) = tfm._local_backbone(
-                    cfg, comm, params, prompt, collect_kv=True)
+                h, (_aux, ks, vs, *states) = tfm._local_backbone(
+                    cfg, comm, params, prompt, collect_kv=True,
+                    forward_only=True)
                 pad = [(0, 0), (0, 0), (0, max_new), (0, 0), (0, 0)]
-                kc = jnp.pad(ks, pad)       # (L, B, Tp+max_new, Hl, hd)
-                vc = jnp.pad(vs, pad)
-                last, states = h[:, -1, :], ()
+                if cfg.index is None:
+                    kc = jnp.pad(ks, pad)   # (L, B, Tp+max_new, Hl, hd)
+                    vc = jnp.pad(vs, pad)
+                else:   # one row a position; the index's keys beside it
+                    kc = jnp.pad(jnp.concatenate([ks, vs], axis=3), pad)
+                    vc = None
+                    states = [jnp.pad(ki, [(0, 0)] * 3 + [(0, max_new)])
+                              for ki in states]
+                last = h[:, -1, :]
             else:
                 last, kc, vc, *states = prefill_in_groups(params, prompt)
             logits = tfm._whole_vocab(cfg, jnp.einsum(

@@ -63,9 +63,14 @@ class TransformerConfig:
     # Gated experts: down(silu(gate(x)) * up(x)) with a third leaf "w3"
     # (up) beside "w1" (gate) and "w2" (down), instead of w2(gelu(w1 x)).
     moe_gated: bool = False
-    # RMSNorm of q and k over their whole projected width, before the
-    # split into heads and the rotary embedding (leaves "qn", "kn").
-    qk_norm: bool = False
+    # The dropless path's top-k weights divided by their sum (a token's
+    # experts then weigh one together); False: the probabilities as they are.
+    moe_norm_topk: bool = False
+    # RMSNorm of q and k before the rotary embedding (leaves "qn", "kn").
+    # True: over the whole projected width, before the split into heads,
+    # with a scale as wide as the projection.  "head": over each head's
+    # width on its own, with one scale of a head's width for q, one for k.
+    qk_norm: Any = False
     norm_eps: float = 1e-6
     # False: the output projection is a leaf of its own, "head" (V, D),
     # and "emb" is a lookup table only.
@@ -127,6 +132,10 @@ class TransformerConfig:
     # "w3": down(silu(gate(x)) * up(x))), and the block's constant
     # multipliers apply.  None: the block above.
     hybrid: Any = None
+    # models/sparse_index.SparseIndex: a learned index picks the ``topk``
+    # positions each query attends to, and a decoder carries the index's
+    # keys beside K and V.  None: attention over every earlier position.
+    index: Any = None
     # The most tokens one pass of a decoder's prefill holds: the prompts are
     # then prefilled a group of whole sequences at a time, each group writing
     # into the cache that was allocated once.  0: every prompt in one pass.
@@ -172,8 +181,11 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
         "lnf": np.ones((D,), np.float32),
     }
     if cfg.qk_norm:
-        params["qn"] = np.ones((L, D), np.float32)
-        params["kn"] = np.ones((L, D), np.float32)
+        per_head = cfg.qk_norm == "head"
+        params["qn"] = np.ones((L, cfg.head_dim if per_head else Dq),
+                               np.float32)
+        params["kn"] = np.ones((L, cfg.head_dim if per_head else Dkv),
+                               np.float32)
     if not cfg.tie_head:
         params["head"] = w(V, D, scale=0.02)
     if cfg.moe_experts:
@@ -191,6 +203,10 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
         from ompi_tpu.models import ssm
 
         params.update(ssm.init_leaves(cfg, rng))
+    if cfg.index is not None:
+        from ompi_tpu.models import sparse_index
+
+        params.update(sparse_index.init_leaves(cfg, rng))
     if cfg.param_dtype not in (None, "float32"):
         # live params are stored in param_dtype; the optimizer's f32
         # master copy is created from them at init (one-time rounding)
@@ -237,7 +253,9 @@ def param_specs(P, cfg: Optional[TransformerConfig] = None, mesh=None):
         "wv": P(None, None, "tp"), "wo": P(None, "tp", None),
     }
     if cfg is not None and cfg.qk_norm:
-        specs["qn"] = specs["kn"] = P(None, "tp")
+        # a head's scale serves every head; a projection's splits with it
+        specs["qn"] = specs["kn"] = (P() if cfg.qk_norm == "head"
+                                     else P(None, "tp"))
     if cfg is not None and not cfg.tie_head:
         specs["head"] = table
     if cfg is not None and cfg.moe_experts:
@@ -254,6 +272,10 @@ def param_specs(P, cfg: Optional[TransformerConfig] = None, mesh=None):
         from ompi_tpu.models import ssm
 
         specs.update({leaf: P() for leaf in ssm.leaf_names()})
+    if cfg is not None and cfg.index is not None:
+        from ompi_tpu.models import sparse_index
+
+        specs.update({leaf: P() for leaf in sparse_index.leaf_names()})
     return specs
 
 
@@ -281,20 +303,28 @@ def _rmsnorm(x, scale, eps: float = 1e-6):
 
 
 def _qk_norm(cfg, x, scale, comm):
-    """RMSNorm of a projected q or k over its whole width ``d_model``, of
-    which this device holds the ``tp`` shard of its heads: the squares are
+    """RMSNorm of a projected q or k (..., heads x head width), of which
+    this device holds the ``tp`` shard of the heads.  ``cfg.qk_norm ==
+    "head"``: over each head's width on its own, ``scale`` one head wide;
+    nothing crosses ``tp``.  Otherwise over the projection's whole width,
+    which is this device's times ``tp`` (the query heads' for q, the K/V
+    heads' for k: not ``d_model`` where either is narrower): the squares are
     summed over ``tp`` before the root."""
     import jax.numpy as jnp
     from jax import lax
 
     from ompi_tpu.core.scopes import coll
 
-    if int(comm.mesh.shape["tp"]) == 1:
+    if cfg.qk_norm == "head":
+        heads = x.reshape(*x.shape[:-1], -1, cfg.head_dim)
+        return _rmsnorm(heads, scale, cfg.norm_eps).reshape(x.shape)
+    tp = int(comm.mesh.shape["tp"])
+    if tp == 1:
         return _rmsnorm(x, scale, cfg.norm_eps)
     xf = x.astype(jnp.float32)
     with coll("allreduce", "tp"):
         total = lax.psum(jnp.sum(xf * xf, axis=-1, keepdims=True), "tp")
-    norm = xf * lax.rsqrt(total / cfg.d_model + cfg.norm_eps)
+    norm = xf * lax.rsqrt(total / (x.shape[-1] * tp) + cfg.norm_eps)
     return (norm * scale).astype(x.dtype)
 
 
@@ -313,6 +343,10 @@ def layer_leaves(cfg: TransformerConfig) -> tuple:
         from ompi_tpu.models import ssm
 
         leaves += ssm.leaf_names()
+    if cfg.index is not None:
+        from ompi_tpu.models import sparse_index
+
+        leaves += sparse_index.leaf_names()
     return tuple(leaves)
 
 
@@ -534,7 +568,8 @@ def _moe_ffn_tail(cfg, h, lp, comm, layer=None):
             # described for a compile); XLA's ragged_dot on any other
             mo = routed_moe(x, weights, cfg.moe_top_k, gated=cfg.moe_gated,
                             layer=layer, kernel=comm.mesh.devices.flat[
-                                0].platform == "tpu")
+                                0].platform == "tpu",
+                            renorm=cfg.moe_norm_topk)
             return h + mo, jnp.zeros((), jnp.float32)
         mo, aux = switch_moe(
             comm, x, {"wg": lp["wg"], "w1": lp["w1"], "w2": lp["w2"]},
@@ -577,7 +612,8 @@ def _dense_ffn_tail(h, lp, comm, cdt, eps: float = 1e-6, gated=None,
 
 
 def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
-                    collect_kv: bool = False, grad_axes=None):
+                    collect_kv: bool = False, grad_axes=None,
+                    forward_only: bool = False):
     """Per-device forward through the final rmsnorm (everything except the
     unembed matmul).
 
@@ -585,7 +621,9 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     aux) — aux is the summed MoE load-balancing loss (0.0 for dense).
     With ``collect_kv`` returns (h, (aux, k, v)) where k/v are the
     post-rope per-layer attention inputs stacked (L, B, T, Hkv/tp, hd) —
-    the KV-cache prefill (models/decode.py); with a hybrid block
+    the KV-cache prefill (models/decode.py); with an index
+    (h, (aux, k, v, ki)): every layer's index keys (L, B, width, T) too,
+    positions last as the carry holds them (models/sparse_index.py); with a hybrid block
     (h, (aux, k, v, conv, ssm)): every layer's mixer states after the last
     position too, stacked alike, the second in the block's ``state_dtype``.
     With that block h comes scaled by its ``lm_head_multiplier``.
@@ -593,7 +631,9 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     gradient of a layer's leaves is summed over those axes in that layer's
     backward, inside the loop: a projection's or the MLP's matrix where
     the backward of its matmul is, the other leaves' where the layer's
-    backward ends (:func:`_sum_in_backward`).
+    backward ends (:func:`_sum_in_backward`).  ``forward_only`` (a
+    decoder's prefill): no gradient will be asked of this pass, so it may
+    take a kernel that has no backward pass (an index's masked attention).
     """
     import jax
     import jax.numpy as jnp
@@ -611,6 +651,10 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
         from ompi_tpu.models import ssm
 
         ssm.check_mesh(cfg, comm.mesh)
+    if cfg.index is not None:
+        from ompi_tpu.models import sparse_index
+
+        sparse_index.check_mesh(cfg, comm.mesh)
     h_local, kv_local = cfg.n_heads // tp, cfg.kv_heads // tp
     hd = cfg.head_dim
     T = tokens.shape[1]
@@ -663,11 +707,20 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
                       cfg.rope_theta)
             v = v.reshape(B, t, kv_local, hd)
             k_all, v_all = k, v
-            if kv_local != h_local:     # each K/V head before its queries
+            if kv_local != h_local and cfg.index is None:
+                # each K/V head before its queries
                 k_all, v_all = (jnp.repeat(y, h_local // kv_local, axis=2)
                                 for y in (k, v))
-        with scope("attention"):
-            o = attend(comm, q, k_all, v_all, axis="sp", impl=impl)
+        if cfg.index is not None:
+            # a slice of queries at a time, each query over its own
+            # selection; K/V heads are read once for their query heads
+            # (the kernel has no backward pass and compiles for the TPU)
+            o, ki = sparse_index.attend(
+                cfg, lp, x, q, k, v, positions, kernel=forward_only
+                and comm.mesh.devices.flat[0].platform == "tpu")
+        else:
+            with scope("attention"):
+                o = attend(comm, q, k_all, v_all, axis="sp", impl=impl)
         with scope("attn_proj"):
             o, w = weights(o.reshape(B, t, h_local * hd), "wo")
             a = row_parallel(o, w["wo"].astype(cdt), comm, axis="tp")
@@ -691,6 +744,8 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
         if collect_kv and hy is not None:
             return h, (aux, k, v, states[0],
                        states[1].astype(hy.state_dtype))
+        if collect_kv and cfg.index is not None:
+            return h, (aux, k, v, ki)
         if collect_kv:
             return h, (aux, k, v)
         return h, aux

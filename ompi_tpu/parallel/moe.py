@@ -156,7 +156,7 @@ def switch_moe(comm, x, params, axis: str = "ep",
 
 
 def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
-               kernel: bool = False):
+               kernel: bool = False, renorm: bool = False):
     """Dropless top-k MoE layer, every expert on this device: x (B, T, D)
     local tokens → (B, T, D).
 
@@ -171,8 +171,9 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
 
     Routing (all shapes static, no capacity, no token dropped): the
     router's logits, softmax and top-k in float32; each token keeps its
-    ``top_k`` most probable experts with their probabilities as they are
-    (not renormalised).  The ``tokens × top_k`` assignments are sorted by
+    ``top_k`` most probable experts with their probabilities as they are,
+    or with ``renorm`` divided by their sum, so that a token's experts weigh
+    one together.  The ``tokens × top_k`` assignments are sorted by
     expert (stable) and laid out in row tiles of ``tm`` rows, every
     expert's run starting at a tile boundary, so a tile's rows all go to
     one expert and the experts run as ``ops.grouped_matmul`` over the
@@ -209,6 +210,8 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
                             params["wg"].astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
         gate, expert = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        if renorm:
+            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
     with scope("moe.dispatch"):
         # assignments sorted by expert; ``order``: sorted row -> assignment
         sorted_group, order = lax.sort(
