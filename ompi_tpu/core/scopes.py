@@ -62,8 +62,8 @@ SCOPES = (
     "attention.ring",   # ... K/V blocks around the sp ring
     "attention.ulysses",    # ... resharded seq -> heads by all_to_all
     "attention.flash",  # ... the pallas kernels
-    "attention.gather",     # a cached step's selected K/V rows read out of
-                            # the carry (models/sparse_index.py)
+    "attention.gather",     # a cached step's selected K/V rows gathered out
+                            # of the carry (nothing where it streams them)
     "ffn",              # ln2, the MLP, the residual
     "moe.route", "moe.dispatch", "moe.experts", "moe.combine",
     "ssm_proj",         # the mixer's scalings, in/out projections, gated norm

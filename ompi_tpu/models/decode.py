@@ -36,13 +36,21 @@ stack more that does grow with the sequence, the index's keys ``(L, B,
 width, Tp+max_new)``, positions last, written at ``pos`` beside K and V by
 the prefill and by every step.  A step scores the new position's index
 queries against the layer's index keys, takes the ``topk`` positions of the
-largest scores, and reads K and V of those positions out of the carry: the
-layer's whole K and V are the operand of one gather and of nothing else.
-One gather, because with an index a position's K and V are one row of one
-stack, ``(L, B, Tp+max_new, 2 Hkv/tp, hd)``, the K heads and then the V
-heads, and there is no second stack (``vc`` is ``None``): a gather on this
-chip costs its 15 ns a row whether the row is 1 KB or 2, so two stacks
-would take twice as long to read the same bytes (PERF.md section 5).
+largest scores, and attends to those.  With an index a position's K and V
+are one row of one stack, the K heads and then the V heads, and there is no
+second stack (``vc`` is ``None``).  How a step reads the rows it selected is
+decided once a program, from its static sizes
+(``sparse_index.streams``), and the carry is laid out for it.  **The
+gather**, ``(L, B, Tp+max_new, 2 Hkv/tp, hd)``: the layer's whole K and V
+are the operand of one gather of ``topk`` rows and of nothing else; one
+gather, because a gather on this chip costs its 15 ns a row whether the row
+is 1 KB or 2, so two stacks would take twice as long to read the same bytes.
+**The stream**, rows flat, ``(L, B, Tp+max_new, 2 Hkv/tp · hd)``: where the
+cache is no more than eight selections long, on a mesh of TPUs and at sizes
+that tile, the layer's rows pass once through the pallas kernel
+``ops/selected_attention`` under the selection's mask, at the HBM's rate,
+which a gather of a quarter of them does not reach (PERF.md section 5).
+CPU meshes, tiny sizes and long caches gather.
 
 The prefill hands the carry over.  By default it is one pass over every
 prompt whose K/V are padded to the cache's length.  With
@@ -120,28 +128,32 @@ def _attend_whole_cache(q, kc, vc, layer, pos):
 def _attend_selection(cfg, lp, x, q, k, v, kvc, ic, layer, pos):
     """The indexed block's cached attention of ONE new position: from the
     block's normed input x (B, 1, D) and its rotated q (B, 1, H, hd) and k,
-    v (B, 1, Hkv, hd), against kvc (L, B, Tmax, 2 Hkv, hd), a position's K
-    heads and then its V heads in one row, and the index keys ic (L, B,
-    width, Tmax).  Writes the row and the index key at ``pos``, then reads
-    the ``topk`` selected rows alone (every row where the cache is no longer
-    than ``topk``).  Returns (context float32, kvc, ic)."""
+    v (B, 1, Hkv, hd), against kvc, a position's K heads and then its V
+    heads in one row, (L, B, Tmax, 2 Hkv, hd) or, where the program streams
+    (``sparse_index.streams``), flat, (L, B, Tmax, 2 Hkv hd), and the index
+    keys ic (L, B, width, Tmax).  Writes the row and the index key at
+    ``pos``, then reads the ``topk`` selected rows alone, by a gather, or
+    the layer's rows once under the selection's mask, by the kernel
+    (``sparse_index.attend_cached``); every row where the cache is no
+    longer than ``topk``.  Returns (context float32, kvc, ic)."""
     import jax.numpy as jnp
     from jax import lax
 
     from ompi_tpu.core.scopes import scope
     from ompi_tpu.models import sparse_index
 
-    Tmax, hkv = kvc.shape[2], kvc.shape[3] // 2
+    Tmax, hkv = kvc.shape[2], k.shape[2]
     qi, ki, wi = sparse_index.project(cfg, lp, x, pos[None])
     with scope("kv_cache"):
+        row = jnp.concatenate([k, v], axis=2).astype(kvc.dtype)
         kvc = lax.dynamic_update_slice(
-            kvc, jnp.concatenate([k, v], axis=2).astype(kvc.dtype)[None],
-            (layer, 0, pos, 0, 0))
+            kvc, row.reshape(1, *row.shape[:2], *kvc.shape[3:]),
+            (layer, 0, pos) + (0,) * (kvc.ndim - 3))
         ic = sparse_index.positions_minor(lax.dynamic_update_slice(
             ic, ki.swapaxes(1, 2).astype(ic.dtype)[None], (layer, 0, 0, pos)))
     if cfg.index.topk < Tmax:
         o = sparse_index.attend_cached(cfg, q, kvc, ic, qi, wi, layer, pos)
-    else:
+    else:       # never flat: a cache within its selection does not stream
         o = _attend_whole_cache(q, kvc[..., :hkv, :], kvc[..., hkv:, :],
                                 layer, pos)
     return o, kvc, ic
@@ -153,7 +165,8 @@ def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, layer, pos,
 
     h: (B, 1, D); kc/vc: the stacked cache (L, B, Tmax, Hkv/tp, hd), or with
     an index kc alone, a position's K and V heads in one row (L, B, Tmax,
-    2 Hkv/tp, hd), and vc ``None``; lp:
+    2 Hkv/tp, hd), or that row flat where the step streams it, and vc
+    ``None``; lp:
     this layer's parameters, but for the dropless experts' leaves
     (``moe.EXPERT_LEAVES``), which are the whole stacks over layers that
     ``routed_moe`` indexes by ``layer``.  ``states``: with a hybrid block
@@ -164,6 +177,8 @@ def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, layer, pos,
     at ``(layer, :, pos)``, its index key at ``(layer, :, :, pos)`` and the
     layer's states at ``(layer,)``.
     """
+    import math
+
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -173,7 +188,8 @@ def _step_layer(cfg: TransformerConfig, comm, lp, h, kc, vc, layer, pos,
 
     cdt = h.dtype
     B = h.shape[0]
-    hkv, hd = kc.shape[3:]
+    hd = cfg.head_dim
+    hkv = math.prod(kc.shape[3:]) // hd
     hy, ix = cfg.hybrid, cfg.index
     if ix is not None:
         hkv //= 2       # a row of kc holds the K heads and then the V heads
@@ -250,8 +266,13 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
 
     The carry of the token scan and of the loop over layers inside it:
     K and V ``(L, B, Tp+max_new, Hkv/tp, hd)`` in the compute dtype (with an
-    index one stack of both, a position's K and V heads in one row) and,
-    with a hybrid block, the mixer's two states beside them, stacked over
+    index one stack of both, a position's K and V heads in one row, ``(L,
+    B, Tp+max_new, 2 Hkv/tp, hd)`` where a cached step gathers the rows it
+    selected and flat, ``(L, B, Tp+max_new, 2 Hkv/tp · hd)``, where it
+    streams the layer's rows under the selection's mask:
+    ``sparse_index.streams`` says which from the mesh's platform and the
+    program's static sizes, and no argument chooses) and, with a hybrid
+    block, the mixer's two states beside them, stacked over
     layers alike: the convolution's last inputs ``(L, B, d_conv - 1,
     conv_dim)`` and the heads' states ``(L, B, H, P, N)`` in the block's
     ``state_dtype``; with an index, its keys ``(L, B, width, Tp+max_new)``
@@ -349,6 +370,23 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
         return jax.random.categorical(key, scaled,
                                       axis=-1).astype(jnp.int32)
 
+    def row_shape(t_max, heads):
+        """With an index: what a carry of ``t_max`` positions holds of one
+        position, its ``heads`` K heads and then its V heads in one row:
+        ``(2 heads, hd)``, and the row flat, ``(2 heads hd,)``, where the
+        program's cached steps stream it (``sparse_index.streams``: static,
+        so all of a program's steps or none)."""
+        if sparse_index.streams(cfg.index, t_max, cfg.head_dim,
+                                mesh.devices.flat[0].platform == "tpu"):
+            return (2 * heads * cfg.head_dim,)
+        return (2 * heads, cfg.head_dim)
+
+    def in_rows(ks, vs, t_max):
+        """The prefill's K and V, (L, B, T, Hkv/tp, hd) each, as the rows of
+        a carry of ``t_max`` positions."""
+        return jnp.concatenate([ks, vs], axis=3).reshape(
+            *ks.shape[:3], *row_shape(t_max, ks.shape[3]))
+
     def prefill_in_groups(params, prompt):
         """The carry, filled a group of sequences at a time: (last hidden
         states (B, D), kc, vc, *states); with an index kc holds K and V and
@@ -359,7 +397,7 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
               cfg.kv_heads // int(mesh.shape["tp"]), cfg.head_dim)
         ix = cfg.index
         stacks = ([jnp.zeros(kv, cdt), jnp.zeros(kv, cdt)] if ix is None else
-                  [jnp.zeros((*kv[:3], 2 * kv[3], kv[4]), cdt)])
+                  [jnp.zeros((*kv[:3], *row_shape(*kv[2:4])), cdt)])
         if hy is not None:
             stacks += [jnp.zeros((cfg.n_layers, *shape), dtype)
                        for shape, dtype in zip(ssm.state_shapes(cfg, B),
@@ -373,7 +411,7 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
             h, (_aux, *cached) = tfm._local_backbone(
                 cfg, comm, params, rows, collect_kv=True, forward_only=True)
             if ix is not None:
-                cached[:2] = [jnp.concatenate(cached[:2], axis=3)]
+                cached[:2] = [in_rows(*cached[:2], kv[2])]
             stacks = [lax.dynamic_update_slice(
                 stack, new.astype(stack.dtype),
                 (0, g * group) + (0,) * (stack.ndim - 2))
@@ -401,7 +439,8 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
                     kc = jnp.pad(ks, pad)   # (L, B, Tp+max_new, Hl, hd)
                     vc = jnp.pad(vs, pad)
                 else:   # one row a position; the index's keys beside it
-                    kc = jnp.pad(jnp.concatenate([ks, vs], axis=3), pad)
+                    kc = in_rows(ks, vs, Tp + max_new)
+                    kc = jnp.pad(kc, pad[:kc.ndim])
                     vc = None
                     states = [jnp.pad(ki, [(0, 0)] * 3 + [(0, max_new)])
                               for ki in states]

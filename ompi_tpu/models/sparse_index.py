@@ -21,9 +21,17 @@ whole sequence; the set is found as a threshold, the ``topk``-th largest
 score of a row by bisection on the scores' bits (:func:`select`; exact, ties
 included), and applied as a mask.  **One position against the carried
 keys** (``models/decode.py``): :func:`attend_cached`, the scores against the
-layer's index keys in the carry, ``lax.top_k``, and the selected positions'
-rows gathered out of the carry, in which a position's K and V are one row: a
-step reads ``topk`` rows of the layer's K/V, not the layer's K/V.  The index
+layer's index keys in the carry, then one of two reads of the same set, in a
+carry in which a position's K and V are one row.  **The gather:**
+``lax.top_k`` and the selected positions' rows gathered out of the carry: a
+step reads ``topk`` rows of the layer's K/V, not the layer's K/V.  **The
+stream:** the set as a mask (:func:`select`, as the prefill finds it) and the
+layer's rows streamed once under it by the pallas kernel
+``ops/selected_attention.py``.  A gathered row costs this chip eight times
+what a streamed one does, so a step streams where its cache is no more than
+``_STREAM_UP_TO`` times its selection, the mesh is of TPUs and the sizes
+tile (:func:`streams`, which the decoder asks before it lays the carry out:
+a streamed carry holds a row flat, ``(L, B, Tmax, 2 Hkv hd)``).  The index
 keys are carried with the positions last, ``(L, B, width, Tmax)``, and held
 to that layout (:func:`positions_minor`): a step's scores are then one
 product of the layer's slice as it lies, where the compiler, left to itself,
@@ -32,9 +40,10 @@ every step (PERF.md section 6).  The selection passes no gradient (it is a
 set); the index's alignment loss is not built, so a trainer leaves its
 leaves where they were.
 
-Everything here is ``jax.numpy`` and ``lax`` but the prefill's attention
-under the mask, which may be the pallas kernel ``ops/masked_attention.py``
-(:func:`attend` says when).  Nothing imports this module but a configuration
+Everything here is ``jax.numpy`` and ``lax`` but attention under a mask,
+which may be a pallas kernel: ``ops/masked_attention.py`` in the prefill
+(:func:`attend` says when), ``ops/selected_attention.py`` in a cached step
+(:func:`streams`).  Nothing imports this module but a configuration
 that has an index, so the other programs' set-up does not pay for it.
 """
 
@@ -45,8 +54,17 @@ import dataclasses
 import numpy as np
 
 __all__ = ["SparseIndex", "sparse_config", "project", "scores", "select",
-           "attend", "attend_cached", "positions_minor", "init_leaves",
-           "leaf_names", "check_mesh"]
+           "attend", "attend_cached", "streams", "positions_minor",
+           "init_leaves", "leaf_names", "check_mesh"]
+
+# A cached step streams the layer's K/V under a mask where its cache is at
+# most this many times its selection, and gathers the selected rows beyond.
+# One layer of cell 6 on the chip (64 sequences, 8192 positions, rows of
+# 2 KB; ms with the selection; PERF.md section 6, PR 43): the stream 1.65
+# whatever the selection (0.21 of it ``select``); the gather with its
+# ``lax.top_k`` and its two products 5.61 at topk 4096, 2.97 at 2048, 1.66
+# at 1024, 0.72 at 512.  They meet at topk 1024: a cache of 8 selections.
+_STREAM_UP_TO = 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -285,25 +303,58 @@ def positions_minor(ic):
         ic, Layout(major_to_minor=tuple(range(ic.ndim))))
 
 
+def streams(ix: SparseIndex, t_max: int, head_dim: int, tpu: bool) -> bool:
+    """True where a cached step against ``t_max`` carried positions streams
+    the layer's K/V under the selection's mask (``ops/selected_attention``)
+    and does not gather the selected rows: on a mesh of TPUs (``tpu``:
+    attached, or described for a compile; the kernel compiles for nothing
+    else), where the sizes tile for the kernel, and where the cache is longer
+    than the selection by no more than ``_STREAM_UP_TO`` times.  All static:
+    a program streams in every step or in none, and the decoder lays the
+    carry's rows out flat for it."""
+    from ompi_tpu.ops.selected_attention import tiles
+
+    return (tpu and tiles(t_max, head_dim)
+            and ix.topk < t_max <= _STREAM_UP_TO * ix.topk)
+
+
 def attend_cached(cfg, q, kvc, ic, qi, wi, layer, pos):
     """One new position's attention against the carry: q (B, 1, H, hd); kvc
     (L, B, Tmax, 2 Hkv, hd), a position's K heads and then its V heads in
-    one row, and ic (L, B, width, Tmax), with the position's own k, v and
-    index key already written at ``pos``; qi, wi its index queries and head
-    weights (:func:`project`).  Scores against the layer's index keys up to
-    ``pos``, the ``topk`` largest, the rows of those positions read out of
-    the carry, attention over them: (B, 1, H, hd) float32.  The layer's
-    whole K and V are the operand of the one gather alone."""
+    one row, or the same rows flat, (L, B, Tmax, 2 Hkv hd), where the decoder
+    found that this program :func:`streams`; and ic (L, B, width, Tmax), with
+    the position's own k, v and index key already written at ``pos``; qi, wi
+    its index queries and head weights (:func:`project`).  Scores against
+    the layer's index keys up to ``pos``, the ``topk`` largest, attention
+    over those positions: (B, 1, H, hd) float32.
+
+    Over the 5-D carry the set is ``lax.top_k``'s indices and its rows are
+    read out of the carry by one gather, of which the layer's whole K and V
+    are the operand and of nothing else.  Over the flat carry the set is a
+    mask (:func:`select`: the same set, ties included) and the layer's rows
+    pass once through ``ops/selected_attention``, the whole stack its
+    operand and the layer a scalar: no gather, and nothing under
+    ``attention.gather``."""
     import jax.numpy as jnp
     from jax import lax
 
     from ompi_tpu.core.scopes import scope
 
-    B, Tmax, hkv = kvc.shape[1], kvc.shape[2], kvc.shape[3] // 2
+    B, Tmax = kvc.shape[1], kvc.shape[2]
+    flat = kvc.ndim == 4
+    live = jnp.arange(Tmax) <= pos
     with scope("index.score"):
         keys = lax.dynamic_index_in_dim(ic, layer, keepdims=False)
         found = scores(qi, wi, keys)[:, 0]                  # (B, Tmax)
-        found = jnp.where(jnp.arange(Tmax) <= pos, found, -jnp.inf)
+        if not flat:
+            found = jnp.where(live, found, -jnp.inf)
+    if flat:
+        from ompi_tpu.ops.selected_attention import selected_attention
+
+        with scope("index.select"):
+            chosen = select(found, live, cfg.index.topk)    # (B, Tmax) bool
+        with scope("attention"):
+            return selected_attention(q, kvc, chosen, layer)
     with scope("index.select"):
         best, chosen = lax.top_k(found, cfg.index.topk)     # (B, topk)
     with scope("attention.gather"):
