@@ -66,11 +66,20 @@ SCOPES = (
                             # of the carry (nothing where it streams them)
     "ffn",              # ln2, the MLP, the residual
     "moe.route", "moe.dispatch", "moe.experts", "moe.combine",
+    "moe.shared",       # the shared expert every token passes
     "ssm_proj",         # the mixer's scalings, in/out projections, gated norm
     "ssm.conv",         # ... its causal convolution (from a state, when cached)
     "ssm.scan",         # ... the chunked scan over a whole sequence
     "ssm.update",       # ... one cached step's recurrence: from reading the
                         # layer's state out of the carry to writing it back
+    "kda_proj",         # the delta-rule mixer's ln1, six projections, L2 norms,
+                        # gated norm and output projection
+    "kda.conv",         # ... its three causal convolutions (from a state, cached)
+    "kda.scan",         # ... the chunked recurrence over a whole sequence
+    "kda.update",       # ... one cached step's recurrence: from reading the
+                        # layer's matrix state out of the carry to writing it
+    "mla_proj",         # latent attention's ln1, projections, latent norm and
+                        # the cached step's absorbed products
     "index_proj",       # the index's three projections, key norm, rotary
     "index.score",      # ... its scores of a query against the index keys
     "index.select",     # ... the topk positions: a threshold, or a top-k

@@ -52,9 +52,24 @@ that tile, the layer's rows pass once through the pallas kernel
 which a gather of a quarter of them does not reach (PERF.md section 5).
 CPU meshes, tiny sizes and long caches gather.
 
+A layer plan (``models/plan.py``: layers of different kinds, a delta-rule
+mixer or latent attention in place of attention) carries no K and V of heads
+at all (``kc`` and ``vc`` are ``None``): every layer has buffers of its own
+(``plan.carry``), a latent layer's cache ``(1, B, Tp+max_new, kv_rank +
+rope)``, one normed latent and one shared key part a position for all heads,
+a KDA layer's convolution inputs and its float32 matrix states ``(1, B,
+heads, K, K)``.  The plan is static, so a step is a python loop over it and
+not a ``fori_loop``: a layer reads its own buffers and replaces them.  Such
+a configuration's decoder is two programs (``_two_programs``): the prefill,
+one executable for every ``max_new`` whose carry ends at the prompt, and a
+second that takes the carry over donated, lengthens the latent caches and
+generates, so that every decoder of the configuration picks the same first
+token for the same prompt.
+
 The prefill hands the carry over.  By default it is one pass over every
 prompt whose K/V are padded to the cache's length.  With
-``TransformerConfig.prefill_tokens`` (and for a hybrid block, always) the
+``TransformerConfig.prefill_tokens`` (and for a hybrid block or a plan,
+always) the
 carry is allocated first at its final size and the prompts are
 prefilled a group of whole sequences at a time, each group writing its
 K/V and final states into it, so the pass's temporaries are a group's
@@ -80,6 +95,8 @@ the full forward agree at any batch, up to the order of summation.
 """
 
 from __future__ import annotations
+
+import functools
 
 from ompi_tpu.models.transformer import (TransformerConfig,
                                          _dense_ffn_tail, _head,
@@ -260,8 +277,9 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
     Greedy decode by default: prefill through the training backbone
     (K/V collected per layer), then ``max_new`` single-token
     steps over the static cache.  Requires sp == 1; dense, switch-MoE,
-    dropless top-k MoE, hybrid (``models/ssm.py``) and indexed
-    (``models/sparse_index.py``) configs are supported (MoE routes each
+    dropless top-k MoE, hybrid (``models/ssm.py``), indexed
+    (``models/sparse_index.py``) and planned (``models/plan.py``) configs
+    are supported (MoE routes each
     token through the same layer as training).
 
     The carry of the token scan and of the loop over layers inside it:
@@ -306,19 +324,26 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
     from ompi_tpu.core import scopes
 
     with scopes.host("build.decoder", program="decode"):
-        return _build_decoder(cfg, mesh, max_new, temperature, top_k,
-                              keep_logits)
+        if cfg.plan is not None:
+            return _two_programs(cfg, mesh, max_new, temperature, top_k,
+                                 keep_logits)
+        return _one_program(cfg, mesh, max_new, temperature, top_k,
+                            keep_logits)
 
 
-def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
-                   temperature: float, top_k: int, keep_logits: int):
-    """The body of :func:`make_decoder`."""
+def _halves(cfg: TransformerConfig, mesh, max_new: int,
+            temperature: float, top_k: int, keep_logits: int):
+    """A decoder's two halves, per device (under ``shard_map``), and the
+    head both multiply by, ``unembedding(params)`` in the compute type:
+    ``prefill(params, head, prompt, seed) -> (tok0, logits, kc, vc,
+    *states)``, the first token, the logits (B, V) it was picked from and
+    the carry, and ``generate(params, head, prompt, seed, tok0, logits, kc,
+    vc, *states)``, what the decoder returns and the states after the last
+    step."""
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.sharding import PartitionSpec as P
 
-    from ompi_tpu.core import scopes
     from ompi_tpu.core.scopes import scope
     from ompi_tpu.models import transformer as tfm
     from ompi_tpu.mpi.device_comm import DeviceCommunicator
@@ -344,6 +369,10 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
         from ompi_tpu.models import sparse_index
 
         sparse_index.check_mesh(cfg, mesh)
+    if cfg.plan is not None:
+        from ompi_tpu.models import plan
+
+        plan.check_mesh(cfg, mesh)
     axes = tuple(a for a in ("dp", "sp", "tp", "ep")
                  if a in mesh.axis_names)
     comm = DeviceCommunicator(mesh, axes)
@@ -396,8 +425,11 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
         kv = (cfg.n_layers, B, Tp + max_new,
               cfg.kv_heads // int(mesh.shape["tp"]), cfg.head_dim)
         ix = cfg.index
-        stacks = ([jnp.zeros(kv, cdt), jnp.zeros(kv, cdt)] if ix is None else
-                  [jnp.zeros((*kv[:3], *row_shape(*kv[2:4])), cdt)])
+        if cfg.plan is not None:    # a layer's own buffers; no K and V of heads
+            stacks = plan.carry(cfg, B, Tp + max_new, cdt)
+        else:
+            stacks = ([jnp.zeros(kv, cdt), jnp.zeros(kv, cdt)] if ix is None
+                      else [jnp.zeros((*kv[:3], *row_shape(*kv[2:4])), cdt)])
         if hy is not None:
             stacks += [jnp.zeros((cfg.n_layers, *shape), dtype)
                        for shape, dtype in zip(ssm.state_shapes(cfg, B),
@@ -419,18 +451,23 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
             return (lax.dynamic_update_slice(last, h[:, -1, :],
                                              (g * group, 0)), *stacks)
 
-        last, kc, *stacks = lax.fori_loop(
+        last, *stacks = lax.fori_loop(
             0, B // group, one, (jnp.zeros((B, cfg.d_model), cdt), *stacks))
+        if cfg.plan is not None:
+            return (last, None, None, *stacks)
+        kc, *stacks = stacks
         return (last, kc, *stacks) if ix is None else (last, kc, None, *stacks)
 
-    def local(params, prompt, seed):
+    def unembedding(params):
+        return _head(cfg, params).astype(cdt)
+
+    def prefill(params, head, prompt, seed):
         B, Tp = prompt.shape
         if keep_logits > B:
             raise ValueError(f"keep_logits={keep_logits} of {B} sequences")
-        head = _head(cfg, params).astype(cdt)
         # ---- prefill: the training backbone, K/V collected ----
         with scope("prefill"):
-            if hy is None and not cfg.prefill_tokens:
+            if hy is None and cfg.plan is None and not cfg.prefill_tokens:
                 h, (_aux, ks, vs, *states) = tfm._local_backbone(
                     cfg, comm, params, prompt, collect_kv=True,
                     forward_only=True)
@@ -450,7 +487,13 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
             logits = tfm._whole_vocab(cfg, jnp.einsum(
                 "bd,vd->bv", last, head, preferred_element_type=jnp.float32))
             tok0 = pick(logits, jnp.int32(Tp - 1), seed)          # (B,)
+        return (tok0, logits, kc, vc, *states)
 
+    def generate(params, head, prompt, seed, tok0, logits, kc, vc, *states):
+        Tp = prompt.shape[1]
+        if cfg.plan is not None:    # the prefill program's carry ends at Tp
+            with scope("prefill"):
+                states = plan.lengthened(cfg, states, Tp + max_new)
         layer_params = {k: params[k] for k in layer_leaves(cfg)}
         # the dropless experts' kernel reads its layer out of the stack
         whole = EXPERT_LEAVES if cfg.moe_top_k else ()
@@ -472,8 +515,12 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
                                    state[3:])
 
             with scope("layers"):
-                h, kc, vc, *states = lax.fori_loop(
-                    0, cfg.n_layers, per_layer, (h, kc, vc, *states))
+                if cfg.plan is not None:    # a python loop over the plan
+                    h, *states = plan.step(cfg, comm, layer_params, h,
+                                           states, pos)
+                else:
+                    h, kc, vc, *states = lax.fori_loop(
+                        0, cfg.n_layers, per_layer, (h, kc, vc, *states))
             with scope("unembed"):
                 h = _rmsnorm(h, params["lnf"], cfg.norm_eps)
                 if hy is not None:
@@ -492,7 +539,7 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
         # (the scope is around the scan, not inside ``gen``, so that a
         # copy XLA makes of the loop's carry would be the step's as well)
         with scope("decode.step"):
-            _, toks = lax.scan(
+            (_kc, _vc, *states, _tok, _pos), toks = lax.scan(
                 gen, (kc, vc, *states, tok0, jnp.int32(Tp)), None,
                 length=max_new - 1)
         if keep_logits:
@@ -502,7 +549,36 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
         gen_toks = jnp.concatenate(
             [tok0[None], toks], axis=0)       # (max_new, B)
         tokens = jnp.concatenate([prompt, gen_toks.swapaxes(0, 1)], axis=1)
-        return (tokens, kept) if keep_logits else tokens
+        return ((tokens, kept) if keep_logits else tokens), tuple(states)
+
+    return unembedding, prefill, generate
+
+
+def _greedy(decode, temperature: float):
+    """``decode`` as :func:`make_decoder` hands it out."""
+    if temperature:
+        return decode
+    # greedy keeps its two-argument signature; seed is inert
+    import numpy as _np
+
+    return lambda params, prompt: decode(params, prompt, _np.int32(0))
+
+
+def _one_program(cfg: TransformerConfig, mesh, max_new: int,
+                 temperature: float, top_k: int, keep_logits: int):
+    """:func:`make_decoder`: prefill and generation in one program."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from ompi_tpu.core import scopes
+
+    unembedding, prefill, generate = _halves(
+        cfg, mesh, max_new, temperature, top_k, keep_logits)
+
+    def local(params, prompt, seed):
+        head = unembedding(params)
+        return generate(params, head, prompt, seed,
+                        *prefill(params, head, prompt, seed))[0]
 
     mapped = jax.shard_map(
         local, mesh=mesh,
@@ -518,9 +594,111 @@ def _build_decoder(cfg: TransformerConfig, mesh, max_new: int,
         record.traced()
         return mapped(params, prompt, seed)
 
-    if temperature:
-        return decode
-    # greedy keeps its two-argument signature; seed is inert
-    import numpy as _np
+    return _greedy(decode, temperature)
 
-    return lambda params, prompt: decode(params, prompt, _np.int32(0))
+
+@functools.lru_cache(maxsize=8)
+def _prefill_program(cfg: TransformerConfig, mesh, temperature: float,
+                     top_k: int, keep_logits: int):
+    """jitted (params, prompt (B, Tp), seed) -> (tokens (B, Tp+1), logits
+    (keep_logits, 1, V), carry): what a decoder of ``max_new=1`` returns, and
+    the carry ``Tp`` positions long (``plan.carry``'s buffers).  One object
+    for every ``max_new`` of a configuration on a mesh, so one executable:
+    :func:`_two_programs` says why."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from ompi_tpu.core import scopes
+
+    unembedding, prefill, _ = _halves(cfg, mesh, 0, temperature, top_k,
+                                      keep_logits)
+
+    def local(params, prompt, seed):
+        tok0, logits, _kc, _vc, *states = prefill(
+            params, unembedding(params), prompt, seed)
+        return (jnp.concatenate([prompt, tok0[:, None]], axis=1),
+                logits[:keep_logits, None], tuple(states))
+
+    mapped = jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(param_specs(P, cfg, mesh), P("dp", None), P()),
+        out_specs=(P("dp", None), P(), P(None, "dp")), check_vma=False)
+    record = scopes.program("decode")
+
+    # both halves of a job are ``decode`` in a profile and in the host's
+    # record (``scopes.startup()``), as the one program is
+    @jax.jit
+    def decode(params, prompt, seed):
+        record.traced()
+        return mapped(params, prompt, seed)
+
+    return decode
+
+
+def _two_programs(cfg: TransformerConfig, mesh, max_new: int,
+                  temperature: float, top_k: int, keep_logits: int):
+    """:func:`make_decoder` of a configuration with a layer plan: the prefill
+    a program of its own (:func:`_prefill_program`), and for ``max_new > 1``
+    a second that takes its carry over (donated: the states are updated in
+    the buffers the prefill filled), lengthens the latent caches to ``Tp +
+    max_new`` and generates.
+
+    Every decoder of one configuration on one mesh starts from the same
+    prefill executable, so what two of them make of the same prompts is the
+    same bits, the first token too.  Two programs that each hold a prefill
+    are compiled apart; on the chip a few of 384 first tokens then differed
+    between ``max_new=1`` and ``max_new=128`` in every run (PR 45): a sum in
+    another order turns a router's tie somewhere in a prompt, and the state
+    remembers it.  A service that answers with the first token from one
+    program and goes on from another cannot have that."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from ompi_tpu.core import scopes
+    from ompi_tpu.models import plan
+
+    first = _prefill_program(cfg, mesh, float(temperature), top_k,
+                             keep_logits)
+    if max_new == 1:
+        def decode(params, prompt, seed):
+            tokens, kept, _carry = first(params, prompt, seed)
+            return (tokens, kept) if keep_logits else tokens
+
+        return _greedy(decode, temperature)
+
+    unembedding, _, generate = _halves(cfg, mesh, max_new, temperature,
+                                       top_k, keep_logits)
+    # a latent cache grows to Tp + max_new here, so its buffer is of no use
+    # to this program's outputs; every other one is written where it lies
+    grows = plan.grows(cfg)
+
+    def local(params, tokens, kept, seed, fixed, growing):
+        fixed, growing = list(fixed), list(growing)
+        carry = [(growing if g else fixed).pop(0) for g in grows]
+        return generate(params, unembedding(params), tokens[:, :-1], seed,
+                        tokens[:, -1], kept[:, 0], None, None, *carry)
+
+    mapped = jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(param_specs(P, cfg, mesh), P("dp", None), P(), P(),
+                  P(None, "dp"), P(None, "dp")),
+        out_specs=((P("dp", None), P()) if keep_logits else P("dp", None),
+                   P(None, "dp")),
+        check_vma=False)
+    record = scopes.program("decode")
+
+    # the states come back so that each is written in the buffer it came
+    # in: a donated buffer is reused for an output of its shape alone
+    @functools.partial(jax.jit, donate_argnums=4)
+    def decode(params, tokens, kept, seed, fixed, growing):
+        record.traced()
+        return mapped(params, tokens, kept, seed, fixed, growing)
+
+    def both(params, prompt, seed):
+        tokens, kept, carry = first(params, prompt, seed)
+        return decode(params, tokens, kept, seed,
+                      [b for b, g in zip(carry, grows) if not g],
+                      [b for b, g in zip(carry, grows) if g])[0]
+
+    return _greedy(both, temperature)

@@ -1,0 +1,227 @@
+"""Kimi Delta Attention: a gated delta rule with a matrix state a head, the
+mixer of a ``models/plan.py`` layer of kind "kda".
+
+From the block's input ``h`` and its norm ``x = RMSNorm(h; ln1)``:
+
+    q~, k~, v~ = x kda_q, x kda_k, x kda_v            (heads x K each)
+    q, k, v    = silu(conv(.)) a branch; q, k L2-normed a head; q K^-1/2
+    g_t  = -exp(kda_a[h]) softplus((x kda_f1) kda_f2 + kda_dt)   (a channel)
+    beta = sigmoid(x kda_b)                                      (a head)
+    S <- diag(exp g_t) S;  u = beta (v_t - S^T k_t);  S <- S + k_t u^T
+    o_t  = S^T q_t
+    h   += (RMSNorm(o_t; kda_n) a head * sigmoid((x kda_g1) kda_g2)) kda_o
+
+:func:`mixer` is the one function both paths call, as ``ssm.mixer`` is: the
+whole sequence from a zero state (trainer, prefill: a causal convolution
+over the sequence and :func:`chunked`) and one position against a carried
+state (``models/decode.py``: the convolution from its last inputs, the
+recurrence once).  Everything here is ``jax.numpy`` and ``lax``: no kernel.
+The decay, the recurrence and the norms are float32 whatever the compute
+type.
+
+Nothing imports this module but a configuration whose plan has the kind.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+__all__ = ["KDA", "mixer", "chunked", "update", "leaf_shapes",
+           "state_shapes"]
+
+L2_EPS = 1e-6
+
+
+@dataclasses.dataclass(frozen=True)
+class KDA:
+    """The mixer's sizes, under the published configuration's names where it
+    has one (``linear_attn_config``)."""
+    n_heads: int
+    head_dim: int               # K: a head's keys and values alike
+    conv: int                   # taps of the causal depthwise convolutions
+    rank: int                   # of the decay's and the output gate's
+    #                             low-rank projections
+    # positions a block of the whole-sequence form: inside it position i
+    # reads j <= i through exp(G_i - G_j) a channel, formed from the
+    # difference (never exp(-G_j), which overflows where a channel decays
+    # fast); across blocks the recurrence on the blocks' final states
+    chunk: int = 16
+    # what the carried matrix state is stored in between cached steps; the
+    # update itself is float32
+    state_dtype: str = "float32"
+
+    @property
+    def width(self) -> int:
+        return self.n_heads * self.head_dim
+
+
+def leaf_shapes(cfg, kd: KDA) -> dict:
+    """One layer's leaves: name -> (shape, deviation of the program's own
+    initializer or None for ones)."""
+    D, HK, r = cfg.d_model, kd.width, kd.rank
+    return {
+        "kda_q": ((D, HK), D ** -0.5), "kda_k": ((D, HK), D ** -0.5),
+        "kda_v": ((D, HK), D ** -0.5),
+        "kda_cq": ((kd.conv, HK), kd.conv ** -0.5),
+        "kda_ck": ((kd.conv, HK), kd.conv ** -0.5),
+        "kda_cv": ((kd.conv, HK), kd.conv ** -0.5),
+        "kda_f1": ((D, r), D ** -0.5), "kda_f2": ((r, HK), r ** -0.5),
+        "kda_a": ((kd.n_heads,), 1.0), "kda_dt": ((HK,), 1.0),
+        "kda_b": ((D, kd.n_heads), D ** -0.5),
+        "kda_g1": ((D, r), D ** -0.5), "kda_g2": ((r, HK), r ** -0.5),
+        "kda_n": ((kd.head_dim,), None),
+        "kda_o": ((HK, D), HK ** -0.5 / max(1, 2 * cfg.n_layers) ** 0.5),
+    }
+
+
+def state_shapes(kd: KDA, batch: int) -> tuple:
+    """One layer's carried state for ``batch`` sequences: the three
+    convolutions' last inputs ``(B, conv - 1, 3 heads K)`` (q's, k's, v's
+    channels in this order) and the heads' states ``(B, heads, K, K)``, key
+    by value."""
+    return ((batch, kd.conv - 1, 3 * kd.width),
+            (batch, kd.n_heads, kd.head_dim, kd.head_dim))
+
+
+def chunked(q, k, v, g, beta, chunk: int):
+    """The recurrence over whole sequences from a zero state, a block of
+    ``chunk`` positions at a time.  q, k, v, g: (B, T, H, K) float32, g the
+    log decay (<= 0); beta: (B, T, H).  Returns o (B, T, H, K) and the state
+    after the last position (B, H, K, K), float32.
+
+    Inside a block that starts from state ``S0``, with ``G`` the decay's
+    running sum: ``u_i = beta_i (v_i - S0^T (k_i e^{G_i}) - sum_{j<i} A_ij
+    u_j)``, ``A_ij = sum_d k_id k_jd e^{G_id - G_jd}``: a unit lower
+    triangular system, solved a row at a time; ``o_i = S0^T (q_i e^{G_i}) +
+    sum_{j<=i} B_ij u_j`` with ``B`` as ``A`` but of q against k; the state
+    after the block ``e^{G_last} S0 + sum_j (k_j e^{G_last - G_j}) u_j^T``.
+    Every exponent is a difference that is at most zero.  A length that is
+    no multiple of the block is padded with positions of g = 0 and beta = 0,
+    which leave the state as it is."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    B, T, H, K = q.shape
+    c = chunk
+    nc = -(-T // c)
+
+    def blocks(y):      # (B, T, H, ...) -> (nc, B, H, c, ...)
+        y = jnp.pad(y, [(0, 0), (0, nc * c - T)] + [(0, 0)] * (y.ndim - 2))
+        y = y.reshape(B, nc, c, *y.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(y, 1, 0), 2, 3)
+
+    upto = jnp.tril(jnp.ones((c, c), bool))
+    before = jnp.tril(jnp.ones((c, c), f32), -1)
+
+    def one(S, block):
+        q, k, v, g, b = block               # (B, H, c, K); b (B, H, c)
+        G = jnp.cumsum(g, axis=2)
+        w = jnp.exp(jnp.where(upto[:, :, None], G[:, :, :, None, :]
+                              - G[:, :, None, :, :], -jnp.inf))
+        kj = k[:, :, None, :, :] * w        # (B, H, i, j, K)
+        A = jnp.sum(k[:, :, :, None, :] * kj, axis=-1) * before
+        Bm = jnp.sum(q[:, :, :, None, :] * kj, axis=-1)
+        eG = jnp.exp(G)
+        U = b[..., None] * (v - jnp.einsum(
+            "bhck,bhkv->bhcv", k * eG, S, precision=hi))
+        Lw = b[..., None] * A
+        for i in range(1, c):
+            U = U.at[:, :, i].add(-jnp.einsum(
+                "bhj,bhjv->bhv", Lw[:, :, i, :i], U[:, :, :i], precision=hi))
+        o = (jnp.einsum("bhck,bhkv->bhcv", q * eG, S, precision=hi)
+             + jnp.einsum("bhcj,bhjv->bhcv", Bm, U, precision=hi))
+        S = S * eG[:, :, -1][..., None] + jnp.einsum(
+            "bhck,bhcv->bhkv", k * jnp.exp(G[:, :, -1:] - G), U,
+            precision=hi)
+        return S, o
+
+    S, o = lax.scan(one, jnp.zeros((B, H, K, K), f32),
+                    tuple(blocks(y.astype(f32)) for y in (q, k, v, g, beta)))
+    o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1).reshape(B, nc * c, H, K)
+    return o[:, :T], S
+
+
+def update(state, q, k, v, g, beta):
+    """The recurrence once, for one new position: ``state`` (B, H, K, K) as
+    it is carried, q, k, v, g (B, H, K) and beta (B, H) float32.  Returns o
+    (B, H, K) float32 and the new state in ``state``'s type.  Two passes
+    over the state: its products with k and q, then the decayed state plus
+    the write."""
+    import jax.numpy as jnp
+
+    S = state.astype(jnp.float32) * jnp.exp(g)[..., None]
+    u = beta[..., None] * (v - jnp.sum(S * k[..., None], axis=2))
+    o = (jnp.sum(S * q[..., None], axis=2)
+         + jnp.sum(q * k, axis=-1, keepdims=True) * u)
+    return o, (S + k[..., None] * u[..., None, :]).astype(state.dtype)
+
+
+def mixer(cfg, lp, h, carry=None):
+    """One layer's mixer on the block's input ``h`` (B, T, D): the norm, the
+    mixer and the residual add.
+
+    ``carry`` None: whole sequences from a zero state; returns ``(h,
+    conv_state, state)``, the layer's states after the last position, ``(B,
+    conv - 1, 3 heads K)`` in h's type and ``(B, heads, K, K)`` float32.
+    ``carry = (conv, state)``: T == 1 against this layer's own carried
+    states, ``(B, conv - 1, 3 heads K)`` and ``(B, heads, K, K)`` in whatever
+    they are stored in; returns ``(h, conv, state)``, the new ones in the
+    same types.  A layer's state is a buffer of its own and not a slice of a
+    stack over layers (``models/plan.carry`` says why)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ompi_tpu.core.scopes import scope
+    from ompi_tpu.models.transformer import _rmsnorm
+
+    kd, f32, cdt = cfg.plan.kda, jnp.float32, h.dtype
+    B, T, _ = h.shape
+    H, K, HK = kd.n_heads, kd.head_dim, kd.width
+
+    def proj(y, *names):
+        for name in names:
+            y = jnp.einsum("btd,df->btf", y, lp[name].astype(cdt))
+        return y
+
+    with scope("kda_proj"):
+        x = _rmsnorm(h, lp["ln1"], cfg.norm_eps)
+        raw = jnp.concatenate([proj(x, w) for w in
+                               ("kda_q", "kda_k", "kda_v")], axis=-1)
+        g = -jnp.exp(lp["kda_a"].astype(f32))[:, None] * jax.nn.softplus(
+            proj(x, "kda_f1", "kda_f2").astype(f32).reshape(B, T, H, K)
+            + lp["kda_dt"].astype(f32).reshape(H, K))
+        beta = jax.nn.sigmoid(proj(x, "kda_b").astype(f32))
+        gate = jax.nn.sigmoid(proj(x, "kda_g1", "kda_g2").astype(f32))
+    with scope("kda.conv"):
+        taps = kd.conv
+        if carry is None:
+            window = jnp.pad(raw, ((0, 0), (taps - 1, 0), (0, 0)))
+            conv_out = window[:, T:]
+        else:
+            conv, state = carry
+            window = jnp.concatenate([conv.astype(cdt), raw], axis=1)
+            conv_out = window[:, 1:].astype(conv.dtype)
+        w = jnp.concatenate([lp[name].astype(f32) for name in
+                             ("kda_cq", "kda_ck", "kda_cv")], axis=-1)
+        qkv = jax.nn.silu(sum(window[:, j:j + T].astype(f32) * w[j]
+                              for j in range(taps)))
+    with scope("kda_proj"):
+        q, k, v = (y.reshape(B, T, H, K) for y in jnp.split(qkv, 3, axis=-1))
+        q, k = (y * lax.rsqrt(jnp.sum(y * y, axis=-1, keepdims=True) + L2_EPS)
+                for y in (q, k))
+        q = q * K ** -0.5
+    if carry is None:
+        with scope("kda.scan"):
+            o, state = chunked(q, k, v, g, beta, kd.chunk)
+    else:
+        with scope("kda.update"):
+            o, state = update(state, *(y[:, 0] for y in
+                                       (q, k, v, g, beta)))     # T == 1
+            o = o[:, None]
+    with scope("kda_proj"):
+        o = (o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                           + cfg.norm_eps) * lp["kda_n"].astype(f32))
+        y = (o.reshape(B, T, HK) * gate).astype(cdt)
+        return h + proj(y, "kda_o"), conv_out, state

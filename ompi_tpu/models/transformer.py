@@ -57,8 +57,9 @@ class TransformerConfig:
     # Experts a token: 0 keeps the top-1 switch above (capacity drops, the
     # aux loss, experts over "ep").  k >= 1 routes every token to its k
     # most probable experts and drops none (parallel/moe.routed_moe:
-    # sort by expert, grouped matmul, weighted sum back); every expert is
-    # then on every device (ep == 1) and no aux term joins the loss.
+    # sort by expert, grouped matmul, weighted sum back); no exchange is
+    # built (ep == 1) and no aux term joins the loss.  Every expert is then
+    # on every device, unless ``moe_held`` says which this device holds.
     moe_top_k: int = 0
     # Gated experts: down(silu(gate(x)) * up(x)) with a third leaf "w3"
     # (up) beside "w1" (gate) and "w2" (down), instead of w2(gelu(w1 x)).
@@ -66,6 +67,24 @@ class TransformerConfig:
     # The dropless path's top-k weights divided by their sum (a token's
     # experts then weigh one together); False: the probabilities as they are.
     moe_norm_topk: bool = False
+    # The dropless path's scores: "softmax" over all experts, or each
+    # expert's own "sigmoid"; with ``moe_select_bias`` a leaf "wgb" (one an
+    # expert) is added to the scores for the choice of the top-k alone, and
+    # the chosen experts weigh their scores without it.  ``moe_scale``
+    # multiplies the weights, after ``moe_norm_topk``.
+    moe_score: str = "softmax"
+    moe_select_bias: bool = False
+    moe_scale: float = 1.0
+    # (first, count): this device is one of several that share each routed
+    # layer by expert, and holds experts first .. first + count - 1 alone;
+    # the expert leaves are ``count`` long and the router stays
+    # ``moe_experts`` wide.  A token picks among all experts; the picks
+    # held elsewhere add nothing here (parallel/moe.routed_moe), as the
+    # device that holds them adds them.  None: every expert is here.
+    moe_held: Any = None
+    # Width of a shared expert, a gated MLP (leaves "sw1", "sw3", "sw2")
+    # that every token passes, unweighted, beside its routed experts.  0: none.
+    moe_shared: int = 0
     # RMSNorm of q and k before the rotary embedding (leaves "qn", "kn").
     # True: over the whole projected width, before the split into heads,
     # with a scale as wide as the projection.  "head": over each head's
@@ -136,6 +155,12 @@ class TransformerConfig:
     # positions each query attends to, and a decoder carries the index's
     # keys beside K and V.  None: attention over every earlier position.
     index: Any = None
+    # models/plan.LayerPlan: layers of different kinds (a mixer's kind and
+    # an MLP's a layer), their leaves stacked by kind, a decoder's carry a
+    # layer's own buffers.
+    # None: the plan of one kind, attention and the one MLP above in every
+    # layer.
+    plan: Any = None
     # The most tokens one pass of a decoder's prefill holds: the prompts are
     # then prefilled a group of whole sequences at a time, each group writing
     # into the cache that was allocated once.  0: every prompt in one pass.
@@ -165,6 +190,10 @@ FLAGSHIP_BATCH = 16
 def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
     """Global (unsharded) parameter pytree; layers stacked for lax.scan."""
     rng = np.random.default_rng(seed)
+    if cfg.plan is not None:
+        from ompi_tpu.models import plan
+
+        return _as_stored(cfg, plan.init_params(cfg, rng))
     L, D, F, V = cfg.n_layers, cfg.d_model, cfg.d_ff, cfg.vocab
 
     def w(*shape, scale=None):
@@ -207,6 +236,11 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
         from ompi_tpu.models import sparse_index
 
         params.update(sparse_index.init_leaves(cfg, rng))
+    return _as_stored(cfg, params)
+
+
+def _as_stored(cfg: TransformerConfig, params: dict) -> dict:
+    """``params`` in the type they are stored in."""
     if cfg.param_dtype not in (None, "float32"):
         # live params are stored in param_dtype; the optimizer's f32
         # master copy is created from them at init (one-time rounding)
@@ -247,6 +281,12 @@ def param_specs(P, cfg: Optional[TransformerConfig] = None, mesh=None):
     divide, the table is whole on every device and the loss splits its
     work over ``tp`` by positions (:func:`_local_loss`)."""
     table = P("tp", None) if _rows_over_tp(cfg, mesh) else P()
+    if cfg is not None and cfg.plan is not None:
+        # a plan's mixers run with tp == 1 and its experts without an
+        # exchange: every leaf whole on every device
+        return {"emb": table, "lnf": P(), **{
+            leaf: P() for leaf in layer_leaves(cfg)},
+            **({} if cfg.tie_head else {"head": table})}
     specs = {
         "emb": table, "lnf": P(), "ln1": P(), "ln2": P(),
         "wq": P(None, None, "tp"), "wk": P(None, None, "tp"),
@@ -331,7 +371,11 @@ def _qk_norm(cfg, x, scale, comm):
 def layer_leaves(cfg: TransformerConfig) -> tuple:
     """Names of the leaves stacked over layers: what the layer loops of the
     backbone and of the cached decode step slice a layer's parameters
-    from."""
+    from (with a plan each over the layers of its kind)."""
+    if cfg.plan is not None:
+        from ompi_tpu.models import plan
+
+        return plan.leaf_names(cfg)
     leaves = ["wq", "wk", "wv", "wo", "w1", "w2", "ln1", "ln2"]
     if cfg.moe_experts:
         leaves.append("wg")
@@ -543,12 +587,13 @@ def _rope(x, positions, impl: str = "jnp", theta=10_000):
 
 def _moe_ffn_tail(cfg, h, lp, comm, layer=None):
     """Post-attention half of the MoE layer: ln2 → ep-sharded switch, or
-    dropless top-k routed experts → residual (shared by the training
-    layer, the prefill and the cached decode step — one source of truth,
-    like _dense_ffn_tail).  Returns (h, aux).  With ``layer``, the
-    dropless path's expert leaves (``moe.EXPERT_LEAVES``) are the whole
-    stacks over layers and ``layer`` this layer's index in them
-    (``routed_moe`` says why)."""
+    dropless top-k routed experts (those this device holds: ``moe_held``)
+    and, with ``moe_shared``, the shared expert every token passes →
+    residual (shared by the training layer, the prefill and the cached
+    decode step — one source of truth, like _dense_ffn_tail).  Returns (h,
+    aux).  With ``layer``, the dropless path's expert leaves
+    (``moe.EXPERT_LEAVES``) are the whole stacks over layers and ``layer``
+    this layer's index in them (``routed_moe`` says why)."""
     import jax.numpy as jnp
 
     from ompi_tpu.core.scopes import scope
@@ -564,18 +609,38 @@ def _moe_ffn_tail(cfg, h, lp, comm, layer=None):
                     f"ragged: not built; keep every expert on the device "
                     f"(ep == 1), or use the top-1 switch (moe_top_k=0)")
             weights = {k: lp[k] for k in ("wg", *EXPERT_LEAVES) if k in lp}
+            if cfg.moe_select_bias:
+                weights["wgb"] = lp["wgb"]
             # the pallas kernel where the mesh is of TPUs (attached, or
             # described for a compile); XLA's ragged_dot on any other
             mo = routed_moe(x, weights, cfg.moe_top_k, gated=cfg.moe_gated,
                             layer=layer, kernel=comm.mesh.devices.flat[
                                 0].platform == "tpu",
-                            renorm=cfg.moe_norm_topk)
+                            renorm=cfg.moe_norm_topk, score=cfg.moe_score,
+                            scale=cfg.moe_scale, held=cfg.moe_held)
+            if cfg.moe_shared:
+                mo = mo + _shared_expert(x, lp)
             return h + mo, jnp.zeros((), jnp.float32)
         mo, aux = switch_moe(
             comm, x, {"wg": lp["wg"], "w1": lp["w1"], "w2": lp["w2"]},
             axis="ep", capacity_factor=cfg.moe_capacity_factor,
             with_aux=True)
         return h + mo, aux
+
+
+def _shared_expert(x, lp):
+    """``sw2(silu(x sw1) * x sw3)``: the gated MLP that every token passes
+    beside its routed experts (whole on every device)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ompi_tpu.core.scopes import scope
+
+    with scope("moe.shared"):
+        up = [jnp.einsum("btd,df->btf", x, lp[k].astype(x.dtype))
+              for k in ("sw1", "sw3")]
+        return jnp.einsum("btf,fd->btd", jax.nn.silu(up[0]) * up[1],
+                          lp["sw2"].astype(x.dtype))
 
 
 def _dense_ffn_tail(h, lp, comm, cdt, eps: float = 1e-6, gated=None,
@@ -623,7 +688,8 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     post-rope per-layer attention inputs stacked (L, B, T, Hkv/tp, hd) —
     the KV-cache prefill (models/decode.py); with an index
     (h, (aux, k, v, ki)): every layer's index keys (L, B, width, T) too,
-    positions last as the carry holds them (models/sparse_index.py); with a hybrid block
+    positions last as the carry holds them (models/sparse_index.py); with a
+    plan (h, (aux, *states)) as ``models/plan.backbone`` says; with a hybrid block
     (h, (aux, k, v, conv, ssm)): every layer's mixer states after the last
     position too, stacked alike, the second in the block's ``state_dtype``.
     With that block h comes scaled by its ``lm_head_multiplier``.
@@ -643,6 +709,12 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     from ompi_tpu.parallel import attention as attn_mod
     from ompi_tpu.parallel.layers import column_parallel, row_parallel
 
+    if cfg.plan is not None:
+        # layers of several kinds: a loop over the plan, leaves by kind
+        from ompi_tpu.models import plan
+
+        return plan.backbone(cfg, comm, params, tokens, collect_kv,
+                             grad_axes)
     cdt = jnp.dtype(cfg.compute_dtype)
     tp = int(comm.mesh.shape["tp"])
     sp = int(comm.mesh.shape["sp"])

@@ -1,6 +1,7 @@
 """Mixture-of-experts layers: a switch-style MoE over an ``ep`` mesh axis
-(:func:`switch_moe`, below) and dropless top-k routing with every expert
-on the device (:func:`routed_moe`, at the end).
+(:func:`switch_moe`, below) and dropless top-k routing over the experts
+this device holds (:func:`routed_moe`, at the end): every expert, or, told
+``held``, one device's share of them, with no exchange.
 
 The fourth parallelism dimension (after dp/sp/tp): experts shard over
 ``ep`` and tokens travel to their expert's device through
@@ -156,24 +157,41 @@ def switch_moe(comm, x, params, axis: str = "ep",
 
 
 def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
-               kernel: bool = False, renorm: bool = False):
-    """Dropless top-k MoE layer, every expert on this device: x (B, T, D)
-    local tokens → (B, T, D).
+               kernel: bool = False, renorm: bool = False,
+               score: str = "softmax", scale: float = 1.0, held=None):
+    """Dropless top-k MoE layer over the experts this device holds: x (B, T,
+    D) local tokens → (B, T, D).
 
     ``params``: ``wg`` (D, E) the router; ``w1`` (E, D, F) and ``w2``
     (E, F, D) the experts, ``w2(gelu(w1 x))``; with ``gated`` also ``w3``
     (E, D, F), and an expert is ``w2(silu(w1 x) * w3 x)``.  With ``layer``
-    (a traced index) the three are the whole stacks over layers, (L, E, ·,
-    ·), and the kernel reads layer ``layer``'s matrices out of them: a
-    layer loop that sliced them first would copy every expert of the layer
-    out of the stack each time (a pallas call takes whole arrays), 0.8 GB
-    a layer of a cached step at OLMoE's widths.
+    (an index, traced or not) the three are the whole stacks over layers,
+    (L, E, ·, ·), and the kernel reads layer ``layer``'s matrices out of
+    them: a layer loop that sliced them first would copy every expert of
+    the layer out of the stack each time (a pallas call takes whole
+    arrays), 0.8 GB a layer of a cached step at OLMoE's widths.
+
+    ``held`` None: every expert of the router is on this device.  ``held =
+    (first, count)``: the device is one of several that share the layer by
+    expert and holds experts ``first .. first + count - 1`` alone, so the
+    three stacks are ``count`` long where the router stays ``E`` wide.  A
+    token still picks ``top_k`` of all ``E`` and its weights are made over
+    all its picks; the picks that fall to an expert held elsewhere are given
+    no tile here and weigh nothing (the device that holds the expert adds
+    them: the shares of all devices add up to the whole layer), and the
+    weights are *not* renormalised over the picks held here.  No exchange is
+    made and nothing stands in for one.
 
     Routing (all shapes static, no capacity, no token dropped): the
-    router's logits, softmax and top-k in float32; each token keeps its
-    ``top_k`` most probable experts with their probabilities as they are,
-    or with ``renorm`` divided by their sum, so that a token's experts weigh
-    one together.  The ``tokens × top_k`` assignments are sorted by
+    router's logits, scores and top-k in float32.  ``score`` "softmax":
+    each token keeps its ``top_k`` most probable experts with their
+    probabilities as they are, or with ``renorm`` divided by their sum, so
+    that a token's experts weigh one together.  ``score`` "sigmoid": the
+    scores are each expert's own sigmoid; with a leaf ``wgb`` (E,) in
+    ``params``, the selection bias, the ``top_k`` largest of ``score +
+    wgb`` are picked and weigh their scores without it.  Either way the
+    weights are then multiplied by ``scale``.  The ``tokens × top_k``
+    assignments are sorted by
     expert (stable) and laid out in row tiles of ``tm`` rows, every
     expert's run starting at a tile boundary, so a tile's rows all go to
     one expert and the experts run as ``ops.grouped_matmul`` over the
@@ -204,14 +222,32 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
     cdt = x.dtype
     xf = x.reshape(n, D)
     tm = tile_rows(n * k / E)
+    if held is not None:
+        E = held[1]                     # the groups that get tiles
     n_tiles = -(-n * k // tm) + E       # sum of ceil(rows_e / tm) is below
     with scope("moe.route"):
         logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
                             params["wg"].astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
-        gate, expert = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        if score == "softmax":
+            gate, expert = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            biased = (scores + params["wgb"].astype(jnp.float32)
+                      if "wgb" in params else scores)
+            expert = lax.top_k(biased, k)[1]
+            gate = jnp.take_along_axis(scores, expert, axis=-1)
         if renorm:
             gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        if scale != 1.0:
+            gate = gate * scale
+        if held is not None:
+            # a pick held elsewhere: weighs nothing, and sorts past the last
+            # expert of this device, where no tile is made for it
+            first = held[0]
+            here = (expert >= first) & (expert < first + E)
+            gate = jnp.where(here, gate, 0.0)
+            expert = jnp.where(here, expert - first, E)
     with scope("moe.dispatch"):
         # assignments sorted by expert; ``order``: sorted row -> assignment
         sorted_group, order = lax.sort(
@@ -228,9 +264,9 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
             E - 1).astype(jnp.int32)
         # slot -> the sorted row it holds (where a slot only fills a tile
         # up, a real row of the next run, or the last)
-        held = jnp.clip(jnp.arange(n_tiles * tm).reshape(n_tiles, tm)
-                        - shift[tile_group][:, None], 0, n * k - 1)
-        rows = xf[order[held.reshape(-1)] // k]     # (n_tiles·tm, D)
+        holds = jnp.clip(jnp.arange(n_tiles * tm).reshape(n_tiles, tm)
+                         - shift[tile_group][:, None], 0, n * k - 1)
+        rows = xf[order[holds.reshape(-1)] // k]    # (n_tiles·tm, D)
         # assignment -> its slot (a sort by ``order`` is its inverse)
         _, slot = lax.sort(
             (order, jnp.arange(n * k)
