@@ -8,11 +8,22 @@ the rows out so that a tile never straddles two groups (a group's rows
 start at a tile boundary and its last tile is filled up with rows nobody
 reads back), which is what keeps the kernel this small: no masks, no tile
 visited twice, the group of a tile one scalar read from SMEM by the index
-map of the weights' block.  ``tile_group`` is non-decreasing, so when a
-whole matrix is one block (small ``tm``: the memory-bound case, a few rows
-an expert) consecutive tiles of one group fetch it once.  Tiles from
-``tiles_used`` on hold no row of any group; they are written as zeros and
-multiply nothing.
+map of the weights' block.  Tiles from ``tiles_used`` on hold no row of any
+group; they are written as zeros and multiply nothing.
+
+The weights' block (``weight_block``, from static shapes alone).  Up to 128
+rows a tile the call is bound by the weights' bytes (128 operations a byte
+of bfloat16 weights against the v5e's ridge of 240), so a matrix must be
+read once a group: where the kernel's whole working set fits its VMEM
+budget (``_VMEM_BUDGET_BYTES``), a whole ``(K, N)`` matrix is one block,
+whose index ``(tile_group[t], 0, 0)`` stays put while the group does
+(``tile_group`` is non-decreasing), so consecutive tiles of one group, and
+all the unused tiles after them, fetch it once.  Everything else goes by blocks of ``K`` and ``N`` (larger
+tiles, bound by the MXU, and matrices too large to hold twice): there the
+weights' index changes at every grid step and *every row tile reads its
+group's whole matrix again*, the tiles past ``tiles_used`` too (they skip
+the product, not the copy), which is what a tile of 256 or 512 rows
+amortises and a smaller one does not.
 
 XLA's own ``lax.ragged_dot`` lowers on the TPU to kernels of the same kind,
 but under the one ``op_name`` ``ragged-dot-none``: the scopes around it are
@@ -36,12 +47,24 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["grouped_matmul", "grouped_matmul_xla", "tile_rows"]
+__all__ = ["grouped_matmul", "grouped_matmul_xla", "tile_rows",
+           "weight_block"]
 
-# A block of the weights may take this many bytes of VMEM (it is double
-# buffered).  Up to it, and under small row tiles, a whole (K, N) matrix is
-# one block.
-_RHS_BLOCK_BYTES = 4 << 20
+# What the kernel's blocks may take of VMEM: what Mosaic gives a kernel that
+# names no limit (16 MiB on the v5e's compiler; what the rule admits just
+# under it compiles, tests/parallel/test_grouped_matmul_compiled.py).  The
+# call names none on purpose: a stated ``vmem_limit_bytes`` is reserved
+# whole, whatever the kernel needs, and XLA then assigns less VMEM to the
+# operations around it: at 32 MiB Keye-VL's cached step, whose calls take
+# the same blocks either way, ran 0.9% slower (PERF.md section 6, PR 46).
+# The largest matrices a cell holds, 2304 x 1024 bfloat16 at 128 rows a
+# tile, make a working set of 13.5 MB as ``_working_set_bytes`` counts it,
+# an upper bound: compiled for a v5e, that call needs under 11 MiB.
+_VMEM_BUDGET_BYTES = 16 << 20
+
+# Rows a tile up to which a call is bound by the weights' bytes: a byte of
+# bfloat16 weights meets ``tm`` operations, the v5e's ridge is 240.
+_WEIGHT_BOUND_ROWS = 128
 
 
 def tile_rows(rows_a_group: float) -> int:
@@ -65,6 +88,27 @@ def _block(dim: int, cap: int) -> int:
         raise ValueError(f"grouped_matmul: {dim} has no power-of-two tile "
                          f"between 128 and {cap}")
     return b
+
+
+def _working_set_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """VMEM the kernel holds at blocks of ``(tm, tk)`` rows, ``(tk, tn)``
+    weights and ``(tm, tn)`` output: each of the three twice (the pipeline
+    copies the next while this one is multiplied), the float32 accumulator
+    and the product's float32 result."""
+    return (2 * itemsize * (tk * tn + tm * tk + tm * tn)
+            + 2 * 4 * tm * tn)
+
+
+def weight_block(tm: int, K: int, N: int, itemsize: int) -> tuple[int, int]:
+    """``(tk, tn)``, the block of a ``(K, N)`` matrix that a tile of ``tm``
+    rows multiplies: the whole matrix where the call is bound by the
+    weights' bytes and the working set fits the kernel's VMEM budget, so
+    that a group's matrix is read once; blocks of both dimensions anywhere
+    else."""
+    if (tm <= _WEIGHT_BOUND_ROWS
+            and _working_set_bytes(tm, K, N, itemsize) <= _VMEM_BUDGET_BYTES):
+        return K, N
+    return _block(K, 1024), _block(N, 512)
 
 
 def _kernel(tile_group, tiles_used, lhs, rhs, out, acc):
@@ -103,11 +147,7 @@ def _forward(rows, w, tile_group, tiles_used):
         raise ValueError(f"grouped_matmul: rows {rows.shape}, w {w.shape}, "
                          f"{n_tiles} tiles")
     tm = m // n_tiles
-    itemsize = jnp.dtype(w.dtype).itemsize
-    if tm <= 64 and K * N * itemsize <= _RHS_BLOCK_BYTES:
-        tk, tn = K, N       # a few rows a group: every matrix read once
-    else:
-        tk, tn = _block(K, 1024), _block(N, 512)
+    tk, tn = weight_block(tm, K, N, jnp.dtype(w.dtype).itemsize)
     return pl.pallas_call(
         _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

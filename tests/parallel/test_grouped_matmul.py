@@ -1,7 +1,9 @@
 """The pallas grouped matmul under dropless expert routing
 (``ops/grouped_matmul.py``), in TPU interpret mode: each row tile against
 the matrix its entry of ``tile_group`` names, the tiles past ``tiles_used``
-zero, and the custom backward against ``lax.ragged_dot``'s own."""
+zero, and the custom backward against ``lax.ragged_dot``'s own; and the
+rule that picks the weights' block (``weight_block``), held to what every
+routed cell of the benchmark hands it."""
 
 import numpy as np
 import pytest
@@ -10,7 +12,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ompi_tpu.ops.grouped_matmul import grouped_matmul, tile_rows
+from ompi_tpu.ops.grouped_matmul import (_VMEM_BUDGET_BYTES,
+                                         _working_set_bytes, grouped_matmul,
+                                         grouped_matmul_xla, tile_rows,
+                                         weight_block)
 
 
 @pytest.mark.parametrize("rows_a_group,want", [
@@ -23,29 +28,123 @@ def test_tile_rows_follow_the_groups_size(rows_a_group, want):
 def _case(tm, K, N, G, tile_group, seed=0, dtype=np.float32):
     rng = np.random.default_rng(seed)
     tile_group = np.asarray(tile_group, np.int32)
-    rows = rng.normal(size=(len(tile_group) * tm, K)).astype(dtype)
-    w = rng.normal(size=(G, K, N)).astype(dtype)
-    return jnp.asarray(rows), jnp.asarray(w), jnp.asarray(tile_group)
+    rows = rng.normal(size=(len(tile_group) * tm, K)).astype(np.float32)
+    w = rng.normal(size=(G, K, N)).astype(np.float32)
+    return (jnp.asarray(rows, dtype), jnp.asarray(w, dtype),
+            jnp.asarray(tile_group))
 
 
-@pytest.mark.parametrize("tm,K,N", [
-    pytest.param(16, 64, 32, id="whole-matrix-blocks"),
-    pytest.param(128, 2048, 1024, id="k-and-n-in-blocks"),
+# Groups 1 and 4 have no tile and the last two tiles hold no row.
+_SPARSE = dict(G=6, tile_group=[0, 0, 2, 3, 3, 3, 5, 5], used=6,
+               dtype=np.float32)
+# Kimi-Linear's held experts: 2304 x 1024 and 1024 x 2304, 4.72 MB in
+# bfloat16 (over the 4 MiB a whole-matrix block stopped at until PR 46), a K
+# with a factor of 9.  Group 0 has two tiles, group 1 none, and the last two
+# tiles hold no row.
+_KIMI = dict(G=4, tile_group=[0, 0, 2, 3, 3, 3], used=4, dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("tm,K,N,whole,case", [
+    pytest.param(16, 64, 32, True, _SPARSE, id="whole-matrix-blocks"),
+    pytest.param(128, 2048, 1024, False, _SPARSE, id="k-and-n-in-blocks"),
+    pytest.param(16, 2304, 1024, True, _KIMI, id="over-4-mib-step-w1"),
+    pytest.param(16, 1024, 2304, True, _KIMI, id="over-4-mib-step-w2"),
+    pytest.param(128, 2304, 1024, True, _KIMI, id="over-4-mib-prefill-w1"),
+    pytest.param(128, 1024, 2304, True, _KIMI, id="over-4-mib-prefill-w2"),
+    pytest.param(512, 2304, 1024, False, _KIMI, id="over-4-mib-mxu-bound"),
 ])
-def test_each_tile_multiplies_its_groups_matrix(tm, K, N):
-    tile_group = [0, 0, 2, 3, 3, 3, 5, 5]    # groups 1 and 4 have no tile
-    rows, w, tg = _case(tm, K, N, 6, tile_group)
-    used = jnp.asarray([6], jnp.int32)
-    got = np.asarray(jax.jit(grouped_matmul)(rows, w, tg, used))
+def test_each_tile_multiplies_its_groups_matrix(tm, K, N, whole, case):
+    tile_group, n_used, dtype = case["tile_group"], case["used"], case["dtype"]
+    rows, w, tg = _case(tm, K, N, case["G"], tile_group, dtype=dtype)
+    used = jnp.asarray([n_used], jnp.int32)
+    assert (weight_block(tm, K, N, rows.dtype.itemsize) == (K, N)) is whole
+    got = np.asarray(jax.jit(grouped_matmul)(rows, w, tg, used), np.float64)
+    # float32: the accumulation's own error; bfloat16: the result's one
+    # rounding, a unit in the last place of the largest entry
+    tol = 1e-4 if dtype == np.float32 else 2.0 ** -7
     for t, g in enumerate(tile_group):
         tile = slice(t * tm, (t + 1) * tm)
-        if t < 6:
+        if t < n_used:
             want = np.asarray(rows[tile], np.float64) @ np.asarray(
                 w[g], np.float64)
-            np.testing.assert_allclose(got[tile], want, rtol=1e-4,
-                                       atol=1e-4 * np.abs(want).max())
+            np.testing.assert_allclose(got[tile], want, rtol=tol,
+                                       atol=tol * np.abs(want).max())
         else:
             assert not got[tile].any()
+    same = np.asarray(jax.jit(grouped_matmul_xla)(rows, w, tg, used),
+                      np.float64)
+    np.testing.assert_allclose(got, same, rtol=tol,
+                               atol=tol * np.abs(same).max())
+
+
+_OLMOE = "olmoe-1b-7b.decode-1k-128"
+_KEYE = "keye-vl-2.0-30b-a3b.decode-8k-128-b64"
+_KIMI_CELL = "kimi-linear-48b-a3b.decode-512-128-b384"
+
+
+def _routed_call(workload, phase):
+    """``(tm, n_tiles, {leaf: (K, N)}, itemsize)`` of the ``grouped_matmul``
+    calls one routed layer makes in a cached step or in a pass of the
+    prefill of a benchmark cell, from the cell's configuration and traffic
+    files as ``parallel/moe.routed_moe`` tiles them."""
+    from benchmarks.lib import cells, program
+    from ompi_tpu.models.decode import _prefill_group
+
+    cell = cells.resolve(workload)
+    cfg = program.program_config(cell.config)
+    ref = program.reference(cell.config)
+    routed = program.counts(ref, ref.Shape.from_config(cell.config))["routed"]
+    B, Tp = cell.traffic["batch"], cell.traffic["prompt_len"]
+    n = B if phase == "step" else Tp * _prefill_group(B, Tp,
+                                                      cfg.prefill_tokens)
+    picks = n * cfg.moe_top_k
+    tm = tile_rows(picks / cfg.moe_experts)
+    D, F = routed["d_model"], routed["d_expert"]
+    return (tm, -(-picks // tm) + routed["experts"],
+            {"w1": (D, F), "w2": (F, D)},
+            jnp.dtype(cfg.compute_dtype).itemsize)
+
+
+# Which path every routed cell's calls take (PERF.md section 5).  The blocks
+# of OLMoE's and Keye-VL's calls are what the rule before PR 46 gave them
+# (whole up to 64 rows and 4 MiB, else ``_block(K, 1024), _block(N, 512)``):
+# their programs are the parent's.
+@pytest.mark.parametrize("workload,phase,tm,n_tiles,blocks", [
+    pytest.param(_OLMOE, "step", 16, 88, "whole", id="olmoe-step"),
+    pytest.param(_KEYE, "step", 16, 160, "whole", id="keye-step"),
+    pytest.param(_OLMOE, "prefill", 512, 832,
+                 {"w1": (1024, 512), "w2": (1024, 512)}, id="olmoe-prefill"),
+    pytest.param(_KEYE, "prefill", 512, 380,
+                 {"w1": (1024, 256), "w2": (768, 512)}, id="keye-prefill"),
+    pytest.param(_KIMI_CELL, "step", 16, 320, "whole", id="kimi-step"),
+    pytest.param(_KIMI_CELL, "prefill", 128, 384, "whole",
+                 id="kimi-prefill"),
+])
+def test_which_block_each_routed_cell_takes(workload, phase, tm, n_tiles,
+                                            blocks):
+    got_tm, got_tiles, matrices, itemsize = _routed_call(workload, phase)
+    assert (got_tm, got_tiles) == (tm, n_tiles)
+    for leaf, (K, N) in matrices.items():
+        tk, tn = weight_block(tm, K, N, itemsize)
+        if blocks == "whole":
+            assert (tk, tn) == (K, N), leaf
+            held = _working_set_bytes(tm, K, N, itemsize)
+            assert 2 * K * N * itemsize < held <= _VMEM_BUDGET_BYTES, (
+                leaf, held)
+        else:
+            assert (tk, tn) == blocks[leaf], leaf
+            assert K % tk == 0 and N % tn == 0
+
+
+def test_the_working_set_is_counted_as_the_kernel_holds_it():
+    # Kimi-Linear's w2 at 128 rows: the matrix, the rows and the output
+    # twice each, the accumulator and the product in float32: 13.5 MB
+    assert _working_set_bytes(128, 1024, 2304, 2) == 13_500_416
+    # past the ridge a whole matrix is never one block, however small
+    assert weight_block(256, 256, 256, 2) == (256, 256)     # _block's own
+    assert weight_block(256, 2304, 1024, 2) == (256, 512)
+    # nor under it where the matrix cannot be held twice
+    assert weight_block(16, 4096, 2048, 2) == (1024, 512)
 
 
 def test_backward_is_ragged_dots_own():

@@ -1,0 +1,49 @@
+"""``grouped_matmul`` compiled for a v5e that is described and not attached
+(``tests/benchmarks/test_fits.py``'s idiom), wherever its rule takes a whole
+matrix as one block near the top of the kernel's VMEM budget: the call
+names no ``vmem_limit_bytes``, so what ``weight_block`` admits has to fit
+what the compiler gives a kernel by default.  Nothing runs and nothing here
+is a time.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+
+from ompi_tpu.ops.grouped_matmul import (_VMEM_BUDGET_BYTES,  # noqa: E402
+                                         _working_set_bytes, grouped_matmul,
+                                         weight_block)
+# the described chip, and the compile cache and interpret mode off around it
+from tests.parallel.test_selected_attention_compiled import (  # noqa: E402,F401
+    chip, for_the_chip)
+
+
+@pytest.mark.parametrize("tm,K,N", [
+    pytest.param(16, 2304, 1024, id="kimi-step-w1"),
+    pytest.param(128, 2304, 1024, id="kimi-prefill-w1"),
+    pytest.param(128, 1024, 2304, id="kimi-prefill-w2"),
+    pytest.param(16, 2048, 1920, id="15.5-mib-of-16-rows"),
+    pytest.param(64, 2048, 1792, id="15.8-mib-of-64-rows"),
+    pytest.param(128, 1536, 2048, id="15.75-mib-of-128-rows"),
+])
+def test_a_whole_matrix_block_compiles_under_the_default_limit(
+        tm, K, N, chip, for_the_chip):
+    assert weight_block(tm, K, N, 2) == (K, N)
+    assert _working_set_bytes(tm, K, N, 2) <= _VMEM_BUDGET_BYTES
+    from jax.sharding import SingleDeviceSharding
+
+    n_tiles, G = 64, 48
+    on_chip = SingleDeviceSharding(chip[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=on_chip)
+
+    text = jax.jit(grouped_matmul).lower(
+        shape((n_tiles * tm, K), jnp.bfloat16),
+        shape((G, K, N), jnp.bfloat16), shape((n_tiles,), jnp.int32),
+        shape((1,), jnp.int32)).compile().as_text()
+    assert "grouped_matmul" in text and "tpu_custom_call" in text
