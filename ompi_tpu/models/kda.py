@@ -15,9 +15,11 @@ From the block's input ``h`` and its norm ``x = RMSNorm(h; ln1)``:
 whole sequence from a zero state (trainer, prefill: a causal convolution
 over the sequence and :func:`chunked`) and one position against a carried
 state (``models/decode.py``: the convolution from its last inputs, the
-recurrence once).  Everything here is ``jax.numpy`` and ``lax``: no kernel.
-The decay, the recurrence and the norms are float32 whatever the compute
-type.
+recurrence once).  Everything here is ``jax.numpy`` and ``lax`` but the
+cached step's recurrence, :func:`update`, which on TPUs is one pallas pass
+over the layer's state (``ops/kda_update.py``; ``kda_update.block`` is the
+rule, from static facts alone).  The decay, the recurrence and the norms are
+float32 whatever the compute type.
 
 Nothing imports this module but a configuration whose plan has the kind.
 """
@@ -142,14 +144,38 @@ def chunked(q, k, v, g, beta, chunk: int):
     return o[:, :T], S
 
 
+def _traced_for_tpus() -> bool:
+    """Whether what is being traced will run on TPUs: the kind of the
+    devices of the mesh the trace is under (a decoder's programs are
+    ``shard_map``s over theirs), attached or described for a compile; under
+    no mesh, the process's own backend."""
+    import jax
+
+    device = jax.sharding.get_abstract_mesh().abstract_device
+    if device is None:
+        return jax.default_backend() == "tpu"
+    return device.device_kind.startswith("TPU")
+
+
 def update(state, q, k, v, g, beta):
     """The recurrence once, for one new position: ``state`` (B, H, K, K) as
     it is carried, q, k, v, g (B, H, K) and beta (B, H) float32.  Returns o
-    (B, H, K) float32 and the new state in ``state``'s type.  Two passes
-    over the state: its products with k and q, then the decayed state plus
-    the write."""
+    (B, H, K) float32 and the new state in ``state``'s type.
+
+    Where ``ops/kda_update.block`` gives a block (on TPUs, a float32 state
+    of heads that tile), one pallas pass: a block of matrices is read into
+    VMEM, swept twice there and written back into ``state``'s own buffer.
+    Anywhere else (the CPU, a state carried in bfloat16, a head narrower
+    than 128) the ``jax.numpy`` form below, which XLA makes two fusions of,
+    three passes over the state: its products with k and q, then the decayed
+    state plus the write."""
     import jax.numpy as jnp
 
+    from ompi_tpu.ops import kda_update
+
+    if kda_update.block(_traced_for_tpus(), state.dtype,
+                        *state.shape[1:3]) is not None:
+        return kda_update.kda_update(state, q, k, v, g, beta)
     S = state.astype(jnp.float32) * jnp.exp(g)[..., None]
     u = beta[..., None] * (v - jnp.sum(S * k[..., None], axis=2))
     o = (jnp.sum(S * q[..., None], axis=2)
