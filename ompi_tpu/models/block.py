@@ -1,0 +1,252 @@
+"""One layer of a configuration without a plan, written once: attention as a
+mixer of the form ``ssm.mixer``, ``kda.mixer`` and ``mla.mixer`` have
+(:func:`mixer`: ln1, the q/k/v projections, q/k-norm, the rotary embedding
+and ``wo`` are there and nowhere else) and the layer around it
+(:func:`block`), each ONE function for whole sequences (trainer, prefill) and
+for one position against a carry (``models/decode.py``).
+
+What this mixer carries: K and V after the rotary embedding, stacked over
+layers at their final length, ``(L, B, t_max, Hkv/tp, hd)`` each in the
+compute type, head-sharded like the weights.  A step writes ``B Hkv hd``
+values a layer in place at ``(layer, :, pos)`` and reads layer ``layer``
+through a slice the compiler fuses into the products; K/V heads may be fewer
+than query heads and are read once for the heads they serve.  With an index
+the K and V are ``models/sparse_index.py``'s to lay out.
+
+A decoder needs two things of whatever a configuration carries, and every
+module that carries state gives them under the same names (:func:`mechanisms`
+lists the modules): ``carry(cfg, mesh, batch, t_max)``, the zeros of its
+stacks, and ``carried(cfg, mesh, collected, t_max, into)``, what a
+whole-sequence pass collected for it as those stacks hold it (:func:`written`).
+"""
+
+from __future__ import annotations
+
+__all__ = ["mixer", "block", "mechanisms", "carry", "carried", "written",
+           "check_mesh"]
+
+
+def mechanisms(cfg) -> tuple:
+    """The modules whose state a decoder of ``cfg`` carries, in the carry's
+    order: ``plan``; or this one (with an index ``sparse_index``, which lays
+    K and V out with its keys) and, with a hybrid block, ``ssm``.  The one
+    place that reads the configuration for them."""
+    import importlib
+
+    names = ["block" if cfg.index is None else "sparse_index"]
+    if cfg.hybrid is not None:
+        names.append("ssm")
+    return tuple(importlib.import_module("ompi_tpu.models." + name)
+                 for name in (names if cfg.plan is None else ["plan"]))
+
+
+def check_mesh(cfg, mesh) -> None:
+    """Query and K/V heads are split over ``tp`` whole."""
+    tp = int(dict(mesh.shape).get("tp", 1))
+    if cfg.n_heads % tp or cfg.kv_heads % tp:
+        raise ValueError(f"tp={tp} does not divide {cfg.n_heads} query "
+                         f"heads and {cfg.kv_heads} K/V heads")
+
+
+def carry(cfg, mesh, batch: int, t_max: int) -> list:
+    """K and V for ``batch`` sequences of up to ``t_max`` positions, zeros."""
+    import jax.numpy as jnp
+
+    shape = (cfg.n_layers, batch, t_max,
+             cfg.kv_heads // int(mesh.shape["tp"]), cfg.head_dim)
+    return [jnp.zeros(shape, cfg.compute_dtype) for _ in "kv"]
+
+
+def carried(cfg, mesh, collected, t_max: int, into=None, **group) -> list:
+    """Every layer's K and V of whole sequences, the next two of the iterator
+    ``collected``, as :func:`carry`'s stacks (:func:`written`)."""
+    return [written(next(collected), t_max, stack, **group)
+            for stack in into or (None, None)]
+
+
+def written(new, t_max: int, into=None, g=0, group: int = 0, axis=2):
+    """``new``, a state of ``group`` whole sequences stacked over layers
+    (sequences on axis 1), as a carry holds it: written into the stack
+    ``into`` from sequence ``g * group`` on, in the stack's type; with no
+    stack, padded with zeros to ``t_max`` positions along ``axis`` (None: a
+    state that does not grow)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    if into is not None:
+        return lax.dynamic_update_slice(
+            into, new.astype(into.dtype),
+            (0, g * group) + (0,) * (into.ndim - 2))
+    if axis is None:
+        return new
+    pad = [(0, 0)] * new.ndim
+    pad[axis] = (0, t_max - new.shape[axis])
+    return jnp.pad(new, pad)
+
+
+def _attend_whole_cache(q, kc, vc, layer, pos):
+    """q (B, 1, H, hd) against every position up to ``pos`` of layer
+    ``layer`` of the cache (L, B, Tmax, Hkv, hd), a K/V head read once for
+    the query heads it serves: the context, float32, (B, 1, H, hd) or
+    grouped (B, 1, Hkv, H / Hkv, hd)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ompi_tpu.core.scopes import scope
+
+    B, hl, hd = q.shape[0], q.shape[2], q.shape[3]
+    Tmax, hkv = kc.shape[2], kc.shape[3]
+    with scope("attention"):
+        # scores against every cached position, masked beyond `pos`
+        k_all = lax.dynamic_index_in_dim(kc, layer, keepdims=False)
+        v_all = lax.dynamic_index_in_dim(vc, layer, keepdims=False)
+        if hkv == hl:
+            s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                           k_all.astype(jnp.float32)) * (hd ** -0.5)
+            live = jnp.arange(Tmax)[None, None, None, :] <= pos
+            s = jnp.where(live, s, -1e30)
+            w = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bhqk,bkhd->bqhd", w, v_all.astype(jnp.float32))
+        else:       # K/V head g serves the query heads (g, r): read it once
+            qg = q.astype(jnp.float32).reshape(B, 1, hkv, hl // hkv, hd)
+            s = jnp.einsum("bqgrd,bkgd->bgrqk", qg,
+                           k_all.astype(jnp.float32)) * (hd ** -0.5)
+            s = jnp.where(jnp.arange(Tmax) <= pos, s, -1e30)
+            w = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bgrqk,bkgd->bqgrd", w,
+                           v_all.astype(jnp.float32))
+    return o
+
+
+def mixer(cfg, comm, lp, h, positions, carry=None, impl: str = "jnp",
+          weights=None, forward_only: bool = False):
+    """The attention branch of one layer on the block's input ``h``
+    (B, T, D) at ``positions`` (T,), without its residual add: ln1, the
+    projections, q/k-norm and then the hybrid's key multiplier (an RMS norm
+    would take a multiplier before it away), the rotary embedding in the
+    form ``impl`` reads, attention, ``wo``.  ``lp``: the layer's leaves.
+    ``weights(x, *names)``, the train step's: ``(x, leaves)`` for the
+    matmuls that read ``x`` (``transformer._local_backbone`` says what it
+    ties to them); None: ``lp``'s own.
+
+    ``carry`` None: whole sequences, the layout's attention over ``sp`` or
+    the index's (``forward_only``: no gradient will be asked, so it may take
+    the kernel that has none); returns ``(a, x, collected)``, the branch's
+    output, the normed input (a hybrid's mixer reads it too) and what a
+    decoder collects: this layer's k and v (B, T, Hkv/tp, hd), and the
+    index's keys.  ``carry = (stacks, layer, pos)``: T == 1, position ``pos``
+    against layer ``layer`` of the stacks (:func:`carry`'s, or the index's),
+    its own k and v written in place first; returns ``(a, x, stacks)``."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ompi_tpu.core.scopes import scope
+    from ompi_tpu.models import transformer as tfm
+    from ompi_tpu.parallel import attention as attn_mod
+    from ompi_tpu.parallel.layers import column_parallel, row_parallel
+
+    if cfg.index is not None:
+        from ompi_tpu.models import sparse_index
+    weights = weights or (lambda x, *_names: (x, lp))
+    cdt, hy = h.dtype, cfg.hybrid
+    B, T, tp = h.shape[0], h.shape[1], int(comm.mesh.shape["tp"])
+    hl, hkv, hd = cfg.n_heads // tp, cfg.kv_heads // tp, cfg.head_dim
+    with scope("attn_proj"):
+        # the norms are called through the module: a benchmark's control
+        # plants a wrong one there while a decoder is traced
+        x = tfm._rmsnorm(h, lp["ln1"], cfg.norm_eps)
+        xa = x if hy is None else x * hy.attention_in_multiplier
+        xa, w = weights(xa, "wq", "wk", "wv")
+        q, k, v = (column_parallel(xa, w[name].astype(cdt))
+                   for name in ("wq", "wk", "wv"))
+        if cfg.qk_norm:
+            q = tfm._qk_norm(cfg, q, lp["qn"], comm)
+            k = tfm._qk_norm(cfg, k, lp["kn"], comm)
+        if hy is not None:
+            k = k * hy.key_multiplier
+        q = tfm._rope(q.reshape(B, T, hl, hd), positions, impl,
+                      cfg.rope_theta)
+        k = tfm._rope(k.reshape(B, T, hkv, hd), positions, impl,
+                      cfg.rope_theta)
+        v = v.reshape(B, T, hkv, hd)
+        k_all, v_all = k, v
+        if carry is None and cfg.index is None and hkv != hl:
+            # each K/V head before its queries
+            k_all, v_all = (jnp.repeat(y, hl // hkv, axis=2) for y in (k, v))
+    if carry is None and cfg.index is None:
+        layout = tfm._ATTENTION_LAYOUT.get(cfg.attention, "gathered")
+        with scope("attention"):
+            o = getattr(attn_mod, layout + "_attention")(
+                comm, q, k_all, v_all, axis="sp", impl=impl)
+        out = (k, v)
+    elif carry is None:
+        # each query over its own selection (the kernel has no backward
+        # pass and compiles for the TPU)
+        o, ki = sparse_index.attend(
+            cfg, lp, x, q, k, v, positions, kernel=forward_only
+            and comm.mesh.devices.flat[0].platform == "tpu")
+        out = (k, v, ki)
+    elif cfg.index is None:
+        (kc, vc), layer, pos = carry
+        with scope("kv_cache"):
+            kc = lax.dynamic_update_slice(kc, k.astype(kc.dtype)[None],
+                                          (layer, 0, pos, 0, 0))
+            vc = lax.dynamic_update_slice(vc, v.astype(vc.dtype)[None],
+                                          (layer, 0, pos, 0, 0))
+        o, out = _attend_whole_cache(q, kc, vc, layer, pos), [kc, vc]
+    else:
+        o, *out = sparse_index._attend_selection(cfg, lp, x, q, k, v, *carry)
+    with scope("attn_proj"):
+        o, w = weights(o.astype(cdt).reshape(B, T, hl * hd), "wo")
+        return row_parallel(o, w["wo"].astype(cdt), comm, axis="tp"), x, out
+
+
+def block(cfg, comm, lp, h, positions, carry=None, **how):
+    """One layer on ``h`` (B, T, D): :func:`mixer` (``how``: its ``impl``,
+    ``weights``, ``forward_only``) and the residual, with a hybrid block
+    ``h + a * attention_out_multiplier + ssm.mixer(x)``, both branches
+    reading the one normed input; then ln2, the MLP or the experts.
+
+    ``carry`` None: whole sequences; returns ``(h, (aux, *collected))``, aux
+    the switch's balance term (0 without one) and ``collected`` what the
+    mixers hand a decoder, in :func:`mechanisms`' order.  ``carry = (stacks,
+    layer, pos)``: T == 1 against the carry, a list of each mechanism's own
+    stacks; ``lp``'s dropless expert leaves (``moe.EXPERT_LEAVES``) are then
+    the whole stacks over layers, which ``routed_moe`` indexes by ``layer``.
+    Returns ``(h, stacks)``."""
+    import jax.numpy as jnp
+
+    from ompi_tpu.core.scopes import scope
+    from ompi_tpu.models import transformer as tfm
+
+    hy, layer = cfg.hybrid, None
+    if carry is not None:
+        stacks, layer, pos = carry
+        carry = (stacks[0], layer, pos)
+    a, x, own = mixer(cfg, comm, lp, h, positions, carry, **how)
+    if hy is None:
+        with scope("attn_proj"):
+            h = h + a
+    else:
+        from ompi_tpu.models import ssm
+
+        s, *states = ssm.mixer(cfg, lp, x, carry and (*stacks[1], layer))
+        with scope("attn_proj"):
+            h = h + a * hy.attention_out_multiplier + s
+    if cfg.moe_experts:
+        # the switch over "ep" (tp ranks replicate the expert compute), or
+        # the dropless experts, which a cached step gets as whole stacks
+        h, aux = tfm._moe_ffn_tail(
+            cfg, h, lp, comm, layer=layer if cfg.moe_top_k else None)
+    else:
+        h = tfm._dense_ffn_tail(h, lp, comm, h.dtype, cfg.norm_eps,
+                                gated=hy and hy.mlp_multipliers,
+                                weights=how.get("weights"))
+    if carry is not None:
+        return h, [own] if hy is None else [own, states]
+    if not cfg.moe_experts:
+        aux = jnp.zeros((), jnp.float32)
+    if hy is not None:      # the states as the carry stores them
+        own += (states[0], states[1].astype(hy.state_dtype))
+    return h, (aux, *own)

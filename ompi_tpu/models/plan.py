@@ -11,17 +11,15 @@ touched by this module.
 Leaves are stacked by kind, not by layer: the KDA leaves over the KDA
 layers, the latent leaves over the latent layers, the dense MLP's over the
 dense layers, the router's, the shared expert's and the experts' over the
-routed layers; ``ln1`` and ``ln2`` over all layers.  A decoder carries, for
-each layer, that layer's own buffers: a latent layer's cache ``(1, B, Tmax,
-kv_rank + rope)``, a KDA layer's convolution inputs and matrix state ``(1,
-B, ...)`` (:func:`carry` says why not a stack a kind).  The plan is static,
-so both passes are python loops over it (:func:`backbone`, the whole
-sequence; :func:`step`, one cached position): a layer's place in its kind's
-stacks of leaves is a python integer, its leaves are static slices, and its
-state is a buffer that the step reads and replaces.  Unrolled and
-not a scan over whole periods with the leading layers outside it, because
+routed layers; ``ln1`` and ``ln2`` over all layers.  A decoder carries each
+layer's own buffers (:func:`carry` says which, and why not a stack a kind).
+The plan is static, so both passes are python loops over it
+(:func:`backbone`, the whole sequence; :func:`step`, one cached position):
+a layer's place in its kind's stacks of leaves is a python integer, its
+leaves are static slices, and its state is a buffer that the step reads and
+replaces.  Not a scan over whole periods with the leading layers outside it:
 the plans built so far are a handful of layers whose MLPs differ inside a
-period; a deep plan would want the scan (``ROADMAP.md`` D1).
+period; a deep plan would want the scan (``ROADMAP.md`` D1').
 
 Nothing imports this module but a configuration that has a plan.
 """
@@ -34,7 +32,7 @@ from typing import Any
 import numpy as np
 
 __all__ = ["LayerPlan", "kda_mla_config", "leaf_names", "init_params",
-           "carry", "grows", "lengthened", "backbone", "step", "check_mesh"]
+           "carry", "grows", "carried", "backbone", "step", "check_mesh"]
 
 ROUTER_LEAVES = ("wg", "wgb")
 SHARED_LEAVES = ("sw1", "sw3", "sw2")
@@ -174,7 +172,7 @@ def init_params(cfg, rng) -> dict:
     return params
 
 
-def carry(cfg, batch: int, t_max: int, cdt) -> list:
+def carry(cfg, mesh, batch: int, t_max: int) -> list:
     """A decoder's carry for ``batch`` sequences of up to ``t_max``
     positions, zeros, a layer's own buffers after another's in the plan's
     order, each with a leading axis of one (so that whoever fills a carry a
@@ -197,7 +195,7 @@ def carry(cfg, batch: int, t_max: int, cdt) -> list:
 
     from ompi_tpu.models import kda
 
-    pl, buffers = cfg.plan, []
+    pl, cdt, buffers = cfg.plan, cfg.compute_dtype, []
     for mixer, _mlp_kind in pl.layers:
         if mixer == "mla":
             buffers.append(jnp.zeros((1, batch, t_max, pl.mla.cached), cdt))
@@ -215,14 +213,17 @@ def grows(cfg) -> tuple:
                  for grown in ((True,) if mixer == "mla" else (False, False)))
 
 
-def lengthened(cfg, buffers, t_max: int) -> list:
-    """:func:`carry`'s buffers, those that grow padded with zeros to
-    ``t_max`` positions."""
-    import jax.numpy as jnp
+def carried(cfg, mesh, collected, t_max: int, into=None, **group) -> list:
+    """:func:`backbone`'s collected states of whole sequences, or a carry
+    that ends before ``t_max`` (``collected``, used up; :func:`carry`'s
+    order), as :func:`carry`'s buffers: written into ``into``, or those that
+    grow padded with zeros to ``t_max`` positions (``block.written``)."""
+    from ompi_tpu.models.block import written
 
-    return [jnp.pad(buffer, [(0, 0), (0, 0), (0, t_max - buffer.shape[2]),
-                             (0, 0)]) if grown else buffer
-            for buffer, grown in zip(buffers, grows(cfg))]
+    grown = grows(cfg)
+    return [written(new, t_max, buffer, axis=2 if longer else None, **group)
+            for new, buffer, longer in zip(
+                collected, into or [None] * len(grown), grown)]
 
 
 def _mlp(cfg, comm, params, layer: int, kind: str, h):

@@ -1,9 +1,9 @@
 """Learned sparse attention: an index picks the cached positions a query
 attends to.
 
-``TransformerConfig.index`` holds a :class:`SparseIndex`; attention of the
-block in ``models/transformer.py`` then reads, for the query at position
-``t``, the set ``S_t`` alone:
+``TransformerConfig.index`` holds a :class:`SparseIndex`; attention
+(``models/block.mixer``) then reads, for the query at position ``t``, the
+set ``S_t`` alone:
 
     qI = x Wiq  (heads x width),  kI = LayerNorm(x Wik)  (one key),
     wI = (x Wiw) * heads^-1/2 * width^-1/2,  rotary embedding on qI and kI
@@ -19,10 +19,10 @@ own: its sizes, its leaves, and the two paths that call the same
 far, so that neither the index's scores nor attention's are ever held for a
 whole sequence; the set is found as a threshold, the ``topk``-th largest
 score of a row by bisection on the scores' bits (:func:`select`; exact, ties
-included), and applied as a mask.  **One position against the carried
-keys** (``models/decode.py``): :func:`attend_cached`, the scores against the
-layer's index keys in the carry, then one of two reads of the same set, in a
-carry in which a position's K and V are one row.  **The gather:**
+included), and applied as a mask.  **One position against the carry**
+(:func:`carry`: the index keys, and K and V as one row a position):
+:func:`attend_cached`, the scores against the layer's index keys, then one
+of two reads of the same set.  **The gather:**
 ``lax.top_k`` and the selected positions' rows gathered out of the carry: a
 step reads ``topk`` rows of the layer's K/V, not the layer's K/V.  **The
 stream:** the set as a mask (:func:`select`, as the prefill finds it) and the
@@ -30,8 +30,8 @@ layer's rows streamed once under it by the pallas kernel
 ``ops/selected_attention.py``.  A gathered row costs this chip eight times
 what a streamed one does, so a step streams where its cache is no more than
 ``_STREAM_UP_TO`` times its selection, the mesh is of TPUs and the sizes
-tile (:func:`streams`, which the decoder asks before it lays the carry out:
-a streamed carry holds a row flat, ``(L, B, Tmax, 2 Hkv hd)``).  The index
+tile (:func:`streams`, which :func:`row_shape` asks where the carry is laid
+out: a streamed carry holds a row flat, ``(L, B, Tmax, 2 Hkv hd)``).  The index
 keys are carried with the positions last, ``(L, B, width, Tmax)``, and held
 to that layout (:func:`positions_minor`): a step's scores are then one
 product of the layer's slice as it lies, where the compiler, left to itself,
@@ -55,7 +55,8 @@ import numpy as np
 
 __all__ = ["SparseIndex", "sparse_config", "project", "scores", "select",
            "attend", "attend_cached", "streams", "positions_minor",
-           "init_leaves", "leaf_names", "check_mesh"]
+           "row_shape", "in_rows", "carry", "carried", "init_leaves",
+           "leaf_names", "check_mesh"]
 
 # A cached step streams the layer's K/V under a mask where its cache is at
 # most this many times its selection, and gathers the selected rows beyond.
@@ -316,6 +317,83 @@ def streams(ix: SparseIndex, t_max: int, head_dim: int, tpu: bool) -> bool:
 
     return (tpu and tiles(t_max, head_dim)
             and ix.topk < t_max <= _STREAM_UP_TO * ix.topk)
+
+
+def row_shape(cfg, mesh, t_max: int, heads: int) -> tuple:
+    """What a carry of ``t_max`` positions holds of one position, its
+    ``heads`` K heads and then its V heads in one row: ``(2 heads, hd)``, and
+    the row flat, ``(2 heads hd,)``, where the program's cached steps stream
+    it (:func:`streams`: static, so all of a program's steps or none)."""
+    if streams(cfg.index, t_max, cfg.head_dim,
+               mesh.devices.flat[0].platform == "tpu"):
+        return (2 * heads * cfg.head_dim,)
+    return (2 * heads, cfg.head_dim)
+
+
+def in_rows(cfg, mesh, ks, vs, t_max: int):
+    """A whole-sequence pass's K and V, (L, B, T, Hkv/tp, hd) each, as the
+    rows of a carry of ``t_max`` positions."""
+    import jax.numpy as jnp
+
+    return jnp.concatenate([ks, vs], axis=3).reshape(
+        *ks.shape[:3], *row_shape(cfg, mesh, t_max, ks.shape[3]))
+
+
+def carry(cfg, mesh, batch: int, t_max: int) -> list:
+    """What a decoder carries with an index, zeros in the compute type: K and
+    V as one stack of rows ``(L, B, t_max, *row_shape)`` (one, because a
+    gather costs this chip its 15 ns a row whether the row is 1 KB or 2) and
+    the index's keys ``(L, B, width, t_max)``, positions last."""
+    import jax.numpy as jnp
+
+    heads = cfg.kv_heads // int(mesh.shape["tp"])
+    return [jnp.zeros((cfg.n_layers, batch, t_max,
+                       *row_shape(cfg, mesh, t_max, heads)),
+                      cfg.compute_dtype),
+            jnp.zeros((cfg.n_layers, batch, cfg.index.head_dim, t_max),
+                      cfg.compute_dtype)]
+
+
+def carried(cfg, mesh, collected, t_max: int, into=None, **group) -> list:
+    """Every layer's k, v and index keys of whole sequences, the next three
+    of the iterator ``collected``, as :func:`carry`'s two stacks."""
+    from ompi_tpu.models.block import written
+
+    ks, vs, ki = (next(collected) for _ in range(3))
+    rows, keys = into or (None, None)
+    return [written(in_rows(cfg, mesh, ks, vs, t_max), t_max, rows, **group),
+            written(ki, t_max, keys, axis=3, **group)]
+
+
+def _attend_selection(cfg, lp, x, q, k, v, stacks, layer, pos):
+    """The cached attention of ONE new position: from the block's normed
+    input x (B, 1, D) and its rotated q (B, 1, H, hd) and k, v (B, 1, Hkv,
+    hd), against :func:`carry`'s ``stacks``, the rows kvc and the index keys
+    ic.  Writes the row and the index key at ``pos``, then reads the
+    selection (:func:`attend_cached`), or every row where the cache is no
+    longer than ``topk``.  Returns (context float32, kvc, ic)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ompi_tpu.core.scopes import scope
+    from ompi_tpu.models.block import _attend_whole_cache
+
+    kvc, ic = stacks
+    Tmax, hkv = kvc.shape[2], k.shape[2]
+    qi, ki, wi = project(cfg, lp, x, pos[None])
+    with scope("kv_cache"):
+        row = jnp.concatenate([k, v], axis=2).astype(kvc.dtype)
+        kvc = lax.dynamic_update_slice(
+            kvc, row.reshape(1, *row.shape[:2], *kvc.shape[3:]),
+            (layer, 0, pos) + (0,) * (kvc.ndim - 3))
+        ic = positions_minor(lax.dynamic_update_slice(
+            ic, ki.swapaxes(1, 2).astype(ic.dtype)[None], (layer, 0, 0, pos)))
+    if cfg.index.topk < Tmax:
+        o = attend_cached(cfg, q, kvc, ic, qi, wi, layer, pos)
+    else:       # never flat: a cache within its selection does not stream
+        o = _attend_whole_cache(q, kvc[..., :hkv, :], kvc[..., hkv:, :],
+                                layer, pos)
+    return o, kvc, ic
 
 
 def attend_cached(cfg, q, kvc, ic, qi, wi, layer, pos):

@@ -1,7 +1,7 @@
 """The hybrid block: a Mamba-2 mixer beside attention in every layer.
 
-``TransformerConfig.hybrid`` holds a :class:`HybridBlock`; the block of
-``models/transformer.py`` then computes, from one normed input ``u``,
+``TransformerConfig.hybrid`` holds a :class:`HybridBlock`; the block
+(``models/block.block``) then computes, from one normed input ``u``,
 
     x <- x + attention(u) * attention_out_multiplier + mixer(u)
     x <- x + down(silu(gate(f) * m0) * up(f)) * m1,   f = RMSNorm(x; ln2)
@@ -12,8 +12,9 @@ This module has what is the mixer's own: its sizes and the multipliers, its
 leaves, and :func:`mixer`, the one function both paths call: the whole
 sequence (trainer, prefill: a causal convolution over the sequence and the
 chunked scan, from a zero state) and one position against a carried state
-(``models/decode.py``: the convolution from its last inputs, the recurrence
-once).  Everything here is ``jax.numpy`` and ``lax``: no kernel.
+(its states in :func:`carry`'s stacks: the convolution from its last
+inputs, the recurrence once).  Everything here is ``jax.numpy`` and ``lax``:
+no kernel.
 
 Nothing imports this module but a configuration that has the block, so the
 other programs' set-up does not pay for it.
@@ -27,7 +28,8 @@ import math
 import numpy as np
 
 __all__ = ["HybridBlock", "hybrid_config", "mixer", "chunked_scan",
-           "init_leaves", "leaf_names", "state_shapes", "check_mesh"]
+           "init_leaves", "leaf_names", "state_shapes", "carry", "carried",
+           "check_mesh"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,6 +145,28 @@ def state_shapes(cfg, batch: int) -> tuple:
     hy = cfg.hybrid
     return ((batch, hy.d_conv - 1, hy.conv_dim),
             (batch, hy.n_heads, hy.head_dim, hy.d_state))
+
+
+def carry(cfg, mesh, batch: int, t_max: int) -> list:
+    """What a decoder carries for the mixer, zeros: every layer's
+    :func:`state_shapes` stacked over layers (no ``t_max`` in them), the
+    convolution's inputs in the compute type and the heads' states in the
+    block's ``state_dtype``.  A step updates layer ``l``'s in place."""
+    import jax.numpy as jnp
+
+    types = (cfg.compute_dtype, cfg.hybrid.state_dtype)
+    return [jnp.zeros((cfg.n_layers, *shape), dtype)
+            for shape, dtype in zip(state_shapes(cfg, batch), types)]
+
+
+def carried(cfg, mesh, collected, t_max: int, into=None, **group) -> list:
+    """Every layer's two states after the last position of whole sequences,
+    the next two of the iterator ``collected``, as :func:`carry`'s stacks
+    (``block.written``)."""
+    from ompi_tpu.models.block import written
+
+    return [written(next(collected), t_max, stack, axis=None, **group)
+            for stack in into or (None, None)]
 
 
 def _column_multipliers(hy: HybridBlock) -> np.ndarray:

@@ -589,9 +589,8 @@ def _moe_ffn_tail(cfg, h, lp, comm, layer=None):
     """Post-attention half of the MoE layer: ln2 → ep-sharded switch, or
     dropless top-k routed experts (those this device holds: ``moe_held``)
     and, with ``moe_shared``, the shared expert every token passes →
-    residual (shared by the training layer, the prefill and the cached
-    decode step — one source of truth, like _dense_ffn_tail).  Returns (h,
-    aux).  With ``layer``, the dropless path's expert leaves
+    residual (``models/block.block``'s and a plan's).  Returns (h, aux).
+    With ``layer``, the dropless path's expert leaves
     (``moe.EXPERT_LEAVES``) are the whole stacks over layers and ``layer``
     this layer's index in them (``routed_moe`` says why)."""
     import jax.numpy as jnp
@@ -645,21 +644,18 @@ def _shared_expert(x, lp):
 
 def _dense_ffn_tail(h, lp, comm, cdt, eps: float = 1e-6, gated=None,
                     weights=None):
-    """Post-attention half of the dense layer: ln2 → gelu MLP →
-    residual (shared by the training layer and the cached decode step,
-    models/decode.py — one source of truth for this math).  ``gated``, a
-    pair of multipliers (m0, m1): down(silu(gate(x)·m0) * up(x))·m1 with
-    the up projection in a third leaf "w3".  ``weights(x, *names)``, the
-    train step's: ``(x, leaves)`` for the matmuls that read ``x``
+    """Post-attention half of the dense layer: ln2 → gelu MLP → residual
+    (``models/block.block``'s and a plan's).  ``gated``, a pair of
+    multipliers (m0, m1): down(silu(gate(x)·m0) * up(x))·m1 with the up
+    projection in a third leaf "w3".  ``weights(x, *names)``, the train
+    step's: ``(x, leaves)`` for the matmuls that read ``x``
     (``_local_backbone`` says what it ties to them); None: ``lp``'s own."""
     import jax
 
     from ompi_tpu.core.scopes import scope
     from ompi_tpu.parallel.layers import column_parallel, row_parallel
 
-    if weights is None:
-        def weights(x, *_names):
-            return x, lp
+    weights = weights or (lambda x, *_names: (x, lp))
     with scope("ffn"):
         x = _rmsnorm(h, lp["ln2"], eps)
         if gated is not None:
@@ -680,19 +676,18 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
                     collect_kv: bool = False, grad_axes=None,
                     forward_only: bool = False):
     """Per-device forward through the final rmsnorm (everything except the
-    unembed matmul).
+    unembed matmul): a ``lax.scan`` of ``models/block.block`` over the layers
+    (with a plan ``models/plan.backbone``'s loop).
 
     tokens: (B/dp, S/sp) int32.  Returns (h (B/dp, S/sp, D) compute-dtype,
     aux) — aux is the summed MoE load-balancing loss (0.0 for dense).
-    With ``collect_kv`` returns (h, (aux, k, v)) where k/v are the
-    post-rope per-layer attention inputs stacked (L, B, T, Hkv/tp, hd) —
-    the KV-cache prefill (models/decode.py); with an index
-    (h, (aux, k, v, ki)): every layer's index keys (L, B, width, T) too,
-    positions last as the carry holds them (models/sparse_index.py); with a
-    plan (h, (aux, *states)) as ``models/plan.backbone`` says; with a hybrid block
-    (h, (aux, k, v, conv, ssm)): every layer's mixer states after the last
-    position too, stacked alike, the second in the block's ``state_dtype``.
-    With that block h comes scaled by its ``lm_head_multiplier``.
+    With ``collect_kv`` (a decoder's prefill) returns ``(h, (aux,
+    *collected))``: what the configuration's mechanisms
+    (``block.mechanisms``) hand a decoder, each state stacked over layers
+    with the sequences on axis 1, in the mechanisms' order: the post-rope k
+    and v (L, B, T, Hkv/tp, hd), then an index's keys or a hybrid block's
+    two states; with a plan its layers' own.
+    With a hybrid block h comes scaled by its ``lm_head_multiplier``.
     ``grad_axes`` (:func:`grad_sum_axes`, the train step's alone): the
     gradient of a layer's leaves is summed over those axes in that layer's
     backward, inside the loop: a projection's or the MLP's matrix where
@@ -706,8 +701,8 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     from jax import lax
 
     from ompi_tpu.core.scopes import scope
+    from ompi_tpu.models import block as blk
     from ompi_tpu.parallel import attention as attn_mod
-    from ompi_tpu.parallel.layers import column_parallel, row_parallel
 
     if cfg.plan is not None:
         # layers of several kinds: a loop over the plan, leaves by kind
@@ -715,29 +710,17 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
 
         return plan.backbone(cfg, comm, params, tokens, collect_kv,
                              grad_axes)
-    cdt = jnp.dtype(cfg.compute_dtype)
-    tp = int(comm.mesh.shape["tp"])
-    sp = int(comm.mesh.shape["sp"])
-    hy = cfg.hybrid
-    if hy is not None:
-        from ompi_tpu.models import ssm
-
-        ssm.check_mesh(cfg, comm.mesh)
-    if cfg.index is not None:
-        from ompi_tpu.models import sparse_index
-
-        sparse_index.check_mesh(cfg, comm.mesh)
-    h_local, kv_local = cfg.n_heads // tp, cfg.kv_heads // tp
-    hd = cfg.head_dim
-    T = tokens.shape[1]
-    sp_idx = lax.axis_index("sp")
-    positions = sp_idx * T + jnp.arange(T)
+    for mechanism in blk.mechanisms(cfg):
+        mechanism.check_mesh(cfg, comm.mesh)
+    cdt, hy, T = jnp.dtype(cfg.compute_dtype), cfg.hybrid, tokens.shape[1]
+    positions = lax.axis_index("sp") * T + jnp.arange(T)
     # one choice of local attention for the layer, made where the layouts
     # make theirs; the rotary embedding writes what that attention reads
-    layout = _ATTENTION_LAYOUT.get(cfg.attention, "gathered")
-    attend = getattr(attn_mod, layout + "_attention")
-    shape = (tokens.shape[0], T, h_local, hd)
-    impl = attn_mod.layout_impl(comm, layout, shape, shape, cdt, "sp")
+    shape = (tokens.shape[0], T, cfg.n_heads // int(comm.mesh.shape["tp"]),
+             cfg.head_dim)
+    impl = attn_mod.layout_impl(
+        comm, _ATTENTION_LAYOUT.get(cfg.attention, "gathered"), shape, shape,
+        cdt, "sp")
 
     with scope("embed"):
         h = _lookup(cfg, params["emb"], tokens)  # (b, t, D)
@@ -751,8 +734,6 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
 
     def layer(h, lp):
         def weights(x, *names):
-            if grad_axes is None:
-                return x, lp
             return _sum_in_backward({k: lp[k] for k in names}, grad_axes,
                                     hold=x)
 
@@ -760,67 +741,10 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
             lp = {**lp, **_sum_in_backward(
                 {k: v for k, v in lp.items() if k not in at_matmul},
                 grad_axes)}
-        with scope("attn_proj"):
-            x = _rmsnorm(h, lp["ln1"], cfg.norm_eps)
-            xa = x if hy is None else x * hy.attention_in_multiplier
-            xa, w = weights(xa, "wq", "wk", "wv")
-            q = column_parallel(xa, w["wq"].astype(cdt))
-            k = column_parallel(xa, w["wk"].astype(cdt))
-            v = column_parallel(xa, w["wv"].astype(cdt))
-            if hy is not None:
-                k = k * hy.key_multiplier
-            if cfg.qk_norm:
-                q = _qk_norm(cfg, q, lp["qn"], comm)
-                k = _qk_norm(cfg, k, lp["kn"], comm)
-            B, t = x.shape[0], x.shape[1]
-            q = _rope(q.reshape(B, t, h_local, hd), positions, impl,
-                      cfg.rope_theta)
-            k = _rope(k.reshape(B, t, kv_local, hd), positions, impl,
-                      cfg.rope_theta)
-            v = v.reshape(B, t, kv_local, hd)
-            k_all, v_all = k, v
-            if kv_local != h_local and cfg.index is None:
-                # each K/V head before its queries
-                k_all, v_all = (jnp.repeat(y, h_local // kv_local, axis=2)
-                                for y in (k, v))
-        if cfg.index is not None:
-            # a slice of queries at a time, each query over its own
-            # selection; K/V heads are read once for their query heads
-            # (the kernel has no backward pass and compiles for the TPU)
-            o, ki = sparse_index.attend(
-                cfg, lp, x, q, k, v, positions, kernel=forward_only
-                and comm.mesh.devices.flat[0].platform == "tpu")
-        else:
-            with scope("attention"):
-                o = attend(comm, q, k_all, v_all, axis="sp", impl=impl)
-        with scope("attn_proj"):
-            o, w = weights(o.reshape(B, t, h_local * hd), "wo")
-            a = row_parallel(o, w["wo"].astype(cdt), comm, axis="tp")
-            if hy is None:
-                h = h + a
-        if hy is not None:
-            # both branches read the one normed input and share a residual
-            s, *states = ssm.mixer(cfg, lp, x)
-            with scope("attn_proj"):
-                h = h + a * hy.attention_out_multiplier + s
-        if cfg.moe_experts:
-            # MoE family: expert-parallel switch FFN over the "ep" axis
-            # (tp ranks replicate the expert compute — activations are
-            # identical across tp after the row_parallel psum)
-            h, aux = _moe_ffn_tail(cfg, h, lp, comm)
-        else:
-            h = _dense_ffn_tail(h, lp, comm, cdt, cfg.norm_eps,
-                                gated=hy and hy.mlp_multipliers,
-                                weights=weights)
-            aux = jnp.zeros((), jnp.float32)
-        if collect_kv and hy is not None:
-            return h, (aux, k, v, states[0],
-                       states[1].astype(hy.state_dtype))
-        if collect_kv and cfg.index is not None:
-            return h, (aux, k, v, ki)
-        if collect_kv:
-            return h, (aux, k, v)
-        return h, aux
+        h, ys = blk.block(cfg, comm, lp, h, positions, impl=impl,
+                          weights=None if grad_axes is None else weights,
+                          forward_only=forward_only)
+        return h, (ys if collect_kv else ys[0])
 
     layer_params = {k: params[k] for k in layer_leaves(cfg)}
     if cfg.remat in (True, "full"):

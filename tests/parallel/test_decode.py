@@ -193,14 +193,14 @@ OLMOE = tfm.TransformerConfig(
 
 def _cached_logits(cfg, mesh, params, tokens, prompt_len):
     """Logits of positions ``prompt_len - 1 ..`` of ``tokens`` through the
-    decoder's own pieces: the backbone as prefill, then ``_step_layer`` a
-    token at a time against the cache, fed the given tokens."""
+    decoder's own pieces: the backbone as prefill, then ``block.block`` with
+    a carry a token at a time against the cache, fed the given tokens."""
     import jax
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from ompi_tpu.models import decode
+    from ompi_tpu.models import block
     from ompi_tpu.mpi.device_comm import DeviceCommunicator
 
     comm = DeviceCommunicator(mesh, tuple(mesh.axis_names))
@@ -212,15 +212,16 @@ def _cached_logits(cfg, mesh, params, tokens, prompt_len):
         h, (_aux, ks, vs) = tfm._local_backbone(
             cfg, comm, params, tokens[:, :prompt_len], collect_kv=True)
         pad = [(0, 0), (0, 0), (0, steps), (0, 0), (0, 0)]
-        kc, vc = jnp.pad(ks, pad), jnp.pad(vs, pad)
+        stacks = [[jnp.pad(ks, pad), jnp.pad(vs, pad)]]
         out = [tfm._whole_vocab(cfg, h[:, -1, :] @ head.T)]
         for pos in range(prompt_len, prompt_len + steps):
             h = tfm._lookup(cfg, params["emb"], tokens[:, pos])[:, None, :]
             for l in range(cfg.n_layers):
                 lp = {k: params[k] if k in EXPERT_LEAVES
                       else params[k][l] for k in tfm.layer_leaves(cfg)}
-                h, kc, vc = decode._step_layer(cfg, comm, lp, h, kc, vc, l,
-                                               jnp.int32(pos))
+                h, stacks = block.block(
+                    cfg, comm, lp, h, jnp.int32(pos)[None],
+                    carry=(stacks, l, jnp.int32(pos)))
             out.append(tfm._whole_vocab(cfg, tfm._rmsnorm(
                 h, params["lnf"], cfg.norm_eps)[:, 0, :] @ head.T))
         return jnp.stack(out, axis=1)
@@ -320,6 +321,133 @@ def test_olmoe_program_logits_against_the_plain_reference(variant, holds):
     got = np.asarray(jax.jit(tfm.make_forward(cfg, mesh))(params, tokens))
     error = np.abs(got - want).max() / want.std()
     assert (error < OLMOE_PARITY) == holds, error
+
+
+# ---- one block for both passes (models/block.py) -----------------------------
+
+def _hybrid(**changes):
+    """A tiny hybrid block, float32 throughout, every multiplier away from
+    one; ``key_multiplier`` far from it."""
+    from ompi_tpu.models.ssm import HybridBlock
+
+    block = HybridBlock(
+        d_ssm=48, d_state=6, n_groups=2, n_heads=6, d_conv=4, chunk=4,
+        embedding_multiplier=1.5, attention_in_multiplier=0.8,
+        attention_out_multiplier=1.25, key_multiplier=3.0,
+        lm_head_multiplier=0.7, ssm_in_multiplier=1.1,
+        ssm_multipliers=(0.9, 1.2, 0.8, 1.1, 0.95), ssm_out_multiplier=0.6,
+        mlp_multipliers=(1.3, 0.75), state_dtype="float32")
+    return dataclasses.replace(CFG, n_kv_heads=2, remat=False, hybrid=block,
+                               **changes)
+
+
+def _drawn(cfg, seed):
+    """Seeded parameters, every norm's scale drawn away from one."""
+    params = tfm.init_params(cfg, seed=seed)
+    rng = np.random.default_rng(seed + 1)
+    for leaf in ("qn", "kn", "ln1", "ln2", "lnf"):
+        if leaf in params:
+            params[leaf] = rng.uniform(0.5, 1.5, size=params[leaf].shape
+                                       ).astype(np.float32)
+    return params
+
+
+@pytest.mark.parametrize("qk_norm", [True, "head"], ids=["whole", "head"])
+def test_a_key_multiplier_beside_qk_norm_is_the_same_in_both_passes(qk_norm):
+    """A hybrid block's ``key_multiplier`` AND q/k-norm: norm, then multiply,
+    in the whole-sequence pass and in the cached step alike.  A multiplier
+    before an RMS norm is taken away by it; while the two passes were written
+    apart the prefill dropped the multiplier so and every cached step applied
+    it, and the decoder's first token and its second came from different
+    models.  Logits of the decoder at every generated position against the
+    whole-sequence forward's over prompt plus continuation."""
+    import jax
+
+    cfg, mesh = _hybrid(qk_norm=qk_norm), _mesh_of(ONE)
+    params = _drawn(cfg, seed=5)
+    prompt = np.random.default_rng(6).integers(
+        0, cfg.vocab, size=(3, 7)).astype(np.int32)
+    tokens, logits = make_decoder(cfg, mesh, max_new=5, keep_logits=3)(
+        params, prompt)
+    full = np.asarray(jax.jit(tfm.make_forward(cfg, mesh))(
+        params, np.asarray(tokens)))[:, 6:-1]
+    assert logits.shape == full.shape == (3, 5, cfg.vocab)
+    np.testing.assert_allclose(np.asarray(logits), full, rtol=0,
+                               atol=2e-5 * full.std())
+    # the multiplier is felt: without it the logits are others
+    flat = dataclasses.replace(cfg, hybrid=dataclasses.replace(
+        cfg.hybrid, key_multiplier=1.0))
+    other = np.asarray(jax.jit(tfm.make_forward(flat, mesh))(
+        params, np.asarray(tokens)))[:, 6:-1]
+    assert np.abs(other - full).max() > 1e-2 * full.std()
+
+
+def _index():
+    from ompi_tpu.models.sparse_index import SparseIndex
+
+    return SparseIndex(n_heads=2, head_dim=8, topk=4, q_slice=4)
+
+
+@pytest.mark.parametrize("cfg", [
+    pytest.param(dataclasses.replace(CFG, remat=False), id="dense"),
+    pytest.param(dataclasses.replace(CFG, remat=False, n_kv_heads=2,
+                                     qk_norm="head"), id="grouped-query"),
+    pytest.param(_hybrid(), id="hybrid"),
+    pytest.param(dataclasses.replace(CFG, remat=False, n_kv_heads=2,
+                                     index=_index()), id="indexed"),
+    pytest.param(dataclasses.replace(CFG, remat=False, moe_experts=4,
+                                     moe_capacity_factor=4.0), id="switch"),
+    pytest.param(dataclasses.replace(OLMOE, n_kv_heads=2), id="dropless"),
+])
+def test_the_blocks_two_forms_agree(cfg):
+    """``block.block`` is one function for both passes: the whole-sequence
+    form over 12 positions against its own carry form, the states that the
+    form without a carry collected over the first 8 handed over by each
+    mechanism's ``carried`` and 4 steps taken with a carry: the same hidden
+    states at the last 4 positions, whatever the configuration carries."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from ompi_tpu.models import block
+    from ompi_tpu.mpi.device_comm import DeviceCommunicator
+
+    mesh = _mesh_of(ONE)
+    comm = DeviceCommunicator(mesh, tuple(mesh.axis_names))
+    params = _drawn(cfg, seed=7)
+    tokens = np.random.default_rng(8).integers(
+        0, cfg.vocab, size=(2, 12)).astype(np.int32)
+    whole = EXPERT_LEAVES if cfg.moe_top_k else ()
+    scale = (1.0, 1.0) if cfg.hybrid is None else (
+        cfg.hybrid.embedding_multiplier, cfg.hybrid.lm_head_multiplier)
+
+    def local(params, tokens):
+        want, _aux = tfm._local_backbone(cfg, comm, params, tokens)
+        _h, (_aux, *collected) = tfm._local_backbone(
+            cfg, comm, params, tokens[:, :8], collect_kv=True)
+        collected = iter(collected)
+        stacks = [mechanism.carried(cfg, mesh, collected, 12)
+                  for mechanism in block.mechanisms(cfg)]
+        assert not list(collected)
+        got = []
+        for at in range(8, 12):
+            pos = jnp.int32(at)
+            h = tfm._lookup(cfg, params["emb"], tokens[:, at])[:, None]
+            h = h * scale[0]
+            for layer in range(cfg.n_layers):
+                lp = {k: params[k] if k in whole else params[k][layer]
+                      for k in tfm.layer_leaves(cfg)}
+                h, stacks = block.block(cfg, comm, lp, h, pos[None],
+                                        carry=(stacks, layer, pos))
+            got.append(tfm._rmsnorm(h, params["lnf"], cfg.norm_eps)[:, 0]
+                       * scale[1])
+        return want[:, 8:], jnp.stack(got, axis=1)
+
+    want, got = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(tfm.param_specs(P, cfg, mesh), P()),
+        out_specs=P(), check_vma=False))(params, tokens)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=2e-5 * np.asarray(want).std())
 
 
 def test_decode_odd_prompt_length():

@@ -393,8 +393,9 @@ def test_a_train_step_runs_on_two_devices():
 
 
 def test_the_carry_is_a_layers_own_buffers_and_the_state_is_float32():
-    _ref, _shape, cfg, _mesh, _params = tiny()
-    buffers = plan.carry(cfg, 3, 20, jnp.bfloat16)
+    _ref, _shape, cfg, mesh, _params = tiny()
+    cfg = dataclasses.replace(cfg, compute_dtype="bfloat16")
+    buffers = plan.carry(cfg, mesh, 3, 20)
     kd, ml = cfg.plan.kda, cfg.plan.mla
     conv = (1, 3, kd.conv - 1, 3 * kd.width)
     state = (1, 3, kd.n_heads, kd.head_dim, kd.head_dim)

@@ -250,7 +250,7 @@ def test_the_index_keys_are_written_at_pos_and_nowhere_else():
     Tmax)`` only ``(layer, :, :, pos)`` changes, to the key the
     whole-sequence path computes for that position; of the one stack of K
     and V only the row ``(layer, :, pos)``; and there is no second stack."""
-    from ompi_tpu.models.decode import _step_layer
+    from ompi_tpu.models.block import block
     from ompi_tpu.mpi.device_comm import DeviceCommunicator
 
     _ref, _shape, cfg, mesh, params = tiny()
@@ -268,9 +268,9 @@ def test_the_index_keys_are_written_at_pos_and_nowhere_else():
           for k in tfm.layer_leaves(cfg)}
 
     def step(h, kc, ic):
-        h, kc, vc, ic = _step_layer(cfg, comm, lp, h, kc, None,
-                                    jnp.int32(layer), jnp.int32(pos), (ic,))
-        assert vc is None
+        at = jnp.int32(pos)
+        h, ((kc, ic),) = block(cfg, comm, lp, h, at[None],
+                               carry=([[kc, ic]], jnp.int32(layer), at))
         return h, kc, ic
 
     _h, kc2, ic2 = jax.jit(jax.shard_map(
