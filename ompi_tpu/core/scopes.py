@@ -78,6 +78,11 @@ SCOPES = (
     "kda.scan",         # ... the chunked recurrence over a whole sequence
     "kda.update",       # ... one cached step's recurrence: from reading the
                         # layer's matrix state out of the carry to writing it
+    "retention.scan",   # power retention's chunked form over a whole sequence
+    "retention.update",     # ... one cached step's recurrence: from reading
+                            # the layer's state out of the carry to writing
+                            # it back, the expansion, the read for the query
+                            # heads and the quotient inside it
     "mla_proj",         # latent attention's ln1, projections, latent norm and
                         # the cached step's absorbed products
     "index_proj",       # the index's three projections, key norm, rotary

@@ -29,11 +29,13 @@ __all__ = ["mixer", "block", "mechanisms", "carry", "carried", "written",
 def mechanisms(cfg) -> tuple:
     """The modules whose state a decoder of ``cfg`` carries, in the carry's
     order: ``plan``; or this one (with an index ``sparse_index``, which lays
-    K and V out with its keys) and, with a hybrid block, ``ssm``.  The one
+    K and V out with its keys; with power retention ``retention``, which
+    keeps a state and no K/V) and, with a hybrid block, ``ssm``.  The one
     place that reads the configuration for them."""
     import importlib
 
-    names = ["block" if cfg.index is None else "sparse_index"]
+    names = ["retention" if cfg.retention is not None
+             else "block" if cfg.index is None else "sparse_index"]
     if cfg.hybrid is not None:
         names.append("ssm")
     return tuple(importlib.import_module("ompi_tpu.models." + name)
@@ -125,7 +127,9 @@ def mixer(cfg, comm, lp, h, positions, carry=None, impl: str = "jnp",
     (B, T, D) at ``positions`` (T,), without its residual add: ln1, the
     projections, q/k-norm and then the hybrid's key multiplier (an RMS norm
     would take a multiplier before it away), the rotary embedding in the
-    form ``impl`` reads, attention, ``wo``.  ``lp``: the layer's leaves.
+    form ``impl`` reads, attention (or, where the configuration has power
+    retention, ``retention.core`` on the same q, k and v and the gate's
+    projection), ``wo``.  ``lp``: the layer's leaves.
     ``weights(x, *names)``, the train step's: ``(x, leaves)`` for the
     matmuls that read ``x`` (``transformer._local_backbone`` says what it
     ties to them); None: ``lp``'s own.
@@ -148,8 +152,10 @@ def mixer(cfg, comm, lp, h, positions, carry=None, impl: str = "jnp",
 
     if cfg.index is not None:
         from ompi_tpu.models import sparse_index
+    if cfg.retention is not None:
+        from ompi_tpu.models import retention
     weights = weights or (lambda x, *_names: (x, lp))
-    cdt, hy = h.dtype, cfg.hybrid
+    cdt, hy, rt = h.dtype, cfg.hybrid, cfg.retention
     B, T, tp = h.shape[0], h.shape[1], int(comm.mesh.shape["tp"])
     hl, hkv, hd = cfg.n_heads // tp, cfg.kv_heads // tp, cfg.head_dim
     with scope("attn_proj"):
@@ -171,10 +177,15 @@ def mixer(cfg, comm, lp, h, positions, carry=None, impl: str = "jnp",
                       cfg.rope_theta)
         v = v.reshape(B, T, hkv, hd)
         k_all, v_all = k, v
-        if carry is None and cfg.index is None and hkv != hl:
+        if rt is not None:      # the decay a K/V head and position
+            logg = retention.log_gate(rt, jnp.einsum(
+                "btd,dg->btg", x, lp["wd"].astype(cdt)))
+        elif carry is None and cfg.index is None and hkv != hl:
             # each K/V head before its queries
             k_all, v_all = (jnp.repeat(y, hl // hkv, axis=2) for y in (k, v))
-    if carry is None and cfg.index is None:
+    if rt is not None:
+        o, out = retention.core(cfg, q, k, v, logg, carry and carry[:2])
+    elif carry is None and cfg.index is None:
         layout = tfm._ATTENTION_LAYOUT.get(cfg.attention, "gathered")
         with scope("attention"):
             o = getattr(attn_mod, layout + "_attention")(
@@ -240,9 +251,12 @@ def block(cfg, comm, lp, h, positions, carry=None, **how):
         h, aux = tfm._moe_ffn_tail(
             cfg, h, lp, comm, layer=layer if cfg.moe_top_k else None)
     else:
+        # a hybrid block's MLP is gated under its multipliers, a retention
+        # layer's plainly
+        gated = (hy.mlp_multipliers if hy is not None
+                 else cfg.retention and (1.0, 1.0))
         h = tfm._dense_ffn_tail(h, lp, comm, h.dtype, cfg.norm_eps,
-                                gated=hy and hy.mlp_multipliers,
-                                weights=how.get("weights"))
+                                gated=gated, weights=how.get("weights"))
     if carry is not None:
         return h, [own] if hy is None else [own, states]
     if not cfg.moe_experts:

@@ -6,7 +6,9 @@ TPU-first shape: ONE compiled program per (prompt_len, max_new) pair —
 prefill runs the training backbone (``collect_kv`` returns what every
 layer's mixers hand over, in a single pass), then a ``lax.scan`` generates
 tokens against a static-shape carry (no growing arrays, no recompilation per
-token).  A layer plan's decoder is two programs (:func:`_two_programs`).
+token).  Where every mechanism says which of its buffers grow with the
+sequence (``grows``: a layer plan, power retention) the decoder is two
+programs, the prefill one of its own (:func:`_two_programs`).
 
 The life of the carry: it is allocated once at its final length, and from
 then on it is loop carry — of the token scan and, inside it, of a
@@ -80,9 +82,9 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
     each layer's mixers hand over collected), then ``max_new`` single-token
     steps over the static carry.  Requires sp == 1; dense, switch-MoE,
     dropless top-k MoE, hybrid (``models/ssm.py``), indexed
-    (``models/sparse_index.py``) and planned (``models/plan.py``) configs
-    are supported (MoE routes each token through the same layer as
-    training).
+    (``models/sparse_index.py``), planned (``models/plan.py``) and
+    power-retention (``models/retention.py``) configs are supported (MoE
+    routes each token through the same layer as training).
 
     The carry of the token scan and of the loop over layers inside it is a
     list of stacks for each of ``block.mechanisms(cfg)``, ``Tp + max_new``
@@ -109,12 +111,15 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
     """
     from ompi_tpu.core import scopes
 
+    from ompi_tpu.models import block as blk
+
     with scopes.host("build.decoder", program="decode"):
-        if cfg.plan is not None:
-            return _two_programs(cfg, mesh, max_new, temperature, top_k,
-                                 keep_logits)
-        return _one_program(cfg, mesh, max_new, temperature, top_k,
-                            keep_logits)
+        # a carry whose every mechanism says which buffers grow can be
+        # handed from one program to the next
+        split = all(hasattr(mechanism, "grows")
+                    for mechanism in blk.mechanisms(cfg))
+        return (_two_programs if split else _one_program)(
+            cfg, mesh, max_new, temperature, top_k, keep_logits)
 
 
 def _halves(cfg: TransformerConfig, mesh, max_new: int,
@@ -342,7 +347,8 @@ def _prefill_program(cfg: TransformerConfig, mesh, temperature: float,
                      top_k: int, keep_logits: int):
     """jitted (params, prompt (B, Tp), seed) -> (tokens (B, Tp+1), logits
     (keep_logits, 1, V), carry): what a decoder of ``max_new=1`` returns, and
-    the carry ``Tp`` positions long (``plan.carry``'s buffers).  One object
+    the carry ``Tp`` positions long (every mechanism's buffers, in
+    ``block.mechanisms``' order, flat).  One object
     for every ``max_new`` of a configuration on a mesh, so one executable:
     :func:`_two_programs` says why."""
     import jax.numpy as jnp
@@ -352,10 +358,11 @@ def _prefill_program(cfg: TransformerConfig, mesh, temperature: float,
                                       keep_logits)
 
     def local(params, prompt, seed):
-        tok0, logits, (buffers,) = prefill(
+        tok0, logits, stacks = prefill(
             params, unembedding(params), prompt, seed)
         return (jnp.concatenate([prompt, tok0[:, None]], axis=1),
-                logits[:keep_logits, None], tuple(buffers))
+                logits[:keep_logits, None],
+                tuple(buffer for own in stacks for buffer in own))
 
     return _program(cfg, mesh, local, (P("dp", None), P()),
                     (P("dp", None), P(), P(None, "dp")))
@@ -363,11 +370,12 @@ def _prefill_program(cfg: TransformerConfig, mesh, temperature: float,
 
 def _two_programs(cfg: TransformerConfig, mesh, max_new: int,
                   temperature: float, top_k: int, keep_logits: int):
-    """:func:`make_decoder` of a configuration with a layer plan: the prefill
-    a program of its own (:func:`_prefill_program`), and for ``max_new > 1``
-    a second that takes its carry over (donated: the states are updated in
-    the buffers the prefill filled), lengthens the latent caches to ``Tp +
-    max_new`` and generates.
+    """:func:`make_decoder` of a configuration whose mechanisms say which of
+    their buffers grow (a layer plan, power retention): the prefill a program
+    of its own (:func:`_prefill_program`), and for ``max_new > 1`` a second
+    that takes its carry over (donated: the states are updated in the buffers
+    the prefill filled), lengthens the caches that grow to ``Tp + max_new``
+    and generates.
 
     Every decoder of one configuration on one mesh starts from the same
     prefill executable, so what two of them make of the same prompts is the
@@ -375,12 +383,15 @@ def _two_programs(cfg: TransformerConfig, mesh, max_new: int,
     are compiled apart; on the chip a few of 384 first tokens then differed
     between ``max_new=1`` and ``max_new=128`` in every run (PR 45): a sum in
     another order turns a router's tie somewhere in a prompt, and the state
-    remembers it.  A service that answers with the first token from one
-    program and goes on from another cannot have that."""
+    remembers it; with power retention, whose logits have no router behind
+    them, one run in three still had a first token whose two best logits lay
+    within the two compilations' rounding of each other (PR 49).  A service
+    that answers with the first token from one program and goes on from
+    another cannot have that."""
     from jax.sharding import PartitionSpec as P
 
     from ompi_tpu.core.scopes import scope
-    from ompi_tpu.models import plan
+    from ompi_tpu.models import block as blk
 
     first = _prefill_program(cfg, mesh, float(temperature), top_k,
                              keep_logits)
@@ -395,18 +406,22 @@ def _two_programs(cfg: TransformerConfig, mesh, max_new: int,
                                        top_k, keep_logits)
     # a latent cache grows to Tp + max_new here, so its buffer is of no use
     # to this program's outputs; every other one is written where it lies
-    grows = plan.grows(cfg)
+    mechanisms = blk.mechanisms(cfg)
+    grows = tuple(grown for mechanism in mechanisms
+                  for grown in mechanism.grows(cfg))
 
     def local(params, tokens, kept, seed, fixed, growing):
         fixed, growing = list(fixed), list(growing)
-        carry = [(growing if g else fixed).pop(0) for g in grows]
+        carry = iter([(growing if g else fixed).pop(0) for g in grows])
         head, prompt = unembedding(params), tokens[:, :-1]
         tok0, logits = tokens[:, -1], kept[:, 0]
         with scope("prefill"):      # the prefill program's carry ends at Tp
-            carry = plan.carried(cfg, mesh, carry, prompt.shape[1] + max_new)
-        out, (carry,) = generate(params, head, prompt, seed, tok0, logits,
-                                 [carry])
-        return out, tuple(carry)
+            stacks = [mechanism.carried(cfg, mesh, carry,
+                                        prompt.shape[1] + max_new)
+                      for mechanism in mechanisms]
+        out, stacks = generate(params, head, prompt, seed, tok0, logits,
+                               stacks)
+        return out, tuple(buffer for own in stacks for buffer in own)
 
     # the states come back so that each is written in the buffer it came
     # in: a donated buffer is reused for an output of its shape alone

@@ -165,6 +165,11 @@ class TransformerConfig:
     # then prefilled a group of whole sequences at a time, each group writing
     # into the cache that was allocated once.  0: every prompt in one pass.
     prefill_tokens: int = 0
+    # models/retention.Retention: every layer's attention core is power
+    # retention (a decayed power of q . k, carried as a matrix state and a
+    # normaliser a K/V head and no K/V cache) with a leaf "wd" for its gate,
+    # and its dense MLP is gated (a third leaf "w3").  None: softmax.
+    retention: Any = None
 
     @property
     def head_dim(self) -> int:
@@ -236,6 +241,10 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> dict:
         from ompi_tpu.models import sparse_index
 
         params.update(sparse_index.init_leaves(cfg, rng))
+    if cfg.retention is not None:
+        from ompi_tpu.models import retention
+
+        params.update(retention.init_leaves(cfg, rng))
     return _as_stored(cfg, params)
 
 
@@ -316,6 +325,8 @@ def param_specs(P, cfg: Optional[TransformerConfig] = None, mesh=None):
         from ompi_tpu.models import sparse_index
 
         specs.update({leaf: P() for leaf in sparse_index.leaf_names()})
+    if cfg is not None and cfg.retention is not None:
+        specs.update({"wd": P(), "w3": specs["w1"]})
     return specs
 
 
@@ -391,6 +402,10 @@ def layer_leaves(cfg: TransformerConfig) -> tuple:
         from ompi_tpu.models import sparse_index
 
         leaves += sparse_index.leaf_names()
+    if cfg.retention is not None:
+        from ompi_tpu.models import retention
+
+        leaves += retention.leaf_names()
     return tuple(leaves)
 
 
@@ -686,7 +701,8 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     (``block.mechanisms``) hand a decoder, each state stacked over layers
     with the sequences on axis 1, in the mechanisms' order: the post-rope k
     and v (L, B, T, Hkv/tp, hd), then an index's keys or a hybrid block's
-    two states; with a plan its layers' own.
+    two states; with power retention its state and normaliser and no k or
+    v; with a plan its layers' own.
     With a hybrid block h comes scaled by its ``lm_head_multiplier``.
     ``grad_axes`` (:func:`grad_sum_axes`, the train step's alone): the
     gradient of a layer's leaves is summed over those axes in that layer's

@@ -388,6 +388,14 @@ def _index():
     return SparseIndex(n_heads=2, head_dim=8, topk=4, q_slice=4)
 
 
+def _retention():
+    from ompi_tpu.models.retention import Retention
+
+    return dataclasses.replace(
+        CFG, remat=False, n_kv_heads=2, qk_norm="head",
+        retention=Retention(gate_offset=1.0, chunk=3))
+
+
 @pytest.mark.parametrize("cfg", [
     pytest.param(dataclasses.replace(CFG, remat=False), id="dense"),
     pytest.param(dataclasses.replace(CFG, remat=False, n_kv_heads=2,
@@ -398,6 +406,7 @@ def _index():
     pytest.param(dataclasses.replace(CFG, remat=False, moe_experts=4,
                                      moe_capacity_factor=4.0), id="switch"),
     pytest.param(dataclasses.replace(OLMOE, n_kv_heads=2), id="dropless"),
+    pytest.param(_retention(), id="retention"),
 ])
 def test_the_blocks_two_forms_agree(cfg):
     """``block.block`` is one function for both passes: the whole-sequence
