@@ -30,7 +30,11 @@ end.  :func:`update` is one position against the carried state
 quotient are float32 whatever the compute type;
 a cached step's products against the state are float32 too, the chunked
 form's run in the compute type and add up in float32, as the other mixers'.
-Everything here is ``jax.numpy`` and ``lax``: no kernel.
+Everything here is ``jax.numpy`` and ``lax`` but a cached step's pass over
+the matrix state, which on TPUs is one pallas kernel over the layer where it
+lies in the stack (``ops/retention_update.py``; ``retention_update.block`` is
+the rule, from static facts alone): :func:`read`'s sums over the state and
+:func:`write`'s decay and outer product in one sweep (:class:`InPlace`).
 
 What the state's layout is: ``phi(u)`` holds ``u_a u_{a+s}`` (indices modulo
 the head width ``d``) for the shifts ``s = 0 .. d/2``, a row of ``d`` a shift:
@@ -52,7 +56,7 @@ import math
 import numpy as np
 
 __all__ = ["Retention", "retention_config", "phi", "chunked", "read", "write",
-           "update", "core", "log_gate", "leaf_names", "init_leaves",
+           "update", "InPlace", "core", "log_gate", "leaf_names", "init_leaves",
            "state_shapes", "carry", "carried", "grows", "check_mesh"]
 
 
@@ -262,38 +266,8 @@ def read(S, z, q, k, v, logg, eps: float):
     for the R heads: ``S_t^T phi(q) = g_t S_{t-1}^T phi(q) + (phi(k_t) .
     phi(q)) v_t``, the normaliser alike, and the quotient.  Taken from the
     old state so that the read does not wait for the write and the new state
-    exists in the carry alone.  Returns y (B, G, R, d) float32."""
-    import jax.numpy as jnp
-
-    f32 = jnp.float32
-    g = jnp.exp(logg.astype(f32))
-    pk, pq = phi(k), phi(q, 1.0 / k.shape[-1])
-    own = jnp.sum(pk[:, :, None] * pq, axis=-1)         # (B, G, R): a(t, t)
-    num = (g[..., None, None] * jnp.sum(S[:, :, None] * pq[..., None], axis=3)
-           + own[..., None] * v.astype(f32)[:, :, None])
-    den = g[..., None] * jnp.sum(z[:, :, None] * pq, axis=-1) + own
-    return _quotient(num, den, eps)
-
-
-def write(S, z, k, v, logg):
-    """The state after a new position: ``g S + phi(k) v^T`` and ``g z +
-    phi(k)``, float32."""
-    import jax.numpy as jnp
-
-    f32 = jnp.float32
-    g, pk, v = jnp.exp(logg.astype(f32)), phi(k), v.astype(f32)
-    return (S * g[..., None, None] + pk[..., None] * v[..., None, :],
-            z * g[..., None] + pk)
-
-
-def read(S, z, q, k, v, logg, eps: float):
-    """What the query heads q (B, G, R, d) of each K/V head read at a new
-    position with k, v (B, G, d) and logg (B, G), from the state the step
-    starts from, ``S`` (B, G, D, d) and ``z`` (B, G, D) float32, read once
-    for the R heads: ``S_t^T phi(q) = g_t S_{t-1}^T phi(q) + (phi(k_t) .
-    phi(q)) v_t``, the normaliser alike, and the quotient.  Taken from the
-    old state so that the read does not wait for the write and the new state
-    exists in the carry alone.  Returns y (B, G, R, d) float32."""
+    exists in the carry alone.  ``S`` may be the layer where it lies, swept
+    (:class:`InPlace`).  Returns y (B, G, R, d) float32."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -301,23 +275,31 @@ def read(S, z, q, k, v, logg, eps: float):
     g = jnp.exp(logg.astype(f32))
     pk, pq = phi(k), phi(q, 1.0 / k.shape[-1])
     own = jnp.sum(pk[:, :, None] * pq, axis=-1)         # (B, G, R): a(t, t)
-    # a float32 product on the matrix unit: as a sum of products the
-    # compiler first copies the layer's state out of the stack, two passes
-    # over it more, and a step took 66 ms for 35 (PR 49, on the chip)
-    num = (g[..., None, None] * jnp.einsum(
-        "bgnv,bgrn->bgrv", S, pq, precision=lax.Precision.HIGHEST)
-        + own[..., None] * v.astype(f32)[:, :, None])
+    if isinstance(S, InPlace):      # the kernel's sweep added them up
+        sums = S.sums
+    else:
+        # a float32 product on the matrix unit: as a sum of products the
+        # compiler first copies the layer's state out of the stack, two
+        # passes over it more, and a step took 66 ms for 35 (PR 49, on the
+        # chip)
+        sums = jnp.einsum("bgnv,bgrn->bgrv", S, pq,
+                          precision=lax.Precision.HIGHEST)
+    num = (g[..., None, None] * sums
+           + own[..., None] * v.astype(f32)[:, :, None])
     den = g[..., None] * jnp.sum(z[:, :, None] * pq, axis=-1) + own
     return _quotient(num, den, eps)
 
 
 def write(S, z, k, v, logg):
     """The state after a new position: ``g S + phi(k) v^T`` and ``g z +
-    phi(k)``, float32."""
+    phi(k)``, float32; of a layer where it lies (:class:`InPlace`), the
+    layer with what its sweep is to add."""
     import jax.numpy as jnp
 
     f32 = jnp.float32
     g, pk, v = jnp.exp(logg.astype(f32)), phi(k), v.astype(f32)
+    if isinstance(S, InPlace):
+        return dataclasses.replace(S, adds=(g, pk, v)), z * g[..., None] + pk
     return (S * g[..., None, None] + pk[..., None] * v[..., None, :],
             z * g[..., None] + pk)
 
@@ -362,12 +344,48 @@ def carried(cfg, mesh, collected, t_max: int, into=None, **group) -> list:
             for stack in into or (None, None)]
 
 
+@dataclasses.dataclass(frozen=True)
+class InPlace:
+    """Layer ``layer`` of the stacked matrix states where it lies: a cached
+    step's ``S`` where the update is the kernel's one pass
+    (``ops/retention_update.py``), in which nothing may slice the layer out
+    of the stack (a slice handed to a kernel and written back is four
+    passes more, not one fewer).  :func:`write` leaves what it adds in
+    ``adds``, :meth:`swept` is the pass, and :func:`read` takes what the
+    query heads read of the state before it from ``sums``."""
+    stack: object               # (L, B, G, D, d)
+    layer: object               # a traced int32
+    adds: tuple = None          # (g, phi(k), v) of the position
+    sums: object = None         # S^T phi(q), (B, G, R, d)
+
+    # what an array in its place would say of itself: float32, as
+    # :func:`_state_before` gives a layer
+    shape = property(lambda self: self.stack.shape[1:])
+    dtype = property(lambda self: np.dtype(np.float32))
+
+    def swept(self, pq):
+        """The kernel's pass for the query heads' ``pq`` (B, G, R, D): the
+        stack after the write, and what they read of the state before it."""
+        from ompi_tpu.ops.retention_update import retention_update
+
+        sums, stack = retention_update(self.stack, self.layer, pq, *self.adds)
+        return dataclasses.replace(self, stack=stack, sums=sums)
+
+
 def _state_before(stack, layer):
     """A cached step's state of layer ``layer`` as the update starts from
-    it, float32."""
+    it, float32: an array, or the stack of matrix states itself where the
+    update is the kernel's (``retention_update.block``: traced for TPUs, a
+    float32 state of heads that tile), :class:`InPlace`."""
     import jax.numpy as jnp
     from jax import lax
 
+    from ompi_tpu.models import kda
+    from ompi_tpu.ops import retention_update
+
+    if stack.ndim == 5 and retention_update.block(
+            kda._traced_for_tpus(), stack.dtype, *stack.shape[3:]):
+        return InPlace(stack, layer)
     return lax.dynamic_index_in_dim(stack, layer,
                                     keepdims=False).astype(jnp.float32)
 
@@ -380,7 +398,9 @@ def core(cfg, q, k, v, logg, carry=None):
     z))``, y (B, T, H, d) float32 and the layer's state after the last
     position as the carry stores it.  ``carry = ((S, z), layer)``: T == 1
     against layer ``layer`` of the stacks (:func:`carry`'s), read and written
-    in place; returns ``(y, [S, z])``, the stacks."""
+    in place, by the kernel where :func:`_state_before` hands the matrix
+    states over as they lie and by :func:`read`, a barrier and :func:`write`
+    on a slice anywhere else; returns ``(y, [S, z])``, the stacks."""
     from jax import lax
 
     from ompi_tpu.core.scopes import scope
@@ -394,20 +414,29 @@ def core(cfg, q, k, v, logg, carry=None):
         return y, (S.astype(rt.state_dtype), z.astype(rt.state_dtype))
     (S_c, z_c), layer = carry
     now = k[:, 0], v[:, 0], logg[:, 0]
+    heads = q[:, 0].reshape(B, G, H // G, d)
     with scope("attention"), scope("retention.update"):
-        y = read(_state_before(S_c, layer), _state_before(z_c, layer),
-                 q[:, 0].reshape(B, G, H // G, d), *now, rt.eps)
-        # the read is done before the write begins: left to itself the
-        # compiler writes first and keeps a copy of the old state to read
-        (S_c, z_c), y = lax.optimization_barrier(((S_c, z_c), y))
-        S, z = write(_state_before(S_c, layer), _state_before(z_c, layer),
-                     *now)
-        S_c = lax.dynamic_update_slice(S_c, S.astype(S_c.dtype)[None],
-                                       (layer, 0, 0, 0, 0))
+        S, z = _state_before(S_c, layer), _state_before(z_c, layer)
+        if isinstance(S, InPlace):
+            # one pass over the layer where it lies, which adds what
+            # ``write`` says and sums what ``read`` reads
+            S, z_new = write(S, z, *now)
+            S = S.swept(phi(heads, 1.0 / d))
+            y, S_c = read(S, z, heads, *now, rt.eps), S.stack
+        else:
+            y = read(S, z, heads, *now, rt.eps)
+            # the read is done before the write begins: left to itself the
+            # compiler writes first and keeps a copy of the old state to
+            # read
+            (S_c, z_c), y = lax.optimization_barrier(((S_c, z_c), y))
+            S, z_new = write(_state_before(S_c, layer),
+                             _state_before(z_c, layer), *now)
+            S_c = lax.dynamic_update_slice(S_c, S.astype(S_c.dtype)[None],
+                                           (layer, 0, 0, 0, 0))
     # the normaliser's write is the carry's write every decoder's programs
-    # have under this name; the matrix state's is the update's own, which
-    # the compiler fuses with the decay
+    # have under this name; the matrix state's is the update's own: the
+    # kernel's, or a fusion the compiler makes of it with the decay
     with scope("kv_cache"):
-        z_c = lax.dynamic_update_slice(z_c, z.astype(z_c.dtype)[None],
+        z_c = lax.dynamic_update_slice(z_c, z_new.astype(z_c.dtype)[None],
                                        (layer, 0, 0, 0))
     return y.reshape(B, 1, H, d), [S_c, z_c]

@@ -4,7 +4,8 @@ float32, seeded, on the CPU: the feature map, the chunked form against the
 recurrence and the quadratic form, prefill then cached steps against the full
 forward on logits, which K/V head's state a query head reads, the carry and
 its in-place write, loss and gradient of the train path, the form a cell's
-cached update takes, and the layouts that are refused.  Agreement and
+cached update takes (the rule of ``ops/retention_update.py``, from the cell's
+own files), and the layouts that are refused.  Agreement and
 control flow only: nothing here is a time.
 """
 
@@ -263,18 +264,44 @@ def _cell_state(**changes):
 
 
 # Which form every cached step of a retention cell takes (PERF.md section 5).
-@pytest.mark.parametrize("changes,dtype", [
-    pytest.param({}, "float32", id="brumby-step"),
-    pytest.param({"retention_state_dtype": "bfloat16"}, "bfloat16",
+@pytest.mark.parametrize("tpu,changes,takes", [
+    pytest.param(True, {}, (1664, 128), id="brumby-step-on-the-chip"),
+    pytest.param(False, {}, None, id="brumby-step-on-the-cpu"),
+    pytest.param(True, {"retention_state_dtype": "bfloat16"}, None,
                  id="a-bfloat16-state"),
+    pytest.param(True, {"head_dim": 64}, None, id="heads-of-64"),
 ])
-def test_which_update_each_retention_cell_takes(changes, dtype):
-    """One form wherever it runs: the state read by one float32 product on
-    the matrix unit (a sum of products makes the compiler copy the layer's
-    state out of the stack first), no kernel, the write after the read."""
+def test_which_update_each_retention_cell_takes(tpu, changes, takes,
+                                                monkeypatch):
+    """One rule from static facts (``retention_update.block``): on TPUs a
+    float32 state of heads that tile goes through the kernel where it lies
+    in the stack, a fifth of a (sequence, K/V head)'s rows a block, once a
+    layer and step; anywhere else the state is read by one float32 product
+    on the matrix unit (a sum of products makes the compiler copy the
+    layer's state out of the stack first), a barrier, then the write."""
+    from ompi_tpu.models import kda
+    from ompi_tpu.ops import retention_update
+
     cfg, shape = _cell_state(**changes)
-    assert shape == (48, 8, 8320, 128) and cfg.retention.state_dtype == dtype
-    B, G, D, d = shape
+    d = changes.get("head_dim", 128)
+    D = retention.state_dim(d)
+    assert shape == (48, 8, D, d)
+    assert cfg.retention.state_dtype == changes.get(
+        "retention_state_dtype", "float32")
+    B, G, _D, _d = shape
+    assert retention_update.block(tpu, cfg.retention.state_dtype,
+                                  D, d) == takes
+    # and the core asks that rule of the stack it is handed, and nothing else
+    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: tpu)
+    stack = jax.ShapeDtypeStruct((cfg.n_layers, *shape),
+                                 cfg.retention.state_dtype)
+    if takes:
+        held = retention._state_before(stack, 1)
+        assert isinstance(held, retention.InPlace) and held.stack is stack
+        assert (held.shape, held.dtype) == (shape, jnp.float32)
+        return
+    before = jax.eval_shape(lambda s: retention._state_before(s, 1), stack)
+    assert (before.shape, before.dtype) == (shape, jnp.float32)
     R = cfg.n_heads // G
     f32, cdt = jnp.float32, jnp.bfloat16
     jaxpr = jax.make_jaxpr(retention.read, static_argnums=6)(
