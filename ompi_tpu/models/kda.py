@@ -13,13 +13,16 @@ From the block's input ``h`` and its norm ``x = RMSNorm(h; ln1)``:
 
 :func:`mixer` is the one function both paths call, as ``ssm.mixer`` is: the
 whole sequence from a zero state (trainer, prefill: a causal convolution
-over the sequence and :func:`chunked`) and one position against a carried
-state (``models/decode.py``: the convolution from its last inputs, the
-recurrence once).  Everything here is ``jax.numpy`` and ``lax`` but the
-cached step's recurrence, :func:`update`, which on TPUs is one pallas pass
-over the layer's state (``ops/kda_update.py``; ``kda_update.block`` is the
-rule, from static facts alone).  The decay, the recurrence and the norms are
-float32 whatever the compute type.
+over the sequence and :func:`chunked`, the rule in blocks of 64 positions:
+what reads the inputs alone, the triangular solve among it, is made for all
+the blocks of a pass at once, and a scan over the blocks carries the state
+through five products a block) and one position against a carried state
+(``models/decode.py``: the convolution from its last inputs, the recurrence
+once).  Everything here is ``jax.numpy`` and ``lax`` but the cached step's
+recurrence, :func:`update`, which on TPUs is one pallas pass over the
+layer's state (``ops/kda_update.py``; ``kda_update.block`` is the rule, from
+static facts alone).  The decay, the recurrence and the norms are float32
+whatever the compute type.
 
 Nothing imports this module but a configuration whose plan has the kind.
 """
@@ -43,11 +46,12 @@ class KDA:
     conv: int                   # taps of the causal depthwise convolutions
     rank: int                   # of the decay's and the output gate's
     #                             low-rank projections
-    # positions a block of the whole-sequence form: inside it position i
-    # reads j <= i through exp(G_i - G_j) a channel, formed from the
-    # difference (never exp(-G_j), which overflows where a channel decays
-    # fast); across blocks the recurrence on the blocks' final states
-    chunk: int = 16
+    # positions a block of the whole-sequence form (:func:`chunked`): the
+    # recurrence on the blocks' final states runs across blocks, so a block
+    # is a scan step and half a pass of the matrix unit's rows; inside it
+    # position i reads j <= i through exp(G_i - G_j) a channel, never
+    # through exp(-G_j), which overflows where a channel decays fast
+    chunk: int = 64
     # what the carried matrix state is stored in between cached steps; the
     # update itself is float32
     state_dtype: str = "float32"
@@ -93,54 +97,116 @@ def chunked(q, k, v, g, beta, chunk: int):
 
     Inside a block that starts from state ``S0``, with ``G`` the decay's
     running sum: ``u_i = beta_i (v_i - S0^T (k_i e^{G_i}) - sum_{j<i} A_ij
-    u_j)``, ``A_ij = sum_d k_id k_jd e^{G_id - G_jd}``: a unit lower
-    triangular system, solved a row at a time; ``o_i = S0^T (q_i e^{G_i}) +
-    sum_{j<=i} B_ij u_j`` with ``B`` as ``A`` but of q against k; the state
-    after the block ``e^{G_last} S0 + sum_j (k_j e^{G_last - G_j}) u_j^T``.
-    Every exponent is a difference that is at most zero.  A length that is
-    no multiple of the block is padded with positions of g = 0 and beta = 0,
-    which leave the state as it is."""
+    u_j)``, ``A_ij = sum_d k_id k_jd e^{G_id - G_jd}``, a unit lower
+    triangular system; ``o_i = S0^T (q_i e^{G_i}) + sum_{j<=i} B_ij u_j``
+    with ``B`` as ``A`` but of q against k; the state after the block
+    ``e^{G_last} S0 + sum_j (k_j e^{G_last - G_j}) u_j^T``.
+
+    ``A``, ``B`` and ``beta`` read the inputs alone, so they and the
+    triangle's inverse ``T = (I + beta A)^-1`` are made for every block of
+    the pass at once, and a step of the scan over blocks is what reads the
+    state: ``U = T (beta (v - (k e^G) S0))``, ``o`` and the next state, five
+    products of ``chunk`` rows.  A block is sub-blocks of ``min(16, chunk)``
+    positions.  Where i and j share one, ``A_ij`` and ``B_ij`` are summed
+    over the differences themselves, a channel at a time; where j's lies
+    before i's they are products of ``q_i e^{G_i - r}`` (or k_i) with ``k_j
+    e^{r - G_j}``, ``r`` the running sum where i's sub-block starts.  Every
+    exponent is a sum of g's, at most zero: no factor passes 1, and one
+    underflows only where the whole term does.  ``T``'s diagonal sub-blocks
+    are inverted by forward substitution a row at a time, all of them
+    together, and a sub-block's row of the others follows from the rows
+    above it; for that the blocks are laid out last (the lanes, on TPUs,
+    which a 16 x 16 matrix a block would fill an eighth of).  A length that
+    is no multiple of the block is padded with positions of g = 0 and
+    beta = 0, which leave the state as it is."""
     import jax.numpy as jnp
     from jax import lax
+    from jax.experimental.layout import Layout, with_layout_constraint
 
     f32, hi = jnp.float32, lax.Precision.HIGHEST
     B, T, H, K = q.shape
-    c = chunk
-    nc = -(-T // c)
+    c, s = chunk, min(16, chunk)
+    if c % s:
+        raise ValueError(f"a block of {c} positions is no multiple of its "
+                         f"sub-blocks' {s}")
+    n, N = c // s, -(-T // c)
 
-    def blocks(y):      # (B, T, H, ...) -> (nc, B, H, c, ...)
-        y = jnp.pad(y, [(0, 0), (0, nc * c - T)] + [(0, 0)] * (y.ndim - 2))
-        y = y.reshape(B, nc, c, *y.shape[2:])
+    def blocks(y):      # (B, T, H, ...) -> (N, B, H, c, ...)
+        y = jnp.pad(y.astype(f32),
+                    [(0, 0), (0, N * c - T)] + [(0, 0)] * (y.ndim - 2))
+        y = y.reshape(B, N, c, *y.shape[2:])
         return jnp.moveaxis(jnp.moveaxis(y, 1, 0), 2, 3)
 
-    upto = jnp.tril(jnp.ones((c, c), bool))
-    before = jnp.tril(jnp.ones((c, c), f32), -1)
+    def sub(y):         # (N, B, H, c, ...) -> (N, B, H, n, s, ...)
+        return y.reshape(N, B, H, n, s, *y.shape[4:])
 
-    def one(S, block):
-        q, k, v, g, b = block               # (B, H, c, K); b (B, H, c)
-        G = jnp.cumsum(g, axis=2)
-        w = jnp.exp(jnp.where(upto[:, :, None], G[:, :, :, None, :]
-                              - G[:, :, None, :, :], -jnp.inf))
-        kj = k[:, :, None, :, :] * w        # (B, H, i, j, K)
-        A = jnp.sum(k[:, :, :, None, :] * kj, axis=-1) * before
-        Bm = jnp.sum(q[:, :, :, None, :] * kj, axis=-1)
+    def mm(spec, x, y):
+        return jnp.einsum(spec, x, y, precision=hi)
+
+    q, k, v, g, b = (blocks(y) for y in (q, k, v, g, beta))
+    G = jnp.cumsum(g, axis=3)
+    ks, Gs = sub(k), sub(G)
+    # j <= i of one sub-block: k_j e^{G_i - G_j}, from the differences
+    near = ks[..., None, :, :] * jnp.exp(jnp.where(
+        jnp.tril(jnp.ones((s, s), bool))[:, :, None],
+        Gs[..., :, None, :] - Gs[..., None, :, :], -jnp.inf))
+    # sub-blocks 1 to n - 1 against every position before them, through the
+    # running sum where each starts
+    start = Gs[..., :-1, -1, :]                             # (..., n - 1, K)
+    since = jnp.exp(Gs[..., 1:, :, :] - start[..., None, :])
+    before = jnp.arange(c) < s * jnp.arange(1, n)[:, None]  # (n - 1, c)
+    far = k[..., None, :, :] * jnp.exp(jnp.where(
+        before[..., None], start[..., None, :] - G[..., None, :, :],
+        -jnp.inf))                                          # (..., n - 1, c, K)
+
+    def against_k(y):   # (N, B, H, c, K) -> (N, B, H, c, c)
+        diagonal = jnp.sum(sub(y)[..., :, None, :] * near, axis=-1)
+        return jnp.concatenate(
+            [jnp.pad(diagonal[..., a, :, :],
+                     [(0, 0)] * 4 + [(a * s, c - (a + 1) * s)])
+             for a in range(n)], axis=-2) + jnp.pad(
+            mm("...aik,...ajk->...aij", sub(y)[..., 1:, :, :] * since, far
+               ).reshape(N, B, H, c - s, c), [(0, 0)] * 3 + [(s, 0), (0, 0)])
+
+    A, Bm = against_k(k), against_k(q)
+
+    # T = (I + beta A_strict)^-1 with the blocks of the pass laid out last:
+    # the diagonal sub-blocks a row at a time, all of them together, then a
+    # sub-block's row of the others from the rows above it
+    def blocks_last(y):
+        return with_layout_constraint(
+            y, Layout(major_to_minor=tuple(range(y.ndim))))
+
+    L = blocks_last(jnp.moveaxis(
+        (b[..., None] * A * jnp.tril(jnp.ones((c, c), f32), -1)
+         ).reshape(N * B * H, n, s, c), 0, -1))             # (n, s, c, blocks)
+    Ld = jnp.stack([L[a, :, a * s:(a + 1) * s] for a in range(n)])
+    X = jnp.broadcast_to(jnp.eye(s, dtype=f32)[:, :, None], Ld.shape)
+    for i in range(1, s):
+        X = X.at[:, i].add(-jnp.sum(Ld[:, i, :i, None] * X[:, :i], axis=1))
+    rows = [X[0]]                                           # (s, s, blocks)
+    for a in range(1, n):
+        above = jnp.concatenate([jnp.pad(r, [
+            (0, 0), (0, a * s - r.shape[1]), (0, 0)]) for r in rows])
+        left = jnp.sum(L[a, :, :a * s, None] * above, axis=1)
+        rows.append(jnp.concatenate(
+            [-jnp.sum(X[a][:, :, None] * left, axis=1), X[a]], axis=1))
+    Tm = jnp.moveaxis(blocks_last(jnp.concatenate([jnp.pad(r, [
+        (0, 0), (0, c - r.shape[1]), (0, 0)]) for r in rows])), -1, 0
+    ).reshape(N, B, H, c, c)
+
+    def one(S, block):      # what reads the state, and nothing else
+        q, k, v, G, b, Tm, Bm = block
         eG = jnp.exp(G)
-        U = b[..., None] * (v - jnp.einsum(
-            "bhck,bhkv->bhcv", k * eG, S, precision=hi))
-        Lw = b[..., None] * A
-        for i in range(1, c):
-            U = U.at[:, :, i].add(-jnp.einsum(
-                "bhj,bhjv->bhv", Lw[:, :, i, :i], U[:, :, :i], precision=hi))
-        o = (jnp.einsum("bhck,bhkv->bhcv", q * eG, S, precision=hi)
-             + jnp.einsum("bhcj,bhjv->bhcv", Bm, U, precision=hi))
-        S = S * eG[:, :, -1][..., None] + jnp.einsum(
-            "bhck,bhcv->bhkv", k * jnp.exp(G[:, :, -1:] - G), U,
-            precision=hi)
-        return S, o
+        U = mm("bhcj,bhjv->bhcv", Tm, b[..., None] * (
+            v - mm("bhck,bhkv->bhcv", k * eG, S)))
+        o = mm("bhck,bhkv->bhcv", q * eG, S) + mm("bhcj,bhjv->bhcv", Bm, U)
+        return S * eG[:, :, -1][..., None] + mm(
+            "bhck,bhcv->bhkv", k * jnp.exp(G[:, :, -1:] - G), U), o
 
     S, o = lax.scan(one, jnp.zeros((B, H, K, K), f32),
-                    tuple(blocks(y.astype(f32)) for y in (q, k, v, g, beta)))
-    o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1).reshape(B, nc * c, H, K)
+                    (q, k, v, G, b, Tm, Bm))
+    o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1).reshape(B, N * c, H, K)
     return o[:, :T], S
 
 

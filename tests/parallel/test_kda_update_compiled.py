@@ -1,11 +1,15 @@
-"""``kda_update`` and cell 7's generating program, compiled at the cell's
-real sizes for a v5e that is described and not attached
+"""``kda_update`` and cell 7's two programs, compiled at the cell's real
+sizes for a v5e that is described and not attached
 (``tests/benchmarks/test_fits.py``'s idiom).  Nothing runs and nothing here
 is a time: what is read is that the kernel compiles at the cell's block with
-no ``vmem_limit_bytes`` named, and the compiled program's text and memory:
-every cached step passes a layer's state through the kernel and through
-nothing else as large (a copy of a layer's state is 805 MB a step and 0.75
-GiB of the chip), in the buffer it lies in.
+no ``vmem_limit_bytes`` named, and the compiled programs' text and memory.
+The generating program: every cached step passes a layer's state through
+the kernel and through nothing else as large (a copy of a layer's state is
+805 MB a step and 0.75 GiB of the chip), in the buffer it lies in.  The
+prefill program: a delta-rule layer is one loop over the pass's 8 blocks of
+64 whose body is the products with the state and no row-at-a-time solve,
+and what is made before the loop never writes the diagonal sub-blocks'
+per-channel differences out whole (1.07 GB a layer).
 """
 
 import math
@@ -16,6 +20,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
 
 from ompi_tpu.ops import kda_update as kernel_module  # noqa: E402
 # the described chip, and the compile cache and interpret mode off around it
@@ -27,6 +32,8 @@ CELL = "kimi-linear-48b-a3b.decode-512-128-b384"
 # the parent's generating program (``traffic/decode-512-128-b384.json``'s
 # ``batch_why``: arguments + results + temporaries - written in place)
 PARENT_PEAK_GIB = 13.15
+# what the prefill program read here with blocks of 16 solved inside the scan
+PARENT_PREFILL_PEAK_GIB = 13.05
 
 
 def _on(chip, dims, dtype=jnp.float32):
@@ -56,33 +63,46 @@ def test_the_kernel_compiles_at_cell_7s_block_with_no_limit_named(
     assert memory.temp_size_in_bytes < 32 << 20
 
 
-def _generating_program(job, chip):
-    """Cell 7's second program as it runs (``decode._two_programs``: the
-    carry donated), cut out of the job's ``full`` decoder, whose two
-    programs ``job.programs()`` compiles as one."""
+@pytest.fixture(scope="module")
+def cell_7(chip):
+    """(the program's configuration, the cell's job) from the configuration
+    and traffic files; building traces nothing."""
+    from benchmarks.lib import cells, program
+
+    cell = cells.resolve(CELL)
+    return (program.program_config(cell.config),
+            cell.runner.build(cell.config, cell.traffic, chip))
+
+
+def _program(job, chip, which):
+    """One of the two programs of a decoder that has two, as it runs
+    (``decode._two_programs``: the prefill's, then the generating one, which
+    takes the carry donated), cut out of the job's ``full`` decoder, whose
+    two programs ``job.programs()`` compiles as one."""
     from jax.extend.core import jaxpr_as_fun
 
     _fn, args = job.programs()["decode_full"]
     programs = [eqn for eqn in jax.make_jaxpr(job.full)(*args).eqns
                 if eqn.params.get("name") == "decode"]
-    assert len(programs) == 2       # the prefill's, then this one
-    closed = programs[1].params["jaxpr"]
+    assert len(programs) == 2
+    closed = programs[which].params["jaxpr"]
     donated = [i for i, given in enumerate(
-        programs[1].params["donated_invars"]) if given]
-    assert donated
+        programs[which].params["donated_invars"]) if given]
+    assert bool(donated) == bool(which)
     return (jax.jit(jaxpr_as_fun(closed), donate_argnums=donated),
             [_on(chip, v.aval.shape, v.aval.dtype)
              for v in closed.jaxpr.invars])
 
 
-def test_cell_7_steps_pass_each_state_through_the_kernel_alone(
-        chip, for_the_chip):
-    from benchmarks.lib import cells, program
+def _peak(memory) -> int:
+    return (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
 
-    cell = cells.resolve(CELL)
-    cfg = program.program_config(cell.config)
-    job = cell.runner.build(cell.config, cell.traffic, chip)
-    fn, args = _generating_program(job, chip)
+
+def test_cell_7_steps_pass_each_state_through_the_kernel_alone(
+        chip, cell_7, for_the_chip):
+    cfg, job = cell_7
+    fn, args = _program(job, chip, 1)
     compiled = fn.lower(*args).compile()
     text = compiled.as_text()
 
@@ -105,6 +125,51 @@ def test_cell_7_steps_pass_each_state_through_the_kernel_alone(
     memory = compiled.memory_analysis()
     # every state is written where it lies: 4 x 805 MB and the convolutions'
     assert memory.alias_size_in_bytes > 4 * 4 * math.prod(state)
-    peak = (memory.argument_size_in_bytes + memory.output_size_in_bytes
-            - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    peak = _peak(memory)
     assert peak < (PARENT_PEAK_GIB * 2 ** 30) + (64 << 20), peak / 2 ** 30
+
+
+def test_cell_7s_prefill_scans_eight_blocks_a_layer_and_solves_before_them(
+        chip, cell_7, for_the_chip):
+    from ompi_tpu.models import decode
+
+    cfg, job = cell_7
+    fn, args = _program(job, chip, 0)
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    computations = dict(re.findall(
+        r"^(?:ENTRY )?%(\S+) \([^\n]*\{\n(.*?)^\}", text, re.M | re.S))
+
+    kd = cfg.plan.kda
+    loops = [line for line in text.splitlines()
+             if " while(" in line and "kda.scan" in line]
+    assert len(loops) == cfg.plan.count("kda") == 4
+    steps = job.prompt_len // kd.chunk
+    assert steps == 8
+    for loop in loops:
+        condition, body = (computations[name] for name in re.search(
+            r"condition=%([\w.-]+), body=%([\w.-]+)", loop).groups())
+        assert f"constant({steps})" in condition
+        # the five products with the state, what they are made from, the
+        # slices of this block: the parent's body, a block of 16 solved a
+        # row at a time, was 174 instructions, 15 of them row updates
+        assert "dynamic-update-slice(" not in body
+        assert len(body.splitlines()) < 48, body
+
+    # the (B, H, blocks, sub-blocks, 16, 16, K) differences of a layer, or a
+    # quarter of them, in no instruction but one inside a fusion
+    sub = min(16, kd.chunk)
+    group = decode._prefill_group(job.batch, job.prompt_len,
+                                  cfg.prefill_tokens)
+    assert group == 8
+    whole = group * kd.n_heads * job.prompt_len * sub * kd.head_dim
+    written = [
+        (name, dims) for body_name, body in computations.items()
+        if "fused_computation" not in body_name
+        for name, dims, _op, _rest in INSTRUCTION.findall(body)
+        if dims.endswith(f",{sub},{sub},{kd.head_dim}")
+        and math.prod(map(int, dims.split(","))) >= whole // 4]
+    assert not written, written
+
+    peak = _peak(compiled.memory_analysis())
+    assert peak < (PARENT_PREFILL_PEAK_GIB + 0.3) * 2 ** 30, peak / 2 ** 30

@@ -87,8 +87,10 @@ def delta_inputs(seed, B=2, T=37, H=2, K=8, fast=True):
 # ---- the delta rule --------------------------------------------------------
 
 @pytest.mark.parametrize("T,chunk", [(37, 16), (16, 16), (5, 16), (33, 4),
-                                     (64, 64)])
+                                     (64, 64), (130, 64), (100, 32)])
 def test_the_chunked_rule_is_the_recurrence(T, chunk):
+    """Lengths short of a block, of one block, and that cross sub-blocks
+    (16 positions) and blocks at the default of 64 and at 32."""
     ref, *_ = tiny()
     args = delta_inputs(T, T=T)
     want_o, want_s = ref.delta_rule(*map(jnp.asarray, args))
@@ -98,20 +100,59 @@ def test_the_chunked_rule_is_the_recurrence(T, chunk):
     assert error(got_s, want_s) < PARITY
 
 
-def test_a_fast_channel_neither_overflows_nor_hides_a_slow_one():
+@pytest.mark.parametrize("chunk,T,held", [(16, 48, 30), (64, 80, 60)])
+def test_a_fast_channel_neither_overflows_nor_hides_a_slow_one(chunk, T,
+                                                               held):
     """With the decays' exponentials formed from ``exp(-cumsum)`` a channel
-    at 30 a position reads inf or nan inside a chunk; here its differences
-    are taken first.  A slow channel beside it keeps what was written 30
-    positions ago."""
+    at 30 a position reads inf or nan inside a block, and inside a sub-block
+    of a block of 64, and across the boundary of two; here every exponent
+    is a difference that is at most zero.  Nothing is written in the last
+    ``held`` positions, so what a slow channel beside it still holds was
+    written that long ago."""
     ref, *_ = tiny()
-    q, k, v, g, beta = delta_inputs(3, T=48)
-    assert (np.cumsum(-g, axis=1)[:, 15] > 100).any()     # e^100 > float32
-    got_o, got_s = kda.chunked(q, k, v, g, beta, 16)
+    q, k, v, g, beta = delta_inputs(3, T=T)
+    beta[:, T - held:] = 0.0
+    fallen = np.cumsum(-g, axis=1)
+    assert (fallen[:, 15] > 100).any()                    # e^100 > float32
+    assert (fallen[:, 20] - fallen[:, 10] > 100).any()    # across position 16
+    got_o, got_s = kda.chunked(q, k, v, g, beta, chunk)
     want_o, want_s = ref.delta_rule(q, k, v, g, beta)
+    assert np.isfinite(np.asarray(got_o)).all()
     assert np.isfinite(np.asarray(got_s)).all()
     assert error(got_o, want_o) < PARITY and error(got_s, want_s) < PARITY
     slow = g.max(axis=(0, 1)) > -2e-3 * 1.5
     assert slow.any() and np.abs(np.asarray(got_s))[:, slow].max() > 0.1
+
+
+def test_the_chunked_rules_gradient_is_the_recurrences():
+    """``jax.grad`` of one number made of every output and of the last
+    state, through blocks of 64 (two blocks, four sub-blocks each, fast
+    channels among them) and through the reference's recurrence: the
+    trainer's path, which no cell runs."""
+    ref, *_ = tiny()
+    args = tuple(map(jnp.asarray, delta_inputs(5, T=100)))
+    B, _T, H, K = args[0].shape
+    rng = np.random.default_rng(6)
+    wo, ws = (jnp.asarray(rng.normal(size=dims), jnp.float32)
+              for dims in (args[0].shape, (B, H, K, K)))
+
+    def gradient(rule):
+        def number(*inputs):
+            o, S = rule(*inputs)
+            return jnp.sum(o * wo) + jnp.sum(S * ws)
+        return jax.jit(jax.grad(number, argnums=range(5)))(*args)
+
+    want = gradient(ref.delta_rule)
+    got = gradient(lambda *inputs: kda.chunked(*inputs, 64))
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert np.isfinite(np.asarray(a)).all(), name
+        assert error(a, b) < PARITY, name
+
+
+def test_a_block_that_is_no_multiple_of_its_sub_blocks_is_refused():
+    args = delta_inputs(1, T=48)
+    with pytest.raises(ValueError, match="no multiple"):
+        kda.chunked(*args, 24)
 
 
 def test_a_step_against_the_carried_state_is_the_next_position():

@@ -22,7 +22,7 @@ from ompi_tpu.ops import retention_update as kernel_module  # noqa: E402
 # the described chip, and the compile cache and interpret mode off around it
 from tests.parallel.test_kda_update import _pallas_calls  # noqa: E402
 from tests.parallel.test_kda_update_compiled import (  # noqa: E402
-    _generating_program, _on)
+    _on, _program)
 from tests.parallel.test_selected_attention_compiled import (  # noqa: E402,F401
     INSTRUCTION, chip, for_the_chip)
 
@@ -61,7 +61,7 @@ def test_cell_8_steps_pass_a_layers_state_through_the_kernel_alone(
     cell = cells.resolve(CELL)
     cfg = program.program_config(cell.config)
     job = cell.runner.build(cell.config, cell.traffic, chip)
-    fn, args = _generating_program(job, chip)
+    fn, args = _program(job, chip, 1)
     compiled = fn.lower(*args).compile()
     text = compiled.as_text()
 
