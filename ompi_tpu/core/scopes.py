@@ -83,6 +83,18 @@ SCOPES = (
                             # the layer's state out of the carry to writing
                             # it back, the expansion, the read for the query
                             # heads and the quotient inside it
+    "lightning_proj",   # the lightning mixer's ln1, four projections, head
+                        # norms, rotary embedding, gated norm and output
+                        # projection
+    "lightning.scan",   # ... the chunked recurrence over a whole sequence
+    "lightning.update",     # ... one cached step's recurrence: from reading
+                            # the layer's matrix state out of the carry to
+                            # writing it
+    "blocks.pool",      # a block selection's pooled keys: all of them in a
+                        # whole-sequence pass, the one a cached step completes
+    "blocks.score",     # ... a query's scores against them, the softmax, the
+                        # group's sum, the pooling to blocks
+    "blocks.select",    # ... the forced blocks and the topk: a threshold
     "mla_proj",         # latent attention's ln1, projections, latent norm and
                         # the cached step's absorbed products
     "index_proj",       # the index's three projections, key norm, rotary

@@ -28,7 +28,9 @@ __all__ = ["mixer", "block", "mechanisms", "carry", "carried", "written",
 
 def mechanisms(cfg) -> tuple:
     """The modules whose state a decoder of ``cfg`` carries, in the carry's
-    order: ``plan``; or this one (with an index ``sparse_index``, which lays
+    order: ``plan`` (whose layers' kinds, ``plan.MIXERS``, are modules of
+    their own: ``kda``, ``mla``, ``lightning``, ``block_select``); or this
+    one (with an index ``sparse_index``, which lays
     K and V out with its keys; with power retention ``retention``, which
     keeps a state and no K/V) and, with a hybrid block, ``ssm``.  The one
     place that reads the configuration for them."""

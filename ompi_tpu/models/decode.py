@@ -239,6 +239,8 @@ def _halves(cfg: TransformerConfig, mesh, max_new: int,
                 h = tfm._lookup(cfg, params["emb"], tok)[:, None, :]
                 if hy is not None:
                     h = h * hy.embedding_multiplier
+                elif cfg.plan is not None and cfg.plan.scale_emb != 1:
+                    h = h * cfg.plan.scale_emb
 
             # the whole stacked carry is this loop's carry too; as a
             # scan's xs and ys it would be sliced out and copied back
@@ -263,6 +265,8 @@ def _halves(cfg: TransformerConfig, mesh, max_new: int,
                 h = _rmsnorm(h, params["lnf"], cfg.norm_eps)
                 if hy is not None:
                     h = h * hy.lm_head_multiplier
+                elif cfg.plan is not None and cfg.plan.head_divisor != 1:
+                    h = h / cfg.plan.head_divisor
                 logits = tfm._whole_vocab(cfg, jnp.einsum(
                     "bd,vd->bv", h[:, 0, :], head,
                     preferred_element_type=jnp.float32))

@@ -32,7 +32,7 @@ from __future__ import annotations
 import dataclasses
 
 __all__ = ["KDA", "mixer", "chunked", "update", "leaf_shapes",
-           "state_shapes"]
+           "state_shapes", "buffers", "POSITIONED"]
 
 L2_EPS = 1e-6
 
@@ -317,3 +317,16 @@ def mixer(cfg, lp, h, carry=None):
                            + cfg.norm_eps) * lp["kda_n"].astype(f32))
         y = (o.reshape(B, T, HK) * gate).astype(cdt)
         return h + proj(y, "kda_o"), conv_out, state
+
+
+# what ``models/plan.py`` asks of a mixer's kind beside the above (kept below
+# the cached update: a kernel's compile cache key holds its call site's lines)
+POSITIONED = False      # a cached step reads no position
+
+
+def buffers(cfg, kd: KDA, batch: int, t_max: int) -> tuple:
+    """What a decoder carries for one layer (``models/plan.py``'s form):
+    :func:`state_shapes`' two, the inputs in the compute type, the states in
+    the mixer's ``state_dtype``; neither grows."""
+    conv, state = state_shapes(kd, batch)
+    return ((conv, cfg.compute_dtype, None), (state, kd.state_dtype, None))

@@ -26,7 +26,9 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 
-__all__ = ["MLA", "mixer", "leaf_shapes"]
+__all__ = ["MLA", "mixer", "leaf_shapes", "buffers", "POSITIONED"]
+
+POSITIONED = True       # a cached step's carry ends with its position
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,6 +59,13 @@ def leaf_shapes(cfg, ml: MLA) -> dict:
                     ml.kv_rank ** -0.5),
         "wo": ((out, D), out ** -0.5 / max(1, 2 * cfg.n_layers) ** 0.5),
     }
+
+
+def buffers(cfg, ml: MLA, batch: int, t_max: int) -> tuple:
+    """What a decoder carries for one layer (``models/plan.py``'s form): the
+    cached rows ``(B, t_max, kv_rank + rope)`` in the compute type, which
+    grow along the carry's axis 2."""
+    return (((batch, t_max, ml.cached), cfg.compute_dtype, 2),)
 
 
 @contextlib.contextmanager

@@ -1,12 +1,29 @@
 """Layers of different kinds in one model: the plan.
 
 ``TransformerConfig.plan`` holds a :class:`LayerPlan`: for every layer its
-mixer's kind ("kda": ``models/kda.py``; "mla": ``models/mla.py``) and its
-MLP's ("dense": a gated MLP of width ``cfg.d_ff``; "moe": the dropless
-routed experts of ``parallel/moe.routed_moe``, of width ``d_expert``, under
-the configuration's ``moe_*`` fields).  A configuration without a plan is
+mixer's kind (``MIXERS``: "kda", ``models/kda.py``; "mla", ``models/mla.py``;
+"lightning", ``models/lightning.py``; "block_select",
+``models/block_select.py``) and its MLP's ("dense": a gated MLP of width
+``cfg.d_ff``; "moe": the dropless routed experts of
+``parallel/moe.routed_moe``, of width ``d_expert``, under the
+configuration's ``moe_*`` fields).  A configuration without a plan is
 attention and one MLP in every layer under one ``lax.scan``, and is not
 touched by this module.
+
+A mixer's kind is a module and a line of ``MIXERS``.  The plan holds the
+kind's sizes in the field of the kind's name, and the module gives:
+``leaf_shapes(cfg, sizes)``, one layer's leaves; ``buffers(cfg, sizes,
+batch, t_max)``, what a decoder carries for one layer, ``(shape, dtype,
+axis)`` each, ``axis`` the axis of a carried buffer (its leading axis of one
+counted) along which it grows with the sequence, the shape given at
+``t_max`` positions, or None for a state that does not grow; ``mixer(cfg,
+lp, h, carry=None)``, the layer's mixer with its norm and its residual add,
+over whole sequences (returns ``(h, *states)``, the states in ``buffers``'
+order, a growing one as long as the sequences) and, T == 1, against the
+layer's own buffers, with the position after them where the module says
+``POSITIONED`` (returns ``(h, *buffers)``); and, where a layer's place in the
+model is part of its arithmetic, ``constants(sizes, layer)``, what the plan
+hands the mixer beside its leaves.
 
 Leaves are stacked by kind, not by layer: the KDA leaves over the KDA
 layers, the latent leaves over the latent layers, the dense MLP's over the
@@ -31,8 +48,14 @@ from typing import Any
 
 import numpy as np
 
-__all__ = ["LayerPlan", "kda_mla_config", "leaf_names", "init_params",
-           "carry", "grows", "carried", "backbone", "step", "check_mesh"]
+__all__ = ["LayerPlan", "MIXERS", "kda_mla_config", "lightning_blocks_config",
+           "leaf_names", "init_params", "carry", "grows", "carried",
+           "backbone", "step", "check_mesh"]
+
+# a mixer's kind -> its module under ``ompi_tpu.models``
+MIXERS = {"kda": "kda", "mla": "mla", "lightning": "lightning",
+          "block_select": "block_select"}
+MLPS = ("dense", "moe")
 
 ROUTER_LEAVES = ("wg", "wgb")
 SHARED_LEAVES = ("sw1", "sw3", "sw2")
@@ -40,13 +63,23 @@ SHARED_LEAVES = ("sw1", "sw3", "sw2")
 
 @dataclasses.dataclass(frozen=True)
 class LayerPlan:
-    """``layers``: a (mixer, mlp) pair of kinds a layer.  ``kda`` and
-    ``mla``: the sizes of the mixers the plan names.  ``d_expert``: a routed
-    expert's width (``cfg.d_ff`` is the dense MLP's)."""
+    """``layers``: a (mixer, mlp) pair of kinds a layer.  ``kda``, ``mla``,
+    ``lightning`` and ``block_select``: the sizes of the mixers the plan
+    names.  ``d_expert``: a routed expert's width (``cfg.d_ff`` is the dense
+    MLP's).  Three constants of the model, each 1 where it has none: the
+    embedding is multiplied by ``scale_emb``, every branch by
+    ``branch_scale`` before its residual add (in the kinds that read it:
+    "lightning", "block_select" and the dense MLP), and the last norm's
+    output divided by ``head_divisor`` before the head."""
     layers: tuple
     kda: Any = None
     mla: Any = None
     d_expert: int = 0
+    lightning: Any = None
+    block_select: Any = None
+    scale_emb: float = 1.0
+    branch_scale: float = 1.0
+    head_divisor: float = 1.0
 
     def count(self, kind: str) -> int:
         """Layers whose mixer or MLP is ``kind``."""
@@ -105,48 +138,133 @@ def kda_mla_config(linear_attn_config: dict, first_k_dense_replace: int,
         moe_shared=num_shared_experts * moe_intermediate_size, **sizes)
 
 
+def lightning_blocks_config(mixer_types: list, lightning_nh: int,
+                            lightning_nkv: int, lightning_head_dim: int,
+                            lightning_use_rope: bool, qk_norm: bool,
+                            use_output_norm: bool, use_output_gate: bool,
+                            attn_use_rope: bool, attn_use_output_gate: bool,
+                            scale_emb: float, scale_depth: float,
+                            dim_model_base: int, sparse_config: dict,
+                            lightning_state_dtype: str = "float32", **sizes):
+    """``entry.config`` of a configuration file whose layers are Lightning
+    linear attention ("lightning-attn") or block-selected grouped-query
+    attention ("minicpm4") over a dense gated MLP, under the keys such a
+    model is published with: a ``TransformerConfig`` whose plan is read off
+    ``mixer_types``, a kind a layer, which may run past ``n_layers`` (a file
+    that cuts the depth keeps the list whole: the list's length is the
+    published depth, which the residual branches' scale ``scale_depth /
+    sqrt(depth)`` and the decays' layer factor are of).  ``sparse_config``:
+    the block selection's sizes, under MiniCPM4's names."""
+    from ompi_tpu.models.block_select import BlockSelect
+    from ompi_tpu.models.lightning import Lightning
+    from ompi_tpu.models.transformer import TransformerConfig
+
+    kinds = {"lightning-attn": "lightning", "minicpm4": "block_select"}
+    unknown = set(mixer_types) - set(kinds)
+    if unknown:
+        raise ValueError(f"mixer_types names {sorted(unknown)}: not built "
+                         f"(have {sorted(kinds)})")
+    if lightning_nkv != lightning_nh:
+        raise ValueError(f"lightning_nkv {lightning_nkv} of lightning_nh "
+                         f"{lightning_nh}: grouped lightning heads are not "
+                         f"built")
+    missing = [key for key, has in (
+        ("qk_norm", qk_norm), ("use_output_norm", use_output_norm),
+        ("attn_use_output_gate", attn_use_output_gate),
+        ("attn_use_rope false", not attn_use_rope)) if not has]
+    if missing:
+        raise ValueError(f"a plan of lightning and block-selected layers is "
+                         f"built with {', '.join(missing)} alone")
+    depth, sc = len(mixer_types), sparse_config
+    sizes["rope_theta"] = float(sizes.get("rope_theta", 10_000))
+    plan = LayerPlan(
+        layers=tuple((kinds[kind], "dense")
+                     for kind in mixer_types[:sizes["n_layers"]]),
+        lightning=Lightning(
+            n_heads=lightning_nh, head_dim=lightning_head_dim, depth=depth,
+            rope=bool(lightning_use_rope), gate=bool(use_output_gate),
+            state_dtype=lightning_state_dtype),
+        block_select=BlockSelect(
+            kernel=sc["kernel_size"], stride=sc["kernel_stride"],
+            block=sc["block_size"], topk=sc["topk"],
+            init_blocks=sc["init_blocks"], window=sc["window_size"],
+            dense_len=sc["dense_len"]),
+        scale_emb=float(scale_emb),
+        branch_scale=float(scale_depth) / depth ** 0.5,
+        head_divisor=sizes["d_model"] / dim_model_base)
+    return TransformerConfig(plan=plan, **sizes)
+
+
+def _module(kind: str):
+    """The module of a mixer's kind."""
+    import importlib
+
+    return importlib.import_module("ompi_tpu.models." + MIXERS[kind])
+
+
 def check_mesh(cfg, mesh) -> None:
-    """A head's state and the latent are whole on a device, and over ``sp``
-    the delta rule needs an exclusive scan of per-rank states: neither split
-    is built, and no cell asks."""
+    """A head's state, the latent and a selection's candidates are whole on
+    a device, and over ``sp`` a recurrence needs an exclusive scan of
+    per-rank states: neither split is built, and no cell asks."""
     for axis in ("sp", "tp"):
         if int(dict(mesh.shape).get(axis, 1)) > 1:
             raise ValueError(
-                f"a layer plan (KDA and latent-attention mixers) runs with "
-                f"{axis} == 1 only, and the mesh has {axis}="
+                f"a layer plan (mixers of kinds {', '.join(MIXERS)}) runs "
+                f"with {axis} == 1 only, and the mesh has {axis}="
                 f"{mesh.shape[axis]}: its mixers are not split over {axis}")
-    unknown = {kind for pair in cfg.plan.layers for kind in pair} - {
-        "kda", "mla", "dense", "moe"}
+    unknown = ({mixer for mixer, _mlp in cfg.plan.layers} - set(MIXERS)
+               | {mlp for _mixer, mlp in cfg.plan.layers} - set(MLPS))
     if unknown:
         raise ValueError(f"a layer plan of kinds {sorted(unknown)}: not "
-                         f"built (have kda, mla; dense, moe)")
+                         f"built (have {', '.join(MIXERS)}; "
+                         f"{', '.join(MLPS)})")
 
 
 def _kinds(cfg) -> dict:
     """kind -> (layers of it, one layer's leaves: name -> (shape,
-    deviation or None)), for the kinds the plan has."""
-    from ompi_tpu.models import kda, mla
-
+    deviation or None)), for the kinds the plan has.  The dense MLP's leaves
+    are ``dw1``, ``dw3``, ``dw2`` beside routed layers, whose experts are
+    ``w1``, ``w3``, ``w2``, and take those names where no layer routes."""
     pl, D = cfg.plan, cfg.d_model
     depth = max(1, 2 * cfg.n_layers) ** 0.5
-    F, Fe, Fs = cfg.d_ff, pl.d_expert, cfg.moe_shared
-    held = cfg.moe_held[1] if cfg.moe_held else cfg.moe_experts
-    moe = {"wg": ((D, cfg.moe_experts), 0.02),
-           "w1": ((held, D, Fe), D ** -0.5),
-           "w3": ((held, D, Fe), D ** -0.5),
-           "w2": ((held, Fe, D), Fe ** -0.5 / depth)}
-    if cfg.moe_select_bias:
-        moe["wgb"] = ((cfg.moe_experts,), 0.01)
-    if Fs:
-        moe.update({"sw1": ((D, Fs), D ** -0.5), "sw3": ((D, Fs), D ** -0.5),
-                    "sw2": ((Fs, D), Fs ** -0.5 / depth)})
-    dense = {"dw1": ((D, F), D ** -0.5), "dw3": ((D, F), D ** -0.5),
-             "dw2": ((F, D), F ** -0.5 / depth)}
-    kinds = {"kda": pl.kda and kda.leaf_shapes(cfg, pl.kda),
-             "mla": pl.mla and mla.leaf_shapes(cfg, pl.mla),
-             "dense": dense, "moe": moe}
-    return {kind: (pl.count(kind), leaves)
-            for kind, leaves in kinds.items() if pl.count(kind)}
+
+    def moe():
+        Fe, Fs = pl.d_expert, cfg.moe_shared
+        held = cfg.moe_held[1] if cfg.moe_held else cfg.moe_experts
+        leaves = {"wg": ((D, cfg.moe_experts), 0.02),
+                  "w1": ((held, D, Fe), D ** -0.5),
+                  "w3": ((held, D, Fe), D ** -0.5),
+                  "w2": ((held, Fe, D), Fe ** -0.5 / depth)}
+        if cfg.moe_select_bias:
+            leaves["wgb"] = ((cfg.moe_experts,), 0.01)
+        if Fs:
+            leaves.update({"sw1": ((D, Fs), D ** -0.5),
+                           "sw3": ((D, Fs), D ** -0.5),
+                           "sw2": ((Fs, D), Fs ** -0.5 / depth)})
+        return leaves
+
+    def dense():
+        F, (d1, d3, d2) = cfg.d_ff, _dense_leaves(pl)
+        return {d1: ((D, F), D ** -0.5), d3: ((D, F), D ** -0.5),
+                d2: ((F, D), F ** -0.5 / depth)}
+
+    makers = {**{kind: lambda kind=kind: _module(kind).leaf_shapes(
+        cfg, getattr(pl, kind)) for kind in MIXERS},
+        "dense": dense, "moe": moe}
+    kinds = {kind: (pl.count(kind), leaves())
+             for kind, leaves in makers.items() if pl.count(kind)}
+    names = [name for _n, leaves in kinds.values() for name in leaves]
+    twice = sorted({name for name in names if names.count(name) > 1})
+    if twice:
+        raise ValueError(f"two kinds of this plan name a leaf alike, "
+                         f"{twice}: a leaf is stacked over the layers of one "
+                         f"kind")
+    return kinds
+
+
+def _dense_leaves(pl) -> tuple:
+    """The dense MLP's gate, up and down projections' names."""
+    return (("dw1", "dw3", "dw2") if pl.count("moe") else ("w1", "w3", "w2"))
 
 
 def leaf_names(cfg) -> tuple:
@@ -172,16 +290,28 @@ def init_params(cfg, rng) -> dict:
     return params
 
 
+def _buffers(cfg, batch: int, t_max: int) -> list:
+    """``(shape, dtype, axis)`` of every buffer a decoder carries, a layer's
+    own after another's in the plan's order (a kind's ``buffers``)."""
+    pl = cfg.plan
+    return [buffer for mixer, _mlp_kind in pl.layers
+            for buffer in _module(mixer).buffers(
+                cfg, getattr(pl, mixer), batch, t_max)]
+
+
 def carry(cfg, mesh, batch: int, t_max: int) -> list:
     """A decoder's carry for ``batch`` sequences of up to ``t_max``
     positions, zeros, a layer's own buffers after another's in the plan's
     order, each with a leading axis of one (so that whoever fills a carry a
     group of sequences at a time finds the batch on axis 1, as in a stack
-    over layers): a latent layer's cache ``(1, B, t_max, kv_rank + rope)``
-    in the compute type; a KDA layer's convolution inputs ``(1, B, conv - 1,
-    3 heads K)`` in the compute type and its matrix states ``(1, B, heads,
-    K, K)`` in the mixer's ``state_dtype``.  The order is that of
-    :func:`backbone`'s collected states.
+    over layers); what they are is each kind's to say (``buffers``): a
+    latent layer's cache ``(1, B, t_max, kv_rank + rope)`` in the compute
+    type; a KDA layer's convolution inputs ``(1, B, conv - 1, 3 heads K)``
+    in the compute type and its matrix states ``(1, B, heads, K, K)`` in the
+    mixer's ``state_dtype``; a lightning layer's matrix states alike; a
+    block-selected layer's K and V rows and, one for every ``stride``
+    positions, its pooled keys.  The order is that of :func:`backbone`'s
+    collected states.
 
     A buffer a layer and not a stack a kind, because the steps' loop over
     the plan is unrolled: with one float32 stack ``(KDA layers, B, heads, K,
@@ -193,37 +323,30 @@ def carry(cfg, mesh, batch: int, t_max: int) -> list:
     replaced whole, which needs no reasoning about slices."""
     import jax.numpy as jnp
 
-    from ompi_tpu.models import kda
-
-    pl, cdt, buffers = cfg.plan, cfg.compute_dtype, []
-    for mixer, _mlp_kind in pl.layers:
-        if mixer == "mla":
-            buffers.append(jnp.zeros((1, batch, t_max, pl.mla.cached), cdt))
-        else:
-            conv, state = kda.state_shapes(pl.kda, batch)
-            buffers += [jnp.zeros((1, *conv), cdt),
-                        jnp.zeros((1, *state), pl.kda.state_dtype)]
-    return buffers
+    return [jnp.zeros((1, *shape), dtype)
+            for shape, dtype, _axis in _buffers(cfg, batch, t_max)]
 
 
 def grows(cfg) -> tuple:
     """Of each of :func:`carry`'s buffers: whether it grows with the
-    sequence (a latent layer's cache) or not (a KDA layer's two)."""
-    return tuple(grown for mixer, _mlp_kind in cfg.plan.layers
-                 for grown in ((True,) if mixer == "mla" else (False, False)))
+    sequence (a latent layer's cache, a selected layer's rows and pooled
+    keys) or not (a matrix state, a convolution's inputs)."""
+    return tuple(axis is not None for _shape, _dtype, axis
+                 in _buffers(cfg, 0, 0))
 
 
 def carried(cfg, mesh, collected, t_max: int, into=None, **group) -> list:
     """:func:`backbone`'s collected states of whole sequences, or a carry
     that ends before ``t_max`` (``collected``, used up; :func:`carry`'s
     order), as :func:`carry`'s buffers: written into ``into``, or those that
-    grow padded with zeros to ``t_max`` positions (``block.written``)."""
+    grow padded with zeros to what they hold at ``t_max`` positions
+    (``block.written``), each along its own axis."""
     from ompi_tpu.models.block import written
 
-    grown = grows(cfg)
-    return [written(new, t_max, buffer, axis=2 if longer else None, **group)
-            for new, buffer, longer in zip(
-                collected, into or [None] * len(grown), grown)]
+    buffers = _buffers(cfg, 0, t_max)
+    return [written(new, axis and shape[axis - 1], buffer, axis=axis, **group)
+            for new, buffer, (shape, _dtype, axis) in zip(
+                collected, into or [None] * len(buffers), buffers)]
 
 
 def _mlp(cfg, comm, params, layer: int, kind: str, h):
@@ -233,10 +356,11 @@ def _mlp(cfg, comm, params, layer: int, kind: str, h):
     pl = cfg.plan
     at = pl.index(layer, kind)
     if kind == "dense":
-        lp = {"ln2": params["ln2"][layer], "w1": params["dw1"][at],
-              "w3": params["dw3"][at], "w2": params["dw2"][at]}
+        lp = {"ln2": params["ln2"][layer], **{
+            name: params[leaf][at] for name, leaf in zip(
+                ("w1", "w3", "w2"), _dense_leaves(pl))}}
         return tfm._dense_ffn_tail(h, lp, comm, h.dtype, cfg.norm_eps,
-                                   gated=(1.0, 1.0))
+                                   gated=(1.0, pl.branch_scale))
     # the experts' whole stacks and the layer's place in them
     # (``routed_moe`` says why); the router's and the shared expert's sliced
     lp = {"ln2": params["ln2"][layer],
@@ -247,9 +371,13 @@ def _mlp(cfg, comm, params, layer: int, kind: str, h):
 
 
 def _mixer_leaves(cfg, params, layer: int, kind: str) -> dict:
+    """Layer ``layer``'s mixer's leaves, and the constants of its place."""
     at = cfg.plan.index(layer, kind)
+    constants = getattr(_module(kind), "constants", None)
     return {"ln1": params["ln1"][layer],
-            **{k: params[k][at] for k in _kinds(cfg)[kind][1]}}
+            **{k: params[k][at] for k in _kinds(cfg)[kind][1]},
+            **(constants(getattr(cfg.plan, kind), layer) if constants
+               else {})}
 
 
 def _own_program(layer):
@@ -272,22 +400,25 @@ def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
     per-device forward through the last norm, a python loop over the plan.
     Returns ``(h, aux)`` (aux 0: the dropless experts have no balance term),
     or with ``collect_kv`` ``(h, (aux, *states))``, the states as
-    :func:`carry` orders and shapes them but the cache ``T`` long: a latent
-    layer's rows ``(1, B, T, kv_rank + rope)``, a KDA layer's convolution
-    inputs and matrix state after the last position.  ``grad_axes``: the
+    :func:`carry` orders and shapes them but what grows as long as the
+    sequences: a latent layer's rows ``(1, B, T, kv_rank + rope)``, a KDA
+    layer's convolution inputs and matrix state after the last position.
+    The embedding comes times the plan's ``scale_emb`` and ``h`` over its
+    ``head_divisor``.  ``grad_axes``: the
     layers' leaves' gradients are summed over those axes where the backward
     pass reaches the start of the loop."""
     import jax
     import jax.numpy as jnp
 
     from ompi_tpu.core.scopes import scope
-    from ompi_tpu.models import kda, mla
     from ompi_tpu.models import transformer as tfm
 
     check_mesh(cfg, comm.mesh)
     pl = cfg.plan
     with scope("embed"):
         h = tfm._lookup(cfg, params["emb"], tokens)
+        if pl.scale_emb != 1:
+            h = h * pl.scale_emb
     if grad_axes is not None:
         params = {**params, **tfm._sum_in_backward(
             {k: params[k] for k in leaf_names(cfg)}, grad_axes)}
@@ -295,7 +426,7 @@ def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
     def layer_fn(layer, mixer, mlp):
         def run(h, params):
             lp = _mixer_leaves(cfg, params, layer, mixer)
-            h, *states = (kda if mixer == "kda" else mla).mixer(cfg, lp, h)
+            h, *states = _module(mixer).mixer(cfg, lp, h)
             return _mlp(cfg, comm, params, layer, mlp, h), states
 
         if cfg.remat in (True, "full"):
@@ -311,6 +442,8 @@ def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
             h, states = layer_fn(layer, mixer, mlp)(h, params)
             collected += [state[None] for state in states]
     h = tfm._rmsnorm(h, params["lnf"], cfg.norm_eps)
+    if pl.head_divisor != 1:
+        h = h / pl.head_divisor
     aux = jnp.zeros((), jnp.float32)
     if not collect_kv:
         return h, aux
@@ -322,18 +455,16 @@ def step(cfg, comm, params, h, states, pos):
     carry ``states`` (:func:`carry`'s buffers), each layer reading and
     replacing its own.  ``params``: the leaves stacked over layers.  Returns
     ``(h, *states)``."""
-    from ompi_tpu.models import kda, mla
-
     pl = cfg.plan
 
     def layer_fn(layer, mixer, mlp):
+        module = _module(mixer)
+
         def run(h, params, own, pos):
             lp = _mixer_leaves(cfg, params, layer, mixer)
-            own = [buffer[0] for buffer in own]
-            if mixer == "kda":
-                h, *own = kda.mixer(cfg, lp, h, carry=tuple(own))
-            else:
-                h, *own = mla.mixer(cfg, lp, h, carry=(*own, pos))
+            own = tuple(buffer[0] for buffer in own)
+            h, *own = module.mixer(
+                cfg, lp, h, carry=(*own, pos) if module.POSITIONED else own)
             return (_mlp(cfg, comm, params, layer, mlp, h),
                     [buffer[None] for buffer in own])
 
@@ -341,7 +472,7 @@ def step(cfg, comm, params, h, states, pos):
 
     states, at = list(states), 0
     for layer, (mixer, mlp) in enumerate(pl.layers):
-        n = 2 if mixer == "kda" else 1
+        n = len(_module(mixer).buffers(cfg, getattr(pl, mixer), 0, 0))
         h, states[at:at + n] = layer_fn(layer, mixer, mlp)(
             h, params, states[at:at + n], pos)
         at += n

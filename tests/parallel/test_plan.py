@@ -476,8 +476,8 @@ def test_the_plan_is_read_off_the_published_lists():
 
 
 BENCH = cells.load_benchmark()
-ONE_KIND = [c["name"] for c in BENCH["configs"]
-            if c["name"] != "kimi-linear-48b-a3b"]
+PLANNED = ("kimi-linear-48b-a3b", "minicpm-sala")
+ONE_KIND = [c["name"] for c in BENCH["configs"] if c["name"] not in PLANNED]
 
 
 @pytest.mark.parametrize("name", ONE_KIND)
@@ -498,4 +498,93 @@ def test_a_configuration_without_a_plan_has_nothing_of_a_plans(name):
     assert set(tfm.layer_leaves(cfg)) == {
         k for k, v in tree.items() if v.ndim and v.shape[0] == cfg.n_layers
         and k not in ("emb", "head", "lnf")}
-    assert not {k for k in tree if k.startswith(("kda_", "mla_", "sw"))}
+    assert not {k for k in tree if k.startswith(("kda_", "mla_", "sw",
+                                                 "lt_"))}
+
+
+# ---- a kind is a module and a line ------------------------------------------
+
+def test_the_old_two_kinds_give_the_tree_the_carry_and_the_names_they_gave():
+    """Cell 7's plan through the table of kinds: the leaves in the order the
+    program draws them, the dense MLP's under the names it has beside
+    experts, the carry's buffers and which of them grow."""
+    _ref, _shape, cfg, mesh, params = tiny()
+    assert plan.leaf_names(cfg) == (
+        "ln1", "ln2", "kda_q", "kda_k", "kda_v", "kda_cq", "kda_ck",
+        "kda_cv", "kda_f1", "kda_f2", "kda_a", "kda_dt", "kda_b", "kda_g1",
+        "kda_g2", "kda_n", "kda_o", "mla_q", "mla_kva", "mla_n", "mla_kvb",
+        "wo", "dw1", "dw3", "dw2", "wg", "w1", "w3", "w2", "wgb", "sw1",
+        "sw3", "sw2")
+    assert set(params) == {"emb", "head", "lnf", *plan.leaf_names(cfg)}
+    assert plan.grows(cfg) == (False, False) * 3 + (True,) + (False, False)
+    assert (cfg.plan.scale_emb, cfg.plan.branch_scale,
+            cfg.plan.head_divisor) == (1, 1, 1)
+    assert not kda.POSITIONED and mla.POSITIONED
+    short = plan.carry(cfg, mesh, 2, 9)
+    longer = plan.carried(cfg, mesh, iter(short), 13)
+    assert [b.shape for b in longer] == [
+        b.shape for b in plan.carry(cfg, mesh, 2, 13)]
+    assert longer[6].shape[2] == 13 and longer[0] is short[0]
+
+
+def four_kinds(cfg):
+    """Cell 7's tiny configuration with a lightning and a block-selected
+    layer in the place of its latent one and of a KDA one."""
+    from ompi_tpu.models.block_select import BlockSelect
+    from ompi_tpu.models.lightning import Lightning
+
+    layers = (("kda", "dense"), ("lightning", "moe"), ("kda", "moe"),
+              ("block_select", "moe"), ("lightning", "dense"))
+    return dataclasses.replace(cfg, plan=dataclasses.replace(
+        cfg.plan, layers=layers,
+        lightning=Lightning(n_heads=2, head_dim=16, depth=5, chunk=8),
+        block_select=BlockSelect(kernel=4, stride=2, block=8, topk=3,
+                                 init_blocks=1, window=8, dense_len=16,
+                                 q_slice=16),
+        scale_emb=2.0, branch_scale=0.5, head_divisor=4.0))
+
+
+def test_a_plan_of_the_new_kinds_beside_the_old_decodes_as_it_forwards():
+    _ref, _shape, cfg, mesh, _params = tiny()
+    cfg = four_kinds(cfg)
+    names = plan.leaf_names(cfg)
+    assert {"kda_q", "lt_q", "wq", "wz", "wo", "dw1", "w1"} <= set(names)
+    assert "mla_q" not in names
+    params = tfm.shard_params(cfg, mesh, tfm.init_params(cfg, seed=3))
+    assert params["lt_q"].shape[0] == params["dw1"].shape[0] == 2
+    assert params["wq"].shape[0] == 1 and params["kda_q"].shape[0] == 2
+    assert plan.grows(cfg) == (False, False, False, False, False, True, True,
+                               False)
+    prompts = prompts_of(cfg, 3, 27)
+    tokens, kept = make_decoder(cfg, mesh, max_new=10, keep_logits=3)(
+        params, prompts)
+    full = jax.jit(tfm.make_forward(cfg, mesh))(params, tokens)[:, 26:-1]
+    assert error(kept, full) < PARITY
+    again = make_decoder(dataclasses.replace(cfg, prefill_tokens=27), mesh,
+                         max_new=10)(params, prompts)
+    assert np.array_equal(again, tokens)
+    # the three constants are in the result
+    for change in ({"scale_emb": 1.0}, {"branch_scale": 1.0},
+                   {"head_divisor": 1.0}):
+        other = dataclasses.replace(cfg, plan=dataclasses.replace(
+            cfg.plan, **change))
+        moved = jax.jit(tfm.make_forward(other, mesh))(params,
+                                                       tokens)[:, 26:-1]
+        assert error(moved, full) > 0.05, change
+
+
+def test_two_kinds_that_name_a_leaf_alike_are_refused():
+    _ref, _shape, cfg, _mesh, _params = tiny()
+    cfg = four_kinds(cfg)
+    cfg = dataclasses.replace(cfg, plan=dataclasses.replace(
+        cfg.plan, layers=(*cfg.plan.layers[:4], ("mla", "moe"))))
+    with pytest.raises(ValueError, match="name a leaf alike.*wo"):
+        plan.leaf_names(cfg)
+
+
+def test_a_kind_the_table_lacks_is_refused():
+    _ref, _shape, cfg, mesh, _params = tiny()
+    cfg = dataclasses.replace(cfg, plan=dataclasses.replace(
+        cfg.plan, layers=(("mamba", "dense"), ("kda", "glu"))))
+    with pytest.raises(ValueError, match=r"\['glu', 'mamba'\]: not built"):
+        plan.check_mesh(cfg, mesh)
