@@ -189,12 +189,25 @@ def mixer(cfg, lp, h, carry=None):
     def proj(y, name):
         return jnp.einsum("btd,df->btf", y, lp[name].astype(cdt))
 
+    def heads(name):
+        y = proj(x, name)
+        if carry is not None:
+            # a cached step's product ends here, (B, 1, H K) as lt_z's is:
+            # the TPU's compiler otherwise folds the reshape into it (the
+            # weight seen (D, H, K), the head norm's sums a second result),
+            # wants that weight with D minor, and copies the layer's matrix
+            # out of its stack re-laid every step, 32 MiB read and written
+            # for each of lt_q, lt_k, lt_v (PR 53; ``tests/parallel/
+            # test_plan_step_compiled.py``).  Behind the barrier the weight
+            # is read where it lies, a static slice inside the product
+            y = lax.optimization_barrier(y)
+        return y.reshape(B, T, H, K)
+
     with scope("lightning_proj"):
         # the norms and the rotary embedding are called through the module:
         # a benchmark's control plants a wrong one there
         x = tfm._rmsnorm(h, lp["ln1"], cfg.norm_eps)
-        q, k, v = (proj(x, name).reshape(B, T, H, K)
-                   for name in ("lt_q", "lt_k", "lt_v"))
+        q, k, v = (heads(name) for name in ("lt_q", "lt_k", "lt_v"))
         q = tfm._rmsnorm(q, lp["lt_qn"], cfg.norm_eps)
         k = tfm._rmsnorm(k, lp["lt_kn"], cfg.norm_eps)
         if lt.rope:

@@ -320,7 +320,16 @@ def carry(cfg, mesh, batch: int, t_max: int) -> list:
     ``...fusion.8.remat``, both run, both on the one buffer: the chip's
     trace, PR 45), and the cached steps read a logit error of 0.15 where the
     same program with a bfloat16 stack read 0.013.  A layer's own buffer is
-    replaced whole, which needs no reasoning about slices."""
+    replaced whole, which needs no reasoning about slices.
+
+    The leaves need no counterpart of this rule: they stay stacked by kind,
+    a layer's are static slices (:func:`_mixer_leaves`), and a cached step
+    reads each where it lies, inside the product's own fusion.  Where the
+    compiler would want a weight re-laid behind a product and copy the
+    layer's slice out every step, the kind's mixer ends the product before
+    what it would fold in (``lightning.mixer``, PR 53), and
+    ``tests/parallel/test_plan_step_compiled.py`` holds the compiled step of
+    every cell with a plan to moving no weight."""
     import jax.numpy as jnp
 
     return [jnp.zeros((1, *shape), dtype)

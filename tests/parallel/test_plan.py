@@ -573,6 +573,35 @@ def test_a_plan_of_the_new_kinds_beside_the_old_decodes_as_it_forwards():
         assert error(moved, full) > 0.05, change
 
 
+def test_the_barrier_behind_a_steps_lightning_products_moves_no_arithmetic(
+        monkeypatch):
+    """A cached step's q, k and v products of a lightning layer end behind
+    an ``optimization_barrier`` (``lightning.mixer`` says why: the weights
+    are then read where they lie in their stacks); the whole-sequence pass
+    has none.  The decoder's tokens and kept logits are bit for bit what the
+    same plan gives with the barrier taken out."""
+    _ref, _shape, cfg, mesh, _params = tiny()
+    cfg = four_kinds(cfg)
+    params = tfm.shard_params(cfg, mesh, tfm.init_params(cfg, seed=3))
+    prompts = prompts_of(cfg, 3, 27)
+
+    def decoded():
+        decoder = make_decoder(cfg, mesh, max_new=10, keep_logits=3)
+        return (str(jax.make_jaxpr(decoder)(params, prompts)),
+                *decoder(params, prompts))
+
+    text, tokens, kept = decoded()
+    assert (text.count("optimization_barrier")
+            == 3 * cfg.plan.count("lightning") == 6)
+    forward = str(jax.make_jaxpr(tfm.make_forward(cfg, mesh))(params, tokens))
+    assert "optimization_barrier" not in forward
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+    plain, plain_tokens, plain_kept = decoded()
+    assert "optimization_barrier" not in plain
+    assert np.array_equal(plain_tokens, tokens)
+    assert np.array_equal(plain_kept, kept)
+
+
 def test_two_kinds_that_name_a_leaf_alike_are_refused():
     _ref, _shape, cfg, _mesh, _params = tiny()
     cfg = four_kinds(cfg)

@@ -1,0 +1,143 @@
+"""The generating programs of the two cells with a layer plan, compiled at
+the cells' real sizes for a v5e that is described and not attached
+(``tests/benchmarks/test_fits.py``'s idiom).  Nothing runs and nothing here
+is a time: what is read is the compiled programs' text and memory.  No cached
+step writes a weight: a layer's leaves are static slices of their kind's
+stacks, which the compiler reads where they lie, inside the product's own
+fusion.  Cell 9's parent did not: the lightning mixer's q, k and v products
+had the head reshape and the head norm's sums folded into them, wanted their
+weights with ``D`` minor, and each step copied every layer's matrix out of
+the re-laid stack first (three ``slice`` fusions of ``bf16[3,4096,4096]``,
+603 MB read and written a step, 10% of it: PR 53).
+"""
+
+import math
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
+
+from tests.parallel.test_kda_update_compiled import (  # noqa: E402
+    _peak, _program)
+# the described chip, and the compile cache and interpret mode off around it
+from tests.parallel.test_selected_attention_compiled import (  # noqa: E402,F401
+    INSTRUCTION, MOVES, chip, for_the_chip)
+
+CELL_9 = "minicpm-sala.decode-16k-512-b24"
+CELL_7 = "kimi-linear-48b-a3b.decode-512-128-b384"
+# cell 9's generating program at the parent (CPU box, PR 53): the re-laid
+# stacks, 288 MiB standing and as much again of layers' copies, were in both
+PARENT_TEMP_BYTES = 501_877_248
+PARENT_PEAK_BYTES = 5_197_119_488
+# an instruction with its result's types whole, an array or a tuple of them
+# (``INSTRUCTION`` reads single arrays: what is inside a fusion)
+RESULTS = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\((.*)$", re.M)
+# what names or views a buffer inside a fusion that does nothing but move
+VIEWS = ("parameter", "bitcast", "reshape", "tuple", "get-tuple-element")
+_COMPILED = {}
+
+
+def _generating(name, chip):
+    """(the program's configuration, the cell's generating program compiled
+    for ``chip``), compiled once a cell; call under ``for_the_chip``."""
+    from benchmarks.lib import cells, program
+
+    if name not in _COMPILED:
+        cell = cells.resolve(name)
+        job = cell.runner.build(cell.config, cell.traffic, chip)
+        fn, args = _program(job, chip, 1)
+        _COMPILED[name] = (program.program_config(cell.config),
+                           fn.lower(*args).compile())
+    return _COMPILED[name]
+
+
+def _sizes(dims) -> tuple:
+    """An array's sizes above one, sorted: what a move in any order of the
+    axes, with or without an axis of one, leaves alike."""
+    return tuple(sorted(int(d) for d in dims if int(d) > 1))
+
+
+def _weights(cfg) -> set:
+    """The sizes, sorted, of one layer or of several of every matrix, of
+    ``d_model`` squared elements or more, that is stacked over a kind's
+    layers: what a copy, a slice or a relayout of a weight results in,
+    whatever the order it leaves the axes in."""
+    from ompi_tpu.models import plan
+
+    sizes = set()
+    for n, leaves in plan._kinds(cfg).values():
+        for dims, _deviation in leaves.values():
+            if math.prod(dims) >= cfg.d_model ** 2:
+                sizes |= {_sizes((layers, *dims))
+                          for layers in range(1, n + 1)}
+    return sizes
+
+
+def _weight_moves(cfg, text) -> list:
+    """The instructions of the step's body, outside any fusion, that move a
+    weight: a move by name or a fusion of nothing but moves whose result is
+    a layer's matrix or several layers'.  A product that reads a slice of
+    the stack inside its own fusion is none, nor is the compiler's
+    asynchronous prefetch (``slice-start``), which writes no HBM."""
+    computations = dict(re.findall(
+        r"^(?:ENTRY )?%(\S+) \([^\n]*\{\n(.*?)^\}", text, re.M | re.S))
+    weights = _weights(cfg)
+    # the scan over steps, and every loop and call inside its body (what
+    # the compiler hoists out of the loop keeps its name and is not a step's)
+    [steps] = [line for line in text.splitlines() if " while(" in line
+               and 'decode.step/while"' in line]
+    bodies, called = [], [re.search(r"body=%([\w.-]+)", steps).group(1)]
+    while called:
+        bodies.append(called.pop())
+        called += [name for name in re.findall(
+            r"(?:body|condition|to_apply)=%([\w.-]+)",
+            computations[bodies[-1]]) if name not in bodies]
+
+    def only_moves(rest):
+        body = computations[re.search(r"calls=%([\w.-]+)", rest).group(1)]
+        return all(op in MOVES or op in VIEWS
+                   for _n, _d, op, _r in INSTRUCTION.findall(body))
+
+    moved = []
+    for body in bodies:
+        for instruction, types, op, rest in RESULTS.findall(
+                computations[body]):
+            results = {_sizes(dims.split(","))
+                       for dims in re.findall(r"\w+\[([\d,]+)\]", types)}
+            if results & weights and (
+                    op in MOVES or op == "fusion" and only_moves(rest)):
+                moved.append((instruction, types.split("{")[0], op))
+    return moved
+
+
+def test_cell_9_steps_move_no_weight(chip, for_the_chip):
+    cfg, compiled = _generating(CELL_9, chip)
+    lt = cfg.plan.lightning
+    assert (cfg.d_model, lt.width) == (4096, 4096)
+    assert (4096, 4096) in _weights(cfg) and (3, 4096, 4096) in _weights(cfg)
+    # the parent: fusion.587, fusion.584, fusion.563, each ``lt_k``, ``lt_q``
+    # or ``lt_v`` out of its stack as three ``bf16[1,4096,4096]``
+    moved = _weight_moves(cfg, compiled.as_text())
+    assert not moved, moved
+
+
+def test_cell_9s_generating_program_holds_no_relaid_stack(chip, for_the_chip):
+    cfg, compiled = _generating(CELL_9, chip)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes <= PARENT_TEMP_BYTES
+    assert _peak(memory) <= PARENT_PEAK_BYTES, _peak(memory) / 2 ** 30
+    # nothing before the loop re-lays a stack either (the parent: copy.235
+    # to .237): every product reads the layout the leaves are stored in
+    weights = _weights(cfg)
+    relaid = [(name, dims) for name, dims, op, _rest
+              in INSTRUCTION.findall(compiled.as_text())
+              if op in ("copy", "transpose") and dims
+              and _sizes(dims.split(",")) in weights]
+    assert not relaid, relaid
+
+
+def test_cell_7_steps_move_no_weight(chip, for_the_chip):
+    cfg, compiled = _generating(CELL_7, chip)
+    assert (128, 1024, 2304) in _weights(cfg)       # a layer's held experts
+    moved = _weight_moves(cfg, compiled.as_text())
+    assert not moved, moved
