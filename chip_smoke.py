@@ -30,9 +30,11 @@ exits non-zero before compiling anything.  The phases are functions of a
 CPU mesh, where the suite's conftest puts pallas in interpret mode.
 
 The line before the last is ``startup {...}``: where the host's time went,
-by span and by program (``ompi_tpu/core/scopes.startup()``, which also gives
-every phase's compile seconds and cache hits).  The last line of stdout is
-``{"ok": true, "device": {...}}``.
+by span, by program, by program object and stage (``calls``) and by layer
+kind and kernel traced (``trace``), with the spans the record could not keep
+(``dropped``: 0 in a sound run) (``ompi_tpu/core/scopes.startup()``, which
+also gives every phase's compile seconds and cache hits).  The last line of
+stdout is ``{"ok": true, "device": {...}}``.
 """
 
 from __future__ import annotations
@@ -86,8 +88,9 @@ def compile_totals() -> dict:
 
 def startup_summary(slowest: int = 5) -> dict:
     """The program's own account of the host side of this process
-    (``scopes.startup()``), with every program that is not the package's own
-    summed but for the ``slowest`` few."""
+    (``scopes.startup()``: ``spans``, ``programs``, ``calls``, ``trace``,
+    ``retraces``, ``totals``, ``records``, ``dropped``), with every program
+    that is not the package's own summed but for the ``slowest`` few."""
     from ompi_tpu.core import scopes
 
     out = scopes.startup()

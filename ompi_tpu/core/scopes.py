@@ -25,26 +25,30 @@ is served the cached executable with the old names; use a fresh
 compilation cache) and to feed it after is a span from the second tuple,
 ``HOST_SPANS``, opened by ``host()`` where the work happens: the factories
 (``make_train_step``, ``make_train_loop``, ``make_decoder``,
-``train_stream``), the package's import of pallas and of jax, the input
-stream's worker.  A span is a ``jax.profiler.TraceAnnotation`` named
-``ompi_tpu:<name>``, so a profile shows it on the device trace's clock
+``train_stream``), the package's import of pallas, one layer's python and one
+kernel's body while a program is traced, the input stream's worker.  A span
+is a ``jax.profiler.TraceAnnotation`` named ``ompi_tpu:<name>``, so a
+profile shows it on the device trace's clock
 (``benchmarks/lib/clock.py`` names idle gaps after it), and one record in
 memory on ``time.perf_counter``, so that a process can say where its
 set-up went with no profiler: ``startup()``.  The three ``compile.*`` names
 are not opened here: they are JAX's own timing of every program's trace,
 lowering and backend compile (``jax.monitoring``), recorded under the
 program's name by listeners that the first ``host()`` registers, once, for
-the life of the process.  Always on, like the stream's ``stats()``: no
-file, no exporter, no option.  The flight recorder of ``mpi/trace.py``
-belongs to the host MPI plane and carries none of this
-(``OBSERVABILITY.md``, "The device path").
+the life of the process.  A stage of one of the package's own programs (one
+a factory registered, ``program()``) and a top-level stage of any other are
+records; a stage of any other program that begins inside a stage, a helper
+(``multiply``, ``_where``, a plan's layer), is **folded**: no record, its
+seconds stay the enclosing stage's, which counts it (``helpers``).  Always
+on, like the stream's ``stats()``: no file, no exporter, no option.  The
+flight recorder of ``mpi/trace.py`` belongs to the host MPI plane and
+carries none of this (``OBSERVABILITY.md``, "The device path").
 """
 
 from __future__ import annotations
 
 import collections
 import itertools
-import sys
 import threading
 import time
 from typing import NamedTuple, Optional
@@ -139,7 +143,6 @@ def coll(method: str, axes):
 
 # ``<what>.<which>``; ``PERF.md`` section 3 says which metric reads which
 HOST_SPANS = (
-    "import.jax",       # the process's first ``import jax``, made by host()
     "import.pallas",    # jax.experimental.pallas[.tpu] (``ops/_pallas.py``)
     "import.optax",     # the train step's optimizer library
     "build.train_step", "build.train_loop", "build.decoder", "build.stream",
@@ -147,12 +150,18 @@ HOST_SPANS = (
     "compile.trace",    # JAX's own clock, by program: python to a jaxpr
     "compile.lower",    # ... the jaxpr to an MLIR module
     "compile.backend",  # ... the backend: a compile, or a cache read
+    "trace.layer",      # while a program is traced, one layer's python, by
+                        # kind: a plan's mixer (``models/plan.py``), "block"
+    "trace.kernel",     # ... one ``pallas_call``'s, the kernel's body traced
+                        # with it, by the kernel's name (``ops/_pallas.py``)
     "data.produce",     # the input stream's worker: one host batch made
                         # and put on the devices
 )
 PREFIX = "ompi_tpu:"    # of the annotation's name in a profile
-# The record keeps a process's first LIMIT spans and counts the rest: what
-# it is read for is the start, and a long job's stream makes a span a batch.
+# The record keeps a process's first LIMIT spans and counts the rest
+# (``startup()["dropped"]``): what it is read for is the start, and a long
+# job's stream makes a span a batch.  Helpers being folded, a decoder's
+# set-up is a few hundred.
 LIMIT = 16384
 
 _STAGES = {
@@ -173,35 +182,70 @@ class Span(NamedTuple):
     ``parent`` is the ``id`` of the innermost span that was open on the same
     thread when this one began, or None."""
     name: str                   # of HOST_SPANS
-    program: Optional[str]      # the program built, traced, lowered, compiled
+    program: Optional[str]      # the program built, traced, lowered, compiled;
+                                # a ``trace.*`` span's layer kind, kernel name
     start: float
     end: float
     parent: Optional[int]
     id: int
     cache: Optional[str] = None     # compile.backend: "hit" | "miss"
+    helpers: int = 0            # compile.*: the stages folded into this one
+    built: Optional[int] = None     # compile.* of an own program: which of
+                                    # the name's objects (``Program.built``)
 
 
 class _Open:
     """A span that has begun on a thread and not ended: what a later span
     of that thread names as its parent."""
-    __slots__ = ("id", "name", "program", "cache")
+    __slots__ = ("id", "name", "program", "cache", "helpers", "built")
+    folded = False
 
     def __init__(self, name: str, program: Optional[str]) -> None:
         self.id, self.name, self.program = next(_ids), name, program
         self.cache = None       # compile.backend: what the cache answered
+        self.helpers, self.built = 0, None
+
+
+class _Folded:
+    """A helper's stage, which leaves no record: it stands on the thread's
+    stack until it ends, under the ``id`` of the span around it (the parent
+    of whatever begins inside it), so that ``_on_span`` can tell its end
+    from that of a stage which began before the listeners were registered."""
+    __slots__ = ("id", "name", "program", "cache", "into")
+    folded = True
+
+    def __init__(self, name: str, program: str, around: int,
+                 into: _Open) -> None:
+        self.id, self.name, self.program, self.cache = (around, name,
+                                                        program, None)
+        self.into = into        # the stage that keeps its seconds
+        into.helpers += 1
 
 
 class Program:
-    """One program a factory builds.  The factory calls ``traced()`` as the
-    first statement of the jitted function: python that runs when JAX traces
-    the function and never when the program runs."""
-    __slots__ = ("name", "traces")
+    """One program object a factory builds: ``name``, that of its jitted
+    function; ``part``, which of a decoder's programs it is ("prefill",
+    "generate", "whole") or None; ``built``, its place among the factories'
+    calls (None once ``reset()`` has forgotten it).  The factory calls
+    ``traced()`` as the first statement of the jitted function: python that
+    runs when JAX traces the function and never when the program runs."""
+    __slots__ = ("name", "part", "built", "traces")
 
-    def __init__(self, name: str) -> None:
-        self.name, self.traces = name, 0
+    def __init__(self, name: str, part: Optional[str], built: int) -> None:
+        self.name, self.part, self.built, self.traces = name, part, built, 0
 
     def traced(self) -> None:
+        """Inside the open ``compile.trace`` stage of the object's own jit:
+        that stage is this object's, and so are the lowering and the backend
+        stage that follow on the thread under its name."""
         self.traces += 1
+        _traced_last()[self.name] = self.built
+        for entry in reversed(_stack()):
+            # folded: the name is no longer a registered one (``reset()``)
+            if (entry.name == "compile.trace" and entry.program == self.name
+                    and not entry.folded):
+                entry.built = self.built
+                break
 
 
 def _new_totals() -> dict:
@@ -212,10 +256,12 @@ def _new_totals() -> dict:
 _lock = threading.Lock()        # the record, the totals, the registration
 _records: list = []
 _programs: list = []
+_own: set = set()               # the names of _programs
 _totals = _new_totals()
 _dropped = 0
 _ids = itertools.count()
-_open = threading.local()       # .stack: the spans open on this thread
+_open = threading.local()       # .stack: the spans open on this thread;
+                                # .last: name -> the object last traced on it
 _listening = False
 _offset = 0.0                   # perf_counter less time.time, at registration
 
@@ -228,6 +274,14 @@ def _stack() -> list:
         return _open.stack
 
 
+def _traced_last() -> dict:
+    try:
+        return _open.last
+    except AttributeError:
+        _open.last = {}
+        return _open.last
+
+
 def _append(span: Span) -> None:
     global _dropped
     with _lock:
@@ -237,7 +291,7 @@ def _append(span: Span) -> None:
             _dropped += 1
 
 
-def _close(stack: list, entry: _Open) -> Optional[int]:
+def _close(stack: list, entry) -> Optional[int]:
     """Take ``entry`` (and anything left open above it) off ``stack``; the
     id of its parent."""
     for i in range(len(stack) - 1, -1, -1):
@@ -248,16 +302,9 @@ def _close(stack: list, entry: _Open) -> Optional[int]:
 
 
 def _jax():
-    """``jax``, with the listeners registered; imported under an
-    ``import.jax`` record where this is the process's first import of it."""
-    first = "jax" not in sys.modules
-    start = time.perf_counter()
+    """``jax``, with the listeners registered."""
     import jax
 
-    if first:
-        stack = _stack()
-        _append(Span("import.jax", None, start, time.perf_counter(),
-                     stack[-1].id if stack else None, next(_ids)))
     if not _listening:
         _listen(jax)
     return jax
@@ -289,10 +336,23 @@ def _program_of(fun_name: str) -> str:
 def _on_begin(event: str, _value, fun_name: str = "", **_kw) -> None:
     """A stage begins (JAX records its start as a scalar): it is open on
     this thread until ``_on_span`` closes it, so what happens inside it, an
-    inner trace or a lazy import, is its child."""
+    inner trace or a lazy import, is its child.  A stage of a program that
+    no factory registered, inside another stage, is folded into that one."""
     name = _STAGES.get(event)
-    if name is not None:
-        _stack().append(_Open(name, _program_of(fun_name)))
+    if name is None:
+        return
+    prog, stack = _program_of(fun_name), _stack()
+    if prog in _own:
+        entry = _Open(name, prog)
+        entry.built = _traced_last().get(prog)  # a trace: ``traced()`` says
+        stack.append(entry)
+        return
+    for around in reversed(stack):
+        if around.name in _STAGE_KEYS:
+            stack.append(_Folded(name, prog, stack[-1].id,
+                                 around.into if around.folded else around))
+            return
+    stack.append(_Open(name, prog))
 
 
 def _on_span(event: str, start: float, end: float, fun_name: str = "",
@@ -300,18 +360,19 @@ def _on_span(event: str, start: float, end: float, fun_name: str = "",
     name = _STAGES.get(event)
     if name is None:
         return
+    if name == "compile.backend":
+        with _lock:
+            _totals["programs"] += 1
+            _totals["backend_s"] += end - start
     prog, stack = _program_of(fun_name), _stack()
     entry = next((e for e in reversed(stack)
                   if e.name == name and e.program == prog), None)
     if entry is None:       # it began before the listeners were registered
         entry = _Open(name, prog)
     parent = _close(stack, entry)
-    _append(Span(name, prog, start + _offset, end + _offset, parent,
-                 entry.id, entry.cache))
-    if name == "compile.backend":
-        with _lock:
-            _totals["programs"] += 1
-            _totals["backend_s"] += end - start
+    if not entry.folded:
+        _append(Span(name, prog, start + _offset, end + _offset, parent,
+                     entry.id, entry.cache, entry.helpers, entry.built))
 
 
 def _on_event(event: str, **_kw) -> None:
@@ -358,13 +419,16 @@ class host:
                      _close(_stack(), self.entry), self.entry.id))
 
 
-def program(name: str) -> Program:
+def program(name: str, part: Optional[str] = None) -> Program:
     """Called by the factory that builds the program ``name`` (the name of
-    its jitted function): the record then knows ``name`` for one of the
-    package's own programs, and the handle counts that object's traces."""
-    handle = Program(name)
+    its jitted function), once for each program object; ``part`` says which
+    of a factory's objects this one is.  The record then knows ``name`` for
+    one of the package's own programs, and the handle counts that object's
+    traces and marks its stages."""
     with _lock:
+        handle = Program(name, part, len(_programs))
         _programs.append(handle)
+        _own.add(name)
     return handle
 
 
@@ -380,38 +444,54 @@ def reset() -> None:
     global _dropped, _totals
     with _lock:
         del _records[:]
+        for handle in _programs:
+            handle.built = None
         del _programs[:]
+        _own.clear()
         _totals = _new_totals()
         _dropped = 0
 
 
 def _row() -> dict:
     return {"trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0, "cache": None,
-            "traces": 0, "compiles": 0}
+            "traces": 0, "compiles": 0, "helpers": 0}
 
 
 def startup(spans: Optional[list] = None) -> dict:
     """Where the host's time went, from the record (or from ``spans``).
 
     Seconds are **self time by containment**: a span's duration less its
-    children's, because spans nest (``matmul`` is traced inside
-    ``train_step``'s trace, a lazy ``import.pallas`` falls inside the trace
-    of ``decode``) and a plain sum would count those seconds twice.
+    children's, because spans nest (a ``trace.layer`` lies inside
+    ``decode``'s trace, a ``trace.kernel`` inside that, a lazy
+    ``import.pallas`` inside that) and a plain sum would count those seconds
+    twice.  A folded helper's seconds are its enclosing stage's own.
 
     - ``spans``: seconds by span name.
     - ``programs``: the package's own programs (those a factory registered),
-      a row each: ``trace_s``, ``lower_s``, ``backend_s``; ``cache``, "miss"
-      where a compile of it missed the persistent cache, else "hit" where
-      one was read from it, else None; ``traces``, how often the python
-      bodies of its objects ran (``Program.traced``); ``compiles``, how
-      often it went to the backend.  A stage inside another stage counts
-      for the outermost program of the package's own around it, else for
-      the outermost: the helpers traced inside ``train_step`` are
-      ``train_step``'s seconds.
-    - ``others``: the same rows for every other program of the process;
-      their ``traces`` are JAX's trace events, and JAX also records one, of
-      microseconds, for a call that misses the jitted function's fast path
-      and finds its jaxpr cached.
+      a row each name: ``trace_s``, ``lower_s``, ``backend_s``; ``cache``,
+      "miss" where a compile of it missed the persistent cache, else "hit"
+      where one was read from it, else None; ``traces``, how often the
+      python bodies of its objects ran (``Program.traced``); ``compiles``,
+      how often it went to the backend; ``helpers``, the helper stages
+      folded into its own.  A stage inside another stage counts for the
+      outermost program of the package's own around it, else for the
+      outermost.  A ``trace.*`` span inside a stage counts for that stage's
+      program under that stage's key: ``trace_s`` is the whole trace, its
+      layers and kernels included (an ``import.pallas`` there is not).
+    - ``calls``: the same seconds by program object, a row each in the order
+      the factories were called: ``program``, ``part``, ``built``,
+      ``trace_s``, ``lower_s``, ``backend_s``, ``cache``, ``traces``,
+      ``helpers``.  A name's row of ``programs`` is the sum of its objects'
+      rows; a stage that no object marked (a made-up record) is the name's
+      last object's.
+    - ``others``: the rows of ``programs`` for every other program of the
+      process that has a record (a top-level one: the draws, the
+      reference's); their ``traces`` are JAX's trace events, and JAX also
+      records one, of microseconds, for a call that misses the jitted
+      function's fast path and finds its jaxpr cached.
+    - ``trace``: the ``trace.*`` spans, by span name and then by layer kind
+      or kernel name: ``seconds``, ``own_s`` (those inside the package's own
+      programs) and ``spans``, how many.
     - ``retraces``: traces of an own program beyond the first of each
       distinct program object.
     - ``totals``: the process's counts since the listeners were registered:
@@ -420,12 +500,12 @@ def startup(spans: Optional[list] = None) -> dict:
     - ``records``, ``dropped``: spans kept, and spans beyond ``LIMIT``.
     """
     with _lock:
-        traced = collections.Counter()
-        for p in _programs:
-            traced[p.name] += p.traces
-        retraces = sum(max(0, p.traces - 1) for p in _programs)
+        calls = [{"program": p.name, "part": p.part, "built": p.built,
+                  "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+                  "cache": None, "traces": p.traces, "helpers": 0}
+                 for p in _programs]
         totals, dropped = dict(_totals), _dropped
-    own = set(traced)
+    last = {call["program"]: call for call in calls}    # a name's last object
     if spans is None:
         spans = records()
     by_id = {s.id: s for s in spans}
@@ -434,37 +514,61 @@ def startup(spans: Optional[list] = None) -> dict:
         if s.parent in by_id:
             inside[s.parent] += s.end - s.start
 
-    def owner(span: Span) -> str:
-        found, outermost_own = span.program, None
+    def stages(span: Span):
+        """The stages around ``span``, itself included: the innermost, the
+        outermost and the outermost of an own program."""
+        innermost = outermost = outermost_own = None
         while span is not None:
             if span.name in _STAGE_KEYS:
-                found = span.program
-                if found in own:
-                    outermost_own = found
+                innermost, outermost = innermost or span, span
+                if span.program in last:
+                    outermost_own = span
             span = by_id.get(span.parent)
-        return outermost_own or found
+        return innermost, outermost, outermost_own
 
     by_name = collections.defaultdict(float)
-    rows = {"programs": collections.defaultdict(_row),
-            "others": collections.defaultdict(_row)}
+    programs = collections.defaultdict(_row)
+    others = collections.defaultdict(_row)
+    traced = collections.defaultdict(
+        lambda: collections.defaultdict(
+            lambda: {"seconds": 0.0, "own_s": 0.0, "spans": 0}))
     for s in spans:
         self_s = max(0.0, s.end - s.start - inside[s.id])
         by_name[s.name] += self_s
-        key = _STAGE_KEYS.get(s.name)
-        if key is None:
+        is_stage = s.name in _STAGE_KEYS
+        if not (is_stage or s.name.startswith("trace.")):
             continue
-        name = owner(s)
-        row = rows["programs" if name in own else "others"][name]
-        row[key] += self_s
-        if s.name == "compile.trace":
-            row["traces"] += s.program == name  # an own row's: see below
-        elif s.name == "compile.backend":
-            row["compiles"] += 1
-            if s.cache == "miss" or row["cache"] is None:
-                row["cache"] = s.cache
-    for name, row in rows["programs"].items():
-        row["traces"] = traced[name]    # counted where the python body ran
-    return {"spans": dict(by_name), "programs": dict(rows["programs"]),
-            "others": dict(rows["others"]), "retraces": retraces,
+        innermost, outermost, own = stages(s)
+        if not is_stage:
+            entry = traced[s.name][s.program]
+            entry["seconds"] += self_s
+            entry["own_s"] += self_s if own is not None else 0.0
+            entry["spans"] += 1
+            if innermost is None:   # a kernel called outside any program
+                continue
+        if own is None:
+            row = others[outermost.program]
+            into = (row,)
+        else:
+            row, call = programs[own.program], last[own.program]
+            if (own.built is not None and own.built < len(calls)
+                    and calls[own.built]["program"] == own.program):
+                call = calls[own.built]
+            into = (row, call)
+        for sums in into:
+            sums[_STAGE_KEYS[innermost.name]] += self_s
+            sums["helpers"] += s.helpers
+            if s.name == "compile.backend" and (s.cache == "miss"
+                                                or sums["cache"] is None):
+                sums["cache"] = s.cache
+        row["compiles"] += s.name == "compile.backend"
+        row["traces"] += (s.name == "compile.trace"     # an own row's: below
+                          and s.program == outermost.program)
+    for name, row in programs.items():
+        # counted where the python body ran
+        row["traces"] = sum(c["traces"] for c in calls if c["program"] == name)
+    return {"spans": dict(by_name), "programs": dict(programs),
+            "calls": calls, "others": dict(others),
+            "trace": {name: dict(by) for name, by in traced.items()},
+            "retraces": sum(max(0, c["traces"] - 1) for c in calls),
             "totals": totals, "records": len(spans), "dropped": dropped}
-

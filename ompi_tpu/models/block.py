@@ -227,7 +227,17 @@ def block(cfg, comm, lp, h, positions, carry=None, **how):
     layer, pos)``: T == 1 against the carry, a list of each mechanism's own
     stacks; ``lp``'s dropless expert leaves (``moe.EXPERT_LEAVES``) are then
     the whole stacks over layers, which ``routed_moe`` indexes by ``layer``.
-    Returns ``(h, stacks)``."""
+    Returns ``(h, stacks)``.
+
+    Runs while a program is traced: what that costs the host is a
+    ``trace.layer`` span of its record (``core/scopes.py``), kind "block"."""
+    from ompi_tpu.core.scopes import host
+
+    with host("trace.layer", program="block"):
+        return _block(cfg, comm, lp, h, positions, carry, **how)
+
+
+def _block(cfg, comm, lp, h, positions, carry, **how):
     import jax.numpy as jnp
 
     from ompi_tpu.core.scopes import scope

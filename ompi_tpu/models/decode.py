@@ -304,12 +304,13 @@ def _greedy(decode, temperature: float):
     return lambda params, prompt: decode(params, prompt, _np.int32(0))
 
 
-def _program(cfg: TransformerConfig, mesh, local, in_specs: tuple, out_specs,
-             **options):
+def _program(cfg: TransformerConfig, mesh, local, part: str, in_specs: tuple,
+             out_specs, **options):
     """``local(params, *args)`` of one device under ``shard_map``, jitted
     with ``options``.  The function's name is the program's name in a
     profile and in the host's record (``scopes.startup()``): both halves of
-    a plan's job are ``decode``, as the one program is."""
+    a plan's job are ``decode``, as the one program is, and ``part`` tells
+    the objects apart in the record's ``calls``."""
     import jax
     from jax.sharding import PartitionSpec as P
 
@@ -318,7 +319,7 @@ def _program(cfg: TransformerConfig, mesh, local, in_specs: tuple, out_specs,
     mapped = jax.shard_map(
         local, mesh=mesh, in_specs=(param_specs(P, cfg, mesh), *in_specs),
         out_specs=out_specs, check_vma=False)
-    record = scopes.program("decode")
+    record = scopes.program("decode", part)
 
     @functools.partial(jax.jit, **options)
     def decode(params, *args):
@@ -342,7 +343,7 @@ def _one_program(cfg: TransformerConfig, mesh, max_new: int,
                         *prefill(params, head, prompt, seed))[0]
 
     return _greedy(_program(
-        cfg, mesh, local, (P("dp", None), P()),
+        cfg, mesh, local, "whole", (P("dp", None), P()),
         (P("dp", None), P()) if keep_logits else P("dp", None)), temperature)
 
 
@@ -368,7 +369,7 @@ def _prefill_program(cfg: TransformerConfig, mesh, temperature: float,
                 logits[:keep_logits, None],
                 tuple(buffer for own in stacks for buffer in own))
 
-    return _program(cfg, mesh, local, (P("dp", None), P()),
+    return _program(cfg, mesh, local, "prefill", (P("dp", None), P()),
                     (P("dp", None), P(), P(None, "dp")))
 
 
@@ -430,7 +431,7 @@ def _two_programs(cfg: TransformerConfig, mesh, max_new: int,
     # the states come back so that each is written in the buffer it came
     # in: a donated buffer is reused for an output of its shape alone
     decode = _program(
-        cfg, mesh, local,
+        cfg, mesh, local, "generate",
         (P("dp", None), P(), P(), P(None, "dp"), P(None, "dp")),
         ((P("dp", None), P()) if keep_logits else P("dp", None),
          P(None, "dp")), donate_argnums=4)

@@ -419,7 +419,7 @@ def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
     import jax
     import jax.numpy as jnp
 
-    from ompi_tpu.core.scopes import scope
+    from ompi_tpu.core.scopes import host, scope
     from ompi_tpu.models import transformer as tfm
 
     check_mesh(cfg, comm.mesh)
@@ -448,7 +448,9 @@ def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
     collected = []
     with scope("layers"):
         for layer, (mixer, mlp) in enumerate(pl.layers):
-            h, states = layer_fn(layer, mixer, mlp)(h, params)
+            # the host's record of what tracing this layer costs, by kind
+            with host("trace.layer", program=mixer):
+                h, states = layer_fn(layer, mixer, mlp)(h, params)
             collected += [state[None] for state in states]
     h = tfm._rmsnorm(h, params["lnf"], cfg.norm_eps)
     if pl.head_divisor != 1:
@@ -464,6 +466,8 @@ def step(cfg, comm, params, h, states, pos):
     carry ``states`` (:func:`carry`'s buffers), each layer reading and
     replacing its own.  ``params``: the leaves stacked over layers.  Returns
     ``(h, *states)``."""
+    from ompi_tpu.core.scopes import host
+
     pl = cfg.plan
 
     def layer_fn(layer, mixer, mlp):
@@ -482,7 +486,8 @@ def step(cfg, comm, params, h, states, pos):
     states, at = list(states), 0
     for layer, (mixer, mlp) in enumerate(pl.layers):
         n = len(_module(mixer).buffers(cfg, getattr(pl, mixer), 0, 0))
-        h, states[at:at + n] = layer_fn(layer, mixer, mlp)(
-            h, params, states[at:at + n], pos)
+        with host("trace.layer", program=mixer):
+            h, states[at:at + n] = layer_fn(layer, mixer, mlp)(
+                h, params, states[at:at + n], pos)
         at += n
     return (h, *states)

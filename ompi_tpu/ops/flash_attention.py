@@ -354,13 +354,13 @@ def _flash_fwd_raw(q3, k3, v3, qoff, koff, heads: int, scale: float,
                    causal: bool, blocks: tuple[int, int]):
     """(B', Tq, H'·D) × (B', Tk, H'·D) → (out like q3, lse (B', H', 1, Tq)
     float32)."""
-    from ompi_tpu.ops._pallas import pl
+    from ompi_tpu.ops._pallas import pallas_call
 
     b, t_q, hd = q3.shape
     t_k, d = k3.shape[1], hd // heads
     block_q, block_k = blocks
     smem, blk, whole, row_blk, _ = _specs(d)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
         grid=(b, heads, t_q // block_q),
@@ -376,13 +376,13 @@ def _flash_fwd_raw(q3, k3, v3, qoff, koff, heads: int, scale: float,
 def _flash_bwd_raw(q3, k3, v3, g3, lse4, dm4, qoff, koff, heads: int,
                    scale: float, causal: bool, blocks: tuple[int, int]):
     """(B', ·, H'·D) operands, (B', H', 1, Tq) rows → (dq3, dk3, dv3)."""
-    from ompi_tpu.ops._pallas import pl
+    from ompi_tpu.ops._pallas import pallas_call
 
     b, t_q, hd = q3.shape
     t_k, d = k3.shape[1], hd // heads
     smem, blk, whole, row_blk, row_whole = _specs(d)
     block_q, block_k = blocks
-    dq3 = pl.pallas_call(
+    dq3 = pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
         grid=(b, heads, t_q // block_q),
@@ -393,7 +393,7 @@ def _flash_bwd_raw(q3, k3, v3, g3, lse4, dm4, qoff, koff, heads: int,
         compiler_params=_params(),
         name="flash_bwd_dq",
     )(qoff, koff, q3, k3, v3, g3, lse4, dm4)
-    dk3, dv3 = pl.pallas_call(
+    dk3, dv3 = pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
         grid=(b, heads, t_k // block_k),

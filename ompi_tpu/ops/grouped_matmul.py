@@ -137,7 +137,7 @@ def _kernel(tile_group, tiles_used, lhs, rhs, out, acc):
 
 
 def _forward(rows, w, tile_group, tiles_used):
-    from ompi_tpu.ops._pallas import pl
+    from ompi_tpu.ops._pallas import pallas_call, pl
     from ompi_tpu.ops._pallas import pltpu
 
     n_tiles = tile_group.shape[0]
@@ -148,7 +148,7 @@ def _forward(rows, w, tile_group, tiles_used):
                          f"{n_tiles} tiles")
     tm = m // n_tiles
     tk, tn = weight_block(tm, K, N, jnp.dtype(w.dtype).itemsize)
-    return pl.pallas_call(
+    return pallas_call(
         _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,

@@ -93,7 +93,7 @@ def _kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s_ref, o_ref, s_out):
 
 @jax.jit
 def _call(state, q, k, v, g, beta):
-    from ompi_tpu.ops._pallas import pl
+    from ompi_tpu.ops._pallas import pallas_call, pl
     from ompi_tpu.ops._pallas import pltpu
 
     B, H, K, _ = state.shape
@@ -103,7 +103,7 @@ def _call(state, q, k, v, g, beta):
     rows = [y.reshape(B, H // hb, hb, K) for y in (q, k, v, g)]
     vector = pl.BlockSpec((None, None, hb, K), lambda b, j: (b, j, 0, 0))
     matrix = pl.BlockSpec((None, hb, K, K), lambda b, j: (b, j, 0, 0))
-    o, state = pl.pallas_call(
+    o, state = pallas_call(
         _kernel,
         grid=(B, H // hb),
         in_specs=[vector, vector, vector, vector,

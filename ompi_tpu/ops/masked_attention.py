@@ -81,7 +81,7 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 @functools.partial(jax.jit, static_argnums=(4,))
 def _call(q3, k3, v3, mask, kv_heads: int):
-    from ompi_tpu.ops._pallas import pl
+    from ompi_tpu.ops._pallas import pallas_call, pl
     from ompi_tpu.ops._pallas import pltpu
 
     b, t_q, width = q3.shape
@@ -90,7 +90,7 @@ def _call(q3, k3, v3, mask, kv_heads: int):
     group = width // (kv_heads * d)
     block_q = _BLOCK if t_q % _BLOCK == 0 else t_q
     block_k = min(_BLOCK, t_k)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_kernel, scale=d ** -0.5, group=group, head_dim=d),
         grid=(b, kv_heads, t_q // block_q, t_k // block_k),
         in_specs=[

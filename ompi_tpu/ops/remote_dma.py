@@ -113,12 +113,14 @@ def window_put(win, value, src: int, dst: int, axis: str):
     """
     import jax
 
+    from ompi_tpu.ops._pallas import pallas_call
+
     pl, pltpu = _pl()
     if win.shape != value.shape or win.dtype != value.dtype:
         raise ValueError(
             f"window_put: value {value.shape}/{value.dtype} must match the "
             f"window shard {win.shape}/{win.dtype}")
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_put_kernel, src=src, dst=dst, axis=axis),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
@@ -175,8 +177,10 @@ def window_get(win, src: int, dst: int, axis: str):
     """
     import jax
 
+    from ompi_tpu.ops._pallas import pallas_call
+
     pl, pltpu = _pl()
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_get_kernel, src=src, dst=dst, axis=axis),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
@@ -237,8 +241,10 @@ def fetch_bcast(x, root: int, n: int, axis: str):
 
     if n == 1:
         return x
+    from ompi_tpu.ops._pallas import pallas_call
+
     pl, pltpu = _pl()
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_bcast_kernel, root=root, n=n, axis=axis),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),

@@ -125,7 +125,7 @@ def _kernel(layer_ref, pq_ref, pk_ref, v_ref, decay_ref, s_ref, sums_ref,
 
 @jax.jit
 def _call(stack, layer, pq, decay, pk, v):
-    from ompi_tpu.ops._pallas import pl
+    from ompi_tpu.ops._pallas import pallas_call, pl
     from ompi_tpu.ops._pallas import pltpu
 
     _, B, G, D, d = stack.shape
@@ -135,7 +135,7 @@ def _call(stack, layer, pq, decay, pk, v):
     row = pl.BlockSpec((None, None, 1, d), lambda b, g, j, layer: (b, g, 0, 0))
     state = pl.BlockSpec((None, None, None, rows, d),
                          lambda b, g, j, layer: (layer[0], b, g, j, 0))
-    sums, stack = pl.pallas_call(
+    sums, stack = pallas_call(
         _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,

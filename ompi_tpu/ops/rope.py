@@ -67,12 +67,12 @@ def _kernel(x_ref, cos_ref, sin_ref, o_ref, *, head_dim: int):
 # train step at one shape, traced once a process and lowered once a program
 @functools.partial(jax.jit, static_argnums=3)
 def _call(x, cos, sin, head_dim: int):
-    from ompi_tpu.ops._pallas import pl
+    from ompi_tpu.ops._pallas import pallas_call, pl
     from ompi_tpu.ops._pallas import pltpu
 
     b, t, width = x.shape
     rows = _rows(t, width, x.dtype.itemsize)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_kernel, head_dim=head_dim),
         grid=(b, t // rows),
         in_specs=[pl.BlockSpec((1, rows, width), lambda i, j: (i, j, 0)),

@@ -93,12 +93,12 @@ def _kernel(layer, q_ref, kv_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 @jax.jit
 def _call(layer, q4, kv, mask3):
-    from ompi_tpu.ops._pallas import pl
+    from ompi_tpu.ops._pallas import pallas_call, pl
     from ompi_tpu.ops._pallas import pltpu
 
     b, kv_heads, group, d = q4.shape
     t_max, width = kv.shape[2], kv.shape[3]
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_kernel, scale=d ** -0.5),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,

@@ -1,0 +1,241 @@
+"""Five more readers of the program's own host record, and the rows they
+wait with.  ``metrics/startup_trace_s.py``, ``startup_lower_s.py``,
+``startup_kernel_trace_s.py``, ``startup_helper_traces.py`` and
+``startup_records_dropped.py`` read what ``ompi_tpu/core/scopes.startup()``
+has had since PR 54: ``calls`` (a row a program object, its stages apart),
+``trace`` (seconds by layer kind and by kernel), ``helpers`` and ``dropped``.
+Their rows are not in ``BENCHMARK.json``: a per-layer row goes at the end of
+its list alone, and ``test_startup_metrics.py`` holds six other rows to that
+end.  ``ROWS`` is what a ``benchmark`` PR appends once that line goes, with
+the eighteen of ``test_keye_vl2_rows.py``, ``test_kimi_linear_rows.py``,
+``test_brumby_rows.py`` and ``test_minicpm_sala_rows.py``.  Until then what
+the harness's tests ask of a row is asked here: the form of a row, a reader
+under its name, a number on a tiny run of every cell and on a made-up record,
+nothing on a program without the split; and what the record holds of cell 9's
+two programs lowered at their real sizes.  CPU only: nothing here is a time.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
+
+import jax  # noqa: E402
+import pytest  # noqa: E402
+
+from benchmarks.lib import cells, program  # noqa: E402
+from benchmarks.lib.rundata import RunData  # noqa: E402
+from benchmarks.lib.spans import Spans  # noqa: E402
+from ompi_tpu.core import scopes  # noqa: E402
+from ompi_tpu.core.scopes import Span  # noqa: E402
+from tests.benchmarks.test_harness import (LAYER, NAME,  # noqa: E402
+                                           PERF_LAYERS, TINY_TRAFFIC)
+
+BENCH = cells.load_benchmark()
+WORKLOADS = [w["name"] for w in BENCH["workloads"]]
+CELL_2, CELL_9 = WORKLOADS[1], WORKLOADS[8]
+
+
+def _row(name, unit, source):
+    return {"name": name, "unit": unit, "better": "lower", "source": source,
+            "layer": "startup", "moves": "setup_s", "workloads": WORKLOADS}
+
+
+ROWS = [
+    _row("startup_trace_s", "s", "program_span"),
+    _row("startup_lower_s", "s", "program_span"),
+    _row("startup_kernel_trace_s", "s", "program_span"),
+    _row("startup_helper_traces", "stages", "program_counter"),
+    _row("startup_records_dropped", "spans", "program_counter"),
+]
+NAMES = [row["name"] for row in ROWS]
+
+
+def _read(metric: str):
+    run = RunData(durations={}, facts={}, peaks=None, trace=None,
+                  compiles_in_window=0, peak_bytes=None)
+    return cells.load_reader(cells.BENCH_DIR, metric).read(run)
+
+
+@pytest.fixture(autouse=True)
+def fresh_record():
+    scopes.reset()
+    yield
+    scopes.reset()
+
+
+@pytest.fixture
+def fresh_programs():
+    """No program object and no trace of another test's: a prefill program
+    is one object a configuration and mesh (``lru_cache``), and a kernel's
+    jitted caller is traced once a shape a process."""
+    from ompi_tpu.models import decode
+
+    decode._prefill_program.cache_clear()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("row", ROWS, ids=lambda r: r["name"])
+def test_a_row_moves_set_up_in_every_cell(row):
+    assert NAME.match(row["name"]) and LAYER.match(row["layer"])
+    assert row["layer"] in PERF_LAYERS
+    taken = {m["name"] for key in ("end_to_end", "per_layer")
+             for m in BENCH[key]}
+    assert row["name"] not in taken
+    moved = next(m for m in BENCH["end_to_end"] if m["name"] == row["moves"])
+    assert "workloads" not in moved         # every cell reports ``setup_s``
+    # beside PR 40's five, in their form
+    like = next(m for m in BENCH["per_layer"]
+                if m["name"] == "startup_trace_lower_s")
+    assert set(row) == set(like)
+    assert {k: row[k] for k in ("layer", "moves", "better", "workloads")} == {
+        k: like[k] for k in ("layer", "moves", "better", "workloads")}
+    assert callable(cells.load_reader(cells.BENCH_DIR, row["name"]).read)
+
+
+@pytest.mark.parametrize("workload", WORKLOADS)
+def test_every_reader_gives_a_number_on_a_tiny_set_up_of_its_cell(
+        workload, fresh_programs):
+    cell = cells.resolve(workload)
+    cell.config = program.tiny(cell.config)
+    cell.traffic = {k: TINY_TRAFFIC.get(k, v)
+                    for k, v in cell.traffic.items()}
+    job = cell.runner.build(cell.config, cell.traffic,
+                            jax.devices()[:cell.chips])
+    try:
+        job.setup(5, Spans())
+        got = {name: _read(name) for name in NAMES}
+        whole = _read("startup_trace_lower_s")
+        out = scopes.startup()
+    finally:
+        job.close()
+    assert got["startup_trace_s"] > 0 and got["startup_lower_s"] > 0
+    assert got["startup_trace_s"] + got["startup_lower_s"] == pytest.approx(
+        whole, abs=1e-6)
+    assert got["startup_helper_traces"] > 0
+    assert got["startup_records_dropped"] == 0
+    # the tiny programs hold no kernel (``test_flash_in_cells.py``)
+    assert got["startup_kernel_trace_s"] == 0
+    assert "trace.kernel" not in out["spans"]
+    # a row an object; a decoder's say which of its programs they are
+    parts = [(row["program"], row["part"]) for row in out["calls"]]
+    if "train" in workload:
+        assert parts == [("train_step", None)]
+    else:
+        assert parts in ([("decode", "prefill"), ("decode", "generate")],
+                         [("decode", "whole")] * 2)
+    for key in ("trace_s", "lower_s", "backend_s", "helpers"):
+        (name,) = out["programs"]
+        assert sum(row[key] for row in out["calls"]) == pytest.approx(
+            out["programs"][name][key], abs=1e-6)
+    kinds = set(out["trace"]["trace.layer"])
+    plan = program.program_config(cell.config).plan
+    assert kinds == ({mixer for mixer, _mlp in plan.layers} if plan
+                     else {"block"})
+    # every helper folded: what is left is a few spans a layer
+    assert out["records"] < 300
+
+
+def test_the_readers_arithmetic_on_a_made_up_record(monkeypatch):
+    made_up = [
+        Span("compile.trace", "decode", 0.0, 10.0, None, 1, None, 40, 0),
+        Span("import.pallas", None, 1.0, 2.0, 1, 2),
+        Span("trace.layer", "block_select", 2.0, 8.0, 1, 3),
+        Span("trace.kernel", "masked_attention", 3.0, 4.0, 3, 4),
+        Span("trace.kernel", "masked_attention", 4.0, 5.5, 3, 5),
+        Span("compile.lower", "decode", 10.0, 14.0, None, 6, None, 2, 0),
+        Span("compile.trace", "decode", 20.0, 21.0, None, 7, None, 7, 1),
+        Span("trace.kernel", "selected_attention", 20.0, 20.25, 7, 8),
+        Span("compile.lower", "decode", 21.0, 21.5, None, 9, None, 0, 1),
+        Span("compile.backend", "decode", 21.5, 22.0, None, 10, "hit", 0, 1),
+        # the reference's: another program's kernel is not the package's
+        Span("compile.trace", "forward", 30.0, 33.0, None, 11, None, 9),
+        Span("trace.kernel", "rope", 31.0, 32.0, 11, 12),
+    ]
+    scopes.program("decode", "prefill")
+    scopes.program("decode", "generate")
+    whole = scopes.startup
+    monkeypatch.setattr(scopes, "startup", lambda: {**whole(made_up),
+                                                    "dropped": 3})
+    want = {"startup_trace_s": 9.0 + 1.0, "startup_lower_s": 4.5,
+            "startup_kernel_trace_s": 2.5 + 0.25,
+            "startup_helper_traces": 49, "startup_records_dropped": 3,
+            "startup_trace_lower_s": 14.5}
+    for name, value in want.items():
+        assert _read(name) == value, name
+    out = scopes.startup()
+    assert [(r["part"], r["trace_s"], r["lower_s"], r["backend_s"],
+             r["helpers"]) for r in out["calls"]] == [
+        ("prefill", 9.0, 4.0, 0.0, 42), ("generate", 1.0, 0.5, 0.5, 7)]
+    assert out["trace"]["trace.kernel"]["rope"] == {
+        "seconds": 1.0, "own_s": 0.0, "spans": 1}
+    assert out["others"]["forward"]["trace_s"] == 3.0
+
+
+@pytest.mark.parametrize("metric", NAMES)
+def test_a_program_without_the_split_reads_nothing(metric, monkeypatch):
+    """Laid over the parent's checkout: a record without ``calls`` and
+    ``trace`` (PR 40's), or no record at all."""
+    whole = scopes.startup
+    monkeypatch.setattr(scopes, "startup", lambda: {
+        k: v for k, v in whole().items() if k not in ("calls", "trace")})
+    assert _read(metric) is None
+    monkeypatch.delattr(scopes, "startup")
+    assert _read(metric) is None
+
+
+@pytest.fixture(scope="module")
+def chips():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    except Exception as e:      # no libtpu here: nothing to lower for
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+
+
+def _lowered_at_real_sizes(workload: str, chips) -> dict:
+    cell = cells.resolve(workload)
+    job = cell.runner.build(cell.config, cell.traffic, chips[:cell.chips])
+    for fn, args in job.programs().values():
+        fn.lower(*args)
+    return scopes.startup()
+
+
+def test_cell_9s_two_programs_at_real_sizes_leave_a_few_dozen_records(
+        chips, fresh_programs):
+    """10,787 records on the parent, all but 11 of them helpers' traces
+    (``multiply`` 1965, ``add`` 1360, ``_where`` 1144 ...): two thirds of
+    ``LIMIT`` for one cell's warm-up.  The lowering here is the runner's
+    outer ``jax.jit``'s, an ``others`` row; the objects' traces are theirs."""
+    out = _lowered_at_real_sizes(CELL_9, chips)
+    assert out["records"] < 300 and out["dropped"] == 0
+    first, full = out["calls"]
+    assert (first["part"], full["part"]) == ("prefill", "generate")
+    assert first["trace_s"] > full["trace_s"] > 0
+    assert first["helpers"] > 5000 > full["helpers"] > 100
+    own = out["programs"]["decode"]
+    for key in ("trace_s", "lower_s", "backend_s"):
+        assert first[key] + full[key] == pytest.approx(own[key], abs=1e-6)
+    # by layer kind and by kernel: the selected layer's 31 slices
+    assert set(out["trace"]["trace.layer"]) == {"block_select", "lightning"}
+    kernels = out["trace"]["trace.kernel"]
+    assert set(kernels) == {"masked_attention", "selected_attention"}
+    assert kernels["masked_attention"]["spans"] == 31
+    assert kernels["selected_attention"]["spans"] == 1
+    assert all(row["own_s"] == row["seconds"] > 0
+               for by in out["trace"].values() for row in by.values())
+    assert _read("startup_kernel_trace_s") == pytest.approx(
+        sum(row["seconds"] for row in kernels.values()))
+    assert _read("startup_helper_traces") == own["helpers"]
+    assert _read("startup_trace_s") == pytest.approx(own["trace_s"])
+
+
+def test_cell_2s_programs_at_real_sizes_call_no_kernel(chips, fresh_programs):
+    """1024 keys: the rule takes the jnp forms (``attention.local_impl``)."""
+    out = _lowered_at_real_sizes(CELL_2, chips)
+    assert "trace.kernel" not in out["trace"]
+    assert _read("startup_kernel_trace_s") == 0
+    assert set(out["trace"]["trace.layer"]) == {"block"}
+    assert [row["part"] for row in out["calls"]] == ["whole", "whole"]
+    assert out["records"] < 300 and out["dropped"] == 0
