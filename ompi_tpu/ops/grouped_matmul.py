@@ -11,19 +11,24 @@ visited twice, the group of a tile one scalar read from SMEM by the index
 map of the weights' block.  Tiles from ``tiles_used`` on hold no row of any
 group; they are written as zeros and multiply nothing.
 
-The weights' block (``weight_block``, from static shapes alone).  Up to 128
-rows a tile the call is bound by the weights' bytes (128 operations a byte
-of bfloat16 weights against the v5e's ridge of 240), so a matrix must be
-read once a group: where the kernel's whole working set fits its VMEM
-budget (``_VMEM_BUDGET_BYTES``), a whole ``(K, N)`` matrix is one block,
-whose index ``(tile_group[t], 0, 0)`` stays put while the group does
-(``tile_group`` is non-decreasing), so consecutive tiles of one group, and
-all the unused tiles after them, fetch it once.  Everything else goes by blocks of ``K`` and ``N`` (larger
-tiles, bound by the MXU, and matrices too large to hold twice): there the
-weights' index changes at every grid step and *every row tile reads its
-group's whole matrix again*, the tiles past ``tiles_used`` too (they skip
-the product, not the copy), which is what a tile of 256 or 512 rows
-amortises and a smaller one does not.
+The weights' block (``weight_block``, from static shapes alone) is chosen
+by the bytes a row tile moves.  Where the kernel's whole working set fits
+its VMEM budget (``_VMEM_BUDGET_BYTES``), a whole ``(K, N)`` matrix is one
+block, at any rows a tile: its index ``(tile_group[t], 0, 0)`` stays put
+while the group does (``tile_group`` is non-decreasing), so consecutive
+tiles of one group, and all the unused tiles after them, fetch it once, and
+every tile reads its rows once.  Up to 128 rows a tile that is what bounds
+the call (128 operations a byte of bfloat16 weights against the v5e's ridge
+of 240).  A matrix too large for that keeps its whole ``K`` and takes the
+widest block of ``N`` that fits: the weights' index then changes at every
+grid step and *every row tile reads its group's whole matrix again*, the
+tiles past ``tiles_used`` too (they skip the product, not the copy), which
+a tile of 256 or 512 rows amortises; but the rows' index ``(t, 0)`` stays
+put under every block of ``N``, so the pipeline copies a tile's rows once.
+Only where not even one lane tile of a whole ``K`` fits do both dimensions
+go by blocks, and a tile's rows are read again for every block of ``N``:
+at 512 rows that re-read, not the weights, was what bound Keye-VL's
+prefill calls (PERF.md section 6, PR 55).
 
 XLA's own ``lax.ragged_dot`` lowers on the TPU to kernels of the same kind,
 but under the one ``op_name`` ``ragged-dot-none``: the scopes around it are
@@ -57,14 +62,12 @@ __all__ = ["grouped_matmul", "grouped_matmul_xla", "tile_rows",
 # whole, whatever the kernel needs, and XLA then assigns less VMEM to the
 # operations around it: at 32 MiB Keye-VL's cached step, whose calls take
 # the same blocks either way, ran 0.9% slower (PERF.md section 6, PR 46).
-# The largest matrices a cell holds, 2304 x 1024 bfloat16 at 128 rows a
-# tile, make a working set of 13.5 MB as ``_working_set_bytes`` counts it,
-# an upper bound: compiled for a v5e, that call needs under 11 MiB.
+# The largest working set a cell's call makes, as ``_working_set_bytes``
+# counts it, is Keye-VL's 2048 x 768 bfloat16 beside 512 rows, 15.2 MB; the
+# count is an upper bound (the float32 product is not held whole beside
+# the accumulator): Kimi-Linear's 1024 x 2304 at 128 rows counts 13.5 MB
+# and, compiled for a v5e, needs 11 MiB.
 _VMEM_BUDGET_BYTES = 16 << 20
-
-# Rows a tile up to which a call is bound by the weights' bytes: a byte of
-# bfloat16 weights meets ``tm`` operations, the v5e's ridge is 240.
-_WEIGHT_BOUND_ROWS = 128
 
 
 def tile_rows(rows_a_group: float) -> int:
@@ -99,15 +102,23 @@ def _working_set_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
             + 2 * 4 * tm * tn)
 
 
+def _widths(N: int) -> list[int]:
+    """``N``, then its divisors that are whole lane tiles (multiples of
+    128), widest first."""
+    return [N] + [N // d for d in range(2, N // 128 + 1)
+                  if N % (128 * d) == 0]
+
+
 def weight_block(tm: int, K: int, N: int, itemsize: int) -> tuple[int, int]:
     """``(tk, tn)``, the block of a ``(K, N)`` matrix that a tile of ``tm``
-    rows multiplies: the whole matrix where the call is bound by the
-    weights' bytes and the working set fits the kernel's VMEM budget, so
-    that a group's matrix is read once; blocks of both dimensions anywhere
-    else."""
-    if (tm <= _WEIGHT_BOUND_ROWS
-            and _working_set_bytes(tm, K, N, itemsize) <= _VMEM_BUDGET_BYTES):
-        return K, N
+    rows multiplies, by the bytes the tile then moves: the whole ``K`` and
+    the widest block of ``N`` whose working set fits the kernel's VMEM
+    budget, so that a tile's rows are read once; that is the whole matrix
+    where it fits, and a group's matrix is then read once too.  Blocks of
+    both dimensions only where no block of a whole ``K`` fits."""
+    for tn in _widths(N):
+        if _working_set_bytes(tm, K, tn, itemsize) <= _VMEM_BUDGET_BYTES:
+            return K, tn
     return _block(K, 1024), _block(N, 512)
 
 

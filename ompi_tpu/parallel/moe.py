@@ -208,11 +208,12 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
     rows an expert, 16 for a cached step's handful, where the layer is the
     stream of every expert's weights.  One function, two tilings; and the
     kernel picks the weights' block from ``tm`` and the matrix
-    (``ops.grouped_matmul.weight_block``): up to 128 rows a tile a whole
-    matrix is one block wherever the working set fits the kernel's VMEM
-    budget, so that each expert's matrix is read once; from 256 rows on,
-    blocks of both dimensions, every tile reading its expert's matrix for
-    itself, which that many rows amortise.
+    (``ops.grouped_matmul.weight_block``): a whole matrix is one block
+    wherever the working set fits the kernel's VMEM budget, so that each
+    expert's matrix is read once and every tile its rows once; a matrix too
+    large beside 256 or 512 rows keeps its whole ``K`` and goes by blocks
+    of ``N``, every tile reading its expert's matrix for itself, which that
+    many rows amortise, and still its own rows once.
     """
     import jax
     import jax.numpy as jnp

@@ -42,22 +42,44 @@ _SPARSE = dict(G=6, tile_group=[0, 0, 2, 3, 3, 3, 5, 5], used=6,
 # with a factor of 9.  Group 0 has two tiles, group 1 none, and the last two
 # tiles hold no row.
 _KIMI = dict(G=4, tile_group=[0, 0, 2, 3, 3, 3], used=4, dtype=jnp.bfloat16)
+# A routed prefill's tiles of 512 rows (OLMoE's and Keye-VL's matrices):
+# group 0 has three tiles, group 1 none, and the last two tiles hold no row.
+_PREFILL = dict(G=4, tile_group=[0, 0, 0, 2, 3, 3, 3, 3], used=6,
+                dtype=jnp.bfloat16)
+# A K of which not one lane tile fits whole beside 512 rows of float32.
+_DEEP = dict(G=3, tile_group=[0, 0, 2, 2], used=3, dtype=np.float32)
 
 
-@pytest.mark.parametrize("tm,K,N,whole,case", [
-    pytest.param(16, 64, 32, True, _SPARSE, id="whole-matrix-blocks"),
-    pytest.param(128, 2048, 1024, False, _SPARSE, id="k-and-n-in-blocks"),
-    pytest.param(16, 2304, 1024, True, _KIMI, id="over-4-mib-step-w1"),
-    pytest.param(16, 1024, 2304, True, _KIMI, id="over-4-mib-step-w2"),
-    pytest.param(128, 2304, 1024, True, _KIMI, id="over-4-mib-prefill-w1"),
-    pytest.param(128, 1024, 2304, True, _KIMI, id="over-4-mib-prefill-w2"),
-    pytest.param(512, 2304, 1024, False, _KIMI, id="over-4-mib-mxu-bound"),
+@pytest.mark.parametrize("tm,K,N,block,case", [
+    pytest.param(16, 64, 32, (64, 32), _SPARSE, id="whole-matrix-blocks"),
+    pytest.param(128, 2048, 1024, (2048, 512), _SPARSE,
+                 id="whole-k-blocks-of-n"),
+    pytest.param(512, 4096, 256, (1024, 256), _DEEP,
+                 id="k-and-n-in-blocks"),
+    pytest.param(16, 2304, 1024, (2304, 1024), _KIMI,
+                 id="over-4-mib-step-w1"),
+    pytest.param(16, 1024, 2304, (1024, 2304), _KIMI,
+                 id="over-4-mib-step-w2"),
+    pytest.param(128, 2304, 1024, (2304, 1024), _KIMI,
+                 id="over-4-mib-prefill-w1"),
+    pytest.param(128, 1024, 2304, (1024, 2304), _KIMI,
+                 id="over-4-mib-prefill-w2"),
+    pytest.param(512, 2304, 1024, (2304, 512), _KIMI,
+                 id="over-4-mib-mxu-bound"),
+    pytest.param(512, 2048, 768, (2048, 768), _PREFILL,
+                 id="keye-prefill-w1-whole"),
+    pytest.param(512, 768, 2048, (768, 1024), _PREFILL,
+                 id="keye-prefill-w2"),
+    pytest.param(512, 2048, 1024, (2048, 512), _PREFILL,
+                 id="olmoe-prefill-w1"),
+    pytest.param(512, 1024, 2048, (1024, 1024), _PREFILL,
+                 id="olmoe-prefill-w2"),
 ])
-def test_each_tile_multiplies_its_groups_matrix(tm, K, N, whole, case):
+def test_each_tile_multiplies_its_groups_matrix(tm, K, N, block, case):
     tile_group, n_used, dtype = case["tile_group"], case["used"], case["dtype"]
     rows, w, tg = _case(tm, K, N, case["G"], tile_group, dtype=dtype)
     used = jnp.asarray([n_used], jnp.int32)
-    assert (weight_block(tm, K, N, rows.dtype.itemsize) == (K, N)) is whole
+    assert weight_block(tm, K, N, rows.dtype.itemsize) == block
     got = np.asarray(jax.jit(grouped_matmul)(rows, w, tg, used), np.float64)
     # float32: the accumulation's own error; bfloat16: the result's one
     # rounding, a unit in the last place of the largest entry
@@ -105,17 +127,19 @@ def _routed_call(workload, phase):
             jnp.dtype(cfg.compute_dtype).itemsize)
 
 
-# Which path every routed cell's calls take (PERF.md section 5).  The blocks
-# of OLMoE's and Keye-VL's calls are what the rule before PR 46 gave them
-# (whole up to 64 rows and 4 MiB, else ``_block(K, 1024), _block(N, 512)``):
-# their programs are the parent's.
+# Which path every routed cell's calls take (PERF.md section 5).  The four
+# calls of up to 128 rows a tile take a whole matrix, as since PR 46 (the
+# steps' since before it): their programs are the parent's.  The two
+# prefills of 512 rows a tile took ``(1024, 512)`` and ``(1024, 256)`` /
+# ``(768, 512)`` until PR 55, which read a tile's rows once for every block
+# of ``N``.
 @pytest.mark.parametrize("workload,phase,tm,n_tiles,blocks", [
     pytest.param(_OLMOE, "step", 16, 88, "whole", id="olmoe-step"),
     pytest.param(_KEYE, "step", 16, 160, "whole", id="keye-step"),
     pytest.param(_OLMOE, "prefill", 512, 832,
-                 {"w1": (1024, 512), "w2": (1024, 512)}, id="olmoe-prefill"),
+                 {"w1": (2048, 512), "w2": (1024, 1024)}, id="olmoe-prefill"),
     pytest.param(_KEYE, "prefill", 512, 380,
-                 {"w1": (1024, 256), "w2": (768, 512)}, id="keye-prefill"),
+                 {"w1": (2048, 768), "w2": (768, 1024)}, id="keye-prefill"),
     pytest.param(_KIMI_CELL, "step", 16, 320, "whole", id="kimi-step"),
     pytest.param(_KIMI_CELL, "prefill", 128, 384, "whole",
                  id="kimi-prefill"),
@@ -126,25 +150,37 @@ def test_which_block_each_routed_cell_takes(workload, phase, tm, n_tiles,
     assert (got_tm, got_tiles) == (tm, n_tiles)
     for leaf, (K, N) in matrices.items():
         tk, tn = weight_block(tm, K, N, itemsize)
-        if blocks == "whole":
-            assert (tk, tn) == (K, N), leaf
-            held = _working_set_bytes(tm, K, N, itemsize)
-            assert 2 * K * N * itemsize < held <= _VMEM_BUDGET_BYTES, (
-                leaf, held)
-        else:
-            assert (tk, tn) == blocks[leaf], leaf
-            assert K % tk == 0 and N % tn == 0
+        want = (K, N) if blocks == "whole" else blocks[leaf]
+        assert (tk, tn) == want, leaf
+        # every cell's tile reads its rows once, and the blocks tile
+        assert tk == K and N % tn == 0, leaf
+        held = _working_set_bytes(tm, tk, tn, itemsize)
+        assert held <= _VMEM_BUDGET_BYTES, (leaf, held)
+        if (tk, tn) == (K, N):
+            assert 2 * K * N * itemsize < held, (leaf, held)
 
 
 def test_the_working_set_is_counted_as_the_kernel_holds_it():
     # Kimi-Linear's w2 at 128 rows: the matrix, the rows and the output
     # twice each, the accumulator and the product in float32: 13.5 MB
     assert _working_set_bytes(128, 1024, 2304, 2) == 13_500_416
-    # past the ridge a whole matrix is never one block, however small
-    assert weight_block(256, 256, 256, 2) == (256, 256)     # _block's own
-    assert weight_block(256, 2304, 1024, 2) == (256, 512)
-    # nor under it where the matrix cannot be held twice
-    assert weight_block(16, 4096, 2048, 2) == (1024, 512)
+    # past the ridge too a whole matrix is one block where that fits:
+    # Keye-VL's w1 beside 512 rows, 15.2 MB of 16 MiB
+    assert _working_set_bytes(512, 2048, 768, 2) == 15_204_352
+    assert weight_block(512, 2048, 768, 2) == (2048, 768)
+    assert weight_block(256, 256, 256, 2) == (256, 256)
+    assert weight_block(256, 2304, 1024, 2) == (2304, 1024)
+    # where it does not, the whole K and the widest block of N that does,
+    # a power of two or not, so that a tile's rows are read once
+    assert _working_set_bytes(512, 2304, 1024, 2) > _VMEM_BUDGET_BYTES
+    assert weight_block(512, 2304, 1024, 2) == (2304, 512)
+    assert weight_block(512, 1024, 2304, 2) == (1024, 1152)
+    # under the ridge as well, where the matrix cannot be held twice
+    assert weight_block(16, 4096, 2048, 2) == (4096, 512)
+    # both dimensions only where not one lane tile of a whole K fits
+    assert _working_set_bytes(512, 8192, 128, 2) > _VMEM_BUDGET_BYTES
+    assert weight_block(512, 8192, 2048, 2) == (1024, 512)
+    assert weight_block(512, 4096, 256, 4) == (1024, 256)
 
 
 def test_backward_is_ragged_dots_own():
