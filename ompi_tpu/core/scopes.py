@@ -101,6 +101,11 @@ SCOPES = (
     "blocks.select",    # ... the forced blocks and the topk: a threshold
     "mla_proj",         # latent attention's ln1, projections, latent norm and
                         # the cached step's absorbed products
+    "mla_proj.rope",    # the same of the form that rotates (``MLA.theta``):
+                        # a name of its own, the NoPE form's metrics' keys
+                        # find nothing under it
+    "mla.rotate",       # ... the rotary embedding of its queries' rope part
+                        # and of the shared key, inside it
     "index_proj",       # the index's three projections, key norm, rotary
     "index.score",      # ... its scores of a query against the index keys
     "index.select",     # ... the topk positions: a threshold, or a top-k

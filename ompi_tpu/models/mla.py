@@ -1,22 +1,31 @@
-"""Latent attention without a rotary embedding (NoPE), the mixer of a
-``models/plan.py`` layer of kind "mla".
+"""Latent attention, the mixer of a ``models/plan.py`` layer of kind "mla",
+in both published forms: without a rotary embedding (NoPE, ``MLA.theta`` 0)
+and with one on the key part all heads share (``MLA.theta`` the base).
 
 From the block's input ``h`` and its norm ``x = RMSNorm(h; ln1)``:
 
     q        = x mla_q                        heads x (nope + rope)
     [c, k_r] = x mla_kva                      kv_rank + rope; c <- RMSNorm(c)
     [k_n, v] = c mla_kvb                      heads x (nope + v_dim)
-    a head's key is [k_n, k_r], k_r shared by all heads and not rotated
+    theta > 0: every head's q[nope:] and k_r rotated at their position
+               (``rotate``: neighbouring pairs, pair i by position x
+               theta^(-2i / rope)); theta 0: neither is
+    a head's key is [k_n, k_r], k_r shared by all heads
     h       += softmax(q . k (nope + rope)^-1/2, causal) v  wo
 
 What is cached of a position is the normed latent and the shared key part
-alone, ``kv_rank + rope`` elements for all heads.  The whole-sequence form
-(trainer, prefill) multiplies a head's keys and values out of the latent,
-the cheaper order at T rows a sequence.  The cached step absorbs: ``q_lat =
-q_n W^K`` (heads x kv_rank), scores ``q_lat . c_s + q_r . k_r,s``, the
-context ``sum_s p_s c_s`` (heads x kv_rank) through ``W^V``: it reads the
-latent once for all heads and never multiplies a cached position out.
-``jax.numpy`` alone.
+as the scores read it (rotated, where the form rotates), ``kv_rank + rope``
+elements for all heads.  The whole-sequence form (trainer, prefill)
+multiplies a head's keys and values out of the latent, the cheaper order at
+T rows a sequence; its attention is ``jax.numpy`` over a sequence's whole (T,
+T) scores up to ``KERNEL_FROM`` positions and anywhere but on TPUs, and from
+there on the pallas ``ops/latent_attention.py``, which holds a tile of them
+(forward only: a trainer of such lengths is not built).  The cached step
+absorbs: ``q_lat = q_n W^K`` (heads x kv_rank), scores ``q_lat . c_s + q_r .
+k_r,s``, the context ``sum_s p_s c_s`` (heads x kv_rank) through ``W^V``: it
+reads the latent for all heads at once and never multiplies a cached
+position out; ``jax.numpy``, which reads the cache once for the scores and
+once for the context.
 
 Nothing imports this module but a configuration whose plan has the kind.
 """
@@ -26,9 +35,13 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 
-__all__ = ["MLA", "mixer", "leaf_shapes", "buffers", "POSITIONED"]
+__all__ = ["MLA", "mixer", "rotate", "leaf_shapes", "buffers", "POSITIONED",
+           "KERNEL_FROM"]
 
 POSITIONED = True       # a cached step's carry ends with its position
+# sequences from this many positions on take the kernel where it tiles, as
+# ``parallel/attention.local_impl`` takes its own from 2048 keys a device
+KERNEL_FROM = 2048
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,6 +52,7 @@ class MLA:
     rope: int           # qk_rope_head_dim: the key part all heads share
     v_dim: int          # v_head_dim
     kv_rank: int        # kv_lora_rank: the latent
+    theta: float = 0.0  # rope_theta: the rotation's base; 0: NoPE, no rotation
 
     @property
     def cached(self) -> int:
@@ -69,13 +83,40 @@ def buffers(cfg, ml: MLA, batch: int, t_max: int) -> tuple:
 
 
 @contextlib.contextmanager
-def _proj():
+def _proj(ml: MLA):
     """The projections' scope: the latent's own name under the one every
-    attending layer's projections have."""
+    attending layer's projections have.  The form that rotates has a name of
+    its own, so that a metric of one form's projections finds nothing in a
+    cell of the other's."""
     from ompi_tpu.core.scopes import scope
 
-    with scope("attn_proj"), scope("mla_proj"):
+    with scope("attn_proj"), scope("mla_proj.rope" if ml.theta
+                                   else "mla_proj"):
         yield
+
+
+def rotate(x, positions, theta: float):
+    """The rotary embedding of x (B, T, ..., rope) at ``positions`` (T,), as
+    the models of this family are published: elements 2i and 2i + 1 are a
+    pair, turned by ``position x theta^(-2i / rope)``; the pairs stay where
+    they are (a key and a query turned alike, so their product is that of any
+    other placing of the pairs).  Float32 inside, x's type out.  A neighbour
+    is fetched by a roll along the lanes, which needs no re-layout."""
+    import jax.numpy as jnp
+
+    from ompi_tpu.core.scopes import scope
+
+    with scope("mla.rotate"):
+        rope, f32 = x.shape[-1], jnp.float32
+        lane = jnp.arange(rope)
+        ang = (positions.astype(f32)[:, None]
+               * theta ** (-(lane // 2 * 2).astype(f32) / rope))
+        ang = ang.reshape(-1, *(1,) * (x.ndim - 3), rope)
+        xf = x.astype(f32)
+        # pair (a, b) -> (a cos - b sin, b cos + a sin)
+        other = jnp.where(lane % 2 == 0, jnp.roll(xf, -1, axis=-1),
+                          -jnp.roll(xf, 1, axis=-1))
+        return (xf * jnp.cos(ang) - other * jnp.sin(ang)).astype(x.dtype)
 
 
 def mixer(cfg, lp, h, carry=None):
@@ -92,40 +133,52 @@ def mixer(cfg, lp, h, carry=None):
     from jax import lax
 
     from ompi_tpu.core.scopes import scope
+    from ompi_tpu.models.kda import _traced_for_tpus
     from ompi_tpu.models.transformer import _rmsnorm
+    from ompi_tpu.ops import latent_attention
 
     ml, f32, cdt = cfg.plan.mla, jnp.float32, h.dtype
     B, T, _ = h.shape
     H, N, P, W, R = ml.n_heads, ml.nope, ml.rope, ml.v_dim, ml.kv_rank
     scale = (N + P) ** -0.5
-    with _proj():
+    with _proj(ml):
         x = _rmsnorm(h, lp["ln1"], cfg.norm_eps)
         q = jnp.einsum("btd,df->btf", x, lp["mla_q"].astype(cdt)
                        ).reshape(B, T, H, N + P)
         kva = jnp.einsum("btd,df->btf", x, lp["mla_kva"].astype(cdt))
-        lat = jnp.concatenate([
-            _rmsnorm(kva[..., :R], lp["mla_n"], cfg.norm_eps),
-            kva[..., R:]], axis=-1)
+        c = _rmsnorm(kva[..., :R], lp["mla_n"], cfg.norm_eps)
+        k_r = kva[..., R:]
+        if ml.theta:
+            at = jnp.arange(T) if carry is None else carry[1][None]
+            q = jnp.concatenate([q[..., :N], rotate(q[..., N:], at,
+                                                    ml.theta)], axis=-1)
+            k_r = rotate(k_r, at, ml.theta)
+        lat = jnp.concatenate([c, k_r], axis=-1)
         wkv = lp["mla_kvb"].astype(cdt).reshape(R, H, N + W)
     if carry is None:
-        with _proj():
+        with _proj(ml):
             kv = jnp.einsum("btr,rhf->bthf", lat[..., :R], wkv)
         with scope("attention"):
-            s = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :N], kv[..., :N],
-                            preferred_element_type=f32)
-                 + jnp.einsum("bqhd,bkd->bhqk", q[..., N:], lat[..., R:],
-                              preferred_element_type=f32)) * scale
-            causal = jnp.tril(jnp.ones((T, T), bool))
-            w = jax.nn.softmax(jnp.where(causal, s, -1e30), axis=-1)
-            o = jnp.einsum("bhqk,bkhd->bqhd", w.astype(cdt), kv[..., N:],
-                           preferred_element_type=f32)
+            if (T >= KERNEL_FROM and _traced_for_tpus()
+                    and latent_attention.tiles(T, N, W)):
+                o = latent_attention.latent_attention(q, kv, lat[..., R:],
+                                                      scale)
+            else:
+                s = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :N], kv[..., :N],
+                                preferred_element_type=f32)
+                     + jnp.einsum("bqhd,bkd->bhqk", q[..., N:], lat[..., R:],
+                                  preferred_element_type=f32)) * scale
+                causal = jnp.tril(jnp.ones((T, T), bool))
+                w = jax.nn.softmax(jnp.where(causal, s, -1e30), axis=-1)
+                o = jnp.einsum("bhqk,bkhd->bqhd", w.astype(cdt), kv[..., N:],
+                               preferred_element_type=f32)
         out = lat
     else:
         lat_c, pos = carry
         with scope("kv_cache"):
             out = lax.dynamic_update_slice(
                 lat_c, lat.astype(lat_c.dtype), (0, pos, 0))
-        with _proj():
+        with _proj(ml):
             q_abs = jnp.concatenate([
                 jnp.einsum("bthn,rhn->bthr", q[..., :N], wkv[..., :N]),
                 q[..., N:]], axis=-1)[:, 0]             # (B, H, R + P)
@@ -138,9 +191,9 @@ def mixer(cfg, lp, h, carry=None):
             ctx = jnp.einsum("bhk,bkr->bhr", w.astype(cdt),
                              cache[..., :R].astype(cdt),
                              preferred_element_type=f32)
-        with _proj():
+        with _proj(ml):
             o = jnp.einsum("bhr,rhw->bhw", ctx.astype(cdt), wkv[..., N:],
                            preferred_element_type=f32)[:, None]
-    with _proj():
+    with _proj(ml):
         o = o.astype(cdt).reshape(B, T, H * W)
         return h + jnp.einsum("btf,fd->btd", o, lp["wo"].astype(cdt)), out

@@ -38,7 +38,7 @@ replaces.  Not a scan over whole periods with the leading layers outside it:
 the plans built so far are a handful of layers whose MLPs differ inside a
 period; a deep plan would want the scan (``ROADMAP.md`` D1').
 
-Nothing imports this module but a configuration that has a plan.
+Only a configuration with a plan imports this (its doors: ``ENTRY_CONFIGS``).
 """
 
 from __future__ import annotations
@@ -50,7 +50,7 @@ import numpy as np
 
 __all__ = ["LayerPlan", "MIXERS", "kda_mla_config", "lightning_blocks_config",
            "leaf_names", "init_params", "carry", "grows", "carried",
-           "backbone", "step", "check_mesh"]
+           "backbone", "step", "check_mesh", "mla_moe_config", "ENTRY_CONFIGS"]
 
 # a mixer's kind -> its module under ``ompi_tpu.models``
 MIXERS = {"kda": "kda", "mla": "mla", "lightning": "lightning",
@@ -491,3 +491,61 @@ def step(cfg, comm, params, h, states, pos):
                 h, params, states[at:at + n], pos)
         at += n
     return (h, *states)
+
+
+def mla_moe_config(first_k_dense_replace: int, kv_lora_rank: int,
+                   q_lora_rank, qk_nope_head_dim: int, qk_rope_head_dim: int,
+                   v_head_dim: int, moe_intermediate_size: int,
+                   n_shared_experts: int, routed_scaling_factor: float,
+                   n_group: int, topk_group: int, moe_layer_freq: int,
+                   scoring_func: str, topk_method: str, rope_theta: float,
+                   rope_scaling=None, **sizes):
+    """``entry.config`` of a configuration file whose every layer is latent
+    attention with a rotary embedding on the shared key part, over a leading
+    dense MLP and routed experts after it, under the keys DeepSeek-V3's
+    family is published with (no ``linear_attn_config``): a
+    ``TransformerConfig`` whose plan is ``("mla", "dense")`` for the first
+    ``first_k_dense_replace`` layers and ``("mla", "moe")`` for the others.
+    The router is the family's: sigmoid scores, a selection bias that picks
+    and does not weigh (``noaux_tc``), the picks' weights renormalised where
+    ``moe_norm_topk`` says so and times ``routed_scaling_factor``, and
+    ``n_shared_experts`` shared experts as one gated MLP of their summed
+    width.  What is not built raises: a query latent (``q_lora_rank``),
+    grouped top-k (``n_group``, ``topk_group`` over 1), a scaled rotary
+    embedding (``rope_scaling``), dense layers among the routed ones
+    (``moe_layer_freq`` other than 1), another ``scoring_func`` or
+    ``topk_method``."""
+    from ompi_tpu.models.mla import MLA
+    from ompi_tpu.models.transformer import TransformerConfig
+
+    not_built = [f"{key} {value!r}" for key, value, built in (
+        ("q_lora_rank", q_lora_rank, None), ("n_group", n_group, 1),
+        ("topk_group", topk_group, 1), ("rope_scaling", rope_scaling, None),
+        ("moe_layer_freq", moe_layer_freq, 1),
+        ("scoring_func", scoring_func, "sigmoid"),
+        ("topk_method", topk_method, "noaux_tc")) if value != built]
+    if not_built:
+        raise ValueError(f"a plan of rotary latent layers over routed "
+                         f"experts is not built for {', '.join(not_built)}")
+    plan = LayerPlan(
+        layers=tuple(("mla", "dense" if layer < first_k_dense_replace
+                      else "moe") for layer in range(sizes["n_layers"])),
+        mla=MLA(n_heads=sizes["n_heads"], nope=qk_nope_head_dim,
+                rope=qk_rope_head_dim, v_dim=v_head_dim,
+                kv_rank=kv_lora_rank, theta=float(rope_theta)),
+        d_expert=moe_intermediate_size)
+    return TransformerConfig(
+        plan=plan, moe_gated=True, moe_score="sigmoid", moe_select_bias=True,
+        moe_scale=float(routed_scaling_factor),
+        moe_shared=n_shared_experts * moe_intermediate_size, **sizes)
+
+
+# A configuration file reaches a plan through its ``entry.config``, one
+# function a published family's keys (each raises for what is not built):
+# ``kda_mla_config``: KDA and NoPE latent layers by ``linear_attn_config``'s
+# lists, a leading dense MLP, then a sigmoid router with a shared expert;
+# ``mla_moe_config``: DeepSeek-V3's keys, rotary latent attention in every
+# layer, a leading dense MLP, then the same router with ``n_shared_experts``;
+# ``lightning_blocks_config``: lightning and block-selected layers by
+# ``mixer_types`` over a dense MLP.
+ENTRY_CONFIGS = (kda_mla_config, mla_moe_config, lightning_blocks_config)
