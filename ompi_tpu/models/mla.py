@@ -24,8 +24,11 @@ there on the pallas ``ops/latent_attention.py``, which holds a tile of them
 absorbs: ``q_lat = q_n W^K`` (heads x kv_rank), scores ``q_lat . c_s + q_r .
 k_r,s``, the context ``sum_s p_s c_s`` (heads x kv_rank) through ``W^V``: it
 reads the latent for all heads at once and never multiplies a cached
-position out; ``jax.numpy``, which reads the cache once for the scores and
-once for the context.
+position out.  On TPUs, where the cache is whole blocks of positions and the
+latent whole lane tiles (``ops/latent_decode.tiles``), that is one pallas
+pass, ``ops/latent_decode.py``, which holds a block of rows for the scores
+and for the context; anywhere else ``jax.numpy``, which reads the cache once
+for each.
 
 Nothing imports this module but a configuration whose plan has the kind.
 """
@@ -135,7 +138,7 @@ def mixer(cfg, lp, h, carry=None):
     from ompi_tpu.core.scopes import scope
     from ompi_tpu.models.kda import _traced_for_tpus
     from ompi_tpu.models.transformer import _rmsnorm
-    from ompi_tpu.ops import latent_attention
+    from ompi_tpu.ops import latent_attention, latent_decode
 
     ml, f32, cdt = cfg.plan.mla, jnp.float32, h.dtype
     B, T, _ = h.shape
@@ -184,13 +187,16 @@ def mixer(cfg, lp, h, carry=None):
                 q[..., N:]], axis=-1)[:, 0]             # (B, H, R + P)
         with scope("attention"):
             cache = out                                 # (B, Tmax, R + P)
-            s = jnp.einsum("bhc,bkc->bhk", q_abs, cache.astype(cdt),
-                           preferred_element_type=f32) * scale
-            s = jnp.where(jnp.arange(cache.shape[1]) <= pos, s, -1e30)
-            w = jax.nn.softmax(s, axis=-1)
-            ctx = jnp.einsum("bhk,bkr->bhr", w.astype(cdt),
-                             cache[..., :R].astype(cdt),
-                             preferred_element_type=f32)
+            if _traced_for_tpus() and latent_decode.tiles(cache.shape[1], R):
+                ctx = latent_decode.latent_decode(q_abs, cache, pos, scale, R)
+            else:
+                s = jnp.einsum("bhc,bkc->bhk", q_abs, cache.astype(cdt),
+                               preferred_element_type=f32) * scale
+                s = jnp.where(jnp.arange(cache.shape[1]) <= pos, s, -1e30)
+                w = jax.nn.softmax(s, axis=-1)
+                ctx = jnp.einsum("bhk,bkr->bhr", w.astype(cdt),
+                                 cache[..., :R].astype(cdt),
+                                 preferred_element_type=f32)
         with _proj(ml):
             o = jnp.einsum("bhr,rhw->bhw", ctx.astype(cdt), wkv[..., N:],
                            preferred_element_type=f32)[:, None]

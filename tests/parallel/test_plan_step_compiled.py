@@ -1,4 +1,4 @@
-"""The generating programs of the two cells with a layer plan, compiled at
+"""The generating programs of the three cells with a layer plan, compiled at
 the cells' real sizes for a v5e that is described and not attached
 (``tests/benchmarks/test_fits.py``'s idiom).  Nothing runs and nothing here
 is a time: what is read is the compiled programs' text and memory.  No cached
@@ -8,7 +8,9 @@ fusion.  Cell 9's parent did not: the lightning mixer's q, k and v products
 had the head reshape and the head norm's sums folded into them, wanted their
 weights with ``D`` minor, and each step copied every layer's matrix out of
 the re-laid stack first (three ``slice`` fusions of ``bf16[3,4096,4096]``,
-603 MB read and written a step, 10% of it: PR 53).
+603 MB read and written a step, 10% of it: PR 53).  Cell 10's cached step
+reads each latent layer's cache by one call of ``latent_decode`` on the carry
+as it lies (the parent read it twice, in two fusions: PR 57).
 """
 
 import math
@@ -25,6 +27,7 @@ from tests.parallel.test_selected_attention_compiled import (  # noqa: E402,F401
 
 CELL_9 = "minicpm-sala.decode-16k-512-b24"
 CELL_7 = "kimi-linear-48b-a3b.decode-512-128-b384"
+CELL_10 = "kimi-vl-a3b.decode-16k-256-b32"
 # cell 9's generating program at the parent (CPU box, PR 53): the re-laid
 # stacks, 288 MiB standing and as much again of layers' copies, were in both
 PARENT_TEMP_BYTES = 501_877_248
@@ -73,17 +76,12 @@ def _weights(cfg) -> set:
     return sizes
 
 
-def _weight_moves(cfg, text) -> list:
-    """The instructions of the step's body, outside any fusion, that move a
-    weight: a move by name or a fusion of nothing but moves whose result is
-    a layer's matrix or several layers'.  A product that reads a slice of
-    the stack inside its own fusion is none, nor is the compiler's
-    asynchronous prefetch (``slice-start``), which writes no HBM."""
+def _step_bodies(text) -> tuple:
+    """(every computation of the program by name, the names of the scan over
+    steps' body and of every loop and call inside it): what the compiler
+    hoists out of the loop keeps its name and is not a step's."""
     computations = dict(re.findall(
         r"^(?:ENTRY )?%(\S+) \([^\n]*\{\n(.*?)^\}", text, re.M | re.S))
-    weights = _weights(cfg)
-    # the scan over steps, and every loop and call inside its body (what
-    # the compiler hoists out of the loop keeps its name and is not a step's)
     [steps] = [line for line in text.splitlines() if " while(" in line
                and 'decode.step/while"' in line]
     bodies, called = [], [re.search(r"body=%([\w.-]+)", steps).group(1)]
@@ -92,22 +90,38 @@ def _weight_moves(cfg, text) -> list:
         called += [name for name in re.findall(
             r"(?:body|condition|to_apply)=%([\w.-]+)",
             computations[bodies[-1]]) if name not in bodies]
+    return computations, bodies
+
+
+def _moves(text, moved) -> list:
+    """The instructions of the step's body, outside any fusion, that move an
+    array ``moved`` says yes to (asked with the sizes, sorted, of each of an
+    instruction's results): a move by name or a fusion of nothing but moves.
+    A product that reads a slice inside its own fusion is none, nor is the
+    compiler's asynchronous prefetch (``slice-start``), which writes no
+    HBM."""
+    computations, bodies = _step_bodies(text)
 
     def only_moves(rest):
         body = computations[re.search(r"calls=%([\w.-]+)", rest).group(1)]
         return all(op in MOVES or op in VIEWS
                    for _n, _d, op, _r in INSTRUCTION.findall(body))
 
-    moved = []
+    found = []
     for body in bodies:
         for instruction, types, op, rest in RESULTS.findall(
                 computations[body]):
-            results = {_sizes(dims.split(","))
-                       for dims in re.findall(r"\w+\[([\d,]+)\]", types)}
-            if results & weights and (
+            results = [_sizes(dims.split(","))
+                       for dims in re.findall(r"\w+\[([\d,]+)\]", types)]
+            if any(map(moved, results)) and (
                     op in MOVES or op == "fusion" and only_moves(rest)):
-                moved.append((instruction, types.split("{")[0], op))
-    return moved
+                found.append((instruction, types.split("{")[0], op))
+    return found
+
+
+def _weight_moves(cfg, text) -> list:
+    """``_moves`` of a weight: a layer's matrix or several layers'."""
+    return _moves(text, _weights(cfg).__contains__)
 
 
 def test_cell_9_steps_move_no_weight(chip, for_the_chip):
@@ -141,3 +155,37 @@ def test_cell_7_steps_move_no_weight(chip, for_the_chip):
     assert (128, 1024, 2304) in _weights(cfg)       # a layer's held experts
     moved = _weight_moves(cfg, compiled.as_text())
     assert not moved, moved
+
+
+def test_cell_10_steps_read_each_latent_cache_by_one_kernel_call(
+        chip, for_the_chip):
+    cfg, compiled = _generating(CELL_10, chip)
+    text = compiled.as_text()
+    computations, bodies = _step_bodies(text)
+    ml = cfg.plan.mla
+    cache = (32, 16_384, ml.cached)             # 604 MB a layer
+    assert (ml.kv_rank, ml.rope, len(cfg.plan.layers)) == (512, 64, 5)
+    # a layer, a call: its operands the position, the absorbed queries and
+    # the cache that the row's write results in, in the carry's own layout
+    calls = [(types, rest) for body in bodies for _name, types, op, rest
+             in RESULTS.findall(computations[body])
+             if op == "custom-call" and "latent_decode" in rest]
+    assert len(calls) == 5
+    written = set()
+    for types, rest in calls:
+        assert types.startswith("f32[32,16,512]")
+        operands = re.match(r"([^)]*)\)", rest).group(1).split(", ")
+        assert len(operands) == 3 and "dynamic_update_slice" in operands[2]
+        written.add(operands[2])
+        assert "bf16[32,16384,576]{2,1,0}" in rest      # no other layout
+    assert len(written) == 5
+    # the cache goes through a step as the loop's own buffer: written in
+    # place, and nothing as large is copied, re-laid, padded, cut or converted
+    large = math.prod(cache)
+    moved = _moves(text, lambda sizes: math.prod(sizes) >= large)
+    assert not moved, moved
+    passes = {op for body in bodies for _name, types, op, _rest
+              in RESULTS.findall(computations[body])
+              if _sizes(cache) in {_sizes(dims.split(",")) for dims
+                                   in re.findall(r"\w+\[([\d,]+)\]", types)}}
+    assert passes <= {*VIEWS, "dynamic-update-slice", "while", "call"}, passes
