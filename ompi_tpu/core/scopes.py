@@ -48,12 +48,13 @@ carries none of this (``OBSERVABILITY.md``, "The device path").
 from __future__ import annotations
 
 import collections
+import contextlib
 import itertools
 import threading
 import time
 from typing import NamedTuple, Optional
 
-__all__ = ["SCOPES", "COLL", "scope", "coll",
+__all__ = ["SCOPES", "COLL", "scope", "second", "coll",
            "HOST_SPANS", "Span", "host", "program", "records", "startup",
            "reset"]
 
@@ -71,6 +72,8 @@ SCOPES = (
     "ffn",              # ln2, the MLP, the residual
     "moe.route", "moe.dispatch", "moe.experts", "moe.combine",
     "moe.shared",       # the shared expert every token passes
+    "moe.zero",         # the identity experts' part: a token itself times
+                        # the summed weights of its picks among them
     "ssm_proj",         # the mixer's scalings, in/out projections, gated norm
     "ssm.conv",         # ... its causal convolution (from a state, when cached)
     "ssm.scan",         # ... the chunked scan over a whole sequence
@@ -104,8 +107,13 @@ SCOPES = (
     "mla_proj.rope",    # the same of the form that rotates (``MLA.theta``):
                         # a name of its own, the NoPE form's metrics' keys
                         # find nothing under it
-    "mla.rotate",       # ... the rotary embedding of its queries' rope part
-                        # and of the shared key, inside it
+    "mla_proj.query_latent",    # the same of the form with a query latent
+                        # (``MLA.q_rank``: its down-projection, norm and
+                        # up-projection too), rotating or not: a third name,
+                        # the other two forms' metrics' keys find nothing
+                        # under it
+    "mla.rotate",       # ... the rotary embedding of the queries' rope part
+                        # and of the shared key, inside either
     "index_proj",       # the index's three projections, key norm, rotary
     "index.score",      # ... its scores of a query against the index keys
     "index.select",     # ... the topk positions: a threshold, or a top-k
@@ -116,6 +124,11 @@ SCOPES = (
     "kv_cache",         # ... writing the new K/V (and index key) into the cache
     "unembed",          # ... the vocabulary matmul
     "sample",           # ... picking the next token
+    # a layer of two mixers and two MLPs (``models/plan.py``'s ``branches``:
+    # a shortcut-connected layer): its second half's scopes, each opened
+    # inside the name without the suffix, which so keeps its meaning and
+    # holds both halves (``second()``; the cache's write has none)
+    "attn_proj.second", "attention.second", "ffn.second",
 )
 
 # ``coll.<method>.<axes>``: one collective call site, named by the
@@ -125,13 +138,36 @@ SCOPES = (
 COLL = "coll"
 
 
+_SECOND = ".second"
+_half = threading.local()       # ``second()``'s flag, of the tracing thread
+
+
 def scope(name: str):
-    """``with scope("attention"): ...`` around traced code."""
+    """``with scope("attention"): ...`` around traced code.  Under
+    :func:`second` a name that has a ``<name>.second`` in the vocabulary
+    opens that inside itself."""
     import jax
 
     if name not in SCOPES:
         raise ValueError(f"{name!r} is not in the scope vocabulary {SCOPES}")
+    if getattr(_half, "second", False) and name + _SECOND in SCOPES:
+        return jax.named_scope(f"{name}/{name}{_SECOND}")
     return jax.named_scope(name)
+
+
+@contextlib.contextmanager
+def second():
+    """While the second mixer and MLP of a layer that has two are traced:
+    the scopes they open (the same functions as the first's) also open their
+    ``.second`` where the vocabulary has one, so a profile tells the halves
+    apart and every reader of ``attention``, ``attn_proj`` or ``ffn`` still
+    finds both."""
+    was = getattr(_half, "second", False)
+    _half.second = True
+    try:
+        yield
+    finally:
+        _half.second = was
 
 
 def coll(method: str, axes):

@@ -5,7 +5,10 @@ and with one on the key part all heads share (``MLA.theta`` the base).
 From the block's input ``h`` and its norm ``x = RMSNorm(h; ln1)``:
 
     q        = x mla_q                        heads x (nope + rope)
-    [c, k_r] = x mla_kva                      kv_rank + rope; c <- RMSNorm(c)
+               or, with a query latent (``MLA.q_rank``),
+               q_scale RMSNorm(x mla_qa; mla_qn) mla_qb
+    [c, k_r] = x mla_kva                      kv_rank + rope;
+                                              c <- kv_scale RMSNorm(c)
     [k_n, v] = c mla_kvb                      heads x (nope + v_dim)
     theta > 0: every head's q[nope:] and k_r rotated at their position
                (``rotate``: neighbouring pairs, pair i by position x
@@ -56,6 +59,12 @@ class MLA:
     v_dim: int          # v_head_dim
     kv_rank: int        # kv_lora_rank: the latent
     theta: float = 0.0  # rope_theta: the rotation's base; 0: NoPE, no rotation
+    q_rank: int = 0     # q_lora_rank: the query latent; 0: none, the queries
+                        # are projected straight from the stream
+    # what the two normed latents are multiplied by (``mla_scale_q_lora``,
+    # ``mla_scale_kv_lora``: sqrt(d_model / rank) each); 1: nothing
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
 
     @property
     def cached(self) -> int:
@@ -68,8 +77,12 @@ def leaf_shapes(cfg, ml: MLA) -> dict:
     initializer or None for ones)."""
     D, H = cfg.d_model, ml.n_heads
     out = H * ml.v_dim
+    wide = H * (ml.nope + ml.rope)
     return {
-        "mla_q": ((D, H * (ml.nope + ml.rope)), D ** -0.5),
+        **({"mla_qa": ((D, ml.q_rank), D ** -0.5),
+            "mla_qn": ((ml.q_rank,), None),
+            "mla_qb": ((ml.q_rank, wide), ml.q_rank ** -0.5)} if ml.q_rank
+           else {"mla_q": ((D, wide), D ** -0.5)}),
         "mla_kva": ((D, ml.cached), D ** -0.5),
         "mla_n": ((ml.kv_rank,), None),
         "mla_kvb": ((ml.kv_rank, H * (ml.nope + ml.v_dim)),
@@ -89,12 +102,13 @@ def buffers(cfg, ml: MLA, batch: int, t_max: int) -> tuple:
 def _proj(ml: MLA):
     """The projections' scope: the latent's own name under the one every
     attending layer's projections have.  The form that rotates has a name of
-    its own, so that a metric of one form's projections finds nothing in a
-    cell of the other's."""
+    its own, and so has the form with a query latent, so that a metric of one
+    form's projections finds nothing in a cell of another's."""
     from ompi_tpu.core.scopes import scope
 
-    with scope("attn_proj"), scope("mla_proj.rope" if ml.theta
-                                   else "mla_proj"):
+    with scope("attn_proj"), scope(
+            "mla_proj.query_latent" if ml.q_rank
+            else "mla_proj.rope" if ml.theta else "mla_proj"):
         yield
 
 
@@ -122,6 +136,18 @@ def rotate(x, positions, theta: float):
         return (xf * jnp.cos(ang) - other * jnp.sin(ang)).astype(x.dtype)
 
 
+def _scaled_norm(cfg, x, scale, times: float):
+    """``times x RMSNorm(x; scale)``, the factor folded into the norm's
+    float32 scale (one rounding, the norm's own)."""
+    import jax.numpy as jnp
+
+    from ompi_tpu.models.transformer import _rmsnorm
+
+    if times != 1:
+        scale = scale.astype(jnp.float32) * times
+    return _rmsnorm(x, scale, cfg.norm_eps)
+
+
 def mixer(cfg, lp, h, carry=None):
     """One layer's mixer on the block's input ``h`` (B, T, D): the norm,
     attention and the residual add.
@@ -146,10 +172,15 @@ def mixer(cfg, lp, h, carry=None):
     scale = (N + P) ** -0.5
     with _proj(ml):
         x = _rmsnorm(h, lp["ln1"], cfg.norm_eps)
-        q = jnp.einsum("btd,df->btf", x, lp["mla_q"].astype(cdt)
-                       ).reshape(B, T, H, N + P)
+        if ml.q_rank:
+            q = jnp.einsum("btr,rf->btf", _scaled_norm(
+                cfg, jnp.einsum("btd,dr->btr", x, lp["mla_qa"].astype(cdt)),
+                lp["mla_qn"], ml.q_scale), lp["mla_qb"].astype(cdt))
+        else:
+            q = jnp.einsum("btd,df->btf", x, lp["mla_q"].astype(cdt))
+        q = q.reshape(B, T, H, N + P)
         kva = jnp.einsum("btd,df->btf", x, lp["mla_kva"].astype(cdt))
-        c = _rmsnorm(kva[..., :R], lp["mla_n"], cfg.norm_eps)
+        c = _scaled_norm(cfg, kva[..., :R], lp["mla_n"], ml.kv_scale)
         k_r = kva[..., R:]
         if ml.theta:
             at = jnp.arange(T) if carry is None else carry[1][None]

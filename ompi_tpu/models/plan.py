@@ -8,7 +8,12 @@ mixer's kind (``MIXERS``: "kda", ``models/kda.py``; "mla", ``models/mla.py``;
 ``parallel/moe.routed_moe``, of width ``d_expert``, under the
 configuration's ``moe_*`` fields).  A configuration without a plan is
 attention and one MLP in every layer under one ``lax.scan``, and is not
-touched by this module.
+touched by this module.  A row of the plan is one mixer and one MLP; a
+model's layer that has more than one mixer is as many rows, with
+``LayerPlan.branches`` saying what else reads the stream inside it and where
+that lands (a shortcut-connected layer: routed experts that read the first
+row's normed post-mixer stream and land after the second row's MLP, beside
+everything in between).
 
 A mixer's kind is a module and a line of ``MIXERS``.  The plan holds the
 kind's sizes in the field of the kind's name, and the module gives:
@@ -43,6 +48,7 @@ Only a configuration with a plan imports this (its doors: ``ENTRY_CONFIGS``).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any
 
@@ -50,7 +56,8 @@ import numpy as np
 
 __all__ = ["LayerPlan", "MIXERS", "kda_mla_config", "lightning_blocks_config",
            "leaf_names", "init_params", "carry", "grows", "carried",
-           "backbone", "step", "check_mesh", "mla_moe_config", "ENTRY_CONFIGS"]
+           "backbone", "step", "check_mesh", "mla_moe_config",
+           "shortcut_moe_config", "ENTRY_CONFIGS"]
 
 # a mixer's kind -> its module under ``ompi_tpu.models``
 MIXERS = {"kda": "kda", "mla": "mla", "lightning": "lightning",
@@ -63,14 +70,20 @@ SHARED_LEAVES = ("sw1", "sw3", "sw2")
 
 @dataclasses.dataclass(frozen=True)
 class LayerPlan:
-    """``layers``: a (mixer, mlp) pair of kinds a layer.  ``kda``, ``mla``,
+    """``layers``: a (mixer, mlp) pair of kinds a layer (a row: ``cfg.n_layers``
+    counts rows).  ``kda``, ``mla``,
     ``lightning`` and ``block_select``: the sizes of the mixers the plan
     names.  ``d_expert``: a routed expert's width (``cfg.d_ff`` is the dense
     MLP's).  Three constants of the model, each 1 where it has none: the
     embedding is multiplied by ``scale_emb``, every branch by
     ``branch_scale`` before its residual add (in the kinds that read it:
     "lightning", "block_select" and the dense MLP), and the last norm's
-    output divided by ``head_divisor`` before the head."""
+    output divided by ``head_divisor`` before the head.  ``branches``:
+    ``(kind, reads, lands)`` each, an MLP of ``kind`` that reads what row
+    ``reads``'s own MLP reads (the stream after that row's mixer, under its
+    ``ln2``) and is added to the stream after row ``lands``'s MLP, ``lands
+    >= reads``: it runs beside every mixer and MLP in between, which do not
+    see it."""
     layers: tuple
     kda: Any = None
     mla: Any = None
@@ -80,14 +93,27 @@ class LayerPlan:
     scale_emb: float = 1.0
     branch_scale: float = 1.0
     head_divisor: float = 1.0
+    branches: tuple = ()
 
     def count(self, kind: str) -> int:
-        """Layers whose mixer or MLP is ``kind``."""
-        return sum(kind in pair for pair in self.layers)
+        """Layers whose mixer or MLP is ``kind``, and branches of it."""
+        return (sum(kind in pair for pair in self.layers)
+                + sum(kind == of for of, _reads, _lands in self.branches))
 
-    def index(self, layer: int, kind: str) -> int:
-        """Layer ``layer``'s place in the stacks of ``kind``."""
-        return sum(kind in pair for pair in self.layers[:layer])
+    def index(self, layer: int, kind: str, branch: bool = False) -> int:
+        """Layer ``layer``'s place in the stacks of ``kind``; ``branch``:
+        that of the branch that reads there, which lies after the layer's
+        own."""
+        return (sum(kind in pair for pair in self.layers[:layer])
+                + sum(kind == of and reads < layer
+                      for of, reads, _lands in self.branches)
+                + (branch and kind in self.layers[layer]))
+
+    def second(self, layer: int) -> bool:
+        """Whether ``layer`` is past the first row of a model's layer: a
+        branch that was read before it has not landed yet."""
+        return any(reads < layer <= lands
+                   for _kind, reads, lands in self.branches)
 
 
 def kda_mla_config(linear_attn_config: dict, first_k_dense_replace: int,
@@ -212,12 +238,20 @@ def check_mesh(cfg, mesh) -> None:
                 f"a layer plan (mixers of kinds {', '.join(MIXERS)}) runs "
                 f"with {axis} == 1 only, and the mesh has {axis}="
                 f"{mesh.shape[axis]}: its mixers are not split over {axis}")
-    unknown = ({mixer for mixer, _mlp in cfg.plan.layers} - set(MIXERS)
-               | {mlp for _mixer, mlp in cfg.plan.layers} - set(MLPS))
+    pl = cfg.plan
+    unknown = ({mixer for mixer, _mlp in pl.layers} - set(MIXERS)
+               | {mlp for _mixer, mlp in pl.layers} - set(MLPS)
+               | {kind for kind, _reads, _lands in pl.branches} - {"moe"})
     if unknown:
         raise ValueError(f"a layer plan of kinds {sorted(unknown)}: not "
                          f"built (have {', '.join(MIXERS)}; "
-                         f"{', '.join(MLPS)})")
+                         f"{', '.join(MLPS)}; a branch: moe)")
+    astray = [b for b in pl.branches
+              if not 0 <= b[1] <= b[2] < len(pl.layers)]
+    if astray:
+        raise ValueError(f"a branch reads a row of the plan and lands at "
+                         f"that row or a later one: {astray} of "
+                         f"{len(pl.layers)} rows")
 
 
 def _kinds(cfg) -> dict:
@@ -230,7 +264,8 @@ def _kinds(cfg) -> dict:
 
     def moe():
         Fe, Fs = pl.d_expert, cfg.moe_shared
-        held = cfg.moe_held[1] if cfg.moe_held else cfg.moe_experts
+        held = (cfg.moe_held[1] if cfg.moe_held
+                else cfg.moe_experts - cfg.moe_zero)
         leaves = {"wg": ((D, cfg.moe_experts), 0.02),
                   "w1": ((held, D, Fe), D ** -0.5),
                   "w3": ((held, D, Fe), D ** -0.5),
@@ -358,12 +393,14 @@ def carried(cfg, mesh, collected, t_max: int, into=None, **group) -> list:
                 collected, into or [None] * len(buffers), buffers)]
 
 
-def _mlp(cfg, comm, params, layer: int, kind: str, h):
-    """Layer ``layer``'s MLP half, ``ln2`` and the residual add in it."""
+def _mlp(cfg, comm, params, layer: int, kind: str, h, branch: bool = False):
+    """Layer ``layer``'s MLP half, ``ln2`` and the residual add in it; or,
+    ``branch``, what the branch of ``kind`` that reads there adds to the
+    stream where it lands."""
     from ompi_tpu.models import transformer as tfm
 
     pl = cfg.plan
-    at = pl.index(layer, kind)
+    at = pl.index(layer, kind, branch)
     if kind == "dense":
         lp = {"ln2": params["ln2"][layer], **{
             name: params[leaf][at] for name, leaf in zip(
@@ -376,7 +413,41 @@ def _mlp(cfg, comm, params, layer: int, kind: str, h):
           **{k: params[k] for k in ("w1", "w3", "w2")},
           **{k: params[k][at] for k in (*ROUTER_LEAVES, *SHARED_LEAVES)
              if k in params}}
-    return tfm._moe_ffn_tail(cfg, h, lp, comm, layer=at)[0]
+    return tfm._moe_ffn_tail(cfg, h, lp, comm, layer=at,
+                             residual=not branch)[0]
+
+
+def _row(cfg, comm, params, layer: int, mixer: str, mlp: str, lp, h,
+         landing, carry=None):
+    """Row ``layer`` of the plan on the stream ``h``: its mixer on the leaves
+    ``lp`` (against ``carry`` in a cached step), the branches that read
+    there, its MLP, and the branches that land there (``landing``) added.
+    Returns ``(h, what the mixer hands back, the branches begun that land
+    later: row -> those that land there)``."""
+    from ompi_tpu.core.scopes import scope, second
+
+    pl = cfg.plan
+    half = second if pl.second(layer) else contextlib.nullcontext
+    with half():
+        h, *states = _module(mixer).mixer(cfg, lp, h, carry=carry)
+        begun = {}
+        for kind, reads, lands in pl.branches:
+            if reads == layer:
+                begun.setdefault(lands, []).append(
+                    _mlp(cfg, comm, params, layer, kind, h, branch=True))
+        h = _mlp(cfg, comm, params, layer, mlp, h)
+        landing = [*landing, *begun.pop(layer, [])]
+        if landing:
+            with scope("ffn"):
+                for branch in landing:
+                    h = h + branch
+    return h, states, begun
+
+
+def _take_off(flying: dict, begun: dict) -> None:
+    """``begun`` (row -> branches that land there) joins what is in flight."""
+    for lands, branches in begun.items():
+        flying.setdefault(lands, []).extend(branches)
 
 
 def _mixer_leaves(cfg, params, layer: int, kind: str) -> dict:
@@ -433,10 +504,9 @@ def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
             {k: params[k] for k in leaf_names(cfg)}, grad_axes)}
 
     def layer_fn(layer, mixer, mlp):
-        def run(h, params):
+        def run(h, params, landing):
             lp = _mixer_leaves(cfg, params, layer, mixer)
-            h, *states = _module(mixer).mixer(cfg, lp, h)
-            return _mlp(cfg, comm, params, layer, mlp, h), states
+            return _row(cfg, comm, params, layer, mixer, mlp, lp, h, landing)
 
         if cfg.remat in (True, "full"):
             run = jax.checkpoint(run)
@@ -445,12 +515,14 @@ def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
                                  .dots_with_no_batch_dims_saveable)
         return _own_program(run)
 
-    collected = []
+    collected, flying = [], {}
     with scope("layers"):
         for layer, (mixer, mlp) in enumerate(pl.layers):
             # the host's record of what tracing this layer costs, by kind
             with host("trace.layer", program=mixer):
-                h, states = layer_fn(layer, mixer, mlp)(h, params)
+                h, states, begun = layer_fn(layer, mixer, mlp)(
+                    h, params, flying.pop(layer, []))
+            _take_off(flying, begun)
             collected += [state[None] for state in states]
     h = tfm._rmsnorm(h, params["lnf"], cfg.norm_eps)
     if pl.head_divisor != 1:
@@ -473,22 +545,23 @@ def step(cfg, comm, params, h, states, pos):
     def layer_fn(layer, mixer, mlp):
         module = _module(mixer)
 
-        def run(h, params, own, pos):
+        def run(h, params, own, pos, landing):
             lp = _mixer_leaves(cfg, params, layer, mixer)
             own = tuple(buffer[0] for buffer in own)
-            h, *own = module.mixer(
-                cfg, lp, h, carry=(*own, pos) if module.POSITIONED else own)
-            return (_mlp(cfg, comm, params, layer, mlp, h),
-                    [buffer[None] for buffer in own])
+            h, own, begun = _row(
+                cfg, comm, params, layer, mixer, mlp, lp, h, landing,
+                carry=(*own, pos) if module.POSITIONED else own)
+            return h, [buffer[None] for buffer in own], begun
 
         return _own_program(run)
 
-    states, at = list(states), 0
+    states, at, flying = list(states), 0, {}
     for layer, (mixer, mlp) in enumerate(pl.layers):
         n = len(_module(mixer).buffers(cfg, getattr(pl, mixer), 0, 0))
         with host("trace.layer", program=mixer):
-            h, states[at:at + n] = layer_fn(layer, mixer, mlp)(
-                h, params, states[at:at + n], pos)
+            h, states[at:at + n], begun = layer_fn(layer, mixer, mlp)(
+                h, params, states[at:at + n], pos, flying.pop(layer, []))
+        _take_off(flying, begun)
         at += n
     return (h, *states)
 
@@ -510,7 +583,8 @@ def mla_moe_config(first_k_dense_replace: int, kv_lora_rank: int,
     and does not weigh (``noaux_tc``), the picks' weights renormalised where
     ``moe_norm_topk`` says so and times ``routed_scaling_factor``, and
     ``n_shared_experts`` shared experts as one gated MLP of their summed
-    width.  What is not built raises: a query latent (``q_lora_rank``),
+    width; with ``q_lora_rank`` the queries come from a normed query latent
+    of that rank.  What is not built raises:
     grouped top-k (``n_group``, ``topk_group`` over 1), a scaled rotary
     embedding (``rope_scaling``), dense layers among the routed ones
     (``moe_layer_freq`` other than 1), another ``scoring_func`` or
@@ -519,7 +593,7 @@ def mla_moe_config(first_k_dense_replace: int, kv_lora_rank: int,
     from ompi_tpu.models.transformer import TransformerConfig
 
     not_built = [f"{key} {value!r}" for key, value, built in (
-        ("q_lora_rank", q_lora_rank, None), ("n_group", n_group, 1),
+        ("n_group", n_group, 1),
         ("topk_group", topk_group, 1), ("rope_scaling", rope_scaling, None),
         ("moe_layer_freq", moe_layer_freq, 1),
         ("scoring_func", scoring_func, "sigmoid"),
@@ -532,12 +606,73 @@ def mla_moe_config(first_k_dense_replace: int, kv_lora_rank: int,
                       else "moe") for layer in range(sizes["n_layers"])),
         mla=MLA(n_heads=sizes["n_heads"], nope=qk_nope_head_dim,
                 rope=qk_rope_head_dim, v_dim=v_head_dim,
-                kv_rank=kv_lora_rank, theta=float(rope_theta)),
+                kv_rank=kv_lora_rank, theta=float(rope_theta),
+                q_rank=int(q_lora_rank or 0)),
         d_expert=moe_intermediate_size)
     return TransformerConfig(
         plan=plan, moe_gated=True, moe_score="sigmoid", moe_select_bias=True,
         moe_scale=float(routed_scaling_factor),
         moe_shared=n_shared_experts * moe_intermediate_size, **sizes)
+
+
+def shortcut_moe_config(kv_lora_rank: int, q_lora_rank, qk_nope_head_dim: int,
+                        qk_rope_head_dim: int, v_head_dim: int,
+                        expert_ffn_hidden_size: int,
+                        routed_scaling_factor: float, router_experts: int,
+                        zero_expert_num: int, zero_expert_type: str,
+                        mla_scale_q_lora: bool, mla_scale_kv_lora: bool,
+                        rope_theta: float, attention_method: str,
+                        attention_bias: bool, experts_held=None, **sizes):
+    """``entry.config`` of a configuration file whose layer is
+    shortcut-connected, under the keys LongCat-Flash is published with: a
+    ``TransformerConfig`` whose plan has two rows a layer, each rotary
+    latent attention with a query latent over a dense MLP, and one branch a
+    layer, routed experts that read the first row's normed post-attention
+    stream and land after the second row's MLP.  ``sizes["n_layers"]`` comes
+    in as the model's layers and goes on as the plan's rows, twice as many.
+    The router is ``router_experts + zero_expert_num`` wide, the last
+    ``zero_expert_num`` of its outputs identity experts: softmax scores, a
+    selection bias that picks and does not weigh, the picks' weights as they
+    are (or renormalised where ``moe_norm_topk`` says so) times
+    ``routed_scaling_factor``.  The two normed latents are multiplied by
+    ``sqrt(d_model / rank)`` where ``mla_scale_q_lora`` and
+    ``mla_scale_kv_lora`` say so.  ``experts_held`` as in
+    :func:`kda_mla_config`.  What is not built raises: another
+    ``attention_method`` than "MLA", an ``attention_bias``, another
+    ``zero_expert_type`` than "identity"."""
+    from ompi_tpu.models.mla import MLA
+    from ompi_tpu.models.transformer import TransformerConfig
+
+    not_built = [f"{key} {value!r}" for key, value, built in (
+        ("attention_method", attention_method, "MLA"),
+        ("attention_bias", bool(attention_bias), False),
+        ("zero_expert_type", zero_expert_type, "identity"))
+        if value != built]
+    if not_built:
+        raise ValueError(f"a plan of shortcut-connected layers is not built "
+                         f"for {', '.join(not_built)}")
+    D, q_rank = sizes["d_model"], int(q_lora_rank or 0)
+    blocks = sizes["n_layers"]
+    sizes["n_layers"] = 2 * blocks
+    plan = LayerPlan(
+        layers=(("mla", "dense"),) * (2 * blocks),
+        branches=tuple(("moe", 2 * block, 2 * block + 1)
+                       for block in range(blocks)),
+        mla=MLA(n_heads=sizes["n_heads"], nope=qk_nope_head_dim,
+                rope=qk_rope_head_dim, v_dim=v_head_dim,
+                kv_rank=kv_lora_rank, theta=float(rope_theta), q_rank=q_rank,
+                q_scale=((D / q_rank) ** 0.5 if mla_scale_q_lora and q_rank
+                         else 1.0),
+                kv_scale=((D / kv_lora_rank) ** 0.5 if mla_scale_kv_lora
+                          else 1.0)),
+        d_expert=expert_ffn_hidden_size)
+    held = (None if experts_held is None
+            else (int(experts_held["first"]), int(experts_held["count"])))
+    return TransformerConfig(
+        plan=plan, moe_gated=True, moe_score="softmax", moe_select_bias=True,
+        moe_scale=float(routed_scaling_factor), moe_held=held,
+        moe_experts=router_experts + zero_expert_num,
+        moe_zero=zero_expert_num, **sizes)
 
 
 # A configuration file reaches a plan through its ``entry.config``, one
@@ -547,5 +682,9 @@ def mla_moe_config(first_k_dense_replace: int, kv_lora_rank: int,
 # ``mla_moe_config``: DeepSeek-V3's keys, rotary latent attention in every
 # layer, a leading dense MLP, then the same router with ``n_shared_experts``;
 # ``lightning_blocks_config``: lightning and block-selected layers by
-# ``mixer_types`` over a dense MLP.
-ENTRY_CONFIGS = (kda_mla_config, mla_moe_config, lightning_blocks_config)
+# ``mixer_types`` over a dense MLP; ``shortcut_moe_config``: LongCat-Flash's
+# keys, two rows of rotary latent attention with a query latent over a dense
+# MLP a layer and a softmax router with identity experts as a branch across
+# them.
+ENTRY_CONFIGS = (kda_mla_config, mla_moe_config, lightning_blocks_config,
+                 shortcut_moe_config)

@@ -82,6 +82,11 @@ class TransformerConfig:
     # held elsewhere add nothing here (parallel/moe.routed_moe), as the
     # device that holds them adds them.  None: every expert is here.
     moe_held: Any = None
+    # Identity experts (zero-computation experts): the last ``moe_zero`` of
+    # the router's ``moe_experts`` outputs have no matrices, and a pick of
+    # one adds the token itself times the pick's weight; the expert leaves
+    # are ``moe_experts - moe_zero`` long (or ``moe_held``'s count).  0: none.
+    moe_zero: int = 0
     # Width of a shared expert, a gated MLP (leaves "sw1", "sw3", "sw2")
     # that every token passes, unweighted, beside its routed experts.  0: none.
     moe_shared: int = 0
@@ -600,11 +605,13 @@ def _rope(x, positions, impl: str = "jnp", theta=10_000):
     return rot.astype(x.dtype)
 
 
-def _moe_ffn_tail(cfg, h, lp, comm, layer=None):
+def _moe_ffn_tail(cfg, h, lp, comm, layer=None, residual: bool = True):
     """Post-attention half of the MoE layer: ln2 → ep-sharded switch, or
     dropless top-k routed experts (those this device holds: ``moe_held``)
     and, with ``moe_shared``, the shared expert every token passes →
-    residual (``models/block.block``'s and a plan's).  Returns (h, aux).
+    residual (``models/block.block``'s and a plan's).  Returns (h, aux);
+    without ``residual`` what the layer adds to ``h`` in h's place (a plan's
+    branch, which lands elsewhere).
     With ``layer``, the dropless path's expert leaves
     (``moe.EXPERT_LEAVES``) are the whole stacks over layers and ``layer``
     this layer's index in them (``routed_moe`` says why)."""
@@ -631,15 +638,16 @@ def _moe_ffn_tail(cfg, h, lp, comm, layer=None):
                             layer=layer, kernel=comm.mesh.devices.flat[
                                 0].platform == "tpu",
                             renorm=cfg.moe_norm_topk, score=cfg.moe_score,
-                            scale=cfg.moe_scale, held=cfg.moe_held)
+                            scale=cfg.moe_scale, held=cfg.moe_held,
+                            zero=cfg.moe_zero)
             if cfg.moe_shared:
                 mo = mo + _shared_expert(x, lp)
-            return h + mo, jnp.zeros((), jnp.float32)
+            return h + mo if residual else mo, jnp.zeros((), jnp.float32)
         mo, aux = switch_moe(
             comm, x, {"wg": lp["wg"], "w1": lp["w1"], "w2": lp["w2"]},
             axis="ep", capacity_factor=cfg.moe_capacity_factor,
             with_aux=True)
-        return h + mo, aux
+        return h + mo if residual else mo, aux
 
 
 def _shared_expert(x, lp):

@@ -22,8 +22,10 @@ the call (128 operations a byte of bfloat16 weights against the v5e's ridge
 of 240).  A matrix too large for that keeps its whole ``K`` and takes the
 widest block of ``N`` that fits: the weights' index then changes at every
 grid step and *every row tile reads its group's whole matrix again*, the
-tiles past ``tiles_used`` too (they skip the product, not the copy), which
-a tile of 256 or 512 rows amortises; but the rows' index ``(t, 0)`` stays
+used ones (a tile past ``tiles_used`` names the block that the step before
+it named and copies nothing: where a device holds a few of a wide router's
+experts, nearly every tile is one), which a tile of 256 or 512 rows
+amortises; but the rows' index ``(t, 0)`` stays
 put under every block of ``N``, so the pipeline copies a tile's rows once.
 Only where not even one lane tile of a whole ``K`` fits do both dimensions
 go by blocks, and a tile's rows are read again for every block of ``N``:
@@ -159,15 +161,27 @@ def _forward(rows, w, tile_group, tiles_used):
                          f"{n_tiles} tiles")
     tm = m // n_tiles
     tk, tn = weight_block(tm, K, N, jnp.dtype(w.dtype).itemsize)
+    nj, nk = N // tn, K // tk
+
+    def weights_at(i, j, k, tg, used):
+        return tg[i], k, j
+
+    def weights_at_or_where_they_were(i, j, k, tg, used):
+        """A tile past ``tiles_used`` multiplies nothing: it names the block
+        the last step of the tile before it named, and copies none."""
+        live = i < used[0]
+        return (tg[i], jnp.where(live, k, nk - 1), jnp.where(live, j, nj - 1))
+
     return pallas_call(
         _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(n_tiles, N // tn, K // tk),
+            grid=(n_tiles, nj, nk),
             in_specs=[
                 pl.BlockSpec((tm, tk), lambda i, j, k, tg, used: (i, k)),
-                pl.BlockSpec((None, tk, tn),
-                             lambda i, j, k, tg, used: (tg[i], k, j)),
+                # a whole matrix a block stays put by itself
+                pl.BlockSpec((None, tk, tn), weights_at if nj * nk == 1
+                             else weights_at_or_where_they_were),
             ],
             out_specs=pl.BlockSpec((tm, tn),
                                    lambda i, j, k, tg, used: (i, j)),

@@ -158,7 +158,8 @@ def switch_moe(comm, x, params, axis: str = "ep",
 
 def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
                kernel: bool = False, renorm: bool = False,
-               score: str = "softmax", scale: float = 1.0, held=None):
+               score: str = "softmax", scale: float = 1.0, held=None,
+               zero: int = 0):
     """Dropless top-k MoE layer over the experts this device holds: x (B, T,
     D) local tokens → (B, T, D).
 
@@ -182,14 +183,23 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
     weights are *not* renormalised over the picks held here.  No exchange is
     made and nothing stands in for one.
 
+    ``zero``: the router's last ``zero`` outputs are identity experts
+    (zero-computation experts: a pick of one adds the token itself, times the
+    pick's weight), so ``E - zero`` of its ``E`` outputs have matrices.  A
+    token picks ``top_k`` of all ``E`` and its weights are made over all its
+    picks; the identity picks get no tile, and what they add, ``(the sum of
+    their weights) x`` in float32, is computed here for this device's own
+    tokens (scope ``moe.zero``), whatever ``held`` says: the devices that
+    share a layer by expert each add it for the tokens they route.
+
     Routing (all shapes static, no capacity, no token dropped): the
     router's logits, scores and top-k in float32.  ``score`` "softmax":
     each token keeps its ``top_k`` most probable experts with their
     probabilities as they are, or with ``renorm`` divided by their sum, so
     that a token's experts weigh one together.  ``score`` "sigmoid": the
-    scores are each expert's own sigmoid; with a leaf ``wgb`` (E,) in
-    ``params``, the selection bias, the ``top_k`` largest of ``score +
-    wgb`` are picked and weigh their scores without it.  Either way the
+    scores are each expert's own sigmoid.  Under either, with a leaf ``wgb``
+    (E,) in ``params``, the selection bias, the ``top_k`` largest of ``score
+    + wgb`` are picked and weigh their scores without it.  Either way the
     weights are then multiplied by ``scale``.  The ``tokens × top_k``
     assignments are sorted by
     expert (stable) and laid out in row tiles of ``tm`` rows, every
@@ -229,6 +239,9 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
     cdt = x.dtype
     xf = x.reshape(n, D)
     tm = tile_rows(n * k / E)
+    if zero and held is None:
+        held = (0, E - zero)            # every expert that has matrices
+    first_zero = E - zero
     if held is not None:
         E = held[1]                     # the groups that get tiles
     n_tiles = -(-n * k // tm) + E       # sum of ceil(rows_e / tm) is below
@@ -236,7 +249,12 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
         logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
                             params["wg"].astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
-        if score == "softmax":
+        if score == "softmax" and "wgb" in params:
+            probs = jax.nn.softmax(logits, axis=-1)
+            expert = lax.top_k(probs + params["wgb"].astype(jnp.float32),
+                               k)[1]
+            gate = jnp.take_along_axis(probs, expert, axis=-1)
+        elif score == "softmax":
             gate, expert = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
         else:
             scores = jax.nn.sigmoid(logits)
@@ -248,6 +266,10 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
             gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
         if scale != 1.0:
             gate = gate * scale
+        if zero:
+            # a token's identity picks weigh their sum, on the token itself
+            zero_gate = jnp.sum(jnp.where(expert >= first_zero, gate, 0.0),
+                                axis=-1)
         if held is not None:
             # a pick held elsewhere: weighs nothing, and sorts past the last
             # expert of this device, where no tile is made for it
@@ -297,5 +319,10 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
         out = matmul(hid, stacks["w2"], tile_group, used)
     with scope("moe.combine"):
         out = out[slot].reshape(n, k, D).astype(jnp.float32)
-        y = jnp.sum(out * gate[:, :, None], axis=1).astype(cdt)
+        y = jnp.sum(out * gate[:, :, None], axis=1)
+        if not zero:
+            y = y.astype(cdt)
+    if zero:
+        with scope("moe.zero"):
+            y = (y + zero_gate[:, None] * xf.astype(jnp.float32)).astype(cdt)
     return y.reshape(B, T, D)

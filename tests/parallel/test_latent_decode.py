@@ -32,6 +32,8 @@ from tests.parallel.test_mla_rope import PARITY, error
 BLOCK = kernel_module._BLOCK
 CELL_10 = "kimi-vl-a3b.decode-16k-256-b32"
 CELL_7 = "kimi-linear-48b-a3b.decode-512-128-b384"
+# one block of 1024 positions a sequence, 64 heads
+CELL_11 = "longcat-flash-chat.decode-896-128-b160"
 # first block only, a block's last row, the next block's first, the last row
 POSITIONS = (5, BLOCK - 1, BLOCK, 2 * BLOCK - 1)
 
@@ -198,6 +200,9 @@ def _latent_step(workload, tiny):
     pytest.param(CELL_7, False, False, 640, False, id="cell-7-cpu"),
     pytest.param(CELL_10, True, True, 24, False, id="cell-10-tiny"),
     pytest.param(CELL_7, True, True, 24, False, id="cell-7-tiny"),
+    pytest.param(CELL_11, False, True, 1024, True, id="cell-11"),
+    pytest.param(CELL_11, False, False, 1024, False, id="cell-11-cpu"),
+    pytest.param(CELL_11, True, True, 24, False, id="cell-11-tiny"),
 ])
 def test_which_form_each_latent_cells_step_takes(monkeypatch, workload, tiny,
                                                  tpus, positions, kernel):
@@ -209,8 +214,8 @@ def test_which_form_each_latent_cells_step_takes(monkeypatch, workload, tiny,
 
 
 def test_no_other_cell_has_latent_layers():
-    """The two above are every cell the rule is asked about."""
+    """The three above are every cell the rule is asked about."""
     planned = {row["name"] for row in cells.load_benchmark()["workloads"]
                if getattr(getattr(program.program_config(cells.resolve(
                    row["name"]).config), "plan", None), "mla", None)}
-    assert planned == {CELL_10, CELL_7}
+    assert planned == {CELL_10, CELL_7, CELL_11}

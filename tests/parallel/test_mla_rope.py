@@ -212,7 +212,16 @@ def test_the_mixer_takes_the_kernel_on_tpus_from_kernel_from_on(monkeypatch):
 
 def test_the_entry_config_refuses_what_is_not_built():
     config = copy.deepcopy(program.tiny(cells.resolve(CELL).config))
-    for key, value in (("q_lora_rank", 1536), ("n_group", 8),
+    # a query latent is built (``MLA.q_rank``); this family's reference is
+    # written without one
+    latent = program.program_config({**config, "q_lora_rank": 24})
+    assert latent.plan.mla.q_rank == 24 and {
+        "mla_qa", "mla_qn", "mla_qb"} <= set(plan.leaf_names(latent))
+    assert "mla_q" not in plan.leaf_names(latent)
+    with pytest.raises(ValueError, match="written for"):
+        program.reference(config).Shape.from_config(
+            {**config, "q_lora_rank": 24})
+    for key, value in (("n_group", 8),
                        ("topk_group", 4), ("rope_scaling", {"type": "yarn"}),
                        ("moe_layer_freq", 2), ("scoring_func", "softmax"),
                        ("topk_method", "greedy")):
@@ -228,7 +237,7 @@ def test_every_planned_configurations_door_is_listed():
              for c in (cells.load_json(f"{cells.BENCH_DIR}/../{row['file']}")
                        for row in cells.load_benchmark()["configs"])
              if ".plan." in c["entry"]["config"]}
-    assert doors == set(plan.ENTRY_CONFIGS) and len(doors) == 3
+    assert doors == set(plan.ENTRY_CONFIGS) and len(doors) == 4
 
 
 def test_the_nope_form_has_no_rotation_in_it():
