@@ -30,6 +30,7 @@ layer bit-for-bit (tests/parallel/test_moe.py).
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 __all__ = ["switch_moe", "routed_moe", "moe_params", "EXPERT_LEAVES"]
@@ -86,8 +87,6 @@ def switch_moe(comm, x, params, axis: str = "ep",
     E = e_local * ep
     n_tok = B * T
     if capacity is None:
-        import math
-
         capacity = max(1, math.ceil((n_tok / E) * capacity_factor))
     C = capacity
 
@@ -156,6 +155,29 @@ def switch_moe(comm, x, params, axis: str = "ep",
     return y, aux
 
 
+# Where a device holds few of a wide router's experts, ``routed_moe`` lays
+# out, multiplies and sums windows of the picks it holds and not all it
+# routes.  Both constants are read against static shapes alone (the picks
+# routed, the experts held, the router's width).  A window has room for
+# ``_WINDOW_ROOM`` times the picks expected here, so that one window nearly
+# always takes them all (a second one costs a second read of the held
+# experts' matrices); and windows are taken only where one's layout is at
+# most ``_WINDOW_SHARE`` of the whole one: above that the loop and its branch
+# cost what the smaller layout saves.
+_WINDOW_ROOM = 2
+_WINDOW_SHARE = 1 / 4
+
+
+def _window_rows(picks: int, tm: int, held: int, width: int) -> int:
+    """The sorted rows ``routed_moe`` lays out at a time: a window, a
+    multiple of ``tm``, where ``held`` of a router's ``width`` outputs get
+    tiles here and that is few of them; all ``picks`` otherwise."""
+    cap = math.ceil(_WINDOW_ROOM * picks * held / (width * tm)) * tm
+    if cap + held * tm <= _WINDOW_SHARE * (picks + held * tm):
+        return cap
+    return picks
+
+
 def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
                kernel: bool = False, renorm: bool = False,
                score: str = "softmax", scale: float = 1.0, held=None,
@@ -213,6 +235,20 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
     read back by nobody.  Each token then sums its ``top_k`` result rows
     weighted by their probabilities, in float32.
 
+    Where the device holds few of a wide router's experts
+    (``_window_rows``, from static shapes: LongCat-Flash's 16 of 768
+    outputs, one pick in 48), the layout, the experts' calls and the sum are
+    sized by the picks it holds and not by all it routes: the held picks
+    are the first sorted rows, and a *window* of them at a time (twice the
+    picks expected here) is laid out on ``window/tm + E`` tiles, multiplied,
+    and its real rows added, times their weights, in float32, to their
+    tokens.  One window nearly always; a further one for as long as held
+    picks are left (a loop of static length whose body runs under
+    ``lax.cond``, so it differentiates in reverse), so no pick is dropped
+    and nothing is a capacity: with every pick of the batch held here every
+    window runs.  The same picks, weights and products as the whole
+    layout's; a token's held picks are added by expert, not by pick.
+
     ``tm`` follows the rows an expert gets on average
     (``ops.grouped_matmul.tile_rows``): 512 for a prefill of thousands of
     rows an expert, 16 for a cached step's handful, where the layer is the
@@ -237,14 +273,14 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
     n, k = B * T, top_k
     E = params["wg"].shape[-1]
     cdt = x.dtype
-    xf = x.reshape(n, D)
+    xf = jnp.asarray(x).reshape(n, D)
     tm = tile_rows(n * k / E)
     if zero and held is None:
         held = (0, E - zero)            # every expert that has matrices
     first_zero = E - zero
+    cap = _window_rows(n * k, tm, E if held is None else held[1], E)
     if held is not None:
         E = held[1]                     # the groups that get tiles
-    n_tiles = -(-n * k // tm) + E       # sum of ceil(rows_e / tm) is below
     with scope("moe.route"):
         logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
                             params["wg"].astype(jnp.float32),
@@ -282,44 +318,88 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
         sorted_group, order = lax.sort(
             (expert.reshape(n * k), jnp.arange(n * k, dtype=jnp.int32)),
             num_keys=1, is_stable=True)
-        is_group = sorted_group[:, None] == jnp.arange(E)[None, :]
-        rows_of = jnp.sum(is_group, axis=0, dtype=jnp.int32)    # (E,)
-        tiles_of = -(-rows_of // tm)
-        tile_end = jnp.cumsum(tiles_of)             # expert e ends before
-        # a run of sorted rows moves up by this much into the tiled layout
-        shift = (tile_end - tiles_of) * tm - (jnp.cumsum(rows_of) - rows_of)
-        tile_group = jnp.minimum(
-            jnp.searchsorted(tile_end, jnp.arange(n_tiles), side="right"),
-            E - 1).astype(jnp.int32)
-        # slot -> the sorted row it holds (where a slot only fills a tile
-        # up, a real row of the next run, or the last)
-        holds = jnp.clip(jnp.arange(n_tiles * tm).reshape(n_tiles, tm)
-                         - shift[tile_group][:, None], 0, n * k - 1)
-        rows = xf[order[holds.reshape(-1)] // k]    # (n_tiles·tm, D)
-        # assignment -> its slot (a sort by ``order`` is its inverse)
-        _, slot = lax.sort(
-            (order, jnp.arange(n * k)
-             + jnp.sum(jnp.where(is_group, shift[None, :], 0), axis=1)),
-            num_keys=1)
-    with scope("moe.experts"):
-        used = tile_end[-1:]
-        stacks = {name: params[name].astype(cdt)
-                  for name in EXPERT_LEAVES if name in params}
-        if layer is not None:       # (L, E, ·, ·): layer l's are l·E + e
-            stacks = {name: w.reshape(-1, *w.shape[2:])
-                      for name, w in stacks.items()}
-            tile_group = tile_group + layer * E
-        matmul = grouped_matmul if kernel else grouped_matmul_xla
-        hid = matmul(rows, stacks["w1"], tile_group, used)
-        if gated:
-            hid = jax.nn.silu(hid) * matmul(rows, stacks["w3"], tile_group,
-                                            used)
-        else:
-            hid = jax.nn.gelu(hid)
-        out = matmul(hid, stacks["w2"], tile_group, used)
+
+    def window(group, order, y=None):
+        """The layout, the experts and the weighted sum of a run of sorted
+        rows (``group`` their experts, ``order`` their assignments): all of
+        them, and then the sum is every token's over its ``k`` result rows;
+        or, given ``y`` (n, D) float32, a window of them, whose real rows'
+        results are added to ``y`` at their tokens."""
+        rows_in = group.shape[0]
+        n_tiles = -(-rows_in // tm) + E     # sum of ceil(rows_e / tm) is below
+        with scope("moe.dispatch"):
+            is_group = group[:, None] == jnp.arange(E)[None, :]
+            rows_of = jnp.sum(is_group, axis=0, dtype=jnp.int32)    # (E,)
+            tiles_of = -(-rows_of // tm)
+            tile_end = jnp.cumsum(tiles_of)         # expert e ends before
+            # a run of sorted rows moves up by this much into the tiled
+            # layout
+            shift = ((tile_end - tiles_of) * tm
+                     - (jnp.cumsum(rows_of) - rows_of))
+            tile_group = jnp.minimum(
+                jnp.searchsorted(tile_end, jnp.arange(n_tiles),
+                                 side="right"),
+                E - 1).astype(jnp.int32)
+            # slot -> the sorted row it holds (where a slot only fills a
+            # tile up, a real row of the next run, or the last)
+            holds = jnp.clip(jnp.arange(n_tiles * tm).reshape(n_tiles, tm)
+                             - shift[tile_group][:, None], 0, rows_in - 1)
+            rows = xf[order[holds.reshape(-1)] // k]    # (n_tiles·tm, D)
+            # sorted row -> its slot (a row held elsewhere: any slot)
+            slot = jnp.arange(rows_in) + jnp.sum(
+                jnp.where(is_group, shift[None, :], 0), axis=1)
+            if y is None:
+                # assignment -> its slot (a sort by ``order`` is its inverse)
+                _, slot = lax.sort((order, slot), num_keys=1)
+        with scope("moe.experts"):
+            used = tile_end[-1:]
+            stacks = {name: params[name].astype(cdt)
+                      for name in EXPERT_LEAVES if name in params}
+            if layer is not None:   # (L, E, ·, ·): layer l's are l·E + e
+                stacks = {name: w.reshape(-1, *w.shape[2:])
+                          for name, w in stacks.items()}
+                tile_group = tile_group + layer * E
+            matmul = grouped_matmul if kernel else grouped_matmul_xla
+            hid = matmul(rows, stacks["w1"], tile_group, used)
+            if gated:
+                hid = jax.nn.silu(hid) * matmul(rows, stacks["w3"],
+                                                tile_group, used)
+            else:
+                hid = jax.nn.gelu(hid)
+            out = matmul(hid, stacks["w2"], tile_group, used)
+        with scope("moe.combine"):
+            if y is None:
+                out = out[slot].reshape(n, k, D).astype(jnp.float32)
+                return jnp.sum(out * gate[:, :, None], axis=1)
+            # a row past the held picks (held elsewhere, or the padding of
+            # the last window) weighs nothing
+            weight = jnp.where(group < E, gate.reshape(n * k)[order], 0.0)
+            return y.at[order // k].add(
+                out[slot].astype(jnp.float32) * weight[:, None])
+
+    if cap == n * k:
+        y = window(sorted_group, order)
+    else:
+        # the held picks are the first sorted rows: windows of ``cap`` rows
+        # are worked through until they are exhausted (one, nearly always;
+        # every one if every pick of the batch fell here), under a static
+        # count so that the loop is reverse-differentiable
+        windows = -(-n * k // cap)
+        with scope("moe.dispatch"):
+            held_count = jnp.sum(sorted_group < E, dtype=jnp.int32)
+            pad = windows * cap - n * k
+            sorted_group = jnp.pad(sorted_group, (0, pad), constant_values=E)
+            order = jnp.pad(order, (0, pad))
+
+        def step(w, y):
+            group, at = (lax.dynamic_slice_in_dim(rows, w * cap, cap)
+                         for rows in (sorted_group, order))
+            return lax.cond(w * cap < held_count,
+                            lambda y: window(group, at, y), lambda y: y, y)
+
+        y = lax.fori_loop(0, windows, step,
+                          jnp.zeros((n, D), jnp.float32))
     with scope("moe.combine"):
-        out = out[slot].reshape(n, k, D).astype(jnp.float32)
-        y = jnp.sum(out * gate[:, :, None], axis=1)
         if not zero:
             y = y.astype(cdt)
     if zero:

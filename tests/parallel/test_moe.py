@@ -146,10 +146,12 @@ def _routed_params(rng, D, F, E, gated, skewed):
     return {k: v.astype(np.float32) for k, v in p.items()}
 
 
-def _routed_oracle(x, p, k, gated):
+def _routed_oracle(x, p, k, gated, held=None, zero=0):
     """numpy, float64, a loop over experts: every token's k most probable
-    experts, each weighted by its probability as it is; returns the layer's
-    output and the rows each expert got."""
+    outputs of the router, each weighted by its probability as it is;
+    returns the layer's output and the rows each expert got.  ``held``
+    (first, count): the loop is over those experts alone, whose matrices
+    ``p`` holds.  ``zero``: the router's last outputs add the token itself."""
     D = x.shape[-1]
     xf = x.reshape(-1, D).astype(np.float64)
     p = {name: w.astype(np.float64) for name, w in p.items()}
@@ -158,85 +160,112 @@ def _routed_oracle(x, p, k, gated):
     probs /= probs.sum(-1, keepdims=True)
     best = np.argsort(-probs, axis=-1, kind="stable")[:, :k]
     y, rows = np.zeros_like(xf), []
+    first = held[0] if held else 0
     for e in range(p["w1"].shape[0]):
-        mine = np.where((best == e).any(axis=-1))[0]
+        mine = np.where((best == first + e).any(axis=-1))[0]
         rows.append(len(mine))
         h = xf[mine] @ p["w1"][e]
         if gated:
             h = h / (1 + np.exp(-h)) * (xf[mine] @ p["w3"][e])
         else:
             h = _gelu_tanh(h)
-        y[mine] += probs[mine, e][:, None] * (h @ p["w2"][e])
+        y[mine] += probs[mine, first + e][:, None] * (h @ p["w2"][e])
+    for e in range(probs.shape[-1] - zero, probs.shape[-1]):
+        mine = np.where((best == e).any(axis=-1))[0]
+        y[mine] += probs[mine, e][:, None] * xf[mine]
     return y.reshape(x.shape), rows
 
 
-def _routed_jnp(x, p, k, gated):
+def _routed_jnp(x, p, k, gated, held=None, zero=0):
     """The oracle again in jax.numpy (every expert on every token, under a
     mask), for its gradient."""
     xf = x.reshape(-1, x.shape[-1])
     probs = jax.nn.softmax(xf @ p["wg"], axis=-1)
     kth = jnp.sort(probs, axis=-1)[:, -k, None]
     weight = jnp.where(probs >= kth, probs, 0.0)
+    first, count = held or (0, p["w1"].shape[0])
     h = jnp.einsum("td,edf->etf", xf, p["w1"])
     if gated:
         h = jax.nn.silu(h) * jnp.einsum("td,edf->etf", xf, p["w3"])
     else:
         h = jax.nn.gelu(h)
-    y = jnp.einsum("etf,efd,te->td", h, p["w2"], weight)
+    y = jnp.einsum("etf,efd,te->td", h, p["w2"],
+                   weight[:, first:first + count])
+    if zero:
+        y = y + jnp.sum(weight[:, -zero:], axis=-1, keepdims=True) * xf
     return y.reshape(x.shape)
 
 
-ROUTED = [pytest.param(8, 2, True, id="8x2-gated"),
-          pytest.param(8, 2, False, id="8x2-gelu"),
-          pytest.param(4, 1, False, id="4x1-gelu"),
-          pytest.param(16, 4, True, id="16x4-gated"),
-          pytest.param(64, 8, True, id="64x8-gated")]
+ROUTED = [pytest.param(8, 2, True, None, 0, id="8x2-gated"),
+          pytest.param(8, 2, False, None, 0, id="8x2-gelu"),
+          pytest.param(4, 1, False, None, 0, id="4x1-gelu"),
+          pytest.param(16, 4, True, None, 0, id="16x4-gated"),
+          pytest.param(64, 8, True, None, 0, id="64x8-gated")]
+# a device that holds 2 of a router's 64 outputs (16 of them identity
+# experts): few enough of a wide router that ``routed_moe`` works through
+# windows of the held picks (32 rows a window of the 384 routed)
+WINDOWED = [pytest.param(64, 8, True, (0, 2), 0, id="64x8-held2"),
+            pytest.param(64, 8, True, (0, 2), 16, id="64x8-held2-zero16")]
 
 
-def _routed_case(E, gated, skewed, seed):
+def _routed_case(E, gated, skewed, seed, held=None, zero=0):
     rng = np.random.default_rng(seed)
     B, T, D, F = 2, 24, 32, 16
     x = rng.normal(size=(B, T, D)).astype(np.float32)
     if skewed:
         x[..., 0] = 3.0
-    return x, _routed_params(rng, D, F, E, gated, skewed)
+    p = _routed_params(rng, D, F, E, gated, skewed)
+    if held:    # the router stays E wide; the matrices are the held experts'
+        p = {name: w if name == "wg" else w[held[0]:held[0] + held[1]]
+             for name, w in p.items()}
+    return x, p
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
 @pytest.mark.parametrize("skewed", [False, True], ids=["spread", "skewed"])
-@pytest.mark.parametrize("E,k,gated", ROUTED)
-def test_routed_moe_equals_a_loop_over_experts(E, k, gated, skewed, kernel):
+@pytest.mark.parametrize("E,k,gated,held,zero", ROUTED + WINDOWED)
+def test_routed_moe_equals_a_loop_over_experts(E, k, gated, held, zero,
+                                               skewed, kernel):
     """No capacity: with a router that sends every token to expert 0 first,
     that expert gets all 48 rows (three and more tiles of 16) while others
     get none, and every token still has all k of its experts' results and
     nothing of the rows that fill a tile up.  Through the pallas kernel (in
     interpret mode here) and through XLA's ragged_dot, which the model
-    runs off the TPU."""
-    from ompi_tpu.parallel.moe import routed_moe
+    runs off the TPU.  In windows too: the held picks of a spread router
+    fit the first window of 32 rows; skewed, expert 0's 48 rows overflow
+    it, a second window runs, and no pick is dropped."""
+    from ompi_tpu.parallel.moe import _window_rows, routed_moe
 
-    x, p = _routed_case(E, gated, skewed, seed=E + k)
-    want, rows = _routed_oracle(x, p, k, gated)
-    assert sum(rows) == x.shape[0] * x.shape[1] * k     # nothing dropped
+    x, p = _routed_case(E, gated, skewed, seed=E + k, held=held, zero=zero)
+    want, rows = _routed_oracle(x, p, k, gated, held, zero)
+    n = x.shape[0] * x.shape[1]
+    if held is None:
+        assert sum(rows) == n * k                       # nothing dropped
+        assert _window_rows(n * k, 16, E, E) == n * k   # the whole layout
+    else:
+        cap = _window_rows(n * k, 16, held[1], E)
+        assert cap == 32 and (sum(rows) > cap) == skewed
     if skewed:
-        assert rows[0] == x.shape[0] * x.shape[1] and min(rows) < 16
-    got = jax.jit(lambda a, q: routed_moe(a, q, k, gated=gated,
-                                          kernel=kernel))(x, p)
+        assert rows[0] == n and min(rows) < 16
+    got = jax.jit(lambda a, q: routed_moe(
+        a, q, k, gated=gated, kernel=kernel, held=held, zero=zero))(x, p)
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-5)
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
-@pytest.mark.parametrize("E,k,gated", ROUTED[:4])
-def test_routed_moe_gradient(E, k, gated, kernel):
+@pytest.mark.parametrize("E,k,gated,held,zero", ROUTED[:4] + WINDOWED)
+def test_routed_moe_gradient(E, k, gated, held, zero, kernel):
     """Through the kernel's custom_vjp, the gathers and the float32 router,
-    against the gradient of the masked dense form."""
+    against the gradient of the masked dense form; and, in windows (the
+    skewed router overflows the first), through the loop over them."""
     from ompi_tpu.parallel.moe import routed_moe
 
-    x, p = _routed_case(E, gated, skewed=True, seed=7)
+    x, p = _routed_case(E, gated, skewed=True, seed=7, held=held, zero=zero)
     target = np.random.default_rng(1).normal(size=x.shape).astype(np.float32)
 
     def loss(layer, **how):
-        return lambda a, q: ((layer(a, q, k, gated, **how) - target) ** 2
-                             ).sum()
+        return lambda a, q: ((layer(a, q, k, gated, held=held, zero=zero,
+                                    **how) - target) ** 2).sum()
 
     got = jax.jit(jax.grad(loss(routed_moe, kernel=kernel),
                            argnums=(0, 1)))(x, p)
@@ -246,6 +275,35 @@ def test_routed_moe_gradient(E, k, gated, kernel):
         assert np.abs(np.asarray(w)).max() > 0
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3,
                                    atol=2e-4 * np.abs(np.asarray(w)).max())
+
+
+@pytest.mark.parametrize("held,windows", [
+    pytest.param(None, 0, id="every-expert"),
+    pytest.param((0, 32), 0, id="half"),        # Kimi-Linear's share
+    pytest.param((0, 2), 12, id="2-of-64")])
+def test_routed_moe_takes_windows_where_few_of_a_wide_router_are_held(
+        held, windows):
+    """The form is read off static shapes: a device that holds every expert,
+    or half of them, lowers to the whole layout (its three grouped calls
+    take ``n k + E tm`` rows, under no branch and no loop but
+    ``searchsorted``'s own); one that holds 2 of 64 to a loop over windows
+    of ``cap + E tm`` rows with the window's body under a branch."""
+    from ompi_tpu.parallel.moe import routed_moe
+
+    E, k, tm = 64, 8, 16
+    x, p = _routed_case(E, True, False, seed=3, held=held)
+    text = jax.jit(lambda a, q: routed_moe(a, q, k, gated=True, held=held)
+                   ).lower(x, p).as_text()
+    count = held[1] if held else E
+    laid_out = (32 if windows else x.shape[0] * x.shape[1] * k) + count * tm
+    # off the TPU a grouped call is ``ragged_dot``, lowered here to one
+    # product of every held expert's matrix with every laid-out row
+    calls = [line for line in text.splitlines()
+             if "stablehlo.dot_general" in line and "HIGHEST" not in line]
+    assert len(calls) == 3
+    assert all(f"tensor<{count}x{laid_out}x" in line for line in calls)
+    assert text.count("stablehlo.case") == (1 if windows else 0)
+    assert text.count("stablehlo.while") == (2 if windows else 1)
 
 
 def test_routed_moe_refuses_ep():

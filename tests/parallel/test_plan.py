@@ -27,6 +27,7 @@ from benchmarks.lib import cells, program
 from ompi_tpu.models import kda, mla, plan
 from ompi_tpu.models import transformer as tfm
 from ompi_tpu.models.decode import make_decoder
+from ompi_tpu.parallel import moe
 from ompi_tpu.parallel.mesh import make_mesh
 from ompi_tpu.parallel.moe import routed_moe
 
@@ -295,60 +296,73 @@ def test_the_shares_of_two_devices_and_the_shared_expert_are_the_uncut_layer():
     assert error(x + got, uncut) < PARITY
 
 
-def test_four_shares_of_two_and_the_identity_part_once_are_the_uncut_layer():
+@pytest.mark.parametrize("experts,identity,tokens,windows", [
+    pytest.param(8, 4, (2, 9), False, id="4-shares-of-12-outputs"),
+    pytest.param(8, 16, (4, 32), True, id="4-shares-of-24-outputs")])
+def test_the_shares_of_two_and_the_identity_part_once_are_the_uncut_layer(
+        experts, identity, tokens, windows):
     """LongCat-Flash's router at a small size: 8 experts and 4 identity
     experts, 3 a token, shared by 4 devices of 2 experts each.  The four
     shares' routed parts, and what every device adds alike for its own
     tokens (the identity picks' part) once, are the uncut ``MoE(x)``; and a
-    shortcut-connected layer built from them is the uncut layer."""
+    shortcut-connected layer built from them is the uncut layer.  And at a
+    router 24 wide, 16 of its outputs identity experts, where every share
+    works through windows of its held picks (``moe._window_rows``)."""
     cell = "longcat-flash-chat.decode-896-128-b160"
     config = copy.deepcopy(program.tiny(cells.resolve(cell).config))
     config["entry"]["options"]["compute_dtype"] = "float32"
-    config.update(num_layers=1, router_experts=8, zero_expert_num=4,
-                  n_routed_experts=8, experts_held={"first": 0, "count": 8})
+    config.update(num_layers=1, router_experts=experts,
+                  zero_expert_num=identity, n_routed_experts=experts,
+                  experts_held={"first": 0, "count": experts})
     ref = program.reference(config)
     shape, cfg = ref.Shape.from_config(config), program.program_config(config)
-    assert (cfg.moe_experts, cfg.moe_zero, cfg.moe_top_k) == (12, 4, 3)
+    assert (cfg.moe_experts, cfg.moe_zero, cfg.moe_top_k) == (
+        experts + identity, identity, 3)
+    picks = tokens[0] * tokens[1] * 3
+    assert (moe._window_rows(picks, 16, 2, cfg.moe_experts) < picks) == windows
     mesh = program.mesh(config, jax.devices()[:1])
     params = program.init_params(
         ref, config, program.param_shardings(config, cfg, mesh), seed=13)
     x = jnp.asarray(np.random.default_rng(14).normal(
-        size=(2, 9, cfg.d_model)), jnp.float32)
+        size=(*tokens, cfg.d_model)), jnp.float32)
     whole, weight = ref.moe(shape, params, 0, x)
-    assert (np.asarray(weight[..., 8:]) > 0).any()      # identity picks
+    assert (np.asarray(weight[..., experts:]) > 0).any()    # identity picks
     router = {"wg": params["wg"][0], "wgb": params["wgb"][0]}
+    firsts = range(0, experts, 2)
 
     def share(first, zero):
         """Device ``first // 2``'s routed part (``zero`` 0: the router's
-        last four outputs are experts held elsewhere), or with the identity
+        last outputs are experts held elsewhere), or with the identity
         part."""
         held = {k: params[k][:, first:first + 2] for k in ("w1", "w3", "w2")}
         return routed_moe(x, {**router, **held}, 3, gated=True, layer=0,
                           score="softmax", scale=cfg.moe_scale,
                           held=(first, 2), zero=zero)
 
-    routed = [share(first, 0) for first in (0, 2, 4, 6)]
-    identity = share(0, 4) - routed[0]
-    assert error(sum(routed) + identity, whole) < PARITY
+    routed = [share(first, 0) for first in firsts]
+    identity_part = share(0, identity) - routed[0]
+    assert error(sum(routed) + identity_part, whole) < PARITY
     assert all(float(jnp.abs(part).max()) > 0.01
-               for part in (*routed, identity))
+               for part in (*routed, identity_part))
     # the reference's own shares say the same
     parts = [ref.moe(shape, {**params, **{
         k: params[k][:, first:first + 2] for k in ("w1", "w3", "w2")}}, 0, x,
-        (first, 2), False)[0] for first in (0, 2, 4, 6)]
+        (first, 2), False)[0] for first in firsts]
     for got, want in zip(routed, parts):
         assert error(got, want) < PARITY
     only = ref.moe(shape, params, 0, x, (0, 0), True)[0]
     assert error(sum(parts) + only, whole) < PARITY
+    if windows:     # the layer below is built from the same function
+        return
     # the layer: a branch lands by an add, so a layer built from the shares
     # is the uncut layer where the shares add up to ``MoE(x)`` and the uncut
     # program is the uncut reference (a chip's own share through the whole
     # model is ``tests/parallel/test_shortcut_plan.py``'s)
-    tokens = np.random.default_rng(15).integers(
+    ids = np.random.default_rng(15).integers(
         0, cfg.vocab, size=(2, 9)).astype(np.int32)
     uncut = jax.jit(lambda p: plan.backbone(
-        cfg, tfm._mesh_comm(mesh), p, tokens)[0])(params)
-    assert error(uncut, ref.forward(shape, params, tokens)) < PARITY
+        cfg, tfm._mesh_comm(mesh), p, ids)[0])(params)
+    assert error(uncut, ref.forward(shape, params, ids)) < PARITY
 
 
 def test_picks_held_elsewhere_add_nothing_and_are_not_renormalised_away():
