@@ -64,6 +64,21 @@ if "jax" in sys.modules and os.environ.get("OMPI_TPU_TEST_REAL") != "1":
 import pytest  # noqa: E402
 
 
+def pytest_configure(config):
+    """The driver runs tier-1 as ``-p xdist -n 6 --dist loadfile``: a file is
+    one worker's from its first test to its last, and a worker takes the next
+    file of the queue when two or fewer of its tests are pending.  xdist
+    would queue the files by their number of tests, most first, and the
+    suite's longest file (``tests/benchmarks/test_fits.py``: a compile a
+    cell) has among the fewest: it started at minute ten and ran alone while
+    five workers idled.  Without the reorder the queue is the collection
+    order, which puts ``tests/benchmarks/`` and so the two longest files
+    first.  ``tools/tier1_time.py`` replays either queue over a run's junit
+    file.  A run without xdist has no such option."""
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
 @pytest.fixture(scope="session", autouse=True)
 def _pallas_tpu_interpret_mode():
     """The pallas kernels (ops/) carry no interpret selection of their own:

@@ -174,8 +174,8 @@ def _steps(cfg, lp, h):
 
 
 def test_a_step_through_the_kernel_is_the_next_position(monkeypatch):
-    """As ``test_plan.py``'s of the same name but for the heads' width, and
-    with the kernel where the ``jax.numpy`` form ran."""
+    """As ``test_plan_mixers.py``'s of a like name but for the heads' width,
+    and with the kernel where the ``jax.numpy`` form ran."""
     _ref, _shape, cfg, _mesh, _params = tiny()
     cfg = _wide(cfg)
     lp = _wide_leaves(cfg)
@@ -263,7 +263,7 @@ def test_the_rule_takes_the_most_heads_that_fit():
 
 def test_off_the_tpu_update_is_the_jnp_form():
     """On this box nothing is traced for TPUs, under a mesh or under none:
-    ``update`` calls no kernel (tier-1's decoders and ``test_plan.py`` run
+    ``update`` calls no kernel (tier-1's decoders and ``test_plan*.py`` run
     the form they ran)."""
     from jax.sharding import PartitionSpec as P
 
