@@ -21,9 +21,10 @@ as the scores read it (rotated, where the form rotates), ``kv_rank + rope``
 elements for all heads.  The whole-sequence form (trainer, prefill)
 multiplies a head's keys and values out of the latent, the cheaper order at
 T rows a sequence; its attention is ``jax.numpy`` over a sequence's whole (T,
-T) scores up to ``KERNEL_FROM`` positions and anywhere but on TPUs, and from
+T) scores anywhere but on TPUs and under ``KERNEL_FROM`` positions, and from
 there on the pallas ``ops/latent_attention.py``, which holds a tile of them
-(forward only: a trainer of such lengths is not built).  The cached step
+and cuts the tile to the length (its backward pass is the ``jax.numpy``
+form's, so a loss takes the same rule).  The cached step
 absorbs: ``q_lat = q_n W^K`` (heads x kv_rank), scores ``q_lat . c_s + q_r .
 k_r,s``, the context ``sum_s p_s c_s`` (heads x kv_rank) through ``W^V``: it
 reads the latent for all heads at once and never multiplies a cached
@@ -45,9 +46,14 @@ __all__ = ["MLA", "mixer", "rotate", "leaf_shapes", "buffers", "POSITIONED",
            "KERNEL_FROM"]
 
 POSITIONED = True       # a cached step's carry ends with its position
-# sequences from this many positions on take the kernel where it tiles, as
-# ``parallel/attention.local_impl`` takes its own from 2048 keys a device
-KERNEL_FROM = 2048
+# Whole sequences from this many positions on take the kernel where it tiles:
+# the shortest length at which it was measured against the ``jax.numpy`` form
+# inside a cell's own prefill, and it won at every one (the ``attention``
+# scope of a prefill on a v5e, PERF.md section 6, PR 61: 8 x 512 positions of
+# 32 heads 76.5 -> 19.0 ms, 4 x 896 of 64 heads 1812 -> 268 ms; at 16,128
+# positions the form's scores do not fit).  Under it nothing is measured and
+# no cell runs, so the form that needs no kernel stays.
+KERNEL_FROM = 512
 
 
 @dataclasses.dataclass(frozen=True)
@@ -193,19 +199,11 @@ def mixer(cfg, lp, h, carry=None):
         with _proj(ml):
             kv = jnp.einsum("btr,rhf->bthf", lat[..., :R], wkv)
         with scope("attention"):
-            if (T >= KERNEL_FROM and _traced_for_tpus()
-                    and latent_attention.tiles(T, N, W)):
-                o = latent_attention.latent_attention(q, kv, lat[..., R:],
-                                                      scale)
-            else:
-                s = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :N], kv[..., :N],
-                                preferred_element_type=f32)
-                     + jnp.einsum("bqhd,bkd->bhqk", q[..., N:], lat[..., R:],
-                                  preferred_element_type=f32)) * scale
-                causal = jnp.tril(jnp.ones((T, T), bool))
-                w = jax.nn.softmax(jnp.where(causal, s, -1e30), axis=-1)
-                o = jnp.einsum("bhqk,bkhd->bqhd", w.astype(cdt), kv[..., N:],
-                               preferred_element_type=f32)
+            form = (latent_attention.latent_attention
+                    if (T >= KERNEL_FROM and _traced_for_tpus()
+                        and latent_attention.tiles(T, H, N, P, W))
+                    else latent_attention.jnp_form)
+            o = form(q, kv, lat[..., R:], scale)
         out = lat
     else:
         lat_c, pos = carry

@@ -10,9 +10,10 @@ Callers that want the pure-XLA form ask for it by name
 Which form a program takes is its model's rule from static facts, never a
 kernel's: a kernel says what it tiles (``tiles``, ``block``) and refuses the
 rest.  The latent mixer (``models/mla.py``) takes ``latent_attention`` for a
-prefill from 2048 positions and ``latent_decode`` for a cached step over a
-cache of whole blocks of 1024 positions, both only in a trace for TPUs; the
-``jax.numpy`` forms anywhere else.
+prefill from the length where it was measured to win (``mla.KERNEL_FROM``)
+and ``latent_decode`` for a cached step over a cache of whole blocks of 1024
+positions, both only in a trace for TPUs; the ``jax.numpy`` forms anywhere
+else.
 """
 
 from ompi_tpu.ops.flash_attention import flash_attention, flash_attention_lse

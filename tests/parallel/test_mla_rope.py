@@ -4,23 +4,29 @@ reference, ``benchmarks/reference/kimi_vl.py``, at the configuration's tiny
 sizes, float32, seeded, on the CPU: prefill then cached steps against the full
 forward on logits position by position, the rotation at positions past the
 first, what the carry holds, the loss, the kernel against the ``jax.numpy``
-form, what the ``entry.config`` refuses, and the NoPE form left as it was.
+form by length and tile, the mixer's rule and its gradient through the
+kernel, what the ``entry.config`` refuses, and the NoPE form left as it was.
 Agreement only: nothing here is a time.
 """
 
 import copy
 import dataclasses
+import os
+import re
 
-import jax
-import jax.numpy as jnp
-import numpy as np
-import pytest
+os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
 
-from benchmarks.lib import cells, program
-from ompi_tpu.models import kda, mla, plan
-from ompi_tpu.models import transformer as tfm
-from ompi_tpu.models.decode import make_decoder
-from ompi_tpu.ops import latent_attention
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+
+from benchmarks.lib import cells, program  # noqa: E402
+from ompi_tpu.models import kda, mla, plan  # noqa: E402
+from ompi_tpu.models import transformer as tfm  # noqa: E402
+from ompi_tpu.models.decode import make_decoder  # noqa: E402
+from ompi_tpu.ops import latent_attention  # noqa: E402
+from tests.parallel.test_kda_update import _pallas_calls  # noqa: E402
 
 CELL = "kimi-vl-a3b.decode-16k-256-b32"
 NOPE_CELL = "kimi-linear-48b-a3b.decode-512-128-b384"
@@ -157,57 +163,222 @@ def test_the_carry_holds_the_rotated_shared_key():
         kva[..., :R], params["mla_n"][layer], shape.eps), atol=1e-5)
 
 
-@pytest.mark.parametrize("T", [300, 1100])
-def test_the_kernel_is_the_jnp_attention(T):
-    """One tile, and three with two visited below the diagonal; positions
-    that are no multiple of a tile."""
-    B, H, N, P, W = 1, 2, 128, 64, 128
+def _operands(T, B=1, H=2, dtype=jnp.float32):
+    N, P, W = 128, 64, 128
     keys = jax.random.split(jax.random.key(T), 3)
-    q = jax.random.normal(keys[0], (B, T, H, N + P), jnp.float32)
-    kv = jax.random.normal(keys[1], (B, T, H, N + W), jnp.float32)
-    k_r = jax.random.normal(keys[2], (B, T, P), jnp.float32)
-    scale = (N + P) ** -0.5
-    s = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :N], kv[..., :N])
-         + jnp.einsum("bqhd,bkd->bhqk", q[..., N:], k_r)) * scale
-    w = jax.nn.softmax(jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30),
-                       axis=-1)
-    want = jnp.einsum("bhqk,bkhd->bqhd", w, kv[..., N:])
-    got = jax.jit(lambda *a: latent_attention.latent_attention(*a, scale))(
-        q, kv, k_r)
+    return (jax.random.normal(keys[0], (B, T, H, N + P), dtype),
+            jax.random.normal(keys[1], (B, T, H, N + W), dtype),
+            jax.random.normal(keys[2], (B, T, P), dtype), (N + P) ** -0.5)
+
+
+def _kernel_call(q, kv, k_r, scale, rows=None):
+    """(grid, block shapes, stated VMEM) of the one kernel call traced for
+    these operands (shapes are enough)."""
+    (eqn,) = _pallas_calls(jax.make_jaxpr(
+        lambda *a: latent_attention.latent_attention(*a, scale, rows))(
+            q, kv, k_r).jaxpr)
+    mapping = eqn.params["grid_mapping"]
+    return (mapping.grid,
+            [tuple(getattr(d, "block_size", d) for d in m.block_shape)
+             for m in mapping.block_mappings],
+            eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes)
+
+
+# (positions, (rows of q, rows of k) or None for the rule's): one tile and
+# three with two visited below the diagonal, positions that are no multiple
+# of a tile (300, 1100, 200: padded); cell 11's 896 as the rule takes them
+# (one tile of queries, seven slabs, no padding), as square tiles of 128
+# (seven a head, none padded) and of 256 (padded to 1024); 1024 in square
+# tiles of 512
+@pytest.mark.parametrize("T,rows", [
+    (300, None), (1100, None), (896, None), (896, (128, 128)),
+    (896, (256, 256)), (200, None), (1024, (512, 512)), (1024, (512, 256))])
+def test_the_kernel_is_the_jnp_attention(T, rows):
+    q, kv, k_r, scale = _operands(T)
+    want = latent_attention.jnp_form(q, kv, k_r, scale)
+    got = jax.jit(lambda *a: latent_attention.latent_attention(
+        *a, scale, rows))(q, kv, k_r)
     assert got.shape == want.shape and error(got, want) < 1e-5
-    assert latent_attention.tiles(T, N, W)
-    assert not latent_attention.tiles(T, 64, W)
-    assert not latent_attention.tiles(latent_attention.MAX_ROWS + 1, N, W)
+    rows_q, rows_k = rows or latent_attention.tile(T)
+    grid, blocks, _limit = _kernel_call(q, kv, k_r, scale, rows)
+    padded = -(-T // rows_q) * rows_q
+    assert grid == (1, 2, padded // rows_q)
+    assert blocks[0] == (1, rows_q, 128) and blocks[2] == (1, padded, 128)
+    assert (padded == T) == (T % 128 == 0 and T % rows_q == 0)
+
+
+def test_what_the_kernel_refuses():
+    q, kv, k_r, scale = _operands(300)
+    assert latent_attention.tiles(300, 2, 128, 64, 128)
+    assert not latent_attention.tiles(300, 2, 64, 64, 128)
+    assert not latent_attention.tiles(300, 3, 128, 64, 128)     # a head alone
+    assert latent_attention.tiles(300, 3, 128, 128, 128)
+    assert not latent_attention.tiles(300, 2, 128, 48, 128)
+    assert not latent_attention.tiles(latent_attention.MAX_ROWS + 1, 2, 128,
+                                      64, 128)
     with pytest.raises(ValueError, match="do not tile"):
         latent_attention.latent_attention(q[..., :100], kv, k_r, scale)
+    for rows in ((512, 384), (256, 64), (128, 256)):
+        with pytest.raises(ValueError, match="a tile of"):
+            latent_attention.latent_attention(q, kv, k_r, scale, rows)
 
 
-def test_the_mixer_takes_the_kernel_on_tpus_from_kernel_from_on(monkeypatch):
-    """Heads that tile, told that the trace is for TPUs: the whole-sequence
-    mixer through the kernel is the mixer through ``jax.numpy``, and the rows
-    it hands on are the same."""
-    ml = mla.MLA(n_heads=2, nope=128, rope=64, v_dim=128, kv_rank=48,
+def test_the_tile_and_the_stated_vmem_follow_the_length():
+    """896 positions are one tile of queries whose keys go by 128, unpadded;
+    a length that is no multiple of 128 is padded to one; past 1024 positions
+    square tiles of 512.  A short call states what its blocks count (under
+    what the compiler gives unasked); cell 10's 16,128 positions state what
+    they did, on the grid and the blocks they had."""
+    assert latent_attention.tile(896) == (896, 128)
+    assert latent_attention.tile(512) == (512, 128)
+    assert latent_attention.tile(200) == (256, 128)
+    assert latent_attention.tile(1024) == (1024, 128)
+    assert latent_attention.tile(1025) == (512, 512)
+    assert latent_attention.tile(16_128) == (512, 512)
+    short = latent_attention.vmem_limit(
+        1024, *latent_attention.tile(1024), 128, 64, 128)
+    assert 4 << 20 < short < 16 << 20
+    assert latent_attention.vmem_limit(1024, 512, 512, 128, 64, 128) < 16 << 20
+    assert latent_attention.vmem_limit(16_384, 512, 512, 128, 64,
+                                       128) == 96 << 20
+    bf16 = jnp.bfloat16
+    cell_10 = (jax.ShapeDtypeStruct((1, 16_128, 16, 192), bf16),
+               jax.ShapeDtypeStruct((1, 16_128, 16, 256), bf16),
+               jax.ShapeDtypeStruct((1, 16_128, 64), bf16), 192 ** -0.5)
+    assert _kernel_call(*cell_10) == (
+        (1, 16, 32),
+        [(1, 512, 128), (1, 512, 128), (1, 16_384, 128), (1, 16_384, 128),
+         (1, 16_384, 128), (1, 512, 128)], 96 << 20)
+    cell_11 = (jax.ShapeDtypeStruct((4, 896, 64, 192), bf16),
+               jax.ShapeDtypeStruct((4, 896, 64, 256), bf16),
+               jax.ShapeDtypeStruct((4, 896, 64), bf16), 192 ** -0.5)
+    grid, blocks, limit = _kernel_call(*cell_11)
+    assert grid == (4, 64, 1) and blocks[:2] == [(1, 896, 128)] * 2
+    assert blocks[2] == (1, 896, 128) and limit < 16 << 20
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        return list(topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices)
+    except Exception as e:      # no libtpu here: nothing to lower for
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+
+
+def test_cell_10s_prefill_holds_the_kernel_call_it_had(v5e):
+    """The cell that ran the kernel before any other did: its prefill at the
+    real sizes, lowered for the described chip, holds one kernel function
+    for its five call sites, over 16,384 padded positions, the keys, values
+    and output where they were and 96 MiB of VMEM stated (the grid and the
+    blocks: ``test_the_tile_and_the_stated_vmem_follow_the_length``).  Its
+    text is not the parent's: since PR 61 the queries are read as they lie
+    and not transposed to the heads, which took 0.39 s off this cell's
+    prefill too (PERF.md section 6, PR 61)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    cell = cells.resolve(CELL)
+    job = cell.runner.build(cell.config, cell.traffic, v5e[:cell.chips])
+    fn, args = job.programs()["decode_first"]
+    with pltpu.force_tpu_interpret_mode(None):   # for the chip, not the suite
+        text = fn.lower(*args).as_text()
+    (call,) = [line for line in text.splitlines()
+               if 'kernel_name = "latent_attention"' in line]
+    kinds = re.search(r" : \((.*)\) -> (tensor<\S+>)$", call)
+    assert kinds.group(1).split(", ") == [
+        "tensor<1x16384x2048xbf16>", "tensor<1x16384x1024xbf16>",
+        "tensor<1x16384x4096xbf16>", "tensor<1x16384x128xbf16>",
+        "tensor<1x16384x4096xbf16>"]
+    assert kinds.group(2) == "tensor<1x16384x2048xbf16>"
+    assert re.search(r'scoped_memory_configs[^]]*\\22size\\22: 100663296',
+                     call)
+
+
+def _latent_layer(H, dtype="float32"):
+    """(config, one layer's leaves) of a latent layer of ``H`` heads that
+    tile (128 + 64 for the scores, 128 for the values) over a small latent
+    and a small stream."""
+    ml = mla.MLA(n_heads=H, nope=128, rope=64, v_dim=128, kv_rank=48,
                  theta=800_000.0)
     cfg = tfm.TransformerConfig(
-        vocab=64, d_model=32, n_heads=2, n_layers=1, d_ff=64, norm_eps=1e-5,
-        compute_dtype="float32", plan=plan.LayerPlan(
+        vocab=64, d_model=32, n_heads=H, n_layers=1, d_ff=64, norm_eps=1e-5,
+        compute_dtype=dtype, plan=plan.LayerPlan(
             layers=(("mla", "dense"),), mla=ml))
     rng = np.random.default_rng(4)
     lp = {"ln1": jnp.ones((32,)), **{
         name: jnp.asarray(rng.normal(0, std or 1, size=dims), jnp.float32)
         if std else jnp.ones(dims)
         for name, (dims, std) in mla.leaf_shapes(cfg, ml).items()}}
-    h = jnp.asarray(rng.normal(size=(2, 200, 32)), jnp.float32)
-    want, want_rows = jax.jit(lambda lp, h: mla.mixer(cfg, lp, h))(lp, h)
+    return cfg, lp
+
+
+# the three cells' whole-sequence attentions: cell 11's prefill pass, cell
+# 7's, and cell 10's (whose 16,128 positions are only traced here: the
+# interpreter would take minutes over them, and the jnp form 17 GB)
+@pytest.mark.parametrize("T,H,run", [(896, 64, True), (512, 32, True),
+                                     (16_128, 16, False)])
+def test_the_mixer_takes_the_kernel_by_the_rule(monkeypatch, T, H, run):
+    """Heads that tile: the whole-sequence mixer traced for TPUs holds the
+    kernel from ``KERNEL_FROM`` positions on, on the tile the length gives,
+    and the ``jax.numpy`` form below it and in a trace for anything else;
+    through the kernel it is the mixer through ``jax.numpy``, and the rows it
+    hands on are the same."""
+    cfg, lp = _latent_layer(H)
+    h = jax.ShapeDtypeStruct((1, T, 32), jnp.float32)
+    traced = lambda T=T: jax.make_jaxpr(                        # noqa: E731
+        lambda lp, h: mla.mixer(cfg, lp, h))(
+            lp, jax.ShapeDtypeStruct((1, T, 32), jnp.float32)).jaxpr
+    assert not list(_pallas_calls(traced()))        # the CPU's trace
+    if run:
+        h = jnp.asarray(np.random.default_rng(T).normal(size=h.shape),
+                        jnp.float32)
+        want, want_rows = jax.jit(lambda lp, h: mla.mixer(cfg, lp, h))(lp, h)
     monkeypatch.setattr(kda, "_traced_for_tpus", lambda: True)
-    monkeypatch.setattr(mla, "KERNEL_FROM", 128)
-    traced = jax.jit(lambda lp, h: mla.mixer(cfg, lp, h))
-    assert "latent_attention" in str(traced.trace(lp, h).jaxpr)
-    got, got_rows = traced(lp, h)
-    assert error(got, want) < 1e-5 and error(got_rows, want_rows) < 1e-6
-    monkeypatch.setattr(mla, "KERNEL_FROM", 2048)       # the cell's rule
-    assert "latent_attention" not in str(jax.jit(
-        lambda lp, h: mla.mixer(cfg, lp, h)).trace(lp, h).jaxpr)
+    assert T >= mla.KERNEL_FROM
+    (call,) = _pallas_calls(traced())
+    assert call.params["name"] == "latent_attention"
+    rows_q, _rows_k = latent_attention.tile(T)
+    assert call.params["grid_mapping"].grid == (1, H, -(-T // rows_q))
+    assert not list(_pallas_calls(traced(mla.KERNEL_FROM - 128)))
+    if run:
+        got, got_rows = jax.jit(lambda lp, h: mla.mixer(cfg, lp, h))(lp, h)
+        assert error(got, want) < 1e-5 and error(got_rows, want_rows) < 1e-6
+
+
+def test_the_gradient_through_the_kernel_is_the_jnp_forms(monkeypatch):
+    """The kernel has no backward pass of its own: ``jax.grad`` through the
+    mixer with the kernel engaged is the gradient through the ``jax.numpy``
+    form, for the stream and for every leaf (a loss over a latent
+    configuration on TPUs works at any length)."""
+    cfg, lp = _latent_layer(2)
+    T = max(mla.KERNEL_FROM, 256)
+    rng = np.random.default_rng(6)
+    h = jnp.asarray(rng.normal(size=(2, T, 32)), jnp.float32)
+    g = jnp.asarray(rng.normal(size=(2, T, 32)), jnp.float32)
+
+    def loss(lp, h):
+        out, rows = mla.mixer(cfg, lp, h)
+        return (out * g).sum() + rows.sum()
+
+    want = jax.jit(jax.grad(loss, (0, 1)))(lp, h)
+    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: True)
+    grad = jax.jit(jax.grad(loss, (0, 1)))
+    assert list(_pallas_calls(grad.trace(lp, h).jaxpr))
+    got = grad(lp, h)
+    for name in lp:
+        assert error(got[0][name], want[0][name]) < 1e-5, name
+    assert error(got[1], want[1]) < 1e-5
+    # the kernel alone, every operand's cotangent
+    q, kv, k_r, scale = _operands(256)
+    w = jnp.asarray(rng.normal(size=(1, 256, 2, 128)), jnp.float32)
+    for got, want in zip(*(jax.jit(jax.grad(
+            lambda q, kv, k_r, form=form: (form(q, kv, k_r, scale) * w).sum(),
+            (0, 1, 2)))(q, kv, k_r) for form in (
+                latent_attention.latent_attention,
+                latent_attention.jnp_form))):
+        assert error(got, want) < 1e-5
 
 
 def test_the_entry_config_refuses_what_is_not_built():
@@ -252,9 +423,9 @@ def test_the_nope_form_has_no_rotation_in_it():
     for of, has in ((nope, False), (cfg, True)):
         table = {k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in
                  tfm.init_params(of, 0).items()}
-        text = jax.jit(make_decoder(of, mesh, max_new=3)).lower(
-            table, jax.ShapeDtypeStruct((2, 8), jnp.int32)).as_text(
-                debug_info=True)
+        traced = jax.jit(make_decoder(of, mesh, max_new=3)).trace(
+            table, jax.ShapeDtypeStruct((2, 8), jnp.int32))
+        text = traced.lower().as_text(debug_info=True)
         assert ("mla_proj.rope/mla.rotate" in text) == has
         assert ("attn_proj/mla_proj/" in text) != has
-        assert "latent_attention" not in text
+        assert not list(_pallas_calls(traced.jaxpr))
