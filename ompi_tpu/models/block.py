@@ -13,6 +13,14 @@ through a slice the compiler fuses into the products; K/V heads may be fewer
 than query heads and are read once for the heads they serve.  With an index
 the K and V are ``models/sparse_index.py``'s to lay out.
 
+The same attention alone in a layer, ``h <- h + attention(RMSNorm(h; ln1))``,
+is the kind "attention" of a layer plan (``models/plan.py``): what the plan
+asks of a kind (``leaf_shapes``, ``buffers``, ``mixer`` with its residual add
+against the layer's own K and V) is under :data:`PLAN_KIND`, because this
+module's own ``mixer`` and ``carry`` are the stacked layer's; the plan's
+``LayerPlan.attention`` holds an :class:`Attention`, which says whether the
+queries and keys are rotated.
+
 A decoder needs two things of whatever a configuration carries, and every
 module that carries state gives them under the same names (:func:`mechanisms`
 lists the modules): ``carry(cfg, mesh, batch, t_max)``, the zeros of its
@@ -22,8 +30,20 @@ whole-sequence pass collected for it as those stacks hold it (:func:`written`).
 
 from __future__ import annotations
 
+import dataclasses
+import types
+
 __all__ = ["mixer", "block", "mechanisms", "carry", "carried", "written",
-           "check_mesh"]
+           "check_mesh", "Attention", "PLAN_KIND"]
+
+
+@dataclasses.dataclass(frozen=True)
+class Attention:
+    """The kind "attention" of a plan: the heads are the configuration's
+    (``n_heads`` over ``kv_heads`` of ``head_dim``, ``qk_norm``); ``rope``
+    False: no rotary embedding (NoPE), q and k go to the scores as they are
+    projected."""
+    rope: bool = True
 
 
 def mechanisms(cfg) -> tuple:
@@ -131,7 +151,8 @@ def mixer(cfg, comm, lp, h, positions, carry=None, impl: str = "jnp",
     would take a multiplier before it away), the rotary embedding in the
     form ``impl`` reads, attention (or, where the configuration has power
     retention, ``retention.core`` on the same q, k and v and the gate's
-    projection), ``wo``.  ``lp``: the layer's leaves.
+    projection), ``wo``.  No rotary embedding where a plan's
+    ``Attention.rope`` says so.  ``lp``: the layer's leaves.
     ``weights(x, *names)``, the train step's: ``(x, leaves)`` for the
     matmuls that read ``x`` (``transformer._local_backbone`` says what it
     ties to them); None: ``lp``'s own.
@@ -173,10 +194,13 @@ def mixer(cfg, comm, lp, h, positions, carry=None, impl: str = "jnp",
             k = tfm._qk_norm(cfg, k, lp["kn"], comm)
         if hy is not None:
             k = k * hy.key_multiplier
-        q = tfm._rope(q.reshape(B, T, hl, hd), positions, impl,
-                      cfg.rope_theta)
-        k = tfm._rope(k.reshape(B, T, hkv, hd), positions, impl,
-                      cfg.rope_theta)
+        rotates = cfg.plan is None or cfg.plan.attention.rope
+        q = q.reshape(B, T, hl, hd)
+        if rotates:
+            q = tfm._rope(q, positions, impl, cfg.rope_theta)
+        k = k.reshape(B, T, hkv, hd)
+        if rotates:
+            k = tfm._rope(k, positions, impl, cfg.rope_theta)
         v = v.reshape(B, T, hkv, hd)
         k_all, v_all = k, v
         if rt is not None:      # the decay a K/V head and position
@@ -276,3 +300,64 @@ def _block(cfg, comm, lp, h, positions, carry, **how):
     if hy is not None:      # the states as the carry stores them
         own += (states[0], states[1].astype(hy.state_dtype))
     return h, (aux, *own)
+
+
+# ---- attention alone in a layer: the kind "attention" of a plan -------------
+
+def _kind_leaf_shapes(cfg, at: Attention) -> dict:
+    """One layer's leaves: name -> (shape, deviation of the program's own
+    initializer or None for ones)."""
+    D = cfg.d_model
+    Dq, Dkv = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    leaves = {"wq": ((D, Dq), D ** -0.5), "wk": ((D, Dkv), D ** -0.5),
+              "wv": ((D, Dkv), D ** -0.5),
+              "wo": ((Dq, D), Dq ** -0.5 / max(1, 2 * cfg.n_layers) ** 0.5)}
+    if cfg.qk_norm:
+        per_head = cfg.qk_norm == "head"
+        leaves.update(qn=((cfg.head_dim if per_head else Dq,), None),
+                      kn=((cfg.head_dim if per_head else Dkv,), None))
+    return leaves
+
+
+def _kind_buffers(cfg, at: Attention, batch: int, t_max: int) -> tuple:
+    """What a decoder carries for one layer (``models/plan.py``'s form): K
+    and V ``(B, t_max, Hkv, hd)`` in the compute type, which grow along the
+    carry's axis 2."""
+    shape = (batch, t_max, cfg.kv_heads, cfg.head_dim)
+    return ((shape, cfg.compute_dtype, 2), (shape, cfg.compute_dtype, 2))
+
+
+def _kind_mixer(cfg, lp, h, carry=None, *, comm):
+    """One layer's mixer on the layer's input ``h`` (B, T, D): :func:`mixer`
+    and the residual add.
+
+    ``carry`` None: whole sequences at positions 0 to T - 1; returns ``(h, k,
+    v)``, every position's K and V ``(B, T, Hkv, hd)``.  ``carry = (kc, vc,
+    pos)``: T == 1, position ``pos`` against this layer's own K and V ``(B,
+    Tmax, Hkv, hd)``, a stack of one layer to :func:`mixer`; returns ``(h,
+    kc, vc)``."""
+    import jax.numpy as jnp
+
+    from ompi_tpu.core.scopes import scope
+    from ompi_tpu.models import transformer as tfm
+    from ompi_tpu.parallel import attention as attn_mod
+
+    if carry is None:
+        B, T = h.shape[:2]
+        shape = (B, T, cfg.n_heads, cfg.head_dim)
+        impl = attn_mod.layout_impl(
+            comm, tfm._ATTENTION_LAYOUT.get(cfg.attention, "gathered"),
+            shape, shape, h.dtype, "sp")
+        a, _x, own = mixer(cfg, comm, lp, h, jnp.arange(T), impl=impl)
+    else:
+        kc, vc, pos = carry
+        a, _x, own = mixer(cfg, comm, lp, h, pos[None],
+                           carry=((kc[None], vc[None]), 0, pos))
+        own = [buffer[0] for buffer in own]
+    with scope("attn_proj"):
+        return (h + a, *own)
+
+
+PLAN_KIND = types.SimpleNamespace(
+    leaf_shapes=_kind_leaf_shapes, buffers=_kind_buffers, mixer=_kind_mixer,
+    POSITIONED=True, MESHED=True)

@@ -1,23 +1,27 @@
 """Layers of different kinds in one model: the plan.
 
-``TransformerConfig.plan`` holds a :class:`LayerPlan`: for every layer its
+``TransformerConfig.plan`` holds a :class:`LayerPlan`: for every row its
 mixer's kind (``MIXERS``: "kda", ``models/kda.py``; "mla", ``models/mla.py``;
 "lightning", ``models/lightning.py``; "block_select",
-``models/block_select.py``) and its MLP's ("dense": a gated MLP of width
-``cfg.d_ff``; "moe": the dropless routed experts of
-``parallel/moe.routed_moe``, of width ``d_expert``, under the
-configuration's ``moe_*`` fields).  A configuration without a plan is
-attention and one MLP in every layer under one ``lax.scan``, and is not
-touched by this module.  A row of the plan is one mixer and one MLP; a
-model's layer that has more than one mixer is as many rows, with
+``models/block_select.py``; "ssm", the Mamba-2 mixer of ``models/ssm.py``;
+"attention", the grouped-query attention of ``models/block.py``, rotary or
+not) and its MLP's ("dense": a gated MLP of width ``cfg.d_ff``; "moe": the
+dropless routed experts of ``parallel/moe.routed_moe``, of width
+``d_expert``, under the configuration's ``moe_*`` fields).  A configuration
+without a plan is attention and one MLP in every layer under one
+``lax.scan``, and is not touched by this module.  A row of the plan is one
+mixer and one MLP, or one of the two alone (the other None: a model whose
+layer is a single mixer, under one norm, and nothing after it); a model's
+layer that has more than one mixer is as many rows, with
 ``LayerPlan.branches`` saying what else reads the stream inside it and where
 that lands (a shortcut-connected layer: routed experts that read the first
 row's normed post-mixer stream and land after the second row's MLP, beside
 everything in between).
 
 A mixer's kind is a module and a line of ``MIXERS``.  The plan holds the
-kind's sizes in the field of the kind's name, and the module gives:
-``leaf_shapes(cfg, sizes)``, one layer's leaves; ``buffers(cfg, sizes,
+kind's sizes in the field of the kind's name, and the module (or, where the
+module's own ``mixer`` and ``carry`` are another form's, its ``PLAN_KIND``)
+gives: ``leaf_shapes(cfg, sizes)``, one layer's leaves; ``buffers(cfg, sizes,
 batch, t_max)``, what a decoder carries for one layer, ``(shape, dtype,
 axis)`` each, ``axis`` the axis of a carried buffer (its leading axis of one
 counted) along which it grows with the sequence, the shape given at
@@ -26,21 +30,23 @@ lp, h, carry=None)``, the layer's mixer with its norm and its residual add,
 over whole sequences (returns ``(h, *states)``, the states in ``buffers``'
 order, a growing one as long as the sequences) and, T == 1, against the
 layer's own buffers, with the position after them where the module says
-``POSITIONED`` (returns ``(h, *buffers)``); and, where a layer's place in the
+``POSITIONED`` (returns ``(h, *buffers)``), and with the communicator
+(``comm=``) where it says ``MESHED``; and, where a layer's place in the
 model is part of its arithmetic, ``constants(sizes, layer)``, what the plan
 hands the mixer beside its leaves.
 
 Leaves are stacked by kind, not by layer: the KDA leaves over the KDA
 layers, the latent leaves over the latent layers, the dense MLP's over the
 dense layers, the router's, the shared expert's and the experts' over the
-routed layers; ``ln1`` and ``ln2`` over all layers.  A decoder carries each
+routed layers; ``ln1`` over the rows that have a mixer and ``ln2`` over
+those that have an MLP (all rows, where every row has both).  A decoder carries each
 layer's own buffers (:func:`carry` says which, and why not a stack a kind).
 The plan is static, so both passes are python loops over it
 (:func:`backbone`, the whole sequence; :func:`step`, one cached position):
 a layer's place in its kind's stacks of leaves is a python integer, its
 leaves are static slices, and its state is a buffer that the step reads and
 replaces.  Not a scan over whole periods with the leading layers outside it:
-the plans built so far are a handful of layers whose MLPs differ inside a
+the plans built so far are at most fourteen rows whose kinds differ inside a
 period; a deep plan would want the scan (``ROADMAP.md`` D1').
 
 Only a configuration with a plan imports this (its doors: ``ENTRY_CONFIGS``).
@@ -57,11 +63,11 @@ import numpy as np
 __all__ = ["LayerPlan", "MIXERS", "kda_mla_config", "lightning_blocks_config",
            "leaf_names", "init_params", "carry", "grows", "carried",
            "backbone", "step", "check_mesh", "mla_moe_config",
-           "shortcut_moe_config", "ENTRY_CONFIGS"]
+           "shortcut_moe_config", "pattern_moe_config", "ENTRY_CONFIGS"]
 
 # a mixer's kind -> its module under ``ompi_tpu.models``
 MIXERS = {"kda": "kda", "mla": "mla", "lightning": "lightning",
-          "block_select": "block_select"}
+          "block_select": "block_select", "ssm": "ssm", "attention": "block"}
 MLPS = ("dense", "moe")
 
 ROUTER_LEAVES = ("wg", "wgb")
@@ -71,9 +77,9 @@ SHARED_LEAVES = ("sw1", "sw3", "sw2")
 @dataclasses.dataclass(frozen=True)
 class LayerPlan:
     """``layers``: a (mixer, mlp) pair of kinds a layer (a row: ``cfg.n_layers``
-    counts rows).  ``kda``, ``mla``,
-    ``lightning`` and ``block_select``: the sizes of the mixers the plan
-    names.  ``d_expert``: a routed expert's width (``cfg.d_ff`` is the dense
+    counts rows), either of them None in a row of one half.  ``kda``,
+    ``mla``, ``lightning``, ``block_select``, ``ssm`` and ``attention``: the
+    sizes of the mixers the plan names.  ``d_expert``: a routed expert's width (``cfg.d_ff`` is the dense
     MLP's).  Three constants of the model, each 1 where it has none: the
     embedding is multiplied by ``scale_emb``, every branch by
     ``branch_scale`` before its residual add (in the kinds that read it:
@@ -94,6 +100,8 @@ class LayerPlan:
     branch_scale: float = 1.0
     head_divisor: float = 1.0
     branches: tuple = ()
+    ssm: Any = None
+    attention: Any = None
 
     def count(self, kind: str) -> int:
         """Layers whose mixer or MLP is ``kind``, and branches of it."""
@@ -108,6 +116,11 @@ class LayerPlan:
                 + sum(kind == of and reads < layer
                       for of, reads, _lands in self.branches)
                 + (branch and kind in self.layers[layer]))
+
+    def norm(self, layer: int, half: int) -> int:
+        """Row ``layer``'s place in ``ln1`` (``half`` 0: the rows that have a
+        mixer) or in ``ln2`` (1: those that have an MLP)."""
+        return sum(pair[half] is not None for pair in self.layers[:layer])
 
     def second(self, layer: int) -> bool:
         """Whether ``layer`` is past the first row of a model's layer: a
@@ -222,30 +235,37 @@ def lightning_blocks_config(mixer_types: list, lightning_nh: int,
 
 
 def _module(kind: str):
-    """The module of a mixer's kind."""
+    """What a mixer's kind gives the plan: its module, or the module's
+    ``PLAN_KIND`` where its own names are another form's."""
     import importlib
 
-    return importlib.import_module("ompi_tpu.models." + MIXERS[kind])
+    module = importlib.import_module("ompi_tpu.models." + MIXERS[kind])
+    return getattr(module, "PLAN_KIND", module)
 
 
 def check_mesh(cfg, mesh) -> None:
     """A head's state, the latent and a selection's candidates are whole on
     a device, and over ``sp`` a recurrence needs an exclusive scan of
     per-rank states: neither split is built, and no cell asks."""
-    for axis in ("sp", "tp"):
-        if int(dict(mesh.shape).get(axis, 1)) > 1:
-            raise ValueError(
-                f"a layer plan (mixers of kinds {', '.join(MIXERS)}) runs "
-                f"with {axis} == 1 only, and the mesh has {axis}="
-                f"{mesh.shape[axis]}: its mixers are not split over {axis}")
+    from ompi_tpu.models import ssm
+
+    ssm.check_mesh(cfg, mesh, f"a layer plan (mixers of kinds "
+                              f"{', '.join(MIXERS)})")
     pl = cfg.plan
-    unknown = ({mixer for mixer, _mlp in pl.layers} - set(MIXERS)
-               | {mlp for _mixer, mlp in pl.layers} - set(MLPS)
+    unknown = ({mixer for mixer, _mlp in pl.layers} - {None, *MIXERS}
+               | {mlp for _mixer, mlp in pl.layers} - {None, *MLPS}
                | {kind for kind, _reads, _lands in pl.branches} - {"moe"})
     if unknown:
         raise ValueError(f"a layer plan of kinds {sorted(unknown)}: not "
                          f"built (have {', '.join(MIXERS)}; "
                          f"{', '.join(MLPS)}; a branch: moe)")
+    if (None, None) in pl.layers:
+        raise ValueError("a row of a layer plan has a mixer, an MLP or both")
+    unsized = [kind for kind in MIXERS
+               if pl.count(kind) and getattr(pl, kind) is None]
+    if unsized:
+        raise ValueError(f"a layer plan names mixers of kinds {unsized} and "
+                         f"holds no sizes for them (LayerPlan.<kind>)")
     astray = [b for b in pl.branches
               if not 0 <= b[1] <= b[2] < len(pl.layers)]
     if astray:
@@ -256,9 +276,11 @@ def check_mesh(cfg, mesh) -> None:
 
 def _kinds(cfg) -> dict:
     """kind -> (layers of it, one layer's leaves: name -> (shape,
-    deviation or None)), for the kinds the plan has.  The dense MLP's leaves
-    are ``dw1``, ``dw3``, ``dw2`` beside routed layers, whose experts are
-    ``w1``, ``w3``, ``w2``, and take those names where no layer routes."""
+    deviation, None for ones, or a draw ``(rng, shape)``)), for the kinds the
+    plan has.  The dense MLP's leaves are ``dw1``, ``dw3``, ``dw2`` beside
+    routed layers, whose experts are ``w1``, ``w3``, ``w2`` (gated; ``w1``,
+    ``w2`` ungated, and the shared expert's alike), and take those names
+    where no layer routes."""
     pl, D = cfg.plan, cfg.d_model
     depth = max(1, 2 * cfg.n_layers) ** 0.5
 
@@ -276,6 +298,9 @@ def _kinds(cfg) -> dict:
             leaves.update({"sw1": ((D, Fs), D ** -0.5),
                            "sw3": ((D, Fs), D ** -0.5),
                            "sw2": ((Fs, D), Fs ** -0.5 / depth)})
+        if not cfg.moe_gated:
+            leaves.pop("w3")
+            leaves.pop("sw3", None)
         return leaves
 
     def dense():
@@ -304,22 +329,31 @@ def _dense_leaves(pl) -> tuple:
 
 def leaf_names(cfg) -> tuple:
     """The leaves stacked over layers (each over its kind's)."""
-    return ("ln1", "ln2", *(name for _n, leaves in _kinds(cfg).values()
-                            for name in leaves))
+    return (*_norms(cfg.plan), *(name for _n, leaves in _kinds(cfg).values()
+                                 for name in leaves))
+
+
+def _norms(pl) -> dict:
+    """``ln1`` and ``ln2`` -> the rows each is stacked over: those that have
+    a mixer, those that have an MLP."""
+    rows = {"ln1": pl.norm(len(pl.layers), 0),
+            "ln2": pl.norm(len(pl.layers), 1)}
+    return {name: n for name, n in rows.items() if n}
 
 
 def init_params(cfg, rng) -> dict:
     """The whole tree as the program initialises it, float32."""
-    L, D, V = cfg.n_layers, cfg.d_model, cfg.vocab
+    D, V = cfg.d_model, cfg.vocab
     params = {"emb": rng.normal(0, 0.02, size=(V, D)).astype(np.float32),
               **({} if cfg.tie_head else {"head": rng.normal(
                   0, 0.02, size=(V, D)).astype(np.float32)}),
-              "ln1": np.ones((L, D), np.float32),
-              "ln2": np.ones((L, D), np.float32),
+              **{name: np.ones((n, D), np.float32)
+                 for name, n in _norms(cfg.plan).items()},
               "lnf": np.ones((D,), np.float32)}
     for n, leaves in _kinds(cfg).values():
         for name, (dims, std) in leaves.items():
             params[name] = (np.ones((n, *dims), np.float32) if std is None
+                            else std(rng, (n, *dims)) if callable(std)
                             else rng.normal(0, std, size=(n, *dims)
                                             ).astype(np.float32))
     return params
@@ -329,7 +363,7 @@ def _buffers(cfg, batch: int, t_max: int) -> list:
     """``(shape, dtype, axis)`` of every buffer a decoder carries, a layer's
     own after another's in the plan's order (a kind's ``buffers``)."""
     pl = cfg.plan
-    return [buffer for mixer, _mlp_kind in pl.layers
+    return [buffer for mixer, _mlp_kind in pl.layers if mixer is not None
             for buffer in _module(mixer).buffers(
                 cfg, getattr(pl, mixer), batch, t_max)]
 
@@ -345,8 +379,9 @@ def carry(cfg, mesh, batch: int, t_max: int) -> list:
     in the compute type and its matrix states ``(1, B, heads, K, K)`` in the
     mixer's ``state_dtype``; a lightning layer's matrix states alike; a
     block-selected layer's K and V rows and, one for every ``stride``
-    positions, its pooled keys.  The order is that of :func:`backbone`'s
-    collected states.
+    positions, its pooled keys; a state-space layer's convolution inputs and
+    heads' states; an attention layer's K and V; nothing for a row without a
+    mixer.  The order is that of :func:`backbone`'s collected states.
 
     A buffer a layer and not a stack a kind, because the steps' loop over
     the plan is unrolled: with one float32 stack ``(KDA layers, B, heads, K,
@@ -398,19 +433,20 @@ def _mlp(cfg, comm, params, layer: int, kind: str, h, branch: bool = False):
     ``branch``, what the branch of ``kind`` that reads there adds to the
     stream where it lands."""
     from ompi_tpu.models import transformer as tfm
+    from ompi_tpu.parallel.moe import EXPERT_LEAVES
 
     pl = cfg.plan
-    at = pl.index(layer, kind, branch)
+    at, ln2 = pl.index(layer, kind, branch), params["ln2"][pl.norm(layer, 1)]
     if kind == "dense":
-        lp = {"ln2": params["ln2"][layer], **{
+        lp = {"ln2": ln2, **{
             name: params[leaf][at] for name, leaf in zip(
                 ("w1", "w3", "w2"), _dense_leaves(pl))}}
         return tfm._dense_ffn_tail(h, lp, comm, h.dtype, cfg.norm_eps,
                                    gated=(1.0, pl.branch_scale))
     # the experts' whole stacks and the layer's place in them
     # (``routed_moe`` says why); the router's and the shared expert's sliced
-    lp = {"ln2": params["ln2"][layer],
-          **{k: params[k] for k in ("w1", "w3", "w2")},
+    lp = {"ln2": ln2,
+          **{k: params[k] for k in EXPERT_LEAVES if k in params},
           **{k: params[k][at] for k in (*ROUTER_LEAVES, *SHARED_LEAVES)
              if k in params}}
     return tfm._moe_ffn_tail(cfg, h, lp, comm, layer=at,
@@ -421,7 +457,8 @@ def _row(cfg, comm, params, layer: int, mixer: str, mlp: str, lp, h,
          landing, carry=None):
     """Row ``layer`` of the plan on the stream ``h``: its mixer on the leaves
     ``lp`` (against ``carry`` in a cached step), the branches that read
-    there, its MLP, and the branches that land there (``landing``) added.
+    there, its MLP, and the branches that land there (``landing``) added; a
+    half the row lacks (``mixer`` or ``mlp`` None) is passed over.
     Returns ``(h, what the mixer hands back, the branches begun that land
     later: row -> those that land there)``."""
     from ompi_tpu.core.scopes import scope, second
@@ -429,13 +466,19 @@ def _row(cfg, comm, params, layer: int, mixer: str, mlp: str, lp, h,
     pl = cfg.plan
     half = second if pl.second(layer) else contextlib.nullcontext
     with half():
-        h, *states = _module(mixer).mixer(cfg, lp, h, carry=carry)
+        states = []
+        if mixer is not None:
+            module = _module(mixer)
+            meshed = getattr(module, "MESHED", False)
+            h, *states = module.mixer(cfg, lp, h, carry=carry,
+                                      **({"comm": comm} if meshed else {}))
         begun = {}
         for kind, reads, lands in pl.branches:
             if reads == layer:
                 begun.setdefault(lands, []).append(
                     _mlp(cfg, comm, params, layer, kind, h, branch=True))
-        h = _mlp(cfg, comm, params, layer, mlp, h)
+        if mlp is not None:
+            h = _mlp(cfg, comm, params, layer, mlp, h)
         landing = [*landing, *begun.pop(layer, [])]
         if landing:
             with scope("ffn"):
@@ -451,10 +494,13 @@ def _take_off(flying: dict, begun: dict) -> None:
 
 
 def _mixer_leaves(cfg, params, layer: int, kind: str) -> dict:
-    """Layer ``layer``'s mixer's leaves, and the constants of its place."""
+    """Layer ``layer``'s mixer's leaves, and the constants of its place
+    (nothing where the row has no mixer)."""
+    if kind is None:
+        return {}
     at = cfg.plan.index(layer, kind)
     constants = getattr(_module(kind), "constants", None)
-    return {"ln1": params["ln1"][layer],
+    return {"ln1": params["ln1"][cfg.plan.norm(layer, 0)],
             **{k: params[k][at] for k in _kinds(cfg)[kind][1]},
             **(constants(getattr(cfg.plan, kind), layer) if constants
                else {})}
@@ -518,7 +564,8 @@ def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
     collected, flying = [], {}
     with scope("layers"):
         for layer, (mixer, mlp) in enumerate(pl.layers):
-            # the host's record of what tracing this layer costs, by kind
+            # the host's record of what tracing this layer costs, by its
+            # mixer's kind (none for a row that is an MLP alone)
             with host("trace.layer", program=mixer):
                 h, states, begun = layer_fn(layer, mixer, mlp)(
                     h, params, flying.pop(layer, []))
@@ -543,21 +590,22 @@ def step(cfg, comm, params, h, states, pos):
     pl = cfg.plan
 
     def layer_fn(layer, mixer, mlp):
-        module = _module(mixer)
+        positioned = mixer is not None and _module(mixer).POSITIONED
 
         def run(h, params, own, pos, landing):
             lp = _mixer_leaves(cfg, params, layer, mixer)
             own = tuple(buffer[0] for buffer in own)
             h, own, begun = _row(
                 cfg, comm, params, layer, mixer, mlp, lp, h, landing,
-                carry=(*own, pos) if module.POSITIONED else own)
+                carry=(*own, pos) if positioned else own)
             return h, [buffer[None] for buffer in own], begun
 
         return _own_program(run)
 
     states, at, flying = list(states), 0, {}
     for layer, (mixer, mlp) in enumerate(pl.layers):
-        n = len(_module(mixer).buffers(cfg, getattr(pl, mixer), 0, 0))
+        n = (0 if mixer is None else
+             len(_module(mixer).buffers(cfg, getattr(pl, mixer), 0, 0)))
         with host("trace.layer", program=mixer):
             h, states[at:at + n], begun = layer_fn(layer, mixer, mlp)(
                 h, params, states[at:at + n], pos, flying.pop(layer, []))
@@ -675,6 +723,78 @@ def shortcut_moe_config(kv_lora_rank: int, q_lora_rank, qk_nope_head_dim: int,
         moe_zero=zero_expert_num, **sizes)
 
 
+def pattern_moe_config(hybrid_override_pattern: str, mamba_num_heads: int,
+                       mamba_head_dim: int, ssm_state_size: int,
+                       n_groups: int, conv_kernel: int, chunk_size: int,
+                       moe_intermediate_size: int,
+                       moe_shared_expert_intermediate_size: int,
+                       n_shared_experts: int, routed_scaling_factor: float,
+                       router_experts: int, n_group: int, topk_group: int,
+                       mlp_hidden_act: str, mamba_hidden_act: str,
+                       use_conv_bias: bool, residual_in_fp32: bool,
+                       attention_bias: bool, mlp_bias: bool, use_bias: bool,
+                       mamba_proj_bias: bool,
+                       attention_use_rope: bool = False,
+                       experts_held=None, ssm_state_dtype: str = "float32",
+                       **sizes):
+    """``entry.config`` of a configuration file whose layer is one mixer
+    alone under one norm, its kind a character of
+    ``hybrid_override_pattern``, under the keys Nemotron-H's family is
+    published with: a ``TransformerConfig`` whose plan has a row a layer, a
+    row of one half: ``M`` a Mamba-2 mixer (``mamba_num_heads`` heads of
+    ``mamba_head_dim`` over a state of ``ssm_state_size``, ``n_groups``
+    groups; the inner width is their product, no ``expand`` is read), ``*``
+    grouped-query attention (the configuration's heads; no rotary embedding
+    unless ``attention_use_rope``), ``E`` routed experts.  The pattern may run
+    past ``n_layers`` (a file that cuts the depth keeps it whole).  The
+    router is ``router_experts`` wide: sigmoid scores, a selection bias that
+    picks and does not weigh, the picks' weights renormalised where
+    ``moe_norm_topk`` says so, times ``routed_scaling_factor``; an expert is
+    ungated, ``w2(relu(w1 x)^2)``, and the shared expert, of
+    ``n_shared_experts x moe_shared_expert_intermediate_size``, alike.
+    ``experts_held`` as in :func:`kda_mla_config`.  What is
+    not built raises: a dense MLP layer (``-``) or any other character,
+    grouped top-k, another activation than relu2 in the MLPs and silu in the
+    mixer, a convolution without its bias, a bias on any projection
+    (``attention_bias``, ``mlp_bias``, ``use_bias``, ``mamba_proj_bias``), a
+    float32 residual stream."""
+    from ompi_tpu.models.block import Attention
+    from ompi_tpu.models.ssm import Mamba2
+    from ompi_tpu.models.transformer import TransformerConfig
+
+    rows = {"M": ("ssm", None), "*": ("attention", None), "E": (None, "moe")}
+    pattern = hybrid_override_pattern[:sizes["n_layers"]]
+    not_built = [f"{key} {value!r}" for key, value, built in (
+        ("n_group", n_group, 1), ("topk_group", topk_group, 1),
+        ("mlp_hidden_act", mlp_hidden_act, "relu2"),
+        ("mamba_hidden_act", mamba_hidden_act, "silu"),
+        ("use_conv_bias", bool(use_conv_bias), True),
+        ("residual_in_fp32", bool(residual_in_fp32), False),
+        ("a projection's bias", bool(attention_bias or mlp_bias or use_bias
+                                     or mamba_proj_bias), False),
+        ("layers of kinds", sorted(set(pattern) - set(rows)), []),
+        ("layers", len(pattern), sizes["n_layers"])) if value != built]
+    if not_built:
+        raise ValueError(f"a plan of single-mixer layers by a pattern is not "
+                         f"built for {', '.join(not_built)}")
+    plan = LayerPlan(
+        layers=tuple(rows[kind] for kind in pattern),
+        ssm=Mamba2(d_ssm=mamba_num_heads * mamba_head_dim,
+                   d_state=ssm_state_size, n_groups=n_groups,
+                   n_heads=mamba_num_heads, d_conv=conv_kernel,
+                   chunk=chunk_size, state_dtype=ssm_state_dtype),
+        attention=Attention(rope=bool(attention_use_rope)),
+        d_expert=moe_intermediate_size)
+    held = (None if experts_held is None
+            else (int(experts_held["first"]), int(experts_held["count"])))
+    return TransformerConfig(
+        plan=plan, moe_gated=False, moe_act="relu2", moe_score="sigmoid",
+        moe_select_bias=True, moe_scale=float(routed_scaling_factor),
+        moe_held=held, moe_experts=router_experts,
+        moe_shared=n_shared_experts * moe_shared_expert_intermediate_size,
+        **sizes)
+
+
 # A configuration file reaches a plan through its ``entry.config``, one
 # function a published family's keys (each raises for what is not built):
 # ``kda_mla_config``: KDA and NoPE latent layers by ``linear_attn_config``'s
@@ -685,6 +805,8 @@ def shortcut_moe_config(kv_lora_rank: int, q_lora_rank, qk_nope_head_dim: int,
 # ``mixer_types`` over a dense MLP; ``shortcut_moe_config``: LongCat-Flash's
 # keys, two rows of rotary latent attention with a query latent over a dense
 # MLP a layer and a softmax router with identity experts as a branch across
-# them.
+# them; ``pattern_moe_config``: Nemotron-H's keys, a row of one half a layer
+# by ``hybrid_override_pattern`` (a Mamba-2 mixer, NoPE grouped-query
+# attention, or ungated relu2 experts under a sigmoid router).
 ENTRY_CONFIGS = (kda_mla_config, mla_moe_config, lightning_blocks_config,
-                 shortcut_moe_config)
+                 shortcut_moe_config, pattern_moe_config)

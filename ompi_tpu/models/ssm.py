@@ -16,41 +16,43 @@ chunked scan, from a zero state) and one position against a carried state
 inputs, the recurrence once).  Everything here is ``jax.numpy`` and ``lax``:
 no kernel.
 
-Nothing imports this module but a configuration that has the block, so the
-other programs' set-up does not pay for it.
+The same mixer alone in a layer, ``h <- h + mixer(RMSNorm(h; ln1))`` with no
+multiplier anywhere, is the kind "ssm" of a layer plan (``models/plan.py``):
+its sizes a :class:`Mamba2` in ``LayerPlan.ssm``, and what the plan asks of a
+kind (``leaf_shapes``, ``buffers``, ``mixer`` with its norm and residual add
+against the layer's own buffers) under :data:`PLAN_KIND`, because this
+module's own ``mixer`` and ``carry`` are the hybrid block's, whose states are
+stacks over layers.  One arithmetic (:func:`_mix`), two ways to hold a state.
+
+Nothing imports this module but a configuration that has the block or a
+plan (whose ``check_mesh`` says what split it refuses through this module's),
+and importing it costs numpy alone, so the other programs' set-up does not
+pay for it.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
+import types
 
 import numpy as np
 
-__all__ = ["HybridBlock", "hybrid_config", "mixer", "chunked_scan",
+__all__ = ["Mamba2", "HybridBlock", "hybrid_config", "mixer", "chunked_scan",
            "init_leaves", "leaf_names", "state_shapes", "carry", "carried",
-           "check_mesh"]
+           "check_mesh", "PLAN_KIND"]
 
 
 @dataclasses.dataclass(frozen=True)
-class HybridBlock:
-    """Sizes of the mixer and the block's constant multipliers, under the
-    published configuration's names where it has one."""
+class Mamba2:
+    """Sizes of the mixer, under the published configuration's names where
+    it has one."""
     d_ssm: int                  # the mixer's inner width: heads x head width
     d_state: int                # N: a head's state is (head width, N)
     n_groups: int               # groups that share one B and one C
     n_heads: int
     d_conv: int                 # taps of the causal depthwise convolution
     chunk: int                  # positions a chunk of the whole-sequence scan
-    embedding_multiplier: float
-    attention_in_multiplier: float
-    attention_out_multiplier: float
-    key_multiplier: float
-    lm_head_multiplier: float
-    ssm_in_multiplier: float
-    ssm_multipliers: tuple      # on z, x, B, C, dt of the input projection
-    ssm_out_multiplier: float
-    mlp_multipliers: tuple      # on the gate's pre-activation, on the output
     # what the carried state is stored in between cached steps; the update
     # itself is float32 and is rounded once, on the way back
     state_dtype: str = "bfloat16"
@@ -68,6 +70,20 @@ class HybridBlock:
     def in_dim(self) -> int:
         """Columns of the input projection: z, x, B, C, dt in this order."""
         return self.d_ssm + self.conv_dim + self.n_heads
+
+
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class HybridBlock(Mamba2):
+    """The mixer's sizes and the hybrid block's constant multipliers."""
+    embedding_multiplier: float
+    attention_in_multiplier: float
+    attention_out_multiplier: float
+    key_multiplier: float
+    lm_head_multiplier: float
+    ssm_in_multiplier: float
+    ssm_multipliers: tuple      # on z, x, B, C, dt of the input projection
+    ssm_out_multiplier: float
+    mlp_multipliers: tuple      # on the gate's pre-activation, on the output
 
 
 _BLOCK_FIELDS = {f.name for f in dataclasses.fields(HybridBlock)}
@@ -90,16 +106,19 @@ def hybrid_config(**sizes):
         hybrid=HybridBlock(n_heads=ssm_heads, **block), **sizes)
 
 
-def check_mesh(cfg, mesh) -> None:
-    """The block's 2 groups and 4 K/V heads bound any split over ``tp``, and
-    over ``sp`` the scan needs an exclusive scan of per-rank states: neither
-    is built, and no cell asks."""
+def check_mesh(cfg, mesh, what: str = "the hybrid block (a state-space "
+               "mixer beside attention)") -> None:
+    """A head's state is whole on a device (the mixer's few groups and K/V
+    heads bound any split over ``tp``), and over ``sp`` a recurrence needs an
+    exclusive scan of per-rank states: neither is built, and no cell asks.
+    ``what``: who runs the mixers, for the message (``plan.check_mesh`` says
+    the same of its kinds through this function)."""
     for axis in ("sp", "tp"):
         if int(dict(mesh.shape).get(axis, 1)) > 1:
             raise ValueError(
-                f"the hybrid block (a state-space mixer beside attention) "
-                f"runs with {axis} == 1 only, and the mesh has {axis}="
-                f"{mesh.shape[axis]}: its mixer is not split over {axis}")
+                f"{what} runs with {axis} == 1 only, and the mesh has "
+                f"{axis}={mesh.shape[axis]}: its mixers are not split over "
+                f"{axis}")
 
 
 def leaf_names() -> tuple:
@@ -108,6 +127,26 @@ def leaf_names() -> tuple:
     down projection)."""
     return ("w3", "ssm_in", "ssm_out", "conv_w", "conv_b", "a_log",
             "dt_bias", "ssm_d", "ssm_norm")
+
+
+def _dt_bias(rng, shape):
+    """dt log-uniform in [1e-3, 1e-1], through the inverse softplus."""
+    dt = np.exp(rng.uniform(math.log(1e-3), math.log(1e-1), size=shape))
+    return (dt + np.log(-np.expm1(-dt))).astype(np.float32)
+
+
+def _a_log(rng, shape):
+    """A uniform in [1, 16]."""
+    return np.log(rng.uniform(1, 16, size=shape)).astype(np.float32)
+
+
+def _conv_w(rng, shape):
+    bound = shape[-2] ** -0.5               # (..., taps, channels)
+    return rng.uniform(-bound, bound, size=shape).astype(np.float32)
+
+
+def _zeros(_rng, shape):
+    return np.zeros(shape, np.float32)
 
 
 def init_leaves(cfg, rng) -> dict:
@@ -120,19 +159,15 @@ def init_leaves(cfg, rng) -> dict:
     def w(*shape, scale):
         return rng.normal(0, scale, size=shape).astype(np.float32)
 
-    dt = np.exp(rng.uniform(math.log(1e-3), math.log(1e-1),
-                            size=(L, hy.n_heads)))
-    bound = hy.d_conv ** -0.5
+    dt_bias = _dt_bias(rng, (L, hy.n_heads))
     return {
         "w3": w(L, D, F, scale=D ** -0.5),
         "ssm_in": w(L, D, hy.in_dim, scale=D ** -0.5),
         "ssm_out": w(L, hy.d_ssm, D, scale=hy.d_ssm ** -0.5 / depth),
-        "conv_w": rng.uniform(-bound, bound, size=(L, hy.d_conv, hy.conv_dim)
-                              ).astype(np.float32),
+        "conv_w": _conv_w(rng, (L, hy.d_conv, hy.conv_dim)),
         "conv_b": np.zeros((L, hy.conv_dim), np.float32),
-        "a_log": np.log(rng.uniform(1, 16, size=(L, hy.n_heads))
-                        ).astype(np.float32),
-        "dt_bias": (dt + np.log(-np.expm1(-dt))).astype(np.float32),
+        "a_log": _a_log(rng, (L, hy.n_heads)),
+        "dt_bias": dt_bias,
         "ssm_d": np.ones((L, hy.n_heads), np.float32),
         "ssm_norm": np.ones((L, hy.d_ssm), np.float32),
     }
@@ -240,11 +275,14 @@ def chunked_scan(x, dt, a, b, c, chunk: int):
 
 
 def _conv_before(conv_c, layer):
-    """A cached step's last ``d_conv - 1`` inputs of layer ``layer``.  (A
-    function of its own, like ``_state_before``, so that the benchmark's
+    """A cached step's last ``d_conv - 1`` inputs of layer ``layer`` of the
+    stack ``conv_c``; ``layer`` None: ``conv_c`` is the layer's own buffer.
+    (A function of its own, like ``_state_before``, so that the benchmark's
     controls can plant a state that is not read while a decoder is traced.)"""
     from jax import lax
 
+    if layer is None:
+        return conv_c
     return lax.dynamic_index_in_dim(conv_c, layer, keepdims=False)
 
 
@@ -253,13 +291,29 @@ def _state_before(ssm_c, layer):
     import jax.numpy as jnp
     from jax import lax
 
+    if layer is None:
+        return ssm_c.astype(jnp.float32)
     return lax.dynamic_index_in_dim(ssm_c, layer,
                                     keepdims=False).astype(jnp.float32)
 
 
+def _written(into, new, layer):
+    """``new``, layer ``layer``'s state after a cached step, as the carry
+    holds it: in the stack ``into`` at ``layer``, in place; ``layer`` None:
+    in the place of the layer's own buffer ``into``.  In ``into``'s type."""
+    from jax import lax
+
+    new = new.astype(into.dtype)
+    if layer is None:
+        return new
+    return lax.dynamic_update_slice(into, new[None],
+                                    (layer,) + (0,) * new.ndim)
+
+
 def mixer(cfg, lp, u, carry=None):
-    """The mixer branch of one layer on the block's normed input ``u``
-    (B, T, D), without its residual add.
+    """The mixer branch of one layer of the hybrid block on the block's
+    normed input ``u`` (B, T, D), without its residual add, under the
+    block's multipliers.
 
     ``carry`` None: whole sequences from a zero state; returns
     ``(s, conv_state, ssm_state)``, the layer's states after the last
@@ -268,22 +322,36 @@ def mixer(cfg, lp, u, carry=None):
     of layer ``layer`` of the stacks ``(L, ...)``, read and written in place;
     returns ``(s, conv_c, ssm_c)``.  The two differ only in where the
     convolution's window comes from and in scan against one update."""
+    hy = cfg.hybrid
+    return _mix(hy, cfg.norm_eps, lp, u, carry, multipliers=(
+        hy.ssm_in_multiplier, _column_multipliers(hy),
+        hy.ssm_out_multiplier))
+
+
+def _mix(sz: Mamba2, eps: float, lp, u, carry=None, multipliers=None):
+    """:func:`mixer`'s arithmetic on the sizes ``sz``, the hybrid block's
+    and the plan's kind's: ``carry`` as :func:`mixer`'s, its ``layer`` None
+    where the two states are the layer's own buffers and not stacks.
+    ``multipliers``: the hybrid block's, on the normed input, on the input
+    projection's columns (a vector) and on the output; None: none."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     from ompi_tpu.core.scopes import scope
 
-    hy, f32, cdt = cfg.hybrid, jnp.float32, u.dtype
+    f32, cdt = jnp.float32, u.dtype
     B, T, _ = u.shape
-    H, P, G, N = hy.n_heads, hy.head_dim, hy.n_groups, hy.d_state
+    H, P, G, N = sz.n_heads, sz.head_dim, sz.n_groups, sz.d_state
+    m_in, m_columns, m_out = multipliers or (None, None, None)
     with scope("ssm_proj"):
-        p = jnp.einsum("btd,df->btf", u * hy.ssm_in_multiplier,
+        p = jnp.einsum("btd,df->btf", u if m_in is None else u * m_in,
                        lp["ssm_in"].astype(cdt))
-        p = p * jnp.asarray(_column_multipliers(hy), cdt)
-        z, xbc, dt = jnp.split(p, [hy.d_ssm, hy.d_ssm + hy.conv_dim], -1)
+        if m_columns is not None:
+            p = p * jnp.asarray(m_columns, cdt)
+        z, xbc, dt = jnp.split(p, [sz.d_ssm, sz.d_ssm + sz.conv_dim], -1)
     with scope("ssm.conv"):
-        taps = hy.d_conv
+        taps = sz.d_conv
         if carry is None:
             window = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
             conv_out = window[:, T:]
@@ -291,21 +359,19 @@ def mixer(cfg, lp, u, carry=None):
             conv_c, ssm_c, layer = carry
             window = jnp.concatenate(
                 [_conv_before(conv_c, layer).astype(cdt), xbc], axis=1)
-            conv_out = lax.dynamic_update_slice(
-                conv_c, window[:, 1:].astype(conv_c.dtype)[None],
-                (layer, 0, 0, 0))
+            conv_out = _written(conv_c, window[:, 1:], layer)
         w = lp["conv_w"].astype(f32)                    # (taps, C)
         acc = lp["conv_b"].astype(f32) + sum(
             window[:, k:k + T].astype(f32) * w[k] for k in range(taps))
         xbc = jax.nn.silu(acc).astype(cdt)
-    x, b, c = jnp.split(xbc, [hy.d_ssm, hy.d_ssm + G * N], -1)
+    x, b, c = jnp.split(xbc, [sz.d_ssm, sz.d_ssm + G * N], -1)
     x = x.reshape(B, T, H, P)
     b, c = b.reshape(B, T, G, N), c.reshape(B, T, G, N)
     dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
     a = -jnp.exp(lp["a_log"].astype(f32))
     if carry is None:
         with scope("ssm.scan"):
-            y, ssm_out = chunked_scan(x, dt, a, b, c, hy.chunk)
+            y, ssm_out = chunked_scan(x, dt, a, b, c, sz.chunk)
     else:
         with scope("ssm.update"):
             h = _state_before(ssm_c, layer).reshape(B, G, H // G, P, N)
@@ -315,17 +381,70 @@ def mixer(cfg, lp, u, carry=None):
                  + xh[..., None] * b.astype(f32).reshape(B, G, 1, 1, N))
             y = jnp.einsum("bgrpn,bgn->bgrp", h,
                            c.astype(f32).reshape(B, G, N)).reshape(B, 1, H, P)
-            ssm_out = lax.dynamic_update_slice(
-                ssm_c, h.reshape(B, H, P, N).astype(ssm_c.dtype)[None],
-                (layer, 0, 0, 0, 0))
+            ssm_out = _written(ssm_c, h.reshape(B, H, P, N), layer)
     with scope("ssm_proj"):
         y = y + lp["ssm_d"].astype(f32)[:, None] * x.astype(f32)
-        y = y.reshape(B, T, hy.d_ssm) * jax.nn.silu(z.astype(f32))
+        y = y.reshape(B, T, sz.d_ssm) * jax.nn.silu(z.astype(f32))
         # gate, then an RMSNorm over each group with one scale of d_ssm
-        yg = y.reshape(B, T, G, hy.d_ssm // G)
-        yg = yg * lax.rsqrt(jnp.mean(yg * yg, axis=-1, keepdims=True)
-                            + cfg.norm_eps)
-        y = (yg.reshape(B, T, hy.d_ssm)
+        yg = y.reshape(B, T, G, sz.d_ssm // G)
+        yg = yg * lax.rsqrt(jnp.mean(yg * yg, axis=-1, keepdims=True) + eps)
+        y = (yg.reshape(B, T, sz.d_ssm)
              * lp["ssm_norm"].astype(f32)).astype(cdt)
         s = jnp.einsum("btf,fd->btd", y, lp["ssm_out"].astype(cdt))
-        return s * hy.ssm_out_multiplier, conv_out, ssm_out
+        return (s if m_out is None else s * m_out), conv_out, ssm_out
+
+
+# ---- the mixer alone in a layer: the kind "ssm" of a plan -------------------
+
+def _kind_leaf_shapes(cfg, sz: Mamba2) -> dict:
+    """One layer's leaves: name -> (shape, deviation of the program's own
+    initializer, None for ones, or a draw ``(rng, shape)``: :func:`init_
+    leaves`' own of dt, A and the taps)."""
+    D = cfg.d_model
+    return {
+        "ssm_in": ((D, sz.in_dim), D ** -0.5),
+        "ssm_out": ((sz.d_ssm, D),
+                    sz.d_ssm ** -0.5 / max(1, 2 * cfg.n_layers) ** 0.5),
+        "conv_w": ((sz.d_conv, sz.conv_dim), _conv_w),
+        "conv_b": ((sz.conv_dim,), _zeros),
+        "a_log": ((sz.n_heads,), _a_log),
+        "dt_bias": ((sz.n_heads,), _dt_bias),
+        "ssm_d": ((sz.n_heads,), None),
+        "ssm_norm": ((sz.d_ssm,), None),
+    }
+
+
+def _kind_buffers(cfg, sz: Mamba2, batch: int, t_max: int) -> tuple:
+    """What a decoder carries for one layer (``models/plan.py``'s form): the
+    convolution's last inputs ``(B, d_conv - 1, conv_dim)`` in the compute
+    type and the heads' states ``(B, heads, head width, N)`` in
+    ``state_dtype``; neither grows."""
+    return (((batch, sz.d_conv - 1, sz.conv_dim), cfg.compute_dtype, None),
+            ((batch, sz.n_heads, sz.head_dim, sz.d_state), sz.state_dtype,
+             None))
+
+
+def _kind_mixer(cfg, lp, h, carry=None):
+    """One layer's mixer on the layer's input ``h`` (B, T, D): the norm, the
+    mixer and the residual add, no multiplier.
+
+    ``carry`` None: whole sequences from a zero state; returns ``(h,
+    conv_state, ssm_state)`` as :func:`mixer` does.  ``carry = (conv_c,
+    ssm_c)``: T == 1 against this layer's own two buffers; returns ``(h,
+    conv_c, ssm_c)``, the new ones in the same types."""
+    from ompi_tpu.core.scopes import scope
+    from ompi_tpu.models import transformer as tfm
+
+    with scope("ssm_proj"):
+        # the norm is called through the module: a benchmark's control
+        # plants a wrong one there while a decoder is traced
+        u = tfm._rmsnorm(h, lp["ln1"], cfg.norm_eps)
+    s, *states = _mix(cfg.plan.ssm, cfg.norm_eps, lp, u,
+                      carry and (*carry, None))
+    with scope("ssm_proj"):
+        return (h + s, *states)
+
+
+PLAN_KIND = types.SimpleNamespace(
+    leaf_shapes=_kind_leaf_shapes, buffers=_kind_buffers, mixer=_kind_mixer,
+    POSITIONED=False)

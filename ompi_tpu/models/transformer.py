@@ -62,8 +62,11 @@ class TransformerConfig:
     # on every device, unless ``moe_held`` says which this device holds.
     moe_top_k: int = 0
     # Gated experts: down(silu(gate(x)) * up(x)) with a third leaf "w3"
-    # (up) beside "w1" (gate) and "w2" (down), instead of w2(gelu(w1 x)).
+    # (up) beside "w1" (gate) and "w2" (down), instead of w2(act(w1 x)).
     moe_gated: bool = False
+    # An ungated expert's activation: "gelu", or "relu2", the square of relu
+    # (``parallel/moe.ACTIVATIONS``); the shared expert's too.
+    moe_act: str = "gelu"
     # The dropless path's top-k weights divided by their sum (a token's
     # experts then weigh one together); False: the probabilities as they are.
     moe_norm_topk: bool = False
@@ -87,8 +90,9 @@ class TransformerConfig:
     # one adds the token itself times the pick's weight; the expert leaves
     # are ``moe_experts - moe_zero`` long (or ``moe_held``'s count).  0: none.
     moe_zero: int = 0
-    # Width of a shared expert, a gated MLP (leaves "sw1", "sw3", "sw2")
-    # that every token passes, unweighted, beside its routed experts.  0: none.
+    # Width of a shared expert, an MLP of the experts' form (gated: leaves
+    # "sw1", "sw3", "sw2"; ungated: "sw1", "sw2" under ``moe_act``) that every
+    # token passes, unweighted, beside its routed experts.  0: none.
     moe_shared: int = 0
     # RMSNorm of q and k before the rotary embedding (leaves "qn", "kn").
     # True: over the whole projected width, before the split into heads,
@@ -635,13 +639,13 @@ def _moe_ffn_tail(cfg, h, lp, comm, layer=None, residual: bool = True):
             # the pallas kernel where the mesh is of TPUs (attached, or
             # described for a compile); XLA's ragged_dot on any other
             mo = routed_moe(x, weights, cfg.moe_top_k, gated=cfg.moe_gated,
-                            layer=layer, kernel=comm.mesh.devices.flat[
-                                0].platform == "tpu",
+                            act=cfg.moe_act, layer=layer,
+                            kernel=comm.mesh.devices.flat[0].platform == "tpu",
                             renorm=cfg.moe_norm_topk, score=cfg.moe_score,
                             scale=cfg.moe_scale, held=cfg.moe_held,
                             zero=cfg.moe_zero)
             if cfg.moe_shared:
-                mo = mo + _shared_expert(x, lp)
+                mo = mo + _shared_expert(x, lp, cfg.moe_act)
             return h + mo if residual else mo, jnp.zeros((), jnp.float32)
         mo, aux = switch_moe(
             comm, x, {"wg": lp["wg"], "w1": lp["w1"], "w2": lp["w2"]},
@@ -650,19 +654,24 @@ def _moe_ffn_tail(cfg, h, lp, comm, layer=None, residual: bool = True):
         return h + mo if residual else mo, aux
 
 
-def _shared_expert(x, lp):
-    """``sw2(silu(x sw1) * x sw3)``: the gated MLP that every token passes
-    beside its routed experts (whole on every device)."""
+def _shared_expert(x, lp, act: str = "gelu"):
+    """The MLP that every token passes beside its routed experts (whole on
+    every device): with a leaf ``sw3`` gated, ``sw2(silu(x sw1) * x sw3)``;
+    without, ``sw2(act(x sw1))``, ``act`` of ``parallel/moe.ACTIVATIONS``."""
     import jax
     import jax.numpy as jnp
 
     from ompi_tpu.core.scopes import scope
+    from ompi_tpu.parallel.moe import ACTIVATIONS
 
     with scope("moe.shared"):
-        up = [jnp.einsum("btd,df->btf", x, lp[k].astype(x.dtype))
-              for k in ("sw1", "sw3")]
-        return jnp.einsum("btf,fd->btd", jax.nn.silu(up[0]) * up[1],
-                          lp["sw2"].astype(x.dtype))
+        hid = jnp.einsum("btd,df->btf", x, lp["sw1"].astype(x.dtype))
+        if "sw3" in lp:
+            up = jnp.einsum("btd,df->btf", x, lp["sw3"].astype(x.dtype))
+            hid = jax.nn.silu(hid) * up
+        else:
+            hid = ACTIVATIONS[act](hid)
+        return jnp.einsum("btf,fd->btd", hid, lp["sw2"].astype(x.dtype))
 
 
 def _dense_ffn_tail(h, lp, comm, cdt, eps: float = 1e-6, gated=None,

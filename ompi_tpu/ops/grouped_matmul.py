@@ -27,6 +27,17 @@ it named and copies nothing: where a device holds a few of a wide router's
 experts, nearly every tile is one), which a tile of 256 or 512 rows
 amortises; but the rows' index ``(t, 0)`` stays
 put under every block of ``N``, so the pipeline copies a tile's rows once.
+A width that has no such block (1856 is fourteen and a half lane tiles:
+``N`` is then one block or none) keeps its whole ``N`` instead and goes by
+blocks of ``K``, which moves the same bytes: a tile's rows once, block by
+block, and its group's matrix once.
+Such a stack is also handed to the kernel with ``K`` last, ``(G, N, K)``, and
+multiplied as ``rows x matrix^T`` (``_forward``): the TPU stores an array
+whose last dimension is no whole number of lane tiles with the dimension
+before it minor where that one is (a parameter ``(.., 2688, 1856)`` lies
+``K``-minor on the chip), a pallas call takes its operands row-major, and
+handed ``(G, K, N)`` the compiler copied the whole stack re-laid, 3.7 GB of
+Nemotron's experts, before the first call (PR 62; the swap is then no copy).
 Only where not even one lane tile of a whole ``K`` fits do both dimensions
 go by blocks, and a tile's rows are read again for every block of ``N``:
 at 512 rows that re-read, not the weights, was what bound Keye-VL's
@@ -51,6 +62,8 @@ does on a mesh that is not of TPUs (``models/transformer._moe_ffn_tail``).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -72,13 +85,28 @@ __all__ = ["grouped_matmul", "grouped_matmul_xla", "tile_rows",
 _VMEM_BUDGET_BYTES = 16 << 20
 
 
-def tile_rows(rows_a_group: float) -> int:
+def tile_rows(rows_a_group: float, matrices=(), itemsize: int = 2) -> int:
     """Rows a tile for groups of about ``rows_a_group`` rows: the largest
     power of two not above it, from 16 (one bfloat16 sublane tile) to 512
-    (above the v5e's ridge of 240 operations a byte of weights)."""
+    (above the v5e's ridge of 240 operations a byte of weights).
+
+    ``matrices``: the ``(K, N)`` of the stacks the tiles will multiply.
+    Where a cached step's handful of rows (a mean under one smallest tile)
+    meets a matrix that goes by blocks (``weight_block``: every tile then
+    reads its group's matrix again), the tile is the smallest that holds
+    the mean and six deviations of a Poisson count, so that a group is one
+    tile and its matrix is read once whatever the router sent it, a router
+    that favours some experts twice over included: at 12 rows on the mean
+    and 16 a tile one expert in eight read its 20 MB twice a step, and
+    ``moe.experts`` moved by 2% with the seed (Nemotron's cell, PR 62: 64
+    rows a tile, which the stream of the matrices still hides)."""
     tm = 16
     while tm * 2 <= min(rows_a_group, 512):
         tm *= 2
+    if tm == 16 and any(weight_block(tm, K, N, itemsize) != (K, N)
+                        for K, N in matrices):
+        while tm < rows_a_group + 6 * rows_a_group ** 0.5:
+            tm *= 2
     return tm
 
 
@@ -116,15 +144,20 @@ def weight_block(tm: int, K: int, N: int, itemsize: int) -> tuple[int, int]:
     rows multiplies, by the bytes the tile then moves: the whole ``K`` and
     the widest block of ``N`` whose working set fits the kernel's VMEM
     budget, so that a tile's rows are read once; that is the whole matrix
-    where it fits, and a group's matrix is then read once too.  Blocks of
-    both dimensions only where no block of a whole ``K`` fits."""
+    where it fits, and a group's matrix is then read once too.  Where no
+    block of ``N`` under a whole ``K`` fits, the whole ``N`` under the widest
+    block of ``K`` that does (the same bytes: rows and matrix once each);
+    blocks of both dimensions only where neither fits."""
     for tn in _widths(N):
         if _working_set_bytes(tm, K, tn, itemsize) <= _VMEM_BUDGET_BYTES:
             return K, tn
+    for tk in _widths(K)[1:]:
+        if _working_set_bytes(tm, tk, N, itemsize) <= _VMEM_BUDGET_BYTES:
+            return tk, N
     return _block(K, 1024), _block(N, 512)
 
 
-def _kernel(tile_group, tiles_used, lhs, rhs, out, acc):
+def _kernel(tile_group, tiles_used, lhs, rhs, out, acc, *, k_last=False):
     from ompi_tpu.ops._pallas import pl
 
     del tile_group          # read by the index map of ``rhs``
@@ -137,8 +170,13 @@ def _kernel(tile_group, tiles_used, lhs, rhs, out, acc):
         def _():
             acc[...] = jnp.zeros_like(acc)
 
-        acc[...] += jnp.dot(lhs[...], rhs[...],
-                            preferred_element_type=jnp.float32)
+        if k_last:      # the block of weights is (tn, tk)
+            acc[...] += jax.lax.dot_general(
+                lhs[...], rhs[...], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        else:
+            acc[...] += jnp.dot(lhs[...], rhs[...],
+                                preferred_element_type=jnp.float32)
 
         @pl.when(last)
         def _():
@@ -162,25 +200,32 @@ def _forward(rows, w, tile_group, tiles_used):
     tm = m // n_tiles
     tk, tn = weight_block(tm, K, N, jnp.dtype(w.dtype).itemsize)
     nj, nk = N // tn, K // tk
+    # a width of lane tiles and a part of one: the stack as the chip stores
+    # it, K last (the module's docstring)
+    k_last = N > 128 and N % 128 != 0
+    if k_last:
+        w = jnp.swapaxes(w, 1, 2)
 
     def weights_at(i, j, k, tg, used):
-        return tg[i], k, j
+        return (tg[i], j, k) if k_last else (tg[i], k, j)
 
     def weights_at_or_where_they_were(i, j, k, tg, used):
         """A tile past ``tiles_used`` multiplies nothing: it names the block
         the last step of the tile before it named, and copies none."""
         live = i < used[0]
-        return (tg[i], jnp.where(live, k, nk - 1), jnp.where(live, j, nj - 1))
+        k, j = jnp.where(live, k, nk - 1), jnp.where(live, j, nj - 1)
+        return weights_at(i, j, k, tg, used)
 
     return pallas_call(
-        _kernel,
+        functools.partial(_kernel, k_last=k_last),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n_tiles, nj, nk),
             in_specs=[
                 pl.BlockSpec((tm, tk), lambda i, j, k, tg, used: (i, k)),
                 # a whole matrix a block stays put by itself
-                pl.BlockSpec((None, tk, tn), weights_at if nj * nk == 1
+                pl.BlockSpec((None, tn, tk) if k_last else (None, tk, tn),
+                             weights_at if nj * nk == 1
                              else weights_at_or_where_they_were),
             ],
             out_specs=pl.BlockSpec((tm, tn),

@@ -33,11 +33,29 @@ from __future__ import annotations
 import math
 from typing import Optional
 
-__all__ = ["switch_moe", "routed_moe", "moe_params", "EXPERT_LEAVES"]
+__all__ = ["ACTIVATIONS", "switch_moe", "routed_moe", "moe_params",
+           "EXPERT_LEAVES"]
 
 # the experts' matrices in a parameter tree: gate (or the one up
 # projection), down, and with gated experts up
 EXPERT_LEAVES = ("w1", "w2", "w3")
+
+
+def _relu2(x):
+    import jax.numpy as jnp
+
+    return jnp.square(jnp.maximum(x, 0))
+
+
+def _gelu(x):
+    import jax
+
+    return jax.nn.gelu(x)
+
+
+# what an ungated expert (and an ungated shared expert) puts between its two
+# matrices, by ``TransformerConfig.moe_act``'s word
+ACTIVATIONS = {"gelu": _gelu, "relu2": _relu2}
 
 
 def moe_params(rng, d_model: int, d_ff: int, n_experts: int,
@@ -181,13 +199,15 @@ def _window_rows(picks: int, tm: int, held: int, width: int) -> int:
 def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
                kernel: bool = False, renorm: bool = False,
                score: str = "softmax", scale: float = 1.0, held=None,
-               zero: int = 0):
+               zero: int = 0, act: str = "gelu"):
     """Dropless top-k MoE layer over the experts this device holds: x (B, T,
     D) local tokens → (B, T, D).
 
     ``params``: ``wg`` (D, E) the router; ``w1`` (E, D, F) and ``w2``
-    (E, F, D) the experts, ``w2(gelu(w1 x))``; with ``gated`` also ``w3``
-    (E, D, F), and an expert is ``w2(silu(w1 x) * w3 x)``.  With ``layer``
+    (E, F, D) the experts, ``w2(act(w1 x))`` (``act`` of ``ACTIVATIONS``:
+    "gelu", or "relu2", ``relu(.)^2``: two grouped products an expert); with
+    ``gated`` also ``w3`` (E, D, F), and an expert is ``w2(silu(w1 x) * w3
+    x)``, three.  With ``layer``
     (an index, traced or not) the three are the whole stacks over layers,
     (L, E, ·, ·), and the kernel reads layer ``layer``'s matrices out of
     them: a layer loop that sliced them first would copy every expert of
@@ -249,7 +269,8 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
     window runs.  The same picks, weights and products as the whole
     layout's; a token's held picks are added by expert, not by pick.
 
-    ``tm`` follows the rows an expert gets on average
+    ``tm`` follows the rows an expert gets on average, with room for their
+    spread where a step's handful meets a matrix too large for one block
     (``ops.grouped_matmul.tile_rows``): 512 for a prefill of thousands of
     rows an expert, 16 for a cached step's handful, where the layer is the
     stream of every expert's weights.  One function, two tilings; and the
@@ -274,7 +295,8 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
     E = params["wg"].shape[-1]
     cdt = x.dtype
     xf = jnp.asarray(x).reshape(n, D)
-    tm = tile_rows(n * k / E)
+    F = params["w1"].shape[-1]
+    tm = tile_rows(n * k / E, ((D, F), (F, D)), jnp.dtype(cdt).itemsize)
     if zero and held is None:
         held = (0, E - zero)            # every expert that has matrices
     first_zero = E - zero
@@ -365,7 +387,7 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
                 hid = jax.nn.silu(hid) * matmul(rows, stacks["w3"],
                                                 tile_group, used)
             else:
-                hid = jax.nn.gelu(hid)
+                hid = ACTIVATIONS[act](hid)
             out = matmul(hid, stacks["w2"], tile_group, used)
         with scope("moe.combine"):
             if y is None:

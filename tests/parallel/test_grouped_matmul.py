@@ -25,6 +25,21 @@ def test_tile_rows_follow_the_groups_size(rows_a_group, want):
     assert tile_rows(rows_a_group) == want
 
 
+def test_a_steps_tile_has_room_for_the_spread_where_the_matrix_goes_by_blocks():
+    """Nemotron's cached step: 12 rows an expert on the mean, matrices of
+    2688 x 1856 that no tile holds whole, so 64 rows a tile (the smallest over
+    12 + 6 x 3.5); a matrix that is
+    one block (Kimi-Linear's, the same 12 rows), a mean of more than a tile,
+    and a handful whose spread still fits 16 rows (LongCat's 2.5) stay."""
+    nemotron = ((2688, 1856), (1856, 2688))
+    assert weight_block(16, *nemotron[0], 2) != nemotron[0]
+    assert tile_rows(12, nemotron) == 64
+    assert tile_rows(12, ((2304, 1024), (1024, 2304))) == 16
+    assert tile_rows(2.5, ((6144, 2048), (2048, 6144))) == 16
+    assert tile_rows(56, ((6144, 2048), (2048, 6144))) == 32
+    assert tile_rows(384, nemotron) == 256
+
+
 def _case(tm, K, N, G, tile_group, seed=0, dtype=np.float32):
     rng = np.random.default_rng(seed)
     tile_group = np.asarray(tile_group, np.int32)
@@ -46,7 +61,8 @@ _KIMI = dict(G=4, tile_group=[0, 0, 2, 3, 3, 3], used=4, dtype=jnp.bfloat16)
 # group 0 has three tiles, group 1 none, and the last two tiles hold no row.
 _PREFILL = dict(G=4, tile_group=[0, 0, 0, 2, 3, 3, 3, 3], used=6,
                 dtype=jnp.bfloat16)
-# A K of which not one lane tile fits whole beside 512 rows of float32.
+# A K of which not one lane tile fits whole beside 512 rows of float32: the
+# whole N under blocks of K where that fits, blocks of both where N is wide.
 _DEEP = dict(G=3, tile_group=[0, 0, 2, 2], used=3, dtype=np.float32)
 
 
@@ -54,7 +70,9 @@ _DEEP = dict(G=3, tile_group=[0, 0, 2, 2], used=3, dtype=np.float32)
     pytest.param(16, 64, 32, (64, 32), _SPARSE, id="whole-matrix-blocks"),
     pytest.param(128, 2048, 1024, (2048, 512), _SPARSE,
                  id="whole-k-blocks-of-n"),
-    pytest.param(512, 4096, 256, (1024, 256), _DEEP,
+    pytest.param(512, 4096, 256, (2048, 256), _DEEP,
+                 id="whole-n-blocks-of-k"),
+    pytest.param(512, 4096, 2048, (1024, 512), _DEEP,
                  id="k-and-n-in-blocks"),
     pytest.param(16, 2304, 1024, (2304, 1024), _KIMI,
                  id="over-4-mib-step-w1"),
@@ -74,6 +92,14 @@ _DEEP = dict(G=3, tile_group=[0, 0, 2, 2], used=3, dtype=np.float32)
                  id="olmoe-prefill-w1"),
     pytest.param(512, 1024, 2048, (1024, 1024), _PREFILL,
                  id="olmoe-prefill-w2"),
+    # Nemotron's experts, 2688 x 1856: fourteen and a half lane tiles wide,
+    # so the whole N under blocks of K, the stack handed over K last
+    pytest.param(16, 2688, 1856, (896, 1856), _KIMI,
+                 id="nemotron-step-w1-k-last"),
+    pytest.param(512, 2688, 1856, (384, 1856), _PREFILL,
+                 id="nemotron-prefill-w1-k-last"),
+    pytest.param(512, 1856, 2688, (1856, 896), _PREFILL,
+                 id="nemotron-prefill-w2"),
 ])
 def test_each_tile_multiplies_its_groups_matrix(tm, K, N, block, case):
     tile_group, n_used, dtype = case["tile_group"], case["used"], case["dtype"]
@@ -177,10 +203,18 @@ def test_the_working_set_is_counted_as_the_kernel_holds_it():
     assert weight_block(512, 1024, 2304, 2) == (1024, 1152)
     # under the ridge as well, where the matrix cannot be held twice
     assert weight_block(16, 4096, 2048, 2) == (4096, 512)
-    # both dimensions only where not one lane tile of a whole K fits
+    # where not one lane tile of a whole K fits, the whole N under the
+    # widest block of K that does (the same bytes: rows and matrix once);
+    # and so for a width that has no block of whole lane tiles (Nemotron's
+    # 1856), whose matrix does not fit whole
     assert _working_set_bytes(512, 8192, 128, 2) > _VMEM_BUDGET_BYTES
-    assert weight_block(512, 8192, 2048, 2) == (1024, 512)
-    assert weight_block(512, 4096, 256, 4) == (1024, 256)
+    assert weight_block(512, 4096, 256, 4) == (2048, 256)
+    assert _working_set_bytes(16, 2688, 1856, 2) > _VMEM_BUDGET_BYTES
+    assert weight_block(16, 2688, 1856, 2) == (896, 1856)
+    assert weight_block(512, 2688, 1856, 2) == (384, 1856)
+    assert weight_block(512, 1856, 2688, 2) == (1856, 896)
+    # both dimensions only where neither fits
+    assert weight_block(512, 8192, 4096, 2) == (1024, 512)
 
 
 def test_backward_is_ragged_dots_own():
