@@ -85,7 +85,12 @@ SCOPES = (
     "kda.scan",         # ... the chunked recurrence over a whole sequence
     "kda.update",       # ... one cached step's recurrence: from reading the
                         # layer's matrix state out of the carry to writing it
-    "retention.scan",   # power retention's chunked form over a whole sequence
+    "retention.scan",   # power retention over a whole sequence: the chunked
+                        # form's scan, or inside it the two that follow
+    "retention.direct",     # ... a prefill's quadratic form summed over the
+                            # whole prompt (the kernel) and the quotient
+    "retention.end_state",  # ... the state after the last position, formed
+                            # once
     "retention.update",     # ... one cached step's recurrence: from reading
                             # the layer's state out of the carry to writing
                             # it back, the expansion, the read for the query

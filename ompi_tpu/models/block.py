@@ -210,7 +210,8 @@ def mixer(cfg, comm, lp, h, positions, carry=None, impl: str = "jnp",
             # each K/V head before its queries
             k_all, v_all = (jnp.repeat(y, hl // hkv, axis=2) for y in (k, v))
     if rt is not None:
-        o, out = retention.core(cfg, q, k, v, logg, carry and carry[:2])
+        o, out = retention.core(cfg, q, k, v, logg, carry and carry[:2],
+                                forward_only)
     elif carry is None and cfg.index is None:
         layout = tfm._ATTENTION_LAYOUT.get(cfg.attention, "gathered")
         with scope("attention"):

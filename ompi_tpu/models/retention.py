@@ -24,17 +24,25 @@ normaliser a K/V head, which is what a decoder carries (no K/V cache):
 :func:`chunked` is the whole-sequence form (trainer, prefill): inside a chunk
 the quadratic form with the decays' differences, every exponent at most
 zero, and no expansion; across chunks ``S`` and ``z`` decayed to the chunk's
-end.  :func:`update` is one position against the carried state
+end.  Reading the past through the state costs a query head ``2 D d`` FLOP
+whatever the chunk, a key read directly ``4 d``: up to :data:`CROSSOVER`
+positions a decoder's prefill on TPUs (:func:`direct`: forward only, static
+facts alone) sums the first pair of equations over the whole prompt in one
+pallas kernel (``ops/retention_prefill.py``) and forms ``S`` and ``z`` once,
+after the last position (:func:`end_state`); a trainer, the CPU, a longer
+prompt and a length that does not tile keep the scan over chunks.
+:func:`update` is one position against the carried state
 (:func:`read`, once for the query heads of its K/V head, then
 :func:`write`).  The gate, the cumulative decays, ``S``, ``z`` and the
 quotient are float32 whatever the compute type;
 a cached step's products against the state are float32 too, the chunked
 form's run in the compute type and add up in float32, as the other mixers'.
-Everything here is ``jax.numpy`` and ``lax`` but a cached step's pass over
-the matrix state, which on TPUs is one pallas kernel over the layer where it
-lies in the stack (``ops/retention_update.py``; ``retention_update.block`` is
-the rule, from static facts alone): :func:`read`'s sums over the state and
-:func:`write`'s decay and outer product in one sweep (:class:`InPlace`).
+Everything here is ``jax.numpy`` and ``lax`` but that prefill and a cached
+step's pass over the matrix state, which on TPUs is one pallas kernel over
+the layer where it lies in the stack (``ops/retention_update.py``;
+``retention_update.block`` is the rule, from static facts alone):
+:func:`read`'s sums over the state and :func:`write`'s decay and outer
+product in one sweep (:class:`InPlace`).
 
 What the state's layout is: ``phi(u)`` holds ``u_a u_{a+s}`` (indices modulo
 the head width ``d``) for the shifts ``s = 0 .. d/2``, a row of ``d`` a shift:
@@ -55,7 +63,8 @@ import math
 
 import numpy as np
 
-__all__ = ["Retention", "retention_config", "phi", "chunked", "read", "write",
+__all__ = ["Retention", "retention_config", "phi", "chunked", "direct",
+           "end_state", "CROSSOVER", "read", "write",
            "update", "InPlace", "core", "log_gate", "leaf_names", "init_leaves",
            "state_shapes", "carry", "carried", "grows", "check_mesh"]
 
@@ -80,6 +89,18 @@ class Retention:
 
 
 _FIELDS = {f.name for f in dataclasses.fields(Retention)}
+
+# The longest whole sequence a prefill reads directly (:func:`direct`).  A
+# query reads what came before it through the state for 2 D d FLOP (2.13 M a
+# head of 128) and directly for 4 d a key, so over a causal sequence the two
+# meet at D = 8320 positions, where the authors too switch from a K/V cache
+# to the state.  On a v5e, in a loop at cell 8's sizes (2 sequences, 40 over
+# 8 heads), the direct form led the chunked one at every length the kernel
+# takes: 2.30 ms for 7.91 at 1024 positions, 4.95 for 15.38 at 2048, 11.61
+# for 30.44 at 4096, 29.25 for 60.69 at 8192 (``PERF.md`` section 6, PR 63),
+# so this is the kernel's longest sequence and the chip's crossover lies
+# past it.
+CROSSOVER = 8192
 
 
 def retention_config(**sizes):
@@ -189,7 +210,86 @@ def log_gate(rt: Retention, gamma):
     return jax.nn.log_sigmoid(gamma.astype(jnp.float32) + rt.gate_offset)
 
 
-def chunked(q, k, v, logg, chunk: int, eps: float):
+def direct(forward_only: bool, tpu: bool, T: int, d: int) -> bool:
+    """Whether the whole-sequence form of ``T`` positions of heads ``d``
+    wide sums its quadratic form over the whole sequence in the kernel
+    (:func:`_direct`) and not chunk by chunk through the state
+    (:func:`chunked`'s scan), from static facts alone: no gradient will be
+    asked (the kernel has no backward pass), the trace is for TPUs (the
+    kernel compiles for nothing else), the lengths tile, and the sequence is
+    no longer than :data:`CROSSOVER`."""
+    from ompi_tpu.ops import retention_prefill
+
+    return bool(forward_only and tpu and T <= CROSSOVER
+                and retention_prefill.tiles(T, d))
+
+
+def _blocks(y, Q: int):
+    """(B, T, ...) -> (nc, B, Q, ...), the tail padded with zeros."""
+    import jax.numpy as jnp
+
+    B, T = y.shape[:2]
+    nc = -(-T // Q)
+    y = jnp.pad(y, [(0, 0), (0, nc * Q - T)] + [(0, 0)] * (y.ndim - 2))
+    return jnp.moveaxis(y.reshape(B, nc, Q, *y.shape[2:]), 1, 0)
+
+
+def end_state(k, v, logg, chunk: int):
+    """The state after the last position of whole sequences from a zero
+    state, formed once: ``S = sum_j exp(c_T - c_j) phi(k_j) v_j^T`` (B, G, D,
+    d) and ``z`` alike (B, G, D), float32, from k, v (B, T, G, d) and the
+    log decays logg (B, T, G).  :func:`chunked`'s arithmetic for a chunk's
+    end over the whole sequence: ``chunk`` positions of ``phi(k)`` held at a
+    time, each under its decay to the sequence's end, ``exp((c_end - c_j) +
+    rest)``, the decays summed inside its chunk and ``rest`` the chunks'
+    after it (every exponent at most zero, and none the difference of two
+    sums over the sequence), so nothing is decayed between chunks."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    f32, cdt = jnp.float32, k.dtype
+    B, T, G, d = k.shape
+    Q, D = min(chunk, T), state_dim(d)
+    # a padded position has k = 0 and log g = 0: it adds and decays nothing
+    k, v, logg = (_blocks(y, Q) for y in (k, v, logg.astype(f32)))
+    cs = jnp.cumsum(logg, axis=2)                       # (nc, B, Q, G)
+    rest = jnp.cumsum(cs[::-1, :, -1:], axis=0)[::-1] - cs[:, :, -1:]
+    w = jnp.exp(cs[:, :, -1:] - cs + rest)
+
+    def one(state, block):
+        S, z = state
+        k, v, w = block
+        pk = phi(k) * w[..., None]                      # (B, Q, G, D)
+        return (S + jnp.einsum("bkgn,bkgv->bgnv", pk.astype(cdt), v,
+                               preferred_element_type=f32),
+                z + pk.sum(axis=1)), None
+
+    state, _ = lax.scan(
+        one, (jnp.zeros((B, G, D, d), f32), jnp.zeros((B, G, D), f32)),
+        (k, v, w))
+    return state
+
+
+def _direct(q, k, v, logg, chunk: int, eps: float):
+    """:func:`chunked`'s results where :func:`direct` says so: position t
+    reads every j <= t through ``exp(c_t - c_j) (q_t . k_j)^2 / d``, ``c``
+    the decay's running sum over the whole sequence, in one kernel
+    (``ops/retention_prefill.py``), and the state at the last position is
+    formed once (:func:`end_state`).  ``phi(q)`` is never formed."""
+    from ompi_tpu.core.scopes import scope
+    from ompi_tpu.ops.retention_prefill import retention_prefill
+
+    B, T, H, d = q.shape
+    with scope("retention.direct"):
+        num, den = retention_prefill(q, k, v, logg, _power)
+        y = _quotient(num, den, eps).reshape(B, T, H, d)
+    with scope("retention.end_state"):
+        S, z = end_state(k, v, logg, chunk)
+    return y, S, z
+
+
+def chunked(q, k, v, logg, chunk: int, eps: float,
+            forward_only: bool = False):
     """The whole-sequence form from a zero state, ``chunk`` positions at a
     time.  q: (B, T, H, d); k, v: (B, T, G, d), K/V head ``g`` serving the
     query heads ``g H/G .. (g + 1) H/G - 1``; logg: (B, T, G) float32, at
@@ -202,19 +302,24 @@ def chunked(q, k, v, logg, chunk: int, eps: float):
     ``z``; the state at the chunk's end is ``exp(c_end) S + sum_j exp(c_end
     - c_j) phi(k_j) v_j^T``.  A length that is no multiple of the chunk is
     padded with positions of k = 0 and log g = 0, which leave the state as
-    it is.  The products run in q's type and add up in float32."""
+    it is.  The products run in q's type and add up in float32.
+
+    ``forward_only`` (a decoder's prefill: no gradient will be asked): where
+    :func:`direct` says so, the same results by :func:`_direct`, the sums
+    over the whole sequence in one kernel and the state formed once at the
+    end; anywhere else what follows."""
     import jax.numpy as jnp
     from jax import lax
 
+    from ompi_tpu.models import kda
+
     f32, cdt = jnp.float32, q.dtype
     B, T, H, d = q.shape
+    if direct(forward_only, kda._traced_for_tpus(), T, d):
+        return _direct(q, k, v, logg, chunk, eps)
     G = k.shape[2]
     R, Q = H // G, min(chunk, T)
     nc = -(-T // Q)
-
-    def blocks(y):      # (B, T, ...) -> (nc, B, Q, ...), the tail padded
-        y = jnp.pad(y, [(0, 0), (0, nc * Q - T)] + [(0, 0)] * (y.ndim - 2))
-        return jnp.moveaxis(y.reshape(B, nc, Q, *y.shape[2:]), 1, 0)
 
     causal = jnp.tril(jnp.ones((Q, Q), bool))
 
@@ -255,7 +360,7 @@ def chunked(q, k, v, logg, chunk: int, eps: float):
     D = state_dim(d)
     (S, z), y = lax.scan(
         one, (jnp.zeros((B, G, D, d), f32), jnp.zeros((B, G, D), f32)),
-        tuple(blocks(t) for t in (q, k, v, logg)))
+        tuple(_blocks(t, Q) for t in (q, k, v, logg)))
     return jnp.moveaxis(y, 0, 1).reshape(B, nc * Q, H, d)[:, :T], S, z
 
 
@@ -390,13 +495,14 @@ def _state_before(stack, layer):
                                     keepdims=False).astype(jnp.float32)
 
 
-def core(cfg, q, k, v, logg, carry=None):
+def core(cfg, q, k, v, logg, carry=None, forward_only: bool = False):
     """The core on a layer's rotated q (B, T, H, d) and k, its v (B, T, G,
     d) and ``log g`` (B, T, G).
 
-    ``carry`` None: whole sequences from a zero state; returns ``(y, (S,
-    z))``, y (B, T, H, d) float32 and the layer's state after the last
-    position as the carry stores it.  ``carry = ((S, z), layer)``: T == 1
+    ``carry`` None: whole sequences from a zero state (:func:`chunked`,
+    which is told whether a gradient may be asked: ``forward_only``); returns
+    ``(y, (S, z))``, y (B, T, H, d) float32 and the layer's state after the
+    last position as the carry stores it.  ``carry = ((S, z), layer)``: T == 1
     against layer ``layer`` of the stacks (:func:`carry`'s), read and written
     in place, by the kernel where :func:`_state_before` hands the matrix
     states over as they lie and by :func:`read`, a barrier and :func:`write`
@@ -410,7 +516,9 @@ def core(cfg, q, k, v, logg, carry=None):
     G = k.shape[2]
     if carry is None:
         with scope("attention"), scope("retention.scan"):
-            y, S, z = chunked(q, k, v, logg, rt.chunk, rt.eps)
+            # every argument by position: a control's wrapper of ``chunked``
+            # hands what follows the gate on as it came
+            y, S, z = chunked(q, k, v, logg, rt.chunk, rt.eps, forward_only)
         return y, (S.astype(rt.state_dtype), z.astype(rt.state_dtype))
     (S_c, z_c), layer = carry
     now = k[:, 0], v[:, 0], logg[:, 0]

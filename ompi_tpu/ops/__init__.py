@@ -13,7 +13,12 @@ rest.  The latent mixer (``models/mla.py``) takes ``latent_attention`` for a
 prefill from the length where it was measured to win (``mla.KERNEL_FROM``)
 and ``latent_decode`` for a cached step over a cache of whole blocks of 1024
 positions, both only in a trace for TPUs; the ``jax.numpy`` forms anywhere
-else.
+else.  Power retention (``models/retention.py``) takes ``retention_prefill``
+for a decoder's prefill of up to ``retention.CROSSOVER`` positions
+(``retention.direct``: forward only, whole tiles) and ``retention_update``
+for a cached step over a float32 state of heads 128 wide
+(``retention_update.block``), again only in a trace for TPUs; its trainer and
+every other length keep the chunked ``jax.numpy`` form.
 """
 
 from ompi_tpu.ops.flash_attention import flash_attention, flash_attention_lse
