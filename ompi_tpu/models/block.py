@@ -31,6 +31,7 @@ whole-sequence pass collected for it as those stacks hold it (:func:`written`).
 from __future__ import annotations
 
 import dataclasses
+import inspect
 import types
 
 __all__ = ["mixer", "block", "mechanisms", "carry", "carried", "written",
@@ -279,7 +280,12 @@ def _block(cfg, comm, lp, h, positions, carry, **how):
     else:
         from ompi_tpu.models import ssm
 
-        s, *states = ssm.mixer(cfg, lp, x, carry and (*stacks[1], layer))
+        # told what the pass is for where it takes that (a benchmark's
+        # control puts a mixer without the argument in its place)
+        told = {k: v for k, v in how.items() if k == "forward_only"
+                and k in inspect.signature(ssm.mixer).parameters}
+        s, *states = ssm.mixer(cfg, lp, x, carry and (*stacks[1], layer),
+                               **told)
         with scope("attn_proj"):
             h = h + a * hy.attention_out_multiplier + s
     if cfg.moe_experts:

@@ -30,8 +30,10 @@ lp, h, carry=None)``, the layer's mixer with its norm and its residual add,
 over whole sequences (returns ``(h, *states)``, the states in ``buffers``'
 order, a growing one as long as the sequences) and, T == 1, against the
 layer's own buffers, with the position after them where the module says
-``POSITIONED`` (returns ``(h, *buffers)``), and with the communicator
-(``comm=``) where it says ``MESHED``; and, where a layer's place in the
+``POSITIONED`` (returns ``(h, *buffers)``), with the communicator
+(``comm=``) where it says ``MESHED``, and told whether a gradient may be
+asked of whole sequences where its ``mixer`` takes ``forward_only=``;
+and, where a layer's place in the
 model is part of its arithmetic, ``constants(sizes, layer)``, what the plan
 hands the mixer beside its leaves.
 
@@ -56,6 +58,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import inspect
 from typing import Any
 
 import numpy as np
@@ -454,11 +457,14 @@ def _mlp(cfg, comm, params, layer: int, kind: str, h, branch: bool = False):
 
 
 def _row(cfg, comm, params, layer: int, mixer: str, mlp: str, lp, h,
-         landing, carry=None):
+         landing, carry=None, forward_only: bool = False):
     """Row ``layer`` of the plan on the stream ``h``: its mixer on the leaves
     ``lp`` (against ``carry`` in a cached step), the branches that read
     there, its MLP, and the branches that land there (``landing``) added; a
     half the row lacks (``mixer`` or ``mlp`` None) is passed over.
+    ``forward_only`` (``backbone``'s) goes to a kind whose mixer takes it
+    (read off the function, so that one a benchmark's control puts in its
+    place without the argument is called without it).
     Returns ``(h, what the mixer hands back, the branches begun that land
     later: row -> those that land there)``."""
     from ompi_tpu.core.scopes import scope, second
@@ -469,9 +475,12 @@ def _row(cfg, comm, params, layer: int, mixer: str, mlp: str, lp, h,
         states = []
         if mixer is not None:
             module = _module(mixer)
-            meshed = getattr(module, "MESHED", False)
-            h, *states = module.mixer(cfg, lp, h, carry=carry,
-                                      **({"comm": comm} if meshed else {}))
+            told = {}
+            if getattr(module, "MESHED", False):
+                told["comm"] = comm
+            if "forward_only" in inspect.signature(module.mixer).parameters:
+                told["forward_only"] = forward_only
+            h, *states = module.mixer(cfg, lp, h, carry=carry, **told)
         begun = {}
         for kind, reads, lands in pl.branches:
             if reads == layer:
@@ -521,7 +530,7 @@ def _own_program(layer):
 
 
 def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
-             grad_axes=None):
+             grad_axes=None, forward_only: bool = False):
     """``transformer._local_backbone`` of a configuration with a plan: the
     per-device forward through the last norm, a python loop over the plan.
     Returns ``(h, aux)`` (aux 0: the dropless experts have no balance term),
@@ -532,7 +541,9 @@ def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
     The embedding comes times the plan's ``scale_emb`` and ``h`` over its
     ``head_divisor``.  ``grad_axes``: the
     layers' leaves' gradients are summed over those axes where the backward
-    pass reaches the start of the loop."""
+    pass reaches the start of the loop.  ``forward_only`` (a decoder's
+    prefill): no gradient will be asked of this pass, so a kind may take a
+    kernel that has no backward pass."""
     import jax
     import jax.numpy as jnp
 
@@ -552,7 +563,8 @@ def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
     def layer_fn(layer, mixer, mlp):
         def run(h, params, landing):
             lp = _mixer_leaves(cfg, params, layer, mixer)
-            return _row(cfg, comm, params, layer, mixer, mlp, lp, h, landing)
+            return _row(cfg, comm, params, layer, mixer, mlp, lp, h, landing,
+                        forward_only=forward_only)
 
         if cfg.remat in (True, "full"):
             run = jax.checkpoint(run)

@@ -13,8 +13,9 @@ leaves, and :func:`mixer`, the one function both paths call: the whole
 sequence (trainer, prefill: a causal convolution over the sequence and the
 chunked scan, from a zero state) and one position against a carried state
 (its states in :func:`carry`'s stacks: the convolution from its last
-inputs, the recurrence once).  Everything here is ``jax.numpy`` and ``lax``:
-no kernel.
+inputs, the recurrence once).  Everything here is ``jax.numpy`` and ``lax``
+but a decoder's prefill of more than one chunk on TPUs, whose scan is the
+pallas ``ops/ssm_scan.py`` (:func:`fused` is the rule).
 
 The same mixer alone in a layer, ``h <- h + mixer(RMSNorm(h; ln1))`` with no
 multiplier anywhere, is the kind "ssm" of a layer plan (``models/plan.py``):
@@ -39,8 +40,8 @@ import types
 import numpy as np
 
 __all__ = ["Mamba2", "HybridBlock", "hybrid_config", "mixer", "chunked_scan",
-           "init_leaves", "leaf_names", "state_shapes", "carry", "carried",
-           "check_mesh", "PLAN_KIND"]
+           "fused", "init_leaves", "leaf_names", "state_shapes", "carry",
+           "carried", "check_mesh", "PLAN_KIND"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -274,6 +275,22 @@ def chunked_scan(x, dt, a, b, c, chunk: int):
     return (y.reshape(B, nc * Q, H, P)[:, :T], last.reshape(B, H, P, N))
 
 
+def fused(sz: Mamba2, forward_only: bool, tpu: bool, T: int, dtype) -> bool:
+    """Whether whole sequences of ``T`` positions in the type ``dtype`` are
+    scanned by the one kernel (``ops/ssm_scan.py``) and not by
+    :func:`chunked_scan`, from static facts alone: no gradient will be asked
+    (the kernel has no backward pass), the trace is for TPUs (the kernel
+    compiles for nothing else), the sequence is more than one chunk (one
+    chunk has no recurrence and nothing to keep in VMEM: the kernel's module
+    is not imported for it) and the sizes tile."""
+    if not (forward_only and tpu and T > sz.chunk):
+        return False
+    from ompi_tpu.ops import ssm_scan
+
+    return ssm_scan.tiles(T, sz.chunk, sz.head_dim, sz.d_state,
+                          sz.n_heads // sz.n_groups, dtype)
+
+
 def _conv_before(conv_c, layer):
     """A cached step's last ``d_conv - 1`` inputs of layer ``layer`` of the
     stack ``conv_c``; ``layer`` None: ``conv_c`` is the layer's own buffer.
@@ -310,10 +327,12 @@ def _written(into, new, layer):
                                     (layer,) + (0,) * new.ndim)
 
 
-def mixer(cfg, lp, u, carry=None):
+def mixer(cfg, lp, u, carry=None, forward_only: bool = False):
     """The mixer branch of one layer of the hybrid block on the block's
     normed input ``u`` (B, T, D), without its residual add, under the
-    block's multipliers.
+    block's multipliers.  ``forward_only`` (a decoder's prefill): no
+    gradient will be asked, so whole sequences may take :func:`fused`'s
+    kernel.
 
     ``carry`` None: whole sequences from a zero state; returns
     ``(s, conv_state, ssm_state)``, the layer's states after the last
@@ -325,20 +344,23 @@ def mixer(cfg, lp, u, carry=None):
     hy = cfg.hybrid
     return _mix(hy, cfg.norm_eps, lp, u, carry, multipliers=(
         hy.ssm_in_multiplier, _column_multipliers(hy),
-        hy.ssm_out_multiplier))
+        hy.ssm_out_multiplier), forward_only=forward_only)
 
 
-def _mix(sz: Mamba2, eps: float, lp, u, carry=None, multipliers=None):
+def _mix(sz: Mamba2, eps: float, lp, u, carry=None, multipliers=None,
+         forward_only: bool = False):
     """:func:`mixer`'s arithmetic on the sizes ``sz``, the hybrid block's
     and the plan's kind's: ``carry`` as :func:`mixer`'s, its ``layer`` None
     where the two states are the layer's own buffers and not stacks.
     ``multipliers``: the hybrid block's, on the normed input, on the input
-    projection's columns (a vector) and on the output; None: none."""
+    projection's columns (a vector) and on the output; None: none.
+    ``forward_only`` as :func:`mixer`'s."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     from ompi_tpu.core.scopes import scope
+    from ompi_tpu.models.kda import _traced_for_tpus
 
     f32, cdt = jnp.float32, u.dtype
     B, T, _ = u.shape
@@ -369,9 +391,18 @@ def _mix(sz: Mamba2, eps: float, lp, u, carry=None, multipliers=None):
     b, c = b.reshape(B, T, G, N), c.reshape(B, T, G, N)
     dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
     a = -jnp.exp(lp["a_log"].astype(f32))
+    kernel = carry is None and fused(sz, forward_only, _traced_for_tpus(), T,
+                                     cdt)
     if carry is None:
         with scope("ssm.scan"):
-            y, ssm_out = chunked_scan(x, dt, a, b, c, sz.chunk)
+            if kernel:
+                from ompi_tpu.ops.ssm_scan import ssm_scan
+
+                # x, B and C as the convolution left them, one array; y
+                # comes with the skip D x and as the gate reads it
+                y, ssm_out = ssm_scan(xbc, dt, a, lp["ssm_d"], G, N)
+            else:
+                y, ssm_out = chunked_scan(x, dt, a, b, c, sz.chunk)
     else:
         with scope("ssm.update"):
             h = _state_before(ssm_c, layer).reshape(B, G, H // G, P, N)
@@ -383,7 +414,8 @@ def _mix(sz: Mamba2, eps: float, lp, u, carry=None, multipliers=None):
                            c.astype(f32).reshape(B, G, N)).reshape(B, 1, H, P)
             ssm_out = _written(ssm_c, h.reshape(B, H, P, N), layer)
     with scope("ssm_proj"):
-        y = y + lp["ssm_d"].astype(f32)[:, None] * x.astype(f32)
+        if not kernel:
+            y = y + lp["ssm_d"].astype(f32)[:, None] * x.astype(f32)
         y = y.reshape(B, T, sz.d_ssm) * jax.nn.silu(z.astype(f32))
         # gate, then an RMSNorm over each group with one scale of d_ssm
         yg = y.reshape(B, T, G, sz.d_ssm // G)
@@ -424,9 +456,10 @@ def _kind_buffers(cfg, sz: Mamba2, batch: int, t_max: int) -> tuple:
              None))
 
 
-def _kind_mixer(cfg, lp, h, carry=None):
+def _kind_mixer(cfg, lp, h, carry=None, forward_only: bool = False):
     """One layer's mixer on the layer's input ``h`` (B, T, D): the norm, the
-    mixer and the residual add, no multiplier.
+    mixer and the residual add, no multiplier; ``forward_only`` as
+    :func:`mixer`'s.
 
     ``carry`` None: whole sequences from a zero state; returns ``(h,
     conv_state, ssm_state)`` as :func:`mixer` does.  ``carry = (conv_c,
@@ -440,7 +473,7 @@ def _kind_mixer(cfg, lp, h, carry=None):
         # plants a wrong one there while a decoder is traced
         u = tfm._rmsnorm(h, lp["ln1"], cfg.norm_eps)
     s, *states = _mix(cfg.plan.ssm, cfg.norm_eps, lp, u,
-                      carry and (*carry, None))
+                      carry and (*carry, None), forward_only=forward_only)
     with scope("ssm_proj"):
         return (h + s, *states)
 

@@ -727,7 +727,8 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     the backward of its matmul is, the other leaves' where the layer's
     backward ends (:func:`_sum_in_backward`).  ``forward_only`` (a
     decoder's prefill): no gradient will be asked of this pass, so it may
-    take a kernel that has no backward pass (an index's masked attention).
+    take a kernel that has no backward pass (an index's masked attention,
+    power retention's direct sums, a state-space mixer's scan).
     """
     import jax
     import jax.numpy as jnp
@@ -742,7 +743,7 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
         from ompi_tpu.models import plan
 
         return plan.backbone(cfg, comm, params, tokens, collect_kv,
-                             grad_axes)
+                             grad_axes, forward_only)
     for mechanism in blk.mechanisms(cfg):
         mechanism.check_mesh(cfg, comm.mesh)
     cdt, hy, T = jnp.dtype(cfg.compute_dtype), cfg.hybrid, tokens.shape[1]

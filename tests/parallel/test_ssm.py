@@ -4,13 +4,22 @@ at the configuration's tiny sizes, float32, seeded, on the CPU: the chunked
 scan against the recurrence, prefill then cached steps against the full
 forward on logits, loss and gradient of the train path, which K/V head a
 query head reads, every multiplier, the prefill in groups, and the layouts
-that are refused.  Agreement and control flow only: nothing here is a time.
+that are refused; and the scan as one kernel (``ops/ssm_scan.py``) where
+``ssm.fused`` says so: a decoder whose prompts are two chunks of widths that
+tile against the same reference, and, traced for a v5e that is described and
+not attached, which of the two cells' programs call it (cell 12's prefill six
+times; cell 5's never, and they are the text they were before the kernel
+came).  Agreement and control flow only: nothing here is a time.
 (Where the compiled step keeps its state is asked of the chip's compiler, in
 ``test_decode.py`` beside the same question of the K/V cache.)
 """
 
 import copy
 import dataclasses
+import hashlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +31,11 @@ from ompi_tpu.models import ssm
 from ompi_tpu.models import transformer as tfm
 from ompi_tpu.models.decode import _prefill_group, make_decoder
 from ompi_tpu.parallel.mesh import make_mesh
+from tests.parallel.test_kda_update import _pallas_calls
+from tests.parallel.test_kda_update_compiled import _program
+# the described chip, and the compile cache and interpret mode off around it
+from tests.parallel.test_selected_attention_compiled import (  # noqa: F401
+    chip, for_the_chip)
 
 CELL = "falcon-h1-34b.decode-128-64-b192"
 PARITY = 1e-4       # of a deviation of the logits; float32 on both sides
@@ -288,6 +302,100 @@ def test_the_dense_decoder_hands_its_logits_back_too():
     np.testing.assert_array_equal(np.asarray(tokens), plain)
     want = jax.jit(tfm.make_forward(cfg, mesh))(params, plain[:2])[:, 7:-1]
     assert logits.shape == (2, 5, 97) and error(logits, want) < PARITY
+
+
+# ---- the scan as one kernel ----------------------------------------------------
+
+CELL_12 = "nemotron-3-nano-30b-a3b.decode-1k-128-b256"
+# sha256 of the StableHLO of cell 5's two programs (the ``first`` job's and
+# the ``full`` job's) lowered for a described v5e, as they were at PR 63,
+# before ``ssm.mixer`` was told ``forward_only``: its prompts are one chunk
+# and its states 256 deep, so nothing of them may change.  A PR that changes
+# cell 5's programs on purpose replaces these.
+CELL_5_PROGRAMS = {
+    "decode_first":
+        "517068187efeb16b3e1920af4ca6451d1c3bbda33311b38db1caaff6f9bc9e70",
+    "decode_full":
+        "425f3ac62dbe148db2314c9db46044f0533740cb3d53cb88432722e4505bc78c",
+}
+
+
+def test_a_prefill_through_the_kernel_then_cached_steps_give_the_references_logits(
+        monkeypatch):
+    """``test_prefill_then_cached_steps...``'s form at widths the kernel
+    takes (2 groups of 2 heads 64 wide, states 128 deep, chunks of 128) and
+    prompts of two chunks, the decoder told that it is traced for TPUs: the
+    prefill's scan is the kernel (interpret mode here), the states it hands
+    over are carried by the cached steps, and every generated position's
+    logits are the reference's."""
+    from ompi_tpu.models import kda
+
+    config = copy.deepcopy(program.tiny(cells.resolve(CELL).config))
+    config.update(mamba_d_ssm=256, mamba_n_heads=4, mamba_d_head=64,
+                  mamba_d_state=128, mamba_chunk_size=128,
+                  max_position_embeddings=384)
+    # an interpreted kernel is a host callback, which no checkpoint holds
+    config["entry"]["options"].update(compute_dtype="float32", remat=None)
+    ref = program.reference(config)
+    cfg = program.program_config(config)
+    mesh = program.mesh(config, jax.devices()[:1])
+    params = program.init_params(
+        ref, config, program.param_shardings(config, cfg, mesh), seed=11)
+    prompts = prompts_of(cfg, 2, 256)
+    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: True)
+    decoder = make_decoder(cfg, mesh, max_new=3, keep_logits=2)
+    calls = [c.params["name"] for c in _pallas_calls(
+        jax.make_jaxpr(decoder)(params, prompts).jaxpr)]
+    assert calls == ["ssm_scan"]        # under the loop over layers, once
+    tokens, logits = decoder(params, prompts)
+    tokens, logits = np.asarray(tokens), np.asarray(logits)
+    np.testing.assert_array_equal(logits.argmax(-1), tokens[:, 256:])
+    want = ref.logits(ref.Shape.from_config(config), params,
+                      tokens)[:, 255:-1]
+    assert error(logits, want) < PARITY
+
+
+def _running_sums(jaxpr):
+    """The ``cumsum`` equations of ``jaxpr`` over floats (the experts'
+    offsets are running sums too, of counts), at any depth."""
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "cumsum"
+                and jnp.issubdtype(eqn.invars[0].aval.dtype, jnp.floating)):
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _running_sums(sub)
+
+
+def test_cell_12s_prefill_calls_the_kernel_six_times_and_sums_no_decay_outside(
+        chip, for_the_chip):
+    """The cell's two programs traced at its real sizes for the described
+    chip: the prefill scans each of its six Mamba-2 layers through the
+    kernel and ``chunked_scan``'s running sum is nowhere in it; the
+    generating program has no whole sequence and no such call."""
+    cell = cells.resolve(CELL_12)
+    job = cell.runner.build(cell.config, cell.traffic, chip)
+    scans = []
+    for which in (0, 1):
+        fn, args = _program(job, chip, which)
+        jaxpr = jax.make_jaxpr(fn)(*args)
+        scans.append([c for c in _pallas_calls(jaxpr.jaxpr)
+                      if c.params["name"] == "ssm_scan"])
+        assert not list(_running_sums(jaxpr.jaxpr))
+    assert [len(calls) for calls in scans] == [6, 0]
+    for call in scans[0]:
+        assert call.params["grid_mapping"].grid == (8, 8, 1)
+
+
+def test_cell_5s_programs_are_what_they_were(chip, for_the_chip):
+    cell = cells.resolve(CELL)
+    job = cell.runner.build(cell.config, cell.traffic, chip)
+    programs = job.programs()
+    assert set(programs) == set(CELL_5_PROGRAMS)
+    for name, (fn, args) in programs.items():
+        text = fn.lower(*args).as_text()
+        assert "custom_call" not in text, name
+        assert (hashlib.sha256(text.encode()).hexdigest()
+                == CELL_5_PROGRAMS[name]), name
 
 
 # ---- the scopes ----------------------------------------------------------------
