@@ -34,9 +34,11 @@ pooled keys, ``(B, Hkv, pooled, hd)``, one for every ``stride`` positions
 
 Two paths call the same :func:`block_scores` and :func:`chosen`.  **Whole
 sequences** (trainer, prefill): :func:`attend`, every pooled key first, then
-a slice of ``q_slice`` queries at a time against the keys so far: its scores
-against the pooled keys, its blocks, attention under the blocks' mask
-widened to positions.  **One position against the carry**
+a slice of ``q_slice`` queries at a time, the slices the iterations of a
+``lax.scan`` over one shape: a slice's scores against the sequence's pooled
+keys (its positions mask those not yet complete), its blocks, attention
+under the blocks' mask widened to positions, which the kernel stops reading
+at the slice's end.  **One position against the carry**
 (:func:`attend_cached`): the row and, where the position completes a
 kernel, the pooled key written first; the scores against the layer's pooled
 keys, the blocks, and the layer's rows streamed once under the mask.  The
@@ -189,10 +191,12 @@ def _positions(bs: BlockSelect, blocks, t, keys: int):
             & (jnp.arange(keys) <= t[:, None]))
 
 
-def _attention(q, k, v, mask, kernel: bool):
+def _attention(q, k, v, mask, kernel: bool, k_len=None):
     """Softmax attention of q (N, Tq, r, hd) over k, v (N, Tk, 1, hd) under
     mask (N, Tq, Tk), in q's type: the pallas kernel where ``kernel``, with
-    the jnp form's backward pass behind it (the kernel has none)."""
+    the jnp form's backward pass behind it (the kernel has none).  ``k_len``
+    (a traced int32) is a length from which the mask allows no key: the
+    kernel stops there, the jnp form and the backward pass have the mask."""
     import jax
     import numpy as np
 
@@ -205,20 +209,23 @@ def _attention(q, k, v, mask, kernel: bool):
         return plain(q, k, v, mask)
     from ompi_tpu.ops.masked_attention import masked_attention
 
-    @jax.custom_vjp
-    def attention(q, k, v, mask):
-        return masked_attention(q, k, v, mask)
+    lengths = () if k_len is None else (k_len,)
 
-    def fwd(q, k, v, mask):
-        return attention(q, k, v, mask), (q, k, v, mask)
+    @jax.custom_vjp
+    def attention(q, k, v, mask, *lengths):
+        return masked_attention(q, k, v, mask, *lengths)
+
+    def fwd(q, k, v, mask, *lengths):
+        return attention(q, k, v, mask, *lengths), (q, k, v, mask)
 
     def bwd(saved, g):
         *qkv, mask = saved
         return (*jax.vjp(lambda *a: plain(*a, mask), *qkv)[1](g),
-                np.zeros(mask.shape, jax.dtypes.float0))
+                np.zeros(mask.shape, jax.dtypes.float0),
+                *(np.zeros((), jax.dtypes.float0) for _ in lengths))
 
     attention.defvjp(fwd, bwd)
-    return attention(q, k, v, mask)
+    return attention(q, k, v, mask, *lengths)
 
 
 def attend(bs: BlockSelect, q, k, v):
@@ -228,10 +235,16 @@ def attend(bs: BlockSelect, q, k, v):
     rows (B, Hkv, T, 2 hd) and the pooled keys (B, Hkv, pooled_count(T),
     hd) in q's type.
 
-    A slice of ``q_slice`` queries at a time against the keys up to the
-    slice's end.  A sequence of at most ``dense_len`` positions, and a slice
-    that ends within the first ``topk`` blocks, attends to every earlier
-    position and computes no score."""
+    A slice of ``q_slice`` queries at a time.  The whole slices are the
+    iterations of a ``lax.scan`` over one shape, so that a prompt of any
+    length traces, lowers and compiles a slice once: the slice's queries
+    against the sequence's every key, pooled key and block, which ``t``
+    masks down to those so far, and attention that stops at the slice's end
+    (``masked_attention``'s ``k_len``).  A sequence of at most ``dense_len``
+    positions, and a slice that ends within the first ``topk`` blocks,
+    attends to every earlier position and computes no score: those slices
+    are a scan of their own, before the selected ones.  A tail shorter than
+    ``q_slice`` is one more call, against the keys it has."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -245,32 +258,60 @@ def attend(bs: BlockSelect, q, k, v):
     kh, vh = (y.swapaxes(1, 2) for y in (k, v))             # (B, Hkv, T, hd)
     with scope("blocks.pool"):
         pooled = pool_keys(bs, kh).astype(q.dtype)
-    qh = jnp.moveaxis(q.reshape(B, T, hkv, r, hd), 1, 3)    # (B, Hkv, r, T, hd)
+    q5 = q.reshape(B, T, hkv, r, hd)
     k1, v1 = (y.reshape(B * hkv, T, 1, hd) for y in (kh, vh))
-    out = []
-    for lo in range(0, T, bs.q_slice):
-        hi = min(T, lo + bs.q_slice)
-        t = jnp.arange(lo, hi)
-        mask = jnp.broadcast_to(jnp.arange(hi) <= t[:, None],
-                                (B, hkv, hi - lo, hi))
-        if T > bs.dense_len and hi > bs.topk * bs.block:
+
+    def slice_(qs, t, select: bool, k_len=None):
+        """The context (B, Tq, H, hd) of the queries qs (B, Tq, Hkv, r, hd)
+        at positions t (Tq,), which mask the sequence's keys, pooled keys
+        and blocks down to those so far."""
+        n = qs.shape[1]
+        if select:
             with scope("blocks.score"):
                 scores = block_scores(
-                    bs, lax.stop_gradient(qh[:, :, :, lo:hi]),
-                    lax.stop_gradient(pooled[:, :, :pooled_count(bs, hi)]),
-                    t, -(-hi // bs.block))
+                    bs, lax.stop_gradient(jnp.moveaxis(qs, 1, 3)),
+                    lax.stop_gradient(pooled), t, -(-T // bs.block))
             with scope("blocks.select"):
-                mask = _positions(bs, chosen(bs, scores, t), t, hi)
+                mask = _positions(bs, chosen(bs, scores, t), t, T)
+        else:
+            mask = jnp.broadcast_to(jnp.arange(T) <= t[:, None],
+                                    (B, hkv, n, T))
         with scope("attention"):
             o = _attention(
-                jnp.moveaxis(qh[:, :, :, lo:hi], 2, 3).reshape(
-                    B * hkv, hi - lo, r, hd),
-                k1[:, :hi], v1[:, :hi], mask.reshape(B * hkv, hi - lo, hi),
-                kernel=_traced_for_tpus() and tiles(hi - lo, hd))
-            out.append(o.reshape(B, hkv, hi - lo, r, hd))
-    o = jnp.moveaxis(jnp.concatenate(out, axis=2), 1, 2)    # (B, T, Hkv, r, hd)
-    return (o.reshape(B, T, H, hd), jnp.concatenate([kh, vh], axis=-1),
-            pooled)
+                jnp.moveaxis(qs, 1, 2).reshape(B * hkv, n, r, hd), k1, v1,
+                mask.reshape(B * hkv, n, T),
+                kernel=_traced_for_tpus() and tiles(n, hd), k_len=k_len)
+            return jnp.moveaxis(o.reshape(B, hkv, n, r, hd), 1, 2).reshape(
+                B, n, H, hd)
+
+    def whole(o, first: int, count: int, select: bool):
+        """``o`` (B, T, H, hd) with the slices ``first .. first + count -
+        1`` written."""
+        def one(o, lo):
+            new = slice_(lax.dynamic_slice_in_dim(q5, lo, bs.q_slice, axis=1),
+                         lo + jnp.arange(bs.q_slice), select, lo + bs.q_slice)
+            return lax.dynamic_update_slice_in_dim(o, new, lo, axis=1), None
+
+        return lax.scan(one, o,
+                        bs.q_slice * jnp.arange(first, first + count))[0]
+
+    slices = T // bs.q_slice
+    # the slices that end within the first topk blocks, or all of a sequence
+    # that attends densely
+    dense = slices if T <= bs.dense_len else min(
+        slices, bs.topk * bs.block // bs.q_slice)
+    o = jnp.zeros((B, T, H, hd), q.dtype) if slices else None
+    for first, count, select in ((0, dense, False),
+                                 (dense, slices - dense, True)):
+        if count:
+            o = whole(o, first, count, select)
+    if T % bs.q_slice:
+        lo = slices * bs.q_slice
+        tail = slice_(q5[:, lo:], jnp.arange(lo, T),
+                      T > bs.dense_len and T > bs.topk * bs.block)
+        o = tail if o is None else lax.dynamic_update_slice_in_dim(
+            o, tail, lo, axis=1)
+    return o, jnp.concatenate([kh, vh], axis=-1), pooled
 
 
 def written_pooled(bs: BlockSelect, rows, pooled, pos):
