@@ -69,8 +69,13 @@ SCOPES = (
     "attention.flash",  # ... the pallas kernels
     "attention.gather",     # a cached step's selected K/V rows gathered out
                             # of the carry (nothing where it streams them)
+    "attention.selected",   # a latent layer's read of the rows its index
+                            # selected: the cached step's pass over the
+                            # cache under the mask, the prefill's kernel
     "ffn",              # ln2, the MLP, the residual
     "moe.route", "moe.dispatch", "moe.experts", "moe.combine",
+    "moe.groups",       # inside ``moe.route``: the groups of experts a token
+                        # may pick in (group-limited top-k)
     "moe.shared",       # the shared expert every token passes
     "moe.zero",         # the identity experts' part: a token itself times
                         # the summed weights of its picks among them
@@ -122,6 +127,9 @@ SCOPES = (
     "index_proj",       # the index's three projections, key norm, rotary
     "index.score",      # ... its scores of a query against the index keys
     "index.select",     # ... the topk positions: a threshold, or a top-k
+    # the same three of an index inside a latent layer (``MLA.index``):
+    # names of their own, the K/V index's metrics' keys find nothing there
+    "latent_index_proj", "latent_index.score", "latent_index.select",
     "loss",             # the unembed matmul and the cross entropy
     "optimizer",        # the optimizer's update and its application
     "prefill",          # backbone over the prompt, cache padding, first logits

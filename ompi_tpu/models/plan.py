@@ -661,7 +661,11 @@ def mla_moe_config(first_k_dense_replace: int, kv_lora_rank: int,
                    n_shared_experts: int, routed_scaling_factor: float,
                    n_group: int, topk_group: int, moe_layer_freq: int,
                    scoring_func: str, topk_method: str, rope_theta: float,
-                   rope_scaling=None, **sizes):
+                   rope_scaling=None, experts_held=None,
+                   index_n_heads: int = 0, index_head_dim: int = 0,
+                   index_topk: int = 0, index_q_slice: int = 512,
+                   num_nextn_predict_layers: int = 0,
+                   run_nextn_predict: bool = False, **sizes):
     """``entry.config`` of a configuration file whose every layer is latent
     attention with a rotary embedding on the shared key part, over a leading
     dense MLP and routed experts after it, under the keys DeepSeek-V3's
@@ -672,21 +676,50 @@ def mla_moe_config(first_k_dense_replace: int, kv_lora_rank: int,
     and does not weigh (``noaux_tc``), the picks' weights renormalised where
     ``moe_norm_topk`` says so and times ``routed_scaling_factor``, and
     ``n_shared_experts`` shared experts as one gated MLP of their summed
-    width; with ``q_lora_rank`` the queries come from a normed query latent
-    of that rank.  What is not built raises:
-    grouped top-k (``n_group``, ``topk_group`` over 1), a scaled rotary
-    embedding (``rope_scaling``), dense layers among the routed ones
-    (``moe_layer_freq`` other than 1), another ``scoring_func`` or
-    ``topk_method``."""
-    from ompi_tpu.models.mla import MLA
+    width; with ``n_group`` over 1 the picks are group-limited, inside a
+    token's ``topk_group`` best groups (``parallel/moe._within_groups``).
+    With ``q_lora_rank`` the queries come from a normed query latent
+    of that rank.  ``rope_scaling`` of type "yarn": the rotation's
+    frequencies blended and the softmax scale times ``m^2`` (``mla.Yarn``).
+    ``index_n_heads``, ``index_head_dim``, ``index_topk`` (DeepSeek-V3.2's
+    keys): every layer carries an index of that many heads of that width,
+    its queries out of the query latent, which selects ``index_topk`` cached
+    positions a query (``MLA.index``; ``index_q_slice``, a key of the file's
+    own: the queries a slice of the whole-sequence path).  ``experts_held``
+    as in :func:`kda_mla_config`.  What is not built raises: dense layers
+    among the routed ones (``moe_layer_freq`` other than 1), another
+    ``scoring_func`` or ``topk_method``, a ``rope_scaling`` of another type
+    or one that scales cos and sin (``mscale`` other than
+    ``mscale_all_dim``), an index without a query latent, and the
+    multi-token-prediction module where it is asked to run
+    (``run_nextn_predict`` with ``num_nextn_predict_layers`` over 0: a
+    decoder's step yields one token)."""
+    from ompi_tpu.models.mla import MLA, Yarn
+    from ompi_tpu.models.sparse_index import SparseIndex
     from ompi_tpu.models.transformer import TransformerConfig
 
+    yarn = None
+    if rope_scaling is not None and rope_scaling.get("type") == "yarn":
+        rs = rope_scaling
+        yarn = Yarn(factor=float(rs["factor"]),
+                    original=int(rs["original_max_position_embeddings"]),
+                    beta_fast=float(rs.get("beta_fast", 32)),
+                    beta_slow=float(rs.get("beta_slow", 1)),
+                    mscale=float(rs.get("mscale", 1)),
+                    mscale_all_dim=float(rs.get("mscale_all_dim", 0)))
+    indexed = bool(index_n_heads or index_head_dim or index_topk)
     not_built = [f"{key} {value!r}" for key, value, built in (
-        ("n_group", n_group, 1),
-        ("topk_group", topk_group, 1), ("rope_scaling", rope_scaling, None),
+        ("rope_scaling", rope_scaling if yarn is None else None, None),
+        ("rope_scaling's mscale / mscale_all_dim on cos and sin",
+         yarn is None or yarn.rotation_factor == 1, True),
         ("moe_layer_freq", moe_layer_freq, 1),
         ("scoring_func", scoring_func, "sigmoid"),
-        ("topk_method", topk_method, "noaux_tc")) if value != built]
+        ("topk_method", topk_method, "noaux_tc"),
+        ("an index without q_lora_rank",
+         indexed and not q_lora_rank, False),
+        ("num_nextn_predict_layers asked to run",
+         bool(run_nextn_predict and num_nextn_predict_layers), False))
+        if value != built]
     if not_built:
         raise ValueError(f"a plan of rotary latent layers over routed "
                          f"experts is not built for {', '.join(not_built)}")
@@ -696,12 +729,22 @@ def mla_moe_config(first_k_dense_replace: int, kv_lora_rank: int,
         mla=MLA(n_heads=sizes["n_heads"], nope=qk_nope_head_dim,
                 rope=qk_rope_head_dim, v_dim=v_head_dim,
                 kv_rank=kv_lora_rank, theta=float(rope_theta),
-                q_rank=int(q_lora_rank or 0)),
+                q_rank=int(q_lora_rank or 0),
+                **({"index": SparseIndex(
+                    n_heads=index_n_heads, head_dim=index_head_dim,
+                    topk=index_topk, q_slice=index_q_slice)}
+                   if indexed else {}),
+                **({"yarn": yarn} if yarn else {})),
         d_expert=moe_intermediate_size)
+    held = (None if experts_held is None
+            else (int(experts_held["first"]), int(experts_held["count"])))
     return TransformerConfig(
         plan=plan, moe_gated=True, moe_score="sigmoid", moe_select_bias=True,
         moe_scale=float(routed_scaling_factor),
-        moe_shared=n_shared_experts * moe_intermediate_size, **sizes)
+        moe_shared=n_shared_experts * moe_intermediate_size,
+        **({"moe_held": held} if held else {}),
+        **({"moe_groups": (int(n_group), int(topk_group))}
+           if n_group > 1 else {}), **sizes)
 
 
 def shortcut_moe_config(kv_lora_rank: int, q_lora_rank, qk_nope_head_dim: int,
@@ -924,7 +967,9 @@ def layer_types_moe_config(layer_types: list, mamba_n_heads: int,
 # ``kda_mla_config``: KDA and NoPE latent layers by ``linear_attn_config``'s
 # lists, a leading dense MLP, then a sigmoid router with a shared expert;
 # ``mla_moe_config``: DeepSeek-V3's keys, rotary latent attention in every
-# layer, a leading dense MLP, then the same router with ``n_shared_experts``;
+# layer (its rotation scaled, an index in it, under V3.2's keys), a leading
+# dense MLP, then the same router with ``n_shared_experts``, group-limited
+# where ``n_group`` says so;
 # ``lightning_blocks_config``: lightning and block-selected layers by
 # ``mixer_types`` over a dense MLP; ``shortcut_moe_config``: LongCat-Flash's
 # keys, two rows of rotary latent attention with a query latent over a dense

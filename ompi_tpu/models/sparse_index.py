@@ -130,21 +130,41 @@ def init_leaves(cfg, rng) -> dict:
             "ikb": np.zeros((L, ix.head_dim), np.float32)}
 
 
-def project(cfg, lp, x, positions):
+def project(cfg, lp, x, positions, queries_from=None, ix=None, rotate=None,
+            within: str = "index_proj", ends_product: bool = False):
     """The index's queries, key and head weights of the normed input ``x``
     (B, T, D) at ``positions`` (T,): ``qi`` (B, T, heads, width) and ``ki``
     (B, T, width) in x's type, rotated; ``wi`` (B, T, heads) float32,
-    scaled."""
+    scaled.
+
+    An index inside another mixer (``models/mla.py``) says what is its own:
+    ``queries_from`` (B, T, R), what the queries are projected from where
+    that is not the layer's input (a latent layer's normed query latent: the
+    key and the head weights still read ``x``); ``ix``, the index's sizes
+    where ``cfg.index`` does not hold them; ``rotate(y, positions)``, the
+    rotary embedding of heads y (B, T, heads, width), where it is not the
+    configuration's over a head's whole width; ``within``, the scope the
+    projections run under (``tests/benchmarks/test_keye_vl2_rows.py`` holds
+    the plain names to the one cell whose attention is indexed K/V);
+    ``ends_product``, a cached step's: the queries' product ends before the
+    split into heads, so that the compiler reads its weight where it lies
+    (``mla.mixer`` says what it does otherwise)."""
     import jax.numpy as jnp
     from jax import lax
 
     from ompi_tpu.core.scopes import scope
     from ompi_tpu.models.transformer import _rope
 
-    ix, f32, cdt = cfg.index, jnp.float32, x.dtype
+    ix, f32, cdt = ix or cfg.index, jnp.float32, x.dtype
     B, T, _ = x.shape
-    with scope("index_proj"):
-        qi = jnp.einsum("btd,df->btf", x, lp["wiq"].astype(cdt))
+    if rotate is None:
+        def rotate(y, positions):
+            return _rope(y, positions, theta=cfg.rope_theta)
+    with scope(within):
+        qi = jnp.einsum("btd,df->btf", x if queries_from is None
+                        else queries_from, lp["wiq"].astype(cdt))
+        if ends_product:
+            qi = lax.optimization_barrier(qi)
         ki = jnp.einsum("btd,df->btf", x, lp["wik"].astype(cdt),
                         preferred_element_type=f32)
         ki = ki - ki.mean(axis=-1, keepdims=True)
@@ -154,9 +174,8 @@ def project(cfg, lp, x, positions):
         wi = jnp.einsum("btd,dh->bth", x, lp["wiw"].astype(cdt),
                         preferred_element_type=f32)
         wi = wi * (ix.n_heads ** -0.5 * ix.head_dim ** -0.5)
-        qi = _rope(qi.reshape(B, T, ix.n_heads, ix.head_dim), positions,
-                   theta=cfg.rope_theta)
-        ki = _rope(ki[:, :, None, :], positions, theta=cfg.rope_theta)[:, :, 0]
+        qi = rotate(qi.reshape(B, T, ix.n_heads, ix.head_dim), positions)
+        ki = rotate(ki[:, :, None, :], positions)[:, :, 0]
     return qi, ki, wi
 
 

@@ -78,6 +78,10 @@ class TransformerConfig:
     moe_score: str = "softmax"
     moe_select_bias: bool = False
     moe_scale: float = 1.0
+    # (n_group, topk_group): group-limited top-k under "sigmoid": a token
+    # picks inside its ``topk_group`` best of ``n_group`` groups of
+    # neighbouring experts (parallel/moe._within_groups).  None: among all.
+    moe_groups: Any = None
     # (first, count): this device is one of several that share each routed
     # layer by expert, and holds experts first .. first + count - 1 alone;
     # the expert leaves are ``count`` long and the router stays
@@ -644,7 +648,9 @@ def _moe_ffn_tail(cfg, h, lp, comm, layer=None, residual: bool = True):
                             kernel=comm.mesh.devices.flat[0].platform == "tpu",
                             renorm=cfg.moe_norm_topk, score=cfg.moe_score,
                             scale=cfg.moe_scale, held=cfg.moe_held,
-                            zero=cfg.moe_zero)
+                            zero=cfg.moe_zero,
+                            **({"groups": cfg.moe_groups} if cfg.moe_groups
+                               else {}))
             if cfg.moe_shared:
                 mo = mo + _shared_expert(x, lp, cfg.moe_act)
             if cfg.plan is not None and cfg.plan.branch_factor != 1:

@@ -84,8 +84,9 @@ __all__ = ["grouped_matmul", "grouped_matmul_xla", "tile_rows",
 # and, compiled for a v5e, needs 11 MiB.  Beside rows of 4 MiB it is not
 # one (``weight_block``).
 _VMEM_BUDGET_BYTES = 16 << 20
-# a tile's rows from which ``weight_block`` is wary
-_LONG_ROWS_BYTES = 4 << 20
+# a tile's rows from which ``weight_block`` is wary: 512 rows of 4096 and, PR
+# 67's cell, 256 of 7168 (3.5 MiB)
+_LONG_ROWS_BYTES = 7 << 19
 
 
 def tile_rows(rows_a_group: float, matrices=(), itemsize: int = 2) -> int:
@@ -146,23 +147,25 @@ def weight_block(tm: int, K: int, N: int, itemsize: int) -> tuple[int, int]:
     """``(tk, tn)``, the block of a ``(K, N)`` matrix that a tile of ``tm``
     rows multiplies, by the bytes the tile then moves: the whole ``K`` and
     the widest block of ``N`` whose working set fits the kernel's VMEM
-    budget (one lane tile of it beside rows of 4 MiB), so that a tile's rows
+    budget (one lane tile of it beside rows of 3.5 MiB), so that a tile's rows
     are read once; that is the whole matrix where it fits, and a group's
     matrix is then read once too.  Where no
     block of ``N`` under a whole ``K`` fits, the whole ``N`` under the widest
     block of ``K`` that does (the same bytes: rows and matrix once each);
     blocks of both dimensions only where neither fits."""
-    # beside rows of 4 MiB a part of ``N`` is one lane tile.  The count is
+    # beside rows of 3.5 MiB a part of ``N`` is one lane tile.  The count is
     # what the pipeline and the accumulator hold; Mosaic's own stack for a
     # product of long rows with more than one lane tile of columns is not in
     # it: for 512 rows of 4096 beside (4096, 256) it asks 16.7 MiB where
-    # 13.5 are counted (and 19.1 beside (4096, 384), counted 16.25), while
+    # 13.5 are counted (and 19.1 beside (4096, 384), counted 16.25; for 256
+    # rows of 7168 beside (7168, 256) 17.7 where 14.8 are, PR 67), while
     # 3968 beside 256 columns, and 4096 and 6144 beside 128, compile as
     # counted (for a described v5e, PR 65; no term of the blocks' sizes that
     # I tried gives all of these, so the rule is this threshold and
     # ``PERF.md`` section 7 asks for the count's repair).  Cell 11 has such
     # rows (512 of 6144, 6 MiB) and is unchanged: the budget alone already
-    # gave it (6144, 128); the one call the threshold moves is PR 65's cell's
+    # gave it (6144, 128); the calls the threshold moves are PR 65's cell's
+    # and PR 67's (no other cell has rows between 3.5 and 4 MiB)
     long_rows = tm * K * itemsize >= _LONG_ROWS_BYTES
     for tn in _widths(N):
         if long_rows and 128 < tn < N:

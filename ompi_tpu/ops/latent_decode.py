@@ -16,7 +16,13 @@ products and nothing is cut out of it but the context's lane-aligned
 The operand is a layer's own cache ``(B, Tmax, kv_rank + rope)`` as the
 carry holds it.  The step's position is a prefetched scalar: positions past
 it are masked, and a block wholly past it is neither copied (its index map
-names the last live block again) nor computed.
+names the last live block again) nor computed.  A layer with an index hands
+the positions it selected over as one more operand, ``chosen`` (B, Tmax): a
+block's flags come in beside its rows and mask its scores, so the read of a
+selection is the same one pass over the live rows (the rows are shared by all
+heads, so a row not chosen is a row not needed by any head; skipping it would
+be a gather, which costs this chip more a row than the stream does:
+``models/sparse_index._STREAM_UP_TO``).
 
 No backward pass (a decoder's step has none).
 """
@@ -53,12 +59,14 @@ def tiles(t_max: int, rank: int) -> bool:
     return rank % 128 == 0 and t_max % _BLOCK == 0
 
 
-def _kernel(pos, q_ref, c_ref, o_ref, m_ref, l_ref, acc_ref, *, scale: float,
-            rank: int):
+def _kernel(pos, q_ref, c_ref, *refs, scale: float, rank: int):
+    """One (sequence, block of positions) cell.  ``refs`` begin with the
+    block's flags (1, block) where the call has a selection."""
     from jax import lax
 
     from ompi_tpu.ops._pallas import pl
 
+    *chosen_ref, o_ref, m_ref, l_ref, acc_ref = refs
     block = c_ref.shape[0]
     j = pl.program_id(1)
 
@@ -74,10 +82,15 @@ def _kernel(pos, q_ref, c_ref, o_ref, m_ref, l_ref, acc_ref, *, scale: float,
         s = lax.dot_general(q_ref[...], rows, _NT,
                             preferred_element_type=jnp.float32) * scale
         at = j * block + lax.broadcasted_iota(jnp.int32, (1, block), 1)
-        s = jnp.where(at <= pos[0], s, _NEG)                # (H, block)
+        allowed = at <= pos[0]
+        if chosen_ref:
+            allowed &= chosen_ref[0][...] != 0
+        s = jnp.where(allowed, s, _NEG)                     # (H, block)
         m = m_ref[...]                                      # (H, 1)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)      # a live block: m_new is a real score
+        if chosen_ref:      # a block none of whose rows is chosen: m_new is
+            p = jnp.where(allowed, p, 0.0)      # _NEG, exp(s - m_new) one
         corr = jnp.exp(m - m_new)
         l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
@@ -87,16 +100,26 @@ def _kernel(pos, q_ref, c_ref, o_ref, m_ref, l_ref, acc_ref, *, scale: float,
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _():
-        o_ref[...] = acc_ref[...] / l_ref[...]
+        o_ref[...] = acc_ref[...] / (jnp.maximum(l_ref[...], 1e-30)
+                                     if chosen_ref else l_ref[...])
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4))
-def _call(pos, q, cache, scale: float, rank: int):
+def _call(pos, q, cache, scale: float, rank: int, chosen=None):
     from ompi_tpu.ops._pallas import pallas_call, pl
     from ompi_tpu.ops._pallas import pltpu
 
     b, heads, width = q.shape
     t_max = cache.shape[1]
+
+    def live_block(j, pos):
+        """Block ``j``, or past the last live block that block again, so
+        that nothing new is copied."""
+        return jnp.minimum(j, pos[0] // _BLOCK)
+
+    flags = () if chosen is None else (
+        pl.BlockSpec((None, 1, _BLOCK),
+                     lambda b, j, pos: (b, 0, live_block(j, pos))),)
     return pallas_call(
         functools.partial(_kernel, scale=scale, rank=rank),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -105,10 +128,9 @@ def _call(pos, q, cache, scale: float, rank: int):
             in_specs=[
                 pl.BlockSpec((None, heads, width),
                              lambda b, j, pos: (b, 0, 0)),
-                # past the last live block: that block again, so no new copy
                 pl.BlockSpec((None, _BLOCK, width),
-                             lambda b, j, pos: (
-                                 b, jnp.minimum(j, pos[0] // _BLOCK), 0)),
+                             lambda b, j, pos: (b, live_block(j, pos), 0)),
+                *flags,
             ],
             out_specs=pl.BlockSpec((None, heads, rank),
                                    lambda b, j, pos: (b, 0, 0)),
@@ -120,15 +142,17 @@ def _call(pos, q, cache, scale: float, rank: int):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         name="latent_decode",
-    )(pos, q, cache)
+    )(pos, q, cache, *(() if chosen is None else (chosen,)))
 
 
-def latent_decode(q_abs, cache, pos, scale: float, rank: int):
+def latent_decode(q_abs, cache, pos, scale: float, rank: int, chosen=None):
     """The absorbed step's context: softmax over positions ``0 .. pos`` (a
     traced int32) of ``q_abs . cache_s`` times ``scale``, q_abs (B, H, rank +
     rope) against cache (B, Tmax, rank + rope), then ``sum_s p_s cache_s[:
-    rank]``.  Products in the cache's type (the weights cast to it), sums
-    float32; (B, H, rank) float32."""
+    rank]``.  ``chosen`` (B, Tmax) bool or int8: of those positions the ones
+    it allows alone (a selection; zeros for a sequence it allows nothing).
+    Products in the cache's type (the weights cast to it), sums float32; (B,
+    H, rank) float32."""
     t_max, width = cache.shape[1:]
     if not tiles(t_max, rank) or q_abs.shape[-1] != width:
         raise ValueError(
@@ -136,5 +160,7 @@ def latent_decode(q_abs, cache, pos, scale: float, rank: int):
             f"latent {rank} of them, under queries {q_abs.shape[-1]} wide do "
             f"not tile (blocks of {_BLOCK} positions, a latent of whole 128 "
             f"lanes)")
+    if chosen is not None:
+        chosen = chosen.astype(jnp.int8).reshape(cache.shape[0], 1, t_max)
     return _call(jnp.asarray(pos, jnp.int32).reshape(1),
-                 q_abs.astype(cache.dtype), cache, float(scale), rank)
+                 q_abs.astype(cache.dtype), cache, float(scale), rank, chosen)

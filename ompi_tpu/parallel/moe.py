@@ -196,10 +196,30 @@ def _window_rows(picks: int, tm: int, held: int, width: int) -> int:
     return picks
 
 
+def _within_groups(choice, groups: tuple):
+    """``choice`` (n, E) float32, the scores a token picks by, with every
+    expert outside the token's kept groups at ``-inf``: ``groups = (n_group,
+    topk_group)``, a group ``E / n_group`` neighbouring experts, its score the
+    sum of its two largest ``choice``, the ``topk_group`` best groups kept
+    (ties to the lower group, ``lax.top_k``'s order)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ompi_tpu.core.scopes import scope
+
+    n_group, keep = groups
+    n, E = choice.shape
+    with scope("moe.groups"):
+        by_group = choice.reshape(n, n_group, E // n_group)
+        best = lax.top_k(lax.top_k(by_group, 2)[0].sum(axis=-1), keep)[1]
+        kept = jnp.any(best[:, :, None] == jnp.arange(n_group), axis=1)
+        return jnp.where(kept[:, :, None], by_group, -jnp.inf).reshape(n, E)
+
+
 def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
                kernel: bool = False, renorm: bool = False,
                score: str = "softmax", scale: float = 1.0, held=None,
-               zero: int = 0, act: str = "gelu"):
+               zero: int = 0, act: str = "gelu", groups=None):
     """Dropless top-k MoE layer over the experts this device holds: x (B, T,
     D) local tokens → (B, T, D).
 
@@ -241,7 +261,12 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
     that a token's experts weigh one together.  ``score`` "sigmoid": the
     scores are each expert's own sigmoid.  Under either, with a leaf ``wgb``
     (E,) in ``params``, the selection bias, the ``top_k`` largest of ``score
-    + wgb`` are picked and weigh their scores without it.  Either way the
+    + wgb`` are picked and weigh their scores without it.  With ``groups``
+    ``(n_group, topk_group)`` under "sigmoid" the picks are group-limited:
+    the router's outputs are ``n_group`` groups of neighbouring experts, a
+    group scores the sum of its two largest ``score + wgb``, and a token picks
+    its ``top_k`` inside its ``topk_group`` best groups alone
+    (:func:`_within_groups`).  Either way the
     weights are then multiplied by ``scale``.  The ``tokens × top_k``
     assignments are sorted by
     expert (stable) and laid out in row tiles of ``tm`` rows, every
@@ -293,6 +318,12 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
     B, T, D = x.shape
     n, k = B * T, top_k
     E = params["wg"].shape[-1]
+    if groups is not None and (score != "sigmoid" or E % groups[0]
+                               or groups[1] * (E // groups[0]) < k):
+        raise ValueError(
+            f"group-limited top-k is built for sigmoid scores, groups that "
+            f"divide the router and hold the picks: groups {groups} of {E} "
+            f"outputs, {k} picks under score {score!r}")
     cdt = x.dtype
     xf = jnp.asarray(x).reshape(n, D)
     F = params["w1"].shape[-1]
@@ -318,6 +349,8 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
             scores = jax.nn.sigmoid(logits)
             biased = (scores + params["wgb"].astype(jnp.float32)
                       if "wgb" in params else scores)
+            if groups is not None:
+                biased = _within_groups(biased, groups)
             expert = lax.top_k(biased, k)[1]
             gate = jnp.take_along_axis(scores, expert, axis=-1)
         if renorm:

@@ -392,12 +392,29 @@ def test_the_entry_config_refuses_what_is_not_built():
     with pytest.raises(ValueError, match="written for"):
         program.reference(config).Shape.from_config(
             {**config, "q_lora_rank": 24})
-    for key, value in (("n_group", 8),
-                       ("topk_group", 4), ("rope_scaling", {"type": "yarn"}),
+    yarn = {"type": "yarn", "factor": 40, "mscale": 1, "mscale_all_dim": 1,
+            "original_max_position_embeddings": 4096}
+    for key, value in (("rope_scaling", {"type": "linear", "factor": 4}),
+                       ("rope_scaling", {**yarn, "mscale": 0.5}),
                        ("moe_layer_freq", 2), ("scoring_func", "softmax"),
                        ("topk_method", "greedy")):
         with pytest.raises(ValueError, match=f"not built for {key}"):
             program.program_config({**config, key: value})
+        with pytest.raises(ValueError, match="written for"):
+            program.reference(config).Shape.from_config(
+                {**config, key: value})
+    # built since PR 67 (``tests/benchmarks/test_v32.py``): group-limited
+    # picks and a yarn rotation; this family's reference is written for
+    # neither
+    grouped = program.program_config({**config, "n_group": 4,
+                                      "topk_group": 2})
+    assert grouped.moe_groups == (4, 2)
+    assert program.program_config(config).moe_groups is None
+    scaled = program.program_config({**config, "rope_scaling": yarn})
+    assert scaled.plan.mla.yarn.factor == 40
+    assert program.program_config(config).plan.mla.yarn is None
+    for key, value in (("n_group", 4), ("topk_group", 2),
+                       ("rope_scaling", yarn)):
         with pytest.raises(ValueError, match="written for"):
             program.reference(config).Shape.from_config(
                 {**config, key: value})
