@@ -14,8 +14,12 @@ sequence (trainer, prefill: a causal convolution over the sequence and the
 chunked scan, from a zero state) and one position against a carried state
 (its states in :func:`carry`'s stacks: the convolution from its last
 inputs, the recurrence once).  Everything here is ``jax.numpy`` and ``lax``
-but a decoder's prefill of more than one chunk on TPUs, whose scan is the
-pallas ``ops/ssm_scan.py`` (:func:`fused` is the rule).
+but two kernels a decoder takes on TPUs, each behind a rule of static
+facts: a prefill of more than one chunk scans with the pallas
+``ops/ssm_scan.py`` (:func:`fused` is the rule), and a cached step updates a
+layer's own bfloat16 state of depth 128 in place with ``ops/ssm_update.py``
+(:func:`in_place` is the rule; a float32 state, another depth and a layer
+of a stack take the ``jax.numpy`` update, and do not import the kernel).
 
 The same mixer alone in a layer, ``h <- h + r mixer(RMSNorm(h; ln1))`` with
 no multiplier but the plan's ``branch_factor`` ``r``, is the kind "ssm" of a
@@ -41,8 +45,8 @@ import types
 import numpy as np
 
 __all__ = ["Mamba2", "HybridBlock", "hybrid_config", "mixer", "chunked_scan",
-           "fused", "init_leaves", "leaf_names", "state_shapes", "carry",
-           "carried", "check_mesh", "PLAN_KIND"]
+           "fused", "in_place", "init_leaves", "leaf_names", "state_shapes",
+           "carry", "carried", "check_mesh", "PLAN_KIND"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -292,6 +296,26 @@ def fused(sz: Mamba2, forward_only: bool, tpu: bool, T: int, dtype) -> bool:
                           sz.n_heads // sz.n_groups, dtype)
 
 
+def in_place(sz: Mamba2, tpu: bool, dtype) -> bool:
+    """Whether a cached step's update of a layer's carried state of the type
+    ``dtype`` is the one kernel (``ops/ssm_update.py``: the state moved once
+    each way, in its own buffer) and not the ``jax.numpy`` form, which the
+    compiler makes two fusions and three passes of, from static facts alone:
+    the trace is for TPUs (the kernel compiles for nothing else), the state
+    is carried in bfloat16 (two bytes an element, which the matrix unit reads
+    exactly; a float32 state's update is one fusion as it is) and its depth
+    is one tile of 128 lanes, the shape the kernel is swept on (its module
+    is not imported for another), and the kernel's own rule: heads of whole
+    sublane tiles, a sequence's within its VMEM."""
+    if not (tpu and np.dtype(dtype).name == "bfloat16"
+            and sz.d_state == 128):
+        return False
+    from ompi_tpu.ops import ssm_update
+
+    return ssm_update.block(tpu, dtype, sz.n_heads, sz.head_dim, sz.d_state,
+                            sz.n_groups) is not None
+
+
 def _conv_before(conv_c, layer):
     """A cached step's last ``d_conv - 1`` inputs of layer ``layer`` of the
     stack ``conv_c``; ``layer`` None: ``conv_c`` is the layer's own buffer.
@@ -404,6 +428,18 @@ def _mix(sz: Mamba2, eps: float, lp, u, carry=None, multipliers=None,
                 y, ssm_out = ssm_scan(xbc, dt, a, lp["ssm_d"], G, N)
             else:
                 y, ssm_out = chunked_scan(x, dt, a, b, c, sz.chunk)
+    elif layer is None and in_place(sz, _traced_for_tpus(), ssm_c.dtype):
+        from ompi_tpu.ops.ssm_update import ssm_update
+
+        with scope("ssm.update"):
+            # the state as ``_state_before`` gives it, so that what a control
+            # plants there reaches the kernel, narrowed back: of the carried
+            # buffer a pair of converts, which the compiler removes, and the
+            # kernel writes where the state lies
+            y, ssm_out = ssm_update(
+                _state_before(ssm_c, layer).astype(ssm_c.dtype), x[:, 0],
+                dt[:, 0], a, b[:, 0], c[:, 0])
+            y = y[:, None]                              # T == 1
     else:
         with scope("ssm.update"):
             h = _state_before(ssm_c, layer).reshape(B, G, H // G, P, N)
