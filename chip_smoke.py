@@ -29,6 +29,10 @@ exits non-zero before compiling anything.  The phases are functions of a
 ``Size`` so that tests/test_chip_smoke.py can drive them tiny on the virtual
 CPU mesh, where the suite's conftest puts pallas in interpret mode.
 
+The line before it is ``run {...}``: what the host did while the phases' jobs
+ran, by callable, by program object (a first call's rest, a later call that
+compiled), the collector's passes and the longest jobs, each a call to the next
+(``ompi_tpu/core/scopes.run()``).
 The line before the last is ``startup {...}``: where the host's time went,
 by span, by program, by program object and stage (``calls``) and by layer
 kind and kernel traced (``trace``), with the spans the record could not keep
@@ -104,6 +108,19 @@ def startup_summary(slowest: int = 5) -> dict:
                      "seconds": sum(map(seconds, others.values())),
                      "slowest": {name: seconds(others[name])
                                  for name in by_cost[:slowest]}}
+    return out
+
+
+def run_summary(slowest: int = 5) -> dict:
+    """The program's own account of the host side of its running jobs
+    (``scopes.run()``: ``callables``, ``programs``, ``gc``, ``jobs``,
+    ``records``, ``wrapped``), with ``jobs`` cut to the ``slowest``, each
+    from one call's start to the next's."""
+    from ompi_tpu.core import scopes
+
+    out = scopes.run()
+    out["jobs"] = sorted(out["jobs"],
+                         key=lambda row: -row["wall_s"])[:slowest]
     return out
 
 
@@ -543,6 +560,7 @@ def main() -> int:
     _check(all(n > 0 for n in compiled.values()),
            f"a pallas phase lowered no tpu_custom_call: {compiled}")
     print(f"compiled pallas kernels per program: {compiled}", flush=True)
+    print(f"run {json.dumps(run_summary())}", flush=True)
     print(f"startup {json.dumps(startup_summary())}", flush=True)
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0

@@ -43,20 +43,44 @@ seconds stay the enclosing stage's, which counts it (``helpers``).  Always
 on, like the stream's ``stats()``: no file, no exporter, no option.  The
 flight recorder of ``mpi/trace.py`` belongs to the host MPI plane and
 carries none of this (``OBSERVABILITY.md``, "The device path").
+
+**The run half.**  What the host does once a job runs is three names more of
+``HOST_SPANS``.  ``run.call`` is one call of a callable that a factory hands
+out (``train_step``, ``train_loop``, a decoder's), from entry to the return
+of its last dispatch; its ``id`` is the request's identifier, which
+everything that begins inside it names through ``parent``; it carries ``n``,
+the callable's calls so far, and at its start the process's CPU seconds,
+involuntary context switches and major page faults.  ``run.dispatch`` is
+one invocation of one jitted program object inside it (a plan's decoder:
+``prefill``, then ``generate``): a ``compile.*`` stage that begins inside
+is its child, so a call that compiles says which object, which call and
+which stage.  ``run.gc`` is a pass of CPython's collector of a millisecond
+or more (``gc.callbacks``; every pass is counted, by generation): it stops
+every python thread whichever thread trips it, so it goes on no thread's
+stack and belongs to the call it overlaps in time.  A call made while a
+program is traced (``jax.jit`` over a decoder) is no run and leaves
+nothing.  These records live in a store of their own, a ring of the newest
+``RING`` beside counters that never wrap, because a job's operator wants
+the newest where set-up wants the first: ``records()`` and ``startup()`` are
+the start-up half's as they were, and ``run()`` reads this one.
 """
 
 from __future__ import annotations
 
+import bisect
 import collections
 import contextlib
+import gc
 import itertools
+import resource
+import statistics
 import threading
 import time
 from typing import NamedTuple, Optional
 
 __all__ = ["SCOPES", "COLL", "scope", "second", "coll",
            "HOST_SPANS", "Span", "host", "program", "records", "startup",
-           "reset"]
+           "caller", "ran", "run", "run_records", "reset"]
 
 # plain lower-case words; a dot says which scope a name belongs under
 SCOPES = (
@@ -210,6 +234,10 @@ HOST_SPANS = (
                         # with it, by the kernel's name (``ops/_pallas.py``)
     "data.produce",     # the input stream's worker: one host batch made
                         # and put on the devices
+    # the run half: the ring's, read by ``run()``
+    "run.call",         # one call of a callable a factory hands out
+    "run.dispatch",     # ... one invocation of a jitted program object in it
+    "run.gc",           # a pass of the collector, by generation ("gen2")
 )
 PREFIX = "ompi_tpu:"    # of the annotation's name in a profile
 # The record keeps a process's first LIMIT spans and counts the rest
@@ -217,6 +245,12 @@ PREFIX = "ompi_tpu:"    # of the annotation's name in a profile
 # job's stream makes a span a batch.  Helpers being folded, a decoder's
 # set-up is a few hundred.
 LIMIT = 16384
+# The run half keeps the newest RING of its spans (and each program object's
+# first dispatch beside them): a train step is two spans, so two thousand
+# steps back; the counters beside the ring never wrap.
+RING = 4096
+GC_RECORDED_FROM = 1e-3     # seconds: a shorter pass is counted, not recorded
+_RUN = ("run.call", "run.dispatch", "run.gc")
 
 _STAGES = {
     "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
@@ -244,20 +278,33 @@ class Span(NamedTuple):
     id: int
     cache: Optional[str] = None     # compile.backend: "hit" | "miss"
     helpers: int = 0            # compile.*: the stages folded into this one
-    built: Optional[int] = None     # compile.* of an own program: which of
-                                    # the name's objects (``Program.built``)
+    built: Optional[int] = None     # compile.* of an own program, and
+                                    # run.dispatch: which of the name's
+                                    # objects (``Program.built``); run.call:
+                                    # which callable (``Caller.made``)
+    n: Optional[int] = None     # run.call: the callable's calls so far, this
+                                # one included; run.dispatch: its call's
+    # run.call, at its start: the process's CPU seconds (every thread's user
+    # and system, ``time.process_time``), involuntary context switches and
+    # major page faults (``getrusage(RUSAGE_SELF)``)
+    cpu_s: Optional[float] = None
+    switches: Optional[int] = None
+    faults: Optional[int] = None
 
 
 class _Open:
     """A span that has begun on a thread and not ended: what a later span
     of that thread names as its parent."""
-    __slots__ = ("id", "name", "program", "cache", "helpers", "built")
+    __slots__ = ("id", "name", "program", "cache", "helpers", "built", "n",
+                 "went")
     folded = False
 
     def __init__(self, name: str, program: Optional[str]) -> None:
         self.id, self.name, self.program = next(_ids), name, program
         self.cache = None       # compile.backend: what the cache answered
         self.helpers, self.built = 0, None
+        self.n = None           # run.*: the call's count
+        self.went = 0           # run.*: backend stages that ended inside
 
 
 class _Folded:
@@ -282,11 +329,26 @@ class Program:
     "generate", "whole") or None; ``built``, its place among the factories'
     calls (None once ``reset()`` has forgotten it).  The factory calls
     ``traced()`` as the first statement of the jitted function: python that
-    runs when JAX traces the function and never when the program runs."""
-    __slots__ = ("name", "part", "built", "traces")
+    runs when JAX traces the function and never when the program runs.
+    Around each invocation of the jitted function the factory's callable
+    opens ``dispatch()``; the counters are the run half's: ``dispatches``,
+    ``compiles`` (backend stages that ended inside them) and ``recompiled``
+    (dispatches after the first inside which a program went to the
+    backend)."""
+    __slots__ = ("name", "part", "built", "traces", "dispatches", "compiles",
+                 "recompiled")
 
     def __init__(self, name: str, part: Optional[str], built: int) -> None:
         self.name, self.part, self.built, self.traces = name, part, built, 0
+        self.dispatches = self.compiles = self.recompiled = 0
+
+    def dispatch(self):
+        """``with record.dispatch(): out = jitted(*args)``: a
+        ``run.dispatch`` span, or nothing while a program is traced."""
+        stack = _stack()
+        if stack and _tracing(stack):
+            return _NOTHING
+        return _Dispatch(self, stack)
 
     def traced(self) -> None:
         """Inside the open ``compile.trace`` stage of the object's own jit:
@@ -300,6 +362,36 @@ class Program:
                     and not entry.folded):
                 entry.built = self.built
                 break
+
+
+class Caller:
+    """One callable a factory hands out: ``program``, the name of what it
+    runs; ``made``, its place among the factories' callables (None once
+    ``reset()`` has forgotten it); the counters ``calls``, ``seconds`` (the
+    host's, inside ``run.call``) and ``compiled`` (calls inside which a
+    program went to the backend)."""
+    __slots__ = ("program", "made", "calls", "seconds", "compiled")
+
+    def __init__(self, program: str, made: int) -> None:
+        self.program, self.made = program, made
+        self.calls, self.seconds, self.compiled = 0, 0.0, 0
+
+    def call(self):
+        """``with run.call(): ...`` around one call's dispatches: a
+        ``run.call`` span, or nothing while a program is traced."""
+        stack = _stack()
+        if stack and _tracing(stack):
+            return _NOTHING
+        return _Call(self)
+
+
+def _tracing(stack: list) -> bool:
+    """A ``compile.trace`` stage is open on the thread: what is called now
+    is traced into that program and is no run."""
+    return any(entry.name == "compile.trace" for entry in stack)
+
+
+_NOTHING = contextlib.nullcontext()
 
 
 def _new_totals() -> dict:
@@ -318,6 +410,21 @@ _open = threading.local()       # .stack: the spans open on this thread;
                                 # .last: name -> the object last traced on it
 _listening = False
 _offset = 0.0                   # perf_counter less time.time, at registration
+# the run half's store.  A deque's append is atomic, so the collector's
+# callback, which may fire while this thread holds ``_lock``, takes no lock.
+_ring: collections.deque = collections.deque(maxlen=RING)
+_firsts: dict = {}              # Program.built -> its first run.dispatch
+_callers: list = []
+_ran = 0                        # spans the ring was handed, kept or not
+_annotation = None              # jax.profiler.TraceAnnotation, once listening
+
+
+def _new_passes() -> dict:
+    return {"passes": [0, 0, 0], "seconds": [0.0, 0.0, 0.0], "longest_s": 0.0}
+
+
+_passes = _new_passes()         # of the collector, by generation
+_pass = [0.0, None]             # the open pass: its start, its annotation
 
 
 def _stack() -> list:
@@ -336,13 +443,25 @@ def _traced_last() -> dict:
         return _open.last
 
 
-def _append(span: Span) -> None:
+def _append(span: Span, stack: list = ()) -> None:
+    """``span`` has ended, and ``stack`` is what is still open on its
+    thread.  A start-up span goes to the record; one that ended inside a run
+    span (a ``compile.*`` stage inside a dispatch) to the ring as well,
+    which still has it when the record is full."""
     global _dropped
     with _lock:
         if len(_records) < LIMIT:
             _records.append(span)
         else:
             _dropped += 1
+    if stack and any(entry.name in _RUN for entry in stack):
+        _keep(span)
+
+
+def _keep(span: Span) -> None:
+    global _ran
+    _ring.append(span)
+    _ran += 1
 
 
 def _close(stack: list, entry) -> Optional[int]:
@@ -365,13 +484,15 @@ def _jax():
 
 
 def _listen(jax) -> None:
-    global _listening, _offset
+    global _listening, _offset, _annotation
     with _lock:
         if _listening:
             return
         _listening = True
         # JAX stamps its stages on time.time(); the record is on perf_counter
         _offset = time.perf_counter() - time.time()
+    _annotation = jax.profiler.TraceAnnotation
+    gc.callbacks.append(_on_gc)
     monitoring = jax.monitoring
     monitoring.register_scalar_listener(_on_begin)
     monitoring.register_event_time_span_listener(_on_span)
@@ -414,11 +535,14 @@ def _on_span(event: str, start: float, end: float, fun_name: str = "",
     name = _STAGES.get(event)
     if name is None:
         return
+    prog, stack = _program_of(fun_name), _stack()
     if name == "compile.backend":
         with _lock:
             _totals["programs"] += 1
             _totals["backend_s"] += end - start
-    prog, stack = _program_of(fun_name), _stack()
+        for around in stack:    # the call and the dispatch it ended inside
+            if around.name in _RUN:
+                around.went += 1
     entry = next((e for e in reversed(stack)
                   if e.name == name and e.program == prog), None)
     if entry is None:       # it began before the listeners were registered
@@ -426,7 +550,8 @@ def _on_span(event: str, start: float, end: float, fun_name: str = "",
     parent = _close(stack, entry)
     if not entry.folded:
         _append(Span(name, prog, start + _offset, end + _offset, parent,
-                     entry.id, entry.cache, entry.helpers, entry.built))
+                     entry.id, entry.cache, entry.helpers, entry.built),
+                stack)
 
 
 def _on_event(event: str, **_kw) -> None:
@@ -448,7 +573,8 @@ def _on_duration(event: str, seconds: float, **_kw) -> None:
 
 class host:
     """``with host("build.decoder", program="decode"): ...`` around host
-    work: an ``ompi_tpu:<name>`` annotation in a profile, and one record."""
+    work: an ``ompi_tpu:<name>`` annotation in a profile, and one record (a
+    ``run.*`` name's in the ring, any other's in the start-up record)."""
     __slots__ = ("name", "program", "entry", "annotation", "start")
 
     def __init__(self, name: str, program: Optional[str] = None) -> None:
@@ -469,8 +595,122 @@ class host:
     def __exit__(self, *exc) -> None:
         end = time.perf_counter()
         self.annotation.__exit__(*exc)
-        _append(Span(self.name, self.program, self.start, end,
-                     _close(_stack(), self.entry), self.entry.id))
+        stack = _stack()
+        span = self._span(end, _close(stack, self.entry))
+        if self.name in _RUN:
+            _keep(span)
+        else:
+            _append(span, stack)
+
+    def _span(self, end: float, parent: Optional[int]) -> Span:
+        return Span(self.name, self.program, self.start, end, parent,
+                    self.entry.id)
+
+
+class _Call(host):
+    """``Caller.call()``: the ``run.call`` span of one call."""
+    __slots__ = ("caller", "at_start")
+
+    def __init__(self, caller: Caller) -> None:
+        self.name, self.program, self.caller = ("run.call", caller.program,
+                                                caller)
+
+    def __enter__(self) -> "_Call":
+        caller = self.caller
+        caller.calls += 1
+        usage = resource.getrusage(resource.RUSAGE_SELF)
+        self.at_start = (caller.calls, time.process_time(), usage.ru_nivcsw,
+                         usage.ru_majflt)
+        host.__enter__(self)
+        self.entry.n = caller.calls
+        return self
+
+    def _span(self, end: float, parent: Optional[int]) -> Span:
+        caller, entry = self.caller, self.entry
+        caller.seconds += end - self.start
+        caller.compiled += entry.went > 0
+        return Span("run.call", self.program, self.start, end, parent,
+                    entry.id, None, 0, caller.made, *self.at_start)
+
+
+class _Dispatch(host):
+    """``Program.dispatch()``: the ``run.dispatch`` span of one invocation
+    of the object's jitted function, inside ``stack``'s call if any."""
+    __slots__ = ("record", "n")
+
+    def __init__(self, record: Program, stack: list) -> None:
+        self.name, self.program, self.record = ("run.dispatch", record.name,
+                                                record)
+        # the call's, where one is open around it
+        self.n = getattr(stack[-1], "n", None) if stack else None
+
+    def _span(self, end: float, parent: Optional[int]) -> Span:
+        record, went = self.record, self.entry.went
+        first = not record.dispatches
+        record.dispatches += 1
+        record.compiles += went
+        record.recompiled += bool(went) and not first
+        span = Span("run.dispatch", self.program, self.start, end, parent,
+                    self.entry.id, None, 0, record.built, self.n)
+        if first and record.built is not None:
+            _firsts.setdefault(record.built, span)
+        return span
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks``: every pass counted by generation, a pass of
+    ``GC_RECORDED_FROM`` seconds or more a ``run.gc`` span in the ring.  A
+    pass can begin between any two bytecodes of any thread, so it goes on no
+    thread's stack; it takes no lock and never raises.  Two clock reads a
+    pass, and an annotation only while a profile is taken."""
+    try:
+        if phase == "start":
+            _pass[1] = (_annotation(PREFIX + "run.gc")
+                        if _annotation.is_enabled() else None)
+            _pass[0] = time.perf_counter()
+            return
+        end = time.perf_counter()
+        start, annotation = _pass
+        _pass[:] = 0.0, None
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
+        if not start:           # it began before the callback was registered
+            return
+        gen, seconds, passes = min(info["generation"], 2), end - start, _passes
+        passes["passes"][gen] += 1
+        passes["seconds"][gen] += seconds
+        passes["longest_s"] = max(passes["longest_s"], seconds)
+        if seconds >= GC_RECORDED_FROM:
+            _keep(Span("run.gc", f"gen{gen}", start, end, None, next(_ids)))
+    except Exception:   # noqa: BLE001 - a record is never worth a job
+        pass
+
+
+class _Ran:
+    """A jitted function that a factory hands out itself (a train step): a
+    call is a ``run.call`` span and inside it a ``run.dispatch`` around the
+    jitted function's own call (its fast path); ``lower`` and every other
+    attribute are the jitted function's.  One python frame under the first
+    call, which a decoder's callables do without (``decode._greedy``):
+    set-up's seconds on the chip's host follow how deep that call is made."""
+    __slots__ = ("_jitted", "_record", "_caller")
+
+    def __init__(self, jitted, record: Program) -> None:
+        self._jitted, self._record = jitted, record
+        self._caller = caller(record.name)
+
+    def __call__(self, *args, **kwargs):
+        with self._caller.call(), self._record.dispatch():
+            return self._jitted(*args, **kwargs)
+
+    def __getattr__(self, name: str):
+        return getattr(object.__getattribute__(self, "_jitted"), name)
+
+
+def ran(jitted, record: Program) -> _Ran:
+    """``jitted``, the function of the program object ``record``, as a
+    callable of its own with the run half's spans around each call."""
+    return _Ran(jitted, record)
 
 
 def program(name: str, part: Optional[str] = None) -> Program:
@@ -486,16 +726,34 @@ def program(name: str, part: Optional[str] = None) -> Program:
     return handle
 
 
+def caller(name: str) -> Caller:
+    """Called by the factory for each callable it hands out that runs the
+    program ``name``: the handle counts the callable's calls and opens their
+    ``run.call`` spans (``Caller.call``)."""
+    with _lock:
+        handle = Caller(name, len(_callers))
+        _callers.append(handle)
+    return handle
+
+
 def records() -> list:
-    """The spans recorded so far, in the order they ended."""
+    """The start-up half's spans recorded so far, in the order they ended
+    (the run half's are ``run()``'s)."""
     with _lock:
         return list(_records)
 
 
+def run_records() -> list:
+    """The run half's spans held now, by start: the ring's newest ``RING``
+    (``run.*``, and the stages that ended inside one) and each program
+    object's first dispatch."""
+    return _held(startup=False)
+
+
 def reset() -> None:
-    """Forget the record, the registered programs and the totals (for
-    tests; the listeners stay)."""
-    global _dropped, _totals
+    """Forget both records, the registered programs and callables and the
+    totals (for tests; the listeners stay)."""
+    global _dropped, _totals, _passes, _ran
     with _lock:
         del _records[:]
         for handle in _programs:
@@ -504,6 +762,12 @@ def reset() -> None:
         _own.clear()
         _totals = _new_totals()
         _dropped = 0
+        for handle in _callers:
+            handle.made = None
+        del _callers[:]
+        _ring.clear()
+        _firsts.clear()
+        _passes, _ran = _new_passes(), 0
 
 
 def _row() -> dict:
@@ -626,3 +890,168 @@ def startup(spans: Optional[list] = None) -> dict:
             "trace": {name: dict(by) for name, by in traced.items()},
             "retraces": sum(max(0, c["traces"] - 1) for c in calls),
             "totals": totals, "records": len(spans), "dropped": dropped}
+
+
+# ---------------------------------------------------------------------------
+# the run half's reduction
+# ---------------------------------------------------------------------------
+
+_INSIDE_FIRST = ("compile.", "trace.", "import.")   # not a first call's rest
+
+
+def _held(startup: bool = True) -> list:
+    """Every span ``run()`` reads: each object's first dispatch, the ring,
+    and with ``startup`` the start-up record (the stages inside a
+    dispatch), each once, by start."""
+    while True:
+        try:
+            ring = list(_ring)
+            break
+        except RuntimeError:    # the collector's callback appended meanwhile
+            continue
+    with _lock:
+        spans = {s.id: s for s in _records} if startup else {}
+        spans.update((s.id, s) for s in _firsts.values())
+    spans.update((s.id, s) for s in ring)
+    return sorted(spans.values(), key=lambda s: s.start)
+
+
+def _quiet_row(seconds: list) -> dict:
+    return {"quiet": len(seconds),
+            "median_s": statistics.median(seconds) if seconds else None,
+            "total_s": sum(seconds),
+            "longest_s": max(seconds, default=None)}
+
+
+class _Passes:
+    """The recorded passes of the collector, by start: the seconds of them
+    that lie inside an interval."""
+
+    def __init__(self, spans: list) -> None:
+        self.spans = sorted((s for s in spans if s.name == "run.gc"),
+                            key=lambda s: s.start)
+        self.starts = [s.start for s in self.spans]
+        # a pass stops every thread: no two overlap, so ends ascend too
+        self.ends = [s.end for s in self.spans]
+
+    def inside(self, lo: float, hi: float) -> float:
+        first = bisect.bisect_right(self.ends, lo)
+        last = bisect.bisect_left(self.starts, hi)
+        return sum(max(0.0, min(s.end, hi) - max(s.start, lo))
+                   for s in self.spans[first:last])
+
+
+def run(spans: Optional[list] = None) -> dict:
+    """What the host did while the job ran, from the run half's record (or
+    from ``spans``; the counters are the handles' either way).
+
+    - ``callables``: a row for each callable a factory handed out:
+      ``program``, ``made``; the counters ``calls``, ``host_s`` (seconds
+      inside ``run.call``) and ``compiled`` (calls inside which a program
+      went to the backend); and of the held calls in which none did,
+      ``quiet``, how many, their ``median_s``, ``total_s`` and
+      ``longest_s``.
+    - ``programs``: a row for each program object: ``program``, ``part``,
+      ``built``; the counters ``dispatches``, ``compiles`` (backend stages
+      inside them) and ``recompiled`` (dispatches after the first inside
+      which a program went to the backend); ``first_s``, its first
+      dispatch's seconds, and ``first_rest_s``, that less the ``compile.*``,
+      ``trace.*`` and ``import.*`` spans inside it: what lies between the
+      backend's return and the dispatch's; ``recompiles``, of the held
+      dispatches after the first, ``{"n", "backend_s"}`` for each backend
+      stage inside one: which call recompiled.
+    - ``gc``: ``gen0`` to ``gen2``, ``{"passes", "seconds"}`` each, since
+      the listeners were registered; ``longest_s``; ``recorded``, the held
+      passes (of ``GC_RECORDED_FROM`` seconds or more); ``in_calls_s``,
+      their seconds that overlap a quiet call, on whatever thread.
+    - ``jobs``: every held call to the next in time, whichever callable's
+      (a caller's job starts with a call and ends where its next call
+      starts: its wait for the device and its read-back lie in between):
+      ``program``, ``made``, ``n`` (the earlier call's), ``wall_s``,
+      ``call_s`` (the host's seconds inside the call), and over the job
+      ``cpu_s``, ``switches``, ``faults`` and ``gc_s`` (recorded passes).
+      A stall with collector seconds is the collector's; one with a jump
+      in ``switches`` a host that was descheduled; ``cpu_s`` ticks in the
+      host's steps (10 ms on the chip's), so one job's cannot tell a wait
+      from busy threads below python.
+    - ``records``, ``wrapped``: run spans held, and spans that the ring has
+      let go.
+    """
+    with _lock:
+        callables = [{"program": c.program, "made": c.made, "calls": c.calls,
+                      "host_s": c.seconds, "compiled": c.compiled}
+                     for c in _callers]
+        programs = [{"program": p.name, "part": p.part, "built": p.built,
+                     "dispatches": p.dispatches, "compiles": p.compiles,
+                     "recompiled": p.recompiled, "first_s": None,
+                     "first_rest_s": None, "recompiles": []}
+                    for p in _programs]
+        passes = {"passes": list(_passes["passes"]),
+                  "seconds": list(_passes["seconds"]),
+                  "longest_s": _passes["longest_s"]}
+        wrapped = _ran - len(_ring)
+    if spans is None:
+        spans = _held()
+    by_id = {s.id: s for s in spans}
+    children = collections.defaultdict(list)
+    for s in spans:
+        if s.parent in by_id:
+            children[s.parent].append(s)
+
+    def backend_stages(span: Span) -> list:
+        found = []
+        for child in children[span.id]:
+            if child.name == "compile.backend":
+                found.append(child)
+            found += backend_stages(child)
+        return found
+
+    collector = _Passes(spans)
+    quiet = collections.defaultdict(list)       # made -> quiet calls' seconds
+    calls = []                                  # every call, by start
+    in_calls = 0.0
+    for s in spans:
+        if s.name != "run.call":
+            continue
+        calls.append(s)
+        if not backend_stages(s):
+            quiet[s.built].append(s.end - s.start)
+            in_calls += collector.inside(s.start, s.end)
+    for row in callables:
+        row.update(_quiet_row(quiet[row["made"]]))
+
+    firsts: dict = {}                           # built -> its first dispatch
+    for s in spans:
+        if s.name != "run.dispatch" or s.built is None:
+            continue
+        row = programs[s.built] if s.built < len(programs) else None
+        if row is None or row["program"] != s.program:
+            continue
+        first = firsts.setdefault(s.built, s)
+        if s is first:
+            row["first_s"] = s.end - s.start
+            row["first_rest_s"] = max(0.0, row["first_s"] - sum(
+                c.end - c.start for c in children[s.id]
+                if c.name.startswith(_INSIDE_FIRST)))
+        else:
+            row["recompiles"] += [{"n": s.n, "backend_s": b.end - b.start}
+                                  for b in backend_stages(s)]
+
+    calls.sort(key=lambda s: s.start)
+    jobs = [{"program": a.program, "made": a.built, "n": a.n,
+             "wall_s": b.start - a.start, "call_s": a.end - a.start,
+             "cpu_s": b.cpu_s - a.cpu_s, "switches": b.switches - a.switches,
+             "faults": b.faults - a.faults,
+             "gc_s": collector.inside(a.start, b.start)}
+            for a, b in zip(calls, calls[1:]) if a.cpu_s is not None
+            and b.cpu_s is not None]
+    return {
+        "callables": callables,
+        "programs": programs,
+        "gc": {**{f"gen{g}": {"passes": passes["passes"][g],
+                              "seconds": passes["seconds"][g]}
+                  for g in range(3)},
+               "longest_s": passes["longest_s"],
+               "recorded": len(collector.spans), "in_calls_s": in_calls},
+        "jobs": jobs,
+        "records": sum(s.name in _RUN for s in spans), "wrapped": wrapped}

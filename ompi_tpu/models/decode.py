@@ -50,6 +50,7 @@ of summation.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 # ``_qk_norm`` is ``transformer``'s, which ``block.mixer`` calls; the name is
@@ -294,14 +295,36 @@ def _halves(cfg: TransformerConfig, mesh, max_new: int,
     return unembedding, prefill, generate
 
 
-def _greedy(decode, temperature: float):
-    """``decode`` as :func:`make_decoder` hands it out."""
-    if temperature:
-        return decode
-    # greedy keeps its two-argument signature; seed is inert
+def _greedy(decode, temperature: float, whole=None):
+    """``decode`` as :func:`make_decoder` hands it out: a call of what comes
+    back is a ``run.call`` span of the host's record (``scopes.run()``)
+    around the dispatches of ``decode``'s programs, and nothing while it is
+    traced into another program.  Where ``decode`` is one jitted function
+    itself, ``whole`` is its program object and the call its one dispatch.
+    The spans are opened in the frame that calls ``decode``, the one python
+    function there was: set-up's seconds on the chip's host follow how deep
+    the first call is made (``PERF.md`` section 7)."""
     import numpy as _np
 
-    return lambda params, prompt: decode(params, prompt, _np.int32(0))
+    from ompi_tpu.core import scopes
+
+    run = scopes.caller("decode")
+    dispatch = contextlib.nullcontext if whole is None else whole.dispatch
+    if temperature:
+        def sampled(params, prompt, seed):
+            with run.call(), dispatch():
+                return decode(params, prompt, seed)
+
+        return sampled
+
+    def greedy(params, prompt):     # two arguments; the seed is inert
+        with run.call(), dispatch():
+            return decode(params, prompt, _np.int32(0))
+
+    # a jit over the callable names its module after it (``Job.programs()``,
+    # whose text tests pin): the name of the lambda this was
+    greedy.__name__ = "<lambda>"
+    return greedy
 
 
 def _program(cfg: TransformerConfig, mesh, local, part: str, in_specs: tuple,
@@ -310,7 +333,9 @@ def _program(cfg: TransformerConfig, mesh, local, part: str, in_specs: tuple,
     with ``options``.  The function's name is the program's name in a
     profile and in the host's record (``scopes.startup()``): both halves of
     a plan's job are ``decode``, as the one program is, and ``part`` tells
-    the objects apart in the record's ``calls``."""
+    the objects apart in the record's ``calls``.  What comes back is the
+    jitted function and its program object, whose ``dispatch()`` the caller
+    opens around each call of it."""
     import jax
     from jax.sharding import PartitionSpec as P
 
@@ -326,7 +351,7 @@ def _program(cfg: TransformerConfig, mesh, local, part: str, in_specs: tuple,
         record.traced()
         return mapped(params, *args)
 
-    return decode
+    return decode, record
 
 
 def _one_program(cfg: TransformerConfig, mesh, max_new: int,
@@ -342,16 +367,18 @@ def _one_program(cfg: TransformerConfig, mesh, max_new: int,
         return generate(params, head, prompt, seed,
                         *prefill(params, head, prompt, seed))[0]
 
-    return _greedy(_program(
+    decode, whole = _program(
         cfg, mesh, local, "whole", (P("dp", None), P()),
-        (P("dp", None), P()) if keep_logits else P("dp", None)), temperature)
+        (P("dp", None), P()) if keep_logits else P("dp", None))
+    return _greedy(decode, temperature, whole)
 
 
 @functools.lru_cache(maxsize=8)
 def _prefill_program(cfg: TransformerConfig, mesh, temperature: float,
                      top_k: int, keep_logits: int):
     """jitted (params, prompt (B, Tp), seed) -> (tokens (B, Tp+1), logits
-    (keep_logits, 1, V), carry): what a decoder of ``max_new=1`` returns, and
+    (keep_logits, 1, V), carry), with its program object
+    (:func:`_program`): what a decoder of ``max_new=1`` returns, and
     the carry ``Tp`` positions long (every mechanism's buffers, in
     ``block.mechanisms``' order, flat).  One object
     for every ``max_new`` of a configuration on a mesh, so one executable:
@@ -398,11 +425,12 @@ def _two_programs(cfg: TransformerConfig, mesh, max_new: int,
     from ompi_tpu.core.scopes import scope
     from ompi_tpu.models import block as blk
 
-    first = _prefill_program(cfg, mesh, float(temperature), top_k,
-                             keep_logits)
+    first, prefilled = _prefill_program(cfg, mesh, float(temperature), top_k,
+                                        keep_logits)
     if max_new == 1:
         def decode(params, prompt, seed):
-            tokens, kept, _carry = first(params, prompt, seed)
+            with prefilled.dispatch():
+                tokens, kept, _carry = first(params, prompt, seed)
             return (tokens, kept) if keep_logits else tokens
 
         return _greedy(decode, temperature)
@@ -430,16 +458,18 @@ def _two_programs(cfg: TransformerConfig, mesh, max_new: int,
 
     # the states come back so that each is written in the buffer it came
     # in: a donated buffer is reused for an output of its shape alone
-    decode = _program(
+    decode, generated = _program(
         cfg, mesh, local, "generate",
         (P("dp", None), P(), P(), P(None, "dp"), P(None, "dp")),
         ((P("dp", None), P()) if keep_logits else P("dp", None),
          P(None, "dp")), donate_argnums=4)
 
     def both(params, prompt, seed):
-        tokens, kept, carry = first(params, prompt, seed)
-        return decode(params, tokens, kept, seed,
-                      [b for b, g in zip(carry, grows) if not g],
-                      [b for b, g in zip(carry, grows) if g])[0]
+        with prefilled.dispatch():
+            tokens, kept, carry = first(params, prompt, seed)
+        with generated.dispatch():
+            return decode(params, tokens, kept, seed,
+                          [b for b, g in zip(carry, grows) if not g],
+                          [b for b, g in zip(carry, grows) if g])[0]
 
     return _greedy(both, temperature)

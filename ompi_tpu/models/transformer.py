@@ -1342,14 +1342,17 @@ def make_train_step(cfg: TransformerConfig, mesh, lr: float = 3e-4):
         # params/opt_state are donated: the updated trees reuse their HBM
         # in place of a second full copy (≈1.6 GiB at 133M params with Adam).
         # The function's name is the program's name in a profile and in
-        # the host's record (``scopes.startup()``).
+        # the host's record (``scopes.startup()``).  What is handed out is
+        # the jitted function with the run half's spans around a call
+        # (``scopes.run()``); its ``lower`` and the rest are the function's.
         @functools.partial(jax.jit, donate_argnums=(0, 1),
                            compiler_options=_compiler_options(mesh))
         def train_step(params, opt_state, tokens):
             record.traced()
             return body(params, opt_state, tokens)
 
-        return train_step, _init_on_mesh(cfg, mesh, opt.init)
+        return (scopes.ran(train_step, record),
+                _init_on_mesh(cfg, mesh, opt.init))
 
 
 def make_train_loop(cfg: TransformerConfig, mesh, lr: float = 3e-4,
@@ -1383,4 +1386,5 @@ def make_train_loop(cfg: TransformerConfig, mesh, lr: float = 3e-4,
                 scan_body, (params, opt_state), None, length=steps)
             return params, opt_state, losses
 
-        return train_loop, _init_on_mesh(cfg, mesh, opt.init)
+        return (scopes.ran(train_loop, record),
+                _init_on_mesh(cfg, mesh, opt.init))

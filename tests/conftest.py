@@ -79,25 +79,6 @@ def pytest_configure(config):
         config.option.loadscopereorder = False
 
 
-def pytest_collection_modifyitems(items):
-    """One test under ``tests/benchmarks/`` (``BENCHMARK.json``'s ``paths``:
-    a ``benchmark`` PR's to edit, D15.1) pins two counts that PR 66 changed
-    on purpose: 31 ``masked_attention`` traces and over 5000 helper traces
-    in cell 9's prefill, which are 1 and under 1500 since its slices are
-    scans over one shape.  Its every other assertion, and the counts that
-    hold now, are ``tests/parallel/test_block_select.py::
-    test_cell_9s_two_programs_at_real_sizes_leave_a_few_dozen_records``.
-    Strict: the PR that mends the pin has to delete this hook, and that
-    copy, with it."""
-    for item in items:
-        if item.nodeid == (
-                "tests/benchmarks/test_startup_split_rows.py::test_cell_9s_"
-                "two_programs_at_real_sizes_leave_a_few_dozen_records"):
-            item.add_marker(pytest.mark.xfail(
-                reason="pins PR 54's 31 kernel traces and 5000 helper traces",
-                raises=AssertionError, strict=True))
-
-
 @pytest.fixture(scope="session", autouse=True)
 def _pallas_tpu_interpret_mode():
     """The pallas kernels (ops/) carry no interpret selection of their own:

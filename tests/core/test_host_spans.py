@@ -1,10 +1,12 @@
 """The host half of ``ompi_tpu/core/scopes.py``: ``host()``, the record, the
 compile stages by program name from ``jax.monitoring`` (helpers folded into
 the stage around them), and ``startup()`` by name, by program object and by
-``trace.*`` span.  CPU only: what is recorded and how it adds up, never how
-long a chip took.
+``trace.*`` span; then the run half: ``run.call``, ``run.dispatch`` and
+``run.gc`` in a ring of their own, and ``run()``.  CPU only: what is
+recorded and how it adds up, never how long a chip took.
 """
 
+import gc
 import os
 import re
 import threading
@@ -23,7 +25,10 @@ STAGES = ("compile.trace", "compile.lower", "compile.backend")
 
 
 @pytest.fixture(autouse=True)
-def fresh_record():
+def fresh_record(monkeypatch):
+    """An empty record, and no pass of the collector in it but where a test
+    asks for them (a pass of a millisecond can land in any test)."""
+    monkeypatch.setattr(scopes, "GC_RECORDED_FROM", 1e9)
     scopes.reset()
     yield
     scopes.reset()
@@ -46,9 +51,14 @@ def test_every_name_of_the_vocabulary_records_one_span(name):
     assert re.fullmatch(r"[a-z]+\.[a-z_]+", name)
     with scopes.host(name, program="p"):
         pass
-    (span,) = scopes.records()
+    # a name of the run half is the ring's, any other the start-up record's
+    mine, other = ((scopes.run_records, scopes.records)
+                   if name.startswith("run.")
+                   else (scopes.records, scopes.run_records))
+    (span,) = [s for s in mine() if s.name == name]
     assert (span.name, span.program, span.parent) == (name, "p", None)
     assert span.end >= span.start
+    assert not [s for s in other() if s.name == name]
 
 
 def test_records_nest_with_the_right_parent():
@@ -153,7 +163,8 @@ def test_an_own_program_inside_another_keeps_its_seconds():
 
 
 def _build_train_step():
-    """What a factory does, with a program small enough for any test."""
+    """What a factory does, with a program small enough for any test: the
+    start-up half's tests below all run with the run half recording."""
     with scopes.host("build.train_step", program="train_step"):
         record = scopes.program("train_step")
 
@@ -162,7 +173,7 @@ def _build_train_step():
             record.traced()
             return jnp.tanh(x) @ x + jnp.sum(x)
 
-    return train_step
+    return scopes.ran(train_step, record)
 
 
 def test_a_program_is_recorded_stage_by_stage_under_its_name():
@@ -577,3 +588,463 @@ def test_one_record_and_one_vocabulary():
     assert found == [os.path.join("ompi_tpu", "core", "scopes.py")]
     with open(os.path.join(ROOT, "chip_smoke.py"), encoding="utf-8") as f:
         assert "CompileMeter" not in f.read()
+
+
+# ---------------------------------------------------------------------------
+# the run half: ``run.call``, ``run.dispatch``, ``run.gc`` and ``run()``
+# ---------------------------------------------------------------------------
+
+def _ran(name: str) -> list:
+    return [s for s in scopes.run_records() if s.name == name]
+
+
+def test_the_request_id_runs_from_the_call_through_the_dispatch_to_a_stage():
+    step = _build_train_step()
+    x = jnp.ones((8, 8), jnp.float32)
+    jax.block_until_ready(step(x))
+    jax.block_until_ready(step(x))
+    first, second = _ran("run.call")
+    one, two = _ran("run.dispatch")
+    assert (first.n, second.n, one.n, two.n) == (1, 2, 1, 2)
+    assert first.parent is None and one.parent == first.id
+    assert two.parent == second.id and len({first.id, second.id}) == 2
+    assert (first.program, one.program, one.built) == (
+        "train_step", "train_step", 0)
+    assert first.cpu_s > 0 and first.switches >= 0 and first.faults >= 0
+    assert second.cpu_s >= first.cpu_s and second.switches >= first.switches
+    # the stages began inside the first dispatch: they name it, in the
+    # start-up record as in the ring
+    stages = [s for s in _of("train_step") if s.name in STAGES]
+    assert [s.parent for s in stages] == [one.id] * 3
+    assert [s.id for s in scopes.run_records() if s.name in STAGES] == [
+        s.id for s in stages]
+    assert first.start <= one.start <= stages[0].start
+    assert stages[-1].end <= one.end <= first.end
+    out = scopes.run()
+    (callable_,) = out["callables"]
+    assert (callable_["calls"], callable_["compiled"],
+            callable_["quiet"]) == (2, 1, 1)
+    assert callable_["median_s"] == callable_["longest_s"] == (
+        second.end - second.start)
+    assert callable_["host_s"] == pytest.approx(
+        first.end - first.start + second.end - second.start)
+    (row,) = out["programs"]
+    assert (row["program"], row["part"], row["built"], row["dispatches"],
+            row["compiles"], row["recompiled"], row["recompiles"]) == (
+        "train_step", None, 0, 2, 1, 0, [])
+    assert row["first_s"] == one.end - one.start
+    assert 0 <= row["first_rest_s"] == pytest.approx(
+        row["first_s"] - sum(s.end - s.start for s in stages), abs=1e-9)
+    (job,) = out["jobs"]
+    assert job["n"] == 1 and job["wall_s"] == second.start - first.start
+    assert job["call_s"] == first.end - first.start
+    assert job["cpu_s"] == second.cpu_s - first.cpu_s
+    assert out["records"] == 4 and out["wrapped"] == 0
+
+
+def test_a_later_call_that_compiles_says_which_call_and_which_object():
+    step = _build_train_step()
+    x = jnp.ones((8, 8), jnp.float32)
+    for _ in range(3):
+        step(x)
+    step(jnp.ones((4, 4), jnp.float32))         # the fourth call: a new shape
+    step(x)
+    out = scopes.run()
+    (row,) = out["programs"]
+    assert (row["dispatches"], row["compiles"], row["recompiled"]) == (5, 2, 1)
+    (again,) = row["recompiles"]
+    assert again["n"] == 4 and again["backend_s"] > 0
+    (callable_,) = out["callables"]
+    assert (callable_["calls"], callable_["compiled"],
+            callable_["quiet"]) == (5, 2, 3)
+    fourth = _ran("run.dispatch")[3]
+    assert [s.parent for s in _of("train_step")
+            if s.name in STAGES][3:] == [fourth.id] * 3
+
+
+def test_a_call_traced_into_another_program_is_no_run():
+    """``Job.programs()`` does ``jax.jit(self.first)`` over a decoder's
+    callable: its call runs under that program's ``compile.trace``."""
+    step = _build_train_step()
+    run = scopes.caller("decode")
+
+    def handed_out(x):
+        with run.call():
+            return step(x)
+
+    x = jnp.ones((8, 8), jnp.float32)
+    jax.jit(handed_out).lower(x)
+    jax.block_until_ready(jax.jit(handed_out)(x))
+    assert [s for s in scopes.run_records() if s.name.startswith("run.")] == []
+    out = scopes.run()
+    assert [c["calls"] for c in out["callables"]] == [0, 0]
+    assert out["programs"][0]["dispatches"] == 0
+    assert out["programs"][0]["first_s"] is None
+    # the start-up half saw the program traced, as it did
+    assert scopes.startup()["programs"]["train_step"]["traces"] == 1
+    # and the same callable, called, is a run: two calls, one dispatch
+    jax.block_until_ready(handed_out(x))
+    assert [(s.name, s.program) for s in scopes.run_records()
+            if s.name.startswith("run.")] == [
+        ("run.call", "decode"), ("run.call", "train_step"),
+        ("run.dispatch", "train_step")]
+    outer, inner, dispatch = _ran("run.call") + _ran("run.dispatch")
+    assert inner.parent == outer.id and dispatch.parent == inner.id
+
+
+def _call(n, start, end, ident, cpu_s, switches, faults, made=0):
+    return Span("run.call", "decode", start, end, None, ident, None, 0, made,
+                n, cpu_s, switches, faults)
+
+
+def _dispatch(n, start, end, parent, ident, built):
+    return Span("run.dispatch", "decode", start, end, parent, ident, None, 0,
+                built, n)
+
+
+MADE_UP_RUN = [
+    # the first call: both objects trace, lower and compile inside it
+    _call(1, 0.0, 10.0, 1, 0.5, 3, 0),
+    _dispatch(1, 0.0, 6.0, 1, 2, 0),
+    Span("compile.trace", "decode", 0.5, 2.5, 2, 3, None, 5, 0),
+    Span("trace.layer", "kda", 1.0, 2.0, 3, 4),     # the trace's own child
+    Span("compile.lower", "decode", 2.5, 3.0, 2, 5, None, 0, 0),
+    Span("compile.backend", "decode", 3.0, 5.0, 2, 6, "miss", 0, 0),
+    _dispatch(1, 6.0, 10.0, 1, 7, 1),
+    Span("import.pallas", None, 6.0, 6.5, 7, 8),
+    Span("compile.backend", "decode", 6.5, 9.0, 7, 9, "hit", 0, 1),
+    # a quiet call, with a pass of the collector inside it
+    _call(2, 20.0, 20.5, 10, 0.75, 3, 0),
+    _dispatch(2, 20.0, 20.25, 10, 11, 0),
+    _dispatch(2, 20.25, 20.5, 10, 12, 1),
+    Span("run.gc", "gen2", 20.125, 20.375, None, 13),
+    Span("run.gc", "gen1", 25.0, 26.0, None, 14),   # between two calls
+    # the third call recompiles its second program
+    _call(3, 30.0, 33.0, 15, 1.0, 7, 1),
+    _dispatch(3, 30.0, 30.25, 15, 16, 0),
+    _dispatch(3, 30.25, 33.0, 15, 17, 1),
+    Span("compile.backend", "decode", 30.5, 32.5, 17, 18, "miss", 0, 1),
+    Span("run.gc", "gen2", 31.0, 31.5, None, 19),   # in a call that compiled
+    # another callable of the same name: one quiet call
+    _call(1, 40.0, 41.5, 20, 1.5, 7, 1, made=1),
+    _dispatch(1, 40.0, 41.0, 20, 21, 0),
+]
+
+
+def test_first_rest_recompiles_gc_and_jobs_on_made_up_records():
+    scopes.program("decode", "prefill")
+    scopes.program("decode", "generate")
+    scopes.caller("decode")
+    scopes.caller("decode")
+    out = scopes.run(MADE_UP_RUN)
+    prefill, generate = out["programs"]
+    assert (prefill["part"], prefill["first_s"], prefill["first_rest_s"],
+            prefill["recompiles"]) == ("prefill", 6.0, 6.0 - 4.5, [])
+    assert (generate["part"], generate["first_s"],
+            generate["first_rest_s"]) == ("generate", 4.0, 4.0 - 3.0)
+    assert generate["recompiles"] == [{"n": 3, "backend_s": 2.0}]
+    one, other = out["callables"]
+    assert (one["quiet"], one["median_s"], one["total_s"],
+            one["longest_s"]) == (1, 0.5, 0.5, 0.5)
+    assert (other["quiet"], other["median_s"]) == (1, 1.5)
+    # the pass inside the quiet call is that call's; the one inside the
+    # call that compiled is no quiet call's
+    assert out["gc"]["in_calls_s"] == 0.25 and out["gc"]["recorded"] == 3
+    # a job is a call to the next in time, whichever callable's
+    assert out["jobs"] == [
+        {"program": "decode", "made": 0, "n": 1, "wall_s": 20.0,
+         "call_s": 10.0, "cpu_s": 0.25, "switches": 0, "faults": 0,
+         "gc_s": 0.0},
+        {"program": "decode", "made": 0, "n": 2, "wall_s": 10.0,
+         "call_s": 0.5, "cpu_s": 0.25, "switches": 4, "faults": 1,
+         "gc_s": 0.25 + 1.0},
+        {"program": "decode", "made": 0, "n": 3, "wall_s": 10.0,
+         "call_s": 3.0, "cpu_s": 0.5, "switches": 0, "faults": 0,
+         "gc_s": 0.5}]
+    assert out["records"] == 4 + 7 + 3
+    # the counters are the handles', which made-up spans do not move
+    assert [c["calls"] for c in out["callables"]] == [0, 0]
+    assert [p["dispatches"] for p in out["programs"]] == [0, 0]
+
+
+def test_a_pass_on_another_thread_counts_for_the_call_it_overlaps(
+        monkeypatch):
+    """The input worker's allocations can trip a pass that stops the main
+    thread's dispatch: the pass is on no thread's stack, nobody's child and
+    nobody's parent, and the call's by its time."""
+    monkeypatch.setattr(scopes, "GC_RECORDED_FROM", 0.0)
+    run = scopes.caller("decode")
+    before = scopes.run()["gc"]
+
+    def worker():
+        with scopes.host("data.produce"):
+            gc.collect()
+
+    with run.call():
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join(timeout=30.0)
+        assert not t.is_alive()
+    (call,) = _ran("run.call")
+    passes = [s for s in _ran("run.gc") if s.program == "gen2"]
+    assert passes and all(s.parent is None for s in passes)
+    inside = [s for s in passes if call.start <= s.start and s.end <= call.end]
+    assert inside
+    (produced,) = [s for s in scopes.records() if s.name == "data.produce"]
+    assert produced.parent is None              # and the pass is not its child
+    assert not [s for s in scopes.records() if s.name == "run.gc"]
+    out = scopes.run()
+    assert out["gc"]["in_calls_s"] >= sum(s.end - s.start for s in inside) > 0
+    assert out["gc"]["gen2"]["passes"] > before["gen2"]["passes"]
+    assert out["gc"]["gen2"]["seconds"] > before["gen2"]["seconds"]
+    assert out["gc"]["longest_s"] >= max(s.end - s.start for s in inside)
+
+
+def test_a_short_pass_is_counted_and_not_recorded():
+    with scopes.host("data.produce"):       # the listeners, the callback
+        pass
+    before = scopes.run()["gc"]["gen0"]["passes"]
+    gc.collect(0)
+    out = scopes.run()["gc"]
+    assert out["gen0"]["passes"] == before + 1 and out["recorded"] == 0
+
+
+def test_the_collectors_callback_never_raises(monkeypatch):
+    with scopes.host("data.produce"):
+        pass
+    monkeypatch.setattr(scopes, "_passes", None)    # whatever goes wrong
+    scopes._on_gc("start", {"generation": 2})
+    scopes._on_gc("stop", {"generation": 2})
+    scopes._on_gc("stop", {})                       # a stop with no start
+    assert scopes._on_gc in gc.callbacks
+    assert gc.callbacks.count(scopes._on_gc) == 1
+
+
+def test_the_ring_wraps_and_the_counters_do_not():
+    assert scopes.RING == 4096
+    record, run = scopes.program("decode", "whole"), scopes.caller("decode")
+    calls = scopes.RING // 2 + 52
+    for _ in range(calls):
+        with run.call(), record.dispatch():
+            pass
+    held = scopes.run_records()
+    # the ring's newest 4096, and the object's first dispatch beside them
+    assert len(held) == scopes.RING + 1
+    assert held[0].name == "run.dispatch" and held[0].n == 1
+    assert held[1].n == 53 and held[-1].n == calls
+    out = scopes.run()
+    assert out["wrapped"] == 2 * calls - scopes.RING == 104
+    (callable_,) = out["callables"]
+    assert callable_["calls"] == calls and callable_["quiet"] == calls - 52
+    (row,) = out["programs"]
+    assert row["dispatches"] == calls and row["first_s"] is not None
+    assert len(out["jobs"]) == calls - 52 - 1
+    # the start-up record holds none of it
+    assert scopes.records() == [] and scopes.startup()["records"] == 0
+    scopes.reset()
+    assert scopes.run_records() == [] and scopes.run()["wrapped"] == 0
+    assert run.made is None and scopes.run()["callables"] == []
+
+
+def test_the_start_up_record_is_what_it_was_with_the_run_half_recording():
+    keys = {"spans", "programs", "calls", "others", "trace", "retraces",
+            "totals", "records", "dropped"}
+    step = _build_train_step()
+    x = jnp.ones((8, 8), jnp.float32)
+    jax.block_until_ready(step(x))
+    n = len(scopes.records())
+    for _ in range(3):
+        jax.block_until_ready(step(x))
+    gc.collect()
+    out = scopes.startup()
+    assert set(out) == keys
+    assert out["records"] == len(scopes.records()) == n
+    assert out["dropped"] == 0
+    assert not [s for s in scopes.records() if s.name.startswith("run.")]
+    assert not [name for name in out["spans"] if name.startswith("run.")]
+    assert [s.name for s in _of("train_step")] == ["build.train_step", *STAGES]
+    # a stage inside a dispatch is a top-level stage to the start-up half
+    assert out["programs"]["train_step"]["compiles"] == 1
+    assert len(_ran("run.call")) == 4
+
+
+def test_what_the_factories_hand_out_keeps_lower_and_the_fast_path():
+    from ompi_tpu.models import transformer as tfm
+    from ompi_tpu.models.decode import make_decoder
+    from ompi_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh({"dp": 1, "sp": 1, "tp": 1}, devices=jax.devices()[:1])
+    cfg = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=2, n_layers=1,
+                                d_ff=64, seq=16, attention="xla",
+                                compute_dtype="float32", remat=False)
+    params = tfm.init_params(cfg)
+    tokens = jnp.zeros((2, cfg.seq), jnp.int32)
+    for factory, name in ((tfm.make_train_step, "train_step"),
+                          (tfm.make_train_loop, "train_loop")):
+        step, init = factory(cfg, mesh)
+        assert step.__name__ == name and callable(step.lower)
+        text = step.lower(params, init(params), tokens).as_text()
+        assert f"@jit_{name}" in text
+    assert not _ran("run.call")                 # a lowering is no run
+    decode = make_decoder(cfg, mesh, max_new=2)
+    prompt = jnp.zeros((2, 4), jnp.int32)
+    assert "@jit__lambda" in jax.jit(decode).lower(params, prompt).as_text()
+    assert not _ran("run.call")
+    # a call is the jitted function's own: nothing between the handle and
+    # the function but the two spans
+    step, init = tfm.make_train_step(cfg, mesh)
+    placed = tfm.shard_params(cfg, mesh, params)
+    state = init(placed)
+    placed, state, loss = step(placed, state, tokens)
+    placed, state, loss = step(placed, state, tokens)
+    jax.block_until_ready(loss)
+    (row,) = [r for r in scopes.run()["programs"] if r["dispatches"]]
+    assert (row["program"], row["dispatches"], row["compiles"],
+            row["recompiled"]) == ("train_step", 2, 1, 0)
+    assert [s.n for s in _ran("run.call")] == [1, 2]
+    # parameters that no one placed come back placed: the second call
+    # compiles the step again (``transformer._init_on_mesh``), and the
+    # record says it was the second
+    scopes.reset()
+    step, init = tfm.make_train_step(cfg, mesh)
+    state = init(params)
+    for _ in range(3):
+        params, state, loss = step(params, state, tokens)
+    (row,) = [r for r in scopes.run()["programs"] if r["dispatches"]]
+    assert (row["compiles"], row["recompiled"]) == (2, 1)
+    assert [r["n"] for r in row["recompiles"]] == [2]
+
+
+def test_a_forced_pass_inside_a_job_is_the_jobs_and_an_annotation(
+        tmp_path, monkeypatch):
+    """``gc.in_calls_s`` of the job it stopped, and ``ompi_tpu:run.gc`` on
+    the profile's clock, where ``benchmarks/lib/clock.py`` names idle gaps
+    after the innermost span."""
+    import glob
+
+    monkeypatch.setattr(scopes, "GC_RECORDED_FROM", 0.0)
+    step = _build_train_step()
+    run = scopes.caller("decode")
+
+    def job(x, collect):
+        with run.call():
+            y = step(x)
+            if collect:
+                gc.collect()
+            return y
+
+    x = jnp.ones((8, 8), jnp.float32)
+    jax.block_until_ready(job(x, False))        # compiles: no quiet call
+    assert scopes.run()["gc"]["in_calls_s"] == 0
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=options):
+        jax.block_until_ready(job(x, True))
+    out = scopes.run()
+    forced = [s for s in _ran("run.gc") if s.program == "gen2"][-1]
+    second = [s for s in _ran("run.call") if s.program == "decode"][-1]
+    assert second.start <= forced.start and forced.end <= second.end
+    assert out["gc"]["in_calls_s"] >= forced.end - forced.start > 0
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    names = {e.name for plane in data.planes for line in plane.lines
+             for e in line.events}
+    assert {scopes.PREFIX + "run.gc", scopes.PREFIX + "run.call",
+            scopes.PREFIX + "run.dispatch"} <= names
+
+
+def test_chip_smoke_prints_the_run_half_beside_the_start_up_half():
+    import json
+
+    import chip_smoke
+
+    step = _build_train_step()
+    x = jnp.ones((8, 8), jnp.float32)
+    for _ in range(4):
+        jax.block_until_ready(step(x))
+    line = json.loads(json.dumps(chip_smoke.run_summary(slowest=2)))
+    assert set(line) == {"callables", "programs", "gc", "jobs", "records",
+                         "wrapped"}
+    assert len(line["jobs"]) == 2               # the longest, not all three
+    assert line["callables"][0]["calls"] == 4
+    assert line["programs"][0]["first_rest_s"] >= 0
+
+
+def _cells():
+    from benchmarks.lib import cells
+
+    return [w["name"] for w in cells.load_benchmark()["workloads"]]
+
+
+@pytest.mark.parametrize("workload", _cells())
+def test_a_tiny_job_of_a_cell_is_one_call_and_its_dispatches(workload):
+    """Through the factories' callables as the runners hold them: a train
+    step is one dispatch, a decoder of one program one, a ``full`` job of a
+    plan or retention cell its prefill and then its generating program, and
+    its ``first`` the prefill alone."""
+    from benchmarks.lib import cells, program
+    from benchmarks.lib.spans import Spans
+    from ompi_tpu.models import decode
+    from tests.benchmarks.test_harness import TINY_TRAFFIC
+
+    decode._prefill_program.cache_clear()
+    cell = cells.resolve(workload)
+    cell.config = program.tiny(cell.config)
+    cell.traffic = {k: TINY_TRAFFIC.get(k, v)
+                    for k, v in cell.traffic.items()}
+    job = cell.runner.build(cell.config, cell.traffic,
+                            jax.devices()[:cell.chips])
+    try:
+        if hasattr(job, "step"):
+            job.setup(5, Spans())       # two warm-up steps of one callable
+            jobs = [("train_step", 1, [None]), ("train_step", 2, [None])]
+        else:
+            params, prompts = job.draw(5)
+            jax.block_until_ready(job.first(params, prompts))
+            jax.block_until_ready(job.full(params, prompts))
+            parts = [p.part for p in scopes._programs]
+            assert parts in (["whole", "whole"], ["prefill", "generate"])
+            jobs = ([("decode", 1, ["whole"]), ("decode", 1, ["whole"])]
+                    if parts[0] == "whole" else
+                    [("decode", 1, ["prefill"]),
+                     ("decode", 1, ["prefill", "generate"])])
+    finally:
+        job.close()
+    calls = _ran("run.call")
+    assert [(c.program, c.n, c.parent) for c in calls] == [
+        (name, n, None) for name, n, _parts in jobs]
+    by_built = {p.built: p.part for p in scopes._programs}
+    for call, (name, _n, parts) in zip(calls, jobs):
+        inside = [s for s in _ran("run.dispatch") if s.parent == call.id]
+        assert [(s.program, by_built[s.built], s.n) for s in inside] == [
+            (name, part, call.n) for part in parts]
+        assert call.start <= inside[0].start and inside[-1].end <= call.end
+    out = scopes.run()
+    assert sum(c["calls"] for c in out["callables"]) == len(jobs)
+    assert all(p["first_rest_s"] is not None and p["recompiled"] == 0
+               for p in out["programs"])
+    # a prefill shared by both decoders compiled once, in the first job
+    if jobs[-1][2] == ["prefill", "generate"]:
+        prefill, generate = out["programs"]
+        assert (prefill["dispatches"], prefill["compiles"]) == (2, 1)
+        assert (generate["dispatches"], generate["compiles"]) == (1, 1)
+    assert scopes.startup()["records"] < 300
+
+
+def test_the_tool_lists_the_jobs_off_their_callables_median():
+    """``tools/run_record.py``: ``run()["jobs"]`` against the median of each
+    callable's, and the ones more than ``OFF`` from it."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "run_record", os.path.join(ROOT, "tools", "run_record.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    scopes.caller("decode")
+    out = tool.off(scopes.run(MADE_UP_RUN)["jobs"])
+    assert tool.OFF == 0.005
+    assert out["callables"] == {"0": {"jobs": 3, "median_wall_s": 10.0}}
+    (late,) = out["off"]
+    assert (late["n"], late["wall_s"], late["call_s"], late["off"]) == (
+        1, 20.0, 10.0, 1.0)

@@ -487,55 +487,3 @@ def test_the_indexed_prefill_lowers_to_what_it_did(v5e):
         "masked_attention"] * 3
     assert hashlib.sha256(text.encode()).hexdigest() == (
         "041507389508f99216e0d2374c7122aa05416fedd42ed1ce2f8556130f90397c")
-
-
-def test_cell_9s_two_programs_at_real_sizes_leave_a_few_dozen_records(v5e):
-    """``tests/benchmarks/test_startup_split_rows.py``'s test of this name,
-    line for line but two: the record of the cell's two programs lowered at
-    their real sizes (31 slices of 512 queries a prompt of 15,872) holds one
-    ``masked_attention`` trace (both scans call one jitted caller at one
-    signature) where it pins the 31 that a loop in python left, and under
-    1500 of the prefill's helper traces where it pins over 5000 (10,316 on
-    the chip's host at PR 54).  That one lies under ``BENCHMARK.json``'s
-    ``paths`` and is a strict ``xfail`` from ``tests/conftest.py`` until a
-    ``benchmark`` PR mends the two numbers, which deletes this copy too."""
-    from ompi_tpu.core import scopes
-    from ompi_tpu.models import decode
-    from tests.benchmarks.test_startup_split_rows import _read
-
-    decode._prefill_program.cache_clear()
-    jax.clear_caches()
-    scopes.reset()
-    try:
-        cell = cells.resolve(CELL)
-        job = cell.runner.build(cell.config, cell.traffic, v5e[:cell.chips])
-        texts = {name: _lowered(fn, *args)
-                 for name, (fn, args) in job.programs().items()}
-        out = scopes.startup()
-        rows = {name: _read(name) for name in (
-            "startup_kernel_trace_s", "startup_helper_traces",
-            "startup_trace_s")}
-    finally:
-        scopes.reset()
-    assert out["records"] < 300 and out["dropped"] == 0
-    first, full = out["calls"]
-    assert (first["part"], full["part"]) == ("prefill", "generate")
-    assert first["trace_s"] > full["trace_s"] > 0
-    assert 1500 > first["helpers"] > full["helpers"] > 100
-    own = out["programs"]["decode"]
-    for key in ("trace_s", "lower_s", "backend_s"):
-        assert first[key] + full[key] == pytest.approx(own[key], abs=1e-6)
-    # by layer kind and by kernel: the selected layer's slices traced once
-    assert set(out["trace"]["trace.layer"]) == {"block_select", "lightning"}
-    kernels = out["trace"]["trace.kernel"]
-    assert {name: row["spans"] for name, row in kernels.items()} == {
-        "masked_attention": 1, "selected_attention": 1}
-    assert all(row["own_s"] == row["seconds"] > 0
-               for by in out["trace"].values() for row in by.values())
-    assert rows["startup_kernel_trace_s"] == pytest.approx(
-        sum(row["seconds"] for row in kernels.values()))
-    assert rows["startup_helper_traces"] == own["helpers"]
-    assert rows["startup_trace_s"] == pytest.approx(own["trace_s"])
-    for text in texts.values():
-        assert len(re.findall(r'kernel_name = "masked_attention"', text)) == 1
-        assert "tensor<2x512x2048xbf16>" in text    # a slice, not a prompt
