@@ -216,6 +216,33 @@ def _within_groups(choice, groups: tuple):
         return jnp.where(kept[:, :, None], by_group, -jnp.inf).reshape(n, E)
 
 
+def _sum_of_picks(out, slot, gate):
+    """``sum_j gate[t, j] out[slot[j n + t]]`` in float32, (n, D): ``out``
+    (rows, D) the experts' result rows as they are laid out, ``gate`` (n, k)
+    each token's weights and ``slot`` (k n,) its picks' rows, picks major.
+    One gather, then one fusion that reads the k runs of n rows as the gather
+    leaves them and widens them as it adds.  Gathered tokens major and summed
+    over a middle axis of ``k``, the rows are first written out again in
+    float32 with the picks on the sublane axis, of which a float32 tile has
+    eight: six picks are stored as eight and ten as sixteen (1074 MB written
+    and read again a pass of cell 13 for 335 MB of rows).  The barrier keeps
+    the sum a fusion of its own: a matrix product that takes it as its
+    epilogue (a shared expert's down projection, which the sum is added to)
+    cannot widen its operands, and the rows are written out in float32 for
+    it first."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    n, k = gate.shape
+    rows = out[slot]
+    y = None
+    for j in range(k):      # lax's slices: jnp's index is a helper to trace
+        pick = (lax.slice_in_dim(rows, j * n, (j + 1) * n).astype(jnp.float32)
+                * lax.slice_in_dim(gate, j, j + 1, axis=1))
+        y = pick if y is None else y + pick
+    return lax.optimization_barrier(y)
+
+
 def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
                kernel: bool = False, renorm: bool = False,
                score: str = "softmax", scale: float = 1.0, held=None,
@@ -278,7 +305,9 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
     only at run time; ``tokens·top_k/tm + E`` tiles always suffice).  The
     slots that fill an expert's last tile up hold other real rows and are
     read back by nobody.  Each token then sums its ``top_k`` result rows
-    weighted by their probabilities, in float32.
+    weighted by their probabilities, in float32, from rows gathered picks
+    major (:func:`_sum_of_picks`: summed over a middle axis of six or ten
+    picks they are first written out in float32, padded to a tile's eight).
 
     Where the device holds few of a wide router's experts
     (``_window_rows``, from static shapes: LongCat-Flash's 16 of 768
@@ -404,8 +433,10 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
             slot = jnp.arange(rows_in) + jnp.sum(
                 jnp.where(is_group, shift[None, :], 0), axis=1)
             if y is None:
-                # assignment -> its slot (a sort by ``order`` is its inverse)
-                _, slot = lax.sort((order, slot), num_keys=1)
+                # assignment -> its slot (a sort by ``order`` is its
+                # inverse), a token's first picks before every second one
+                _, slot = lax.sort(((order % k) * n + order // k, slot),
+                                   num_keys=1)
         with scope("moe.experts"):
             used = tile_end[-1:]
             stacks = {name: params[name].astype(cdt)
@@ -424,8 +455,7 @@ def routed_moe(x, params, top_k: int, gated: bool = False, layer=None,
             out = matmul(hid, stacks["w2"], tile_group, used)
         with scope("moe.combine"):
             if y is None:
-                out = out[slot].reshape(n, k, D).astype(jnp.float32)
-                return jnp.sum(out * gate[:, :, None], axis=1)
+                return _sum_of_picks(out, slot, gate)
             # a row past the held picks (held elsewhere, or the padding of
             # the last window) weighs nothing
             weight = jnp.where(group < E, gate.reshape(n * k)[order], 0.0)

@@ -196,21 +196,28 @@ def _routed_jnp(x, p, k, gated, held=None, zero=0):
     return y.reshape(x.shape)
 
 
-ROUTED = [pytest.param(8, 2, True, None, 0, id="8x2-gated"),
-          pytest.param(8, 2, False, None, 0, id="8x2-gelu"),
-          pytest.param(4, 1, False, None, 0, id="4x1-gelu"),
-          pytest.param(16, 4, True, None, 0, id="16x4-gated"),
-          pytest.param(64, 8, True, None, 0, id="64x8-gated")]
+ROUTED = [pytest.param(8, 2, True, None, 0, 24, id="8x2-gated"),
+          pytest.param(8, 2, False, None, 0, 24, id="8x2-gelu"),
+          pytest.param(4, 1, False, None, 0, 24, id="4x1-gelu"),
+          pytest.param(16, 4, True, None, 0, 24, id="16x4-gated"),
+          pytest.param(64, 8, True, None, 0, 24, id="64x8-gated")]
+# six and ten picks a token (cells 10 and 12, cell 13), every expert here or
+# half of the router's, 42 tokens (no multiple of 16): the whole layout, and
+# picks that do not fill a float32 tile's eight sublanes
+PICKS = [pytest.param(16, 6, False, None, 0, 21, id="16x6-gelu"),
+         pytest.param(16, 6, True, (0, 8), 0, 21, id="16x6-held8"),
+         pytest.param(24, 10, True, None, 0, 21, id="24x10-gated"),
+         pytest.param(24, 10, False, (0, 12), 0, 21, id="24x10-held12")]
 # a device that holds 2 of a router's 64 outputs (16 of them identity
 # experts): few enough of a wide router that ``routed_moe`` works through
 # windows of the held picks (32 rows a window of the 384 routed)
-WINDOWED = [pytest.param(64, 8, True, (0, 2), 0, id="64x8-held2"),
-            pytest.param(64, 8, True, (0, 2), 16, id="64x8-held2-zero16")]
+WINDOWED = [pytest.param(64, 8, True, (0, 2), 0, 24, id="64x8-held2"),
+            pytest.param(64, 8, True, (0, 2), 16, 24, id="64x8-held2-zero16")]
 
 
-def _routed_case(E, gated, skewed, seed, held=None, zero=0):
+def _routed_case(E, gated, skewed, seed, held=None, zero=0, T=24):
     rng = np.random.default_rng(seed)
-    B, T, D, F = 2, 24, 32, 16
+    B, D, F = 2, 32, 16
     x = rng.normal(size=(B, T, D)).astype(np.float32)
     if skewed:
         x[..., 0] = 3.0
@@ -223,8 +230,8 @@ def _routed_case(E, gated, skewed, seed, held=None, zero=0):
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
 @pytest.mark.parametrize("skewed", [False, True], ids=["spread", "skewed"])
-@pytest.mark.parametrize("E,k,gated,held,zero", ROUTED + WINDOWED)
-def test_routed_moe_equals_a_loop_over_experts(E, k, gated, held, zero,
+@pytest.mark.parametrize("E,k,gated,held,zero,T", ROUTED + PICKS + WINDOWED)
+def test_routed_moe_equals_a_loop_over_experts(E, k, gated, held, zero, T,
                                                skewed, kernel):
     """No capacity: with a router that sends every token to expert 0 first,
     that expert gets all 48 rows (three and more tiles of 16) while others
@@ -233,15 +240,19 @@ def test_routed_moe_equals_a_loop_over_experts(E, k, gated, held, zero,
     interpret mode here) and through XLA's ragged_dot, which the model
     runs off the TPU.  In windows too: the held picks of a spread router
     fit the first window of 32 rows; skewed, expert 0's 48 rows overflow
-    it, a second window runs, and no pick is dropped."""
+    it, a second window runs, and no pick is dropped.  And with six and ten
+    picks a token, where half the router's experts are held here too (the
+    whole layout still: some of every token's picks weigh nothing)."""
     from ompi_tpu.parallel.moe import _window_rows, routed_moe
 
-    x, p = _routed_case(E, gated, skewed, seed=E + k, held=held, zero=zero)
+    x, p = _routed_case(E, gated, skewed, seed=E + k, held=held, zero=zero,
+                        T=T)
     want, rows = _routed_oracle(x, p, k, gated, held, zero)
     n = x.shape[0] * x.shape[1]
     if held is None:
         assert sum(rows) == n * k                       # nothing dropped
-        assert _window_rows(n * k, 16, E, E) == n * k   # the whole layout
+    if held is None or 2 * held[1] == E:                # the whole layout
+        assert _window_rows(n * k, 16, held[1] if held else E, E) == n * k
     else:
         cap = _window_rows(n * k, 16, held[1], E)
         assert cap == 32 and (sum(rows) > cap) == skewed
@@ -253,14 +264,16 @@ def test_routed_moe_equals_a_loop_over_experts(E, k, gated, held, zero,
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
-@pytest.mark.parametrize("E,k,gated,held,zero", ROUTED[:4] + WINDOWED)
-def test_routed_moe_gradient(E, k, gated, held, zero, kernel):
+@pytest.mark.parametrize("E,k,gated,held,zero,T",
+                         ROUTED[:4] + PICKS + WINDOWED)
+def test_routed_moe_gradient(E, k, gated, held, zero, T, kernel):
     """Through the kernel's custom_vjp, the gathers and the float32 router,
     against the gradient of the masked dense form; and, in windows (the
     skewed router overflows the first), through the loop over them."""
     from ompi_tpu.parallel.moe import routed_moe
 
-    x, p = _routed_case(E, gated, skewed=True, seed=7, held=held, zero=zero)
+    x, p = _routed_case(E, gated, skewed=True, seed=7, held=held, zero=zero,
+                        T=T)
     target = np.random.default_rng(1).normal(size=x.shape).astype(np.float32)
 
     def loss(layer, **how):
@@ -275,6 +288,32 @@ def test_routed_moe_gradient(E, k, gated, held, zero, kernel):
         assert np.abs(np.asarray(w)).max() > 0
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3,
                                    atol=2e-4 * np.abs(np.asarray(w)).max())
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("n,k", [(42, 6), (42, 10), (48, 8), (5, 1)])
+def test_the_sum_of_a_tokens_picks_is_the_sum_over_one_gather(n, k, dtype):
+    """The whole layout's sum, over rows gathered picks major, against the
+    two lines it was until PR 71 (the rows gathered tokens major, widened,
+    and a sum over the middle axis of picks): the same rows, the same
+    weights, float32 both, so equal to the rounding of a sum taken in another
+    order; every third weight is zero, as a pick's is whose expert is held
+    elsewhere and whose slot is any."""
+    from ompi_tpu.parallel.moe import _sum_of_picks
+
+    rng = np.random.default_rng(n + k)
+    D, rows = 256, n * k + 64
+    out = jnp.asarray(rng.normal(size=(rows, D)), dtype)
+    slot = jnp.asarray(rng.integers(0, rows, size=(n, k)), jnp.int32)
+    gate = rng.uniform(size=(n, k)).astype(np.float32)
+    gate.reshape(-1)[::3] = 0.0
+    got = jax.jit(_sum_of_picks)(out, slot.T.reshape(-1), gate)
+    was = out[slot.reshape(-1)].reshape(n, k, D).astype(jnp.float32)
+    want = jnp.sum(was * gate[:, :, None], axis=1)
+    assert got.dtype == jnp.float32 and got.shape == (n, D)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6,
+                               atol=1e-6 * k)
 
 
 @pytest.mark.parametrize("held,windows", [

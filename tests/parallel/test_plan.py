@@ -24,7 +24,7 @@ import numpy as np
 import pytest
 
 from benchmarks.lib import cells, program
-from ompi_tpu.models import kda, mla, plan
+from ompi_tpu.models import decode, kda, mla, plan
 from ompi_tpu.models import transformer as tfm
 from ompi_tpu.models.decode import make_decoder
 from ompi_tpu.parallel.mesh import make_mesh
@@ -277,8 +277,9 @@ def test_the_barrier_behind_a_steps_lightning_products_moves_no_arithmetic(
     """A cached step's q, k and v products of a lightning layer end behind
     an ``optimization_barrier`` (``lightning.mixer`` says why: the weights
     are then read where they lie in their stacks); the whole-sequence pass
-    has none.  The decoder's tokens and kept logits are bit for bit what the
-    same plan gives with the barrier taken out."""
+    has none.  (A routed layer's sum ends behind one of its own in every
+    pass, ``moe._sum_of_picks``.)  The decoder's tokens and kept logits are
+    bit for bit what the same plan gives with the barriers taken out."""
     _ref, _shape, cfg, mesh, _params = tiny()
     cfg = four_kinds(cfg)
     params = tfm.shard_params(cfg, mesh, tfm.init_params(cfg, seed=3))
@@ -290,12 +291,15 @@ def test_the_barrier_behind_a_steps_lightning_products_moves_no_arithmetic(
                 *decoder(params, prompts))
 
     text, tokens, kept = decoded()
-    assert (text.count("optimization_barrier")
+    routed = cfg.plan.count("moe")      # in the prefill and in the step
+    assert (text.count("optimization_barrier") - 2 * routed
             == 3 * cfg.plan.count("lightning") == 6)
     forward = str(jax.make_jaxpr(tfm.make_forward(cfg, mesh))(params, tokens))
-    assert "optimization_barrier" not in forward
+    assert forward.count("optimization_barrier") == routed == 3
     monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+    decode._prefill_program.cache_clear()   # its routed layers' are traced
     plain, plain_tokens, plain_kept = decoded()
+    decode._prefill_program.cache_clear()
     assert "optimization_barrier" not in plain
     assert np.array_equal(plain_tokens, tokens)
     assert np.array_equal(plain_kept, kept)
