@@ -409,32 +409,18 @@ def test_on_tpus_both_paths_take_the_kernels_and_read_the_same(monkeypatch):
     assert error(got_step, want_step) < PARITY
 
 
-@pytest.fixture(scope="module")
-def v5e():
-    from jax.experimental import topologies
-
-    try:
-        return list(topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2").devices)
-    except Exception as e:      # no libtpu here: nothing to lower for
-        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
-
-
 def _lowered(fn, *args) -> str:
     """``fn``'s StableHLO for the devices its arguments are placed on, the
-    kernels as the chip's compiler gets them (not the suite's interpreter),
-    with what moves with the checkout set aside: a kernel's serialized body
-    holds the files' paths and lines."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    with pltpu.force_tpu_interpret_mode(None):
-        text = jax.jit(fn).lower(*args).as_text()
-    return re.sub(r'backend_config = "[^"]*"', 'backend_config = ""', text)
+    kernels as the chip's compiler gets them (under ``for_the_chip``: not the
+    suite's interpreter), with what moves with the checkout set aside: a
+    kernel's serialized body holds the files' paths and lines."""
+    return re.sub(r'backend_config = "[^"]*"', 'backend_config = ""',
+                  jax.jit(fn).lower(*args).as_text())
 
 
 @pytest.mark.parametrize("slices", [3, 9])
 def test_a_prefill_lowers_one_or_two_kernel_calls_whatever_its_slices(
-        v5e, slices):
+        chip, for_the_chip, slices):
     """A decoder's prefill over the tiny plan with heads of 128 (the kernel's
     lanes) and slices of 128 queries, lowered for the described chip and not
     compiled: three slices (two within the first ``topk`` blocks, one past
@@ -450,7 +436,7 @@ def test_a_prefill_lowers_one_or_two_kernel_calls_whatever_its_slices(
         cfg.plan, block_select=block_select.BlockSelect(
             kernel=32, stride=16, block=64, topk=4, init_blocks=1, window=64,
             dense_len=128, q_slice=128)))
-    mesh = program.mesh(config, v5e[:1])
+    mesh = program.mesh(config, chip[:1])
     params = program.abstract_params(
         ref, config, program.param_shardings(config, cfg, mesh))
     prompt = jax.ShapeDtypeStruct((1, 128 * slices), np.int32,
@@ -461,7 +447,7 @@ def test_a_prefill_lowers_one_or_two_kernel_calls_whatever_its_slices(
     assert text.count("stablehlo.while") >= 2   # the two scans
 
 
-def test_the_indexed_prefill_lowers_to_what_it_did(v5e):
+def test_the_indexed_prefill_lowers_to_what_it_did(chip, for_the_chip):
     """``sparse_index.attend`` (cell 6's prefill) passes ``masked_attention``
     no length and unrolls its slices as it did: its text for the described
     chip is the one it lowered to before the kernel took a length (PR 66; a
@@ -472,7 +458,7 @@ def test_the_indexed_prefill_lowers_to_what_it_did(v5e):
 
     cfg = tfm.TransformerConfig(index=sparse_index.SparseIndex(
         n_heads=2, head_dim=32, topk=64, q_slice=128))
-    on = SingleDeviceSharding(v5e[0])
+    on = SingleDeviceSharding(chip[0])
     lp = {name: jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=on)
           for name, dims in (("wiq", (64, 64)), ("wik", (64, 32)),
                              ("wiw", (64, 2)), ("ikn", (32,)),

@@ -8,7 +8,6 @@ cache: the last tests read the compiler's output, they time nothing.
 
 import dataclasses
 import math
-import os
 import re
 
 import numpy as np
@@ -18,6 +17,7 @@ from ompi_tpu.models import transformer as tfm
 from ompi_tpu.models.decode import make_decoder
 from ompi_tpu.parallel.mesh import make_mesh
 from ompi_tpu.parallel.moe import EXPERT_LEAVES
+from tests.parallel.compiled import _cell
 
 CFG = tfm.TransformerConfig(
     vocab=97, d_model=64, n_heads=4, n_layers=2, d_ff=128, seq=64,
@@ -566,45 +566,15 @@ def _check_cache_stays(compiled, floor: int, position: int) -> None:
     assert len(writes) == 2, writes         # K and V, one position each
 
 
-@pytest.fixture
-def chips():
-    from jax.experimental import topologies
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
-    try:
-        return topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2").devices
-    except Exception as e:      # no libtpu here: nothing to compile with
-        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
-
-
-@pytest.fixture
-def no_compile_cache():
-    """As ``tests/benchmarks/test_fits.py``: a program compiled for a
-    described chip cannot be read back from the persistent cache."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache
-
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
-
 def test_compiled_step_for_the_chip_writes_the_cache_and_moves_nothing(
-        chips, no_compile_cache):
+        chip, for_the_chip):
     """The benchmark's decode cell at its real sizes, compiled by the v5e's
     own compiler for a chip that is described and not attached.  This
     checks a compile, not a time: of everything the generation loop runs,
     only the two writes of one position (K and V) may produce an array of
     a layer's cache or more, and no copy of that size may exist: so
     attention reads the carried stack itself, through a fused slice."""
-    from benchmarks.lib import cells
-
-    cell = cells.resolve("pythia-1.4b-widths.decode-1k-128")
-    job = cell.runner.build(cell.config, cell.traffic, chips[:cell.chips])
+    _cfg, job = _cell("pythia-1.4b-widths.decode-1k-128", chip)
     fn, args = job.programs()["decode_full"]
     position = job.batch * job.cfg.d_model          # B·Hl·hd
     _check_cache_stays(fn.lower(*args).compile(),
@@ -612,7 +582,7 @@ def test_compiled_step_for_the_chip_writes_the_cache_and_moves_nothing(
 
 
 def test_compiled_hybrid_step_for_the_chip_keeps_its_state_in_place(
-        chips, no_compile_cache):
+        chip, for_the_chip):
     """The state-space cell at its real sizes, for the described v5e: the
     mixer's stacked state (6 layers x 192 sequences x 32 x 128 x 256) is loop
     carry like K/V.  Of everything the generation loop runs, only the one
@@ -621,11 +591,9 @@ def test_compiled_hybrid_step_for_the_chip_keeps_its_state_in_place(
     through a fused slice and writes it back in place by layer index (as the
     ``xs`` and ``ys`` of a scan it would be rebuilt and copied a step).  The
     CPU's compiler does copy it, twice a layer, so this is asked here only."""
-    from benchmarks.lib import cells
     from ompi_tpu.models import ssm
 
-    cell = cells.resolve("falcon-h1-34b.decode-128-64-b192")
-    job = cell.runner.build(cell.config, cell.traffic, chips[:cell.chips])
+    _cfg, job = _cell("falcon-h1-34b.decode-128-64-b192", chip)
     fn, args = job.programs()["decode_full"]
     layer = math.prod(ssm.state_shapes(job.cfg, job.batch)[1])
     writes, moves = cache_sized_operations(

@@ -6,20 +6,14 @@ what ``weight_block`` admits has to fit what the compiler gives a kernel by
 default.  Nothing runs and nothing here is a time.
 """
 
-import os
+import jax
+import jax.numpy as jnp
+import pytest
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import pytest  # noqa: E402
-
-from ompi_tpu.ops.grouped_matmul import (_VMEM_BUDGET_BYTES,  # noqa: E402
+from ompi_tpu.ops.grouped_matmul import (_VMEM_BUDGET_BYTES,
                                          _working_set_bytes, grouped_matmul,
                                          weight_block)
-# the described chip, and the compile cache and interpret mode off around it
-from tests.parallel.test_selected_attention_compiled import (  # noqa: E402,F401
-    chip, for_the_chip)
+from tests.parallel.compiled import _on
 
 
 def _compiles(tm, K, N, chip):
@@ -27,18 +21,11 @@ def _compiles(tm, K, N, chip):
     compiles for the described chip with no limit named."""
     tk, tn = weight_block(tm, K, N, 2)
     assert _working_set_bytes(tm, tk, tn, 2) <= _VMEM_BUDGET_BYTES
-    from jax.sharding import SingleDeviceSharding
-
     n_tiles, G = 64, 48
-    on_chip = SingleDeviceSharding(chip[0])
-
-    def shape(dims, dtype):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=on_chip)
-
     text = jax.jit(grouped_matmul).lower(
-        shape((n_tiles * tm, K), jnp.bfloat16),
-        shape((G, K, N), jnp.bfloat16), shape((n_tiles,), jnp.int32),
-        shape((1,), jnp.int32)).compile().as_text()
+        _on(chip, (n_tiles * tm, K), jnp.bfloat16),
+        _on(chip, (G, K, N), jnp.bfloat16), _on(chip, (n_tiles,), jnp.int32),
+        _on(chip, (1,), jnp.int32)).compile().as_text()
     assert "grouped_matmul" in text and "tpu_custom_call" in text
 
 

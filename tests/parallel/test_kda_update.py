@@ -22,6 +22,7 @@ from benchmarks.lib import cells, program
 from ompi_tpu.models import kda
 from ompi_tpu.ops import kda_update as kernel_module
 from ompi_tpu.ops.kda_update import block, kda_update
+from tests.parallel.compiled import _pallas_calls
 from tests.parallel.test_plan import PARITY, error, tiny
 
 CELL = "kimi-linear-48b-a3b.decode-512-128-b384"
@@ -95,14 +96,6 @@ def test_several_blocks_of_heads_a_sequence(monkeypatch):
         kernel_module._call.clear_cache()
     _close(got_o, want_o)
     _close(got_s, want_s)
-
-
-def _pallas_calls(jaxpr):
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _pallas_calls(sub)
 
 
 def test_the_state_is_written_into_the_buffer_it_was_read_from():

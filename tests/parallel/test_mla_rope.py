@@ -11,22 +11,19 @@ Agreement only: nothing here is a time.
 
 import copy
 import dataclasses
-import os
 import re
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-import pytest  # noqa: E402
-
-from benchmarks.lib import cells, program  # noqa: E402
-from ompi_tpu.models import kda, mla, plan  # noqa: E402
-from ompi_tpu.models import transformer as tfm  # noqa: E402
-from ompi_tpu.models.decode import make_decoder  # noqa: E402
-from ompi_tpu.ops import latent_attention  # noqa: E402
-from tests.parallel.test_kda_update import _pallas_calls  # noqa: E402
+from benchmarks.lib import cells, program
+from ompi_tpu.models import kda, mla, plan
+from ompi_tpu.models import transformer as tfm
+from ompi_tpu.models.decode import make_decoder
+from ompi_tpu.ops import latent_attention
+from tests.parallel.compiled import _cell, _pallas_calls
 
 CELL = "kimi-vl-a3b.decode-16k-256-b32"
 NOPE_CELL = "kimi-linear-48b-a3b.decode-512-128-b384"
@@ -257,18 +254,7 @@ def test_the_tile_and_the_stated_vmem_follow_the_length():
     assert blocks[2] == (1, 896, 128) and limit < 16 << 20
 
 
-@pytest.fixture(scope="module")
-def v5e():
-    from jax.experimental import topologies
-
-    try:
-        return list(topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2").devices)
-    except Exception as e:      # no libtpu here: nothing to lower for
-        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
-
-
-def test_cell_10s_prefill_holds_the_kernel_call_it_had(v5e):
+def test_cell_10s_prefill_holds_the_kernel_call_it_had(chip, for_the_chip):
     """The cell that ran the kernel before any other did: its prefill at the
     real sizes, lowered for the described chip, holds one kernel function
     for its five call sites, over 16,384 padded positions, the keys, values
@@ -277,13 +263,9 @@ def test_cell_10s_prefill_holds_the_kernel_call_it_had(v5e):
     text is not the parent's: since PR 61 the queries are read as they lie
     and not transposed to the heads, which took 0.39 s off this cell's
     prefill too (PERF.md section 6, PR 61)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cell = cells.resolve(CELL)
-    job = cell.runner.build(cell.config, cell.traffic, v5e[:cell.chips])
+    _cfg, job = _cell(CELL, chip)
     fn, args = job.programs()["decode_first"]
-    with pltpu.force_tpu_interpret_mode(None):   # for the chip, not the suite
-        text = fn.lower(*args).as_text()
+    text = fn.lower(*args).as_text()
     (call,) = [line for line in text.splitlines()
                if 'kernel_name = "latent_attention"' in line]
     kinds = re.search(r" : \((.*)\) -> (tensor<\S+>)$", call)

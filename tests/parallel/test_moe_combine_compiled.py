@@ -18,14 +18,13 @@ is 25 s of compile, of which its two sorts of ``n k`` keys are most.
 """
 
 import math
-import os
 import re
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
+import jax
+import jax.numpy as jnp
+import pytest
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import pytest  # noqa: E402
+from tests.parallel.compiled import _on
 
 # an instruction of the entry computation: what it writes is an array in HBM
 WRITES = re.compile(r"^\s*(?:ROOT )?%\S+ = \(?(\w+)\[([\d,]*)\]")
@@ -42,35 +41,6 @@ CASES = [pytest.param((8, 512), CELL_13, id="cell13-pass"),
          pytest.param((256, 1), CELL_12, id="cell12-step")]
 
 
-@pytest.fixture(scope="module")
-def chip():
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-
-    try:
-        return SingleDeviceSharding(topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2").devices[0])
-    except Exception as e:      # no libtpu here: nothing to compile with
-        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
-
-
-@pytest.fixture
-def for_the_chip():
-    """As ``test_selected_attention_compiled.py``: a program compiled for a
-    described chip cannot be read back from the persistent cache, and the
-    suite's interpret mode would compile host callbacks, not the kernel."""
-    from jax.experimental.compilation_cache import compilation_cache
-    from jax.experimental.pallas import tpu as pltpu
-
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    with pltpu.force_tpu_interpret_mode(None):
-        yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
-
 @pytest.mark.parametrize("tokens,cell", CASES)
 def test_combine_writes_no_float32_copy_of_the_gathered_rows(
         tokens, cell, chip, for_the_chip):
@@ -85,7 +55,7 @@ def test_combine_writes_no_float32_copy_of_the_gathered_rows(
     assert _window_rows(n * k, tm, held, width) == n * k    # the whole layout
 
     def shape(*dims):
-        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=chip)
+        return _on(chip, dims, jnp.bfloat16)
 
     lp = {"wg": shape(D, width), "w1": shape(held, D, F),
           "w2": shape(held, F, D), "sw1": shape(D, cell["shared"]),

@@ -13,76 +13,33 @@ mesh of CPU devices is handed none either, or its backend would refuse the
 compile ("No such compile option").
 """
 
-import contextlib
-import os
 import re
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
+import jax
+import numpy as np
+import pytest
 
-import jax  # noqa: E402
-import numpy as np  # noqa: E402
-import pytest  # noqa: E402
-
-from benchmarks.lib import cells  # noqa: E402
-from ompi_tpu.models import transformer as tfm  # noqa: E402
-from ompi_tpu.parallel.mesh import make_mesh  # noqa: E402
+from ompi_tpu.models import transformer as tfm
+from ompi_tpu.parallel.mesh import make_mesh
+from tests.parallel.compiled import _cell
 
 FOUR_CHIPS = "pythia-6.9b-widths.train-2k-dp2tp2"
 ONE_CHIP = "pythia-1.4b-widths.train-2k"
+# every test of the file: the cache and the suite's interpret mode off
+pytestmark = pytest.mark.usefixtures("for_the_chip")
+_FOUR_CHIP_STEP = {}
 
 
-@pytest.fixture(scope="module")
-def chips():
-    from jax.experimental import topologies
-
-    try:
-        return topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2").devices
-    except Exception as e:      # no libtpu here: nothing to compile with
-        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
-
-
-@contextlib.contextmanager
-def _for_the_chip():
-    """A program compiled for a described chip is written to the persistent
-    cache but cannot be read back without one; and the suite's session
-    fixture puts pallas kernels into TPU interpret mode, for the CPU, under
-    which a step with a kernel in it would lower host callbacks and not the
-    kernel."""
-    from jax.experimental.compilation_cache import compilation_cache
-    from jax.experimental.pallas import tpu as pltpu
-
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        with pltpu.force_tpu_interpret_mode(None):
-            yield
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
-
-
-@pytest.fixture(autouse=True)
-def _compile_for_the_chip():
-    with _for_the_chip():
-        yield
-
-
-@pytest.fixture(scope="module")
-def four_chip_step(chips):
+@pytest.fixture
+def four_chip_step(chip, for_the_chip):
     """name -> lines of every computation of the four-chip cell's real
     ``train_step`` as the v5e's compiler scheduled it: one compile for the
     tests that read it."""
-    with _for_the_chip():
-        fn, args = _job(FOUR_CHIPS, chips).programs()["train_step"]
-        return _computations(fn.lower(*args).compile().as_text())
-
-
-def _job(workload, devices):
-    cell = cells.resolve(workload)
-    return cell.runner.build(cell.config, cell.traffic,
-                             devices[:cell.chips])
+    if not _FOUR_CHIP_STEP:
+        fn, args = _cell(FOUR_CHIPS, chip)[1].programs()["train_step"]
+        _FOUR_CHIP_STEP.update(
+            _computations(fn.lower(*args).compile().as_text()))
+    return _FOUR_CHIP_STEP
 
 
 def _computations(text):
@@ -170,8 +127,8 @@ def _step_before(cfg, mesh, lr):
     return train_step
 
 
-def test_one_chip_step_is_handed_no_option_and_lowers_as_before(chips):
-    job = _job(ONE_CHIP, chips)
+def test_one_chip_step_is_handed_no_option_and_lowers_as_before(chip):
+    _cfg, job = _cell(ONE_CHIP, chip)
     assert tfm._compiler_options(job.mesh) is None
     fn, args = job.programs()["train_step"]
     before = _step_before(job.cfg, job.mesh, job.traffic["lr"])
@@ -187,8 +144,8 @@ def test_one_chip_step_is_handed_no_option_and_lowers_as_before(chips):
     assert text(fn) == text(before)
 
 
-def test_options_follow_the_mesh(chips):
-    four = _job(FOUR_CHIPS, chips).mesh
+def test_options_follow_the_mesh(chip):
+    four = _cell(FOUR_CHIPS, chip)[1].mesh
     assert tfm._compiler_options(four) == tfm._OVERLAP_OPTIONS
     cpus = make_mesh({"dp": 2, "sp": 1, "tp": 2}, devices=jax.devices()[:4])
     assert tfm._compiler_options(cpus) is None

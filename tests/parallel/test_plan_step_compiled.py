@@ -10,20 +10,17 @@ weights with ``D`` minor, and each step copied every layer's matrix out of
 the re-laid stack first (three ``slice`` fusions of ``bf16[3,4096,4096]``,
 603 MB read and written a step, 10% of it: PR 53).  Cell 10's cached step
 reads each latent layer's cache by one call of ``latent_decode`` on the carry
-as it lies (the parent read it twice, in two fusions: PR 57).
+as it lies (the parent read it twice, in two fusions: PR 57).  Cell 7's
+passes a delta-rule layer's state through ``kda_update`` and through nothing
+else as large (a copy of a layer's state is 805 MB a step and 0.75 GiB of
+the chip), in the buffer it lies in.
 """
 
 import math
-import os
 import re
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
-
-from tests.parallel.test_kda_update_compiled import (  # noqa: E402
-    _peak, _program)
-# the described chip, and the compile cache and interpret mode off around it
-from tests.parallel.test_selected_attention_compiled import (  # noqa: E402,F401
-    INSTRUCTION, MOVES, chip, for_the_chip)
+from tests.parallel.compiled import (INSTRUCTION, MOVES, _cell, _peak,
+                                     _program)
 
 CELL_9 = "minicpm-sala.decode-16k-512-b24"
 CELL_7 = "kimi-linear-48b-a3b.decode-512-128-b384"
@@ -32,6 +29,9 @@ CELL_10 = "kimi-vl-a3b.decode-16k-256-b32"
 # stacks, 288 MiB standing and as much again of layers' copies, were in both
 PARENT_TEMP_BYTES = 501_877_248
 PARENT_PEAK_BYTES = 5_197_119_488
+# cell 7's (``traffic/decode-512-128-b384.json``'s ``batch_why``: arguments +
+# results + temporaries - written in place)
+CELL_7_PARENT_PEAK_GIB = 13.15
 # an instruction with its result's types whole, an array or a tuple of them
 # (``INSTRUCTION`` reads single arrays: what is inside a fusion)
 RESULTS = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\((.*)$", re.M)
@@ -41,16 +41,13 @@ _COMPILED = {}
 
 
 def _generating(name, chip):
-    """(the program's configuration, the cell's generating program compiled
-    for ``chip``), compiled once a cell; call under ``for_the_chip``."""
-    from benchmarks.lib import cells, program
-
+    """(the program's configuration, the cell's job, its generating program
+    compiled for ``chip``), compiled once a cell; call under
+    ``for_the_chip``."""
     if name not in _COMPILED:
-        cell = cells.resolve(name)
-        job = cell.runner.build(cell.config, cell.traffic, chip)
+        cfg, job = _cell(name, chip)
         fn, args = _program(job, chip, 1)
-        _COMPILED[name] = (program.program_config(cell.config),
-                           fn.lower(*args).compile())
+        _COMPILED[name] = (cfg, job, fn.lower(*args).compile())
     return _COMPILED[name]
 
 
@@ -125,7 +122,7 @@ def _weight_moves(cfg, text) -> list:
 
 
 def test_cell_9_steps_move_no_weight(chip, for_the_chip):
-    cfg, compiled = _generating(CELL_9, chip)
+    cfg, _job, compiled = _generating(CELL_9, chip)
     lt = cfg.plan.lightning
     assert (cfg.d_model, lt.width) == (4096, 4096)
     assert (4096, 4096) in _weights(cfg) and (3, 4096, 4096) in _weights(cfg)
@@ -136,7 +133,7 @@ def test_cell_9_steps_move_no_weight(chip, for_the_chip):
 
 
 def test_cell_9s_generating_program_holds_no_relaid_stack(chip, for_the_chip):
-    cfg, compiled = _generating(CELL_9, chip)
+    cfg, _job, compiled = _generating(CELL_9, chip)
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes <= PARENT_TEMP_BYTES
     assert _peak(memory) <= PARENT_PEAK_BYTES, _peak(memory) / 2 ** 30
@@ -151,15 +148,43 @@ def test_cell_9s_generating_program_holds_no_relaid_stack(chip, for_the_chip):
 
 
 def test_cell_7_steps_move_no_weight(chip, for_the_chip):
-    cfg, compiled = _generating(CELL_7, chip)
+    cfg, _job, compiled = _generating(CELL_7, chip)
     assert (128, 1024, 2304) in _weights(cfg)       # a layer's held experts
     moved = _weight_moves(cfg, compiled.as_text())
     assert not moved, moved
 
 
+def test_cell_7_steps_pass_each_state_through_the_kernel_alone(
+        chip, for_the_chip):
+    cfg, job, compiled = _generating(CELL_7, chip)
+    text = compiled.as_text()
+
+    kd = cfg.plan.kda
+    state = (job.batch, kd.n_heads, kd.head_dim, kd.head_dim)
+    shapes = {",".join(map(str, dims)) for dims in (state, (1, *state))}
+    # a name for a buffer, not a pass over it
+    names = ("parameter", "get-tuple-element", "bitcast", "tuple", "while")
+    passes = [(name, dims, op)
+              for name, dims, op, _rest in INSTRUCTION.findall(text)
+              if dims in shapes and op not in names]
+    assert not passes, passes
+    # the kernel's result is a tuple (o, state), which the pattern above,
+    # of single arrays, does not read: the calls are counted by name
+    kernels = re.findall(
+        rf"= \(f32\[[\d,]+\]\S* f32\[{','.join(map(str, state))}\]\S*\) "
+        rf"custom-call\([^\n]*kda_update", text)
+    assert len(kernels) == cfg.plan.count("kda") == 4
+
+    memory = compiled.memory_analysis()
+    # every state is written where it lies: 4 x 805 MB and the convolutions'
+    assert memory.alias_size_in_bytes > 4 * 4 * math.prod(state)
+    peak, most = _peak(memory), CELL_7_PARENT_PEAK_GIB * 2 ** 30 + (64 << 20)
+    assert peak < most, peak / 2 ** 30
+
+
 def test_cell_10_steps_read_each_latent_cache_by_one_kernel_call(
         chip, for_the_chip):
-    cfg, compiled = _generating(CELL_10, chip)
+    cfg, _job, compiled = _generating(CELL_10, chip)
     text = compiled.as_text()
     computations, bodies = _step_bodies(text)
     ml = cfg.plan.mla

@@ -19,7 +19,7 @@ from benchmarks import controls_brumby
 from ompi_tpu.models import kda, retention
 from ompi_tpu.ops import retention_prefill as kernel_module
 from ompi_tpu.ops.retention_prefill import ROWS, retention_prefill
-from tests.parallel.test_kda_update import _pallas_calls
+from tests.parallel.compiled import _pallas_calls
 from tests.parallel.test_retention import EPS, PARITY, error, tiny
 
 d = 128

@@ -10,22 +10,15 @@ not above the parent's.  And the trainer's program of the same configuration,
 traced for the same chip, calls no such kernel.
 """
 
-import os
 import re
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
+import jax
+import jax.numpy as jnp
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-from ompi_tpu.models import retention  # noqa: E402
-from ompi_tpu.ops import retention_prefill as kernel_module  # noqa: E402
-# the described chip, and the compile cache and interpret mode off around it
-from tests.parallel.test_kda_update import _pallas_calls  # noqa: E402
-from tests.parallel.test_kda_update_compiled import (  # noqa: E402
-    _on, _peak, _program)
-from tests.parallel.test_selected_attention_compiled import (  # noqa: E402,F401
-    INSTRUCTION, chip, for_the_chip)
+from ompi_tpu.models import retention
+from ompi_tpu.ops import retention_prefill as kernel_module
+from tests.parallel.compiled import (INSTRUCTION, _cell, _on, _pallas_calls,
+                                     _peak, _program)
 
 CELL = "brumby-14b-base.decode-2k-128-b48"
 # the parent's prefill program, the larger of the cell's two
@@ -61,12 +54,8 @@ def test_the_kernel_compiles_at_cell_8s_tile_with_no_limit_named(
 
 def test_cell_8s_prefill_reads_a_prompt_directly_and_expands_no_query(
         chip, for_the_chip):
-    from benchmarks.lib import cells, program
-
-    cell = cells.resolve(CELL)
-    cfg = program.program_config(cell.config)
+    cfg, job = _cell(CELL, chip)
     assert (cfg.n_heads, cfg.kv_heads, cfg.head_dim) == (H, G, d)
-    job = cell.runner.build(cell.config, cell.traffic, chip)
     fn, args = _program(job, chip, 0)
     compiled = fn.lower(*args).compile()
     text = compiled.as_text()
@@ -105,7 +94,7 @@ def test_the_trainers_program_of_cell_8_keeps_the_chunked_form(
 
     cell = cells.resolve(CELL)
     cfg = program.program_config(cell.config)
-    mesh = program.mesh(cell.config, chip)
+    mesh = program.mesh(cell.config, chip[:1])
     params = program.abstract_params(
         program.reference(cell.config), cell.config,
         program.param_shardings(cell.config, cfg, mesh))

@@ -22,7 +22,7 @@ from benchmarks import controls_brumby
 from ompi_tpu.models import kda, retention
 from ompi_tpu.ops import retention_update as kernel_module
 from ompi_tpu.ops.retention_update import block, retention_update
-from tests.parallel.test_kda_update import _pallas_calls
+from tests.parallel.compiled import _pallas_calls
 from tests.parallel.test_retention import EPS, PARITY, drawn, error, tiny
 
 d = 128

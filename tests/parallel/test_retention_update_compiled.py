@@ -10,21 +10,14 @@ layers, once.
 """
 
 import math
-import os
 import re
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
+import jax
+import jax.numpy as jnp
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-from ompi_tpu.ops import retention_update as kernel_module  # noqa: E402
-# the described chip, and the compile cache and interpret mode off around it
-from tests.parallel.test_kda_update import _pallas_calls  # noqa: E402
-from tests.parallel.test_kda_update_compiled import (  # noqa: E402
-    _on, _program)
-from tests.parallel.test_selected_attention_compiled import (  # noqa: E402,F401
-    INSTRUCTION, chip, for_the_chip)
+from ompi_tpu.ops import retention_update as kernel_module
+from tests.parallel.compiled import (INSTRUCTION, _cell, _on, _pallas_calls,
+                                     _program)
 
 CELL = "brumby-14b-base.decode-2k-128-b48"
 # the parent's generating program (``PERF.md`` section 4: arguments + results
@@ -56,11 +49,7 @@ def test_the_kernel_compiles_at_cell_8s_block_with_no_limit_named(
 
 def test_cell_8_steps_pass_a_layers_state_through_the_kernel_alone(
         chip, for_the_chip):
-    from benchmarks.lib import cells, program
-
-    cell = cells.resolve(CELL)
-    cfg = program.program_config(cell.config)
-    job = cell.runner.build(cell.config, cell.traffic, chip)
+    cfg, job = _cell(CELL, chip)
     fn, args = _program(job, chip, 1)
     compiled = fn.lower(*args).compile()
     text = compiled.as_text()

@@ -17,9 +17,6 @@ came).  Agreement and control flow only: nothing here is a time.
 import copy
 import dataclasses
 import hashlib
-import os
-
-os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
 
 import jax
 import jax.numpy as jnp
@@ -31,11 +28,7 @@ from ompi_tpu.models import ssm
 from ompi_tpu.models import transformer as tfm
 from ompi_tpu.models.decode import _prefill_group, make_decoder
 from ompi_tpu.parallel.mesh import make_mesh
-from tests.parallel.test_kda_update import _pallas_calls
-from tests.parallel.test_kda_update_compiled import _program
-# the described chip, and the compile cache and interpret mode off around it
-from tests.parallel.test_selected_attention_compiled import (  # noqa: F401
-    chip, for_the_chip)
+from tests.parallel.compiled import _cell, _pallas_calls, _program
 
 CELL = "falcon-h1-34b.decode-128-64-b192"
 PARITY = 1e-4       # of a deviation of the logits; float32 on both sides
@@ -372,8 +365,7 @@ def test_cell_12s_prefill_calls_the_kernel_six_times_and_sums_no_decay_outside(
     chip: the prefill scans each of its six Mamba-2 layers through the
     kernel and ``chunked_scan``'s running sum is nowhere in it; the
     generating program has no whole sequence and no such call."""
-    cell = cells.resolve(CELL_12)
-    job = cell.runner.build(cell.config, cell.traffic, chip)
+    _cfg, job = _cell(CELL_12, chip)
     scans = []
     for which in (0, 1):
         fn, args = _program(job, chip, which)
@@ -387,8 +379,7 @@ def test_cell_12s_prefill_calls_the_kernel_six_times_and_sums_no_decay_outside(
 
 
 def test_cell_5s_programs_are_what_they_were(chip, for_the_chip):
-    cell = cells.resolve(CELL)
-    job = cell.runner.build(cell.config, cell.traffic, chip)
+    _cfg, job = _cell(CELL, chip)
     programs = job.programs()
     assert set(programs) == set(CELL_5_PROGRAMS)
     for name, (fn, args) in programs.items():

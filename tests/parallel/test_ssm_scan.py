@@ -22,7 +22,7 @@ from benchmarks.lib import cells, program
 from ompi_tpu.models import kda, ssm
 from ompi_tpu.ops import ssm_scan as kernel_module
 from ompi_tpu.ops.ssm_scan import CHUNK, ssm_scan
-from tests.parallel.test_kda_update import _pallas_calls
+from tests.parallel.compiled import _pallas_calls
 
 B, H, P, G, N = 2, 4, 64, 2, 128
 T = 3 * CHUNK

@@ -10,21 +10,13 @@ prompts of 512), which the kernel takes 16 heads a grid cell.  (Which of the
 two cells' programs call it is read in ``test_ssm.py``.)
 """
 
-import os
+import jax
+import jax.numpy as jnp
+import pytest
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import pytest  # noqa: E402
-
-from ompi_tpu.models import ssm  # noqa: E402
-from ompi_tpu.ops import ssm_scan as kernel_module  # noqa: E402
-# the described chip, and the compile cache and interpret mode off around it
-from tests.parallel.test_kda_update import _pallas_calls  # noqa: E402
-from tests.parallel.test_kda_update_compiled import _on  # noqa: E402
-from tests.parallel.test_selected_attention_compiled import (  # noqa: E402,F401
-    chip, for_the_chip)
+from ompi_tpu.models import ssm
+from ompi_tpu.ops import ssm_scan as kernel_module
+from tests.parallel.compiled import _on, _pallas_calls
 
 CELL = "nemotron-3-nano-30b-a3b.decode-1k-128-b256"
 B, T = 8, 1024          # a prefill pass: ``prefill_tokens`` 8192

@@ -12,16 +12,10 @@ another.
 """
 
 import math
-import os
 import re
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")    # or libtpu logs to /tmp
-
-from benchmarks import controls_granite_h  # noqa: E402
-# the described chip, and the compile cache and interpret mode off around it
-from tests.parallel.test_kda_update_compiled import _peak, _program  # noqa: E402
-from tests.parallel.test_selected_attention_compiled import (  # noqa: E402,F401
-    INSTRUCTION, chip, for_the_chip)
+from benchmarks import controls_granite_h
+from tests.parallel.compiled import INSTRUCTION, _cell, _peak, _program
 
 CELL = "granite-4.0-h-small.decode-512-128-b160"
 # the parent's generating program, compiled here (arguments + results +
@@ -41,11 +35,7 @@ def _steps(job, chip):
 
 def test_cell_13_steps_pass_each_state_through_the_kernel_in_place(
         chip, for_the_chip):
-    from benchmarks.lib import cells, program
-
-    cell = cells.resolve(CELL)
-    cfg = program.program_config(cell.config)
-    job = cell.runner.build(cell.config, cell.traffic, chip)
+    cfg, job = _cell(CELL, chip)
     sound = _steps(job, chip)
     compiled = sound.compile()
     text, memory = compiled.as_text(), compiled.memory_analysis()

@@ -40,6 +40,16 @@ def _tpurun(*args, timeout=120):
         cwd=REPO)
 
 
+def _hold(release, seconds, said):
+    """A job's program: say ``said``, then run until the file ``release``
+    exists, ``seconds`` at most.  The test that watches the job run touches
+    the file when it has seen what it waits for."""
+    return (f"import os, time\nprint({said!r}, flush=True)\n"
+            f"end = time.monotonic() + {seconds}\n"
+            f"while not os.path.exists({str(release)!r}) "
+            "and time.monotonic() < end:\n    time.sleep(0.05)\n")
+
+
 @contextlib.contextmanager
 def _standing_vm(tmp_path, *extra_args):
     """Start a DVM, wait for its URI, always stop it."""
@@ -129,15 +139,14 @@ def test_dvm_ps_shows_daemons_and_history(dvm):
     assert table["history"][-1]["np"] == 2
 
 
-def test_dvm_ps_live_job(dvm):
+def test_dvm_ps_live_job(dvm, tmp_path):
     """orte-ps semantics: querying DURING a run shows running procs."""
-    # generous sleep + window: on a loaded 1-core host each --dvm-ps
+    # a generous hold + window: on a loaded 1-core host each --dvm-ps
     # poll is a full interpreter start (seconds); a 6s job could finish
     # between two polls and the test would flake
+    seen = tmp_path / "seen"
     slow = _tpurun_bg("--dvm-submit", "-np", "2", "--dvm-uri", dvm, "--",
-                      sys.executable, "-c",
-                      "import time; print('start', flush=True); "
-                      "time.sleep(20)")
+                      sys.executable, "-c", _hold(seen, 20, "start"))
     try:
         deadline = time.monotonic() + 60
         live = None
@@ -165,6 +174,7 @@ def test_dvm_ps_live_job(dvm):
         assert with_usage, live
         assert all(p["rss_mb"] > 0 and p["pid"] > 0 for p in with_usage)
     finally:
+        seen.touch()
         slow.wait(timeout=60)
 
 

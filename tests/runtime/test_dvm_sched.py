@@ -21,7 +21,8 @@ import pytest
 
 from ompi_tpu.runtime.dvm import gang_place, plan_remediation
 from ompi_tpu.runtime.job import Node
-from tests.runtime.test_dvm import _standing_vm, _tpurun, _tpurun_bg
+from tests.runtime.test_dvm import (_hold, _standing_vm, _tpurun,
+                                    _tpurun_bg)
 
 
 # ---------------------------------------------------------------------------
@@ -128,10 +129,9 @@ def test_admission_queue_full_then_fifo_drain(tmp_path):
     runs (FIFO) once the pool frees up."""
     with _standing_vm(tmp_path, "--mca", "dvm_queue_max", "1",
                       "--mca", "dvm_max_concurrent", "1") as uri:
-        hold = ("import time; print('HOLD up', flush=True); "
-                "time.sleep(6)")
-        a = _tpurun_bg("--dvm-submit", "-np", "4", "--dvm-uri", uri,
-                       "--", sys.executable, "-c", hold)
+        bounced = tmp_path / "bounced"
+        a = _tpurun_bg("--dvm-submit", "-np", "4", "--dvm-uri", uri, "--",
+                       sys.executable, "-c", _hold(bounced, 6, "HOLD up"))
         # wait until A is RUNNING (out of the pending queue) so B takes
         # the single queue slot
         deadline = time.monotonic() + 60
@@ -164,6 +164,7 @@ def test_admission_queue_full_then_fifo_drain(tmp_path):
         verdict = json.loads(c.stdout.strip().splitlines()[-1])
         assert verdict["verdict"] == "rejected"
         assert "queue full" in verdict["reason"]
+        bounced.touch()
         # FIFO drain: A then B both finish clean
         out_a, err_a = a.communicate(timeout=120)
         assert a.returncode == 0, (out_a[-1000:], err_a[-1000:])
@@ -181,10 +182,11 @@ def test_two_tenants_no_output_or_exit_bleed(tmp_path):
     job's IOF, and a tenant's nonzero exit never leaks into its
     co-tenant's rc."""
     with _standing_vm(tmp_path) as uri:
+        b_ended = tmp_path / "b_ended"
         a = _tpurun_bg("--dvm-submit", "-np", "2", "--dvm-uri", uri,
                        "--", sys.executable, "-c",
-                       "import time; print('TENANT_A', flush=True); "
-                       "time.sleep(6); print('A_DONE', flush=True)")
+                       _hold(b_ended, 6, "TENANT_A")
+                       + "print('A_DONE', flush=True)")
         time.sleep(1.0)
         b = _tpurun("--dvm-submit", "-np", "2", "--dvm-uri", uri, "--",
                     sys.executable, "-c",
@@ -193,6 +195,7 @@ def test_two_tenants_no_output_or_exit_bleed(tmp_path):
         assert b.returncode == 3, (b.returncode, b.stderr)
         assert "TENANT_B" in b.stdout
         assert "TENANT_A" not in b.stdout        # jobid-routed IOF
+        b_ended.touch()
         out_a, err_a = a.communicate(timeout=120)
         assert a.returncode == 0, (out_a[-1000:], err_a[-1000:])
         assert "TENANT_A" in out_a and "A_DONE" in out_a
