@@ -29,17 +29,19 @@ whatever the chunk, a key read directly ``4 d``: up to :data:`CROSSOVER`
 positions a decoder's prefill on TPUs (:func:`direct`: forward only, static
 facts alone) sums the first pair of equations over the whole prompt in one
 pallas kernel (``ops/retention_prefill.py``) and forms ``S`` and ``z`` once,
-after the last position (:func:`end_state`); a trainer, the CPU, a longer
-prompt and a length that does not tile keep the scan over chunks.
+after the last position: :func:`end_state`'s sums, there in a second kernel
+(``ops/retention_end_state.py``: ``phi(k)`` never leaves the chip); a
+trainer, the CPU, a longer prompt and a length that does not tile keep the
+scan over chunks.
 :func:`update` is one position against the carried state
 (:func:`read`, once for the query heads of its K/V head, then
 :func:`write`).  The gate, the cumulative decays, ``S``, ``z`` and the
 quotient are float32 whatever the compute type;
 a cached step's products against the state are float32 too, the chunked
 form's run in the compute type and add up in float32, as the other mixers'.
-Everything here is ``jax.numpy`` and ``lax`` but that prefill and a cached
-step's pass over the matrix state, which on TPUs is one pallas kernel over
-the layer where it lies in the stack (``ops/retention_update.py``;
+Everything here is ``jax.numpy`` and ``lax`` but that prefill's two kernels
+and a cached step's pass over the matrix state, which on TPUs is one pallas
+kernel over the layer where it lies in the stack (``ops/retention_update.py``;
 ``retention_update.block`` is the rule, from static facts alone):
 :func:`read`'s sums over the state and :func:`write`'s decay and outer
 product in one sweep (:class:`InPlace`).
@@ -217,7 +219,10 @@ def direct(forward_only: bool, tpu: bool, T: int, d: int) -> bool:
     (:func:`chunked`'s scan), from static facts alone: no gradient will be
     asked (the kernel has no backward pass), the trace is for TPUs (the
     kernel compiles for nothing else), the lengths tile, and the sequence is
-    no longer than :data:`CROSSOVER`."""
+    no longer than :data:`CROSSOVER`.  The state at the sequence's end is
+    then formed once, by the kernel of ``ops/retention_end_state.py`` where
+    that one's own ``tiles`` takes the lengths (today wherever this rule
+    does) and by :func:`end_state` where not."""
     from ompi_tpu.ops import retention_prefill
 
     return bool(forward_only and tpu and T <= CROSSOVER
@@ -234,16 +239,33 @@ def _blocks(y, Q: int):
     return jnp.moveaxis(y.reshape(B, nc, Q, *y.shape[2:]), 1, 0)
 
 
+def _decays_to_end(logg, Q: int):
+    """Each position's decay to the sequence's end, (nc, B, Q, G) float32
+    over :func:`_blocks` of ``Q`` positions of logg (B, T, G): ``exp((c_end -
+    c_j) + rest)``, the decays summed inside its block and ``rest`` the
+    blocks' after it, so every exponent is at most zero and none is the
+    difference of two sums over the sequence.  A padded position has log g
+    = 0: it decays nothing."""
+    import jax.numpy as jnp
+
+    cs = jnp.cumsum(_blocks(logg.astype(jnp.float32), Q), axis=2)
+    rest = jnp.cumsum(cs[::-1, :, -1:], axis=0)[::-1] - cs[:, :, -1:]
+    return jnp.exp(cs[:, :, -1:] - cs + rest)
+
+
 def end_state(k, v, logg, chunk: int):
     """The state after the last position of whole sequences from a zero
     state, formed once: ``S = sum_j exp(c_T - c_j) phi(k_j) v_j^T`` (B, G, D,
     d) and ``z`` alike (B, G, D), float32, from k, v (B, T, G, d) and the
     log decays logg (B, T, G).  :func:`chunked`'s arithmetic for a chunk's
     end over the whole sequence: ``chunk`` positions of ``phi(k)`` held at a
-    time, each under its decay to the sequence's end, ``exp((c_end - c_j) +
-    rest)``, the decays summed inside its chunk and ``rest`` the chunks'
-    after it (every exponent at most zero, and none the difference of two
-    sums over the sequence), so nothing is decayed between chunks."""
+    time, each under its decay to the sequence's end
+    (:func:`_decays_to_end`), so nothing is decayed between chunks.  The
+    ``jax.numpy`` form, on any backend and at any length: a chunk's
+    ``phi(k)`` is an array of the program.  Where the lengths tile a
+    decoder's prefill on TPUs forms the same sums in
+    ``ops/retention_end_state.py`` (:func:`_end_state_in_vmem`), and this is
+    what the tests hold that kernel to."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -251,10 +273,8 @@ def end_state(k, v, logg, chunk: int):
     B, T, G, d = k.shape
     Q, D = min(chunk, T), state_dim(d)
     # a padded position has k = 0 and log g = 0: it adds and decays nothing
-    k, v, logg = (_blocks(y, Q) for y in (k, v, logg.astype(f32)))
-    cs = jnp.cumsum(logg, axis=2)                       # (nc, B, Q, G)
-    rest = jnp.cumsum(cs[::-1, :, -1:], axis=0)[::-1] - cs[:, :, -1:]
-    w = jnp.exp(cs[:, :, -1:] - cs + rest)
+    w = _decays_to_end(logg, Q)
+    k, v = _blocks(k, Q), _blocks(v, Q)
 
     def one(state, block):
         S, z = state
@@ -270,13 +290,33 @@ def end_state(k, v, logg, chunk: int):
     return state
 
 
+def _end_state_in_vmem(k, v, logg, chunk: int):
+    """:func:`end_state`'s sums where ``retention_end_state.tiles`` takes
+    the lengths, in that kernel (PR 73: a block's ``phi(k)`` is built and
+    added into a head's ``S`` in VMEM and is no array of the program): under
+    the same decays, :func:`_decays_to_end` over ``chunk`` positions, and
+    with :func:`phi`'s constants asked of ``phi`` as the trace finds it,
+    ``phi`` of a vector of ones."""
+    import jax.numpy as jnp
+
+    from ompi_tpu.ops.retention_end_state import retention_end_state
+
+    B, T, G, d = k.shape
+    w = jnp.moveaxis(_decays_to_end(logg, min(chunk, T)), 0, 1)
+    return retention_end_state(k, v, w.reshape(B, -1, G)[:, :T],
+                               phi(jnp.ones((d,), jnp.float32)))
+
+
 def _direct(q, k, v, logg, chunk: int, eps: float):
     """:func:`chunked`'s results where :func:`direct` says so: position t
     reads every j <= t through ``exp(c_t - c_j) (q_t . k_j)^2 / d``, ``c``
     the decay's running sum over the whole sequence, in one kernel
     (``ops/retention_prefill.py``), and the state at the last position is
-    formed once (:func:`end_state`).  ``phi(q)`` is never formed."""
+    formed once: in one kernel too where the lengths tile for it
+    (:func:`_end_state_in_vmem`), by :func:`end_state` anywhere else.
+    ``phi(q)`` is never formed."""
     from ompi_tpu.core.scopes import scope
+    from ompi_tpu.ops import retention_end_state
     from ompi_tpu.ops.retention_prefill import retention_prefill
 
     B, T, H, d = q.shape
@@ -284,7 +324,9 @@ def _direct(q, k, v, logg, chunk: int, eps: float):
         num, den = retention_prefill(q, k, v, logg, _power)
         y = _quotient(num, den, eps).reshape(B, T, H, d)
     with scope("retention.end_state"):
-        S, z = end_state(k, v, logg, chunk)
+        at_end = (_end_state_in_vmem if retention_end_state.tiles(T, d)
+                  else end_state)
+        S, z = at_end(k, v, logg, chunk)
     return y, S, z
 
 

@@ -15,8 +15,9 @@ and ``latent_decode`` for a cached step over a cache of whole blocks of 1024
 positions, both only in a trace for TPUs; the ``jax.numpy`` forms anywhere
 else.  Power retention (``models/retention.py``) takes ``retention_prefill``
 for a decoder's prefill of up to ``retention.CROSSOVER`` positions
-(``retention.direct``: forward only, whole tiles) and ``retention_update``
-for a cached step over a float32 state of heads 128 wide
+(``retention.direct``: forward only, whole tiles) with
+``retention_end_state`` for the state that prefill leaves, and
+``retention_update`` for a cached step over a float32 state of heads 128 wide
 (``retention_update.block``), again only in a trace for TPUs; its trainer and
 every other length keep the chunked ``jax.numpy`` form.
 """

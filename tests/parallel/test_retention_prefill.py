@@ -139,8 +139,9 @@ def test_the_rule_reads_static_facts_alone(case, monkeypatch):
     jaxpr = jax.make_jaxpr(lambda *a: retention.chunked(
         *a, 256, EPS, forward_only))(*shapes)
     calls = list(_pallas_calls(jaxpr.jaxpr))
+    # (the state it leaves by the second: ``test_retention_end_state.py``)
     assert [c.params["name"] for c in calls] == (
-        ["retention_prefill"] if direct else [])
+        ["retention_prefill", "retention_end_state"] if direct else [])
     widths = {v.aval.shape for eqn in jaxpr.eqns for v in eqn.outvars}
     # phi(q), a query head's expansion, is the scan's alone
     scan = any(eqn.primitive.name == "scan" and any(
@@ -172,13 +173,17 @@ def test_the_kernel_refuses_what_does_not_tile():
 
 # which of the traced faults a prefill's core holds under the direct form,
 # and what of it is then what ``chunked`` reads under the same fault: all of
-# it; the state alone (``degree_one``: weights of either sign, so a
-# position's quotient is over a sum near zero and says nothing); or nothing
-# (``cross_terms_unscaled``: the direct read is exact and the fault shows in
-# the state alone).  ``rope_dropped`` is planted before the core, in
-# ``block.mixer``; the two of ``_state_before`` are a cached step's
+# it; the state's rows past the squares (``degree_one``: weights of either
+# sign, so a position's quotient is over a sum near zero and says nothing;
+# and the state is the kernel's of ``ops/retention_end_state.py``, which asks
+# ``phi`` for its constants and not for its form: the cross terms' rows are
+# zero as the chunked form's are, the squares' rows keep ``k_a^2`` where the
+# chunked form's hold ``k_a``); or nothing (``cross_terms_unscaled``: the
+# direct read is exact and the fault shows in the state alone).
+# ``rope_dropped`` is planted before the core, in ``block.mixer``; the two of
+# ``_state_before`` are a cached step's
 IN_THE_PREFILL = {
-    "gate_dropped": "all", "group_state_mixed": "all", "degree_one": "state",
+    "gate_dropped": "all", "group_state_mixed": "all", "degree_one": "cross",
     "normaliser_dropped": "all", "cross_terms_unscaled": "exact",
     "state_not_carried": None, "normaliser_not_carried": None,
     "rope_dropped": None}
@@ -227,11 +232,14 @@ def test_a_planted_fault_changes_the_prefill_under_the_direct_form(
     flat = jax.tree.leaves
     assert max(error(a, b) for a, b in zip(flat(faulty), flat(sound))) > (
         100 * PARITY)
-    if IN_THE_PREFILL[fault] != "exact":
-        same = IN_THE_PREFILL[fault] == "all"
-        for a, b in zip(flat(faulty)[not same:],
-                        flat(faulty_chunked)[not same:]):
+    if IN_THE_PREFILL[fault] == "all":
+        for a, b in zip(flat(faulty), flat(faulty_chunked)):
             assert error(a, b) < PARITY
+    elif IN_THE_PREFILL[fault] == "cross":
+        for a, b, c in zip(faulty[1], faulty_chunked[1], sound[1]):  # S, z
+            assert not a[:, :, d:].any() and not b[:, :, d:].any()
+            assert error(a[:, :, :d], c[:, :, :d]) < PARITY
+            assert error(a[:, :, :d], b[:, :, :d]) > 100 * PARITY
     else:       # what the prompt's positions read is sound, the state is not
         assert error(faulty[0], sound[0]) < PARITY
         assert error(faulty[1][0], sound[1][0]) > 100 * PARITY

@@ -5,9 +5,13 @@ kernel compiles at the cell's tile with no ``vmem_limit_bytes`` named, and
 the compiled program's text and memory: a layer's prompt is read directly,
 by the kernel, once, under the loop over layers; no query head's ``phi(q)``
 (8320 wide: ``bf16[2,256,8,5,65,128]`` was the parent's longest operation)
-and no (2048, 2048) array of weights is written out; the program's peak is
-not above the parent's.  And the trainer's program of the same configuration,
-traced for the same chip, calls no such kernel.
+and no (2048, 2048) array of weights is written out; the state the prompt
+leaves is formed by the second kernel (``ops/retention_end_state.py``, PR
+73), once, in the same loop, and no chunk of ``phi(k)`` (``f32[2,256,8,65,
+128]``, which PR 72's program wrote out and read back eight times a layer)
+is an array of the program; the program's peak is not above the parent's.
+And the trainer's program of the same configuration, traced for the same
+chip, calls no such kernel.
 """
 
 import re
@@ -16,19 +20,28 @@ import jax
 import jax.numpy as jnp
 
 from ompi_tpu.models import retention
+from ompi_tpu.ops import retention_end_state as end_module
 from ompi_tpu.ops import retention_prefill as kernel_module
 from tests.parallel.compiled import (INSTRUCTION, _cell, _on, _pallas_calls,
                                      _peak, _program)
 
 CELL = "brumby-14b-base.decode-2k-128-b48"
-# the parent's prefill program, the larger of the cell's two
-# (``decode_peak_hbm_gib`` on the ledger, PR 62)
-PARENT_PEAK_GIB = 12.772
+# the parent's prefill program, the larger of the cell's two, as this file's
+# compile read it at PR 72 on PR 73's box (12.68 at PR 63 by ``PERF.md``;
+# the change reads 12.51)
+PARENT_PEAK_GIB = 12.624
 B, T, H, G, d, D = 2, 2048, 40, 8, 128, 8320
 
 
 def _named(jaxpr, name):
     return [c for c in _pallas_calls(jaxpr) if c.params["name"] == name]
+
+
+def _calls_of(text, kernel):
+    """The ``op_name`` of every custom call of ``kernel`` in a compiled
+    program's text."""
+    return re.findall(r"custom-call\([^\n]*tpu_custom_call[^\n]*"
+                      rf'op_name="([^"\n]*{kernel}[^"\n]*)"', text)
 
 
 def test_the_kernel_compiles_at_cell_8s_tile_with_no_limit_named(
@@ -52,6 +65,28 @@ def test_the_kernel_compiles_at_cell_8s_tile_with_no_limit_named(
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+def test_the_end_states_kernel_compiles_at_cell_8s_block_with_no_limit_named(
+        chip, for_the_chip):
+    """A head's ``S`` twice (the output's buffers) and its transpose are 12.8
+    MB: inside what Mosaic gives unasked, or this compile fails."""
+    assert end_module.tiles(T, d) and end_module.ROWS == 256
+    bf16 = jnp.bfloat16
+    args = (_on(chip, (B, T, G, d), bf16), _on(chip, (B, T, G, d), bf16),
+            _on(chip, (B, T, G)), _on(chip, (D,)))
+    [call] = _pallas_calls(
+        jax.make_jaxpr(end_module.retention_end_state)(*args).jaxpr)
+    [params] = call.params["compiler_params"].values()
+    assert params.vmem_limit_bytes is None
+    assert params.dimension_semantics[-1] == "arbitrary"
+    assert call.params["grid_mapping"].grid == (B, G, T // end_module.ROWS)
+    compiled = jax.jit(end_module.retention_end_state).lower(*args).compile()
+    text = compiled.as_text()
+    assert "retention_end_state" in text and "tpu_custom_call" in text
+    # beside S and z that come back, the decays a column a head (one lane
+    # wide, padded to a tile's 128)
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
+
+
 def test_cell_8s_prefill_reads_a_prompt_directly_and_expands_no_query(
         chip, for_the_chip):
     cfg, job = _cell(CELL, chip)
@@ -62,11 +97,16 @@ def test_cell_8s_prefill_reads_a_prompt_directly_and_expands_no_query(
 
     # one call, in the body of the loop over layers, under the scopes a
     # trace is read by
-    kernels = re.findall(r"custom-call\([^\n]*tpu_custom_call[^\n]*"
-                         r'op_name="([^"\n]*retention_prefill[^"\n]*)"', text)
+    kernels = _calls_of(text, "retention_prefill")
     assert len(kernels) == 1, kernels
     assert ("/prefill/" in kernels[0] and "/layers/while/body/" in kernels[0]
             and "/attention/retention.scan/retention.direct/" in kernels[0])
+    # and one call of the kernel that forms the state the prompt leaves,
+    # in the same loop, under the scope a trace reads the end state by
+    ends = _calls_of(text, "retention_end_state")
+    assert len(ends) == 1, ends
+    assert ("/prefill/" in ends[0] and "/layers/while/body/" in ends[0]
+            and "/attention/retention.scan/retention.end_state/" in ends[0])
     assert "retention.scan/retention.end_state/" in text
     written = {dims for _name, dims, _op, _rest in INSTRUCTION.findall(text)}
     written = [tuple(int(n) for n in dims.split(",")) for dims in written
@@ -76,8 +116,12 @@ def test_cell_8s_prefill_reads_a_prompt_directly_and_expands_no_query(
     assert not [s for s in written if R in s and G in s
                 and (D in s or (D // d in s and d in s))]
     assert not [s for s in written if s.count(T) > 1]
-    # what is as wide as the state is a key's: the chunk of ``phi(k)`` and
-    # the states themselves
+    # no chunk of ``phi(k)``, whole or a shift a row: it is built and used
+    # in VMEM (the parent wrote ``f32[2,256,8,65,128]`` and read it back)
+    chunk = retention.Retention().chunk
+    assert not [s for s in written if chunk in s
+                and (D in s or (D // d in s and d in s))]
+    # what is as wide as the state is the states themselves
     assert [s for s in written if D in s]
     peak = _peak(compiled.memory_analysis())
     assert peak < (PARENT_PEAK_GIB * 2 ** 30) + (64 << 20), peak / 2 ** 30
