@@ -92,8 +92,12 @@ def planted(fault: str):
         "selection_shifted": [
             (sparse_index, "scores", lambda qi, wi, ki: jnp.roll(
                 scores(qi, wi, ki), 1, axis=-1))],
-        "whole_projection_norm": [(transformer, "_qk_norm", whole),
-                                  (decode, "_qk_norm", whole)],
+        # ``block.mixer`` calls ``transformer``'s; a module that has taken
+        # the name for its own (``decode`` has, and uses it nowhere) gets
+        # the fault too for as long as it has
+        "whole_projection_norm": [
+            (module, "_qk_norm", whole) for module in (transformer, decode)
+            if hasattr(module, "_qk_norm")],
     }.get(fault, [])
     sound = [(module, name, getattr(module, name))
              for module, name, _wrong in patches]

@@ -70,6 +70,32 @@ def routed(cell: cells.Cell, bench_dir: str = cells.BENCH_DIR):
     return program.counts(ref, shape).get("routed")
 
 
+# ---- a trace that is told it is for TPUs ------------------------------------
+
+# the program's rule, by the name it has in whichever module holds it
+TRACED_FOR_TPUS = "_traced_for_tpus"
+
+
+def tell_it_is_traced_for_tpus(monkeypatch) -> None:
+    """Every kind's mixer asks one function of the program whether its trace
+    is under a mesh of TPUs before it takes a kernel.  Patched to say yes in
+    every module of ``ompi_tpu.models`` and ``ompi_tpu.ops`` that defines it
+    or has imported it, so a test says what it means and not where the
+    function lives today: the program may move it among those modules."""
+    import importlib
+    import pkgutil
+
+    patched = 0
+    for package in ("ompi_tpu.models", "ompi_tpu.ops"):
+        paths = importlib.import_module(package).__path__
+        for info in pkgutil.iter_modules(paths, package + "."):
+            module = importlib.import_module(info.name)
+            if hasattr(module, TRACED_FOR_TPUS):
+                monkeypatch.setattr(module, TRACED_FOR_TPUS, lambda: True)
+                patched += 1
+    assert patched, f"no module of the program has {TRACED_FOR_TPUS}"
+
+
 # ---- a copy of the benchmark, and what was added to it ----------------------
 
 def digest(top) -> dict[str, str]:

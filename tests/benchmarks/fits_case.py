@@ -4,12 +4,22 @@ are measured on: the v5e's own compiler, installed here, compiling for a
 here is a measurement; a program that outgrows 15.75 GiB of HBM, or that the
 compiler refuses for any other reason, fails here at no chip time.
 
+These compiles run on demand and not in tier-1: ``case`` marks every one
+``slow``, which the driver's ``-m 'not slow'`` leaves out (fourteen of them
+were 1650 of tier-1's 8084 test-seconds on PR 73's tree, cell 6's alone
+296).  What refuses a PR is the driver's own run of every cell on the chip,
+where a program that does not compile or does not fit gives no result.  A
+builder runs a cell's compile before spending chip time on it, for each
+cell whose programs the PR touches and for a cell it adds:
+
+    python -m pytest -m slow tests/benchmarks/test_cell_fits_<cell>.py
+
 The body and the fixtures of the ``test_cell_fits_<cell>.py`` files: a file a
-cell, because the driver runs tier-1 as ``--dist loadfile`` and a file is one
-worker's, and these compiles are the longest cases of the suite (cell 6's
-two minutes).  Each file names its cell and imports the fixtures;
-``test_harness.py`` holds the set of files to ``BENCHMARK.json``'s
-``workloads``, so a new cell's PR adds its file.
+cell, so that one cell is one command (and one worker's, under ``--dist
+loadfile``, where several are run).  Each file names its cell, imports the
+fixtures and takes its test from ``case``; ``test_harness.py`` holds the set
+of files to ``BENCHMARK.json``'s ``workloads`` and each file's test to its
+mark, so a new cell's PR adds its file.
 """
 
 import os
@@ -57,6 +67,16 @@ def no_compile_cache():
     yield
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+def case(workload: str):
+    """The test of ``workload``'s file, marked once for all of them."""
+    @pytest.mark.slow
+    @pytest.mark.parametrize("workload", [workload])
+    def test_cell_programs_compile_for_the_chip(workload, chips):
+        programs_compile_for_the_chip(workload, chips)
+
+    return test_cell_programs_compile_for_the_chip
 
 
 def programs_compile_for_the_chip(workload: str, chips) -> None:

@@ -1,14 +1,7 @@
 """Cell 11's programs compile for the chip (``fits_case.py`` has the body)."""
 
-import pytest
-
-from tests.benchmarks.fits_case import (chips,  # noqa: F401
-                                        no_compile_cache,
-                                        programs_compile_for_the_chip)
+from tests.benchmarks.fits_case import (case, chips,  # noqa: F401
+                                        no_compile_cache)
 
 CELL = "longcat-flash-chat.decode-896-128-b160"
-
-
-@pytest.mark.parametrize("workload", [CELL])
-def test_cell_programs_compile_for_the_chip(workload, chips):  # noqa: F811
-    programs_compile_for_the_chip(workload, chips)
+test_cell_programs_compile_for_the_chip = case(CELL)

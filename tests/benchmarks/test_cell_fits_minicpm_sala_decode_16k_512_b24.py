@@ -1,14 +1,7 @@
 """Cell 9's programs compile for the chip (``fits_case.py`` has the body)."""
 
-import pytest
-
-from tests.benchmarks.fits_case import (chips,  # noqa: F401
-                                        no_compile_cache,
-                                        programs_compile_for_the_chip)
+from tests.benchmarks.fits_case import (case, chips,  # noqa: F401
+                                        no_compile_cache)
 
 CELL = "minicpm-sala.decode-16k-512-b24"
-
-
-@pytest.mark.parametrize("workload", [CELL])
-def test_cell_programs_compile_for_the_chip(workload, chips):  # noqa: F811
-    programs_compile_for_the_chip(workload, chips)
+test_cell_programs_compile_for_the_chip = case(CELL)

@@ -5,8 +5,6 @@ benchmark's own decode cells have a file each
 (``test_cell_controls_<cell>.py``), so that the driver's ``--dist loadfile``
 hands them to several workers."""
 
-import hashlib
-
 import jax
 import numpy as np
 import pytest
@@ -54,28 +52,54 @@ def test_the_dry_cell_is_an_addition_of_files_and_rows_alone():
         f"configs/{decode_cells.LOGITS_FAMILY}.json"}
 
 
-# ---- the train cells' draw is the parent's ---------------------------------
+# ---- the train cells' draw is what the reference's table states ------------
 
-# sha256 of two leaves of ``program.init_params`` at the configurations'
-# tiny sizes, seed 7, printed on the parent of the PR that gave the decode
-# cells a serving draw (CPU box), before any edit
-PARENT_LEAVES = {"emb": "ba3e47db965b1b03", "w2": "fb557aa18e21cc2b"}
-
-
-@pytest.mark.parametrize("workload", TRAIN)
-def test_the_train_cells_seeded_parameters_are_the_parents(workload):
+def _draws(workload):
+    """(the reference's table, the tiny configuration, ``draw(seed,
+    **how)``: the cell's parameters drawn as its runner draws them)."""
     cell = cells.resolve(workload)
     config = program.tiny(cell.config)
     ref = program.reference(config)
     cfg = program.program_config(config)
     mesh = program.mesh(config, jax.devices()[:cell.chips])
     shardings = program.param_shardings(config, cfg, mesh)
-    params = program.init_params(ref, config, shardings, 7)
-    for leaf, want in PARENT_LEAVES.items():
-        got = hashlib.sha256(np.asarray(params[leaf]).tobytes()).hexdigest()
-        assert got[:16] == want, leaf
+
+    def draw(seed, **how):
+        return program.init_params(ref, config, shardings, seed, **how)
+
+    return program.param_table(ref, config), config, draw
+
+
+@pytest.mark.parametrize("workload", TRAIN)
+def test_the_train_cells_seeded_parameters_are_the_parents(workload):
+    """The draw is held by what it means and by no backend's bits: every leaf
+    has the shape and type the configuration states and the mean and
+    deviation the reference's table states for training, to what a sample of
+    the leaf's size can show (five deviations of a mean of ``n`` draws,
+    ``std / sqrt(n)``, and of their deviation, ``std / sqrt(2 n)``: under one
+    leaf in a million).  One seed is one draw bit for bit, another seed is
+    another.  (Until PR 74 two leaves' sha256 stood here, printed on one CPU
+    box at one level of its compiler.)"""
+    table, config, draw = _draws(workload)
+    params = draw(7)
+    assert set(params) == set(table)
+    for leaf, (dims, std) in table.items():
+        got = np.asarray(params[leaf])
+        assert got.shape == tuple(dims), leaf
+        assert got.dtype == np.dtype(config["param_dtype"]), leaf
+        got = got.astype(np.float64)
+        if std is None:
+            assert (got == 1).all(), leaf
+            continue
+        assert abs(got.mean()) < 5 * std / got.size ** 0.5, leaf
+        assert abs(got.std() / std - 1) < 5 / (2 * got.size) ** 0.5, leaf
+    again, other = draw(7), draw(8)
+    for leaf, (_dims, std) in table.items():
+        assert np.array_equal(again[leaf], params[leaf]), leaf
+        assert std is None or not np.array_equal(other[leaf],
+                                                 params[leaf]), leaf
     # and the draw for serving is another: the decode cells' own
-    serving = program.init_params(ref, config, shardings, 7, serving=True)
+    serving = draw(7, serving=True)
     assert not np.array_equal(serving["w2"], params["w2"])
     assert {k: v.shape for k, v in serving.items()} == {
         k: v.shape for k, v in params.items()}

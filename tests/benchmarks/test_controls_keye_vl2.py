@@ -62,8 +62,10 @@ def test_the_faults_are_planted_for_a_trace_and_taken_back():
     from ompi_tpu.models import decode, sparse_index, transformer
 
     def held():
+        # ``decode`` imports the norm's name and uses it nowhere: it is held
+        # for as long as the module has it
         return (sparse_index.project, sparse_index.scores,
-                transformer._qk_norm, decode._qk_norm)
+                transformer._qk_norm, getattr(decode, "_qk_norm", None))
 
     sound = held()
     for fault in controls_keye_vl2.TRACED_FAULTS:

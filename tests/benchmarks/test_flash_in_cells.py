@@ -2,10 +2,10 @@
 
 The trainers (2048 keys a device) hold ``flash_fwd``, ``flash_bwd_dq``,
 ``flash_bwd_dkv`` and the rotary kernel, and nothing as large as a score
-matrix; ``test_fits.py`` compiles them so, at their real sizes.  The
-decoders' prefill (1024 keys) is under the rule's threshold
-(``parallel/attention.local_impl``): their programs hold no kernel of
-attention's, and the dense one's process never imports
+matrix; the cells' ``test_cell_fits_<cell>.py`` compile them so, at their
+real sizes, on demand.  The decoders' prefill (1024 keys) is under the
+rule's threshold (``parallel/attention.local_impl``): their programs hold no
+kernel of attention's, and the dense one's process never imports
 ``jax.experimental.pallas`` (0.8 s of a 6.6 s set-up; ledger, PR 27).  At
 the tiny sizes on CPU devices no program holds a pallas call: an interpreted
 kernel would compile scalar programs inside a timed window.  Programs are
@@ -79,8 +79,8 @@ def test_decoder_programs_stay_as_they_are(workload, chips):
     lowered."""
     allowed, may_import = DECODERS[workload]
     # the child describes a v5e while this process (and other workers'
-    # test_fits.py) hold libtpu for theirs: without this it aborts on
-    # /tmp/libtpu_lockfile
+    # files that compile for one) hold libtpu for theirs: without this it
+    # aborts on /tmp/libtpu_lockfile
     env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": ROOT,
            "ALLOW_MULTIPLE_LIBTPU_LOAD": "1"}
     done = subprocess.run([sys.executable, "-c", LOWER_A_DECODER, workload],

@@ -7,6 +7,7 @@ checks resolution, control flow and the shape of the result line, never a
 device metric.
 """
 
+import importlib
 import json
 import os
 import re
@@ -23,11 +24,12 @@ from benchmarks.lib import cells, program, scopes, xplane
 from benchmarks.lib.compile_meter import CompileMeter
 from benchmarks.lib.rundata import RunData
 from benchmarks.lib.spans import Spans
-from tests.benchmarks import decode_cells
+from tests.benchmarks import controls_cases, decode_cells, fits_case
 from tests.benchmarks.decode_cells import (LOGIT_CHECK, copied_benchmark,
                                            digest)
 
 ROOT = os.path.dirname(cells.BENCH_DIR)
+HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = cells.load_benchmark()
 WORKLOADS = [w["name"] for w in BENCH["workloads"]]
 NAME = re.compile(r"^[A-Za-z0-9][A-Za-z0-9_.-]{0,63}$")
@@ -124,23 +126,45 @@ def test_names_and_shape_of_the_benchmark_file():
     assert all(w["chips"] in (1, 4) for w in BENCH["workloads"])
 
 
-def test_a_cell_has_a_file_of_its_own_of_each_test_that_is_split_a_cell():
-    """The two longest files of tier-1 are a file a cell, so that the
-    driver's ``--dist loadfile`` hands a cell's compile and its controls to
-    whichever worker is free: a PR that adds a cell adds its two files (its
-    one, for a train cell), each naming the cell, or fails here."""
-    from tests.benchmarks import controls_cases, fits_case
+# the two longest sets of cases are a file a cell: (stem, cells that have one)
+SPLIT = ((fits_case.STEM, WORKLOADS),
+         (controls_cases.STEM, controls_cases.DECODE))
 
-    here = os.path.dirname(os.path.abspath(__file__))
-    for stem, workloads in ((fits_case.STEM, WORKLOADS),
-                            (controls_cases.STEM, controls_cases.DECODE)):
-        found = {f for f in os.listdir(here)
+
+def _file_of(stem: str, workload: str) -> str:
+    return os.path.join(HERE, f"{stem}{fits_case.slug(workload)}.py")
+
+
+@pytest.mark.parametrize("stem,workload", [
+    pytest.param(stem, w, id=f"{stem}{w}")
+    for stem, workloads in SPLIT for w in workloads])
+def test_a_cell_has_a_file_of_its_own_of_each_test_that_is_split_a_cell(
+        stem, workload):
+    """A cell's compile at real sizes is a file of its own so that one
+    command runs one cell's, and its controls so that the driver's ``--dist
+    loadfile`` hands them to whichever worker is free: a PR that adds a cell
+    adds its two files (its one, for a train cell), each naming the cell, or
+    fails here.  The compile is on demand: every test of its file carries
+    the ``slow`` mark, which the driver's ``-m 'not slow'`` leaves out."""
+    path = _file_of(stem, workload)
+    assert os.path.isfile(path), path
+    with open(path, encoding="utf-8") as f:
+        assert f'\nCELL = "{workload}"\n' in f.read(), path
+    if stem == fits_case.STEM:
+        module = importlib.import_module(
+            f"tests.benchmarks.{stem}{fits_case.slug(workload)}")
+        tests = [v for k, v in vars(module).items() if k.startswith("test")]
+        assert tests, path
+        for test in tests:
+            assert "slow" in {m.name for m in test.pytestmark}, path
+
+
+def test_the_files_that_are_split_a_cell_are_the_benchmarks_cells():
+    """And no file of either stem is left behind by a cell that went."""
+    for stem, workloads in SPLIT:
+        found = {os.path.join(HERE, f) for f in os.listdir(HERE)
                  if f.startswith(stem) and f.endswith(".py")}
-        assert found == {f"{stem}{fits_case.slug(w)}.py" for w in workloads}
-        for workload in workloads:
-            path = os.path.join(here, f"{stem}{fits_case.slug(workload)}.py")
-            with open(path, encoding="utf-8") as f:
-                assert f'\nCELL = "{workload}"\n' in f.read(), path
+        assert found == {_file_of(stem, w) for w in workloads}
 
 
 def test_harness_names_no_cell_configuration_runner_or_metric():

@@ -34,16 +34,58 @@ BINDS = {"minicpm-sala.decode-16k-512-b24":
 UNUSED = {("minicpm-sala.decode-16k-512-b24",
            "scope/attention.gather@decode.step")}
 OP_NAME = re.compile(r'op_name="([^"]*)"')
+# a computation's first line in a program's text, and an instruction's
+# attributes that name another computation
+COMPUTATION = re.compile(r"(?:ENTRY\s+)?%?([\w.-]+)\s*\(")
+CALLEE = re.compile(r"\b(to_apply|select|scatter|calls)=%?([\w.-]+)")
 
 _tables: dict[str, dict] = {}
 
 
+def by_computation(text: str):
+    """(the computation it is in, the line) of every line of a program's
+    text: a computation begins at a line that is not indented and ends in
+    an opening brace."""
+    name = None
+    for line in text.splitlines():
+        if line.endswith("{") and not line[:1].isspace():
+            found = COMPUTATION.match(line)
+            name = found and found.group(1)
+        yield name, line
+
+
+def reducers_bodies(text: str) -> set:
+    """The computations of a program's text that a reducer applies (the
+    ``to_apply`` of a ``reduce``, an ``all-reduce``, a ``sort``, a
+    ``scatter``; a ``select-and-scatter``'s two) and whatever those call.
+    JAX names the inside of one by the scopes of the enclosing loop's body
+    alone (``layers/attention/reduce_max`` under ``decode.step``), and no
+    profile has an event for it: it runs inside the reducer's own."""
+    callees, applied = {}, set()
+    for name, line in by_computation(text):
+        for how, callee in CALLEE.findall(line):
+            callees.setdefault(name, set()).add(callee)
+            # a ``call``'s ``to_apply`` is a function of the program's own
+            if how != "calls" and " call(" not in line:
+                applied.add(callee)
+    bodies, queue = set(), list(applied)
+    while queue:
+        body = queue.pop()
+        if body not in bodies:
+            bodies.add(body)
+            queue.extend(callees.get(body, ()))
+    return bodies
+
+
 def names_of(compiled) -> tuple[set, set]:
-    """(every op_name, those of collective instructions) of a program."""
+    """(every op_name, those of collective instructions) of a program, but
+    of what a reducer applies: a profile has no event there to name."""
+    text = compiled.as_text()
+    skipped = reducers_bodies(text)
     every, collective = set(), set()
-    for line in compiled.as_text().splitlines():
+    for name, line in by_computation(text):
         found = OP_NAME.search(line)
-        if found:
+        if found and name not in skipped:
             every.add(found.group(1))
             if xplane.collective_kind(line.strip()) is not None:
                 collective.add(found.group(1))
@@ -284,10 +326,37 @@ def _train_table(mesh_shape: dict, **config) -> dict:
         step.lower(params, init(params), tokens).compile()))
 
 
+def _ep_exchange_table() -> dict:
+    """What the reader calls an exchange over ``ep`` and a sum over every
+    axis of an ``ep: 2`` mesh, from a program that asks the communicator
+    for both itself: no layer of the model has to exchange for the reader's
+    naming of one to be held."""
+    from jax.sharding import PartitionSpec as P
+
+    from ompi_tpu.mpi.device_comm import DeviceCommunicator
+    from ompi_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh({"dp": 1, "sp": 1, "tp": 1, "ep": 2},
+                     devices=jax.devices()[:2])
+    comm = DeviceCommunicator(mesh)
+    fn = jax.jit(jax.shard_map(
+        lambda v: comm.allreduce(comm.alltoall_stacked(v, axis="ep")),
+        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False))
+    return table_of(*names_of(
+        fn.lower(np.ones((2, 8), np.float32)).compile()))
+
+
 @pytest.fixture(scope="module")
 def moe_table():
-    return _train_table({"dp": 1, "sp": 1, "tp": 1, "ep": 2},
-                        attention="xla", moe_experts=4, remat=False)
+    """The routed layer's four scopes, from a train step that routes
+    (``routed_moe``, every routed cell's path), beside the two sites of an
+    exchange over ``ep``."""
+    table = _train_table({"dp": 1, "sp": 1, "tp": 1, "ep": 1},
+                         attention="xla", moe_experts=4, moe_top_k=2,
+                         remat=False)
+    for key, names in _ep_exchange_table().items():
+        table.setdefault(key, set()).update(names)
+    return table
 
 
 @pytest.fixture(scope="module")

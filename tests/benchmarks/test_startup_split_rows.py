@@ -211,7 +211,9 @@ def test_cell_9s_two_programs_lowered_at_real_sizes_leave_a_few_dozen_records(
     assert out["records"] < 300 and out["dropped"] == 0
     first, full = out["calls"]
     assert (first["part"], full["part"]) == ("prefill", "generate")
-    assert first["trace_s"] > full["trace_s"] > 0
+    # both parts traced; which took longer is two readings of a wall clock
+    # (0.690 against 0.695 s turned three PRs' runs red) and proves nothing
+    assert first["trace_s"] > 0 and full["trace_s"] > 0
     assert 1500 > first["helpers"] > full["helpers"] > 100
     own = out["programs"]["decode"]
     for key in ("trace_s", "lower_s", "backend_s"):

@@ -17,13 +17,13 @@ import numpy as np
 import pytest
 
 from benchmarks.lib import cells, costs, program
-from ompi_tpu.models import kda, mla, plan, sparse_index
+from ompi_tpu.models import mla, plan, sparse_index
 from ompi_tpu.models import transformer as tfm
 from ompi_tpu.models.decode import make_decoder
 from ompi_tpu.ops import latent_decode as decode_kernel
 from ompi_tpu.ops import masked_latent_attention as prefill_kernel
 from ompi_tpu.parallel import moe
-from tests.benchmarks import test_reference
+from tests.benchmarks import decode_cells, test_reference
 
 NAME = "deepseek-v3.2-exp"
 CELL = "deepseek-v3.2-exp.decode-16k-512-b8"
@@ -280,8 +280,9 @@ def test_the_shares_of_four_chips_and_the_shared_expert_are_the_uncut_layer():
         c = dataclasses.replace(c, moe_held=(first, count),
                                 moe_shared=c.moe_shared if shared else 0)
         with mesh:
-            return tfm._moe_ffn_tail(c, h, lp, comm, layer=0,
-                                     residual=False)[0]
+            # the layer's output: alone, or the first of what it returns
+            return jax.tree.leaves(tfm._moe_ffn_tail(
+                c, h, lp, comm, layer=0, residual=False))[0]
 
     uncut = routed(full, 0, 16, True)
     parts = sum(routed(cfg, 4 * rank, 4, rank == 0) for rank in range(4))
@@ -481,7 +482,7 @@ def test_the_mixer_takes_the_kernels_where_it_is_traced_for_tpus(monkeypatch):
             return _sound(*args, **kwargs)
 
         monkeypatch.setattr(module, name, counted)
-    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: True)
+    decode_cells.tell_it_is_traced_for_tpus(monkeypatch)
     from ompi_tpu.models import decode
 
     decode._prefill_program.cache_clear()
