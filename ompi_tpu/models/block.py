@@ -176,6 +176,7 @@ def mixer(cfg, comm, lp, h, positions, carry=None, impl: str = "jnp",
 
     from ompi_tpu.core.scopes import scope
     from ompi_tpu.models import transformer as tfm
+    from ompi_tpu.ops import _chip
     from ompi_tpu.parallel import attention as attn_mod
     from ompi_tpu.parallel.layers import column_parallel, row_parallel
 
@@ -229,8 +230,8 @@ def mixer(cfg, comm, lp, h, positions, carry=None, impl: str = "jnp",
         # each query over its own selection (the kernel has no backward
         # pass and compiles for the TPU)
         o, ki = sparse_index.attend(
-            cfg, lp, x, q, k, v, positions, kernel=forward_only
-            and comm.mesh.devices.flat[0].platform == "tpu")
+            cfg, lp, x, q, k, v, positions,
+            kernel=forward_only and _chip._traced_for_tpus())
         out = (k, v, ki)
     elif cfg.index is None:
         (kc, vc), layer, pos = carry

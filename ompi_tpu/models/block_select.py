@@ -249,7 +249,7 @@ def attend(bs: BlockSelect, q, k, v):
     from jax import lax
 
     from ompi_tpu.core.scopes import scope
-    from ompi_tpu.models.kda import _traced_for_tpus
+    from ompi_tpu.ops import _chip
     from ompi_tpu.ops.masked_attention import tiles
 
     B, T, H, hd = q.shape
@@ -280,7 +280,8 @@ def attend(bs: BlockSelect, q, k, v):
             o = _attention(
                 jnp.moveaxis(qs, 1, 2).reshape(B * hkv, n, r, hd), k1, v1,
                 mask.reshape(B * hkv, n, T),
-                kernel=_traced_for_tpus() and tiles(n, hd), k_len=k_len)
+                kernel=_chip._traced_for_tpus() and tiles(n, hd),
+                k_len=k_len)
             return jnp.moveaxis(o.reshape(B, hkv, n, r, hd), 1, 2).reshape(
                 B, n, H, hd)
 
@@ -347,7 +348,7 @@ def attend_cached(bs: BlockSelect, q, rows, pooled, pos):
     import jax.numpy as jnp
 
     from ompi_tpu.core.scopes import scope
-    from ompi_tpu.models.kda import _traced_for_tpus
+    from ompi_tpu.ops import _chip
     from ompi_tpu.models.sparse_index import _grouped_attention
     from ompi_tpu.ops.selected_attention import selected_attention, tiles
 
@@ -363,7 +364,7 @@ def attend_cached(bs: BlockSelect, q, rows, pooled, pos):
             mask = _positions(bs, chosen(bs, scores, t), t, Tmax)
     q1 = qh.reshape(B * hkv, 1, H // hkv, hd)
     with scope("attention"):
-        if _traced_for_tpus() and tiles(Tmax, hd):
+        if _chip._traced_for_tpus() and tiles(Tmax, hd):
             o = selected_attention(
                 q1, rows.reshape(1, B * hkv, Tmax, 2 * hd),
                 mask.reshape(B * hkv, Tmax), jnp.int32(0))

@@ -210,19 +210,6 @@ def chunked(q, k, v, g, beta, chunk: int):
     return o[:, :T], S
 
 
-def _traced_for_tpus() -> bool:
-    """Whether what is being traced will run on TPUs: the kind of the
-    devices of the mesh the trace is under (a decoder's programs are
-    ``shard_map``s over theirs), attached or described for a compile; under
-    no mesh, the process's own backend."""
-    import jax
-
-    device = jax.sharding.get_abstract_mesh().abstract_device
-    if device is None:
-        return jax.default_backend() == "tpu"
-    return device.device_kind.startswith("TPU")
-
-
 def update(state, q, k, v, g, beta):
     """The recurrence once, for one new position: ``state`` (B, H, K, K) as
     it is carried, q, k, v, g (B, H, K) and beta (B, H) float32.  Returns o
@@ -237,9 +224,9 @@ def update(state, q, k, v, g, beta):
     state plus the write."""
     import jax.numpy as jnp
 
-    from ompi_tpu.ops import kda_update
+    from ompi_tpu.ops import _chip, kda_update
 
-    if kda_update.block(_traced_for_tpus(), state.dtype,
+    if kda_update.block(_chip._traced_for_tpus(), state.dtype,
                         *state.shape[1:3]) is not None:
         return kda_update.kda_update(state, q, k, v, g, beta)
     S = state.astype(jnp.float32) * jnp.exp(g)[..., None]

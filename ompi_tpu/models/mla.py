@@ -311,7 +311,7 @@ def _select_attend(ml: MLA, q, kv, k_r, qi, ki, wi, forward_only: bool):
 
     from ompi_tpu.core.scopes import scope
     from ompi_tpu.models import sparse_index
-    from ompi_tpu.models.kda import _traced_for_tpus
+    from ompi_tpu.ops import _chip
     from ompi_tpu.ops import masked_latent_attention as kernel
 
     ix = ml.index
@@ -333,7 +333,7 @@ def _select_attend(ml: MLA, q, kv, k_r, qi, ki, wi, forward_only: bool):
                 mask = sparse_index.select(found, mask, ix.topk)
         mask = jnp.broadcast_to(mask, (B, n, T))
         with scope("attention"), scope("attention.selected"):
-            if (forward_only and _traced_for_tpus()
+            if (forward_only and _chip._traced_for_tpus()
                     and kernel.tiles(n, H, ml.nope, ml.rope, ml.v_dim)):
                 return kernel.masked_latent_attention(
                     cut(q), kv, k_r, mask, ml.scale, k_len=lo + n)
@@ -383,9 +383,8 @@ def mixer(cfg, lp, h, carry=None, forward_only: bool = False):
     from jax import lax
 
     from ompi_tpu.core.scopes import scope
-    from ompi_tpu.models.kda import _traced_for_tpus
     from ompi_tpu.models.transformer import _rmsnorm
-    from ompi_tpu.ops import latent_attention, latent_decode
+    from ompi_tpu.ops import _chip, latent_attention, latent_decode
 
     ml, f32, cdt = cfg.plan.mla, jnp.float32, h.dtype
     B, T, _ = h.shape
@@ -437,7 +436,7 @@ def mixer(cfg, lp, h, carry=None, forward_only: bool = False):
         else:
             with scope("attention"):
                 form = (latent_attention.latent_attention
-                        if (T >= KERNEL_FROM and _traced_for_tpus()
+                        if (T >= KERNEL_FROM and _chip._traced_for_tpus()
                             and latent_attention.tiles(T, H, N, P, W))
                         else latent_attention.jnp_form)
                 o = form(q, kv, lat[..., R:], scale)
@@ -466,7 +465,7 @@ def mixer(cfg, lp, h, carry=None, forward_only: bool = False):
                 chosen = sparse_index.select(found, live, ml.index.topk)
         with scope("attention"), (contextlib.nullcontext() if chosen is None
                                   else scope("attention.selected")):
-            if _traced_for_tpus() and latent_decode.tiles(Tmax, R):
+            if _chip._traced_for_tpus() and latent_decode.tiles(Tmax, R):
                 ctx = latent_decode.latent_decode(q_abs, cache, pos, scale,
                                                   R, chosen)
             else:

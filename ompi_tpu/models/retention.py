@@ -353,11 +353,11 @@ def chunked(q, k, v, logg, chunk: int, eps: float,
     import jax.numpy as jnp
     from jax import lax
 
-    from ompi_tpu.models import kda
+    from ompi_tpu.ops import _chip
 
     f32, cdt = jnp.float32, q.dtype
     B, T, H, d = q.shape
-    if direct(forward_only, kda._traced_for_tpus(), T, d):
+    if direct(forward_only, _chip._traced_for_tpus(), T, d):
         return _direct(q, k, v, logg, chunk, eps)
     G = k.shape[2]
     R, Q = H // G, min(chunk, T)
@@ -527,11 +527,10 @@ def _state_before(stack, layer):
     import jax.numpy as jnp
     from jax import lax
 
-    from ompi_tpu.models import kda
-    from ompi_tpu.ops import retention_update
+    from ompi_tpu.ops import _chip, retention_update
 
     if stack.ndim == 5 and retention_update.block(
-            kda._traced_for_tpus(), stack.dtype, *stack.shape[3:]):
+            _chip._traced_for_tpus(), stack.dtype, *stack.shape[3:]):
         return InPlace(stack, layer)
     return lax.dynamic_index_in_dim(stack, layer,
                                     keepdims=False).astype(jnp.float32)

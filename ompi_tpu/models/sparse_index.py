@@ -343,8 +343,10 @@ def row_shape(cfg, mesh, t_max: int, heads: int) -> tuple:
     ``heads`` K heads and then its V heads in one row: ``(2 heads, hd)``, and
     the row flat, ``(2 heads hd,)``, where the program's cached steps stream
     it (:func:`streams`: static, so all of a program's steps or none)."""
-    if streams(cfg.index, t_max, cfg.head_dim,
-               mesh.devices.flat[0].platform == "tpu"):
+    from ompi_tpu.ops import _chip
+
+    # a decoder shapes its carry per device, under its mesh's shard_map
+    if streams(cfg.index, t_max, cfg.head_dim, _chip._traced_for_tpus()):
         return (2 * heads * cfg.head_dim,)
     return (2 * heads, cfg.head_dim)
 

@@ -385,7 +385,7 @@ def _mix(sz: Mamba2, eps: float, lp, u, carry=None, multipliers=None,
     from jax import lax
 
     from ompi_tpu.core.scopes import scope
-    from ompi_tpu.models.kda import _traced_for_tpus
+    from ompi_tpu.ops import _chip
 
     f32, cdt = jnp.float32, u.dtype
     B, T, _ = u.shape
@@ -416,8 +416,8 @@ def _mix(sz: Mamba2, eps: float, lp, u, carry=None, multipliers=None,
     b, c = b.reshape(B, T, G, N), c.reshape(B, T, G, N)
     dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
     a = -jnp.exp(lp["a_log"].astype(f32))
-    kernel = carry is None and fused(sz, forward_only, _traced_for_tpus(), T,
-                                     cdt)
+    tpus = _chip._traced_for_tpus()
+    kernel = carry is None and fused(sz, forward_only, tpus, T, cdt)
     if carry is None:
         with scope("ssm.scan"):
             if kernel:
@@ -428,7 +428,7 @@ def _mix(sz: Mamba2, eps: float, lp, u, carry=None, multipliers=None,
                 y, ssm_out = ssm_scan(xbc, dt, a, lp["ssm_d"], G, N)
             else:
                 y, ssm_out = chunked_scan(x, dt, a, b, c, sz.chunk)
-    elif layer is None and in_place(sz, _traced_for_tpus(), ssm_c.dtype):
+    elif layer is None and in_place(sz, tpus, ssm_c.dtype):
         from ompi_tpu.ops.ssm_update import ssm_update
 
         with scope("ssm.update"):

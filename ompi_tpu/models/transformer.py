@@ -627,6 +627,7 @@ def _moe_ffn_tail(cfg, h, lp, comm, layer=None, residual: bool = True):
     import jax.numpy as jnp
 
     from ompi_tpu.core.scopes import scope
+    from ompi_tpu.ops import _chip
     from ompi_tpu.parallel.moe import EXPERT_LEAVES, routed_moe, switch_moe
 
     with scope("ffn"):
@@ -641,11 +642,11 @@ def _moe_ffn_tail(cfg, h, lp, comm, layer=None, residual: bool = True):
             weights = {k: lp[k] for k in ("wg", *EXPERT_LEAVES) if k in lp}
             if cfg.moe_select_bias:
                 weights["wgb"] = lp["wgb"]
-            # the pallas kernel where the mesh is of TPUs (attached, or
-            # described for a compile); XLA's ragged_dot on any other
+            # the pallas kernel where the trace is for TPUs (attached, or
+            # described for a compile); XLA's ragged_dot anywhere else
             mo = routed_moe(x, weights, cfg.moe_top_k, gated=cfg.moe_gated,
                             act=cfg.moe_act, layer=layer,
-                            kernel=comm.mesh.devices.flat[0].platform == "tpu",
+                            kernel=_chip._traced_for_tpus(),
                             renorm=cfg.moe_norm_topk, score=cfg.moe_score,
                             scale=cfg.moe_scale, held=cfg.moe_held,
                             zero=cfg.moe_zero,

@@ -67,23 +67,19 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ompi_tpu.ops._chip import _VMEM_BUDGET_BYTES
+
 __all__ = ["grouped_matmul", "grouped_matmul_xla", "tile_rows",
            "weight_block"]
 
-# What the kernel's blocks may take of VMEM: what Mosaic gives a kernel that
-# names no limit (16 MiB on the v5e's compiler; what the rule admits just
-# under it compiles, tests/parallel/test_grouped_matmul_compiled.py).  The
-# call names none on purpose: a stated ``vmem_limit_bytes`` is reserved
-# whole, whatever the kernel needs, and XLA then assigns less VMEM to the
-# operations around it: at 32 MiB Keye-VL's cached step, whose calls take
-# the same blocks either way, ran 0.9% slower (PERF.md section 6, PR 46).
-# The largest working set a cell's call makes, as ``_working_set_bytes``
-# counts it, is Keye-VL's 2048 x 768 bfloat16 beside 512 rows, 15.2 MB; the
-# count is an upper bound (the float32 product is not held whole beside
-# the accumulator): Kimi-Linear's 1024 x 2304 at 128 rows counts 13.5 MB
-# and, compiled for a v5e, needs 11 MiB.  Beside rows of 4 MiB it is not
-# one (``weight_block``).
-_VMEM_BUDGET_BYTES = 16 << 20
+# The largest working set a cell's call makes within the budget a kernel gets
+# unasked (``ops/_chip.py`` says why no limit is named), as
+# ``_working_set_bytes`` counts it, is Keye-VL's 2048 x 768 bfloat16 beside
+# 512 rows, 15.2 MB; the count is an upper bound (the float32 product is not
+# held whole beside the accumulator): Kimi-Linear's 1024 x 2304 at 128 rows
+# counts 13.5 MB and, compiled for a v5e, needs 11 MiB.  Beside rows of 4 MiB
+# it is not one (``weight_block``).
+
 # a tile's rows from which ``weight_block`` is wary: 512 rows of 4096 and, PR
 # 67's cell, 256 of 7168 (3.5 MiB)
 _LONG_ROWS_BYTES = 7 << 19

@@ -39,11 +39,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["kda_update", "block"]
+from ompi_tpu.ops._chip import _VMEM_BUDGET_BYTES
 
-# What the kernel's blocks may take of VMEM: what Mosaic gives a kernel that
-# names no limit (``ops/grouped_matmul.py`` says why none is named).
-_VMEM_BUDGET_BYTES = 16 << 20
+__all__ = ["kda_update", "block"]
 
 
 def _working_set_bytes(heads: int, K: int) -> int:

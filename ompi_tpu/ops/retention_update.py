@@ -48,11 +48,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ompi_tpu.ops._chip import _VMEM_BUDGET_BYTES
+
 __all__ = ["retention_update", "block"]
 
-# What the kernel's blocks may take of VMEM: what Mosaic gives a kernel that
-# names no limit (``ops/grouped_matmul.py`` says why none is named).
-_VMEM_BUDGET_BYTES = 16 << 20
 # Rows of the state a sweep in VMEM takes at a time: 16 registers of state,
 # and of the columns a tile of 128 lanes, eight of them a sublane tile.
 _SWEEP = 128

@@ -49,22 +49,6 @@ ms beside 1.046 of a kernel that only copies the same blocks; with ``y``
 summed over lanes by the XLU 2.26 ms, by rolls and adds 12.1: ``PERF.md``
 section 6, PR 68.)
 
-What this shares with the two float32 state kernels, and what a fourth
-should factor out first (``ROADMAP.md`` D24): with ``retention_update.py``
-the state as an aliased operand inside a stack whose layer is a prefetched
-scalar that the index maps read (here a layer's own buffer is a stack of
-one), the budget and a ``block`` rule from static facts that returns None
-where the ``jax.numpy`` form runs, and sweeps unrolled in python; with
-``kda_update.py`` a block of one sequence's heads and the in-VMEM transpose
-of the vectors that multiply along sublanes.  All three repeat
-``_VMEM_BUDGET_BYTES``, a ``_working_set_bytes`` that counts every block
-twice, the "most that fits, a divisor, whole tiles" search of ``block``, the
-``ValueError`` of a shape that does not tile and the ``(…, state) ->
-(result, state)`` call with ``input_output_aliases``: a fourth kernel should
-start by moving those into one ``ops/_state_pass.py`` (the budget, the
-search over divisors, the aliased ``pallas_call`` with an optional
-prefetched layer) and keep only its body and its layout here.
-
 No backward pass (a decoder's step has none).  Like the other kernels here
 it always compiles for the TPU; :func:`block` says where a caller takes it.
 """
@@ -76,11 +60,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ompi_tpu.ops._chip import _VMEM_BUDGET_BYTES
+
 __all__ = ["ssm_update", "block"]
 
-# What the kernel's blocks may take of VMEM: what Mosaic gives a kernel that
-# names no limit (``ops/grouped_matmul.py`` says why none is named).
-_VMEM_BUDGET_BYTES = 16 << 20
 # The state's depth: one tile of lanes, the shape the kernel is swept on.
 _LANES = 128
 # Rows of the numbers that are one a head (the decay, ``B . C``, zeros), of

@@ -361,7 +361,7 @@ def test_on_tpus_both_paths_take_the_kernels_and_read_the_same(monkeypatch):
     cached step through ``selected_attention``, a K/V head a sequence of its
     own under its own mask, and both read what the ``jax.numpy`` forms
     read."""
-    from ompi_tpu.models import kda
+    from ompi_tpu.ops import _chip
     from ompi_tpu.models.block_select import BlockSelect
     from ompi_tpu.ops import masked_attention as masked
     from ompi_tpu.ops import selected_attention as selected
@@ -397,7 +397,7 @@ def test_on_tpus_both_paths_take_the_kernels_and_read_the_same(monkeypatch):
         real = getattr(module, name)
         monkeypatch.setattr(module, name, lambda *a, _f=real, _n=name:
                             calls.append(_n) or _f(*a))
-    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: True)
+    monkeypatch.setattr(_chip, "_traced_for_tpus", lambda: True)
     got, *_ = jax.jit(
         lambda q, k, v: block_select.attend(bs, q, k, v))(q, k, v)
     assert calls == ["masked_attention"] * 2

@@ -20,6 +20,7 @@ import pytest
 from benchmarks import controls_kimi_linear
 from benchmarks.lib import cells, program
 from ompi_tpu.models import kda
+from ompi_tpu.ops import _chip
 from ompi_tpu.ops import kda_update as kernel_module
 from ompi_tpu.ops.kda_update import block, kda_update
 from tests.parallel.compiled import _pallas_calls
@@ -50,7 +51,7 @@ def _case(batch, heads, seed=0, beta=None):
 
 def _jnp_form(*args):
     """``kda.update`` off the TPU: the form the kernel stands in for."""
-    assert not kda._traced_for_tpus()
+    assert not _chip._traced_for_tpus()
     return kda.update(*args)
 
 
@@ -123,7 +124,7 @@ def _kernel_under_update(monkeypatch):
         calls.append(args[0].shape)
         return kernel(*args)
 
-    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: True)
+    monkeypatch.setattr(_chip, "_traced_for_tpus", lambda: True)
     monkeypatch.setattr(kernel_module, "kda_update", counted)
     return calls
 
@@ -266,7 +267,7 @@ def test_off_the_tpu_update_is_the_jnp_form():
     seen = []
 
     def local(*args):
-        seen.append(kda._traced_for_tpus())
+        seen.append(_chip._traced_for_tpus())
         return kda.update(*args)
 
     mapped = jax.shard_map(local, mesh=mesh, in_specs=P(), out_specs=P(),

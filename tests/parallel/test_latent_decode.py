@@ -4,7 +4,7 @@ absorbed step it stands in for (``models/mla.mixer``'s ``carry`` branch): the
 kernel alone, the mixer told that it is traced for TPUs, a whole decoder
 (prefill, then cached steps through the kernel), and the rule that says
 which form a step takes (``latent_decode.tiles`` beside
-``kda._traced_for_tpus``), read from the benchmark's own configuration and
+``_chip._traced_for_tpus``), read from the benchmark's own configuration and
 traffic files.  Agreement and control flow only: nothing here is a time.
 
 Both sides are float32 and differ in the order of their sums alone (a
@@ -20,9 +20,10 @@ import numpy as np
 import pytest
 
 from benchmarks.lib import cells, program
-from ompi_tpu.models import kda, mla, plan
+from ompi_tpu.models import mla, plan
 from ompi_tpu.models import transformer as tfm
 from ompi_tpu.models.decode import make_decoder
+from ompi_tpu.ops import _chip
 from ompi_tpu.ops import latent_decode as kernel_module
 from ompi_tpu.ops.latent_decode import latent_decode, tiles
 from tests.benchmarks import test_harness
@@ -113,7 +114,7 @@ def test_the_mixer_takes_the_kernel_on_tpus_where_the_cache_tiles(
     # a jit of its own each: one of ``steps`` itself would hand the first
     # trace back to the second
     want = jax.jit(lambda *a: steps(*a))(lp, h, cache)
-    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: True)
+    monkeypatch.setattr(_chip, "_traced_for_tpus", lambda: True)
     traced = jax.jit(lambda *a: steps(*a))
     assert "latent_decode" in str(traced.trace(lp, h, cache).jaxpr)
     for (got, got_cache), (out, out_cache) in zip(traced(lp, h, cache), want):
@@ -149,7 +150,7 @@ def test_a_decoder_through_the_kernel_gives_the_jnp_decoders_logits(
     monkeypatch.setattr(kernel_module, "_BLOCK", 16)    # a cache of 48: three
     want_tokens, want = make_decoder(cfg, mesh, max_new=max_new,
                                      keep_logits=2)(params, prompts)
-    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: True)
+    monkeypatch.setattr(_chip, "_traced_for_tpus", lambda: True)
     decoder = make_decoder(cfg, mesh, max_new=max_new, keep_logits=2)
     table = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
              for k, v in params.items()}
@@ -208,7 +209,7 @@ def test_which_form_each_latent_cells_step_takes(monkeypatch, workload, tiny,
                                                  tpus, positions, kernel):
     step, args = _latent_step(workload, tiny)
     assert args[2].shape[1] == positions
-    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: tpus)
+    monkeypatch.setattr(_chip, "_traced_for_tpus", lambda: tpus)
     jaxpr = str(jax.jit(step).trace(*args).jaxpr)
     assert ("latent_decode" in jaxpr) == kernel
 

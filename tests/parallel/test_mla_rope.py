@@ -19,9 +19,10 @@ import numpy as np
 import pytest
 
 from benchmarks.lib import cells, program
-from ompi_tpu.models import kda, mla, plan
+from ompi_tpu.models import mla, plan
 from ompi_tpu.models import transformer as tfm
 from ompi_tpu.models.decode import make_decoder
+from ompi_tpu.ops import _chip
 from ompi_tpu.ops import latent_attention
 from tests.parallel.compiled import _cell, _pallas_calls
 
@@ -317,7 +318,7 @@ def test_the_mixer_takes_the_kernel_by_the_rule(monkeypatch, T, H, run):
         h = jnp.asarray(np.random.default_rng(T).normal(size=h.shape),
                         jnp.float32)
         want, want_rows = jax.jit(lambda lp, h: mla.mixer(cfg, lp, h))(lp, h)
-    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: True)
+    monkeypatch.setattr(_chip, "_traced_for_tpus", lambda: True)
     assert T >= mla.KERNEL_FROM
     (call,) = _pallas_calls(traced())
     assert call.params["name"] == "latent_attention"
@@ -345,7 +346,7 @@ def test_the_gradient_through_the_kernel_is_the_jnp_forms(monkeypatch):
         return (out * g).sum() + rows.sum()
 
     want = jax.jit(jax.grad(loss, (0, 1)))(lp, h)
-    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: True)
+    monkeypatch.setattr(_chip, "_traced_for_tpus", lambda: True)
     grad = jax.jit(jax.grad(loss, (0, 1)))
     assert list(_pallas_calls(grad.trace(lp, h).jaxpr))
     got = grad(lp, h)

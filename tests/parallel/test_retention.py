@@ -279,7 +279,7 @@ def test_which_update_each_retention_cell_takes(tpu, changes, takes,
     layer and step; anywhere else the state is read by one float32 product
     on the matrix unit (a sum of products makes the compiler copy the
     layer's state out of the stack first), a barrier, then the write."""
-    from ompi_tpu.models import kda
+    from ompi_tpu.ops import _chip
     from ompi_tpu.ops import retention_update
 
     cfg, shape = _cell_state(**changes)
@@ -292,7 +292,7 @@ def test_which_update_each_retention_cell_takes(tpu, changes, takes,
     assert retention_update.block(tpu, cfg.retention.state_dtype,
                                   D, d) == takes
     # and the core asks that rule of the stack it is handed, and nothing else
-    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: tpu)
+    monkeypatch.setattr(_chip, "_traced_for_tpus", lambda: tpu)
     stack = jax.ShapeDtypeStruct((cfg.n_layers, *shape),
                                  cfg.retention.state_dtype)
     if takes:

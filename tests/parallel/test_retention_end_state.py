@@ -15,7 +15,8 @@ import numpy as np
 import pytest
 
 from benchmarks import controls_brumby
-from ompi_tpu.models import kda, retention
+from ompi_tpu.models import retention
+from ompi_tpu.ops import _chip
 from ompi_tpu.ops import retention_end_state as kernel_module
 from ompi_tpu.ops.retention_end_state import ROWS, retention_end_state
 from tests.parallel.compiled import _pallas_calls
@@ -124,7 +125,7 @@ def test_tiles_says_no_and_the_kernel_refuses(case):
 def on_tpus(monkeypatch):
     """``chunked`` told that it is traced for TPUs, so that a prefill takes
     both kernels (which the suite's interpret mode runs here)."""
-    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: True)
+    monkeypatch.setattr(_chip, "_traced_for_tpus", lambda: True)
 
 
 def test_a_prefill_on_tpus_forms_its_state_in_the_kernel_once(on_tpus):

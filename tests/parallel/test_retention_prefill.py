@@ -16,7 +16,8 @@ import numpy as np
 import pytest
 
 from benchmarks import controls_brumby
-from ompi_tpu.models import kda, retention
+from ompi_tpu.models import retention
+from ompi_tpu.ops import _chip
 from ompi_tpu.ops import retention_prefill as kernel_module
 from ompi_tpu.ops.retention_prefill import ROWS, retention_prefill
 from tests.parallel.compiled import _pallas_calls
@@ -70,7 +71,7 @@ def _every_position(q, k, v, logg):
 def on_tpus(monkeypatch):
     """``chunked`` told that it is traced for TPUs, so that the rule takes
     the kernel (which the suite's interpret mode runs here)."""
-    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: True)
+    monkeypatch.setattr(_chip, "_traced_for_tpus", lambda: True)
 
 
 NEAR_HALF, MIXED, NEAR_ONE = (0.5, 0.6), (0.5, 0.99999), (0.999, 0.99999)
@@ -132,7 +133,7 @@ def test_the_rule_reads_static_facts_alone(case, monkeypatch):
     assert retention.CROSSOVER % ROWS == 0
     assert retention.CROSSOVER <= kernel_module.MAX_ROWS
     # and ``chunked`` follows it: the kernel once, or today's scan
-    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: tpu)
+    monkeypatch.setattr(_chip, "_traced_for_tpus", lambda: tpu)
     G, R = 2, 3
     shapes = [jax.ShapeDtypeStruct(s, jnp.float32) for s in (
         (1, T, G * R, width), (1, T, G, width), (1, T, G, width), (1, T, G))]
@@ -160,7 +161,7 @@ def test_without_the_flag_chunked_is_the_form_it_was(on_tpus):
         (1, 512, 4, d), (1, 512, 2, d), (1, 512, 2, d), (1, 512, 2))]
     on = str(jax.make_jaxpr(lambda *a: retention.chunked(*a, 256, EPS))(
         *shapes))
-    kda._traced_for_tpus = lambda: False        # the fixture puts it back
+    _chip._traced_for_tpus = lambda: False      # the fixture puts it back
     assert on == str(jax.make_jaxpr(
         lambda *a: retention.chunked(*a, 256, EPS))(*shapes))
 
@@ -227,7 +228,7 @@ def test_a_planted_fault_changes_the_prefill_under_the_direct_form(
     [sound] = _sound
     with controls_brumby.planted(fault):
         faulty = _prefill(cfg, *args)
-        kda._traced_for_tpus = lambda: False    # the fixture puts it back
+        _chip._traced_for_tpus = lambda: False  # the fixture puts it back
         faulty_chunked = _prefill(cfg, *args)
     flat = jax.tree.leaves
     assert max(error(a, b) for a, b in zip(flat(faulty), flat(sound))) > (

@@ -130,7 +130,7 @@ def test_cell_8s_prefill_reads_a_prompt_directly_and_expands_no_query(
 def test_the_trainers_program_of_cell_8_keeps_the_chunked_form(
         chip, for_the_chip):
     """``make_loss_fn`` of the cell's configuration, traced at the cell's
-    sizes under the same described chip (so ``kda._traced_for_tpus`` says
+    sizes under the same described chip (so ``_chip._traced_for_tpus`` says
     TPUs): a gradient may be asked, and the scan it traces is ``chunked``'s,
     with ``phi(q)`` in it and no kernel."""
     from benchmarks.lib import cells, program

@@ -19,7 +19,8 @@ import numpy as np
 import pytest
 
 from benchmarks import controls_brumby
-from ompi_tpu.models import kda, retention
+from ompi_tpu.models import retention
+from ompi_tpu.ops import _chip
 from ompi_tpu.ops import retention_update as kernel_module
 from ompi_tpu.ops.retention_update import block, retention_update
 from tests.parallel.compiled import _pallas_calls
@@ -129,7 +130,7 @@ def _kernel_under_core(monkeypatch):
         calls.append(stack.shape)
         return kernel(stack, *args)
 
-    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: True)
+    monkeypatch.setattr(_chip, "_traced_for_tpus", lambda: True)
     monkeypatch.setattr(kernel_module, "retention_update", counted)
     return calls
 
@@ -244,7 +245,7 @@ def test_off_the_tpu_the_core_is_the_jnp_form():
     (tier-1's decoders and ``test_retention.py`` run the form they ran)."""
     _ref, _shape, cfg, _mesh, _params = tiny()
     cfg = _wide(cfg)
-    assert not kda._traced_for_tpus()
+    assert not _chip._traced_for_tpus()
     stack = jnp.zeros((LAYERS, B, G, D, d), jnp.float32)
     assert not isinstance(retention._state_before(stack, 1),
                           retention.InPlace)

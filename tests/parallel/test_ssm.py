@@ -321,7 +321,7 @@ def test_a_prefill_through_the_kernel_then_cached_steps_give_the_references_logi
     prefill's scan is the kernel (interpret mode here), the states it hands
     over are carried by the cached steps, and every generated position's
     logits are the reference's."""
-    from ompi_tpu.models import kda
+    from ompi_tpu.ops import _chip
 
     config = copy.deepcopy(program.tiny(cells.resolve(CELL).config))
     config.update(mamba_d_ssm=256, mamba_n_heads=4, mamba_d_head=64,
@@ -335,7 +335,7 @@ def test_a_prefill_through_the_kernel_then_cached_steps_give_the_references_logi
     params = program.init_params(
         ref, config, program.param_shardings(config, cfg, mesh), seed=11)
     prompts = prompts_of(cfg, 2, 256)
-    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: True)
+    monkeypatch.setattr(_chip, "_traced_for_tpus", lambda: True)
     decoder = make_decoder(cfg, mesh, max_new=3, keep_logits=2)
     calls = [c.params["name"] for c in _pallas_calls(
         jax.make_jaxpr(decoder)(params, prompts).jaxpr)]

@@ -19,7 +19,8 @@ import numpy as np
 import pytest
 
 from benchmarks.lib import cells, program
-from ompi_tpu.models import kda, ssm
+from ompi_tpu.models import ssm
+from ompi_tpu.ops import _chip
 from ompi_tpu.ops import ssm_scan as kernel_module
 from ompi_tpu.ops.ssm_scan import CHUNK, ssm_scan
 from tests.parallel.compiled import _pallas_calls
@@ -293,7 +294,7 @@ def test_what_does_not_tile_is_refused():
 def on_tpus(monkeypatch):
     """``_mix`` told that it is traced for TPUs, so that the rule takes the
     kernel (which the suite's interpret mode runs here)."""
-    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: True)
+    monkeypatch.setattr(_chip, "_traced_for_tpus", lambda: True)
 
 
 def _leaves(seed=3):

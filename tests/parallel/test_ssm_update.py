@@ -22,7 +22,8 @@ import pytest
 
 from benchmarks import controls_granite_h
 from benchmarks.lib import cells, program
-from ompi_tpu.models import kda, ssm
+from ompi_tpu.models import ssm
+from ompi_tpu.ops import _chip
 
 N = 128
 CELLS = {13: "granite-4.0-h-small.decode-512-128-b160",
@@ -163,7 +164,7 @@ def test_the_kernel_under_mix_and_the_planted_state_that_reaches_it(
         faulty_jnp, _ = _steps(sz, lp, u, carry)
     calls = []
     kernel = kernel_module.ssm_update
-    monkeypatch.setattr(kda, "_traced_for_tpus", lambda: True)
+    monkeypatch.setattr(_chip, "_traced_for_tpus", lambda: True)
     monkeypatch.setattr(kernel_module, "ssm_update", lambda *args: (
         calls.append(args[0].shape), kernel(*args))[1])
     got, got_state = _steps(sz, lp, u, carry)
