@@ -362,7 +362,7 @@ def phase_decode(size: Size, devices) -> dict:
         kv_spec = P(None, "dp", None, "tp", None)
         prefill = jax.jit(jax.shard_map(
             lambda p, t: tfm._local_backbone(cfg, comm, p, t,
-                                             collect_kv=True)[1][1:],
+                                             collect_kv=True)[1],
             mesh=mesh, in_specs=(tfm.param_specs(P, cfg, mesh),
                                  P("dp", "sp")),
             out_specs=(kv_spec, kv_spec), check_vma=False))

@@ -254,13 +254,12 @@ def block(cfg, comm, lp, h, positions, carry=None, **how):
     ``h + a * attention_out_multiplier + ssm.mixer(x)``, both branches
     reading the one normed input; then ln2, the MLP or the experts.
 
-    ``carry`` None: whole sequences; returns ``(h, (aux, *collected))``, aux
-    the switch's balance term (0 without one) and ``collected`` what the
-    mixers hand a decoder, in :func:`mechanisms`' order.  ``carry = (stacks,
-    layer, pos)``: T == 1 against the carry, a list of each mechanism's own
-    stacks; ``lp``'s dropless expert leaves (``moe.EXPERT_LEAVES``) are then
-    the whole stacks over layers, which ``routed_moe`` indexes by ``layer``.
-    Returns ``(h, stacks)``.
+    ``carry`` None: whole sequences; returns ``(h, collected)``, a tuple of
+    what the mixers hand a decoder, in :func:`mechanisms`' order.  ``carry =
+    (stacks, layer, pos)``: T == 1 against the carry, a list of each
+    mechanism's own stacks; ``lp``'s expert leaves (``moe.EXPERT_LEAVES``)
+    are then the whole stacks over layers, which ``routed_moe`` indexes by
+    ``layer``.  Returns ``(h, stacks)``.
 
     Runs while a program is traced: what that costs the host is a
     ``trace.layer`` span of its record (``core/scopes.py``), kind "block"."""
@@ -271,8 +270,6 @@ def block(cfg, comm, lp, h, positions, carry=None, **how):
 
 
 def _block(cfg, comm, lp, h, positions, carry, **how):
-    import jax.numpy as jnp
-
     from ompi_tpu.core.scopes import scope
     from ompi_tpu.models import transformer as tfm
 
@@ -296,10 +293,8 @@ def _block(cfg, comm, lp, h, positions, carry, **how):
         with scope("attn_proj"):
             h = h + a * hy.attention_out_multiplier + s
     if cfg.moe_experts:
-        # the switch over "ep" (tp ranks replicate the expert compute), or
-        # the dropless experts, which a cached step gets as whole stacks
-        h, aux = tfm._moe_ffn_tail(
-            cfg, h, lp, comm, layer=layer if cfg.moe_top_k else None)
+        # the routed experts, which a cached step gets as whole stacks
+        h = tfm._moe_ffn_tail(cfg, h, lp, comm, layer=layer)
     else:
         # a hybrid block's MLP is gated under its multipliers, a retention
         # layer's plainly
@@ -309,11 +304,9 @@ def _block(cfg, comm, lp, h, positions, carry, **how):
                                 gated=gated, weights=how.get("weights"))
     if carry is not None:
         return h, [own] if hy is None else [own, states]
-    if not cfg.moe_experts:
-        aux = jnp.zeros((), jnp.float32)
     if hy is not None:      # the states as the carry stores them
         own += (states[0], states[1].astype(hy.state_dtype))
-    return h, (aux, *own)
+    return h, tuple(own)
 
 
 # ---- attention alone in a layer: the kind "attention" of a plan -------------

@@ -37,15 +37,11 @@ holds and a psum over tp completes them (``transformer._lookup``), and it
 makes the logits of its own rows, which are gathered over tp
 (``transformer._whole_vocab``), so greedy argmax, sampling and
 ``keep_logits`` see the full vocab.  Sequence parallelism is a
-training-time layout — decode requires sp == 1.  MoE configs route each
-generated token through the same layer as training and prefill.  The top-1
-switch (``moe_top_k == 0``) computes its capacity per single-token step (B
-tokens), so under a binding capacity the drop pattern can differ from a
-full-sequence forward — cached and full paths agree exactly whenever
-capacity doesn't bind.  The dropless path (``moe_top_k >= 1``) has no
-capacity: a token's experts and their weights depend on that token alone,
-so the cached step and the full forward agree at any batch, up to the order
-of summation.
+training-time layout — decode requires sp == 1.  Routed configurations
+(``moe_top_k >= 1``) send each generated token through the same layer as
+training and prefill; the routing has no capacity, so a token's experts and
+their weights depend on that token alone, and the cached step and the full
+forward agree at any batch, up to the order of summation.
 """
 
 from __future__ import annotations
@@ -53,10 +49,6 @@ from __future__ import annotations
 import contextlib
 import functools
 
-# ``_qk_norm`` is ``transformer``'s, which ``block.mixer`` calls; the name is
-# kept here for ``benchmarks/controls_keye_vl2.py``, which plants its fault
-# under both names (ROADMAP.md D23)
-from ompi_tpu.models.transformer import _qk_norm  # noqa: F401
 from ompi_tpu.models.transformer import (TransformerConfig, _head, _rmsnorm,
                                          layer_leaves, param_specs)
 from ompi_tpu.parallel.moe import EXPERT_LEAVES
@@ -81,8 +73,8 @@ def make_decoder(cfg: TransformerConfig, mesh, max_new: int,
 
     Greedy decode by default: prefill through the training backbone (what
     each layer's mixers hand over collected), then ``max_new`` single-token
-    steps over the static carry.  Requires sp == 1; dense, switch-MoE,
-    dropless top-k MoE, hybrid (``models/ssm.py``), indexed
+    steps over the static carry.  Requires sp == 1; dense, routed
+    (dropless top-k) MoE, hybrid (``models/ssm.py``), indexed
     (``models/sparse_index.py``), planned (``models/plan.py``) and
     power-retention (``models/retention.py``) configs are supported (MoE
     routes each token through the same layer as training).
@@ -196,7 +188,7 @@ def _halves(cfg: TransformerConfig, mesh, max_new: int,
         def one(g, carry):
             last, stacks = carry
             rows = lax.dynamic_slice_in_dim(prompt, g * group, group)
-            h, (_aux, *collected) = tfm._local_backbone(
+            h, collected = tfm._local_backbone(
                 cfg, comm, params, rows, collect_kv=True, forward_only=True)
             stacks = carried(collected, Tp + max_new, stacks, g=g,
                              group=group)
@@ -216,7 +208,7 @@ def _halves(cfg: TransformerConfig, mesh, max_new: int,
         # ---- prefill: the training backbone, the mixers' states collected
         with scope("prefill"):
             if hy is None and cfg.plan is None and not cfg.prefill_tokens:
-                h, (_aux, *collected) = tfm._local_backbone(
+                h, collected = tfm._local_backbone(
                     cfg, comm, params, prompt, collect_kv=True,
                     forward_only=True)
                 stacks = carried(collected, Tp + max_new)

@@ -482,7 +482,7 @@ def _mlp(cfg, comm, params, layer: int, kind: str, h, branch: bool = False):
           **{k: params[k][at] for k in (*ROUTER_LEAVES, *SHARED_LEAVES)
              if k in params}}
     return tfm._moe_ffn_tail(cfg, h, lp, comm, layer=at,
-                             residual=not branch)[0]
+                             residual=not branch)
 
 
 def _row(cfg, comm, params, layer: int, mixer: str, mlp: str, lp, h,
@@ -562,8 +562,7 @@ def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
              grad_axes=None, forward_only: bool = False):
     """``transformer._local_backbone`` of a configuration with a plan: the
     per-device forward through the last norm, a python loop over the plan.
-    Returns ``(h, aux)`` (aux 0: the dropless experts have no balance term),
-    or with ``collect_kv`` ``(h, (aux, *states))``, the states as
+    Returns ``h``, or with ``collect_kv`` ``(h, states)``, the states as
     :func:`carry` orders and shapes them but what grows as long as the
     sequences: a latent layer's rows ``(1, B, T, kv_rank + rope)``, a KDA
     layer's convolution inputs and matrix state after the last position.
@@ -574,7 +573,6 @@ def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
     prefill): no gradient will be asked of this pass, so a kind may take a
     kernel that has no backward pass."""
     import jax
-    import jax.numpy as jnp
 
     from ompi_tpu.core.scopes import host, scope
     from ompi_tpu.models import transformer as tfm
@@ -615,10 +613,7 @@ def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
     h = tfm._rmsnorm(h, params["lnf"], cfg.norm_eps)
     if pl.logit_divisor != 1:
         h = h / pl.logit_divisor
-    aux = jnp.zeros((), jnp.float32)
-    if not collect_kv:
-        return h, aux
-    return h, (aux, *collected)
+    return (h, collected) if collect_kv else h
 
 
 def step(cfg, comm, params, h, states, pos):

@@ -47,19 +47,16 @@ class TransformerConfig:
     # shape and the mesh's platform (the pallas flash kernels, forward and
     # backward, on a mesh of TPUs from 2048 keys a device; the jnp path
     # elsewhere).
-    # MoE model family: >0 replaces every layer's dense FFN with a
-    # switch-MoE of this many experts, sharded over the mesh's "ep" axis
-    # (experts % ep == 0); the load-balancing aux loss joins the training
-    # loss with weight moe_aux_weight.
+    # MoE model family: >0 replaces every layer's dense FFN with routed
+    # experts behind a router this wide; ``moe_top_k`` says how many a token
+    # takes.
     moe_experts: int = 0
-    moe_capacity_factor: float = 1.25
-    moe_aux_weight: float = 0.01
-    # Experts a token: 0 keeps the top-1 switch above (capacity drops, the
-    # aux loss, experts over "ep").  k >= 1 routes every token to its k
-    # most probable experts and drops none (parallel/moe.routed_moe:
-    # sort by expert, grouped matmul, weighted sum back); no exchange is
-    # built (ep == 1) and no aux term joins the loss.  Every expert is then
-    # on every device, unless ``moe_held`` says which this device holds.
+    # Experts a token: k >= 1 routes every token to its k most probable
+    # experts and drops none (parallel/moe.routed_moe: sort by expert,
+    # grouped matmul, weighted sum back); no exchange is built (ep == 1).
+    # Every expert is on every device, unless ``moe_held`` says which this
+    # device holds.  0: a dense FFN, and beside ``moe_experts`` an error (a
+    # routed layer needs its k).
     moe_top_k: int = 0
     # Gated experts: down(silu(gate(x)) * up(x)) with a third leaf "w3"
     # (up) beside "w1" (gate) and "w2" (down), instead of w2(act(w1 x)).
@@ -283,8 +280,8 @@ def _rows_over_tp(cfg, mesh) -> bool:
 
 def param_specs(P, cfg: Optional[TransformerConfig] = None, mesh=None):
     """PartitionSpecs: attention weights tp-sharded Megatron-style, dense
-    FFN tp-sharded, MoE experts ep-sharded (replicated when the mesh has
-    no "ep" axis), everything else replicated.  A leaf's gradient is
+    FFN tp-sharded, everything else replicated, routed experts too (a cell
+    holds a share of them by ``moe_held``).  A leaf's gradient is
     summed over the axes its spec does not name (:func:`grad_sum_axes`):
     the train step issues that sum itself, a layer's leaves in that layer's
     backward and the others once after the loop over layers
@@ -321,12 +318,9 @@ def param_specs(P, cfg: Optional[TransformerConfig] = None, mesh=None):
     if cfg is not None and not cfg.tie_head:
         specs["head"] = table
     if cfg is not None and cfg.moe_experts:
-        has_ep = mesh is not None and "ep" in mesh.axis_names
-        specs["wg"] = P()
-        specs["w1"] = P(None, "ep", None, None) if has_ep else P()
-        specs["w2"] = P(None, "ep", None, None) if has_ep else P()
+        specs["wg"] = specs["w1"] = specs["w2"] = P()
         if cfg.moe_gated:
-            specs["w3"] = specs["w1"]
+            specs["w3"] = P()
     else:
         specs["w1"] = P(None, None, "tp")
         specs["w2"] = P(None, "tp", None)
@@ -614,54 +608,48 @@ def _rope(x, positions, impl: str = "jnp", theta=10_000):
 
 
 def _moe_ffn_tail(cfg, h, lp, comm, layer=None, residual: bool = True):
-    """Post-attention half of the MoE layer: ln2 → ep-sharded switch, or
-    dropless top-k routed experts (those this device holds: ``moe_held``)
-    and, with ``moe_shared``, the shared expert every token passes, the two
-    together times a plan's ``branch_factor`` →
-    residual (``models/block.block``'s and a plan's).  Returns (h, aux);
-    without ``residual`` what the layer adds to ``h`` in h's place (a plan's
-    branch, which lands elsewhere).
-    With ``layer``, the dropless path's expert leaves
-    (``moe.EXPERT_LEAVES``) are the whole stacks over layers and ``layer``
-    this layer's index in them (``routed_moe`` says why)."""
-    import jax.numpy as jnp
-
+    """Post-attention half of the MoE layer: ln2 → dropless top-k routed
+    experts (those this device holds: ``moe_held``) and, with
+    ``moe_shared``, the shared expert every token passes, the two together
+    times a plan's ``branch_factor`` → residual (``models/block.block``'s
+    and a plan's).  Returns h; without ``residual`` what the layer adds to
+    ``h`` in h's place (a plan's branch, which lands elsewhere).
+    With ``layer``, the expert leaves (``moe.EXPERT_LEAVES``) are the whole
+    stacks over layers and ``layer`` this layer's index in them
+    (``routed_moe`` says why)."""
     from ompi_tpu.core.scopes import scope
     from ompi_tpu.ops import _chip
-    from ompi_tpu.parallel.moe import EXPERT_LEAVES, routed_moe, switch_moe
+    from ompi_tpu.parallel.moe import EXPERT_LEAVES, routed_moe
 
+    if not cfg.moe_top_k:
+        raise ValueError(
+            f"moe_experts={cfg.moe_experts} needs moe_top_k >= 1, the "
+            f"experts a token takes; got moe_top_k={cfg.moe_top_k}")
+    if int(dict(comm.mesh.shape).get("ep", 1)) > 1:
+        raise ValueError(
+            f"moe_top_k={cfg.moe_top_k} routes without drops, so the "
+            f"exchange over ep={comm.mesh.shape['ep']} would be ragged: not "
+            f"built; keep every expert on the device (ep == 1)")
     with scope("ffn"):
         x = _rmsnorm(h, lp["ln2"], cfg.norm_eps)
-        if cfg.moe_top_k:
-            if int(dict(comm.mesh.shape).get("ep", 1)) > 1:
-                raise ValueError(
-                    f"moe_top_k={cfg.moe_top_k} routes without drops, so "
-                    f"the exchange over ep={comm.mesh.shape['ep']} would be "
-                    f"ragged: not built; keep every expert on the device "
-                    f"(ep == 1), or use the top-1 switch (moe_top_k=0)")
-            weights = {k: lp[k] for k in ("wg", *EXPERT_LEAVES) if k in lp}
-            if cfg.moe_select_bias:
-                weights["wgb"] = lp["wgb"]
-            # the pallas kernel where the trace is for TPUs (attached, or
-            # described for a compile); XLA's ragged_dot anywhere else
-            mo = routed_moe(x, weights, cfg.moe_top_k, gated=cfg.moe_gated,
-                            act=cfg.moe_act, layer=layer,
-                            kernel=_chip._traced_for_tpus(),
-                            renorm=cfg.moe_norm_topk, score=cfg.moe_score,
-                            scale=cfg.moe_scale, held=cfg.moe_held,
-                            zero=cfg.moe_zero,
-                            **({"groups": cfg.moe_groups} if cfg.moe_groups
-                               else {}))
-            if cfg.moe_shared:
-                mo = mo + _shared_expert(x, lp, cfg.moe_act)
-            if cfg.plan is not None and cfg.plan.branch_factor != 1:
-                mo = mo * cfg.plan.branch_factor
-            return h + mo if residual else mo, jnp.zeros((), jnp.float32)
-        mo, aux = switch_moe(
-            comm, x, {"wg": lp["wg"], "w1": lp["w1"], "w2": lp["w2"]},
-            axis="ep", capacity_factor=cfg.moe_capacity_factor,
-            with_aux=True)
-        return h + mo if residual else mo, aux
+        weights = {k: lp[k] for k in ("wg", *EXPERT_LEAVES) if k in lp}
+        if cfg.moe_select_bias:
+            weights["wgb"] = lp["wgb"]
+        # the pallas kernel where the trace is for TPUs (attached, or
+        # described for a compile); XLA's ragged_dot anywhere else
+        mo = routed_moe(x, weights, cfg.moe_top_k, gated=cfg.moe_gated,
+                        act=cfg.moe_act, layer=layer,
+                        kernel=_chip._traced_for_tpus(),
+                        renorm=cfg.moe_norm_topk, score=cfg.moe_score,
+                        scale=cfg.moe_scale, held=cfg.moe_held,
+                        zero=cfg.moe_zero,
+                        **({"groups": cfg.moe_groups} if cfg.moe_groups
+                           else {}))
+        if cfg.moe_shared:
+            mo = mo + _shared_expert(x, lp, cfg.moe_act)
+        if cfg.plan is not None and cfg.plan.branch_factor != 1:
+            mo = mo * cfg.plan.branch_factor
+        return h + mo if residual else mo
 
 
 def _shared_expert(x, lp, act: str = "gelu"):
@@ -721,10 +709,9 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     unembed matmul): a ``lax.scan`` of ``models/block.block`` over the layers
     (with a plan ``models/plan.backbone``'s loop).
 
-    tokens: (B/dp, S/sp) int32.  Returns (h (B/dp, S/sp, D) compute-dtype,
-    aux) — aux is the summed MoE load-balancing loss (0.0 for dense).
-    With ``collect_kv`` (a decoder's prefill) returns ``(h, (aux,
-    *collected))``: what the configuration's mechanisms
+    tokens: (B/dp, S/sp) int32.  Returns h (B/dp, S/sp, D) compute-dtype.
+    With ``collect_kv`` (a decoder's prefill) returns ``(h, collected)``:
+    what the configuration's mechanisms
     (``block.mechanisms``) hand a decoder, each state stacked over layers
     with the sequences on axis 1, in the mechanisms' order: the post-rope k
     and v (L, B, T, Hkv/tp, hd), then an index's keys or a hybrid block's
@@ -788,7 +775,7 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
         h, ys = blk.block(cfg, comm, lp, h, positions, impl=impl,
                           weights=None if grad_axes is None else weights,
                           forward_only=forward_only)
-        return h, (ys if collect_kv else ys[0])
+        return h, (ys if collect_kv else None)
 
     layer_params = {k: params[k] for k in layer_leaves(cfg)}
     if cfg.remat in (True, "full"):
@@ -809,20 +796,16 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     h = _rmsnorm(h, params["lnf"], cfg.norm_eps)
     if hy is not None:
         h = h * hy.lm_head_multiplier
-    if collect_kv:
-        aux, *cached = ys
-        return h, (aux.sum(), *cached)
-    return h, ys.sum()
+    return (h, ys) if collect_kv else h
 
 
 def _local_forward(cfg: TransformerConfig, comm, params, tokens):
     """Per-device forward inside shard_map.
 
-    tokens: (B/dp, S/sp) int32.  Returns (logits (B/dp, S/sp, V) float32,
-    aux) — aux is the summed MoE load-balancing loss (0.0 for dense).
+    tokens: (B/dp, S/sp) int32.  Returns logits (B/dp, S/sp, V) float32.
     """
-    h, aux = _local_backbone(cfg, comm, params, tokens)
-    return _whole_vocab(cfg, _unembed(cfg, h, _head(cfg, params))), aux
+    h = _local_backbone(cfg, comm, params, tokens)
+    return _whole_vocab(cfg, _unembed(cfg, h, _head(cfg, params)))
 
 
 def _unembed(cfg: TransformerConfig, h, emb):
@@ -997,7 +980,7 @@ def _local_loss(cfg: TransformerConfig, comm, params, tokens,
         rest = {k: v for k, v in params.items()
                 if k not in layer_leaves(cfg)}
         params = {**params, **_sum_in_backward(rest, grad_axes, site=False)}
-    h, aux = _local_backbone(cfg, comm, params, tokens, grad_axes=grad_axes)
+    h = _local_backbone(cfg, comm, params, tokens, grad_axes=grad_axes)
     sum_axes = ("dp", "sp")
     with scope("loss"):
         head = _head(cfg, params)
@@ -1024,28 +1007,13 @@ def _local_loss(cfg: TransformerConfig, comm, params, tokens,
             total = lax.psum(local_sum, sum_axes)
             count = lax.psum(local_cnt, sum_axes)
     loss = total / count
-    if cfg.moe_experts and not cfg.moe_top_k:
-        # average the per-device balance loss over the whole mesh (tp/ep
-        # ranks see replicated tokens, so the mean is layout-invariant)
-        if comm.size == 1:
-            aux_mean = aux
-        else:
-            with coll("allreduce", comm.axes):
-                aux_mean = lax.psum(aux, comm.axes) / comm.size
-        loss = loss + cfg.moe_aux_weight * aux_mean
     if grad_axes is None:
         return loss
     # devices that compute the same part of the loss: those along the axes
     # the sums above do not run over
     copies = comm.mesh.size // math.prod(
         int(comm.mesh.shape[a]) for a in sum_axes)
-    objective = local_sum / (count * copies)
-    if cfg.moe_experts and not cfg.moe_top_k:
-        # through the mean's own psum, whose transpose sums this term's
-        # cotangent over the same devices: every device holds the term
-        objective = objective + (cfg.moe_aux_weight * aux_mean
-                                 / comm.mesh.size)
-    return objective, loss
+    return local_sum / (count * copies), loss
 
 
 def _mesh_comm(mesh):
@@ -1078,7 +1046,7 @@ def make_forward(cfg: TransformerConfig, mesh):
     comm = _mesh_comm(mesh)
 
     def local(params, tokens):
-        return _local_forward(cfg, comm, params, tokens)[0]  # drop aux
+        return _local_forward(cfg, comm, params, tokens)
 
     return jax.shard_map(
         local, mesh=mesh,

@@ -1,19 +1,15 @@
 """Parallelism layer: mesh construction, sharding helpers, and the
 long-context/parallelism primitives built on the framework's device
-collectives — all five dimensions:
+collectives:
 
 - **dp/sp/tp** — data, sequence (ring/Ulysses attention), and Megatron
   tensor parallelism (``attention``, ``layers``, the flagship model);
-- **ep** — switch-MoE expert parallelism over all_to_all (``moe``);
-- **pp** — GPipe pipeline schedule over ppermute (``pipeline``).
+- routed experts (``moe``): dropless, on the device that holds them.
 
 These are the TPU-native expression of the reference's communication
 patterns (SURVEY.md §5): ring attention is the segmented-ring allreduce
-shape (coll_base_allreduce.c:615) with double buffering; Ulysses and MoE
-dispatch are the pairwise alltoall (coll_base_alltoall.c:132); the
-pipeline handoff is the chain bcast's neighbor hop (coll_base_bcast.c:257).
+shape (coll_base_allreduce.c:615) with double buffering; Ulysses is the
+pairwise alltoall (coll_base_alltoall.c:132).
 """
 
 from ompi_tpu.parallel.mesh import make_mesh, mesh_shape_for
-from ompi_tpu.parallel.moe import moe_params, switch_moe
-from ompi_tpu.parallel.pipeline import gpipe

@@ -1,40 +1,18 @@
-"""Mixture-of-experts layers: a switch-style MoE over an ``ep`` mesh axis
-(:func:`switch_moe`, below) and dropless top-k routing over the experts
-this device holds (:func:`routed_moe`, at the end): every expert, or, told
-``held``, one device's share of them, with no exchange.
+"""Mixture-of-experts layers: dropless top-k routing over the experts this
+device holds (:func:`routed_moe`): every expert, or, told ``held``, one
+device's share of them, with no exchange.
 
-The fourth parallelism dimension (after dp/sp/tp): experts shard over
-``ep`` and tokens travel to their expert's device through
-``all_to_all`` — the communication pattern the reference realizes as
-pairwise alltoall (coll_base_alltoall.c:132) and this framework lowers to
-one fused ICI exchange each way.
-
-Design (top-1 "switch" routing, capacity-factor dispatch — the standard
-SPMD formulation, all shapes static):
-
-1. gate: ``logits = x @ wg`` → top-1 expert per token, gate prob ``p``.
-2. capacity ``C = ceil(tokens_per_device / E · capacity_factor)``; for
-   each expert, the first C tokens routed to it are kept (position by
-   cumulative count), the rest are DROPPED (standard switch semantics —
-   the residual connection carries dropped tokens unchanged).
-3. dispatch: one-hot combine matrix (T_local × E × C) built with
-   MXU-friendly one-hots; ``all_to_all`` ships (E, C, D) token blocks to
-   the expert-owning devices.
-4. each device runs its local experts' FFN on (E_local · ep, C, D).
-5. the inverse ``all_to_all`` + combine matrix returns outputs to their
-   source positions, scaled by the gate prob.
-
-Exact: a pure-numpy reference with identical routing reproduces the
-layer bit-for-bit (tests/parallel/test_moe.py).
+There is no exchange of tokens over an ``ep`` axis here: a cell holds a share
+of a wide router's experts by ``held`` and its tokens stay where they are.
+An exchange that ships tokens to their experts' devices starts from
+``DeviceCommunicator.alltoall_stacked`` (``ROADMAP.md`` M9).
 """
 
 from __future__ import annotations
 
 import math
-from typing import Optional
 
-__all__ = ["ACTIVATIONS", "switch_moe", "routed_moe", "moe_params",
-           "EXPERT_LEAVES"]
+__all__ = ["ACTIVATIONS", "routed_moe", "EXPERT_LEAVES"]
 
 # the experts' matrices in a parameter tree: gate (or the one up
 # projection), down, and with gated experts up
@@ -56,121 +34,6 @@ def _gelu(x):
 # what an ungated expert (and an ungated shared expert) puts between its two
 # matrices, by ``TransformerConfig.moe_act``'s word
 ACTIVATIONS = {"gelu": _gelu, "relu2": _relu2}
-
-
-def moe_params(rng, d_model: int, d_ff: int, n_experts: int,
-               dtype="float32"):
-    """Gate + per-expert FFN weights (experts stacked on axis 0)."""
-    import numpy as np
-
-    def w(*shape, scale=None):
-        scale = scale if scale is not None else (shape[-2] ** -0.5)
-        return rng.normal(0, scale, size=shape).astype(dtype)
-
-    return {
-        "wg": w(d_model, n_experts, scale=0.02),
-        "w1": w(n_experts, d_model, d_ff),
-        "w2": w(n_experts, d_ff, d_model),
-    }
-
-
-def switch_moe(comm, x, params, axis: str = "ep",
-               capacity_factor: float = 1.25,
-               capacity: Optional[int] = None,
-               with_aux: bool = False):
-    """Top-1 MoE layer inside shard_map: x (B, T, D) local tokens →
-    (B, T, D).  ``params['w1']/['w2']`` hold the LOCAL experts
-    (E_local = E / ep_size rows on each device); ``wg`` is replicated.
-
-    ``with_aux=True`` additionally returns the switch load-balancing
-    loss ``E · Σ_e f_e · p_e`` (fraction routed × mean gate prob per
-    expert, over THIS device's tokens) — add it to the training loss
-    scaled by ~1e-2 or experts collapse onto one device.
-
-    Call with ``axis=None`` (or an absent axis) for the single-device
-    degenerate case — routing and capacity behave identically, only the
-    all_to_all disappears.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from ompi_tpu.core.scopes import scope
-
-    B, T, D = x.shape
-    if axis in comm.mesh.axis_names and axis not in comm.axes:
-        raise ValueError(f"axis {axis!r} not bound to this communicator "
-                         f"(axes {comm.axes})")
-    ep = int(comm.mesh.shape[axis]) if axis in comm.mesh.axis_names else 1
-    e_local = params["w1"].shape[0]
-    E = e_local * ep
-    n_tok = B * T
-    if capacity is None:
-        capacity = max(1, math.ceil((n_tok / E) * capacity_factor))
-    C = capacity
-
-    xf = x.reshape(n_tok, D)
-    with scope("moe.route"):
-        logits = jnp.einsum("td,de->te", xf, params["wg"].astype(x.dtype),
-                            preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        expert = jnp.argmax(probs, axis=-1)                  # (n_tok,)
-        gate = jnp.take_along_axis(probs, expert[:, None], axis=1)[:, 0]
-
-        # position of each token within its expert's queue (0-based);
-        # tokens at position >= C are dropped
-        onehot = jax.nn.one_hot(expert, E, dtype=jnp.int32)  # (n_tok, E)
-        pos = jnp.cumsum(onehot, axis=0) * onehot        # 1-based in-slot
-        pos = pos.sum(axis=-1) - 1                           # (n_tok,)
-        keep = pos < C
-
-    with scope("moe.dispatch"):
-        # dispatch tensor (n_tok, E, C): MXU-friendly one-hot outer product
-        dis = (onehot.astype(x.dtype)[:, :, None]
-               * jax.nn.one_hot(jnp.where(keep, pos, C), C + 1,
-                                dtype=x.dtype)[:, None, :-1]
-               )                                             # (n_tok, E, C)
-        send = jnp.einsum("tec,td->ecd", dis, xf)            # (E, C, D)
-
-        if ep > 1:
-            # (E, C, D) → every device ends with (E_local·ep, C, D): the
-            # blocks of ITS experts from every source device
-            send = comm.alltoall_stacked(send.reshape(ep, e_local, C, D),
-                                         axis=axis)
-            # (ep, e_local, C, D): source-device-major blocks of my experts
-            recv = send.reshape(ep, e_local, C, D)
-        else:
-            recv = send.reshape(1, e_local, C, D)
-
-    with scope("moe.experts"):
-        # expert FFN on my local experts (batched over source devices)
-        w1 = params["w1"].astype(x.dtype)                # (e_local, D, F)
-        w2 = params["w2"].astype(x.dtype)                # (e_local, F, D)
-        h = jnp.einsum("secd,edf->secf", recv, w1,
-                       preferred_element_type=jnp.float32).astype(x.dtype)
-        h = jax.nn.gelu(h)
-        out = jnp.einsum("secf,efd->secd", h, w2,
-                         preferred_element_type=jnp.float32).astype(x.dtype)
-
-    with scope("moe.combine"):
-        if ep > 1:
-            # inverse exchange: give every source device back its tokens
-            out = comm.alltoall_stacked(out, axis=axis)
-        out = out.reshape(E, C, D)
-
-        # combine back to token positions, scaled by the gate prob; dropped
-        # tokens contribute zero (their residual path carries them)
-        y = jnp.einsum("tec,ecd->td", dis, out)
-        y = y * gate[:, None].astype(x.dtype)
-        y = y.reshape(B, T, D)
-    if not with_aux:
-        return y
-    with scope("moe.route"):
-        # switch load-balancing loss (Fedus et al.): differentiable through
-        # the mean gate prob; the routed fraction is the (stop-grad) signal
-        frac = jnp.mean(onehot.astype(jnp.float32), axis=0)      # (E,)
-        mean_p = jnp.mean(probs, axis=0)                         # (E,)
-        aux = E * jnp.sum(frac * mean_p)
-    return y, aux
 
 
 # Where a device holds few of a wide router's experts, ``routed_moe`` lays

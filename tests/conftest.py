@@ -17,10 +17,11 @@ if os.environ.get("OMPI_TPU_TEST_REAL") != "1":
     # kernel in interpret mode, and the machine code of that stand-in is
     # nothing a user runs: LLVM compiles it at level 1, not 3 (a whole run
     # on one 8-core box, six workers: 8066 -> 6993 test-seconds, 1404 ->
-    # 1229 s of wall, PR 72).  Level 0 is cheaper still, and its code
-    # rounds otherwise: the parameters that ``tests/benchmarks/
-    # test_controls.py`` pins by sha256 are drawn on this backend and come
-    # out other bits, so level 0 waits for a ``benchmark`` PR.  The HLO
+    # 1229 s of wall, PR 72).  Level 0 is cheaper still and nothing pins
+    # this backend's bits since PR 74, whose whole run at level 0 read 5015
+    # test-seconds beside 5134 at level 1 on one box: 2.3%, and one flake;
+    # declined in PR 75 (it removes nothing, and level-0 code rounds
+    # otherwise), so the flag stays at 1.  The HLO
     # passes are as they were, and libtpu's compiles for a described v5e are
     # the same bytes whatever the level.  A run against an attached chip
     # compiles for it as ever.

@@ -63,17 +63,15 @@ def _kernels(workload: str, tpus: bool) -> frozenset:
     from ompi_tpu.models import decode
 
     cell = cells.resolve(workload)
-    was, _chip._traced_for_tpus = _chip._traced_for_tpus, lambda: tpus
-    # a prefill's program is kept by configuration and mesh: traced anew
-    # under what this call says, and not kept for the next
-    decode._prefill_program.cache_clear()
-    try:
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(_chip, "_traced_for_tpus", lambda: tpus)
+        # a prefill's program is kept by configuration and mesh: traced anew
+        # under what this call says, and not kept for the next
+        decode._prefill_program.cache_clear()
         job = cell.runner.build(cell.config, cell.traffic,
                                 jax.devices()[:cell.chips])
         fn, args = job.programs()["decode_full"]
         jaxpr = jax.make_jaxpr(fn)(*args)
-    finally:
-        _chip._traced_for_tpus = was
         decode._prefill_program.cache_clear()
     return frozenset(c.params["name"] for c in _pallas_calls(jaxpr.jaxpr))
 
@@ -150,9 +148,8 @@ def test_the_models_ask_the_one_home_and_nothing_else():
         if not name.endswith(".py"):
             continue
         with open(os.path.join(MODELS, name)) as f:
-            for n, line in enumerate(f, 1):
-                if platform.search(line) or bound.search(line):
-                    found.append((name, line.strip()))
+            found += [(name, line.strip()) for line in f
+                      if platform.search(line) or bound.search(line)]
     assert found == [
         ("transformer.py", 'tpus = mesh.devices.flat[0].platform == "tpu"')]
 

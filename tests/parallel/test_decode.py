@@ -165,19 +165,19 @@ def test_decode_rejects_sp():
 
 
 @pytest.mark.parametrize("mesh_shape,max_new,n_layers", [
-    pytest.param({"dp": 2, "sp": 1, "tp": 1, "ep": 4}, 4, 2, id="dp2ep4"),
-    pytest.param({"dp": 2, "sp": 1, "tp": 1, "ep": 4}, 1, 2, id="max_new1"),
-    pytest.param({"dp": 2, "sp": 1, "tp": 1, "ep": 4}, 2, 2, id="max_new2"),
-    pytest.param({"dp": 2, "sp": 1, "tp": 1, "ep": 4}, 4, 1, id="one_layer"),
+    pytest.param({"dp": 2, "sp": 1, "tp": 1, "ep": 1}, 4, 2, id="two_layers"),
+    pytest.param({"dp": 2, "sp": 1, "tp": 1, "ep": 1}, 1, 2, id="max_new1"),
+    pytest.param({"dp": 2, "sp": 1, "tp": 1, "ep": 1}, 2, 2, id="max_new2"),
+    pytest.param({"dp": 2, "sp": 1, "tp": 1, "ep": 1}, 4, 1, id="one_layer"),
     pytest.param({"dp": 2, "sp": 1, "tp": 1, "ep": 1}, 4, 3, id="dp2"),
     pytest.param({"dp": 1, "sp": 1, "tp": 2, "ep": 1}, 4, 3, id="tp2"),
 ])
 def test_moe_cached_decode_matches_full_forward(mesh_shape, max_new,
                                                 n_layers):
-    """Expert-parallel decode: same switch routing as training; with a
-    non-binding capacity the cached path reproduces the full forward
-    exactly."""
-    cfg = dataclasses.replace(CFG, moe_experts=4, moe_capacity_factor=4.0,
+    """A routed configuration's decode: the same dropless routing as
+    training (a token's experts depend on that token alone), so the cached
+    path reproduces the full forward."""
+    cfg = dataclasses.replace(CFG, moe_experts=4, moe_top_k=2,
                               n_layers=n_layers)
     _assert_cached_equals_full(cfg, mesh_shape, max_new, prompt_len=6, seed=1)
 
@@ -209,7 +209,7 @@ def _cached_logits(cfg, mesh, params, tokens, prompt_len):
 
     def local(params, tokens):
         head = tfm._head(cfg, params).astype(cdt)
-        h, (_aux, ks, vs) = tfm._local_backbone(
+        h, (ks, vs) = tfm._local_backbone(
             cfg, comm, params, tokens[:, :prompt_len], collect_kv=True)
         pad = [(0, 0), (0, 0), (0, steps), (0, 0), (0, 0)]
         stacks = [[jnp.pad(ks, pad), jnp.pad(vs, pad)]]
@@ -403,8 +403,6 @@ def _retention():
     pytest.param(_hybrid(), id="hybrid"),
     pytest.param(dataclasses.replace(CFG, remat=False, n_kv_heads=2,
                                      index=_index()), id="indexed"),
-    pytest.param(dataclasses.replace(CFG, remat=False, moe_experts=4,
-                                     moe_capacity_factor=4.0), id="switch"),
     pytest.param(dataclasses.replace(OLMOE, n_kv_heads=2), id="dropless"),
     pytest.param(_retention(), id="retention"),
 ])
@@ -431,8 +429,8 @@ def test_the_blocks_two_forms_agree(cfg):
         cfg.hybrid.embedding_multiplier, cfg.hybrid.lm_head_multiplier)
 
     def local(params, tokens):
-        want, _aux = tfm._local_backbone(cfg, comm, params, tokens)
-        _h, (_aux, *collected) = tfm._local_backbone(
+        want = tfm._local_backbone(cfg, comm, params, tokens)
+        _h, collected = tfm._local_backbone(
             cfg, comm, params, tokens[:, :8], collect_kv=True)
         collected = iter(collected)
         stacks = [mechanism.carried(cfg, mesh, collected, 12)

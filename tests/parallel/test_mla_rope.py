@@ -142,7 +142,7 @@ def test_the_carry_holds_the_rotated_shared_key():
     def prefill(params, tokens):
         return plan.backbone(cfg, comm, params, tokens, collect_kv=True)[1]
 
-    _aux, *rows = jax.jit(jax.shard_map(
+    rows = jax.jit(jax.shard_map(
         prefill, mesh=mesh, in_specs=(tfm.param_specs(
             jax.sharding.PartitionSpec, cfg, mesh),
             jax.sharding.PartitionSpec()),

@@ -1,135 +1,11 @@
-"""Expert-parallel switch MoE: the device all_to_all dispatch must equal
-a pure-numpy reference with identical routing/capacity semantics."""
+"""Dropless top-k routing (``parallel/moe.routed_moe``) against a loop over
+experts in numpy, and what a routed configuration refuses."""
 
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-
-from ompi_tpu.mpi.device_comm import DeviceCommunicator
-from ompi_tpu.parallel.moe import moe_params, switch_moe
-
-
-def _oracle(x, params, capacity):
-    """Single-device oracle: switch_moe with ep=1 on a 1-device mesh —
-    the distributed layer must match it exactly (same math, plus two
-    all_to_alls that are pure data movement)."""
-    mesh = Mesh(np.array(jax.devices()[:1]), axis_names=("one",))
-    comm = DeviceCommunicator(mesh, ("one",))
-    fn = jax.shard_map(
-        lambda a: switch_moe(comm, a, params, axis="one",
-                             capacity=capacity),
-        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)
-    return np.asarray(jax.jit(fn)(x))
-
-
-@pytest.fixture(scope="module")
-def mesh_ep():
-    devs = np.array(jax.devices())
-    assert devs.size == 8
-    return Mesh(devs.reshape(8), axis_names=("ep",))
-
-
-def test_switch_moe_matches_single_device_oracle(mesh_ep):
-    """8-way expert parallelism: every device routes ITS tokens through
-    the global expert set via all_to_all; with replicated tokens the
-    result must equal the single-device computation."""
-    rng = np.random.default_rng(0)
-    B, T, D, F, E = 2, 16, 32, 64, 8
-    cap = 8
-    x = rng.normal(size=(B, T, D)).astype(np.float32)
-    full = moe_params(rng, D, F, E)
-
-    want = _oracle(x, full, cap)
-
-    comm = DeviceCommunicator(mesh_ep, ("ep",))
-    # shard experts over ep: device d owns expert d (E/ep = 1 local)
-    sharded = {"wg": full["wg"], "w1": full["w1"], "w2": full["w2"]}
-    fn = jax.shard_map(
-        lambda a, wg, w1, w2: switch_moe(
-            comm, a, {"wg": wg, "w1": w1, "w2": w2}, axis="ep",
-            capacity=cap),
-        mesh=mesh_ep,
-        in_specs=(P(), P(), P("ep"), P("ep")),
-        out_specs=P(), check_vma=False)
-    got = np.asarray(jax.jit(fn)(x, sharded["wg"], sharded["w1"],
-                                 sharded["w2"]))
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
-
-
-def test_switch_moe_capacity_drops_tokens(mesh_ep):
-    """With capacity 1 and many tokens per expert, dropped tokens must
-    contribute exactly zero (their residual path carries them)."""
-    rng = np.random.default_rng(1)
-    B, T, D, F, E = 1, 16, 8, 16, 2
-    x = rng.normal(size=(B, T, D)).astype(np.float32)
-    params = moe_params(rng, D, F, E)
-    tight = _oracle(x, params, capacity=1)
-    loose = _oracle(x, params, capacity=T)
-    # capacity 1 keeps at most E tokens' contributions
-    nz_tight = (np.abs(tight.reshape(-1, D)).sum(axis=1) > 1e-9).sum()
-    nz_loose = (np.abs(loose.reshape(-1, D)).sum(axis=1) > 1e-9).sum()
-    assert nz_tight <= E < nz_loose
-
-
-def test_switch_moe_differentiable(mesh_ep):
-    rng = np.random.default_rng(2)
-    B, T, D, F, E = 1, 8, 16, 32, 8
-    x = rng.normal(size=(B, T, D)).astype(np.float32)
-    params = moe_params(rng, D, F, E)
-    comm = DeviceCommunicator(mesh_ep, ("ep",))
-
-    def loss(x, wg, w1, w2):
-        fn = jax.shard_map(
-            lambda a, g, u, v: switch_moe(comm, a, {"wg": g, "w1": u,
-                                                    "w2": v}, axis="ep",
-                                          capacity=4),
-            mesh=mesh_ep, in_specs=(P(), P(), P("ep"), P("ep")),
-            out_specs=P(), check_vma=False)
-        return (fn(x, wg, w1, w2) ** 2).sum()
-
-    grads = jax.grad(loss, argnums=(1, 2, 3))(
-        x, params["wg"], params["w1"], params["w2"])
-    for g in grads:
-        assert np.isfinite(np.asarray(g)).all()
-
-
-def test_switch_moe_aux_loss():
-    """Load-balancing loss: 1.0 at perfect balance, larger when skewed,
-    and differentiable w.r.t. the gate weights."""
-    rng = np.random.default_rng(3)
-    B, T, D, F, E = 1, 16, 16, 32, 8
-    x = rng.normal(size=(B, T, D)).astype(np.float32)
-    params = moe_params(rng, D, F, E)
-    mesh = Mesh(np.array(jax.devices()[:1]), axis_names=("one",))
-    comm = DeviceCommunicator(mesh, ("one",))
-
-    def run(wg):
-        fn = jax.shard_map(
-            lambda a, g: switch_moe(comm, a, {"wg": g, "w1": params["w1"],
-                                              "w2": params["w2"]},
-                                    axis="one", capacity=T,
-                                    with_aux=True),
-            mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-            check_vma=False)
-        return fn(x, wg)
-
-    y, aux = run(params["wg"])
-    assert y.shape == (B, T, D)
-    # aux >= 1 always (Cauchy-Schwarz: E·Σ f_e·p_e minimized at balance)
-    assert float(aux) >= 0.99
-    # an extreme gate bias toward one expert drives aux toward E
-    skew = params["wg"].copy()
-    skew[:, 0] += 100.0
-    _, aux_skew = run(skew)
-    assert float(aux_skew) > float(aux)
-    g = jax.grad(lambda wg: run(wg)[1])(params["wg"])
-    assert np.isfinite(np.asarray(g)).all() and np.abs(np.asarray(g)).sum() > 0
-
-
-# ---- dropless top-k routing (routed_moe) -----------------------------------
 
 def _gelu_tanh(x):
     return 0.5 * x * (1 + np.tanh(np.sqrt(2 / np.pi) * (x + 0.044715 * x ** 3)))
@@ -356,5 +232,21 @@ def test_routed_moe_refuses_ep():
                                 d_ff=16, seq=16, attention="xla",
                                 moe_experts=4, moe_top_k=2, remat=False)
     with pytest.raises(ValueError, match="ragged"):
+        jax.jit(tfm.make_loss_fn(cfg, mesh)).lower(
+            tfm.init_params(cfg), np.zeros((2, 16), np.int32))
+
+
+def test_a_routed_configuration_without_moe_top_k_is_refused_by_name():
+    """``moe_experts`` without ``moe_top_k`` was the top-1 switch until PR
+    75; it is now an error that names the field to set, raised while the
+    program is traced."""
+    from ompi_tpu.models import transformer as tfm
+    from ompi_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh({"dp": 1, "sp": 1, "tp": 1}, devices=jax.devices()[:1])
+    cfg = tfm.TransformerConfig(vocab=64, d_model=32, n_heads=2, n_layers=1,
+                                d_ff=16, seq=16, attention="xla",
+                                moe_experts=4, remat=False)
+    with pytest.raises(ValueError, match=r"moe_top_k >= 1.*moe_top_k=0"):
         jax.jit(tfm.make_loss_fn(cfg, mesh)).lower(
             tfm.init_params(cfg), np.zeros((2, 16), np.int32))

@@ -1,11 +1,9 @@
-"""MoE flagship family: the dp×ep-parallel MoE transformer must produce
-the same loss as the identical model with experts unsharded, and train."""
+"""MoE flagship family: the routed (dropless top-k) transformer trains over
+dp with every expert on every device."""
 
 import numpy as np
-import pytest
 
 import jax
-from jax.sharding import Mesh
 
 from ompi_tpu.models import transformer as tfm
 from ompi_tpu.models.transformer import TransformerConfig
@@ -13,7 +11,7 @@ from ompi_tpu.parallel.mesh import make_mesh
 
 CFG = dict(vocab=128, d_model=64, n_heads=4, n_layers=2, d_ff=128,
            seq=32, attention="ring", compute_dtype="float32",
-           moe_experts=8, remat=False)
+           moe_experts=8, moe_top_k=2, remat=False)
 
 
 def _mesh(shape):
@@ -21,36 +19,9 @@ def _mesh(shape):
     return make_mesh(shape, devices=devs)
 
 
-def _loss(mesh, cfg, params, toks):
-    return float(jax.jit(tfm.make_loss_fn(cfg, mesh))(params, toks))
-
-
-def test_moe_model_ep_sharding_matches_unsharded():
-    cfg = TransformerConfig(**CFG)
-    params = tfm.init_params(cfg)
-    rng = np.random.default_rng(0)
-    toks = rng.integers(0, cfg.vocab, size=(4, cfg.seq)).astype(np.int32)
-
-    base = _loss(_mesh({"dp": 1, "sp": 1, "tp": 1}), cfg, params, toks)
-    ep8 = _loss(_mesh({"dp": 1, "sp": 1, "tp": 1, "ep": 8}), cfg, params,
-                toks)
-    assert np.isfinite(base)
-    # ep sharding is pure data movement: identical token sharding ⇒
-    # identical loss (routing, capacity, and aux are per source device)
-    np.testing.assert_allclose(ep8, base, rtol=2e-5)
-    dp2ep4 = _loss(_mesh({"dp": 2, "sp": 1, "tp": 1, "ep": 4}), cfg,
-                   params, toks)
-    dp2ep1 = _loss(_mesh({"dp": 2, "sp": 1, "tp": 1, "ep": 1}), cfg,
-                   params, toks)
-    np.testing.assert_allclose(dp2ep4, dp2ep1, rtol=2e-5)
-    # dp resharding legitimately shifts capacity/aux statistics a little
-    # (per-device queues + per-device balance loss) — bounded, not equal
-    np.testing.assert_allclose(dp2ep4, base, rtol=5e-3)
-
-
 def test_moe_model_trains():
     cfg = TransformerConfig(**CFG)
-    mesh = _mesh({"dp": 2, "sp": 1, "tp": 1, "ep": 4})
+    mesh = _mesh({"dp": 2, "sp": 1, "tp": 1, "ep": 1})
     params = tfm.init_params(cfg)
     step, init_opt = tfm.make_train_step(cfg, mesh, lr=1e-2)
     opt_state = init_opt(params)

@@ -157,7 +157,7 @@ def test_the_shares_of_two_and_the_identity_part_once_are_the_uncut_layer(
     ids = np.random.default_rng(15).integers(
         0, cfg.vocab, size=(2, 9)).astype(np.int32)
     uncut = jax.jit(lambda p: plan.backbone(
-        cfg, tfm._mesh_comm(mesh), p, ids)[0])(params)
+        cfg, tfm._mesh_comm(mesh), p, ids))(params)
     assert error(uncut, ref.forward(shape, params, ids)) < PARITY
 
 

@@ -86,43 +86,30 @@ def dvm_respawn(tmp_path):
         yield uri
 
 
-def test_two_jobs_one_vm_second_faster(dvm, tmp_path):
-    """Two jobs on one VM: the SAME daemons serve both (structural check
-    via daemon pids — no re-launch), and a warm submission beats a cold
-    tpurun of the identical job (min over two runs to damp load noise;
-    the ambient per-child python startup tax dominates both paths, so
-    the margin is the daemon spawn + tree wiring it skips)."""
+def test_two_jobs_one_vm_are_served_by_the_same_daemons(dvm):
+    """Two jobs on one VM: the SAME daemons serve both (the daemons' pids
+    before and after: no re-launch), each job's four ranks span both sim
+    hosts.  That a warm submission skips the daemons' spawn and the tree's
+    wiring is this structure; no wall clock is compared (two subprocess
+    times a twentieth apart failed under load: PERF.md section 7, PR 74)."""
     prog = ("import os; print('JOB', os.environ['OMPI_TPU_RANK'], "
             "os.environ.get('OMPI_TPU_FAKE_HOST'))")
-    # cold reference: full VM bring-up + job (the non-DVM path)
-    t0 = time.perf_counter()
-    cold = _tpurun("-np", "4", "--plm", "sim", "--hosts", "2", "--",
-                   sys.executable, "-c", prog)
-    cold_s = time.perf_counter() - t0
-    assert cold.returncode == 0, cold.stderr
-
     pids_before = [d["pid"] for d in json.loads(
         _tpurun("--dvm-ps", "--dvm-uri", dvm).stdout)["daemons"]]
 
-    warm = []
-    hosts = {}
     for _ in range(2):
-        t1 = time.perf_counter()
         r = _tpurun("--dvm-submit", "-np", "4", "--dvm-uri", dvm, "--",
                     sys.executable, "-c", prog)
-        warm.append(time.perf_counter() - t1)
         assert r.returncode == 0, r.stderr
         hosts = {ln.split()[1]: ln.split()[2]
                  for ln in r.stdout.splitlines() if "JOB" in ln}
         assert len(hosts) == 4
-    assert len(set(hosts.values())) == 2     # spans both sim hosts
+        assert len(set(hosts.values())) == 2     # spans both sim hosts
 
     pids_after = [d["pid"] for d in json.loads(
         _tpurun("--dvm-ps", "--dvm-uri", dvm).stdout)["daemons"]]
     assert pids_before == pids_after         # daemons persisted, no respawn
     assert all(p is not None for p in pids_before)
-    assert min(warm) < cold_s, (cold_s, warm)
-    print(f"cold {cold_s:.2f}s warm {[round(w, 2) for w in warm]}")
 
 
 def test_dvm_ps_shows_daemons_and_history(dvm):
