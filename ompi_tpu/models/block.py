@@ -159,9 +159,11 @@ def mixer(cfg, comm, lp, h, positions, carry=None, impl: str = "jnp",
     projection), ``wo``.  No rotary embedding where a plan's
     ``Attention.rope`` says so, and the scores at its ``Attention.scale``.
     ``lp``: the layer's leaves.
-    ``weights(x, *names)``, the train step's: ``(x, leaves)`` for the
-    matmuls that read ``x`` (``transformer._local_backbone`` says what it
-    ties to them); None: ``lp``'s own.
+    ``weights(x, *names)``, the train step's: ``(x, leaves)`` for the block
+    whose first matmuls read ``x`` (``transformer._local_backbone`` says
+    what it ties to them), and ``wo``'s product is then made and summed
+    over ``tp`` by halves (``row_parallel(by_halves=True)``); None: ``lp``'s
+    own.
 
     ``carry`` None: whole sequences, the layout's attention over ``sp`` or
     the index's (``forward_only``: no gradient will be asked, so it may take
@@ -184,6 +186,7 @@ def mixer(cfg, comm, lp, h, positions, carry=None, impl: str = "jnp",
         from ompi_tpu.models import sparse_index
     if cfg.retention is not None:
         from ompi_tpu.models import retention
+    staged = weights is not None
     weights = weights or (lambda x, *_names: (x, lp))
     cdt, hy, rt = h.dtype, cfg.hybrid, cfg.retention
     B, T, tp = h.shape[0], h.shape[1], int(comm.mesh.shape["tp"])
@@ -193,7 +196,7 @@ def mixer(cfg, comm, lp, h, positions, carry=None, impl: str = "jnp",
         # plants a wrong one there while a decoder is traced
         x = tfm._rmsnorm(h, lp["ln1"], cfg.norm_eps)
         xa = x if hy is None else x * hy.attention_in_multiplier
-        xa, w = weights(xa, "wq", "wk", "wv")
+        xa, w = weights(xa, "wo", "wq", "wk", "wv")
         q, k, v = (column_parallel(xa, w[name].astype(cdt))
                    for name in ("wq", "wk", "wv"))
         if cfg.qk_norm:
@@ -244,8 +247,9 @@ def mixer(cfg, comm, lp, h, positions, carry=None, impl: str = "jnp",
     else:
         o, *out = sparse_index._attend_selection(cfg, lp, x, q, k, v, *carry)
     with scope("attn_proj"):
-        o, w = weights(o.astype(cdt).reshape(B, T, hl * hd), "wo")
-        return row_parallel(o, w["wo"].astype(cdt), comm, axis="tp"), x, out
+        o = o.astype(cdt).reshape(B, T, hl * hd)
+        return row_parallel(o, w["wo"].astype(cdt), comm, axis="tp",
+                            by_halves=staged), x, out
 
 
 def block(cfg, comm, lp, h, positions, carry=None, **how):

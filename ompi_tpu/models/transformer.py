@@ -438,16 +438,18 @@ def _sum_in_backward(leaves: dict, axes: dict, hold=None, site=True):
     type, where the backward pass reaches this call.  The forward pass
     holds nothing of it.
 
-    With ``hold``, the activation that the leaves' matmuls read, returns
+    With ``hold``, the activation a block's first matmuls read, returns
     ``(hold, leaves)`` and places the sums in the backward pass: they are
-    made one after the other, and ``hold``'s cotangent goes on only when
-    the last is done.  Left to itself the TPU's compiler moves every
-    weight gradient's matmul and its sum to the end of the layer's
-    backward, where nothing is left to run under them, and combines the
-    sums that are ready together into one tuple all-reduce, which it does
-    not make asynchronous; held so, each sum has to be finished before the
-    backward goes on, so it is started as soon as its gradient is made and
-    runs under the matmul that makes ``hold``'s cotangent.
+    made one after the other, in the order ``leaves`` names them, and
+    ``hold``'s cotangent goes on only when the last is done.  Left to
+    itself the TPU's compiler moves every weight gradient's matmul and its
+    sum to the end of the layer's backward, where nothing is left to run
+    under them, and combines the sums that are ready together into one
+    tuple all-reduce, which it does not make asynchronous; held so, each
+    sum has to be finished before the backward goes on, so it is started
+    as soon as its gradient is made and the one before it is done, and runs
+    under the block's matmuls that do not wait for it: another matrix's
+    gradient, or the one that makes ``hold``'s cotangent.
 
     ``site`` names each sum ``coll.allreduce.<axes>``; without it the sum
     is, as shard_map's transpose left it, a collective of the backward pass
@@ -462,6 +464,8 @@ def _sum_in_backward(leaves: dict, axes: dict, hold=None, site=True):
     if not any(axes[k] for k in leaves):
         return leaves if hold is None else (hold, leaves)
 
+    order = tuple(leaves)       # the cotangents come back sorted by name
+
     @jax.custom_vjp
     def same(hold, leaves):
         return hold, leaves
@@ -469,7 +473,8 @@ def _sum_in_backward(leaves: dict, axes: dict, hold=None, site=True):
     def bwd(_, cts):
         ct_hold, cts = cts
         out, last = {}, None
-        for k, g in cts.items():
+        for k in order:
+            g = cts[k]
             if not axes[k]:
                 out[k] = g
                 continue
@@ -678,28 +683,30 @@ def _dense_ffn_tail(h, lp, comm, cdt, eps: float = 1e-6, gated=None,
     (``models/block.block``'s and a plan's).  ``gated``, a pair of
     multipliers (m0, m1): down(silu(gate(x)·m0) * up(x))·m1 with the up
     projection in a third leaf "w3".  ``weights(x, *names)``, the train
-    step's: ``(x, leaves)`` for the matmuls that read ``x``
-    (``_local_backbone`` says what it ties to them); None: ``lp``'s own."""
+    step's: ``(x, leaves)`` for the block whose first matmuls read ``x``
+    (``_local_backbone`` says what it ties to them), and the output
+    projection's product is then made and summed over ``tp`` by halves
+    (``row_parallel(by_halves=True)``); None: ``lp``'s own."""
     import jax
 
     from ompi_tpu.core.scopes import scope
     from ompi_tpu.parallel.layers import column_parallel, row_parallel
 
+    staged = weights is not None
     weights = weights or (lambda x, *_names: (x, lp))
     with scope("ffn"):
         x = _rmsnorm(h, lp["ln2"], eps)
         if gated is not None:
-            x, w = weights(x, "w1", "w3")
+            x, w = weights(x, "w2", "w1", "w3")
             y = (jax.nn.silu(column_parallel(x, w["w1"].astype(cdt))
                              * gated[0])
                  * column_parallel(x, w["w3"].astype(cdt)))
-            y, w = weights(y, "w2")
-            return h + row_parallel(y, w["w2"].astype(cdt), comm,
-                                    axis="tp") * gated[1]
-        x, w = weights(x, "w1")
+            return h + row_parallel(y, w["w2"].astype(cdt), comm, axis="tp",
+                                    by_halves=staged) * gated[1]
+        x, w = weights(x, "w2", "w1")
         y = jax.nn.gelu(column_parallel(x, w["w1"].astype(cdt)))
-        y, w = weights(y, "w2")
-        return h + row_parallel(y, w["w2"].astype(cdt), comm, axis="tp")
+        return h + row_parallel(y, w["w2"].astype(cdt), comm, axis="tp",
+                                by_halves=staged)
 
 
 def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
@@ -720,9 +727,12 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
     With a hybrid block h comes scaled by its ``lm_head_multiplier``.
     ``grad_axes`` (:func:`grad_sum_axes`, the train step's alone): the
     gradient of a layer's leaves is summed over those axes in that layer's
-    backward, inside the loop: a projection's or the MLP's matrix where
-    the backward of its matmul is, the other leaves' where the layer's
-    backward ends (:func:`_sum_in_backward`).  ``forward_only`` (a
+    backward, inside the loop: the attention block's matrices and the
+    MLP's where their block's backward ends, under the block's last
+    matmuls, the other leaves' where the layer's backward ends
+    (:func:`_sum_in_backward`); and each block's psum over ``tp`` is made
+    and transposed a half of the sequences at a time, one half's under the
+    other's product (``parallel/layers.row_parallel``).  ``forward_only`` (a
     decoder's prefill): no gradient will be asked of this pass, so it may
     take a kernel that has no backward pass (an index's masked attention,
     power retention's direct sums, a state-space mixer's scan).
@@ -758,8 +768,8 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
         if hy is not None:
             h = h * hy.embedding_multiplier
 
-    # the matrices whose gradient sum is tied to the matmul that reads them
-    # (an expert layer's w1, w2, w3 are stacks that a routed layer reads)
+    # the matrices whose gradient sum is tied to their block's matmuls (an
+    # expert layer's w1, w2, w3 are stacks that a routed layer reads)
     at_matmul = ("wq", "wk", "wv", "wo") + (
         () if cfg.moe_experts else ("w1", "w2", "w3"))
 
@@ -782,13 +792,17 @@ def _local_backbone(cfg: TransformerConfig, comm, params, tokens,
         layer_fn = jax.checkpoint(layer)
     elif cfg.remat == "dots":
         # a pallas_call's result is no saveable dot: the flash kernel's out
-        # and lse are kept by name, or its forward would run twice
+        # and lse are kept by name, or its forward would run twice; so is
+        # the sum of a row-parallel product made by halves (the train
+        # step's), or the backward would make that psum again, beside the
+        # halves of its transpose
         from ompi_tpu.ops.flash_attention import RESIDUAL_NAMES
+        from ompi_tpu.parallel.layers import SUMMED_NAME
 
         policies = jax.checkpoint_policies
         layer_fn = jax.checkpoint(layer, policy=policies.save_from_both_policies(
             policies.dots_with_no_batch_dims_saveable,
-            policies.save_only_these_names(*RESIDUAL_NAMES)))
+            policies.save_only_these_names(*RESIDUAL_NAMES, SUMMED_NAME)))
     else:
         layer_fn = layer
     with scope("layers"):

@@ -263,18 +263,25 @@ def _worst(got, want):
 
 
 @pytest.mark.parametrize("accum", [1, 2])
-@pytest.mark.parametrize("mesh_name", sorted(SUM_MESHES))
-def test_step_gradients_equal_the_no_mesh_gradient(mesh_name, accum):
+@pytest.mark.parametrize("mesh_name,batch", [
+    *((name, 8) for name in sorted(SUM_MESHES)),
+    # a replica's 6 sequences: two halves of 3 in one pass, and 3 a pass in
+    # two, an odd number, which the psums' transpose leaves whole
+    ("dp2tp2", 12), ("tp2", 6)])
+def test_step_gradients_equal_the_no_mesh_gradient(mesh_name, batch, accum):
     """Loss and every leaf's gradient of one step, in float32, are those of
     ``jax.grad`` on one device.  2e-6 of a leaf's largest element: what the
     sums of shard_map's transpose read against one device on the meshes
     with ``tp`` (1.2e-6; a matmul split over two devices adds in another
     order), and with one pass the step's gradients are those bit for bit:
-    the same addends, two or four to a sum."""
+    the same addends, two or four to a sum, also where a block's psum over
+    ``tp`` is transposed by halves of an even number of sequences
+    (``layers.row_parallel``: each half's sum and product are the
+    transpose's own rows)."""
     import dataclasses
 
     cfg = dataclasses.replace(SUM_CFG, grad_accum=accum)
-    params, toks = tfm.init_params(cfg), _tokens(cfg, batch=8)
+    params, toks = tfm.init_params(cfg), _tokens(cfg, batch=batch)
     one = _mesh_of({"dp": 1, "sp": 1, "tp": 1})
     l_want, g_want = jax.jit(jax.value_and_grad(
         tfm.make_loss_fn(SUM_CFG, one)))(params, toks)
@@ -350,7 +357,12 @@ def test_dp2tp2_step_sums_each_leaf_once_over_the_same_devices(
     inside the backward loop, over the replica groups and with the total
     operand bytes of the sums shard_map's transpose made.  The table, by
     rows over ``tp``, is summed over the dp pair after the loop; whole (a
-    vocabulary ``tp`` does not divide), over all four devices."""
+    vocabulary ``tp`` does not divide), over all four devices.  Over the
+    ``tp`` pairs the step moves one block's psum a layer less: each block's
+    psum is made and transposed by halves of the sequences, the same bytes
+    in two all-reduces, and the sum the attention block made in the forward
+    is kept (``layers.SUMMED_NAME``) where the reference's backward loop
+    makes it again."""
     import dataclasses
 
     cfg = dataclasses.replace(SUM_CFG, vocab=vocab)
@@ -368,9 +380,14 @@ def test_dp2tp2_step_sums_each_leaf_once_over_the_same_devices(
             out[groups] = out.get(groups, 0) + size
         return out
 
-    assert totals(now) == totals(before)
-    dp, every = "[[0, 2], [1, 3]]", "[[0, 1, 2, 3]]"
-    assert set(totals(now)) == {dp, every, "[[0, 1], [2, 3]]"}
+    dp, every, tp = "[[0, 2], [1, 3]]", "[[0, 1, 2, 3]]", "[[0, 1], [2, 3]]"
+    assert set(totals(now)) == set(totals(before)) == {dp, every, tp}
+    assert all(totals(now)[g] == totals(before)[g] for g in (dp, every))
+    psum = 4 * (toks.shape[0] // 2) * cfg.seq * cfg.d_model
+    assert totals(now)[tp] == totals(before)[tp] - L * psum
+    halves = [size for g, size, in_loop in now if g == tp and in_loop
+              and size == L * psum // 2]
+    assert len(halves) == 8     # two blocks, two passes, two halves each
     # the six matrices of a layer over dp and its two norms over dp and tp,
     # in the loop; the table and lnf after it
     assert sorted(in_loop for g, _, in_loop in now if g == dp) == over_dp

@@ -6,7 +6,10 @@ not attached (the chip's own compiler, installed here; nothing runs and
 nothing here is a measurement): with the step's own compile options
 (``transformer._OVERLAP_OPTIONS``) the backward loop's body holds
 asynchronous pairs, ``async-collective-start`` ... ``async-collective-done``,
-around fusions that hold a part of an all-reduce and a matmul.  The
+around fusions that hold a part of an all-reduce and a matmul: a layer's six
+gradient sums over dp and one half of each block's psum over tp, which
+``layers.row_parallel`` makes and transposes by halves of the sequences
+(the forward loop's body holds the forward's two).  The
 one-chip step is handed no option and lowers to the text of the step as it
 was before the gradient moved inside the shard_map (a local copy below); a
 mesh of CPU devices is handed none either, or its backend would refuse the
@@ -70,6 +73,32 @@ def _async_starts(lines):
             if re.match(r"\s*%async-collective-start(\.\d+)? = ", line)]
 
 
+def _forward_body(computations):
+    """The forward loop's body: where a block's psum over tp is made, and no
+    fusion's computation."""
+    fused = {m.group(1) for lines in computations.values() for line in lines
+             if (m := re.search(r"calls=%([\w.\-]+)", line))}
+    bodies = [lines for name, lines in computations.items()
+              if name not in fused
+              and any((m := _TP_PSUM.match(line))
+                      and m.groups()[2:] == ("all-reduce", "jvp(layers)")
+                      for line in lines)]
+    assert len(bodies) == 1, [len(b) for b in bodies]
+    return bodies[0]
+
+
+# an instruction of a loop's body under a block's ``coll.allreduce.tp``:
+# (name, type, opcode, pass)
+_TP_PSUM = re.compile(
+    r"\s*%([\w.\-]+) = (\w+\[[\d,]*\])\S* ([\w-]+)\(.*op_name=\"[^\"]*?"
+    r"((?:transpose\()?jvp\(layers\)\)?)/while/body/[^\"]*coll\.allreduce\.tp/")
+
+
+def _opcodes(lines):
+    return {m.group(1) for line in lines
+            if (m := re.search(r" ([a-z][\w-]*)\(", line.split(" = ", 1)[-1]))}
+
+
 def test_four_chip_step_runs_all_reduces_under_matmuls(four_chip_step):
     computations = four_chip_step
     body = _backward_body(computations)
@@ -82,9 +111,7 @@ def test_four_chip_step_runs_all_reduces_under_matmuls(four_chip_step):
     under = [m.group(1) for m in under if m]
     assert len(under) >= len(starts)
     for name in under:
-        opcodes = {m.group(1) for line in computations[name]
-                   if (m := re.search(r" ([a-z][\w-]*)\(", line.split(
-                       " = ", 1)[-1]))}
+        opcodes = _opcodes(computations[name])
         assert {"all-reduce", "convolution"} <= opcodes, (name, opcodes)
 
 
@@ -93,14 +120,64 @@ def test_four_chip_step_sums_half_of_the_table_over_the_dp_pair(
     """The table is stored by rows over ``tp``: the scheduled step holds no
     all-reduce of the whole table's gradient, f32[50432, 4096], and one of a
     rank's rows, f32[25216, 4096], between the two chips of a dp pair; and
-    a layer's backward still holds its seven asynchronous pairs."""
+    a layer's backward holds its eight asynchronous pairs: six gradient
+    sums over dp and a half of each block's psum over tp."""
     reduces = [line for lines in four_chip_step.values() for line in lines
                if re.search(r" all-reduce(-start)?\(", line)]
     assert not [line for line in reduces if "f32[50432,4096]" in line]
     table = [line for line in reduces if "f32[25216,4096]" in line]
     assert len(table) == 1, table
     assert "replica_groups={{0,2},{1,3}}" in table[0], table[0]
-    assert len(_async_starts(_backward_body(four_chip_step))) == 7
+    assert len(_async_starts(_backward_body(four_chip_step))) == 8
+
+
+def _halves_under_products(computations, body, where):
+    """Of a loop's ``body``, the pairs and the synchronous all-reduces under
+    a block's ``coll.allreduce.tp`` in the pass ``where``, each as its
+    type, after the check that every pair is around a fusion that holds an
+    all-reduce and a convolution."""
+    found = [m.groups() for line in body if (m := _TP_PSUM.match(line))
+             and m.group(4) == where]
+    done = [(name, kind) for name, kind, opcode, _ in found
+            if name.startswith("async-collective-done")]
+    for name, _ in done:
+        start = name.replace("done", "start")
+        at = [i for i, line in enumerate(body)
+              if re.match(rf"\s*%({re.escape(start)}|{re.escape(name)}) = ",
+                          line)]
+        assert len(at) == 2, (name, at)
+        between = [m.group(1) for line in body[at[0] + 1:at[1]]
+                   if (m := re.search(
+                       r"calls=%(async_collective_fusion[\w.]*)", line))]
+        assert between, name
+        for fusion in between:
+            assert {"all-reduce", "convolution"} <= _opcodes(
+                computations[fusion]), (name, fusion)
+    return ([kind for _, kind in done],
+            [kind for _, kind, opcode, _ in found if opcode == "all-reduce"])
+
+
+def test_four_chip_step_sums_each_tp_psum_by_halves(four_chip_step):
+    """Of a block's psum over tp, bf16[4,2048,4096], each pass sums the two
+    halves of the sequences apart: one half a synchronous all-reduce, the
+    other an asynchronous pair named ``coll.allreduce.tp`` around a fusion
+    that holds the all-reduce and the other half's product.  No whole one is
+    left in either loop's body, and the backward's makes none again (the
+    forward's sums are kept by name).  What S5.4c starts from: the forward's
+    two synchronous halves and what its two pairs still wait."""
+    half = "bf16[2,2048,4096]"
+    body = _backward_body(four_chip_step)
+    assert not [line for line in body if "rematted_computation" in line
+                and "coll.allreduce.tp/psum" in line]
+    assert not [line for line in body
+                if (m := _TP_PSUM.match(line)) and m.group(4) == "jvp(layers)"]
+    for lines, where in ((body, "transpose(jvp(layers))"),
+                         (_forward_body(four_chip_step), "jvp(layers)")):
+        pairs, synchronous = _halves_under_products(
+            four_chip_step, lines, where)
+        assert pairs == synchronous == [half, half], (where, pairs,
+                                                      synchronous)
+    assert len(_async_starts(_forward_body(four_chip_step))) == 2
 
 
 def _step_before(cfg, mesh, lr):
