@@ -96,6 +96,10 @@ SCOPES = (
     "attention.selected",   # a latent layer's read of the rows its index
                             # selected: the cached step's pass over the
                             # cache under the mask, the prefill's kernel
+    "attention.window",     # a windowed layer's scores to context: a band
+                            # of keys a block of queries, a ring when cached
+    "attention.shared",     # a layer's read of another layer's K and V: its
+                            # scores to context against a cache it does not own
     "ffn",              # ln2, the MLP, the residual
     "moe.route", "moe.dispatch", "moe.experts", "moe.combine",
     "moe.groups",       # inside ``moe.route``: the groups of experts a token
@@ -108,6 +112,8 @@ SCOPES = (
     "ssm.scan",         # ... the chunked scan over a whole sequence
     "ssm.update",       # ... one cached step's recurrence: from reading the
                         # layer's state out of the carry to writing it back
+    "gmu",              # a gated memory unit: ln1, its two products and the
+                        # gate on another layer's scan output
     "kda_proj",         # the delta-rule mixer's ln1, six projections, L2 norms,
                         # gated norm and output projection
     "kda.conv",         # ... its three causal convolutions (from a state, cached)

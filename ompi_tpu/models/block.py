@@ -52,7 +52,9 @@ class Attention:
 def mechanisms(cfg) -> tuple:
     """The modules whose state a decoder of ``cfg`` carries, in the carry's
     order: ``plan`` (whose layers' kinds, ``plan.MIXERS``, are modules of
-    their own: ``kda``, ``mla``, ``lightning``, ``block_select``); or this
+    their own: ``kda``, ``mla``, ``lightning``, ``block_select``, ``ssm``,
+    ``selective``, ``differential``, ``gmu``, and this one's
+    ``PLAN_KIND``); or this
     one (with an index ``sparse_index``, which lays
     K and V out with its keys; with power retention ``retention``, which
     keeps a state and no K/V) and, with a hybrid block, ``ssm``.  The one

@@ -234,6 +234,8 @@ def _halves(cfg: TransformerConfig, mesh, max_new: int,
                     h = h * hy.embedding_multiplier
                 elif cfg.plan is not None and cfg.plan.emb_factor != 1:
                     h = h * cfg.plan.emb_factor
+                if cfg.plan is not None and cfg.plan.stream_dtype:
+                    h = h.astype(cfg.plan.stream_dtype)
 
             # the whole stacked carry is this loop's carry too; as a
             # scan's xs and ys it would be sliced out and copied back
@@ -255,7 +257,11 @@ def _halves(cfg: TransformerConfig, mesh, max_new: int,
                     h, stacks = lax.fori_loop(0, cfg.n_layers, per_layer,
                                               (h, stacks))
             with scope("unembed"):
-                h = _rmsnorm(h, params["lnf"], cfg.norm_eps)
+                if cfg.plan is not None:    # with LayerNorm its bias too
+                    h = plan._last_norm(cfg, layer_params | {
+                        "lnf": params["lnf"]}, h)
+                else:
+                    h = _rmsnorm(h, params["lnf"], cfg.norm_eps)
                 if hy is not None:
                     h = h * hy.lm_head_multiplier
                 elif cfg.plan is not None and cfg.plan.logit_divisor != 1:

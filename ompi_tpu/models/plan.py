@@ -5,7 +5,11 @@ mixer's kind (``MIXERS``: "kda", ``models/kda.py``; "mla", ``models/mla.py``;
 "lightning", ``models/lightning.py``; "block_select",
 ``models/block_select.py``; "ssm", the Mamba-2 mixer of ``models/ssm.py``;
 "attention", the grouped-query attention of ``models/block.py``, rotary or
-not) and its MLP's ("dense": a gated MLP of width ``cfg.d_ff``; "moe": the
+not; "selective", the Mamba-1 mixer of ``models/selective.py``; "window",
+"differential" and "shared", ``models/differential.py``'s differential
+attention over a ring of the last positions, over every earlier position, and
+over another row's K and V; "gmu", the gated memory unit of
+``models/gmu.py``) and its MLP's ("dense": a gated MLP of width ``cfg.d_ff``; "moe": the
 dropless routed experts of ``parallel/moe.routed_moe``, of width
 ``d_expert``, under the configuration's ``moe_*`` fields).  A configuration
 without a plan is attention and one MLP in every layer under one
@@ -35,22 +39,33 @@ layer's own buffers, with the position after them where the module says
 asked of whole sequences where its ``mixer`` takes ``forward_only=``;
 and, where a layer's place in the
 model is part of its arithmetic, ``constants(sizes, layer)``, what the plan
-hands the mixer beside its leaves.
+hands the mixer beside its leaves.  A kind whose rows may read another row's
+product, or be read, says so: ``hands(sizes)`` ("buffers": its states, which
+a reader reads and never writes; "output": what its ``mixer`` returns last,
+of the same pass; None) and ``reads(sizes)`` (the same words: what its source
+has to hand on, given to its ``mixer`` as ``source=``; such a row's
+``buffers`` is empty).  ``LayerPlan.reads`` names each reader's source, an
+earlier row (:func:`check_reads`).
 
 Leaves are stacked by kind, not by layer: the KDA leaves over the KDA
 layers, the latent leaves over the latent layers, the dense MLP's over the
 dense layers, the router's, the shared expert's and the experts' over the
 routed layers; ``ln1`` over the rows that have a mixer and ``ln2`` over
-those that have an MLP (all rows, where every row has both).  A decoder carries each
-layer's own buffers (:func:`carry` says which, and why not a stack a kind).
+those that have an MLP (all rows, where every row has both), and with
+``LayerPlan.layernorm`` each norm's bias beside it.  A decoder carries each
+layer's own buffers (:func:`carry` says which, and why not a stack a kind),
+and nothing for a row that reads another's.
 The plan is static, so both passes are python loops over it
 (:func:`backbone`, the whole sequence; :func:`step`, one cached position):
 a layer's place in its kind's stacks of leaves is a python integer, its
 leaves are static slices, and its state is a buffer that the step reads and
-replaces.  Not a scan over whole periods with the leading layers outside it:
-the plans built so far are at most fourteen rows whose kinds differ inside a
-period (or ten of one period); a deep plan would want the scan
-(``ROADMAP.md`` D1').
+replaces; a row that reads another row's is handed that row's buffers as
+the step left them, or its mixer's output of the step.  A decoder's prefill
+runs the rows above the last one that owns state on each prompt's last
+position alone (:func:`_upper`).  Not a scan over whole periods with the
+leading layers outside it: the plans are up to thirty-two rows (a run of
+eight periods, two single rows, a run of seven periods), unrolled; the scan
+needs a chip experiment first (``ROADMAP.md`` D1').
 
 Only a configuration with a plan imports this (its doors: ``ENTRY_CONFIGS``).
 """
@@ -68,11 +83,15 @@ __all__ = ["LayerPlan", "MIXERS", "kda_mla_config", "lightning_blocks_config",
            "leaf_names", "init_params", "carry", "grows", "carried",
            "backbone", "step", "check_mesh", "mla_moe_config",
            "shortcut_moe_config", "pattern_moe_config",
-           "layer_types_moe_config", "ENTRY_CONFIGS"]
+           "layer_types_moe_config", "shared_state_config", "check_reads",
+           "ENTRY_CONFIGS"]
 
 # a mixer's kind -> its module under ``ompi_tpu.models``
 MIXERS = {"kda": "kda", "mla": "mla", "lightning": "lightning",
-          "block_select": "block_select", "ssm": "ssm", "attention": "block"}
+          "block_select": "block_select", "ssm": "ssm", "attention": "block",
+          "selective": "selective", "window": "differential",
+          "differential": "differential", "shared": "differential",
+          "gmu": "gmu"}
 MLPS = ("dense", "moe")
 
 ROUTER_LEAVES = ("wg", "wgb")
@@ -103,7 +122,16 @@ class LayerPlan:
     ``reads``'s own MLP reads (the stream after that row's mixer, under its
     ``ln2``) and is added to the stream after row ``lands``'s MLP, ``lands
     >= reads``: it runs beside every mixer and MLP in between, which do not
-    see it."""
+    see it.  ``layernorm``: every norm removes the mean and has a bias
+    (leaves ``ln1b``, ``ln2b``, ``lnfb``); False: RMSNorm.  ``reads``: ``(row,
+    source)`` each: row ``row``'s mixer owns no state and reads what the
+    earlier row ``source`` made, that row's buffers (K and V: read, never
+    written) or its mixer's output of the same pass, as the two kinds say
+    (``reads`` and ``hands`` of their modules; :func:`check_reads`).
+    ``stream_dtype``: what the residual stream is carried in between rows
+    ("float32": every residual add and every norm reads float32, and a
+    branch's products still multiply in the compute type); None: the compute
+    type."""
     layers: tuple
     kda: Any = None
     mla: Any = None
@@ -119,6 +147,14 @@ class LayerPlan:
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    selective: Any = None
+    window: Any = None
+    differential: Any = None
+    shared: Any = None
+    gmu: Any = None
+    layernorm: bool = False
+    reads: tuple = ()
+    stream_dtype: Any = None
 
     @property
     def emb_factor(self) -> float:
@@ -153,6 +189,10 @@ class LayerPlan:
         """Row ``layer``'s place in ``ln1`` (``half`` 0: the rows that have a
         mixer) or in ``ln2`` (1: those that have an MLP)."""
         return sum(pair[half] is not None for pair in self.layers[:layer])
+
+    def source(self, layer: int):
+        """The row that row ``layer`` reads (``reads``), or None."""
+        return dict(self.reads).get(layer)
 
     def second(self, layer: int) -> bool:
         """Whether ``layer`` is past the first row of a model's layer: a
@@ -304,6 +344,56 @@ def check_mesh(cfg, mesh) -> None:
         raise ValueError(f"a branch reads a row of the plan and lands at "
                          f"that row or a later one: {astray} of "
                          f"{len(pl.layers)} rows")
+    check_reads(pl)
+
+
+def _handed(pl, layer: int):
+    """What row ``layer``'s mixer hands a reader ("buffers", "output" or
+    None) and what it reads of its source (the same words)."""
+    mixer = pl.layers[layer][0]
+    module = None if mixer is None else _module(mixer)
+    if module is None or not hasattr(module, "hands"):
+        return None, None
+    sizes = getattr(pl, mixer)
+    return module.hands(sizes), module.reads(sizes)
+
+
+def check_reads(pl) -> None:
+    """Every reader (a row whose kind says it reads: it owns no buffer) names
+    an earlier row whose kind hands on what the reader reads; refused
+    otherwise, when the plan is built."""
+    readers = dict(pl.reads)
+    for layer in range(len(pl.layers)):
+        _hands, wants = _handed(pl, layer)
+        source = readers.pop(layer, None)
+        if wants is None and source is None:
+            continue
+        if wants is None or source is None:
+            raise ValueError(f"row {layer} of the plan ({pl.layers[layer][0]}"
+                             f") reads {wants} and names the source {source}")
+        if not 0 <= source < layer or _handed(pl, source)[0] != wants:
+            raise ValueError(
+                f"row {layer} of the plan reads the {wants} of row {source} "
+                f"({pl.layers[source][0] if 0 <= source < len(pl.layers) else None}"
+                f"): a source is an earlier row whose kind hands that on")
+    if readers:
+        raise ValueError(f"LayerPlan.reads names rows {sorted(readers)} of "
+                         f"{len(pl.layers)}")
+
+
+def _upper(pl):
+    """The first row after the last that owns a buffer, where some row reads
+    another's product: from there on a row mixes no positions of its own (it
+    owns no state), so a decoder's prefill runs those rows on each prompt's
+    last position alone.  None: a plan without readers, or one whose every
+    row owns state."""
+    if not pl.reads:
+        return None
+    owners = [layer for layer, (mixer, _mlp) in enumerate(pl.layers)
+              if mixer is not None and _handed(pl, layer)[1] is None]
+    cut = max(owners) + 1
+    crossing = [b for b in pl.branches if b[1] < cut <= b[2]]
+    return cut if cut < len(pl.layers) and not crossing else None
 
 
 def _kinds(cfg) -> dict:
@@ -367,9 +457,14 @@ def leaf_names(cfg) -> tuple:
 
 def _norms(pl) -> dict:
     """``ln1`` and ``ln2`` -> the rows each is stacked over: those that have
-    a mixer, those that have an MLP."""
+    a mixer, those that have an MLP; with ``LayerPlan.layernorm`` their
+    biases ``ln1b`` and ``ln2b`` alike and the last norm's, ``lnfb``, a stack
+    of one, so that the bias of a norm outside the layers is placed, summed
+    and handed to a step as the layers' leaves are."""
     rows = {"ln1": pl.norm(len(pl.layers), 0),
             "ln2": pl.norm(len(pl.layers), 1)}
+    if pl.layernorm:            # each norm's bias, and the last norm's
+        rows.update(ln1b=rows["ln1"], ln2b=rows["ln2"], lnfb=1)
     return {name: n for name, n in rows.items() if n}
 
 
@@ -379,7 +474,8 @@ def init_params(cfg, rng) -> dict:
     params = {"emb": rng.normal(0, 0.02, size=(V, D)).astype(np.float32),
               **({} if cfg.tie_head else {"head": rng.normal(
                   0, 0.02, size=(V, D)).astype(np.float32)}),
-              **{name: np.ones((n, D), np.float32)
+              **{name: (np.zeros if name.endswith("b") else np.ones)(
+                  (n, D), np.float32)
                  for name, n in _norms(cfg.plan).items()},
               "lnf": np.ones((D,), np.float32)}
     for n, leaves in _kinds(cfg).values():
@@ -412,8 +508,12 @@ def carry(cfg, mesh, batch: int, t_max: int) -> list:
     mixer's ``state_dtype``; a lightning layer's matrix states alike; a
     block-selected layer's K and V rows and, one for every ``stride``
     positions, its pooled keys; a state-space layer's convolution inputs and
-    heads' states; an attention layer's K and V; nothing for a row without a
-    mixer.  The order is that of :func:`backbone`'s collected states.
+    heads' states; an attention layer's K and V; a selective layer's
+    convolution inputs and ``(1, B, N, Di)`` state; a window layer's ring of K
+    and V ``(1, B, window, Hkv, hd)``, which does not grow, and the full
+    differential layer's K and V, which do; nothing for a row without a
+    mixer, nor for one that reads another row's (``LayerPlan.reads``).  The
+    order is that of :func:`backbone`'s collected states.
 
     A buffer a layer and not a stack a kind, because the steps' loop over
     the plan is unrolled: with one float32 stack ``(KDA layers, B, heads, K,
@@ -464,6 +564,8 @@ def _mlp(cfg, comm, params, layer: int, kind: str, h, branch: bool = False):
     """Layer ``layer``'s MLP half, ``ln2`` and the residual add in it; or,
     ``branch``, what the branch of ``kind`` that reads there adds to the
     stream where it lands."""
+    import jax.numpy as jnp
+
     from ompi_tpu.models import transformer as tfm
     from ompi_tpu.parallel.moe import EXPERT_LEAVES
 
@@ -473,7 +575,10 @@ def _mlp(cfg, comm, params, layer: int, kind: str, h, branch: bool = False):
         lp = {"ln2": ln2, **{
             name: params[leaf][at] for name, leaf in zip(
                 ("w1", "w3", "w2"), _dense_leaves(pl))}}
-        return tfm._dense_ffn_tail(h, lp, comm, h.dtype, cfg.norm_eps,
+        if "ln2b" in params:
+            lp["ln2b"] = params["ln2b"][pl.norm(layer, 1)]
+        cdt = jnp.dtype(cfg.compute_dtype) if pl.stream_dtype else h.dtype
+        return tfm._dense_ffn_tail(h, lp, comm, cdt, cfg.norm_eps,
                                    gated=(1.0, pl.branch_factor))
     # the experts' whole stacks and the layer's place in them
     # (``routed_moe`` says why); the router's and the shared expert's sliced
@@ -486,7 +591,7 @@ def _mlp(cfg, comm, params, layer: int, kind: str, h, branch: bool = False):
 
 
 def _row(cfg, comm, params, layer: int, mixer: str, mlp: str, lp, h,
-         landing, carry=None, forward_only: bool = False):
+         landing, carry=None, forward_only: bool = False, source=None):
     """Row ``layer`` of the plan on the stream ``h``: its mixer on the leaves
     ``lp`` (against ``carry`` in a cached step), the branches that read
     there, its MLP, and the branches that land there (``landing``) added; a
@@ -509,6 +614,8 @@ def _row(cfg, comm, params, layer: int, mixer: str, mlp: str, lp, h,
                 told["comm"] = comm
             if "forward_only" in inspect.signature(module.mixer).parameters:
                 told["forward_only"] = forward_only
+            if source is not None:
+                told["source"] = source
             h, *states = module.mixer(cfg, lp, h, carry=carry, **told)
         begun = {}
         for kind, reads, lands in pl.branches:
@@ -539,9 +646,46 @@ def _mixer_leaves(cfg, params, layer: int, kind: str) -> dict:
     at = cfg.plan.index(layer, kind)
     constants = getattr(_module(kind), "constants", None)
     return {"ln1": params["ln1"][cfg.plan.norm(layer, 0)],
+            **({"ln1b": params["ln1b"][cfg.plan.norm(layer, 0)]}
+               if "ln1b" in params else {}),
             **{k: params[k][at] for k in _kinds(cfg)[kind][1]},
             **(constants(getattr(cfg.plan, kind), layer) if constants
                else {})}
+
+
+def _source(pl, handed: dict, layer: int) -> dict:
+    """What row ``layer`` reads (``LayerPlan.reads``) of ``handed``, row ->
+    its product, as the keyword of the row's program and of :func:`_row`;
+    nothing for a row that reads nothing."""
+    source = pl.source(layer)
+    return {} if source is None else {"source": handed[source]}
+
+
+def _hand_on(pl, handed: dict, layer: int, states: list) -> list:
+    """Row ``layer``'s states after its mixer, less what it made for its
+    readers alone: where a later row reads this one, ``handed`` gets its
+    product, the states themselves (K and V: the readers do not write them)
+    or the mixer's output of this pass, which its kind returns last."""
+    how = _handed(pl, layer)[0]
+    if how == "output":
+        *states, product = states
+    elif how == "buffers":
+        product = tuple(states)
+    if how and layer in dict(pl.reads).values():
+        handed[layer] = product
+    return list(states)
+
+
+def _last_norm(cfg, params, h):
+    """The norm after the last row: ``lnf``, with LayerNorm its bias too."""
+    from ompi_tpu.models import transformer as tfm
+
+    if "lnfb" not in params:
+        h = tfm._rmsnorm(h, params["lnf"], cfg.norm_eps)
+    else:
+        h = tfm._layernorm(h, params["lnf"], params["lnfb"][0], cfg.norm_eps)
+    # out of a stream of another type, back in the compute type
+    return h.astype(cfg.compute_dtype) if cfg.plan.stream_dtype else h
 
 
 def _own_program(layer):
@@ -583,15 +727,17 @@ def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
         h = tfm._lookup(cfg, params["emb"], tokens)
         if pl.emb_factor != 1:
             h = h * pl.emb_factor
+        if pl.stream_dtype:
+            h = h.astype(pl.stream_dtype)
     if grad_axes is not None:
         params = {**params, **tfm._sum_in_backward(
             {k: params[k] for k in leaf_names(cfg)}, grad_axes)}
 
     def layer_fn(layer, mixer, mlp):
-        def run(h, params, landing):
+        def run(h, params, landing, **source):
             lp = _mixer_leaves(cfg, params, layer, mixer)
             return _row(cfg, comm, params, layer, mixer, mlp, lp, h, landing,
-                        forward_only=forward_only)
+                        forward_only=forward_only, **source)
 
         if cfg.remat in (True, "full"):
             run = jax.checkpoint(run)
@@ -600,17 +746,27 @@ def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
                                  .dots_with_no_batch_dims_saveable)
         return _own_program(run)
 
-    collected, flying = [], {}
+    collected, flying, handed = [], {}, {}
+    # a decoder's prefill: the rows above the last that owns state, on each
+    # prompt's last position alone
+    cut = _upper(pl) if collect_kv and forward_only else None
     with scope("layers"):
         for layer, (mixer, mlp) in enumerate(pl.layers):
+            if layer == cut:
+                h = h[:, -1:]
+                handed = {row: (product[:, -1:]
+                                if _handed(pl, row)[0] == "output" else product)
+                          for row, product in handed.items()}
             # the host's record of what tracing this layer costs, by its
             # mixer's kind (none for a row that is an MLP alone)
             with host("trace.layer", program=mixer):
                 h, states, begun = layer_fn(layer, mixer, mlp)(
-                    h, params, flying.pop(layer, []))
+                    h, params, flying.pop(layer, []),
+                    **_source(pl, handed, layer))
             _take_off(flying, begun)
+            states = _hand_on(pl, handed, layer, states)
             collected += [state[None] for state in states]
-    h = tfm._rmsnorm(h, params["lnf"], cfg.norm_eps)
+    h = _last_norm(cfg, params, h)
     if pl.logit_divisor != 1:
         h = h / pl.logit_divisor
     return (h, collected) if collect_kv else h
@@ -619,8 +775,12 @@ def backbone(cfg, comm, params, tokens, collect_kv: bool = False,
 def step(cfg, comm, params, h, states, pos):
     """Every layer for ONE new token position: h (B, 1, D) against the
     carry ``states`` (:func:`carry`'s buffers), each layer reading and
-    replacing its own.  ``params``: the leaves stacked over layers.  Returns
-    ``(h, *states)``."""
+    replacing its own, and a row that reads another's (``LayerPlan.reads``)
+    handed that row's buffers as this step left them, or its mixer's output
+    of this step.  ``params``: the leaves stacked over layers.  Returns ``(h,
+    *states)``."""
+    import jax
+
     from ompi_tpu.core.scopes import host
 
     pl = cfg.plan
@@ -628,23 +788,27 @@ def step(cfg, comm, params, h, states, pos):
     def layer_fn(layer, mixer, mlp):
         positioned = mixer is not None and _module(mixer).POSITIONED
 
-        def run(h, params, own, pos, landing):
+        def run(h, params, own, pos, landing, **source):
             lp = _mixer_leaves(cfg, params, layer, mixer)
             own = tuple(buffer[0] for buffer in own)
+            # what it reads lies in the carry's form too: a leading axis
             h, own, begun = _row(
                 cfg, comm, params, layer, mixer, mlp, lp, h, landing,
-                carry=(*own, pos) if positioned else own)
+                carry=(*own, pos) if positioned else own,
+                **jax.tree.map(lambda buffer: buffer[0], source))
             return h, [buffer[None] for buffer in own], begun
 
         return _own_program(run)
 
-    states, at, flying = list(states), 0, {}
+    states, at, flying, handed = list(states), 0, {}, {}
     for layer, (mixer, mlp) in enumerate(pl.layers):
         n = (0 if mixer is None else
              len(_module(mixer).buffers(cfg, getattr(pl, mixer), 0, 0)))
         with host("trace.layer", program=mixer):
-            h, states[at:at + n], begun = layer_fn(layer, mixer, mlp)(
-                h, params, states[at:at + n], pos, flying.pop(layer, []))
+            h, own, begun = layer_fn(layer, mixer, mlp)(
+                h, params, states[at:at + n], pos, flying.pop(layer, []),
+                **_source(pl, handed, layer))
+        states[at:at + n] = _hand_on(pl, handed, layer, own)
         _take_off(flying, begun)
         at += n
     return (h, *states)
@@ -957,6 +1121,76 @@ def layer_types_moe_config(layer_types: list, mamba_n_heads: int,
         moe_shared=shared_intermediate_size, **sizes)
 
 
+def shared_state_config(mb_per_layer: int, sliding_window: int,
+                        hidden_act: str, mlp_bias: bool, lm_head_bias: bool,
+                        mamba_d_state: int = 16, mamba_d_conv: int = 4,
+                        mamba_expand: int = 2,
+                        ssm_state_dtype: str = "float32",
+                        residual_in_fp32: bool = True, **sizes):
+    """``entry.config`` of a configuration file whose lower half owns every
+    state and whose upper half reads it (a decoder-hybrid-decoder), under the
+    keys such a model is published with: a ``TransformerConfig`` whose plan
+    has a row a layer over a dense gated MLP, LayerNorm with a bias
+    everywhere, no rotary embedding.  With ``mb_per_layer`` 2 a row of even
+    number is a selective state-space mixer and one of odd number attends
+    (differential heads, a bias on both projections): rows under ``n_layers /
+    2`` are "selective" and "window" (``sliding_window`` keys, the query's own
+    counted), row ``n_layers / 2`` is "selective" and hands its scan output
+    on, the next is "differential" (every earlier position) and hands its K
+    and V on, and above them "gmu" rows gate on that scan output and
+    "shared" rows attend that K and V (``LayerPlan.reads``): they own no
+    buffer.  The mixer's sizes, which the published file does not carry, are
+    keys of the file's own: ``mamba_d_state``, ``mamba_d_conv``,
+    ``mamba_expand`` (the inner width over ``d_model``; the step's rank is
+    ``ceil(d_model / 16)``), ``ssm_state_dtype`` and ``residual_in_fp32`` (the
+    residual stream in float32 between rows, the Mamba lineage's default:
+    ``LayerPlan.stream_dtype``).  What is not built
+    raises: another ``mb_per_layer``, activation, a bias on the MLP or the
+    head, a depth that is no whole number of fours or under twelve, K/V
+    heads that do not pair."""
+    from ompi_tpu.models.differential import Differential
+    from ompi_tpu.models.gmu import Gmu
+    from ompi_tpu.models.selective import Selective
+    from ompi_tpu.models.transformer import TransformerConfig
+
+    L, D = sizes["n_layers"], sizes["d_model"]
+    heads = sizes["n_heads"]
+    kv_heads = sizes.get("n_kv_heads") or heads
+    not_built = [f"{key} {value!r}" for key, value, built in (
+        ("mb_per_layer", mb_per_layer, 2), ("hidden_act", hidden_act, "silu"),
+        ("a bias on the MLP or the head", bool(mlp_bias or lm_head_bias),
+         False),
+        ("a depth of whole fours, twelve or more", L % 4 == 0 and L >= 12,
+         True),
+        ("paired K/V heads that divide the query heads",
+         kv_heads % 2 == 0 and heads % kv_heads == 0, True))
+        if value != built]
+    if not_built:
+        raise ValueError(f"a plan whose upper rows read the lower rows' "
+                         f"state is not built for {', '.join(not_built)}")
+    half = L // 2
+    lower, upper = ("selective", "window"), ("gmu", "shared")
+    kinds = [lower[l % 2] if l < half else "selective" if l == half
+             else "differential" if l == half + 1 else upper[l % 2]
+             for l in range(L)]
+    attends = {"n_heads": heads, "kv_heads": kv_heads, "head_dim": D // heads}
+    inner = mamba_expand * D
+    plan = LayerPlan(
+        layers=tuple((kind, "dense") for kind in kinds),
+        selective=Selective(d_inner=inner, d_state=mamba_d_state,
+                            d_conv=mamba_d_conv, dt_rank=-(-D // 16),
+                            state_dtype=ssm_state_dtype),
+        window=Differential(window=sliding_window, prefix="w", **attends),
+        differential=Differential(prefix="a", **attends),
+        shared=Differential(cross=True, prefix="x", **attends),
+        gmu=Gmu(width=inner), layernorm=True,
+        stream_dtype="float32" if residual_in_fp32 else None,
+        reads=tuple((l, half if kinds[l] == "gmu" else half + 1)
+                    for l in range(half + 2, L)))
+    check_reads(plan)
+    return TransformerConfig(plan=plan, **sizes)
+
+
 # A configuration file reaches a plan through its ``entry.config``, one
 # function a published family's keys (each raises for what is not built):
 # ``kda_mla_config``: KDA and NoPE latent layers by ``linear_attn_config``'s
@@ -975,7 +1209,10 @@ def layer_types_moe_config(layer_types: list, mamba_n_heads: int,
 # ``layer_types_moe_config``: GraniteMoeHybrid's keys, a mixer by
 # ``layer_types`` (Mamba-2 or scaled NoPE attention) and then gated experts
 # under a softmax over the picked logits beside a shared one in every layer,
-# under the family's four multipliers.
+# under the family's four multipliers; ``shared_state_config``: a
+# decoder-hybrid-decoder's keys, selective state-space and windowed
+# differential-attention rows under one full-attention row, and above it rows
+# that own nothing: gated memory units and cross attention on those states.
 ENTRY_CONFIGS = (kda_mla_config, mla_moe_config, lightning_blocks_config,
                  shortcut_moe_config, pattern_moe_config,
-                 layer_types_moe_config)
+                 layer_types_moe_config, shared_state_config)

@@ -360,6 +360,27 @@ def _rmsnorm(x, scale, eps: float = 1e-6):
     return (norm * scale).astype(x.dtype)
 
 
+def _layernorm(x, scale, bias, eps: float = 1e-5):
+    """LayerNorm: the mean removed, over the root of the variance plus
+    ``eps``, times ``scale`` plus ``bias``; float32 inside."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    norm = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (norm * scale + bias).astype(x.dtype)
+
+
+def _norm(x, scale, eps: float, bias=None):
+    """A layer's norm: :func:`_rmsnorm`, or where the layer has a bias for it
+    (a plan with ``layernorm``) :func:`_layernorm`.  Both are called
+    through the module: a benchmark's control plants a wrong one there."""
+    if bias is None:
+        return _rmsnorm(x, scale, eps)
+    return _layernorm(x, scale, bias, eps)
+
+
 def _qk_norm(cfg, x, scale, comm):
     """RMSNorm of a projected q or k (..., heads x head width), of which
     this device holds the ``tp`` shard of the heads.  ``cfg.qk_norm ==
@@ -695,7 +716,9 @@ def _dense_ffn_tail(h, lp, comm, cdt, eps: float = 1e-6, gated=None,
     staged = weights is not None
     weights = weights or (lambda x, *_names: (x, lp))
     with scope("ffn"):
-        x = _rmsnorm(h, lp["ln2"], eps)
+        x = _norm(h, lp["ln2"], eps, lp.get("ln2b"))
+        if x.dtype != cdt:      # a plan's stream of another type
+            x = x.astype(cdt)
         if gated is not None:
             x, w = weights(x, "w2", "w1", "w3")
             y = (jax.nn.silu(column_parallel(x, w["w1"].astype(cdt))

@@ -51,6 +51,12 @@ SITES = {
     "sparse-index-rows": ("keye-vl-2.0-30b-a3b.decode-8k-128-b64",
                           "selected_attention"),
     "routed-moe": ("olmoe-1b-7b.decode-1k-128", "grouped_matmul"),
+    # PR 77: a plan's selective rows scan a prefill in one kernel, and its one
+    # full differential row attends through the flash kernel
+    "selective-prefill": ("phi-4-mini-flash-reasoning.decode-16k-256-b16",
+                          "selective_scan"),
+    "differential-prefill": ("phi-4-mini-flash-reasoning.decode-16k-256-b16",
+                             "flash_fwd"),
 }
 
 

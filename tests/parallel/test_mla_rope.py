@@ -408,7 +408,7 @@ def test_every_planned_configurations_door_is_listed():
              for c in (cells.load_json(f"{cells.BENCH_DIR}/../{row['file']}")
                        for row in cells.load_benchmark()["configs"])
              if ".plan." in c["entry"]["config"]}
-    assert doors == set(plan.ENTRY_CONFIGS) and len(doors) == 6
+    assert doors == set(plan.ENTRY_CONFIGS) and len(doors) == 7
 
 
 def test_the_nope_form_has_no_rotation_in_it():
