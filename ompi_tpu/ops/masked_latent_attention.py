@@ -26,11 +26,32 @@ again, which is not fetched twice, and does nothing.  A mask that leaves
 whole tiles empty inside that length (a trained index's) is still read tile
 by tile (``ROADMAP.md`` M18).  No backward pass: a trainer takes the jnp
 form.
+
+What a tile's body spends between its products, a float32 score of a head's
+``(512, 512)`` tile: the add of the two products (a contraction 256 deep is
+two passes of the 128-deep array on two units and the same add: the compiler
+makes it, a scratch of ``[q_n | q_r]`` removes none), one select under the
+mask, the row max, the subtraction of the running max, one multiply, ``exp2``,
+the add into the row's sum and the cast to the compute type.  ``scale`` sits
+in that one multiply, ``exp2((s - m) * scale * log2 e)``: the max is taken of
+the unscaled scores (``scale`` is positive) and the multiply ``exp`` would
+spend on ``log2 e`` applies it, where scaling ``q`` would round the queries
+anew.  A row's running max starts at ``_FLOOR``, finite and far above
+``_NEG``, so a score the mask set to ``_NEG`` underflows to an exact 0
+whatever the row has seen and no second select is needed; a row of no key
+ends with a sum of 0 and reads zeros.  The running max and sum are kept 128
+lanes wide: a ``(512, 1)`` column is a lane broadcast through the cross-lane
+unit at each use (three a head and tile, 768 of them a step, and the MXU
+waited for them: 9658 cycles a step against 6565, PERF.md section 6, PR 78),
+a max that fills its lanes is subtracted as it lies; and the sum is kept a
+lane's part of it, added across the tile's four lane blocks on the vector
+unit, and summed across lanes once, at the last tile.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +59,10 @@ import jax.numpy as jnp
 __all__ = ["masked_latent_attention", "jnp_form", "tiles"]
 
 _NEG = -1e30
+# what a row's running max starts from: far under any score and far enough
+# above _NEG that a score set to _NEG is exp2 of about -2e29 times the scale
+_FLOOR = -1e20
+_LOG2_E = math.log2(math.e)
 _ROWS = 512             # the most queries a call takes, one block
 _BLOCK = 512            # keys a tile
 _GROUP = 4              # heads a grid cell, which share a tile of the mask
@@ -87,10 +112,11 @@ def _kernel(k_len, qn_ref, qr_ref, kv_ref, kr_ref, mask_ref, o_ref, m_ref,
     block = kv_ref.shape[1]
     first = j * block                   # the tile's first key
     group, side = m_ref.shape[0], 128 // rope
+    to_exp2 = scale * _LOG2_E
 
     @pl.when(j == 0)
     def _():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        m_ref[...] = jnp.full_like(m_ref, _FLOOR)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -115,15 +141,19 @@ def _kernel(k_len, qn_ref, qr_ref, kv_ref, kr_ref, mask_ref, o_ref, m_ref,
             s = (lax.dot_general(q_n, k_n, _NT,
                                  preferred_element_type=jnp.float32)
                  + lax.dot_general(q_r, k_r, _NT,
-                                   preferred_element_type=jnp.float32)
-                 ) * scale
+                                   preferred_element_type=jnp.float32))
             s = jnp.where(allowed, s, _NEG)
-            m = m_ref[h]                                    # (bq, 1)
+            m = m_ref[h]                                    # (bq, 128)
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-            # a row that has seen no allowed key yet keeps l = 0
-            p = jnp.where(allowed, jnp.exp(s - m_new), 0.0)
-            corr = jnp.exp(m - m_new)
-            l_ref[h] = l_ref[h] * corr + p.sum(axis=-1, keepdims=True)
+            # m_new is at least _FLOOR, so a score that is not allowed
+            # gives an exact 0 in every row, one that has seen no key too
+            p = jnp.exp2((s - jnp.concatenate([m_new] * (block // 128), 1))
+                         * to_exp2)
+            corr = jnp.exp2((m - m_new) * to_exp2)
+            lanes = p[:, :128]          # a row's sum, a lane's part of it
+            for c in range(128, block, 128):
+                lanes = lanes + p[:, c:c + 128]
+            l_ref[h] = l_ref[h] * corr + lanes
             acc_ref[h] = acc_ref[h] * corr + lax.dot_general(
                 p.astype(v.dtype), v, _NN,
                 preferred_element_type=jnp.float32)
@@ -132,8 +162,9 @@ def _kernel(k_len, qn_ref, qr_ref, kv_ref, kr_ref, mask_ref, o_ref, m_ref,
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
         for h in range(group):
+            total = l_ref[h].sum(axis=-1, keepdims=True)
             o_ref[0, :, h * v_dim:(h + 1) * v_dim] = (
-                acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)).astype(o_ref.dtype)
+                acc_ref[h] / jnp.maximum(total, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnums=(6, 7))
@@ -175,8 +206,8 @@ def _call(k_len, qn3, qr3, kv3, kr3, mask, scale: float, sizes: tuple):
             ],
             out_specs=pl.BlockSpec((1, t_q, _GROUP * v_dim),
                                    lambda b, g, j, n: (b, 0, g)),
-            scratch_shapes=[pltpu.VMEM((_GROUP, t_q, 1), jnp.float32),
-                            pltpu.VMEM((_GROUP, t_q, 1), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((_GROUP, t_q, 128), jnp.float32),
+                            pltpu.VMEM((_GROUP, t_q, 128), jnp.float32),
                             pltpu.VMEM((_GROUP, t_q, v_dim), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b, t_q, heads * v_dim), qn3.dtype),
         compiler_params=pltpu.CompilerParams(
